@@ -133,6 +133,7 @@ def run_local(
     manager.start_workers()
     deadline = time.time() + timeout_s if timeout_s else None
     restarts_left = cfg.master_restarts
+    ok = False
     try:
         while True:
             remaining = deadline - time.time() if deadline else None
@@ -185,5 +186,16 @@ def run_local(
                 rollup.get("skew", 1.0), rollup["straggler_count"],
             )
         master.shutdown()
+        if ok:
+            # Workers that saw job_done leave by themselves; give them the
+            # time. Terminating one in the middle of its teardown turns a
+            # clean exit into a preemption — and a TPU runtime shutdown
+            # takes seconds (longer with more chips), during which a killed
+            # owner can leave libtpu's lockfile to the next process.
+            deadline = time.monotonic() + 30.0
+            while not manager.all_exited() and time.monotonic() < deadline:
+                # one launcher polling its own children, no fleet to
+                # desynchronize: edl-lint: disable=EDL304
+                time.sleep(0.2)
         manager.stop()
     return 0 if ok else 1

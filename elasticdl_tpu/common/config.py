@@ -257,8 +257,9 @@ class JobConfig:
     master_unreachable_timeout_s: float = 300.0
     # Persistent XLA compilation cache (common/runtime.py): relaunched
     # workers deserialize the previous generation's executables instead of
-    # paying the 20-40 s TPU recompile on every elastic recovery. Point it
-    # at storage shared across relaunches (e.g. next to checkpoint_dir).
+    # recompiling on every elastic recovery. A set JAX_COMPILATION_CACHE_DIR
+    # wins over this; "" = <checkout>/.jax_cache. Point it at storage
+    # shared across relaunches (e.g. next to checkpoint_dir).
     compilation_cache_dir: str = ""
     # <0 keeps JAX's default floor (~1 s: only expensive programs persist);
     # >=0 overrides it (tests use 0 so test-sized programs cache too).
@@ -437,7 +438,7 @@ class JobConfig:
                              "per-commit fsync)")
         if self.journal_group_commit_ms > 10_000:
             # Commit.wait gives a flush 30s before declaring the journal
-            # wedged; a window at (or past) that order would fail every
+            # stuck; a window at (or past) that order would fail every
             # journaled RPC before its batch could ever flush. 10s is
             # already far beyond any sane fsync latency it could amortize.
             raise ValueError(

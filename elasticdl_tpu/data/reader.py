@@ -451,8 +451,11 @@ class SyntheticDataReader(AbstractDataReader):
             "vocab": self._vocab, "seq_len": self._seq_len,
         }
 
-    def _record(self, idx: int) -> bytes:
-        rng = np.random.RandomState((self._seed + idx) % (2**31))
+    def _record(self, idx: int, rng: np.random.RandomState) -> bytes:
+        # reseeding one generator draws the same stream as constructing one
+        # per record, at a seventieth of the cost (2 vs 155 us; constructing
+        # was two thirds of a criteo record's time)
+        rng.seed((self._seed + idx) % (2**31))
         if self._kind == "mnist":
             label = idx % 10
             img = (rng.rand(784) * 25 + label * 23).astype(np.uint8)
@@ -500,8 +503,9 @@ class SyntheticDataReader(AbstractDataReader):
         raise ValueError(f"unknown synthetic kind {self._kind!r}")
 
     def read_records(self, shard_name: str, start: int, end: int) -> Iterator[bytes]:
+        rng = np.random.RandomState()     # per call: spans read concurrently
         for i in range(start, min(end, self._n)):
-            yield self._record(i)
+            yield self._record(i, rng)
 
 
 def create_data_reader(

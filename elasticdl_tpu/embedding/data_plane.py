@@ -779,7 +779,7 @@ class GrpcTransport:
 
     def refresh_channel(self, owner: int) -> None:
         """Drop the cached channel so the next call rebuilds it (the
-        ResilientTransport's wedge recovery — a subchannel that wedged
+        ResilientTransport's wedge recovery — a subchannel that got stuck
         across an owner restart must not be trusted forever). The old
         channel is NOT force-closed: close() cancels in-flight RPCs and
         the transport is shared across threads."""

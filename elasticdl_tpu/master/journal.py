@@ -629,7 +629,7 @@ class Commit:
         if not self._event.wait(timeout_s):
             raise JournalCommitError(
                 f"journal group commit not durable after {timeout_s:.0f}s "
-                "(committer wedged or disk stalled)"
+                "(committer stuck or disk stalled)"
             )
         err = getattr(self._batch, "error", None)
         if err is not None:
@@ -1132,7 +1132,7 @@ class ControlPlaneJournal:
         older already-swapped one and invert the disk-order == mutation-
         order replay invariant. Failures park on the batch exactly as a
         committer flush failure would (waiters raise; the journal
-        poisons); a wedged committer bounds this wait at `timeout_s`."""
+        poisons); a stuck committer bounds this wait at `timeout_s`."""
         if self._window_s <= 0:
             return
         with self._qcv:

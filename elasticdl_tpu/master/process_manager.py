@@ -11,6 +11,7 @@ worker processes, as the reference's integration tests killed real pods.
 
 from __future__ import annotations
 
+import glob
 import os
 import signal
 import subprocess
@@ -40,6 +41,57 @@ _SPAWNS = _reg.counter(
     "edl_reform_worker_spawns_total", "worker processes spawned")
 _COHORT_SIZE = _reg.gauge(
     "edl_reform_cohort_size", "current cohort process count")
+
+
+# One process per chip. A TPU chip belongs to the first process that opens
+# it, so N worker processes on one host must each be told which chips are
+# theirs BEFORE they start JAX — through libtpu's own variables in the
+# child's environment. (chips on the host, processes) -> (chips-per-process
+# bounds, process bounds). Only what has formed a world on hardware is
+# listed: four processes x one chip on the four-chip v5e host (a 4-process
+# jax.distributed world summed over its devices, PR 21; no training job has
+# run on it yet). Two processes x two chips is NOT here: libtpu refused both
+# bounds tried ("Chip 0x1x0 not on Host ...") — ROADMAP A3 has the facts.
+_TPU_PROCESS_BOUNDS = {
+    (4, 4): ("1,1,1", "2,2,1"),
+}
+
+
+def local_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from their device nodes — never
+    through JAX: the launcher that asked would hold the chips it counts."""
+    return len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def tpu_process_env(slot: int, n_procs: int, ports: List[int]) -> Dict[str, str]:
+    """The environment that gives process `slot` of `n_procs` its own share
+    of this host's chips ({} when there is nothing to partition: one
+    process, or no TPU). `ports`: one libtpu mesh-service port per process,
+    the same list for every member. A split this host cannot make is logged
+    for what it is — the children will then contend for the chips and all
+    but one die at backend init — rather than raised: this runs on the
+    watcher thread during re-formations."""
+    chips = local_tpu_chips()
+    if n_procs <= 1 or not chips:
+        return {}
+    bounds = _TPU_PROCESS_BOUNDS.get((chips, n_procs))
+    if bounds is None:
+        logger.error(
+            "%d worker processes cannot each own chips of this host's %d: a "
+            "TPU chip belongs to one process, and the known splits are %s "
+            "(chips, processes). Expect backend-init failures in all but "
+            "one of them.", n_procs, chips, sorted(_TPU_PROCESS_BOUNDS))
+        return {}
+    per = chips // n_procs
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(
+            str(c) for c in range(slot * per, (slot + 1) * per)),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds[0],
+        "TPU_PROCESS_BOUNDS": bounds[1],
+        "TPU_PROCESS_ADDRESSES": ",".join(f"localhost:{p}" for p in ports),
+        "TPU_PROCESS_PORT": str(ports[slot]),
+        "CLOUD_TPU_TASK_ID": str(slot),
+    }
 
 
 def _reject_plain_training_scale_out(cfg: JobConfig) -> None:
@@ -114,6 +166,7 @@ class ProcessManager:
         self._next_worker_id = 0                     # guarded_by: _lock
         self._cohort_relaunches = 0                  # guarded_by: _lock
         self._cohort_coordinator = ""                # guarded_by: _lock
+        self._tpu_ports: List[int] = []              # guarded_by: _lock
         # dynamic world resizing state (cohort mode); a replayed journal
         # resumes the pre-crash world version so the next reform bumps
         # PAST it (never backwards past what workers already saw)
@@ -231,6 +284,9 @@ class ProcessManager:
             # differ from the argv's immutable cfg.num_processes
             env["EDL_NUM_PROCESSES"] = str(self._cohort_size)
             env["EDL_WORLD_VERSION"] = str(self._world_version)
+            if env.get("JAX_PLATFORMS") != "cpu":
+                env.update(tpu_process_env(
+                    process_id, self._cohort_size, self._tpu_ports))
         if self._signal_path:
             # where workers read the pending-membership announcement
             env[membership_signal.ENV_VAR] = self._signal_path
@@ -297,6 +353,9 @@ class ProcessManager:
         if size is not None:
             self._cohort_size = size
         self._cohort_coordinator = f"localhost:{free_port()}"
+        self._tpu_ports = (
+            [free_port() for _ in range(self._cohort_size)]
+            if local_tpu_chips() else [])
         for p in range(self._cohort_size):
             self._procs[p] = self._spawn(
                 0, relaunches=self._cohort_relaunches, process_id=p
@@ -674,7 +733,7 @@ class ProcessManager:
                         self.reformation_log[-1][0]
                         if self.reformation_log else 0.0
                     )
-                    if self._infra_retries and time.time() - last > 60:
+                    if self._infra_retries and time.monotonic() - last > 60:
                         self._infra_retries = 0
                         logger.info(
                             "world formation recovered; infra retry budget reset"
@@ -801,6 +860,7 @@ class ProcessManager:
                 with self._lock:
                     for wp in self._procs.values():
                         wp.status = PodStatus.SUCCEEDED
+                logger.info("cohort exited, codes %s", sorted(codes.values()))
                 return
             self._stop.wait(poll_s)
 

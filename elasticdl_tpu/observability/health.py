@@ -422,7 +422,7 @@ class ClusterHealth:
         scrape must never trigger a recompute, and scoring never depends
         on the scrape surface being alive). `snapshot_age_s` stamps how
         stale the cached rollup is AT SERVE TIME (-1 = never computed):
-        a scraper reading a wedged master's /healthz must be able to
+        a scraper reading a stuck master's /healthz must be able to
         tell a live rollup from one frozen at the wedge."""
         with self._lock:
             snap = dict(self._last)

@@ -30,9 +30,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from elasticdl_tpu.common import jax_compat
-
-jax_compat.ensure()  # older-jax API adapters (no-op on current jax)
 from jax import lax
 from jax.sharding import PartitionSpec as P
 

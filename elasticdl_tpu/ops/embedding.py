@@ -36,9 +36,6 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from elasticdl_tpu.common import jax_compat
-
-jax_compat.ensure()  # older-jax API adapters (no-op on current jax)
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -271,7 +268,7 @@ def _pallas_table_grad(cf, sf, num_rows):
     starts = (edges[:-1] // 128) * 128
 
     def pallas_branch(cf_t, sf_pad, starts):
-        from elasticdl_tpu.ops.pallas_attention import _interpret_active
+        from elasticdl_tpu.ops.pallas_attention import kernel_interpret
 
         out_t = pallas_scatter.place_sorted_grads(
             cf_t, sf_pad[None, :], starts,
@@ -279,7 +276,7 @@ def _pallas_table_grad(cf, sf, num_rows):
             split=os.environ.get(
                 "EDL_EMB_PALLAS_PRECISION", "split") != "bf16",
             group=pallas_scatter.group_blocks(),
-            interpret=_interpret_active(),
+            interpret=kernel_interpret(),
         )
         # kernel emits (D, vpad) — rows on lanes, see pallas_scatter —
         # one bandwidth-class transpose restores the param layout
@@ -358,7 +355,14 @@ def _gather_rows_bwd(res, ct):
             order = jnp.argsort(flat)
             d_table = _pallas_table_grad(cf[order], flat[order], num_rows)
             return d_table.astype(proto.dtype), None
-        mode = "tiled"   # no TPU / small shapes: the XLA tiled path
+        # trace-time, once per compiled program: which route this shape took
+        logger.info(
+            "embedding backward (%d ids into %d rows) stays off the Pallas "
+            "placement kernel (needs a TPU or interpret mode: %s; rows >= "
+            "%d; ids >= 4096; window estimate %.0f <= 16384) and takes the "
+            "XLA tiled path", flat.shape[0], num_rows,
+            pallas_scatter.runnable(), 2 * bs_p, est_w)
+        mode = "tiled"
     if mode == "tiled" and num_rows > 2 * _tile_rows() \
             and flat.shape[0] >= 4096:
         # below those sizes the flat scatter is already in (or near) the

@@ -23,9 +23,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from elasticdl_tpu.common import jax_compat
-
-jax_compat.ensure()  # older-jax API adapters (no-op on current jax)
 
 from elasticdl_tpu.common.constants import MeshAxis
 

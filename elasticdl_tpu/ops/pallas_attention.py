@@ -97,12 +97,8 @@ def interpret_mode():
     depend on JAX private internals. Tests use THIS, not pltpu directly."""
     prev = os.environ.get(_INTERPRET_ENV)
     os.environ[_INTERPRET_ENV] = "1"
-    # Older jax has no global interpret-mode context; the env flag above is
-    # the primary routing signal (every pallas_call here threads an explicit
-    # interpret= from _interpret_active), so a nullcontext loses nothing.
-    force = getattr(pltpu, "force_tpu_interpret_mode", contextlib.nullcontext)
     try:
-        with force():
+        with pltpu.force_tpu_interpret_mode():
             yield
     finally:
         if prev is None:
@@ -146,6 +142,23 @@ def _interpret_active() -> bool:
                 "elasticdl_tpu.ops.pallas_attention.interpret_mode()", e,
             )
         return False
+
+
+def kernel_interpret(requested: Optional[bool] = None) -> bool:
+    """The `interpret=` value for a pallas_call traced now: `requested`,
+    or the ambient `_interpret_active()` signal when None. Interpret mode
+    is what the CPU tests run the kernels under; on a TPU backend it would
+    execute the kernel as plain XLA ops under the chip's name, so there it
+    is an error, not a route."""
+    interpret = _interpret_active() if requested is None else bool(requested)
+    if interpret and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "Pallas interpret mode is active on a TPU backend "
+            f"({_INTERPRET_ENV}=1 or force_tpu_interpret_mode): the kernel "
+            "would run interpreted instead of compiled by Mosaic. Interpret "
+            "mode is for CPU tests only; unset it on the chip."
+        )
+    return interpret
 
 
 def _causal_p_mask(p, q_start, kv_start, block_q, block_k):
@@ -524,11 +537,7 @@ def flash_attention(
 
 def _plan_call(q, k, causal, q_offset, kv_offset, block_q, block_k,
                interpret, with_lse):
-    if interpret is None:
-        # default = the ambient interpret signal: on new jax the global
-        # force_tpu_interpret_mode config also catches interpret=False, but
-        # older jax has no global mode — the explicit flag must carry it
-        interpret = _interpret_active()
+    interpret = kernel_interpret(interpret)
     blocks = _plan_blocks(q.shape, k.shape, block_q, block_k,
                           dtype=q.dtype)
     if blocks is None:
@@ -539,7 +548,7 @@ def _plan_call(q, k, causal, q_offset, kv_offset, block_q, block_k,
     bq, bk = blocks
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(kv_offset, jnp.int32)])
-    return _make_flash(bool(causal), bq, bk, bool(interpret),
+    return _make_flash(bool(causal), bq, bk, interpret,
                        bool(with_lse)), offs
 
 

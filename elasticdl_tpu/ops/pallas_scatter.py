@@ -39,6 +39,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from elasticdl_tpu.ops.pallas_attention import _interpret_active, _sds
+
 # Output rows per grid step and sorted-stream rows per MXU chunk. bs*C
 # bf16 one-hot (4 MB at 8192x256) is the VMEM high-water mark; C=256 keeps
 # the contraction MXU-friendly (2x128 lanes). Total kernel work (compares
@@ -206,13 +208,13 @@ def place_sorted_grads(cf, sf, starts, *, num_rows, block_rows, w,
             _kernel, bs=bs, w=w, d=d, d_out=d_out, split=split,
             group=group),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((d_out, num_rows), jnp.float32),
+        # inside the manual (shard_map) lookup schedule the output must
+        # declare the mesh axes it varies over, like the cotangents do
+        out_shape=_sds((d_out, num_rows), jnp.float32, cf),
         interpret=interpret,
     )(starts, sf, cf)
 
 
 def runnable() -> bool:
     """The kernel needs a real TPU or interpret mode (CPU tests)."""
-    from elasticdl_tpu.ops.pallas_attention import _interpret_active
-
     return jax.default_backend() == "tpu" or _interpret_active()

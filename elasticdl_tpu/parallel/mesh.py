@@ -17,9 +17,6 @@ from collections.abc import Mapping
 from typing import Dict, Optional, Sequence
 
 import jax
-from elasticdl_tpu.common import jax_compat
-
-jax_compat.ensure()  # older-jax API adapters (no-op on current jax)
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -404,12 +404,12 @@ class RetryingMasterStub:
     ):
         self._stub = stub if stub is not None else MasterStub(channel)
         # Bounded reconnect loop for UNAVAILABLE-during-restart: a gRPC
-        # channel whose subchannel wedged against a restarted master (stale
+        # channel whose subchannel stuck against a restarted master (stale
         # backoff state, dead reuseport flow) can report connect failures
         # long after the master is back. With a channel_factory, every
         # `refresh_after` consecutive transport failures the stub REBUILDS
         # the channel — fresh sockets, fresh resolver — instead of trusting
-        # the wedged one forever. The workers wire this; injected test
+        # the stuck one forever. The workers wire this; injected test
         # stubs don't need it.
         self._channel = channel
         self._channel_factory = channel_factory

@@ -15,7 +15,7 @@ makes the recompile avoidable at three layers:
    (hits/misses/speculative) feed the bench's `recompile_hit_rate`.
 
 2. The persistent on-disk XLA cache (common/runtime.configure_jax_runtime,
-   `--compilation_cache_dir` / `EDL_COMPILATION_CACHE_DIR`): covers the
+   `JAX_COMPILATION_CACHE_DIR` / `--compilation_cache_dir`): covers the
    case the in-memory cache cannot — a re-formed PROCESS. The relaunched
    generation re-traces but deserializes executables instead of compiling.
 

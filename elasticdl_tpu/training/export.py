@@ -23,9 +23,6 @@ import os
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-from elasticdl_tpu.common import jax_compat
-
-jax_compat.ensure()  # older-jax API adapters (no-op on current jax)
 import numpy as np
 
 from elasticdl_tpu.common.log_utils import default_logger

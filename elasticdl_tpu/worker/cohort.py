@@ -158,13 +158,17 @@ class CohortWorker:
     def _build(self) -> None:
         import jax
 
-        from elasticdl_tpu.common.runtime import configure_jax_runtime
+        from elasticdl_tpu.common.runtime import (
+            configure_jax_runtime,
+            log_training_devices,
+        )
         from elasticdl_tpu.parallel.mesh import build_job_mesh
         from elasticdl_tpu.training.trainer import Trainer
 
         configure_jax_runtime(self.cfg)
         self._spec = ModelSpec.from_config(self.cfg)
         self._mesh = build_job_mesh(self.cfg, jax.devices())
+        log_training_devices(self._mesh)
         from elasticdl_tpu.training import compile_cache as cc
 
         # config-derived token: a re-formed generation at the same mesh
@@ -268,7 +272,7 @@ class CohortWorker:
         # Hardened stub (deadlines, idempotent retries, circuit breaker);
         # every successful RPC refreshes the master-unreachable clock. The
         # channel_factory bounds master-restart recovery: repeated wire
-        # failures rebuild the channel rather than trusting a wedged one.
+        # failures rebuild the channel rather than trusting a stuck one.
         self._stub = RetryingMasterStub(
             self._channel, on_success=self._note_master_ok,
             channel_factory=lambda: make_channel(self.cfg.master_addr),

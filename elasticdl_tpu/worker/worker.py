@@ -138,7 +138,7 @@ class Worker:
         # refreshes the master-unreachable clock through on_success. The
         # channel_factory makes master-restart recovery bounded: repeated
         # transport failures rebuild the channel instead of trusting a
-        # subchannel that wedged when the old master's listener vanished.
+        # subchannel that got stuck when the old master's listener vanished.
         self._stub = RetryingMasterStub(
             self._channel, on_success=self._note_master_ok,
             channel_factory=lambda: make_channel(addr),
@@ -299,7 +299,10 @@ class Worker:
             return False
 
     def _build_trainer(self) -> None:
-        from elasticdl_tpu.common.runtime import configure_jax_runtime
+        from elasticdl_tpu.common.runtime import (
+            configure_jax_runtime,
+            log_training_devices,
+        )
         from elasticdl_tpu.parallel.mesh import build_job_mesh
         import jax
 
@@ -307,6 +310,7 @@ class Worker:
         self._spec = ModelSpec.from_config(self.cfg)
         if self._mesh is None:
             self._mesh = build_job_mesh(self.cfg, jax.devices())
+        log_training_devices(self._mesh)
         self._trainer = self._make_trainer(self._mesh)
 
     def _make_trainer(self, mesh):
@@ -1202,11 +1206,14 @@ class Worker:
         self._metrics_server = start_server(
             role=f"worker-{self.worker_id}", port=self.cfg.metrics_port
         )
-        self._build_trainer()
+        # beats start BEFORE the backend comes up: reaching four chips took
+        # 18 s (PR 21), and a registered worker that stays silent for three
+        # beat periods is declared dead and told to leave
         self._heartbeat_thread = threading.Thread(
             target=self._heartbeat_loop, daemon=True
         )
         self._heartbeat_thread.start()
+        self._build_trainer()
 
         tasks_done = 0
         wait_backoff = 1.0
@@ -1319,6 +1326,15 @@ class Worker:
                     if stats["step_time_sum"] > 0:
                         _TRAIN_THROUGHPUT.set(
                             stats["records_done"] / stats["step_time_sum"]
+                        )
+                    if stats["loss_count"]:
+                        # the first task's figure includes the compile
+                        logger.info(
+                            "training task %d: %d step(s), %.1f ms/step, "
+                            "mean loss %.4f", task.task_id,
+                            stats["loss_count"],
+                            1e3 * stats["step_time_sum"] / stats["loss_count"],
+                            stats["loss_sum"] / stats["loss_count"],
                         )
                     if stats["interrupted"]:
                         self._report_preempted_task(task, stats)

@@ -206,6 +206,9 @@ def test_speculative_compile_hits_on_actual_resize(spec, mesh8, tmp_path,
     from elasticdl_tpu.parallel import elastic
 
     new_mesh = compiled_meshes[4]
+    # read before the handoff: leaves that keep their placement are handed
+    # over by reference, and the next train_step donates them
+    old_step = int(jax.device_get(state.step))
     handoff = elastic.LiveStateHandoff().capture(state)
     t_new = make_trainer(spec, new_mesh, cache)
     new_state = handoff.apply(new_mesh)
@@ -214,7 +217,7 @@ def test_speculative_compile_hits_on_actual_resize(spec, mesh8, tmp_path,
     assert stats["misses"] == 0, stats
     assert stats["hits"] >= 1, stats
     assert stats["hit_rate"] == 1.0
-    assert int(new_state.step) == int(jax.device_get(state.step)) + 1
+    assert int(new_state.step) == old_step + 1
     assert np.isfinite(float(logs["loss"]))
 
 

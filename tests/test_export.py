@@ -7,7 +7,6 @@ where Embedding→keras export had to reproduce the PS table contents exactly.
 import numpy as np
 import pytest
 
-from tests.conftest import requires_spmd_partitioning
 
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.training.export import (
@@ -22,6 +21,7 @@ from elasticdl_tpu.worker.prediction_outputs_processor import (
     InMemoryPredictionOutputsProcessor,
     NpyPredictionOutputsProcessor,
 )
+from tests.conftest import heavy_on_cpu
 
 MODEL_PARAMS = {"field_vocab": 64, "hidden": "32,32"}
 
@@ -148,8 +148,7 @@ def test_saved_model_export(trained, tmp_path):
 
 @pytest.mark.parametrize("params", [
     {"tp_axis": "model"},
-    pytest.param({"pp_axis": "pp", "num_layers": 4},
-                 marks=requires_spmd_partitioning),
+    pytest.param({"pp_axis": "pp", "num_layers": 4}, marks=heavy_on_cpu),
 ])
 def test_export_roundtrip_tp_and_pp_lm(params, tmp_path):
     """Serving completeness for the parallel LM variants: a TP- or

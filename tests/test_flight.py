@@ -244,7 +244,7 @@ def test_scrapes_never_block_or_corrupt_during_dumps(tmp_path):
             t.start()
         for t in threads:
             t.join(timeout=60)
-            assert not t.is_alive(), "scrape wedged behind a dump"
+            assert not t.is_alive(), "scrape stuck behind a dump"
     finally:
         stop.set()
         dump_thread.join(timeout=10)

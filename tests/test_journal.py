@@ -946,7 +946,7 @@ def test_reform_never_announces_undurable_world_version(
     tmp_path, monkeypatch
 ):
     """The crash-consistency pin: when the commit CANNOT be made durable
-    (committer finds the journal wedged/closed), the reform aborts
+    (committer finds the journal stuck/closed), the reform aborts
     un-announced — an announced world version can never be one a
     successor's replay lacks."""
     import pytest as _pytest

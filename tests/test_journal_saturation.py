@@ -1,6 +1,6 @@
 """Journal group-commit saturation (ISSUE 16): the open-batch queue
 depth is observable and bounded by the window swap, the backpressure
-warning edge-triggers once per saturated window, a wedged committer can
+warning edge-triggers once per saturated window, a stuck committer can
 NEVER silently ack (Commit.wait raises JournalCommitError on timeout or
 flush error), and an N-thread x M-commit burst lands every record —
 replaying to the identical state twice."""
@@ -106,12 +106,12 @@ def test_no_backpressure_warning_below_threshold(tmp_path):
 
 
 def test_commit_wait_timeout_raises_not_acks():
-    # a commit whose event never fires (committer wedged / disk stalled):
+    # a commit whose event never fires (committer stuck / disk stalled):
     # the caller must get JournalCommitError, never a clean return it
     # could mistake for durability
-    wedged = Commit(threading.Event(), batch=None)
+    stuck = Commit(threading.Event(), batch=None)
     with pytest.raises(JournalCommitError, match="not durable"):
-        wedged.wait(timeout_s=0.05)
+        stuck.wait(timeout_s=0.05)
 
 
 def test_commit_wait_surfaces_flush_errors():

@@ -9,14 +9,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.conftest import requires_spmd_partitioning
-
 from elasticdl_tpu.ops.attention import full_attention
 from elasticdl_tpu.ops.pallas_attention import (
     can_flash,
     flash_attention,
     pick_block,
 )
+from tests.conftest import heavy_on_cpu
 
 B, T, H, D = 2, 64, 2, 16
 
@@ -286,7 +285,7 @@ def test_flash_lse_value_and_gradient():
 
 
 @pytest.mark.parametrize("causal", [
-    pytest.param(False, marks=requires_spmd_partitioning), True,
+    pytest.param(False, marks=heavy_on_cpu), True,
 ])
 def test_ring_flash_matches_full_attention(monkeypatch, causal):
     """Ring attention with the flash block kernel (EDL_FLASH=1 +

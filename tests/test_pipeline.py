@@ -8,10 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.conftest import requires_spmd_partitioning
-
 from elasticdl_tpu.parallel.mesh import build_mesh
 from elasticdl_tpu.parallel.pipeline import gpipe, stage_partition_specs
+from tests.conftest import heavy_on_cpu
 
 S, DIN = 4, 8
 
@@ -36,8 +35,7 @@ def sequential(params, x):
 
 @pytest.mark.parametrize("mesh_axes", [
     {"pp": 4},
-    pytest.param({"data": 2, "pp": 4},
-                 marks=requires_spmd_partitioning),
+    pytest.param({"data": 2, "pp": 4}, marks=heavy_on_cpu),
 ])
 @pytest.mark.usefixtures("mesh8")
 @pytest.mark.parametrize("num_microbatches", [1, 2, 4])

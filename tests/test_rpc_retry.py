@@ -315,7 +315,7 @@ def test_adopt_generation_from_trailing_metadata_resets_breaker():
 def test_channel_refresh_after_repeated_transport_failures():
     """The bounded reconnect loop: with a channel_factory wired, every
     `refresh_after` consecutive transport failures rebuilds the channel
-    (fresh sockets — a subchannel wedged across a master restart must not
+    (fresh sockets — a subchannel stuck across a master restart must not
     be trusted forever), and a success resets the count."""
 
     class FakeChannel:

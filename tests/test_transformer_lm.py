@@ -3,11 +3,6 @@ mesh, input partitioning honored end to end, loss falls on the synthetic
 bigram stream."""
 
 import numpy as np
-
-from tests.conftest import (
-    requires_spmd_partitioning,
-    requires_tp_exact_backend,
-)
 import pytest
 
 from elasticdl_tpu.common.config import JobConfig
@@ -15,6 +10,7 @@ from elasticdl_tpu.data.reader import SyntheticDataReader, create_data_reader
 from elasticdl_tpu.parallel.mesh import build_mesh
 from elasticdl_tpu.training.model_spec import ModelSpec
 from elasticdl_tpu.training.trainer import Trainer
+from tests.conftest import heavy_on_cpu
 
 MODEL_PARAMS = {
     "vocab": 64, "num_layers": 2, "dim": 64, "heads": 4,
@@ -85,8 +81,8 @@ def test_batch_partition_applied(reader):
     from elasticdl_tpu.parallel.mesh import shard_batch
 
     b = shard_batch(mesh, make_batch(spec, reader, 2), spec.batch_partition)
-    # compare shardings, not raw specs: older jax normalizes spec entries
-    # to tuples (('data',) vs 'data'), so spec == spec is version-fragile
+    # compare shardings, not raw specs: ('data',) and 'data' are the same
+    # sharding but unequal spec entries
     from jax.sharding import NamedSharding
 
     f = b["features"]
@@ -131,7 +127,7 @@ def test_remat_accum_with_flash_kernel(reader, monkeypatch):
     assert knobs == pytest.approx(plain, rel=1e-4), (plain, knobs)
 
 
-@requires_tp_exact_backend
+@heavy_on_cpu
 def test_tensor_parallel_matches_replicated(reader):
     """Megatron-style TP (tp_axis=model): same seed, same batch, one train
     step — loss and (gathered) params must match the replicated run, with
@@ -205,7 +201,7 @@ def test_tensor_parallel_inserts_model_axis_collectives(reader):
     assert n_tp > n_base, (n_tp, n_base)
 
 
-@requires_spmd_partitioning
+@heavy_on_cpu
 def test_pipeline_parallel_lm_matches_no_pp_mesh(reader):
     """pp_axis=pp: the SAME module + params run pipelined on a data x pp
     mesh and sequentially on a data-only mesh (gpipe's fallback) — one
