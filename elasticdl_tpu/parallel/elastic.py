@@ -374,12 +374,10 @@ def context_from_env(cfg) -> Optional[CohortContext]:
     (EDL_PROCESS_ID present), so the override may legitimately be 1.
     """
     n = int(os.environ.get("EDL_NUM_PROCESSES", "0") or 0) or cfg.num_processes
-    if n <= 1 and "EDL_PROCESS_ID" not in os.environ:
+    pid = os.environ.get("EDL_PROCESS_ID")
+    if n <= 1 and pid is None:
         return None
-    if (
-        "EDL_PROCESS_ID" not in os.environ
-        and os.environ.get("EDL_PROCESS_ID_FROM_HOSTNAME") == "1"
-    ):
+    if pid is None and os.environ.get("EDL_PROCESS_ID_FROM_HOSTNAME") == "1":
         # k8s StatefulSet flavor: pods are <name>-<ordinal>; the ordinal IS
         # the cohort process id (stable across pod restarts, which is what
         # makes a StatefulSet the right k8s shape for a jax.distributed
@@ -387,19 +385,16 @@ def context_from_env(cfg) -> Optional[CohortContext]:
         import socket
 
         host = socket.gethostname()
-        ordinal = host.rsplit("-", 1)[-1]
-        if ordinal.isdigit():
-            os.environ["EDL_PROCESS_ID"] = ordinal
-        else:
+        pid = host.rsplit("-", 1)[-1]
+        if not pid.isdigit():
             raise RuntimeError(
                 f"EDL_PROCESS_ID_FROM_HOSTNAME=1 but hostname {host!r} has "
                 "no trailing ordinal"
             )
-    pid = int(os.environ.get("EDL_PROCESS_ID", "0"))
     addr = (
         os.environ.get("EDL_COORDINATOR_ADDR")
         or cfg.coordinator_addr
         or "localhost:29400"
     )
     version = int(os.environ.get("EDL_WORLD_VERSION", "0") or 0)
-    return CohortContext(addr, n, pid, world_version=version)
+    return CohortContext(addr, n, int(pid or 0), world_version=version)

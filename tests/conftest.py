@@ -52,3 +52,38 @@ def mesh_4x2():
     from elasticdl_tpu.parallel.mesh import build_mesh
 
     return build_mesh({"data": 4, "model": 2})
+
+
+_GUARDED_ENV = ("EDL_", "JAX_", "XLA_", "TPU_")
+#: What importing TensorFlow writes, once and for the whole process
+#: (master/summary_service.py and training/export.py import it on first use):
+#: a library's doing, the same after any test that reaches it, so no leak of
+#: the test's.
+_SET_BY_TENSORFLOW = {"TPU_ML_PLATFORM", "TPU_ML_PLATFORM_VERSION"}
+
+
+@pytest.fixture(autouse=True)
+def _environment_is_an_input():
+    """Fail the test that leaves os.environ changed. Every job test starts
+    workers that inherit this process's environment, so one leaked EDL_*
+    variable breaks whichever tests happen to follow (PR 23: a leaked
+    EDL_PROCESS_ID=2 cost the suite three 420 s waits and its time limit).
+    Autouse, so set up before the test's own monkeypatch and torn down
+    after it: this sees what monkeypatch put back."""
+    before = {k: v for k, v in os.environ.items() if k.startswith(_GUARDED_ENV)}
+    yield
+    after = {k: v for k, v in os.environ.items() if k.startswith(_GUARDED_ENV)}
+    leaked = sorted(
+        k for k in (before.keys() | after.keys()) - _SET_BY_TENSORFLOW
+        if before.get(k) != after.get(k)
+    )
+    if leaked:
+        for k in leaked:
+            os.environ.pop(k, None)
+            if k in before:
+                os.environ[k] = before[k]
+        pytest.fail(
+            "test left os.environ changed: "
+            + ", ".join(f"{k}={after.get(k)!r} (was {before.get(k)!r})"
+                        for k in leaked)
+        )

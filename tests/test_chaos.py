@@ -287,8 +287,7 @@ def test_chaos_smoke_different_seed_changes_schedule():
 def test_chaos_soak_e2e(tmp_path):
     from elasticdl_tpu.client.local import free_port
     from elasticdl_tpu.common.config import JobConfig
-    from elasticdl_tpu.master.main import Master
-    from elasticdl_tpu.master.process_manager import ProcessManager
+    from tests.jobs import run_job
 
     trace_path = tmp_path / "fault_trace"
     soak_spec = (
@@ -301,9 +300,6 @@ def test_chaos_soak_e2e(tmp_path):
         "ckpt.save.commit:crash@at=2"
     )
     env = {
-        "JAX_PLATFORMS": "cpu",
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-        "EDL_LOG_LEVEL": "INFO",
         faults.FAULTS_ENV: soak_spec,
         faults.SEED_ENV: "7",
         faults.TRACE_ENV: str(trace_path),
@@ -327,33 +323,17 @@ def test_chaos_soak_e2e(tmp_path):
         checkpoint_steps=3,
         relaunch_max=5,
     )
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        extra_env=env,
-        log_dir=str(tmp_path / "logs"),
-        job_finished_fn=master.dispatcher.finished,
-    )
-    master.start()
-    manager.start_workers()
-    try:
-        ok = master.wait(timeout_s=420, abort_fn=manager.all_failed)
-        log = (tmp_path / "logs" / "worker-0.log").read_text()
-        assert ok, "soak did not finish; worker log:\n" + log[-6000:]
-        counts = master.dispatcher.counts()
-        # exactly-once task accounting under the whole schedule
-        assert counts["failed_permanently"] == 0, counts
-        assert counts["finished_training"] == 4, counts
-        assert counts["todo"] == 0 and counts["doing"] == 0, counts
-        # the schedule really fired: the worker died mid-checkpoint-write
-        # at least once and a relaunched generation restored state
-        trace = trace_path.read_text() if trace_path.exists() else ""
-        assert "ckpt.save.commit:crash" in trace, trace
-        assert "resumed from checkpoint" in log
-    finally:
-        master.shutdown(grace_s=2)
-        manager.stop()
+    _, manager, counts = run_job(cfg, tmp_path, extra_env=env)
+    log = (tmp_path / "logs" / "worker-0.log").read_text()
+    # exactly-once task accounting under the whole schedule
+    assert counts["failed_permanently"] == 0, counts
+    assert counts["finished_training"] == 4, counts
+    assert counts["todo"] == 0 and counts["doing"] == 0, counts
+    # the schedule really fired: the worker died mid-checkpoint-write
+    # at least once and a relaunched generation restored state
+    trace = trace_path.read_text() if trace_path.exists() else ""
+    assert "ckpt.save.commit:crash" in trace, trace
+    assert "resumed from checkpoint" in log
     deadline = time.time() + 30
     while not manager.all_exited() and time.time() < deadline:
         time.sleep(0.5)
@@ -750,13 +730,9 @@ def test_master_restart_e2e(tmp_path):
     generation 2 without being restarted."""
     from elasticdl_tpu.client.local import free_port, run_local
     from elasticdl_tpu.common.config import JobConfig
+    from tests.jobs import HERMETIC_ENV
 
     faults.install("master_crash:drop@at=4")
-    env = {
-        "JAX_PLATFORMS": "cpu",
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-        "EDL_LOG_LEVEL": "INFO",
-    }
     cfg = JobConfig(
         job_name="master-kill-e2e",
         job_type="training_only",
@@ -778,7 +754,8 @@ def test_master_restart_e2e(tmp_path):
         master_restarts=1,
     )
     rc = run_local(
-        cfg, extra_env=env, log_dir=str(tmp_path / "logs"), timeout_s=420
+        cfg, extra_env=HERMETIC_ENV, log_dir=str(tmp_path / "logs"),
+        timeout_s=420,
     )
     log = (tmp_path / "logs" / "worker-0.log").read_text()
     assert rc == 0, "e2e did not finish; worker log:\n" + log[-6000:]

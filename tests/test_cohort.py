@@ -15,13 +15,7 @@ from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.master.main import Master
 from elasticdl_tpu.master.process_manager import ProcessManager
 from tests.conftest import heavy_on_cpu
-
-
-HERMETIC_ENV = {
-    "JAX_PLATFORMS": "cpu",
-    "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-    "EDL_LOG_LEVEL": "INFO",
-}
+from tests.jobs import HERMETIC_ENV, all_logs, run_job
 
 
 def job_config(tmp_path, **overrides):
@@ -46,53 +40,9 @@ def job_config(tmp_path, **overrides):
     return JobConfig(**base)
 
 
-def run_job(cfg, tmp_path, mid_job=None, timeout_s=420, return_all=False,
-            resize_ckpt_timeout_s=30.0, observer=None, extra_env=None):
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        extra_env={**HERMETIC_ENV, **(extra_env or {})},
-        log_dir=str(tmp_path / "logs"),
-        job_finished_fn=master.dispatcher.finished,
-        # production wiring (client/local.py): planned resizes quiesce via
-        # the heartbeat should_checkpoint bit
-        checkpoint_request_fn=lambda: master.servicer.request_checkpoint(0),
-        resize_checkpoint_timeout_s=resize_ckpt_timeout_s,
-    )
-    master.start()
-    manager.start_workers()
-    try:
-        deadline = time.time() + timeout_s
-        fired = False
-        while not master.dispatcher.finished() and time.time() < deadline:
-            master.membership.reap()
-            master.dispatcher.poke()
-            if mid_job is not None and not fired:
-                fired = mid_job(master, manager)
-            if observer is not None:
-                observer(master, manager)
-            time.sleep(0.2)
-        assert master.dispatcher.finished(), (
-            master.dispatcher.counts(), all_logs(tmp_path)[-3000:],
-        )
-        counts = master.dispatcher.counts()
-        return (master, manager, counts) if return_all else counts
-    finally:
-        master.shutdown()
-        manager.stop()
-
-
-def all_logs(tmp_path) -> str:
-    out = []
-    for f in sorted(glob.glob(str(tmp_path / "logs" / "*.log"))):
-        out.append(open(f, errors="replace").read())
-    return "\n".join(out)
-
-
 def test_cohort_job_end_to_end(tmp_path):
     cfg = job_config(tmp_path, output=str(tmp_path / "export"))
-    counts = run_job(cfg, tmp_path)
+    *_, counts = run_job(cfg, tmp_path)
     assert counts["finished_training"] == 4
     assert counts["failed_permanently"] == 0
     log = all_logs(tmp_path)
@@ -107,7 +57,7 @@ def test_cohort_grouped_dispatch_end_to_end(tmp_path):
     per 2 minibatches); a 512-record task at minibatch 64 = 8 batches = 4
     full groups; task accounting and loss reporting unchanged."""
     cfg = job_config(tmp_path, steps_per_dispatch=2, wire_dtype="bfloat16")
-    counts = run_job(cfg, tmp_path)
+    *_, counts = run_job(cfg, tmp_path)
     assert counts["finished_training"] == 4
     assert counts["failed_permanently"] == 0
     log = all_logs(tmp_path)
@@ -133,7 +83,7 @@ def test_master_lr_push_applies(tmp_path, num_processes):
             master.servicer.set_learning_rate(5e-4)
             fired["done"] = True
 
-    counts = run_job(cfg, tmp_path, observer=push_lr)
+    *_, counts = run_job(cfg, tmp_path, observer=push_lr)
     assert counts["failed_permanently"] == 0
     assert fired["done"]
     log = all_logs(tmp_path)
@@ -157,7 +107,7 @@ def test_cohort_evaluation_only_job(tmp_path, steps_per_dispatch):
         records_per_task=256,
         steps_per_dispatch=steps_per_dispatch,
     )
-    master, manager, counts = run_job(cfg, tmp_path, return_all=True)
+    master, _, counts = run_job(cfg, tmp_path)
     assert counts["failed_permanently"] == 0
     results = master.evaluation.latest_results()
     assert "auc" in results and "loss" in results, results
@@ -184,7 +134,7 @@ def test_cohort_prediction_job(tmp_path, num_processes, steps_per_dispatch):
         num_processes=num_processes,
         steps_per_dispatch=steps_per_dispatch,
     )
-    counts = run_job(
+    *_, counts = run_job(
         cfg, tmp_path, extra_env={"EDL_PREDICT_OUT": str(out_dir)})
     assert counts["failed_permanently"] == 0
     files = sorted(glob.glob(str(out_dir / "*.npy")))
@@ -213,7 +163,7 @@ def test_cohort_member_kill_relaunches_and_resumes(tmp_path):
         wp.proc.kill()
         return True
 
-    counts = run_job(cfg, tmp_path, mid_job=kill_follower_after_checkpoint)
+    *_, counts = run_job(cfg, tmp_path, mid_job=kill_follower_after_checkpoint)
     assert counts["finished_training"] == 8
     assert counts["failed_permanently"] == 0
     log = all_logs(tmp_path)
@@ -254,7 +204,7 @@ def test_cohort_leader_sigterm_drains_via_checkpoint(tmp_path):
                 manager.reformation_log:
             lat["reform_t"] = manager.reformation_log[0][0]
 
-    counts = run_job(cfg, tmp_path, mid_job=sigterm_leader, observer=observe)
+    *_, counts = run_job(cfg, tmp_path, mid_job=sigterm_leader, observer=observe)
     assert counts["finished_training"] == 8
     assert counts["failed_permanently"] == 0
     log = all_logs(tmp_path)
@@ -401,8 +351,7 @@ def test_cohort_resizes_down_at_exhausted_budget(tmp_path):
             lat["first_task_t"] = time.monotonic()
 
     master, manager, counts = run_job(
-        cfg, tmp_path, mid_job=kill_follower, return_all=True,
-        observer=observe,
+        cfg, tmp_path, mid_job=kill_follower, observer=observe,
     )
     assert counts["finished_training"] == 8
     assert counts["failed_permanently"] == 0
@@ -447,7 +396,7 @@ def test_cohort_scales_up_on_add_worker(tmp_path):
         return True
 
     master, manager, counts = run_job(
-        cfg, tmp_path, mid_job=scale_up, return_all=True
+        cfg, tmp_path, mid_job=scale_up
     )
     assert counts["finished_training"] == 24
     assert counts["failed_permanently"] == 0
@@ -483,7 +432,7 @@ def test_cohort_remove_worker_quiesces_then_resizes(tmp_path):
         return True
 
     master, manager, counts = run_job(
-        cfg, tmp_path, mid_job=scale_down, return_all=True
+        cfg, tmp_path, mid_job=scale_down
     )
     assert counts["finished_training"] == 24
     assert counts["failed_permanently"] == 0

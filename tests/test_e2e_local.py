@@ -11,15 +11,8 @@ import time
 import pytest
 
 from elasticdl_tpu.common.config import JobConfig
-from elasticdl_tpu.master.main import Master
-from elasticdl_tpu.master.process_manager import ProcessManager
 from elasticdl_tpu.client.local import free_port
-
-HERMETIC_ENV = {
-    "JAX_PLATFORMS": "cpu",
-    "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-    "EDL_LOG_LEVEL": "INFO",
-}
+from tests.jobs import run_job
 
 
 def job_config(tmp_path, num_workers=1, **overrides):
@@ -46,31 +39,13 @@ def job_config(tmp_path, num_workers=1, **overrides):
 
 def test_local_job_end_to_end(tmp_path):
     cfg = job_config(tmp_path, num_workers=1)
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        extra_env=HERMETIC_ENV,
-        log_dir=str(tmp_path / "logs"),
-    )
-    master.start()
-    manager.start_workers()
-    try:
-        ok = master.wait(timeout_s=420)
-        assert ok, (
-            "job did not finish; worker log:\n"
-            + (tmp_path / "logs" / "worker-0.log").read_text()[-4000:]
-        )
-        counts = master.dispatcher.counts()
-        assert counts["finished_training"] == 4      # 400 records / 100 per task
-        assert counts["failed_permanently"] == 0
-        # epoch-end eval ran and aggregated
-        results = master.evaluation.latest_results()
-        assert "accuracy" in results and "loss" in results, results
-        assert master.servicer.mean_training_loss() is not None
-    finally:
-        master.shutdown(grace_s=2)
-        manager.stop()
+    master, manager, counts = run_job(cfg, tmp_path)
+    assert counts["finished_training"] == 4      # 400 records / 100 per task
+    assert counts["failed_permanently"] == 0
+    # epoch-end eval ran and aggregated
+    results = master.evaluation.latest_results()
+    assert "accuracy" in results and "loss" in results, results
+    assert master.servicer.mean_training_loss() is not None
     # workers exited cleanly on job completion
     deadline = time.time() + 30
     while not manager.all_exited() and time.time() < deadline:
@@ -87,31 +62,13 @@ def test_local_job_with_grouped_dispatch(tmp_path):
     32,4 → one full group + one partial)."""
     cfg = job_config(tmp_path, num_workers=1, steps_per_dispatch=2,
                      wire_dtype="bfloat16")  # grouped path must honor the cast
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        extra_env=HERMETIC_ENV,
-        log_dir=str(tmp_path / "logs"),
-    )
-    master.start()
-    manager.start_workers()
-    try:
-        ok = master.wait(timeout_s=420)
-        assert ok, (
-            "job did not finish; worker log:\n"
-            + (tmp_path / "logs" / "worker-0.log").read_text()[-4000:]
-        )
-        counts = master.dispatcher.counts()
-        assert counts["finished_training"] == 4
-        assert counts["failed_permanently"] == 0
-        # all 400 records were applied exactly once (grouped accounting)
-        assert master.servicer.mean_training_loss() is not None
-        results = master.evaluation.latest_results()
-        assert "accuracy" in results, results
-    finally:
-        master.shutdown(grace_s=2)
-        manager.stop()
+    master, _, counts = run_job(cfg, tmp_path)
+    assert counts["finished_training"] == 4
+    assert counts["failed_permanently"] == 0
+    # all 400 records were applied exactly once (grouped accounting)
+    assert master.servicer.mean_training_loss() is not None
+    results = master.evaluation.latest_results()
+    assert "accuracy" in results, results
 
 
 @pytest.mark.slow
@@ -130,24 +87,7 @@ def test_profiling_and_step_time_summaries(tmp_path):
         summary_dir=str(tmp_path / "summaries"),
         job_type="training_only",
     )
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        extra_env=HERMETIC_ENV,
-        log_dir=str(tmp_path / "logs"),
-    )
-    master.start()
-    manager.start_workers()
-    try:
-        ok = master.wait(timeout_s=420)
-        assert ok, (
-            "job did not finish; worker log:\n"
-            + (tmp_path / "logs" / "worker-0.log").read_text()[-4000:]
-        )
-    finally:
-        master.shutdown(grace_s=2)
-        manager.stop()
+    run_job(cfg, tmp_path)
 
     # trace files appeared (jax.profiler writes plugins/profile/<ts>/...)
     trace_files = []
@@ -185,27 +125,21 @@ def test_local_transformer_lm_job_end_to_end(tmp_path):
         records_per_task=128,
         minibatch_size=16,
     )
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        extra_env=HERMETIC_ENV,
-        log_dir=str(tmp_path / "logs"),
-    )
-    master.start()
-    manager.start_workers()
-    try:
-        ok = master.wait(timeout_s=420)
-        assert ok, (
-            "LM job did not finish; worker log:\n"
-            + (tmp_path / "logs" / "worker-0.log").read_text()[-4000:]
-        )
-        counts = master.dispatcher.counts()
-        assert counts["finished_training"] == 4      # 512 / 128
-        assert counts["failed_permanently"] == 0
-        results = master.evaluation.latest_results()
-        assert "token_accuracy" in results, results
-        assert 0.0 <= results["token_accuracy"] <= 1.0
-    finally:
-        master.shutdown(grace_s=2)
-        manager.stop()
+    master, _, counts = run_job(cfg, tmp_path)
+    assert counts["finished_training"] == 4      # 512 / 128
+    assert counts["failed_permanently"] == 0
+    results = master.evaluation.latest_results()
+    assert "token_accuracy" in results, results
+    assert 0.0 <= results["token_accuracy"] <= 1.0
+
+
+def test_run_job_stops_when_the_job_is_dead(tmp_path):
+    """The harness itself (tests/jobs.py): a one-process worker started as
+    cohort member 2 of 1 dies at world formation on every launch. run_job
+    must raise once the relaunch budget is spent — about four launches —
+    with the worker's own words, not wait out its 420 s deadline."""
+    cfg = job_config(tmp_path)
+    t0 = time.time()
+    with pytest.raises(AssertionError, match="world formation failed"):
+        run_job(cfg, tmp_path, extra_env={"EDL_PROCESS_ID": "2"})
+    assert time.time() - t0 < 90

@@ -5,22 +5,14 @@ exactly-once task accounting (no record loss), checkpoint-resume across
 re-formations, and the recovery wall-clock overhead measured and reported.
 """
 
-import glob
 import os
 import re
 import time
 
 from elasticdl_tpu.client.local import free_port
 from elasticdl_tpu.common.config import JobConfig
-from elasticdl_tpu.master.main import Master
-from elasticdl_tpu.master.process_manager import ProcessManager
 from tests.conftest import heavy_on_cpu
-
-HERMETIC_ENV = {
-    "JAX_PLATFORMS": "cpu",
-    "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-    "EDL_LOG_LEVEL": "INFO",
-}
+from tests.jobs import all_logs, run_job
 
 AUC_TARGET = 0.70   # the learnable synthetic stream passes 0.75 quickly;
                     # 0.70 keeps the assert robust to the short run
@@ -49,15 +41,6 @@ def test_elastic_time_to_auc_survives_two_kills(tmp_path):
         checkpoint_steps=16,
         shuffle=False,
     )
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        extra_env=HERMETIC_ENV,
-        log_dir=str(tmp_path / "logs"),
-        job_finished_fn=master.dispatcher.finished,
-        checkpoint_request_fn=lambda: master.servicer.request_checkpoint(0),
-    )
     # Per-kill state machine: killed -> world_dead (the whole cohort has
     # been declared dead: alive_count()==0 — a SIGKILLed member takes the
     # leader down by cohort co-death, surfaced by heartbeat lapse) ->
@@ -68,7 +51,7 @@ def test_elastic_time_to_auc_survives_two_kills(tmp_path):
     kills = []          # [{"t_kill", "t_dead", "t_rec"}]
     kill_after = [1, 4]  # finished-task thresholds for kill #1 and #2
 
-    def observer():
+    def observer(master, manager):
         if kills and kills[-1]["t_rec"] is None:
             if kills[-1]["t_dead"] is None:
                 if master.membership.alive_count() == 0:
@@ -86,23 +69,11 @@ def test_elastic_time_to_auc_survives_two_kills(tmp_path):
                         {"t_kill": time.time(), "t_dead": None, "t_rec": None}
                     )
 
-    master.start()
-    manager.start_workers()
     t0 = time.time()
-    try:
-        deadline = time.time() + 900
-        while not master.dispatcher.finished() and time.time() < deadline:
-            master.membership.reap()
-            master.dispatcher.poke()
-            observer()
-            time.sleep(0.2)
-        counts = master.dispatcher.counts()
-        assert master.dispatcher.finished(), counts
-        wall_s = time.time() - t0
-        results = master.evaluation.latest_results()
-    finally:
-        master.shutdown()
-        manager.stop()
+    master, _, counts = run_job(cfg, tmp_path, observer=observer,
+                                timeout_s=900)
+    wall_s = time.time() - t0
+    results = master.evaluation.latest_results()
 
     # exactly-once accounting: every task retired exactly once, none lost,
     # none failed permanently — the "no record loss" half of the proof
@@ -115,10 +86,7 @@ def test_elastic_time_to_auc_survives_two_kills(tmp_path):
     # recovery overhead: kill -> re-formed leader registered, summed
     overhead_s = sum(k["t_rec"] - k["t_kill"] for k in kills)
 
-    log = "".join(
-        open(f, errors="replace").read()
-        for f in sorted(glob.glob(str(tmp_path / "logs" / "*.log")))
-    )
+    log = all_logs(tmp_path)
     # two re-formations: worlds v1 and v2 came up after v0
     for v in (0, 1, 2):
         assert f"distributed world v{v} up" in log, f"world v{v} missing"

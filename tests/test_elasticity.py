@@ -12,12 +12,7 @@ from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.master.main import Master
 from elasticdl_tpu.master.process_manager import ProcessManager
 from elasticdl_tpu.client.local import free_port
-
-HERMETIC_ENV = {
-    "JAX_PLATFORMS": "cpu",
-    "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-    "EDL_LOG_LEVEL": "INFO",
-}
+from tests.jobs import HERMETIC_ENV, all_logs, run_job
 
 
 def job_config(tmp_path, **overrides):
@@ -41,48 +36,21 @@ def job_config(tmp_path, **overrides):
     return JobConfig(**base)
 
 
-def run_job_with_kill(tmp_path, cfg, kill_after_tasks, signal_kill=True):
-    """Start the job, kill worker 0 once `kill_after_tasks` training tasks
-    finished, wait for completion. Returns (master, manager, ok)."""
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        extra_env=HERMETIC_ENV,
-        log_dir=str(tmp_path / "logs"),
-        job_finished_fn=master.dispatcher.finished,
-    )
-    master.start()
-    manager.start_workers()
-    killed = False
-    deadline = time.time() + 420
-    try:
-        while not master.dispatcher.finished() and time.time() < deadline:
-            master.membership.reap()
-            master.dispatcher.poke()
-            counts = master.dispatcher.counts()
-            if not killed and counts["finished_training"] >= kill_after_tasks:
-                assert manager.kill_worker(0, relaunch=True)
-                killed = True
-            time.sleep(0.2)
-        ok = master.dispatcher.finished()
-        return master, manager, ok, killed
-    finally:
-        master.shutdown(grace_s=2)
-        manager.stop()
+def kill_after(n_tasks, **kill_kwargs):
+    """A run_job `mid_job`: kill worker 0 (relaunching it) once `n_tasks`
+    training tasks have finished."""
+    def mid_job(master, manager):
+        if master.dispatcher.counts()["finished_training"] < n_tasks:
+            return False
+        assert manager.kill_worker(0, relaunch=True, **kill_kwargs)
+        return True
 
-
-def worker_log(tmp_path):
-    path = tmp_path / "logs" / "worker-0.log"
-    return path.read_text() if path.exists() else ""
+    return mid_job
 
 
 def test_kill_worker_mid_job_recovers(tmp_path):
     cfg = job_config(tmp_path)
-    master, manager, ok, killed = run_job_with_kill(tmp_path, cfg, kill_after_tasks=2)
-    assert killed, "worker was never killed — job finished too fast to inject"
-    assert ok, "job did not finish after worker kill:\n" + worker_log(tmp_path)[-4000:]
-    counts = master.dispatcher.counts()
+    *_, counts = run_job(cfg, tmp_path, mid_job=kill_after(2))
     # exactly-once accounting: 600 records / 50 per task = 12 tasks, no
     # double-completion, nothing lost
     assert counts["finished_training"] == 12, counts
@@ -90,7 +58,7 @@ def test_kill_worker_mid_job_recovers(tmp_path):
     assert counts["todo"] == 0 and counts["doing"] == 0, counts
     # the kill was detected and the lease recovered (or already reported):
     # the relaunched worker must have registered under the same id
-    log = worker_log(tmp_path)
+    log = all_logs(tmp_path)
     assert log.count("registered as worker 0") >= 2, log[-2000:]
 
 
@@ -100,12 +68,10 @@ def test_killed_worker_resumes_from_checkpoint(tmp_path):
         checkpoint_dir=str(tmp_path / "ckpt"),
         checkpoint_steps=2,
     )
-    master, manager, ok, killed = run_job_with_kill(tmp_path, cfg, kill_after_tasks=3)
-    assert killed and ok, worker_log(tmp_path)[-4000:]
-    counts = master.dispatcher.counts()
+    *_, counts = run_job(cfg, tmp_path, mid_job=kill_after(3))
     assert counts["finished_training"] == 12, counts
     assert counts["failed_permanently"] == 0, counts
-    log = worker_log(tmp_path)
+    log = all_logs(tmp_path)
     assert "resumed from checkpoint at step" in log, (
         "relaunched worker did not restore:\n" + log[-4000:]
     )
@@ -155,40 +121,12 @@ def test_sigterm_preemption_checkpoints_and_resumes(tmp_path):
         checkpoint_dir=str(tmp_path / "ckpt"),
         checkpoint_steps=0,          # only the preemption save writes
     )
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        extra_env=HERMETIC_ENV,
-        log_dir=str(tmp_path / "logs"),
-        job_finished_fn=master.dispatcher.finished,
-    )
-    master.start()
-    manager.start_workers()
-    preempted = False
-    deadline = time.time() + 420
-    try:
-        while not master.dispatcher.finished() and time.time() < deadline:
-            master.membership.reap()
-            master.dispatcher.poke()
-            if (
-                not preempted
-                and master.dispatcher.counts()["finished_training"] >= 2
-            ):
-                assert manager.kill_worker(0, relaunch=True, graceful=True)
-                preempted = True
-            time.sleep(0.2)
-        assert preempted, "job finished before preemption could be injected"
-        assert master.dispatcher.finished(), worker_log(tmp_path)[-4000:]
-        counts = master.dispatcher.counts()
-        assert counts["finished_training"] == 12, counts
-        assert counts["failed_permanently"] == 0, counts
-        log = worker_log(tmp_path)
-        assert "preemption signal received" in log, log[-2000:]
-        assert "resumed from checkpoint at step" in log, log[-4000:]
-    finally:
-        master.shutdown(grace_s=2)
-        manager.stop()
+    *_, counts = run_job(cfg, tmp_path, mid_job=kill_after(2, graceful=True))
+    assert counts["finished_training"] == 12, counts
+    assert counts["failed_permanently"] == 0, counts
+    log = all_logs(tmp_path)
+    assert "preemption signal received" in log, log[-2000:]
+    assert "resumed from checkpoint at step" in log, log[-4000:]
 
 
 def test_worker_exits_when_master_vanishes(tmp_path):
@@ -249,35 +187,22 @@ def test_relaunch_reuses_compilation_cache(tmp_path):
         compilation_cache_dir=str(cache_dir),
         compilation_cache_min_compile_s=0.0,   # test-sized programs cache
     )
-    master = Master(cfg)
-    manager = ProcessManager(
-        cfg,
-        membership=master.membership,
-        # an inherited JAX_COMPILATION_CACHE_DIR would win over the flag
-        # under test (common/runtime.py); empty reads as unset
-        extra_env={**HERMETIC_ENV, "JAX_COMPILATION_CACHE_DIR": ""},
-        log_dir=str(tmp_path / "logs"),
-        job_finished_fn=master.dispatcher.finished,
-    )
-    master.start()
-    manager.start_workers()
-    entries_at_kill = None
-    deadline = time.time() + 420
-    try:
-        while not master.dispatcher.finished() and time.time() < deadline:
-            master.membership.reap()
-            master.dispatcher.poke()
-            counts = master.dispatcher.counts()
-            if entries_at_kill is None and counts["finished_training"] >= 2:
-                entries_at_kill = set(os.listdir(cache_dir))
-                assert manager.kill_worker(0, relaunch=True)
-            time.sleep(0.2)
-        assert master.dispatcher.finished(), worker_log(tmp_path)[-3000:]
-        assert entries_at_kill, "cache empty at kill: nothing compiled?"
-    finally:
-        master.shutdown(grace_s=2)
-        manager.stop()
-    log = worker_log(tmp_path)
+    at_kill = {}
+
+    def snapshot_then_kill(master, manager):
+        if master.dispatcher.counts()["finished_training"] < 2:
+            return False
+        at_kill["entries"] = set(os.listdir(cache_dir))
+        assert manager.kill_worker(0, relaunch=True)
+        return True
+
+    # an inherited JAX_COMPILATION_CACHE_DIR would win over the flag under
+    # test (common/runtime.py); empty reads as unset
+    run_job(cfg, tmp_path, mid_job=snapshot_then_kill,
+            extra_env={"JAX_COMPILATION_CACHE_DIR": ""})
+    entries_at_kill = at_kill.get("entries")
+    assert entries_at_kill, "cache empty at kill: nothing compiled?"
+    log = all_logs(tmp_path)
     assert "persistent XLA compilation cache" in log
     final = set(os.listdir(cache_dir))
     # The relaunched generation legitimately compiles utility programs the

@@ -7,6 +7,7 @@ proves pod death drives task recovery through the actual callback chain, with
 no heartbeat timeout involved.
 """
 
+import os
 import queue
 import threading
 import time
@@ -323,10 +324,44 @@ def test_cohort_process_id_from_hostname(monkeypatch):
     monkeypatch.setattr(socket, "gethostname", lambda: "kj-worker-2")
     ctx = context_from_env(cfg)
     assert ctx is not None and ctx.process_id == 2 and ctx.num_processes == 4
-    monkeypatch.delenv("EDL_PROCESS_ID", raising=False)
+    assert "EDL_PROCESS_ID" not in os.environ
     monkeypatch.setattr(socket, "gethostname", lambda: "nodigit")
     with pytest.raises(RuntimeError, match="no trailing ordinal"):
         context_from_env(cfg)
+    assert "EDL_PROCESS_ID" not in os.environ
+
+
+@pytest.mark.parametrize(
+    "env,num_processes,expected",
+    [
+        # StatefulSet pod: the ordinal comes from the hostname
+        ({"EDL_PROCESS_ID_FROM_HOSTNAME": "1"}, 4, (3, 4)),
+        # a cohort resized to one process is still a cohort
+        ({"EDL_PROCESS_ID": "1", "EDL_NUM_PROCESSES": "1"}, 4, (1, 1)),
+        # a plain one-process worker
+        ({}, 1, None),
+    ],
+    ids=["from_hostname", "resized_to_one", "plain_worker"],
+)
+def test_context_from_env_reads_and_never_writes(
+    monkeypatch, env, num_processes, expected
+):
+    import socket
+
+    from elasticdl_tpu.parallel.elastic import context_from_env
+
+    for key in ("EDL_PROCESS_ID", "EDL_PROCESS_ID_FROM_HOSTNAME",
+                "EDL_NUM_PROCESSES", "EDL_COORDINATOR_ADDR",
+                "EDL_WORLD_VERSION"):
+        monkeypatch.delenv(key, raising=False)
+    for key, value in env.items():
+        monkeypatch.setenv(key, value)
+    monkeypatch.setattr(socket, "gethostname", lambda: "kj-worker-3")
+    before = dict(os.environ)
+    ctx = context_from_env(make_cfg(num_processes=num_processes))
+    assert dict(os.environ) == before
+    got = None if ctx is None else (ctx.process_id, ctx.num_processes)
+    assert got == expected
 
 
 def test_statefulset_cohort_without_tpu_type_and_single_host_guard():

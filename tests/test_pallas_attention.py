@@ -169,12 +169,17 @@ def test_interpret_active_survives_private_api_loss(monkeypatch, caplog):
     monkeypatch.delenv(pa._INTERPRET_ENV, raising=False)
 
     # probe broken -> False, but LOUD (one warning). The package logger
-    # does not propagate to root (log_utils installs its own handler), so
-    # route it to caplog's handler for this test.
-    monkeypatch.setattr(logging.getLogger("elasticdl_tpu"), "propagate", True)
-    with caplog.at_level(logging.WARNING, "elasticdl_tpu.ops.pallas_attention"):
-        assert pa._interpret_active() is False
-        assert pa._interpret_active() is False  # warned once, not twice
+    # does not propagate to root (default_logger sets that whenever it is
+    # first called, which may be inside this very test), so caplog's
+    # handler goes on the module's own logger.
+    module_logger = logging.getLogger(pa.__name__)
+    module_logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.WARNING, pa.__name__):
+            assert pa._interpret_active() is False
+            assert pa._interpret_active() is False  # warned once, not twice
+    finally:
+        module_logger.removeHandler(caplog.handler)
     assert sum(
         "interpret-mode probe" in r.getMessage() for r in caplog.records
     ) == 1
