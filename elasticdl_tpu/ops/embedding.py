@@ -66,10 +66,14 @@ def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
 
     - `pallas` (default): the Mosaic placement kernel
       (ops/pallas_scatter.py) — sort once, then one-hot matmul the sorted
-      windows onto 2048-row output blocks on the MXU (13-15 ms vs 26-30
-      for the XLA paths on the DeepFM shape; ~4e-6 rel accuracy via a
-      two-term bf16 split). Runs on real TPU or under interpret mode;
-      everywhere else (and below its size gate) it falls back to:
+      windows onto 2048-row output blocks on the MXU (~4e-6 rel accuracy
+      via a two-term bf16 split). Its time follows the table's rows x
+      the window's columns, not the ids: 6.8 ms a step for 213k ids into
+      33.8M rows under Zipf ids, where every step takes the dedupe
+      branch, of a 44.5 ms step (PERF.md §5, PR 24; it was 24.7 ms with
+      512-column windows and one MXU pass per term, ledger PR 23). Runs
+      on real TPU or under interpret mode; everywhere else (and below
+      its size gate) it falls back to:
     - `tiled`: argsort ids, materialize the sorted gradient rows
       once (contiguous), then lax.scan over vocab tiles of <= 256k rows:
       each tile dynamic-slices a fixed window of the sorted stream
@@ -235,20 +239,7 @@ def _pallas_table_grad(cf, sf, num_rows):
     bs = pallas_scatter.block_rows()
     nb = -(-num_rows // bs)
     vpad = nb * bs
-    c = pallas_scatter.CHUNK
-    # window statistics over the REAL row count: ceil-padding the block
-    # count would undersize w for tables barely past the gate and
-    # silently land every step on the flat branch
-    per_block = _window_slack() * n * bs / num_rows
-    w = int(min(-(-n // c) * c, max(c, -(-int(per_block) // c) * c)))
-    # +128: window starts are aligned DOWN to 128 for Mosaic's DMA-offset
-    # tiling proof, so a window may begin up to 127 rows before its
-    # block's first id — the leading slop belongs to the previous block
-    # and the one-hot never matches it. Then round UP to a whole number
-    # of kernel chunks: the kernel iterates w // CHUNK full chunks, so a
-    # ragged tail would be silently skipped — dropped gradient rows that
-    # only full-scale on-TPU numerics catch (round-5 pt2, again).
-    w = -(-(w + 128) // c) * c
+    w = pallas_scatter.window_cols(n, num_rows, bs, _window_slack())
     sf_pad = jnp.concatenate(
         [sf, jnp.full((w,), jnp.iinfo(jnp.int32).max, sf.dtype)])
     # transpose FIRST, pad on lanes: the (N, D) -> (D, N) relayout of the
@@ -265,13 +256,15 @@ def _pallas_table_grad(cf, sf, num_rows):
     edges = jnp.searchsorted(
         sf, jnp.arange(0, vpad + 1, bs, dtype=jnp.int32)
     ).astype(jnp.int32)
-    starts = (edges[:-1] // 128) * 128
+    # what a window holds of its own block: w less the up-to-127 columns
+    # its aligned start may lie before the block's first id
+    room = w - pallas_scatter.LANES
 
-    def pallas_branch(cf_t, sf_pad, starts):
+    def pallas_branch(cf_t, sf_pad, edges):
         from elasticdl_tpu.ops.pallas_attention import kernel_interpret
 
         out_t = pallas_scatter.place_sorted_grads(
-            cf_t, sf_pad[None, :], starts,
+            cf_t, sf_pad[None, :], edges[:-1],
             num_rows=vpad, block_rows=bs, w=w, d_out=d,
             split=os.environ.get(
                 "EDL_EMB_PALLAS_PRECISION", "split") != "bf16",
@@ -282,22 +275,23 @@ def _pallas_table_grad(cf, sf, num_rows):
         # one bandwidth-class transpose restores the param layout
         return out_t[:, :num_rows].T
 
-    def flat(cf_t, sf_pad, starts):
-        del starts
+    def flat(cf_t, sf_pad, edges):
+        del edges
         return jnp.zeros((num_rows, d), jnp.float32).at[sf_pad[:n]].add(
             cf_t[:d, :n].T, mode="drop", indices_are_sorted=True)
 
-    def dedupe_then_place(cf_t, sf_pad, starts):
+    def dedupe_then_place(cf_t, sf_pad, edges):
         """Skew middle path (executed only when a window overflows): a
         hot id concentrates its duplicates in ONE tile, but duplicates
         are ADJACENT in the sorted stream — compact them with fast-zone
-        segment ops (n-row outputs, ~3 ms for the DeepFM shape), then
-        place the per-unique sums with the same kernel. Window
-        populations become DISTINCT-id counts, which hashing spreads
-        near-uniformly, so real-world head skew stays on the MXU path
-        (~9 ms) instead of the 22-30 ms flat scatter. A final flat
-        fallback remains for adversarially CLUSTERED distinct ids."""
-        del starts
+        segment ops (n-row outputs), then place the per-unique sums with
+        the same kernel. Window populations become DISTINCT-id counts,
+        which hashing spreads near-uniformly, so real-world head skew
+        stays on the MXU path: under the benchmark's Zipf ids EVERY step
+        comes this way, sort and compaction ~9.7 ms and the kernel 6.8 ms
+        of DeepFM's step (PERF.md §5, PR 24). A final flat fallback
+        remains for adversarially CLUSTERED distinct ids."""
+        del edges
         imax = jnp.iinfo(jnp.int32).max
         sums, uids = _compact_sorted_duplicates(
             cf_t[:d, :n].T, sf_pad[:n])
@@ -315,18 +309,16 @@ def _pallas_table_grad(cf, sf, num_rows):
         edges2 = jnp.searchsorted(
             uids, jnp.arange(0, vpad + 1, bs, dtype=jnp.int32)
         ).astype(jnp.int32)
-        starts2 = (edges2[:-1] // 128) * 128
-        max_span2 = jnp.max(edges2[1:] - starts2)
+        max_pop2 = jnp.max(edges2[1:] - edges2[:-1])
         return jax.lax.cond(
-            max_span2 <= w, pallas_branch, flat, cf2_t, sf2, starts2)
+            max_pop2 <= room, pallas_branch, flat, cf2_t, sf2, edges2)
 
-    # aligned-start coverage: window b must reach this block's last id.
     # Window statistics assume near-uniform ids (hashed vocab); skewed
     # data routes through the dedupe middle path above.
-    max_span = jnp.max(edges[1:] - starts)
+    max_pop = jnp.max(edges[1:] - edges[:-1])
     return jax.lax.cond(
-        max_span <= w, pallas_branch, dedupe_then_place,
-        cf_t, sf_pad, starts)
+        max_pop <= room, pallas_branch, dedupe_then_place,
+        cf_t, sf_pad, edges)
 
 
 def _gather_rows_bwd(res, ct):

@@ -1,26 +1,37 @@
 """Pallas TPU kernel for the embedding-gradient placement — the MXU
 replacement for XLA's row-serial scatter-add.
 
-Context (BASELINE.md round-5 pt 2): the embedding backward must place ~213k
-sorted gradient rows into a 2.6M-row dense table. Every XLA formulation is
-bound by per-ROW transaction costs — scatter-add ~14 ns/element in the
-fast (<=256k-row output) zone, ~105 ns beyond it, and even dynamic-slice/
-dynamic-update-slice window plumbing costs ~12-18 ns/row — so the best
-XLA schedule (`EDL_EMB_SCATTER=tiled`, ops/embedding.py) still spends
-~16 ms/step. This kernel reformulates placement as BLOCKED ONE-HOT MATMUL:
+The embedding backward must place a batch's sorted gradient rows (213k a
+step for DeepFM at batch 8192) into a dense table of millions of rows.
+Every XLA formulation is bound by per-ROW transaction costs (tens of ns a
+row: scatter-add, segment-sum, and the dynamic-slice plumbing of the
+`EDL_EMB_SCATTER=tiled` schedule alike, ops/embedding.py). This kernel
+reformulates placement as BLOCKED ONE-HOT MATMUL:
 
   grid over output row-blocks (bs rows); block b DMAs the contiguous
   window of the sorted stream that searchsorted assigned to it (scalar-
-  prefetched starts), then accumulates
+  prefetched first columns), then accumulates
       out_block += one_hot(ids - b*bs) @ grads        # (bs,C) @ (C,D)
   chunk by chunk on the MXU. Sorted-stream windows are CONTIGUOUS, so the
-  DMAs run at bandwidth, and the "scatter" itself becomes dense compute
-  (~86 GFLOP for the DeepFM shape — ~0.5 ms of MXU time) instead of 280k
-  row transactions.
+  "scatter" becomes a sequential read and dense compute.
+
+What it costs (TPU v5e, `PERF.md` §6; the round-5 figures this replaces
+were a 2.6M-row table under uniform ids, thirteen times smaller than the
+benchmark's): the kernel's time follows the one-hot, blocks x bs x the
+columns a block builds it for, not the ids. With 512-column windows sent
+through the MXU once per bf16 term it was 1.43-1.57 ps an element on all
+three tables of the benchmark — 24.7 ms a step on 33.8M rows, a quarter
+of the MXU's peak because only D of its rows carry values (ledger, PR 23).
+So the window is sized in whole 128s from what the code sees (n, rows,
+bs: `window_cols`), the columns before a block's first id are rotated out
+of it, and both terms share one pass of the one-hot: 128 columns and
+6.8 ms on that table — 0.41 us a block, which halving the one-hot once
+more (256 -> 128 columns) moved by only 13%: what is left is paid per
+block, not per element (PR 24).
 
 Window coverage follows the tiled path's contract: the caller guarantees
 (via the same lax.cond max-population guard) that no block's population
-exceeds the static window W; ids beyond the caller's row range (manual-
+exceeds the window's room; ids beyond the caller's row range (manual-
 shard sentinels, padding) simply never match the one-hot and drop out.
 
 Reference parity note: the reference's Go PS applied sparse gradients
@@ -31,6 +42,7 @@ component's hot loop, rebuilt as dense MXU math.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -41,19 +53,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.ops.pallas_attention import _interpret_active, _sds
 
-# Output rows per grid step and sorted-stream rows per MXU chunk. bs*C
-# bf16 one-hot (4 MB at 8192x256) is the VMEM high-water mark; C=256 keeps
-# the contraction MXU-friendly (2x128 lanes). Total kernel work (compares
-# AND matmul FLOPs) scales with vocab * window, and the window shrinks
-# with the block, so smaller blocks win until grid/DMA overhead bites —
-# block size is env-tunable for the bench sweep. Chip sweep (round 5,
-# DeepFM shape, TRANSPOSED output): the standalone D=16/sgd update step
-# measured 2048/4096/8192 -> 12.9/11.6/15.4 ms, but the FULL DeepFM
-# step (D=17, adam, fwd gather in the same program) measured 589k
-# samples/s at 2048 vs 560k at 4096 — the end-to-end metric wins, so
-# 2048 stays the default.
+# Output rows per grid step, and sorted-stream columns per MXU pass. The
+# one-hot's size is rows x window and the window shrinks with the block
+# until it reaches 128 columns, so smaller blocks win until per-block
+# costs bite; the block is env-tunable for sweeps. 2048 won round 5's
+# end-to-end sweep against 4096 and 8192 on a 2.6M-row table and has not
+# been swept on the benchmark's tables (PERF.md §7). A window is a whole
+# number of LANES: 128 is what Mosaic's DMA-offset proof and the MXU's
+# contraction depth both want. The loop takes CHUNK columns at a time
+# (4% faster than 128 at a time, kernel alone, PR 24) and a LANES-wide tail
+# where an odd number of them is left; bs*CHUNK bf16 one-hot (1 MB at
+# 2048x256) is the VMEM high-water mark.
 DEFAULT_BLOCK_ROWS = 2048
-CHUNK = 256
+LANES = 128
+CHUNK = 2 * LANES
 
 
 def block_rows() -> int:
@@ -61,7 +74,25 @@ def block_rows() -> int:
         "EDL_EMB_PALLAS_BS", str(DEFAULT_BLOCK_ROWS)))
 
 
-def _kernel(starts_ref, sf_ref, cf_ref, out_ref, ids_vmem, vec_vmem,
+def window_cols(n: int, num_rows: int, block_rows: int, slack: float) -> int:
+    """Static window width, in sorted-stream columns, for `n` ids placed
+    into `num_rows` rows by blocks of `block_rows`: room for `slack` x the
+    mean block population (over the REAL row count: ceil-padding the block
+    count would undersize the window for tables barely past the gate and
+    land every step on the fallback branch), never more than the stream,
+    in whole LANES — plus one LANES that the read needs and the placement
+    does not: a window is read from its block's first id aligned DOWN to
+    128, so it may begin up to 127 columns early. The kernel's time
+    follows the window (module docstring), so it is derived from what the
+    shapes say and rounded no further."""
+    def lanes_up(x):
+        return -(-x // LANES) * LANES
+
+    per_block = math.ceil(slack * n * block_rows / num_rows)
+    return min(lanes_up(n), max(LANES, lanes_up(per_block))) + LANES
+
+
+def _kernel(firsts_ref, sf_ref, cf_ref, out_ref, ids_vmem, vec_vmem,
             sem_ids, sem_vec, *, bs, w, d, d_out, split, group):
     """`group` output blocks per grid step (default 1 — see the sweep
     note in place_sorted_grads). Sub-block indices are PYTHON ints
@@ -71,12 +102,14 @@ def _kernel(starts_ref, sf_ref, cf_ref, out_ref, ids_vmem, vec_vmem,
     b = pl.program_id(0)
 
     def copies(g):
-        # the caller aligns starts to 128: Mosaic must PROVE dynamic DMA
-        # offsets land on tile boundaries, and both streams put the
-        # window dimension on LANES — ids as a (1, N) row, gradients
-        # TRANSPOSED to (D, N) (slicing the untransposed (N, D) would
-        # lane-slice a 128-padded memref, which Mosaic rejects)
-        start = pl.multiple_of(starts_ref[b * group + g], 128)
+        # a window starts at its block's first id aligned DOWN to 128:
+        # Mosaic must PROVE dynamic DMA offsets land on tile boundaries,
+        # and both streams put the window dimension on LANES — ids as a
+        # (1, N) row, gradients TRANSPOSED to (D, N) (slicing the
+        # untransposed (N, D) would lane-slice a 128-padded memref, which
+        # Mosaic rejects)
+        start = pl.multiple_of(
+            firsts_ref[b * group + g] // LANES * LANES, LANES)
         return (
             pltpu.make_async_copy(
                 sf_ref.at[:, pl.ds(start, w)], ids_vmem.at[g],
@@ -100,37 +133,46 @@ def _kernel(starts_ref, sf_ref, cf_ref, out_ref, ids_vmem, vec_vmem,
         # was most of the kernel's cost (write-only floor 7.5 ms) and
         # an OOM at group=8. dot_general(vec, onehot) contracting the
         # chunk gives (D, bs) natively, no in-register transpose.
-        acc = jnp.zeros((d, bs), jnp.float32)
-        row_ids = jax.lax.broadcasted_iota(
-            jnp.int32, (bs, CHUNK), 0) + base
-        for c in range(w // CHUNK):
-            ids_c = ids_vmem[g, :, c * CHUNK:(c + 1) * CHUNK]    # (1, C)
-            vec_c = vec_vmem[g, :, c * CHUNK:(c + 1) * CHUNK]    # (D, C)
-            onehot = (row_ids == ids_c).astype(jnp.bfloat16)     # 0/1
-            dims = (((1,), (1,)), ((), ()))
+        #
+        # The up-to-127 columns before the block's first id belong to the
+        # block before: rotate them to the window's far end, so that the
+        # one-hot is built for w - LANES columns and not for w.
+        shift = (w - firsts_ref[b * group + g] % LANES) % w
+        ids = pltpu.roll(
+            jnp.broadcast_to(ids_vmem[g], (8, w)), shift, 1)[:1] - base
+        vec = pltpu.roll(vec_vmem[g], shift, 1)
+        # One pass of the one-hot per chunk: its bs x C elements are what
+        # the MXU's time follows (only d of its rows carry values), so the
+        # two bf16 terms of the split ride through it STACKED, (2d, C),
+        # into one (2d, bs) float32 accumulator whose halves are added
+        # before the write — the same products and float32 sums as two
+        # passes, at one pass's price.
+        acc = None
+        for c0 in range(0, w - LANES, CHUNK):
+            cw = min(CHUNK, w - LANES - c0)   # a LANES tail when odd
+            vec_c = vec[:, c0:c0 + cw]                           # (D, cw)
+            onehot = (jax.lax.broadcasted_iota(jnp.int32, (bs, cw), 0)
+                      == ids[:, c0:c0 + cw]).astype(jnp.bfloat16)  # 0/1
+            hi = vec_c.astype(jnp.bfloat16)
             if split:
                 # Two-term bf16 split of the f32 gradient values: the
                 # MXU runs bf16, and a single cast rounds the
                 # accumulated gradients to ~8 mantissa bits (0.4% rel
-                # err measured); hi+lo recovers ~16 bits (~4e-6 rel)
-                # for a second matmul pass. EDL_EMB_PALLAS_PRECISION=
-                # bf16 drops the second pass for models already
-                # training in bf16 end to end.
-                hi = vec_c.astype(jnp.bfloat16)
-                lo = (vec_c - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-                acc = acc + jax.lax.dot_general(
-                    hi, onehot, dimension_numbers=dims,
-                    preferred_element_type=jnp.float32,
-                ) + jax.lax.dot_general(
-                    lo, onehot, dimension_numbers=dims,
-                    preferred_element_type=jnp.float32,
-                )
+                # err measured); hi+lo recovers ~16 bits (~4e-6 rel).
+                # EDL_EMB_PALLAS_PRECISION=bf16 drops lo for models
+                # already training in bf16 end to end. Stacked in f32,
+                # where d (8-aligned) is whole sublane tiles, then cast.
+                hi_f = hi.astype(jnp.float32)
+                terms = jnp.concatenate(
+                    [hi_f, vec_c - hi_f], axis=0).astype(jnp.bfloat16)
             else:
-                acc = acc + jax.lax.dot_general(
-                    vec_c.astype(jnp.bfloat16), onehot,
-                    dimension_numbers=dims,
-                    preferred_element_type=jnp.float32,
-                )
+                terms = hi
+            part = jax.lax.dot_general(
+                terms, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc = part if acc is None else acc + part
+        if split:
+            acc = acc[:d] + acc[d:]
         # d is the 8-aligned padded depth the DMA needs; the real
         # embedding width d_out is restored in-register before the write
         out_ref[:, g * bs:(g + 1) * bs] = acc[:d_out, :]
@@ -149,7 +191,7 @@ def group_blocks() -> int:
     static_argnames=(
         "num_rows", "block_rows", "w", "d_out", "split", "group",
         "interpret"))
-def place_sorted_grads(cf, sf, starts, *, num_rows, block_rows, w,
+def place_sorted_grads(cf, sf, firsts, *, num_rows, block_rows, w,
                        d_out=None, split=True, group=1, interpret=False):
     """Dense (D, num_rows) TRANSPOSED gradient from a SORTED stream
     (the row dimension rides the 128-lane axis so output writes aren't
@@ -157,32 +199,36 @@ def place_sorted_grads(cf, sf, starts, *, num_rows, block_rows, w,
 
     cf: (D, N_pad) float32 gradient rows TRANSPOSED into sorted-id order
     along lanes, padded by at least `w` columns; sf: (1, N_pad) the
-    matching sorted int32 ids, padded with int32max; starts:
-    (num_rows/block_rows,) int32 — each block's 128-ALIGNED window start.
-    Ids outside [block*bs, block*bs + bs) contribute nothing (the one-hot
-    never matches), which also silently drops sentinel/padding ids and
-    the aligned-start leading slop. The caller must guarantee every
-    block's window span fits in `w` (lax.cond guard in ops.embedding)
-    and that num_rows % block_rows == 0.
+    matching sorted int32 ids, padded with int32max; firsts:
+    (num_rows/block_rows,) int32 — the column of each block's first id
+    (searchsorted of the block's first row). A block reads the `w`
+    columns from `firsts` aligned down to 128 and places the `w - 128`
+    that follow its first id. Ids outside [block*bs, block*bs + bs)
+    contribute nothing (the one-hot never matches), which also silently
+    drops sentinel/padding ids and the columns of later blocks. The
+    caller must guarantee that no block holds more than `w - 128` ids
+    (lax.cond guard in ops.embedding) and that num_rows % block_rows == 0.
     """
     d, n_pad = cf.shape
     if d % 8:
         raise ValueError(
             f"cf depth {d} must be 8-aligned (Mosaic sublane tiling); pad "
             f"with zero rows and pass d_out")
-    if w % CHUNK:
-        # the kernel iterates w // CHUNK WHOLE chunks — a ragged tail
+    if w % LANES:
+        # the kernel walks the window in whole LANES — a ragged tail
         # would be silently skipped (dropped gradient rows, caught only
         # by full-scale on-chip numerics in round 5); fail loudly instead
-        raise ValueError(f"window {w} must be a multiple of CHUNK={CHUNK}")
+        raise ValueError(f"window {w} must be a multiple of {LANES}")
     d_out = d if d_out is None else d_out
     bs = block_rows
     nb = num_rows // bs
-    # Chip sweep (round 5, DeepFM shape, transposed out): group 1/2/4
-    # all ~8.3 ms, group 8 EXPLODES to ~60 ms (VMEM-pressure spill
-    # signature). The write-only "7.5 ms grid floor" that motivated
-    # grouping turned out to be the lane-padded (bs, 17) write tax the
-    # transposed output already removed — per-step overhead is small.
+    # Chip sweep (round 5: 2.6M rows, uniform ids, 512-column windows,
+    # transposed out): group 1/2/4 all ~8.3 ms, group 8 EXPLODES to
+    # ~60 ms (VMEM-pressure spill signature). The write-only "7.5 ms
+    # grid floor" that motivated grouping turned out to be the lane-
+    # padded (bs, 17) write tax the transposed output already removed.
+    # On 33.8M rows inside the DeepFM step, group 4 read 6.66 ms
+    # against 6.80 (PR 24).
     # `group` is a STATIC arg (callers read group_blocks()) so env
     # sweeps reach the jit cache key; legalize to a divisor of nb.
     while nb % group:
@@ -195,7 +241,7 @@ def place_sorted_grads(cf, sf, starts, *, num_rows, block_rows, w,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (d_out, bs * group), lambda b, starts: (0, b)),
+            (d_out, bs * group), lambda b, firsts: (0, b)),
         scratch_shapes=[
             pltpu.VMEM((group, 1, w), jnp.int32),
             pltpu.VMEM((group, d, w), jnp.float32),
@@ -212,7 +258,7 @@ def place_sorted_grads(cf, sf, starts, *, num_rows, block_rows, w,
         # declare the mesh axes it varies over, like the cotangents do
         out_shape=_sds((d_out, num_rows), jnp.float32, cf),
         interpret=interpret,
-    )(starts, sf, cf)
+    )(firsts, sf, cf)
 
 
 def runnable() -> bool:

@@ -145,32 +145,70 @@ def test_tiled_backward_on_manual_shard_path(monkeypatch, mesh8):
     np.testing.assert_allclose(np.asarray(g), expected, rtol=1e-5, atol=1e-6)
 
 
+def _block_firsts_and_pops(ids_np, V, bs):
+    """What `_pallas_table_grad` sees of a stream: per block, the column
+    of its first id in sorted order and how many ids it holds."""
+    sf = np.sort(ids_np[ids_np >= 0].reshape(-1))
+    edges = np.searchsorted(sf, np.arange(0, V + 1, bs))
+    return edges[:-1], np.diff(edges)
+
+
+def _pallas_case_ids(ids_kind, r):
+    """(V, ids) for test_pallas_backward_matches_reference, block 256."""
+    bs = 256
+    if ids_kind == "offset127_w256":
+        # w = 256 (the large tables' window): block 0 holds 127 ids, so
+        # block 1's ids start 127 columns into its window, straddle the
+        # 128 boundary and fill all the room a window has
+        V, pops = 16384, [127, 128] + [62] * 59 + [61] * 3
+    elif ids_kind == "odd_lanes_window":
+        # w = 768, room for 640 = two 256-wide chunks and a 128-wide tail:
+        # block 6's ids start 2 columns into its window and fill the room
+        V, pops = 4096, [427] * 6 + [640] + [427] * 4 + [426] * 5
+    else:
+        V = 2048
+        ids_np = r.randint(0, V, (64, 81)).astype(np.int32)
+        if ids_kind == "skewed":
+            ids_np[:, :60] = 7      # hot id -> window overflow -> fallback
+        elif ids_kind == "with_padding":
+            ids_np[:, 60:] = -1
+        return V, ids_np
+    ids_np = np.concatenate([
+        b * bs + np.arange(p) % bs for b, p in enumerate(pops)])
+    return V, r.permutation(ids_np).astype(np.int32).reshape(64, -1)
+
+
 @pytest.mark.parametrize("d", [16, 17])
 @pytest.mark.parametrize(
-    "ids_kind", ["uniform", "skewed", "with_padding"])
+    "ids_kind", ["uniform", "skewed", "with_padding", "offset127_w256",
+                 "odd_lanes_window"])
 def test_pallas_backward_matches_reference(monkeypatch, d, ids_kind):
     """EDL_EMB_SCATTER=pallas (round-5 default on TPU): the MXU one-hot
     placement kernel must match a host reference across (a) uniform ids
-    (the kernel path), (b) extreme skew (the lax.cond flat fallback), and
-    (c) negative padding ids — at D=16 (aligned) AND D=17 (the deepfm
-    merged-linear-column depth, which exercises the sublane padding and
-    the in-kernel d_out slice). Runs the REAL Mosaic kernel in interpret
-    mode on CPU; tolerance reflects the two-term bf16 split (~4e-6 rel).
-    Small blocks force several grid steps and a ragged window size (the
-    w % CHUNK truncation bug class, caught on-TPU in round 5)."""
+    (the kernel path), (b) extreme skew (the lax.cond flat fallback),
+    (c) negative padding ids, and (d, e) streams built to sit on the
+    window's edges while still passing the guard — at D=16 (aligned) AND
+    D=17 (the deepfm merged-linear-column depth, which exercises the
+    sublane padding and the in-kernel d_out slice). Runs the REAL Mosaic
+    kernel in interpret mode on CPU; tolerance reflects the two-term bf16
+    split (~4e-6 rel). Small blocks force several grid steps and windows
+    of an odd number of 128s (the ragged-tail truncation bug class, caught
+    on-TPU in round 5)."""
+    from elasticdl_tpu.ops import pallas_scatter as ps
     from elasticdl_tpu.ops.pallas_attention import interpret_mode
 
     monkeypatch.setenv("EDL_EMB_SCATTER", "pallas")
     monkeypatch.setenv("EDL_EMB_PALLAS_BS", "256")
-    V = 2048
     r = np.random.RandomState(31)
+    V, ids_np = _pallas_case_ids(ids_kind, r)
+    w = ps.window_cols(ids_np.size, V, 256, 1.3)
+    firsts, pops = _block_firsts_and_pops(ids_np, V, 256)
+    if ids_kind == "offset127_w256":
+        assert (w, firsts[1] % 128, pops[1], pops.max()) == (256, 127, 128, 128)
+    elif ids_kind == "odd_lanes_window":
+        assert (w, firsts[6] % 128, pops[6], pops.max()) == (768, 2, 640, 640)
     t = jnp.asarray(r.randn(V, d) * 0.1, jnp.float32)
-    ids_np = r.randint(0, V, (64, 81)).astype(np.int32)
-    if ids_kind == "skewed":
-        ids_np[:, :60] = 7          # hot id -> window overflow -> fallback
-    elif ids_kind == "with_padding":
-        ids_np[:, 60:] = -1
-    w_np = r.randn(64, 81, d).astype(np.float32)
+    w_np = r.randn(*ids_np.shape, d).astype(np.float32)
 
     with interpret_mode():
         g = jax.jit(jax.grad(
@@ -185,6 +223,64 @@ def test_pallas_backward_matches_reference(monkeypatch, d, ids_kind):
     scale = np.abs(expected).max()
     np.testing.assert_allclose(
         np.asarray(g) / scale, expected / scale, atol=2e-5)
+
+
+@pytest.mark.parametrize("n,rows,want", [
+    (8192 * 26, 1_300_000 * 26, 256),       # deepfm-criteo.resident and .job
+    (32768 * 26, 3_611_000 * 26 // 4, 256),  # deepfm-criteo1tb, a shard of 4
+    (55296 * 26, 2_605_056, 1664),          # xdeepfm-criteo.resident
+    (4096, 2048, 4096 + 128),               # never more than the stream
+    (100, 1 << 30, 256),                    # never less than 128 + the slop
+])
+def test_pallas_window_cols(n, rows, want):
+    """The window is derived from what the code sees — ids, rows, block —
+    in whole 128s (the DMA's and the MXU's granularity, not the loop's
+    256-wide chunk): the benchmark's three tables get 256 / 256 / 1664
+    columns, and any window covers `slack` x the mean block population
+    after the up-to-127-column slop of its aligned start."""
+    from elasticdl_tpu.ops import pallas_scatter as ps
+
+    w = ps.window_cols(n, rows, 2048, 1.3)
+    assert w == want
+    assert w % 128 == 0
+    assert w >= min(n, 1.3 * n * 2048 / rows) + 127
+
+
+@pytest.mark.parametrize("w,pops", [
+    (256, [127, 128]),          # first id 127 columns in; one 128-wide pass
+    (512, [300, 384]),          # first id 44 columns in; a chunk and a tail
+    (1664, [1500, 1536]),       # 13 x 128: first id 92 columns in; six chunks
+])
+def test_place_sorted_grads_window_edges(w, pops):
+    """The kernel alone, at the benchmark's two window widths and one
+    between: a block that fills its window's room, from a first id that is not on a 128
+    boundary, places every row, and the columns before its first id (the
+    block before) or past its last (the block after) place none."""
+    from elasticdl_tpu.ops import pallas_scatter as ps
+
+    bs, d = 256, 8
+    r = np.random.RandomState(w)
+    sf = np.concatenate(
+        [b * bs + np.sort(r.randint(0, bs, p)) for b, p in enumerate(pops)]
+    ).astype(np.int32)
+    firsts = np.searchsorted(sf, np.arange(0, len(pops) * bs, bs))
+    assert firsts[1] % 128 and max(pops) == w - 128
+    cf = r.randn(sf.size, d).astype(np.float32)
+    args = (
+        jnp.asarray(np.concatenate([cf.T, np.zeros((d, w), np.float32)], 1)),
+        jnp.asarray(np.concatenate(
+            [sf, np.full(w, np.iinfo(np.int32).max, np.int32)])[None, :]),
+        jnp.asarray(firsts, jnp.int32))
+    out = ps.place_sorted_grads(
+        *args, num_rows=len(pops) * bs, block_rows=bs, w=w, interpret=True)
+    ref = _scatter_ref(sf, cf, len(pops) * bs)
+    scale = np.abs(ref).max()
+    np.testing.assert_allclose(
+        np.asarray(out).T / scale, ref / scale, atol=2e-5)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        ps.place_sorted_grads(
+            *args, num_rows=len(pops) * bs, block_rows=bs, w=w - 64,
+            interpret=True)
 
 
 def test_pallas_group_knob(monkeypatch):
