@@ -274,6 +274,7 @@ def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret):
             _sds((B, H, Tq, _LANE), jnp.float32, qt),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(offs, qt, kt, vt)
     return out, lse
 
@@ -429,6 +430,7 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
         ),
         out_shape=[_sds(qt.shape, qt.dtype, qt)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(offs, qt, kt, vt, ot, gt, lse, *extra)[0]
 
     # dk/dv sweep: kv block outer (revisited output), q block inner
@@ -461,6 +463,7 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
             _sds(vt.shape, vt.dtype, vt),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(offs, qt, kt, vt, ot, gt, lse, *extra)
 
     back = lambda x: x.transpose(0, 2, 1, 3)

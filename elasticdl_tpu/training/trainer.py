@@ -586,8 +586,10 @@ class Trainer:
                 (loss_value, new_vars), grads = jax.value_and_grad(
                     compute_loss, has_aux=True
                 )(state.params)
-            updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):     # a name a trace can find
+                updates, new_opt_state = tx.update(
+                    grads, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
             new_state = state.replace(
                 step=state.step + 1,
                 params=new_params,

@@ -133,6 +133,33 @@ def test_local_transformer_lm_job_end_to_end(tmp_path):
     assert 0.0 <= results["token_accuracy"] <= 1.0
 
 
+def test_local_olmoe_job_end_to_end(tmp_path):
+    """OLMoE (dropless top-k experts, two auxiliary losses sown with their
+    own coefficients) through the same master/worker path, grouped
+    dispatch included: what `benchmark`'s job on the chip runs at width."""
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.olmoe.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 2,
+            "num_attention_heads": 4, "intermediate_size": 32,
+            "num_experts": 8, "num_experts_per_tok": 2,
+            "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+    )
+    master, _, counts = run_job(cfg, tmp_path)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    results = master.evaluation.latest_results()
+    assert 0.0 <= results["token_accuracy"] <= 1.0
+    assert master.servicer.mean_training_loss() < 7.0     # ln 256 = 5.5, + aux
+
+
 def test_run_job_stops_when_the_job_is_dead(tmp_path):
     """The harness itself (tests/jobs.py): a one-process worker started as
     cohort member 2 of 1 dies at world formation on every launch. run_job
