@@ -74,8 +74,8 @@ def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
       512-column windows and one MXU pass per term, ledger PR 23). Runs
       on real TPU or under interpret mode; everywhere else (and below
       its size gate) it falls back to:
-    - `tiled`: argsort ids, materialize the sorted gradient rows
-      once (contiguous), then lax.scan over vocab tiles of <= 256k rows:
+    - `tiled`: the same sorted stream (ids and gradient rows,
+      contiguous), then lax.scan over vocab tiles of <= 256k rows:
       each tile dynamic-slices a fixed window of the sorted stream
       (searchsorted tile edges) and scatter-adds INSIDE the fast zone,
       accumulating tiles into the dense gradient by dynamic-update-slice.
@@ -85,12 +85,29 @@ def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
       data-dependent overflow guard (`lax.cond` on the max window
       population) falls back to the flat scatter for pathological skew,
       so the path is exact for every distribution.
-    - `sorted`: argsort + full-table `segment_sum(indices_are_sorted=True)`
+    - `sorted`: the sort + full-table `segment_sum(indices_are_sorted=True)`
       — scatter-free but writes all V segments; measured equal to the flat
       scatter on v5e (23 ms), kept as the structural baseline.
     - `unique`: sort, compact duplicates (boundary cumsum), ONE
       unique-indices scatter — same slow zone, kept for the bench menu.
     - `xla`: the plain take VJP (the flat-scatter baseline).
+
+    What the sorted strategies share (`_sorted_stream`, `_run_sums`;
+    v5e, traced steps of the benchmark's three tables at 212 992 /
+    851 968 / 1 437 696 ids a step, my chip runs, PR 26): ONE stable sort
+    of (ids, positions), 0.20 / 0.96 / 1.92 ms, whose first output is the
+    sorted ids — gathering them by the positions instead cost 7.1 ns an
+    id at every size (1.52 / 6.07 / 10.25 ms) — and one row gather by the
+    positions, 1.8 / 4.5 / 6.2 ns a row (0.39 / 3.86 / 8.88 ms). A sort
+    that carries the D columns itself (13 operands: 1.01 ms and 9.04 ms at
+    the smallest and the largest stream, 0.37-0.48 ns an operand and
+    element) removes that gather and is worth under 1 ms a step either
+    way, for 200-300 s more in the compiler: not kept. The cliff of this
+    backward was elsewhere: the dedupe path's per-run sums, a row scatter
+    that ran at 8.2 / 44.6 / 64.2 ns a row (1.74 / 38.0 / 92.3 ms, the
+    largest operation of two benchmark cells, ledger PR 25) because the
+    compiler lays a scatter's output a row to a line only while that fits
+    its fast memory; `_run_sums` scans the stream in chunks that do.
     """
     return jnp.take(table, ids, axis=0)
 
@@ -220,11 +237,79 @@ def _compact_sorted_duplicates(cf_sorted, sf_sorted):
     is_start = jnp.concatenate(
         [jnp.ones((1,), bool), sf_sorted[1:] != sf_sorted[:-1]])
     seg = jnp.cumsum(is_start) - 1                     # compact, sorted
-    sums = jax.ops.segment_sum(
-        cf_sorted, seg, num_segments=n, indices_are_sorted=True)
+    sums = _run_sums(cf_sorted, seg)
     uids = jax.ops.segment_max(
         sf_sorted, seg, num_segments=n, indices_are_sorted=True)
     return sums, uids
+
+
+# What one row scatter's output may take of the v5e's fast memory. The
+# compiler lays a small scatter's output out a row to a 128-lane line
+# (512 B a row up to D = 128) and keeps it there: 212 992 rows (109 MB)
+# scatter at 8.2 ns a row. 239 616 rows (123 MB) are sent to HBM, and from
+# 359 424 rows on (REHEARSAL: compiled for a described v5e) the output is
+# laid out N-minor, a row in D separate lines: 44.6 ns a row at 851 968
+# rows, 64.2 at 1 437 696 (ledger, PR 25: `fusion.13` 38.0 ms of the
+# four-chip step, `fusion.6` 92.3 ms of xDeepFM's).
+FAST_SCATTER_BYTES = 110 << 20
+
+
+def _run_sums(cf_sorted, seg):
+    """`segment_sum(cf_sorted, seg, num_segments=n)` for the COMPACT sorted
+    segment ids `_compact_sorted_duplicates` makes (seg[0] = 0, steps of 0
+    or 1), with every scatter's output inside the fast zone.
+
+    A stream of up to `FAST_SCATTER_BYTES` is one segment_sum. A longer
+    one is scanned in equal chunks: chunk c's rows belong to segments
+    [seg[c*t], seg[c*t] + t), a contiguous range of the output no longer
+    than the chunk, so the chunk scatter-adds into that slice of the
+    output (taken out and laid back by dynamic slice: two passes of
+    bandwidth a chunk) at the small scatter's rate. A run of duplicates
+    that straddles a chunk edge goes on adding where the chunk before
+    left its sum, in stream order."""
+    n, d = cf_sorted.shape
+    k = -(-n * 512 * -(-d // 128) // FAST_SCATTER_BYTES)
+    if k == 1:
+        return jax.ops.segment_sum(
+            cf_sorted, seg, num_segments=n, indices_are_sorted=True)
+    t = -(-n // (8 * k)) * 8                           # rows a chunk
+    # pad rows: zeros at a segment beyond every chunk's range, dropped
+    seg = jnp.pad(seg, (0, k * t - n),
+                  constant_values=jnp.iinfo(seg.dtype).max)
+    cf_sorted = jnp.pad(cf_sorted, ((0, k * t - n), (0, 0)))
+
+    def body(acc, chunk):
+        rows, seg_c = chunk
+        first = seg_c[0]
+        part = jax.lax.dynamic_slice(acc, (first, 0), (t, d))
+        part = part.at[seg_c - first].add(
+            rows, mode="drop", indices_are_sorted=True)
+        return jax.lax.dynamic_update_slice(acc, part, (first, 0)), None
+
+    # the carry takes the rows' varying-manual-axes type (inside shard_map
+    # a plain zeros carry is 'unvarying' and scan rejects the mismatch)
+    acc = jnp.zeros((k * t, d), cf_sorted.dtype) + jnp.where(
+        True, 0.0, cf_sorted[:1, :1])
+    acc, _ = jax.lax.scan(
+        body, acc, (cf_sorted.reshape(k, t, d), seg.reshape(k, t)))
+    return acc[:n]
+
+
+def _sorted_stream(flat, cf):
+    """The backward's sorted stream `(cf_sorted, sf)`: the ids in ascending
+    order and the cotangent rows in that order, duplicates in batch order.
+
+    `argsort` IS a stable sort of (ids, positions) that throws its sorted
+    ids away; keeping them saves the gather `flat[order]`, which cost more
+    than the row gather beside it (7.1 ns an id against 1.8-6.2 ns a row:
+    10.25 and 8.88 ms of xDeepFM's step at 1 437 696 ids, my chip runs,
+    PR 26). Carrying the D columns through the sort as well was measured
+    and not kept: it runs within 1 ms a step of this on every table, and
+    a sort of D + 2 operands takes the compiler 200-300 s (PERF.md §6)."""
+    sf, order = jax.lax.sort(
+        (flat, jnp.arange(flat.shape[0], dtype=jnp.int32)),
+        dimension=0, is_stable=True, num_keys=1)
+    return cf[order], sf
 
 
 def _pallas_table_grad(cf, sf, num_rows):
@@ -344,8 +429,8 @@ def _gather_rows_bwd(res, ct):
                 and num_rows >= 2 * bs_p
                 and flat.shape[0] >= 4096
                 and est_w <= 16384):
-            order = jnp.argsort(flat)
-            d_table = _pallas_table_grad(cf[order], flat[order], num_rows)
+            d_table = _pallas_table_grad(
+                *_sorted_stream(flat, cf), num_rows)
             return d_table.astype(proto.dtype), None
         # trace-time, once per compiled program: which route this shape took
         logger.info(
@@ -359,18 +444,16 @@ def _gather_rows_bwd(res, ct):
             and flat.shape[0] >= 4096:
         # below those sizes the flat scatter is already in (or near) the
         # fast zone and tiling only adds window overhead
-        order = jnp.argsort(flat)
-        d_table = _tiled_table_grad(cf[order], flat[order], num_rows)
+        d_table = _tiled_table_grad(*_sorted_stream(flat, cf), num_rows)
         return d_table.astype(proto.dtype), None
     if mode == "tiled":
         d_table = jnp.zeros((num_rows, cf.shape[1]), jnp.float32).at[
             flat].add(cf, mode="drop")
         return d_table.astype(proto.dtype), None
-    order = jnp.argsort(flat)
-    sf = flat[order]
+    cf_sorted, sf = _sorted_stream(flat, cf)
     if mode == "unique":
         n = sf.shape[0]
-        sums, uids = _compact_sorted_duplicates(cf[order], sf)
+        sums, uids = _compact_sorted_duplicates(cf_sorted, sf)
         # Empty trailing segments come back at the dtype minimum, and REAL
         # out-of-range uids can also appear (the manual shard path's
         # non-owned sentinels are 2x the shard size). Route every
@@ -387,7 +470,7 @@ def _gather_rows_bwd(res, ct):
             sums, mode="drop", unique_indices=True)
     else:
         d_table = jax.ops.segment_sum(
-            cf[order], sf, num_segments=num_rows,
+            cf_sorted, sf, num_segments=num_rows,
             indices_are_sorted=True,
         )
     return d_table.astype(proto.dtype), None

@@ -145,6 +145,128 @@ def test_tiled_backward_on_manual_shard_path(monkeypatch, mesh8):
     np.testing.assert_allclose(np.asarray(g), expected, rtol=1e-5, atol=1e-6)
 
 
+def _stream_case_ids(ids_kind, n, rows, r):
+    """Id patterns the backward's sorted stream has to keep in order."""
+    if ids_kind == "zipf_runs":        # a few ids hold most of the stream
+        ids = np.minimum(r.zipf(1.1, n) - 1, rows - 1)
+    elif ids_kind == "all_equal":
+        ids = np.full(n, 7)
+    elif ids_kind == "oob_pad":        # embedding_lookup's int32max // 2
+        ids = np.where(r.rand(n) < 0.3, np.iinfo(np.int32).max // 2,
+                       r.randint(0, rows, n))
+    elif ids_kind == "shard_sentinels":   # the manual path's 2 x rows
+        ids = np.where(r.rand(n) < 0.75, 2 * rows, r.randint(0, rows, n))
+    else:
+        assert ids_kind == "unsigned"
+        return r.randint(0, rows, n).astype(np.uint32)
+    return ids.astype(np.int32)
+
+
+@pytest.mark.parametrize("d", [11, 16, 17])
+@pytest.mark.parametrize(
+    "ids_kind", ["zipf_runs", "all_equal", "oob_pad", "shard_sentinels",
+                 "unsigned"])
+def test_sorted_stream_is_the_argsort_permutation(d, ids_kind):
+    """The backward's stream: the one sort's own sorted ids and the rows
+    gathered by its positions are EXACTLY what `argsort` and two gathers
+    gave — same ids, same rows, duplicates in batch order."""
+    r = np.random.RandomState(71)
+    n, rows = 5000, 900
+    ids = jnp.asarray(_stream_case_ids(ids_kind, n, rows, r))
+    cf = jnp.asarray(r.randn(n, d).astype(np.float32))
+    cf_sorted, sf = jax.jit(emb_ops._sorted_stream)(ids, cf)
+    order = jnp.argsort(ids)
+    assert cf_sorted.shape == (n, d) and sf.dtype == ids.dtype
+    np.testing.assert_array_equal(np.asarray(sf), np.asarray(ids[order]))
+    np.testing.assert_array_equal(np.asarray(cf_sorted), np.asarray(cf[order]))
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize("mode", ["pallas", "tiled", "sorted", "unique"])
+def test_backward_holds_no_stream_long_id_gather_or_scatter(monkeypatch, mode):
+    """At xDeepFM's shape (55 296 x 26 = 1 437 696 ids x 11 columns into
+    2 605 056 rows; abstract values, nothing runs) the backward holds ONE
+    sort, of (ids, positions), and neither of the two operations that
+    cost it 102 ms a step there: no gather of N ids out of the N-long id
+    vector (`flat[order]`, 10.25 ms: the sort's own first output is that)
+    and no scatter-add into an N-row output (the dedupe's run sums, 92.3
+    ms: `_run_sums` goes in chunks that fit the fast zone). Neither can
+    come back unnoticed by a CPU-only check."""
+    from elasticdl_tpu.ops.pallas_attention import interpret_mode
+
+    monkeypatch.setenv("EDL_EMB_SCATTER", mode)
+    n, d, rows = 55296 * 26, 11, 2_605_056
+    with interpret_mode():
+        jaxpr = jax.make_jaxpr(
+            lambda ids, cf: emb_ops.scatter_add_dense(ids, cf, rows))(
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n, d), jnp.float32))
+    eqns = list(_all_eqns(jaxpr.jaxpr))
+    sorts = [e for e in eqns if e.primitive.name == "sort"]
+    assert [(len(e.invars), e.params["num_keys"], e.params["is_stable"])
+            for e in sorts] == [(2, 1, True)]
+    id_gathers = [
+        e for e in eqns if e.primitive.name == "gather"
+        and e.invars[0].aval.shape == (n,)
+        and e.outvars[0].aval.shape == (n,)]
+    assert not id_gathers
+    stream_scatters = [
+        e for e in eqns if e.primitive.name == "scatter-add"
+        and e.invars[0].aval.shape == (n, d)]
+    assert not stream_scatters
+    if mode == "pallas":
+        assert any(e.primitive.name == "pallas_call" for e in eqns)
+
+
+@pytest.mark.parametrize("n,chunk_rows", [
+    (8192 * 26, None),        # deepfm-criteo: one segment_sum, as before
+    (32768 * 26, 212992),     # a shard of deepfm-criteo1tb: four chunks
+    (55296 * 26, 205392),     # xdeepfm-criteo: seven, the last one padded
+])
+def test_run_sums_route_at_the_benchmark_streams(n, chunk_rows):
+    """Which route a stream takes is decided by its shape alone (abstract
+    values, nothing runs): up to `FAST_SCATTER_BYTES` of 512-byte rows one
+    segment_sum over the whole stream, beyond it equal chunks that fit."""
+    jaxpr = jax.make_jaxpr(emb_ops._run_sums)(
+        jax.ShapeDtypeStruct((n, 11), jnp.float32),
+        jax.ShapeDtypeStruct((n,), jnp.int32))
+    scatters = [e.invars[0].aval.shape[0] for e in _all_eqns(jaxpr.jaxpr)
+                if e.primitive.name == "scatter-add"]
+    assert scatters == [chunk_rows or n]
+    assert ("scan" in str(jaxpr)) == bool(chunk_rows)
+
+
+@pytest.mark.parametrize("n,d,fast_rows", [
+    (5000, 11, 1000),       # five whole chunks
+    (5000, 11, 999),        # six chunks, the last one padded
+    (4096, 17, 64),         # runs far longer than a chunk
+    (3000, 130, 500),       # rows wider than one 128-lane line
+    (777, 3, 1000),         # one chunk: the plain segment_sum
+])
+def test_run_sums_in_chunks_equal_one_segment_sum(monkeypatch, n, d, fast_rows):
+    """The dedupe's per-run sums, scanned in chunks that fit the fast
+    zone, are the one segment_sum's to the bit: a run that straddles a
+    chunk edge goes on adding in stream order."""
+    r = np.random.RandomState(n + d)
+    sf = np.sort(np.minimum(r.zipf(1.1, n) - 1, 900)).astype(np.int32)
+    seg = jnp.asarray(np.cumsum(np.r_[True, sf[1:] != sf[:-1]]) - 1)
+    cf = jnp.asarray(r.randn(n, d).astype(np.float32))
+    want = jax.ops.segment_sum(cf, seg, num_segments=n,
+                               indices_are_sorted=True)
+    monkeypatch.setattr(
+        emb_ops, "FAST_SCATTER_BYTES", fast_rows * 512 * -(-d // 128))
+    jaxpr = jax.make_jaxpr(emb_ops._run_sums)(cf, seg)
+    assert ("scan" in str(jaxpr)) == (n > fast_rows)
+    got = jax.jit(lambda a, b: emb_ops._run_sums(a, b))(cf, seg)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def _block_firsts_and_pops(ids_np, V, bs):
     """What `_pallas_table_grad` sees of a stream: per block, the column
     of its first id in sorted order and how many ids it holds."""
@@ -168,7 +290,7 @@ def _pallas_case_ids(ids_kind, r):
     else:
         V = 2048
         ids_np = r.randint(0, V, (64, 81)).astype(np.int32)
-        if ids_kind == "skewed":
+        if ids_kind.startswith("skewed"):
             ids_np[:, :60] = 7      # hot id -> window overflow -> fallback
         elif ids_kind == "with_padding":
             ids_np[:, 60:] = -1
@@ -180,13 +302,14 @@ def _pallas_case_ids(ids_kind, r):
 
 @pytest.mark.parametrize("d", [16, 17])
 @pytest.mark.parametrize(
-    "ids_kind", ["uniform", "skewed", "with_padding", "offset127_w256",
-                 "odd_lanes_window"])
+    "ids_kind", ["uniform", "skewed", "skewed_chunked_sums", "with_padding",
+                 "offset127_w256", "odd_lanes_window"])
 def test_pallas_backward_matches_reference(monkeypatch, d, ids_kind):
     """EDL_EMB_SCATTER=pallas (round-5 default on TPU): the MXU one-hot
     placement kernel must match a host reference across (a) uniform ids
-    (the kernel path), (b) extreme skew (the lax.cond flat fallback),
-    (c) negative padding ids, and (d, e) streams built to sit on the
+    (the kernel path), (b) extreme skew (the dedupe branch, its run sums
+    as one segment_sum and, past `FAST_SCATTER_BYTES`, in chunks of 1000
+    rows), (c) negative padding ids, and (d, e) streams built to sit on the
     window's edges while still passing the guard — at D=16 (aligned) AND
     D=17 (the deepfm merged-linear-column depth, which exercises the
     sublane padding and the in-kernel d_out slice). Runs the REAL Mosaic
@@ -199,6 +322,8 @@ def test_pallas_backward_matches_reference(monkeypatch, d, ids_kind):
 
     monkeypatch.setenv("EDL_EMB_SCATTER", "pallas")
     monkeypatch.setenv("EDL_EMB_PALLAS_BS", "256")
+    if ids_kind == "skewed_chunked_sums":
+        monkeypatch.setattr(emb_ops, "FAST_SCATTER_BYTES", 1000 * 512)
     r = np.random.RandomState(31)
     V, ids_np = _pallas_case_ids(ids_kind, r)
     w = ps.window_cols(ids_np.size, V, 256, 1.3)
@@ -400,10 +525,16 @@ def test_gather_rows_backward_unsigned_ids_and_empty(monkeypatch, mode):
     np.testing.assert_array_equal(np.asarray(g_e), 0.0)
 
 
-def test_gather_rows_unique_backward_under_jit_and_lookup(monkeypatch, mesh8):
+@pytest.mark.parametrize("fast_rows", [None, 16])
+def test_gather_rows_unique_backward_under_jit_and_lookup(
+        monkeypatch, mesh8, fast_rows):
     """unique mode composes with the full embedding_lookup paths (manual
-    shard_map + auto) under jit on the 8-device mesh."""
+    shard_map + auto) under jit on the 8-device mesh — also with its run
+    sums scanned in chunks (48 ids in three chunks of 16 rows), whose
+    carry has to take the shard_map's varying type."""
     monkeypatch.setenv("EDL_EMB_SCATTER", "unique")
+    if fast_rows:
+        monkeypatch.setattr(emb_ops, "FAST_SCATTER_BYTES", fast_rows * 512)
     from jax.sharding import NamedSharding
 
     table_np, table = make_table(mesh8, V=256, D=8, seed=7)
