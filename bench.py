@@ -1,5 +1,5 @@
-"""Benchmarks: DeepFM headline + all parity configs + embedding engine +
-input pipeline, on the local chip.
+"""Benchmarks: DeepFM headline + all parity configs + input pipeline, on
+the local chip.
 
 The reference publishes no numbers (`BASELINE.json "published": {}`), so the
 north-star metric is samples/sec/chip on the DeepFM config. Methodology (see
@@ -9,12 +9,11 @@ pipeline (disk → decode → H2D) is measured separately.
 
 Prints exactly ONE JSON line:
   {"metric": ..., "value": N, "unit": "samples/s/chip", "vs_baseline": N, ...}
-Extra keys: per-config sweep (`configs`), embedding engine modes
-(`embedding_rows_per_sec`), pipeline numbers, and — on TPU — MFU/roofline
-fields: every model leg reports analytic FLOPs (XLA cost analysis of the
-lowered step) -> achieved TFLOP/s -> `mfu_pct` vs the chip's bf16 peak;
-the HBM-bound embedding leg reports effective GB/s vs the HBM roofline
-instead. EDL_BENCH_FAST=1 skips the sweep (headline + pipeline only).
+Extra keys: per-config sweep (`configs`), pipeline numbers, and — on TPU —
+MFU/roofline fields: every model leg reports analytic FLOPs (XLA cost
+analysis of the lowered step) -> achieved TFLOP/s -> `mfu_pct` vs the
+chip's bf16 peak. EDL_BENCH_FAST=1 skips the sweep (headline + pipeline
+only).
 
 A subprocess device probe with a hard timeout runs FIRST (the parent never
 initialises a backend, so each leg subprocess gets the chip to itself); a
@@ -56,10 +55,10 @@ SCAN_STEPS = int(os.environ.get("EDL_BENCH_SCAN_STEPS", "32"))
 # share of the measurement.
 MIN_WALL_S = float(os.environ.get("EDL_BENCH_MIN_WALL_S", "2.5"))
 
-# Chip rooflines for MFU / HBM-utilization reporting (device_kind substring
-# -> (peak bf16 dense TFLOP/s, HBM GB/s), public spec-sheet numbers; first
-# match wins, so more specific kinds come first). Override with
-# EDL_PEAK_TFLOPS / EDL_PEAK_HBM_GBPS. MFU here = achieved-FLOPs(analytic,
+# Chip rooflines for MFU reporting (device_kind substring -> (peak bf16
+# dense TFLOP/s, HBM GB/s), public spec-sheet numbers; first match wins,
+# so more specific kinds come first). Override the FLOP peak with
+# EDL_PEAK_TFLOPS. MFU here = achieved-FLOPs(analytic,
 # from the lowered HLO's cost analysis) / bf16 peak — the portable yardstick
 # SURVEY §6 asks for since the reference publishes no absolute numbers.
 TPU_PEAKS = (
@@ -73,30 +72,24 @@ TPU_PEAKS = (
 
 
 def _chip_peaks():
-    """(peak_tflops, peak_hbm_gbps) for this backend; the peaks
-    are None off-TPU with no override (MFU would be meaningless on the CPU
-    mesh). A TPU whose `device_kind` matches no TPU_PEAKS entry is an
-    error, not a v5e: an MFU against the wrong roofline is worse than
-    none. The two env overrides apply independently — they feed disjoint
-    consumers (_mfu_fields uses only the FLOP peak, the embedding leg only
-    the HBM peak)."""
+    """Peak bf16 TFLOP/s for this backend; None off-TPU with no override
+    (MFU would be meaningless on the CPU mesh). A TPU whose `device_kind`
+    matches no TPU_PEAKS entry is an error, not a v5e: an MFU against the
+    wrong roofline is worse than none."""
     import jax
 
     tf_env = os.environ.get("EDL_PEAK_TFLOPS")
-    bw_env = os.environ.get("EDL_PEAK_HBM_GBPS")
-    tf = float(tf_env) if tf_env else None
-    bw = float(bw_env) if bw_env else None
-    if (tf is None or bw is None) and jax.default_backend() == "tpu":
-        kind = jax.devices()[0].device_kind.lower()
-        match = next((peaks for key, peaks in TPU_PEAKS if key in kind), None)
-        if match is None:
-            raise RuntimeError(
-                f"device_kind {kind!r} is not in TPU_PEAKS; add its "
-                "spec-sheet peaks (or set EDL_PEAK_TFLOPS and "
-                "EDL_PEAK_HBM_GBPS)")
-        tf = match[0] if tf is None else tf
-        bw = match[1] if bw is None else bw
-    return tf, bw
+    if tf_env:
+        return float(tf_env)
+    if jax.default_backend() != "tpu":
+        return None
+    kind = jax.devices()[0].device_kind.lower()
+    match = next((peaks for key, peaks in TPU_PEAKS if key in kind), None)
+    if match is None:
+        raise RuntimeError(
+            f"device_kind {kind!r} is not in TPU_PEAKS; add its "
+            "spec-sheet peaks (or set EDL_PEAK_TFLOPS)")
+    return match[0]
 
 
 def _mfu_fields(flops_per_step: float, step_s: float) -> dict:
@@ -104,7 +97,7 @@ def _mfu_fields(flops_per_step: float, step_s: float) -> dict:
     is Trainer.train_step_cost's count: the compiled, SPMD-partitioned
     step's, i.e. ONE device's share on a multi-device mesh — already the
     per-chip numerator to hold against the single-chip peak."""
-    peak_tf, _ = _chip_peaks()
+    peak_tf = _chip_peaks()
     if not flops_per_step or not step_s:
         return {}
     achieved_tf = flops_per_step / step_s / 1e12
@@ -245,171 +238,6 @@ def _census_batches(np, batch):
             "labels": r.randint(0, 2, (batch,)).astype(np.int32),
         })
     return out
-
-
-def bench_embedding_modes(mesh, np):
-    """Sharded-embedding engine: lookup-only and lookup+scatter-update
-    rows/s, manual (shard_map) vs auto (GSPMD) schedule. On one chip the two
-    compile to nearly the same program — the schedules only diverge on a
-    multi-device mesh (see BASELINE.md note); this records both so a regression
-    in either shows up in the round log.
-
-    Inputs are COMMITTED to a NamedSharding before any timing (round-5
-    finding): feeding uncommitted (SingleDeviceSharding) arrays to a jit
-    under an ambient mesh took a ~27x-slower dispatch path even on a
-    1-device mesh — that artifact, not the scatter, produced round 3's
-    "0.18M rows/s" update figure. The real framework path (Trainer +
-    shard_batch) always feeds committed arrays, so committed inputs are
-    the representative measurement."""
-    import jax
-    import jax.numpy as jnp
-    import optax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from elasticdl_tpu.ops import embedding as emb_ops
-
-    V, D, B, L = emb_ops.padded_vocab(FIELD_VOCAB * 26), 16, BATCH, 26
-    repl = NamedSharding(mesh, P())
-    table = jax.device_put(
-        np.random.RandomState(0).randn(V, D).astype(np.float32) * 0.01, repl
-    )
-    ids = jax.device_put(
-        np.random.RandomState(1).randint(0, V, (B, L)).astype(np.int32), repl
-    )
-    opt = optax.sgd(0.1)
-    results = {}
-    with jax.set_mesh(mesh):
-        # the full scatter-strategy menu in one chip window: tiled
-        # (fast-zone scan, round-5 default) vs sorted segment-sum vs
-        # unique-compaction vs the plain XLA scatter baseline
-        # (ops/embedding.gather_rows)
-        from elasticdl_tpu.ops import pallas_scatter as _ps
-
-        if not _ps.runnable():
-            # off-TPU the pallas mode reroutes to tiled — recording both
-            # rows would be the same program under two labels
-            results["pallas_is_tiled_off_tpu"] = True
-        def make_step():
-            # fresh jit per use: EDL_EMB_SCATTER is read at trace time,
-            # and the sweep + skew legs must each trace their own step
-            @jax.jit
-            def step(t, s, i):
-                g = jax.grad(
-                    lambda tt: jnp.sum(
-                        emb_ops.embedding_lookup(tt, i, mode="auto") ** 2
-                    )
-                )(t)
-                up, s = opt.update(g, s)
-                return optax.apply_updates(t, up), s
-
-            return step
-
-        for scatter in ("pallas", "tiled", "sorted", "unique", "xla"):
-            os.environ["EDL_EMB_SCATTER"] = scatter
-            try:
-                opt_state = opt.init(table)
-                sstep = make_step()
-                sbox = [sstep(table, opt_state, ids)]
-                float(jnp.sum(sbox[0][0][:1]))
-
-                def supd(i):
-                    sbox[0] = sstep(sbox[0][0], sbox[0][1], ids)
-
-                n, dt = timed_loop(
-                    supd, lambda: float(jnp.sum(sbox[0][0][:1])), 5)
-                results[f"update_rows_per_sec_{scatter}_scatter"] = round(
-                    n * B * L / dt, 1)
-            finally:
-                os.environ.pop("EDL_EMB_SCATTER", None)
-
-        # skewed-id leg: 30% of all slots hit ONE hot id — real recsys
-        # head skew. On TPU this exercises the pallas dedupe middle path
-        # (adjacent-duplicate compaction before placement); off-TPU the
-        # default reroutes to tiled, whose overflow guard lands on the
-        # flat scatter — the path label below keeps the record honest.
-        results["skewed_ids_path"] = (
-            "pallas-dedupe" if _ps.runnable() else "tiled-flat-fallback")
-        skew_np = np.random.RandomState(2).randint(0, V, (B, L)).astype(
-            np.int32)
-        skew_np[:, :8] = 12345
-        skew_ids = jax.device_put(skew_np, repl)
-        sk = make_step()
-        kbox = [sk(table, opt.init(table), skew_ids)]
-        float(jnp.sum(kbox[0][0][:1]))
-        n, dt = timed_loop(
-            lambda i: kbox.__setitem__(
-                0, sk(kbox[0][0], kbox[0][1], skew_ids)),
-            lambda: float(jnp.sum(kbox[0][0][:1])), 5)
-        results["update_rows_per_sec_skewed_ids"] = round(n * B * L / dt, 1)
-
-        if int(mesh.devices.size) == 1:
-            # honesty marker (code-review r5 pt3): embedding_lookup
-            # reroutes manual->auto on a 1-device mesh, so the two rows
-            # below are the SAME program there; a shard_map-schedule
-            # regression only shows up on a multi-device run
-            results["manual_is_auto_on_1_device"] = True
-        for mode in ("manual", "auto"):
-            # summed output: a scalar readback that depends on every lookup
-            look = jax.jit(
-                lambda t, i: jnp.sum(emb_ops.embedding_lookup(t, i, mode=mode))
-            )
-            out_box = [look(table, ids)]
-            float(out_box[0])
-            n, dt = timed_loop(
-                lambda i: out_box.__setitem__(0, look(table, ids)),
-                lambda: float(out_box[0]), 30)
-            lookup_rps = n * B * L / dt
-
-            opt_state = opt.init(table)
-
-            @jax.jit
-            def step(t, s, i):
-                g = jax.grad(
-                    lambda tt: jnp.sum(
-                        emb_ops.embedding_lookup(tt, i, mode=mode) ** 2
-                    )
-                )(t)
-                up, s = opt.update(g, s)
-                return optax.apply_updates(t, up), s
-
-            box = [step(table, opt_state, ids)]
-            float(jnp.sum(box[0][0][:1]))
-
-            def upd(i):
-                box[0] = step(box[0][0], box[0][1], ids)
-
-            n, dt = timed_loop(
-                upd, lambda: float(jnp.sum(box[0][0][:1])), 10)
-            update_rps = n * B * L / dt
-            results[mode] = {
-                "lookup_rows_per_sec": round(lookup_rps, 1),
-                "update_rows_per_sec": round(update_rps, 1),
-            }
-
-    # Embedding is HBM-bound, not FLOP-bound, so its roofline is bandwidth:
-    # analytic bytes/row (f32, D floats) — lookup touches 2 rows' worth
-    # (table read + output write), a full SGD update ~5 (fwd gather 2 +
-    # grad-segment read 1 + table read-modify-write 2). Utilization against
-    # the chip's HBM peak says how far the engine is from the roof.
-    _, peak_bw = _chip_peaks()
-    row_bytes = D * 4
-    for mode in ("manual", "auto"):
-        r = results[mode]
-        r["lookup_hbm_gbps"] = round(
-            r["lookup_rows_per_sec"] * 2 * row_bytes / 1e9, 2)
-        r["update_hbm_gbps"] = round(
-            r["update_rows_per_sec"] * 5 * row_bytes / 1e9, 2)
-        if peak_bw:
-            r["lookup_hbm_util_pct"] = round(
-                100.0 * r["lookup_hbm_gbps"] / peak_bw, 2)
-            r["update_hbm_util_pct"] = round(
-                100.0 * r["update_hbm_gbps"] / peak_bw, 2)
-            # the utilization is against the ANALYTIC minimum bytes/row
-            # model above, not measured traffic — a low number means the
-            # engine is far from the roof, a high one is still only a
-            # lower bound on real HBM activity (VERDICT r4 weak #8)
-            r["hbm_bytes_model"] = "analytic-min"
-    return results
 
 
 def bench_time_to_auc(mesh, np, target=0.75):
@@ -4347,8 +4175,6 @@ def _run_leg(leg, mesh, np):
             mesh, np, "deepfm.xdeepfm", 4096, criteo_batches,
             model_params={"field_vocab": FIELD_VOCAB},
         )
-    if leg == "embedding":
-        return bench_embedding_modes(mesh, np)
     if leg == "time_to_auc":
         return bench_time_to_auc(mesh, np)
     if leg == "rescale":
@@ -4407,7 +4233,7 @@ def _run_leg(leg, mesh, np):
 SWEEP_LEGS = (
     "rescale", "control_plane", "goodput", "autoscale", "fleet_soak",
     "embedding_tier",
-    "data_plane", "obs_overhead", "embedding", "transformer_lm",
+    "data_plane", "obs_overhead", "transformer_lm",
     "time_to_auc", "mnist_cnn", "census_wide_deep", "xdeepfm",
     "cifar10_resnet20", "resnet50_imagenet",
 )
@@ -4684,7 +4510,6 @@ def main():
                 continue
             print(f"[bench] leg {leg}...", file=sys.stderr, flush=True)
             configs[leg] = leg_subprocess(leg, LEG_TIMEOUT_S)
-        result["embedding_rows_per_sec"] = configs.pop("embedding", None)
         result["configs"] = configs
 
     print(json.dumps(result))

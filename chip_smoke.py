@@ -400,27 +400,24 @@ def check_kernels(size, require_mosaic=True):
         raise RuntimeError(f"DeepFM loss {float(logs['loss'])}")
     del state, exe
 
-    # 2. table gradient: placement kernel vs EDL_EMB_SCATTER=xla, same ids
-    # and cotangents, at the DeepFM shape (D = 16 + the linear column)
+    # 2. table gradient: gather_rows' VJP (the placement kernel) vs
+    # jnp.take's own (XLA's flat scatter-add), same ids and cotangents, at
+    # the DeepFM shape (D = 16 + the linear column)
     rows = embedding.padded_vocab(26 * size["field_vocab"])
     r = np.random.RandomState(7)
     ids = jnp.asarray(r.randint(0, rows, (size["batch"], 26)), jnp.int32)
     ct = jnp.asarray(r.randn(size["batch"], 26, 17), jnp.float32)
     table = jnp.zeros((rows, 17), jnp.float32)
 
-    def table_grad(mode):
-        os.environ["EDL_EMB_SCATTER"] = mode       # read at trace time
-        try:
-            fn = jax.jit(lambda t, i, c: jax.vjp(
-                lambda t: embedding._take(t, i), t)[1](c)[0])
-            exe = fn.lower(table, ids, ct).compile()
-            return exe(table, ids, ct), exe
-        finally:
-            del os.environ["EDL_EMB_SCATTER"]
+    def table_grad(take):
+        fn = jax.jit(lambda t, i, c: jax.vjp(
+            lambda t: take(t, i), t)[1](c)[0])
+        exe = fn.lower(table, ids, ct).compile()
+        return exe(table, ids, ct), exe
 
-    got, exe = table_grad("pallas")
+    got, exe = table_grad(embedding.gather_rows)
     out["placement_mosaic_calls"] = mosaic_calls(exe)
-    ref, _ = table_grad("xla")
+    ref, _ = table_grad(lambda t, i: jnp.take(t, i, axis=0))
     out["placement_err"] = _scaled_err(got, ref)
     if out["placement_err"] > PLACEMENT_TOL:
         raise RuntimeError(

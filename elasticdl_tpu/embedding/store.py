@@ -5,9 +5,9 @@ Reference parity: the Go PS's per-pod embedding hash map + row-by-row
 sparse optimizer (elasticdl/pkg/ps/embedding.go, optimizer.go). Rebuilt
 dense: shard s of table T is ONE (rows, dim) array addressed by
 `local = id // num_shards`, so a pull is a single take and a push is one
-scatter-add routed through the SAME strategy menu as the training
-backward (ops/embedding.scatter_add_dense — pallas placement kernel with
-the skew-dedupe middle path, tiled fast-zone scan, ...). Per-shard
+scatter-add by the SAME route as the training backward
+(ops/embedding.scatter_add_dense — placement kernel with the skew-dedupe
+middle path, tiled fast-zone scan, or flat scatter). Per-shard
 outputs are `vocab/num_shards` rows, which is what keeps the scatter
 inside the measured fast zone at production vocab sizes — the sharding
 is itself the perf fix, not just capacity (BASELINE.md round-5 scatter

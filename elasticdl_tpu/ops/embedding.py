@@ -31,7 +31,6 @@ preprocessing.hashing) bounds the table like the reference's Hashing layer.
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -47,67 +46,41 @@ logger = default_logger(__name__)
 
 @jax.custom_vjp
 def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
-    """`table[ids]` with a BACKWARD built around the TPU scatter cliff.
+    """`table[ids]`, with a backward built around the TPU's scatter-add.
 
-    Measured on the chip (round 5, idle host, scalar-readback timing,
-    213k rows x D=16 into a 2.6M-row table): the scatter-add's per-element
-    cost jumps ~8x once the OUTPUT outgrows the fast zone — ~14 ns/element
-    when the destination is <= ~256k rows (16 MB, VMEM-resident tiles),
-    ~105 ns/element into the full 2.6M-row table — and neither the
-    `indices_are_sorted` nor the `unique_indices` promise changes the slow
-    lowering (22.4 ms either way; a sorted `segment_sum` over V segments
-    costs the same 23 ms). The earlier round-3 "0.18M rows/s, 250x slower
-    than the gather" reading conflated this with an uncommitted-input
-    dispatch pathology under an ambient mesh (see BASELINE.md round-5
-    notes); the honest gap is ~5x (gather 4.6 ms vs scatter 22-23 ms),
-    still the single biggest line in the DeepFM step.
+    Forward: `jnp.take(table, ids, axis=0)`.
 
-    Strategies, selected by EDL_EMB_SCATTER (read at trace time):
+    Backward: the dense (rows, D) sum of the cotangent rows at their ids,
+    by the route `backward_route` picks from what the code can see — the
+    number of ids, the table's rows, and whether the placement kernel can
+    run (a TPU, or interpret mode in the CPU tests). No environment
+    variable is read on the way.
 
-    - `pallas` (default): the Mosaic placement kernel
-      (ops/pallas_scatter.py) — sort once, then one-hot matmul the sorted
-      windows onto 2048-row output blocks on the MXU (~4e-6 rel accuracy
-      via a two-term bf16 split). Its time follows the table's rows x
-      the window's columns, not the ids: 6.8 ms a step for 213k ids into
-      33.8M rows under Zipf ids, where every step takes the dedupe
-      branch, of a 44.5 ms step (PERF.md §5, PR 24; it was 24.7 ms with
-      512-column windows and one MXU pass per term, ledger PR 23). Runs
-      on real TPU or under interpret mode; everywhere else (and below
-      its size gate) it falls back to:
-    - `tiled`: the same sorted stream (ids and gradient rows,
-      contiguous), then lax.scan over vocab tiles of <= 256k rows:
-      each tile dynamic-slices a fixed window of the sorted stream
-      (searchsorted tile edges) and scatter-adds INSIDE the fast zone,
-      accumulating tiles into the dense gradient by dynamic-update-slice.
-      Every scatter's output fits the fast zone, so the whole backward
-      runs at the ~14 ns/element rate plus one sorted materialization
-      (measured: 10.8 ms vs 22.4 ms flat for the bench shape). A
-      data-dependent overflow guard (`lax.cond` on the max window
-      population) falls back to the flat scatter for pathological skew,
-      so the path is exact for every distribution.
-    - `sorted`: the sort + full-table `segment_sum(indices_are_sorted=True)`
-      — scatter-free but writes all V segments; measured equal to the flat
-      scatter on v5e (23 ms), kept as the structural baseline.
-    - `unique`: sort, compact duplicates (boundary cumsum), ONE
-      unique-indices scatter — same slow zone, kept for the bench menu.
-    - `xla`: the plain take VJP (the flat-scatter baseline).
+    - `kernel`: one stable sort of (ids, positions) (`_sorted_stream`),
+      then the Mosaic placement kernel (ops/pallas_scatter.py) one-hot
+      matmuls each output block's window of the sorted stream on the MXU.
+      A `lax.cond` on the fullest window guards it: a stream whose
+      duplicates overflow a window is first compacted to per-distinct-id
+      sums (`dedupe_then_place`; under the benchmark's Zipf ids every step
+      goes this way), and one whose DISTINCT ids still overflow takes a
+      flat sorted scatter.
+    - `tiled`: the same sorted stream, scanned over vocab tiles of
+      `TILE_ROWS` rows whose scatters each stay inside fast memory
+      (`_tiled_table_grad`); a `lax.cond` falls back to the flat scatter
+      when a window overflows. Where the kernel cannot run, or its window
+      would be too wide.
+    - `flat`: XLA's scatter-add of the unsorted stream — what `jnp.take`'s
+      own VJP does. Small streams and small tables.
 
-    What the sorted strategies share (`_sorted_stream`, `_run_sums`;
-    v5e, traced steps of the benchmark's three tables at 212 992 /
-    851 968 / 1 437 696 ids a step, my chip runs, PR 26): ONE stable sort
-    of (ids, positions), 0.20 / 0.96 / 1.92 ms, whose first output is the
-    sorted ids — gathering them by the positions instead cost 7.1 ns an
-    id at every size (1.52 / 6.07 / 10.25 ms) — and one row gather by the
-    positions, 1.8 / 4.5 / 6.2 ns a row (0.39 / 3.86 / 8.88 ms). A sort
-    that carries the D columns itself (13 operands: 1.01 ms and 9.04 ms at
-    the smallest and the largest stream, 0.37-0.48 ns an operand and
-    element) removes that gather and is worth under 1 ms a step either
-    way, for 200-300 s more in the compiler: not kept. The cliff of this
-    backward was elsewhere: the dedupe path's per-run sums, a row scatter
-    that ran at 8.2 / 44.6 / 64.2 ns a row (1.74 / 38.0 / 92.3 ms, the
-    largest operation of two benchmark cells, ledger PR 25) because the
-    compiler lays a scatter's output a row to a line only while that fits
-    its fast memory; `_run_sums` scans the stream in chunks that do.
+    Numerics: the kernel's MXU runs bfloat16, so it places a two-term
+    (hi + lo) bf16 split of each float32 value and accumulates in
+    float32: about 4e-6 relative to a float32 accumulation (the
+    benchmark's `correct` holds it; `chip_smoke.py` phase B measures it
+    against `jnp.take`'s VJP). `dedupe_then_place`'s sums, `tiled` and
+    `flat` add in float32, exactly.
+
+    What each piece costs on the chip, per benchmark cell: PERF.md §5; why
+    it is built this way: PERF.md §6.
     """
     return jnp.take(table, ids, axis=0)
 
@@ -118,28 +91,49 @@ def _gather_rows_fwd(table, ids):
     )
 
 
-# Fast-zone knobs for the tiled backward (see gather_rows docstring).
-# tile_rows x D x 4B must stay inside the measured fast-scatter zone
-# (<= ~16 MB output on v5e); 128k rows x 16 floats = 8 MB leaves headroom
-# for wider embedding dims. Read at trace time so bench sweeps and tests
-# can resize tiles without re-importing.
-DEFAULT_TILE_ROWS = 128 * 1024
-# Windows are sized at slack x the uniform expectation (hashed vocabs make
-# the per-tile population near-uniform; uniform max over ~20 tiles sits
-# ~4 sigma = ~4% above the mean, so 1.3x is comfortable); the cond
-# fallback keeps skewed id distributions exact. Cost is per window SLOT
-# (round-5 chip sweep), so the window is aligned to 256 rows, not rounded
-# to a power of two — pow2 rounding nearly doubled the slot count.
-DEFAULT_TILE_WINDOW_SLACK = 1.3
+# The tiled backward's tile: TILE_ROWS x D x 4B is one scatter's output and
+# must stay inside the fast-scatter zone (<= ~16 MB on v5e); 128k rows x 16
+# floats = 8 MB leaves headroom for wider embedding dims.
+TILE_ROWS = 128 * 1024
+# Windows (the tiled path's and the kernel's) are sized at slack x the
+# uniform expectation (hashed vocabs make the per-tile population
+# near-uniform; uniform max over ~20 tiles sits ~4 sigma = ~4% above the
+# mean, so 1.3x is comfortable); the cond fallbacks keep skewed id
+# distributions exact. The tiled path's cost is per window SLOT, so its
+# window is aligned to 256 rows, not rounded to a power of two — pow2
+# rounding nearly doubled the slot count.
+WINDOW_SLACK = 1.3
+# The widest window the kernel route takes: w scales as slack*n*bs/rows,
+# and a small vocab under a huge batch (just past the 2*bs gate) would
+# demand a VMEM window far beyond the kernel's ~4 MB budget — those shapes
+# take the tiled route instead of failing Mosaic allocation.
+KERNEL_MAX_WINDOW = 16384
+# Below this many ids the flat scatter is already in (or near) the fast
+# zone and sorting and windowing only add overhead.
+SORTED_MIN_IDS = 4096
 
 
-def _tile_rows() -> int:
-    return int(os.environ.get("EDL_EMB_TILE_ROWS", str(DEFAULT_TILE_ROWS)))
+def backward_route(n: int, num_rows: int, kernel_runnable: bool) -> str:
+    """Which backward `n` ids into `num_rows` rows take — "kernel", "tiled"
+    or "flat" (see `gather_rows`): a pure function of the shapes and of
+    whether the placement kernel can run here."""
+    from elasticdl_tpu.ops import pallas_scatter
 
-
-def _window_slack() -> float:
-    return float(os.environ.get(
-        "EDL_EMB_WINDOW_SLACK", str(DEFAULT_TILE_WINDOW_SLACK)))
+    bs = pallas_scatter.BLOCK_ROWS
+    est_w = WINDOW_SLACK * n * bs / max(1, num_rows)
+    if (kernel_runnable and num_rows >= 2 * bs and n >= SORTED_MIN_IDS
+            and est_w <= KERNEL_MAX_WINDOW):
+        return "kernel"
+    route = ("tiled" if num_rows > 2 * TILE_ROWS and n >= SORTED_MIN_IDS
+             else "flat")
+    # trace-time, once per compiled program: which route this shape took
+    logger.info(
+        "embedding backward (%d ids into %d rows) stays off the Pallas "
+        "placement kernel (needs a TPU or interpret mode: %s; rows >= "
+        "%d; ids >= %d; window estimate %.0f <= %d) and takes the "
+        "XLA %s path", n, num_rows, kernel_runnable, 2 * bs,
+        SORTED_MIN_IDS, est_w, KERNEL_MAX_WINDOW, route)
+    return route
 
 
 def _tiled_table_grad(cf, sf, num_rows):
@@ -156,7 +150,7 @@ def _tiled_table_grad(cf, sf, num_rows):
     near-uniform (hashed) ids; `lax.cond` falls back to one flat scatter
     when the data is skewed enough to overflow a window."""
     n, d = cf.shape
-    tile_rows = _tile_rows()
+    tile_rows = TILE_ROWS
     nt = -(-num_rows // tile_rows)
     # Window sizing counts ALL n contributions, including the manual shard
     # path's non-owned sentinels (they sort beyond every real id, so they
@@ -165,7 +159,7 @@ def _tiled_table_grad(cf, sf, num_rows):
     # its owned rows — the backward stays at single-chip cost rather than
     # scaling down. Known refinement: derive the owned fraction from the
     # static shard count when tracing inside shard_map.
-    w = int(min(n, -(-int(max(256.0, _window_slack() * n / nt)) // 256) * 256))
+    w = int(min(n, -(-int(max(256.0, WINDOW_SLACK * n / nt)) // 256) * 256))
     vpad = nt * tile_rows
     edges = jnp.searchsorted(
         sf, jnp.arange(0, vpad + 1, tile_rows, dtype=jnp.int32)
@@ -229,10 +223,8 @@ def _compact_sorted_duplicates(cf_sorted, sf_sorted):
     fast-zone segment ops (both outputs are n rows, n = stream length).
     Returns (sums (n, d), uids (n,)) where slot j holds the j-th distinct
     id's total; trailing empty segments come back with uid = dtype min.
-    Callers apply their own out-of-range remap (the `unique` scatter
-    needs DISTINCT OOB targets for its unique_indices promise; the pallas
-    dedupe path collapses everything to int32max) — keep those strategies
-    at the call sites, not here."""
+    The caller applies its own out-of-range remap (the dedupe path sends
+    everything out of range to int32max)."""
     n = sf_sorted.shape[0]
     is_start = jnp.concatenate(
         [jnp.ones((1,), bool), sf_sorted[1:] != sf_sorted[:-1]])
@@ -312,6 +304,41 @@ def _sorted_stream(flat, cf):
     return cf[order], sf
 
 
+def _kernel_stream(rows, ids, w, vpad, bs):
+    """The placement kernel's input layout, made in ONE place: `rows`
+    (n, d) in sorted-id order and their sorted int32 `ids` ->
+    (`cf_t` (d8, n + w) the rows transposed and zero-padded, `sf_pad`
+    (n + w,) the ids padded with int32max, `edges` (vpad / bs + 1,) the
+    column where each block of `bs` rows begins).
+
+    The expression is as fragile as it is plain: the kernel's time
+    depends on where XLA keeps this operand. Written as one `jnp.pad` of
+    the transpose, the same values got an N-minor `{0,1}` layout, the copy
+    that repairs it went to HBM, the kernel's window DMAs read from HBM
+    and not from fast memory, and `emb_place_ms` went 6.70 -> 14.04
+    (-13% on deepfm-criteo.resident; PERF.md §6, PR 26). Compare the AOT
+    text (`S(1)` after the operand's layout) before and after any change
+    here."""
+    n, d = rows.shape
+    imax = jnp.iinfo(jnp.int32).max
+    sf_pad = jnp.concatenate([ids, jnp.full((w,), imax, ids.dtype)])
+    # transpose FIRST, pad on lanes: the (N, D) -> (D, N) relayout of the
+    # small sorted stream fuses with the reorder gather (~0.7 ms
+    # measured), while transpose-of-concat materialized a separate 2 ms
+    # copy
+    # depth padded to the Mosaic sublane tile (8): D=17 (deepfm's merged
+    # linear column) would otherwise fail the DMA alignment check
+    d8 = -(-d // 8) * 8
+    cf_t = jnp.concatenate([
+        jnp.concatenate([rows.T, jnp.zeros((d8 - d, n), rows.dtype)], axis=0),
+        jnp.zeros((d8, w), rows.dtype),
+    ], axis=1)
+    edges = jnp.searchsorted(
+        ids, jnp.arange(0, vpad + 1, bs, dtype=jnp.int32)
+    ).astype(jnp.int32)
+    return cf_t, sf_pad, edges
+
+
 def _pallas_table_grad(cf, sf, num_rows):
     """Dense gradient via the MXU one-hot placement kernel
     (ops/pallas_scatter.py) — same windowing contract as the tiled path
@@ -321,26 +348,9 @@ def _pallas_table_grad(cf, sf, num_rows):
     from elasticdl_tpu.ops import pallas_scatter
 
     n, d = cf.shape
-    bs = pallas_scatter.block_rows()
-    nb = -(-num_rows // bs)
-    vpad = nb * bs
-    w = pallas_scatter.window_cols(n, num_rows, bs, _window_slack())
-    sf_pad = jnp.concatenate(
-        [sf, jnp.full((w,), jnp.iinfo(jnp.int32).max, sf.dtype)])
-    # transpose FIRST, pad on lanes: the (N, D) -> (D, N) relayout of the
-    # small sorted stream fuses with the reorder gather (~0.7 ms
-    # measured), while transpose-of-concat materialized a separate 2 ms
-    # copy
-    # depth padded to the Mosaic sublane tile (8): D=17 (deepfm's merged
-    # linear column) would otherwise fail the DMA alignment check
-    d8 = -(-d // 8) * 8
-    cf_t = jnp.concatenate([
-        jnp.concatenate([cf.T, jnp.zeros((d8 - d, n), cf.dtype)], axis=0),
-        jnp.zeros((d8, w), cf.dtype),
-    ], axis=1)
-    edges = jnp.searchsorted(
-        sf, jnp.arange(0, vpad + 1, bs, dtype=jnp.int32)
-    ).astype(jnp.int32)
+    bs = pallas_scatter.BLOCK_ROWS
+    vpad = -(-num_rows // bs) * bs
+    w = pallas_scatter.window_cols(n, num_rows, bs, WINDOW_SLACK)
     # what a window holds of its own block: w less the up-to-127 columns
     # its aligned start may lie before the block's first id
     room = w - pallas_scatter.LANES
@@ -351,9 +361,6 @@ def _pallas_table_grad(cf, sf, num_rows):
         out_t = pallas_scatter.place_sorted_grads(
             cf_t, sf_pad[None, :], edges[:-1],
             num_rows=vpad, block_rows=bs, w=w, d_out=d,
-            split=os.environ.get(
-                "EDL_EMB_PALLAS_PRECISION", "split") != "bf16",
-            group=pallas_scatter.group_blocks(),
             interpret=kernel_interpret(),
         )
         # kernel emits (D, vpad) — rows on lanes, see pallas_scatter —
@@ -373,33 +380,25 @@ def _pallas_table_grad(cf, sf, num_rows):
         the same kernel. Window populations become DISTINCT-id counts,
         which hashing spreads near-uniformly, so real-world head skew
         stays on the MXU path: under the benchmark's Zipf ids EVERY step
-        comes this way, sort and compaction ~9.7 ms and the kernel 6.8 ms
-        of DeepFM's step (PERF.md §5, PR 24). A final flat fallback
-        remains for adversarially CLUSTERED distinct ids."""
+        comes this way (PERF.md §5). A final flat fallback remains for
+        adversarially CLUSTERED distinct ids."""
         del edges
-        imax = jnp.iinfo(jnp.int32).max
         sums, uids = _compact_sorted_duplicates(
             cf_t[:d, :n].T, sf_pad[:n])
         # empty trailing segments (dtype min) and real out-of-range ids
         # (manual-path sentinels; their cotangents are zero) both go to
         # int32max: sorted with the pad, matching no window, dropped by
         # every placement below
-        uids = jnp.where((uids < 0) | (uids >= num_rows), imax, uids)
-        sf2 = jnp.concatenate([uids, jnp.full((w,), imax, jnp.int32)])
-        cf2_t = jnp.concatenate([
-            jnp.concatenate(
-                [sums.T, jnp.zeros((d8 - d, n), sums.dtype)], axis=0),
-            jnp.zeros((d8, w), sums.dtype),
-        ], axis=1)
-        edges2 = jnp.searchsorted(
-            uids, jnp.arange(0, vpad + 1, bs, dtype=jnp.int32)
-        ).astype(jnp.int32)
+        uids = jnp.where((uids < 0) | (uids >= num_rows),
+                         jnp.iinfo(jnp.int32).max, uids)
+        cf2_t, sf2, edges2 = _kernel_stream(sums, uids, w, vpad, bs)
         max_pop2 = jnp.max(edges2[1:] - edges2[:-1])
         return jax.lax.cond(
             max_pop2 <= room, pallas_branch, flat, cf2_t, sf2, edges2)
 
     # Window statistics assume near-uniform ids (hashed vocab); skewed
     # data routes through the dedupe middle path above.
+    cf_t, sf_pad, edges = _kernel_stream(cf, sf, w, vpad, bs)
     max_pop = jnp.max(edges[1:] - edges[:-1])
     return jax.lax.cond(
         max_pop <= room, pallas_branch, dedupe_then_place,
@@ -407,72 +406,25 @@ def _pallas_table_grad(cf, sf, num_rows):
 
 
 def _gather_rows_bwd(res, ct):
+    from elasticdl_tpu.ops import pallas_scatter
+
     ids, proto, num_rows = res
-    # int32: the unique path's empty-segment sentinel relies on signed
+    # int32: the dedupe path's empty-segment sentinel relies on signed
     # comparisons (an unsigned dtype would make `uids < 0` vacuous and
-    # collide sentinel rows at 0); vocab sizes are far below 2^31
+    # send sentinel rows to row 0); vocab sizes are far below 2^31
     flat = ids.reshape(-1).astype(jnp.int32)
     cf = ct.reshape(-1, ct.shape[-1]).astype(jnp.float32)
     if flat.shape[0] == 0:  # static: empty batch, zero gradient
         return jnp.zeros((num_rows, ct.shape[-1]), proto.dtype), None
-    mode = os.environ.get("EDL_EMB_SCATTER", "pallas")
-    if mode == "pallas":
-        from elasticdl_tpu.ops import pallas_scatter
-
-        bs_p = pallas_scatter.block_rows()
-        # window cap: w scales as slack*n*bs/num_rows, and a small vocab
-        # under a huge batch (just past the 2*bs gate) would demand a
-        # VMEM window far beyond the kernel's ~4 MB budget — those shapes
-        # route to the tiled path instead of failing Mosaic allocation
-        est_w = _window_slack() * flat.shape[0] * bs_p / max(1, num_rows)
-        if (pallas_scatter.runnable()
-                and num_rows >= 2 * bs_p
-                and flat.shape[0] >= 4096
-                and est_w <= 16384):
-            d_table = _pallas_table_grad(
-                *_sorted_stream(flat, cf), num_rows)
-            return d_table.astype(proto.dtype), None
-        # trace-time, once per compiled program: which route this shape took
-        logger.info(
-            "embedding backward (%d ids into %d rows) stays off the Pallas "
-            "placement kernel (needs a TPU or interpret mode: %s; rows >= "
-            "%d; ids >= 4096; window estimate %.0f <= 16384) and takes the "
-            "XLA tiled path", flat.shape[0], num_rows,
-            pallas_scatter.runnable(), 2 * bs_p, est_w)
-        mode = "tiled"
-    if mode == "tiled" and num_rows > 2 * _tile_rows() \
-            and flat.shape[0] >= 4096:
-        # below those sizes the flat scatter is already in (or near) the
-        # fast zone and tiling only adds window overhead
+    route = backward_route(
+        flat.shape[0], num_rows, pallas_scatter.runnable())
+    if route == "kernel":
+        d_table = _pallas_table_grad(*_sorted_stream(flat, cf), num_rows)
+    elif route == "tiled":
         d_table = _tiled_table_grad(*_sorted_stream(flat, cf), num_rows)
-        return d_table.astype(proto.dtype), None
-    if mode == "tiled":
+    else:
         d_table = jnp.zeros((num_rows, cf.shape[1]), jnp.float32).at[
             flat].add(cf, mode="drop")
-        return d_table.astype(proto.dtype), None
-    cf_sorted, sf = _sorted_stream(flat, cf)
-    if mode == "unique":
-        n = sf.shape[0]
-        sums, uids = _compact_sorted_duplicates(cf_sorted, sf)
-        # Empty trailing segments come back at the dtype minimum, and REAL
-        # out-of-range uids can also appear (the manual shard path's
-        # non-owned sentinels are 2x the shard size). Route every
-        # not-in-range target to a DISTINCT out-of-range row
-        # (num_rows + position) so mode="drop" discards them without ever
-        # violating the unique_indices promise below — duplicate OOB
-        # targets (e.g. a real sentinel uid colliding with a rerouted
-        # empty segment, code-review r5 pt4) would make the scatter
-        # implementation-defined on TPU
-        uids = jnp.where((uids < 0) | (uids >= num_rows),
-                         num_rows + jnp.arange(n), uids)
-        d_table = jnp.zeros((num_rows, cf.shape[1]), jnp.float32)
-        d_table = d_table.at[uids].add(
-            sums, mode="drop", unique_indices=True)
-    else:
-        d_table = jax.ops.segment_sum(
-            cf_sorted, sf, num_segments=num_rows,
-            indices_are_sorted=True,
-        )
     return d_table.astype(proto.dtype), None
 
 
@@ -484,17 +436,16 @@ def scatter_add_dense(
     dtype=jnp.float32,
 ) -> jax.Array:
     """Dense (num_rows, D) sum of `rows` placed at `ids` — the embedding
-    tier's push hot path, routed through the SAME strategy menu as the
-    training backward (`EDL_EMB_SCATTER`: pallas placement kernel with the
-    dedupe middle path, tiled fast-zone scan, sorted segment-sum, unique
-    compaction, flat XLA scatter).
+    tier's push hot path, by the SAME route as the training backward
+    (`backward_route`: the placement kernel with its dedupe middle path,
+    the tiled fast-zone scan, or the flat XLA scatter).
 
     ids: int32 (N,) — out-of-range ids (negative padding sentinels,
     anything >= num_rows) are dropped, contributing nothing. rows: (N, D)
     contribution rows. The duplicates-ADD semantics match a sparse
     gradient push: duplicate ids accumulate. Empty N is a static no-op
     (zeros). This is exactly `gather_rows`'s VJP applied to an explicit
-    cotangent, so every kernel-path guarantee (window guards, skew dedupe,
+    cotangent, so every kernel-route guarantee (window guards, skew dedupe,
     bf16 split accuracy) documented there applies here unchanged."""
     ids = jnp.asarray(ids, jnp.int32).reshape(-1)
     rows = jnp.asarray(rows)
@@ -512,10 +463,6 @@ def scatter_add_dense(
     return d_table
 
 
-def _take(table: jax.Array, ids: jax.Array) -> jax.Array:
-    if os.environ.get("EDL_EMB_SCATTER", "pallas") == "xla":
-        return jnp.take(table, ids, axis=0)
-    return gather_rows(table, ids)
 
 # Table rows are padded to a multiple of this so every device of any mesh up
 # to this many chips gets an equal shard (shard_map needs even shards).
@@ -523,9 +470,9 @@ VOCAB_ALIGN = 256
 # Large tables align to 8192 instead: the Pallas placement kernel emits
 # whole row-blocks, and a vocab that isn't block-aligned costs a 178 MB
 # epilogue slice-copy (~4 ms/step measured) to trim the padding. 8192 is
-# a multiple of every power-of-two block size the kernel sweeps, so the
-# alignment holds regardless of EDL_EMB_PALLAS_BS. Absolute overhead is
-# bounded by 8191 extra rows (~0.5 MB at D=16).
+# a multiple of the kernel's block (pallas_scatter.BLOCK_ROWS, 2048) and
+# of every power-of-two block up to itself. Absolute overhead is bounded
+# by 8191 extra rows (~0.5 MB at D=16).
 # NOTE (round-5 geometry change): tables created before this alignment
 # existed were padded to 256; their checkpoints restore only into models
 # built with the same geometry (pass align=VOCAB_ALIGN explicitly to
@@ -610,7 +557,7 @@ def embedding_lookup(
             mode = "auto"
 
     if mode == "auto" or not axes:
-        out = _take(table, safe_ids)
+        out = gather_rows(table, safe_ids)
         return jnp.where(in_range[..., None], out, 0.0)
 
     if mode != "manual":
@@ -633,7 +580,7 @@ def embedding_lookup(
             "lookup for this mesh (align the vocab via padded_vocab for the "
             "manual schedule)", table.shape[0], n_shards,
         )
-        out = _take(table, safe_ids)
+        out = gather_rows(table, safe_ids)
         return jnp.where(in_range[..., None], out, 0.0)
 
     ids2d = safe_ids.reshape(safe_ids.shape[0], -1)  # (B, L)
@@ -650,17 +597,17 @@ def embedding_lookup(
         # scatter sorts the raw ids — a row-0 pile of every non-owned id
         # (up to (n_shards-1)/n_shards of the batch) would overflow tile
         # 0's window and trip the lax.cond flat fallback EVERY step,
-        # silently making `tiled` slower than the flat scatter on exactly
-        # the multi-chip manual path it exists for (code-review r5 pt3).
+        # silently making the sorted routes slower than the flat scatter
+        # on exactly the multi-chip manual path (code-review r5 pt3).
         # 2x the shard size specifically: the tiled backward's padded
         # vocab is < 1.5x num_rows (tile_rows < num_rows/2 on that path),
         # so 2x sits beyond the last searchsorted edge and the sentinels
-        # count toward NO tile's window population; every scatter mode
-        # drops out-of-range cotangent rows.
+        # count toward NO tile's window population; every route drops
+        # out-of-range cotangent rows.
         sentinel = jnp.int32(2 * table_shard.shape[0])
         part = jnp.where(
             owned[..., None],
-            _take(table_shard, jnp.where(owned, local, sentinel)), 0.0
+            gather_rows(table_shard, jnp.where(owned, local, sentinel)), 0.0
         )  # (B, L, D)
         out = jax.lax.psum_scatter(
             part, data_ax, scatter_dimension=0, tiled=True

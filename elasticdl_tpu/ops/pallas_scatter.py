@@ -5,8 +5,8 @@ The embedding backward must place a batch's sorted gradient rows (213k a
 step for DeepFM at batch 8192) into a dense table of millions of rows.
 Every XLA formulation is bound by per-ROW transaction costs (tens of ns a
 row: scatter-add, segment-sum, and the dynamic-slice plumbing of the
-`EDL_EMB_SCATTER=tiled` schedule alike, ops/embedding.py). This kernel
-reformulates placement as BLOCKED ONE-HOT MATMUL:
+tiled schedule alike, ops/embedding.py). This kernel reformulates
+placement as BLOCKED ONE-HOT MATMUL:
 
   grid over output row-blocks (bs rows); block b DMAs the contiguous
   window of the sorted stream that searchsorted assigned to it (scalar-
@@ -15,10 +15,9 @@ reformulates placement as BLOCKED ONE-HOT MATMUL:
   chunk by chunk on the MXU. Sorted-stream windows are CONTIGUOUS, so the
   "scatter" becomes a sequential read and dense compute.
 
-What it costs (TPU v5e, `PERF.md` §6; the round-5 figures this replaces
-were a 2.6M-row table under uniform ids, thirteen times smaller than the
-benchmark's): the kernel's time follows the one-hot, blocks x bs x the
-columns a block builds it for, not the ids. With 512-column windows sent
+What it costs (TPU v5e, `PERF.md` §6): the kernel's time follows the
+one-hot, blocks x bs x the columns a block builds it for, not the ids.
+With 512-column windows sent
 through the MXU once per bf16 term it was 1.43-1.57 ps an element on all
 three tables of the benchmark — 24.7 ms a step on 33.8M rows, a quarter
 of the MXU's peak because only D of its rows carry values (ledger, PR 23).
@@ -43,11 +42,9 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -56,22 +53,17 @@ from elasticdl_tpu.ops.pallas_attention import _interpret_active, _sds
 # Output rows per grid step, and sorted-stream columns per MXU pass. The
 # one-hot's size is rows x window and the window shrinks with the block
 # until it reaches 128 columns, so smaller blocks win until per-block
-# costs bite; the block is env-tunable for sweeps. 2048 won round 5's
-# end-to-end sweep against 4096 and 8192 on a 2.6M-row table and has not
-# been swept on the benchmark's tables (PERF.md §7). A window is a whole
-# number of LANES: 128 is what Mosaic's DMA-offset proof and the MXU's
-# contraction depth both want. The loop takes CHUNK columns at a time
-# (4% faster than 128 at a time, kernel alone, PR 24) and a LANES-wide tail
-# where an odd number of them is left; bs*CHUNK bf16 one-hot (1 MB at
-# 2048x256) is the VMEM high-water mark.
-DEFAULT_BLOCK_ROWS = 2048
+# costs bite. The block is a constant: a sweep on the benchmark's tables
+# (ROADMAP A5) lands as a new constant or a value derived from the
+# shapes, not as a variable. A window is a whole number of LANES: 128 is
+# what Mosaic's DMA-offset proof and the MXU's contraction depth both
+# want. The loop takes CHUNK columns at a time (4% faster than 128 at a
+# time, kernel alone, PR 24) and a LANES-wide tail where an odd number of
+# them is left; bs*CHUNK bf16 one-hot (1 MB at 2048x256) is the VMEM
+# high-water mark.
+BLOCK_ROWS = 2048
 LANES = 128
 CHUNK = 2 * LANES
-
-
-def block_rows() -> int:
-    return int(os.environ.get(
-        "EDL_EMB_PALLAS_BS", str(DEFAULT_BLOCK_ROWS)))
 
 
 def window_cols(n: int, num_rows: int, block_rows: int, slack: float) -> int:
@@ -93,106 +85,78 @@ def window_cols(n: int, num_rows: int, block_rows: int, slack: float) -> int:
 
 
 def _kernel(firsts_ref, sf_ref, cf_ref, out_ref, ids_vmem, vec_vmem,
-            sem_ids, sem_vec, *, bs, w, d, d_out, split, group):
-    """`group` output blocks per grid step (default 1 — see the sweep
-    note in place_sorted_grads). Sub-block indices are PYTHON ints
-    (static scratch slots: the dynamic-slot double-buffer variant
-    measured 5.5x SLOWER), and a step's DMAs all start before the first
-    wait so multi-block groups overlap their transfers."""
+            sem_ids, sem_vec, *, bs, w, d, d_out):
+    """One output block per grid step: DMA the block's window of both
+    streams, one-hot its ids against the block's rows, matmul."""
     b = pl.program_id(0)
-
-    def copies(g):
-        # a window starts at its block's first id aligned DOWN to 128:
-        # Mosaic must PROVE dynamic DMA offsets land on tile boundaries,
-        # and both streams put the window dimension on LANES — ids as a
-        # (1, N) row, gradients TRANSPOSED to (D, N) (slicing the
-        # untransposed (N, D) would lane-slice a 128-padded memref, which
-        # Mosaic rejects)
-        start = pl.multiple_of(
-            firsts_ref[b * group + g] // LANES * LANES, LANES)
-        return (
-            pltpu.make_async_copy(
-                sf_ref.at[:, pl.ds(start, w)], ids_vmem.at[g],
-                sem_ids.at[g]),
-            pltpu.make_async_copy(
-                cf_ref.at[:, pl.ds(start, w)], vec_vmem.at[g],
-                sem_vec.at[g]),
-        )
-
-    for g in range(group):
-        for cp in copies(g):
-            cp.start()
-
-    for g in range(group):
-        for cp in copies(g):
-            cp.wait()
-        base = (b * group + g) * bs
-        # the accumulator is built TRANSPOSED, (D, bs): the output's
-        # row dimension must ride the 128-lane axis — a (bs, 17) block
-        # lane-pads 17 -> 128 in VMEM, a 7.5x write-bandwidth tax that
-        # was most of the kernel's cost (write-only floor 7.5 ms) and
-        # an OOM at group=8. dot_general(vec, onehot) contracting the
-        # chunk gives (D, bs) natively, no in-register transpose.
-        #
-        # The up-to-127 columns before the block's first id belong to the
-        # block before: rotate them to the window's far end, so that the
-        # one-hot is built for w - LANES columns and not for w.
-        shift = (w - firsts_ref[b * group + g] % LANES) % w
-        ids = pltpu.roll(
-            jnp.broadcast_to(ids_vmem[g], (8, w)), shift, 1)[:1] - base
-        vec = pltpu.roll(vec_vmem[g], shift, 1)
-        # One pass of the one-hot per chunk: its bs x C elements are what
-        # the MXU's time follows (only d of its rows carry values), so the
-        # two bf16 terms of the split ride through it STACKED, (2d, C),
-        # into one (2d, bs) float32 accumulator whose halves are added
-        # before the write — the same products and float32 sums as two
-        # passes, at one pass's price.
-        acc = None
-        for c0 in range(0, w - LANES, CHUNK):
-            cw = min(CHUNK, w - LANES - c0)   # a LANES tail when odd
-            vec_c = vec[:, c0:c0 + cw]                           # (D, cw)
-            onehot = (jax.lax.broadcasted_iota(jnp.int32, (bs, cw), 0)
-                      == ids[:, c0:c0 + cw]).astype(jnp.bfloat16)  # 0/1
-            hi = vec_c.astype(jnp.bfloat16)
-            if split:
-                # Two-term bf16 split of the f32 gradient values: the
-                # MXU runs bf16, and a single cast rounds the
-                # accumulated gradients to ~8 mantissa bits (0.4% rel
-                # err measured); hi+lo recovers ~16 bits (~4e-6 rel).
-                # EDL_EMB_PALLAS_PRECISION=bf16 drops lo for models
-                # already training in bf16 end to end. Stacked in f32,
-                # where d (8-aligned) is whole sublane tiles, then cast.
-                hi_f = hi.astype(jnp.float32)
-                terms = jnp.concatenate(
-                    [hi_f, vec_c - hi_f], axis=0).astype(jnp.bfloat16)
-            else:
-                terms = hi
-            part = jax.lax.dot_general(
-                terms, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            acc = part if acc is None else acc + part
-        if split:
-            acc = acc[:d] + acc[d:]
-        # d is the 8-aligned padded depth the DMA needs; the real
-        # embedding width d_out is restored in-register before the write
-        out_ref[:, g * bs:(g + 1) * bs] = acc[:d_out, :]
-
-
-def group_blocks() -> int:
-    g = int(os.environ.get("EDL_EMB_PALLAS_GROUP", "1"))
-    if g < 1:
-        raise ValueError(
-            f"EDL_EMB_PALLAS_GROUP must be >= 1, got {g}")
-    return g
+    # a window starts at its block's first id aligned DOWN to 128:
+    # Mosaic must PROVE dynamic DMA offsets land on tile boundaries,
+    # and both streams put the window dimension on LANES — ids as a
+    # (1, N) row, gradients TRANSPOSED to (D, N) (slicing the
+    # untransposed (N, D) would lane-slice a 128-padded memref, which
+    # Mosaic rejects)
+    start = pl.multiple_of(firsts_ref[b] // LANES * LANES, LANES)
+    copies = (
+        pltpu.make_async_copy(
+            sf_ref.at[:, pl.ds(start, w)], ids_vmem, sem_ids),
+        pltpu.make_async_copy(
+            cf_ref.at[:, pl.ds(start, w)], vec_vmem, sem_vec),
+    )
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()
+    base = b * bs
+    # the accumulator is built TRANSPOSED, (D, bs): the output's
+    # row dimension must ride the 128-lane axis — a (bs, 17) block
+    # lane-pads 17 -> 128 in VMEM, a 7.5x write-bandwidth tax that
+    # was most of the kernel's cost (write-only floor 7.5 ms).
+    # dot_general(vec, onehot) contracting the chunk gives (D, bs)
+    # natively, no in-register transpose.
+    #
+    # The up-to-127 columns before the block's first id belong to the
+    # block before: rotate them to the window's far end, so that the
+    # one-hot is built for w - LANES columns and not for w.
+    shift = (w - firsts_ref[b] % LANES) % w
+    ids = pltpu.roll(
+        jnp.broadcast_to(ids_vmem[...], (8, w)), shift, 1)[:1] - base
+    vec = pltpu.roll(vec_vmem[...], shift, 1)
+    # One pass of the one-hot per chunk: its bs x C elements are what
+    # the MXU's time follows (only d of its rows carry values), so the
+    # two bf16 terms of the split ride through it STACKED, (2d, C),
+    # into one (2d, bs) float32 accumulator whose halves are added
+    # before the write — the same products and float32 sums as two
+    # passes, at one pass's price.
+    acc = None
+    for c0 in range(0, w - LANES, CHUNK):
+        cw = min(CHUNK, w - LANES - c0)   # a LANES tail when odd
+        vec_c = vec[:, c0:c0 + cw]                           # (D, cw)
+        onehot = (jax.lax.broadcasted_iota(jnp.int32, (bs, cw), 0)
+                  == ids[:, c0:c0 + cw]).astype(jnp.bfloat16)  # 0/1
+        # Two-term bf16 split of the f32 gradient values: the MXU runs
+        # bf16, and a single cast rounds the accumulated gradients to
+        # ~8 mantissa bits (0.4% rel err measured, which the
+        # benchmark's check refuses); hi+lo recovers ~16 bits (~4e-6
+        # rel). Stacked in f32, where d (8-aligned) is whole sublane
+        # tiles, then cast.
+        hi_f = vec_c.astype(jnp.bfloat16).astype(jnp.float32)
+        terms = jnp.concatenate(
+            [hi_f, vec_c - hi_f], axis=0).astype(jnp.bfloat16)
+        part = jax.lax.dot_general(
+            terms, onehot, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc = part if acc is None else acc + part
+    acc = acc[:d] + acc[d:]
+    # d is the 8-aligned padded depth the DMA needs; the real
+    # embedding width d_out is restored in-register before the write
+    out_ref[...] = acc[:d_out, :]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "num_rows", "block_rows", "w", "d_out", "split", "group",
-        "interpret"))
+    static_argnames=("num_rows", "block_rows", "w", "d_out", "interpret"))
 def place_sorted_grads(cf, sf, firsts, *, num_rows, block_rows, w,
-                       d_out=None, split=True, group=1, interpret=False):
+                       d_out=None, interpret=False):
     """Dense (D, num_rows) TRANSPOSED gradient from a SORTED stream
     (the row dimension rides the 128-lane axis so output writes aren't
     lane-padded; callers transpose once at the end).
@@ -221,38 +185,23 @@ def place_sorted_grads(cf, sf, firsts, *, num_rows, block_rows, w,
         raise ValueError(f"window {w} must be a multiple of {LANES}")
     d_out = d if d_out is None else d_out
     bs = block_rows
-    nb = num_rows // bs
-    # Chip sweep (round 5: 2.6M rows, uniform ids, 512-column windows,
-    # transposed out): group 1/2/4 all ~8.3 ms, group 8 EXPLODES to
-    # ~60 ms (VMEM-pressure spill signature). The write-only "7.5 ms
-    # grid floor" that motivated grouping turned out to be the lane-
-    # padded (bs, 17) write tax the transposed output already removed.
-    # On 33.8M rows inside the DeepFM step, group 4 read 6.66 ms
-    # against 6.80 (PR 24).
-    # `group` is a STATIC arg (callers read group_blocks()) so env
-    # sweeps reach the jit cache key; legalize to a divisor of nb.
-    while nb % group:
-        group //= 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nb // group,),
+        grid=(num_rows // bs,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (d_out, bs * group), lambda b, firsts: (0, b)),
+        out_specs=pl.BlockSpec((d_out, bs), lambda b, firsts: (0, b)),
         scratch_shapes=[
-            pltpu.VMEM((group, 1, w), jnp.int32),
-            pltpu.VMEM((group, d, w), jnp.float32),
-            pltpu.SemaphoreType.DMA((group,)),
-            pltpu.SemaphoreType.DMA((group,)),
+            pltpu.VMEM((1, w), jnp.int32),
+            pltpu.VMEM((d, w), jnp.float32),
+            pltpu.SemaphoreType.DMA,
+            pltpu.SemaphoreType.DMA,
         ],
     )
     return pl.pallas_call(
-        functools.partial(
-            _kernel, bs=bs, w=w, d=d, d_out=d_out, split=split,
-            group=group),
+        functools.partial(_kernel, bs=bs, w=w, d=d, d_out=d_out),
         grid_spec=grid_spec,
         # inside the manual (shard_map) lookup schedule the output must
         # declare the mesh axes it varies over, like the cotangents do
