@@ -31,6 +31,7 @@ preprocessing.hashing) bounds the table like the reference's Hashing layer.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -219,19 +220,24 @@ def _tiled_table_grad(cf, sf, num_rows):
 
 
 def _compact_sorted_duplicates(cf_sorted, sf_sorted):
-    """Per-distinct-id sums over a SORTED contribution stream, via
-    fast-zone segment ops (both outputs are n rows, n = stream length).
-    Returns (sums (n, d), uids (n,)) where slot j holds the j-th distinct
-    id's total; trailing empty segments come back with uid = dtype min.
-    The caller applies its own out-of-range remap (the dedupe path sends
-    everything out of range to int32max)."""
-    n = sf_sorted.shape[0]
+    """Per-distinct-id sums over a SORTED contribution stream (both outputs
+    are n rows, n = stream length). Returns (sums (n, d), uids (n,)) where
+    slot j holds the j-th distinct id and its rows' total; the slots after
+    the last distinct id hold zero sums at uid = int32max, where the
+    stream's pad already is.
+
+    The j-th distinct id of a sorted vector is its j-th run start, so the
+    run starts, everything else sent to int32max, sorted once more (keys
+    only) ARE the compact ascending ids. No scatter over the stream: a
+    scatter-max (`segment_max`) costs 8.7 ns an id, 12.6 ms of xDeepFM's
+    step, where this sort takes 0.8 (PERF.md §6, PR 28)."""
     is_start = jnp.concatenate(
         [jnp.ones((1,), bool), sf_sorted[1:] != sf_sorted[:-1]])
     seg = jnp.cumsum(is_start) - 1                     # compact, sorted
     sums = _run_sums(cf_sorted, seg)
-    uids = jax.ops.segment_max(
-        sf_sorted, seg, num_segments=n, indices_are_sorted=True)
+    uids = jax.lax.sort(
+        jnp.where(is_start, sf_sorted, jnp.iinfo(sf_sorted.dtype).max),
+        is_stable=False)
     return sums, uids
 
 
@@ -298,10 +304,42 @@ def _sorted_stream(flat, cf):
     PR 26). Carrying the D columns through the sort as well was measured
     and not kept: it runs within 1 ms a step of this on every table, and
     a sort of D + 2 operands takes the compiler 200-300 s (PERF.md §6)."""
-    sf, order = jax.lax.sort(
-        (flat, jnp.arange(flat.shape[0], dtype=jnp.int32)),
-        dimension=0, is_stable=True, num_keys=1)
-    return cf[order], sf
+    with jax.named_scope("emb/bwd/sort"):
+        sf, order = jax.lax.sort(
+            (flat, jnp.arange(flat.shape[0], dtype=jnp.int32)),
+            dimension=0, is_stable=True, num_keys=1)
+        return cf[order], sf
+
+
+def _block_starts(ids, vpad, bs):
+    """`jnp.searchsorted(ids, arange(0, vpad + 1, bs))` (side "left") for
+    SORTED int32 `ids`: edges[b] = how many ids lie below row b * bs, the
+    column where block b begins. Straight-line code, exact on any sorted
+    input (duplicates, empty blocks, sentinels beyond `vpad`).
+
+    `searchsorted` itself is a loop of log2(n) dependent steps, each a
+    gather of one scalar per block: 1.97 ms on deepfm-criteo's 16.5k
+    blocks where this takes 0.11 (PERF.md §6, PR 28). Here the ids are read
+    as lines of `line` ids (the last one padded with int32max). All lines
+    whose FIRST id is below a query lie below it entirely, except the last
+    of them, which holds the edge: count those lines (a compare of every
+    query with every line's first id), fetch that one line per query, and
+    count inside it. The work is queries x (n / line + line), least at
+    line = sqrt(n): the power of two nearest to it, in whole 128-lane
+    rows, from the stream's length alone."""
+    n = ids.shape[0]
+    imax = jnp.iinfo(jnp.int32).max
+    queries = jnp.arange(0, vpad + 1, bs, dtype=jnp.int32)
+    line = 128 * max(1, 2 ** round(math.log2(math.sqrt(n) / 128)))
+    m = -(-n // line)
+    lines = jnp.pad(
+        ids, (0, m * line - n), constant_values=imax).reshape(m, line)
+    below = jnp.sum(lines[:, 0][None, :] < queries[:, None], axis=1,
+                    dtype=jnp.int32)
+    last = jnp.maximum(below - 1, 0)       # no line below: line 0 counts 0
+    inside = jnp.sum(lines[last] < queries[:, None], axis=1,
+                     dtype=jnp.int32)
+    return last * line + inside
 
 
 def _kernel_stream(rows, ids, w, vpad, bs):
@@ -333,9 +371,8 @@ def _kernel_stream(rows, ids, w, vpad, bs):
         jnp.concatenate([rows.T, jnp.zeros((d8 - d, n), rows.dtype)], axis=0),
         jnp.zeros((d8, w), rows.dtype),
     ], axis=1)
-    edges = jnp.searchsorted(
-        ids, jnp.arange(0, vpad + 1, bs, dtype=jnp.int32)
-    ).astype(jnp.int32)
+    with jax.named_scope("emb/bwd/edges"):
+        edges = _block_starts(ids, vpad, bs)
     return cf_t, sf_pad, edges
 
 
@@ -358,14 +395,15 @@ def _pallas_table_grad(cf, sf, num_rows):
     def pallas_branch(cf_t, sf_pad, edges):
         from elasticdl_tpu.ops.pallas_attention import kernel_interpret
 
-        out_t = pallas_scatter.place_sorted_grads(
-            cf_t, sf_pad[None, :], edges[:-1],
-            num_rows=vpad, block_rows=bs, w=w, d_out=d,
-            interpret=kernel_interpret(),
-        )
-        # kernel emits (D, vpad) — rows on lanes, see pallas_scatter —
-        # one bandwidth-class transpose restores the param layout
-        return out_t[:, :num_rows].T
+        with jax.named_scope("emb/bwd/place"):
+            out_t = pallas_scatter.place_sorted_grads(
+                cf_t, sf_pad[None, :], edges[:-1],
+                num_rows=vpad, block_rows=bs, w=w, d_out=d,
+                interpret=kernel_interpret(),
+            )
+            # kernel emits (D, vpad) — rows on lanes, see pallas_scatter —
+            # one bandwidth-class transpose restores the param layout
+            return out_t[:, :num_rows].T
 
     def flat(cf_t, sf_pad, edges):
         del edges
@@ -383,14 +421,15 @@ def _pallas_table_grad(cf, sf, num_rows):
         comes this way (PERF.md §5). A final flat fallback remains for
         adversarially CLUSTERED distinct ids."""
         del edges
-        sums, uids = _compact_sorted_duplicates(
-            cf_t[:d, :n].T, sf_pad[:n])
-        # empty trailing segments (dtype min) and real out-of-range ids
-        # (manual-path sentinels; their cotangents are zero) both go to
-        # int32max: sorted with the pad, matching no window, dropped by
-        # every placement below
-        uids = jnp.where((uids < 0) | (uids >= num_rows),
-                         jnp.iinfo(jnp.int32).max, uids)
+        with jax.named_scope("emb/bwd/dedupe"):
+            sums, uids = _compact_sorted_duplicates(
+                cf_t[:d, :n].T, sf_pad[:n])
+            # real out-of-range ids (manual-path sentinels; their
+            # cotangents are zero) join the empty trailing slots at
+            # int32max: sorted with the pad, matching no window, dropped
+            # by every placement below
+            uids = jnp.where(
+                uids >= num_rows, jnp.iinfo(jnp.int32).max, uids)
         cf2_t, sf2, edges2 = _kernel_stream(sums, uids, w, vpad, bs)
         max_pop2 = jnp.max(edges2[1:] - edges2[:-1])
         return jax.lax.cond(
@@ -409,9 +448,9 @@ def _gather_rows_bwd(res, ct):
     from elasticdl_tpu.ops import pallas_scatter
 
     ids, proto, num_rows = res
-    # int32: the dedupe path's empty-segment sentinel relies on signed
-    # comparisons (an unsigned dtype would make `uids < 0` vacuous and
-    # send sentinel rows to row 0); vocab sizes are far below 2^31
+    # int32: the stream's pad and the dedupe path's empty slots are
+    # int32max, and the kernel subtracts block bases from the ids; vocab
+    # sizes are far below 2^31
     flat = ids.reshape(-1).astype(jnp.int32)
     cf = ct.reshape(-1, ct.shape[-1]).astype(jnp.float32)
     if flat.shape[0] == 0:  # static: empty batch, zero gradient

@@ -101,14 +101,14 @@ def test_gather_rows_backward_matches_take_vjp():
 def _check_compaction(sums, uids, sf, cf):
     """`_compact_sorted_duplicates`' contract against numpy: slot j holds
     the j-th distinct id and the sum of its rows; the slots after the last
-    distinct id hold zero sums at the dtype's minimum, which the dedupe
-    path's `uids < 0` remap sends out of every window."""
+    distinct id hold zero sums at int32max, where the stream's pad is, so
+    the ids stay ascending to the end."""
     want_ids, inverse = np.unique(sf, return_inverse=True)
     want_sums = np.zeros((sf.size, cf.shape[1]), np.float32)
     np.add.at(want_sums, inverse, cf)
     k = want_ids.size
     np.testing.assert_array_equal(uids[:k], want_ids)
-    assert (uids[k:] == np.iinfo(np.int32).min).all()
+    assert (uids[k:] == np.iinfo(np.int32).max).all()
     np.testing.assert_allclose(sums, want_sums, rtol=1e-6, atol=1e-6)
     assert not sums[k:].any()
 
@@ -124,9 +124,9 @@ def _check_compaction(sums, uids, sf, cf):
 )
 def test_compact_sorted_duplicates_matches_numpy(ids_np):
     """The dedupe path's compaction (sorted boundary cumsum -> per-run
-    sums -> per-run id) must equal `np.unique` + `np.add.at` across
-    duplicate-heavy, distinct and degenerate id patterns, trailing empty
-    segments included."""
+    sums; run starts sorted once more -> per-run id) must equal
+    `np.unique` + `np.add.at` across duplicate-heavy, distinct and
+    degenerate id patterns, trailing empty slots included."""
     r = np.random.RandomState(ids_np.size)
     cf = r.randn(ids_np.size, 16).astype(np.float32)
     cf_sorted, sf = emb_ops._sorted_stream(
@@ -135,6 +135,35 @@ def test_compact_sorted_duplicates_matches_numpy(ids_np):
     assert sums.shape == cf.shape and uids.dtype == jnp.int32
     _check_compaction(np.asarray(sums), np.asarray(uids),
                       np.asarray(sf), np.asarray(cf_sorted))
+
+
+@pytest.mark.parametrize("ids_kind", ["oob_pad", "shard_sentinels"])
+def test_compact_sorted_duplicates_out_of_range_ids(ids_kind):
+    """Out-of-range ids (`embedding_lookup`'s int32max // 2, the manual
+    path's 2 x shard rows) are the LAST run of the sorted stream: they
+    take the last distinct slot, `dedupe_then_place`'s remap sends that
+    slot to int32max beside the empty ones, the remapped ids stay
+    ascending (the block starts are searched in them), and `sums` slot j
+    is still `uids` slot j's run."""
+    r = np.random.RandomState(5)
+    n, rows, d = 5000, 900, 11
+    ids = _stream_case_ids(ids_kind, n, rows, r)
+    cf = np.where((ids < rows)[:, None], r.randn(n, d), 0).astype(np.float32)
+    cf_sorted, sf = emb_ops._sorted_stream(jnp.asarray(ids), jnp.asarray(cf))
+    sums, uids = jax.jit(emb_ops._compact_sorted_duplicates)(cf_sorted, sf)
+    sums, uids = np.asarray(sums), np.asarray(uids)
+    _check_compaction(sums, uids, np.asarray(sf), np.asarray(cf_sorted))
+    k = np.unique(ids).size
+    assert uids[k - 1] == ids.max() >= rows and uids[k - 2] < rows
+    remapped = np.where(uids >= rows, np.iinfo(np.int32).max, uids)
+    assert (np.diff(remapped) >= 0).all()
+    assert (remapped[k - 1:] == np.iinfo(np.int32).max).all()
+    assert not sums[k - 1:].any()
+    want = np.zeros((rows, d), np.float32)
+    np.add.at(want, ids[ids < rows], cf[ids < rows])
+    got = np.zeros((rows, d), np.float32)
+    got[remapped[:k - 1]] = sums[:k - 1]
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize(
@@ -241,20 +270,29 @@ def _all_eqns(jaxpr):
             yield from _all_eqns(sub)
 
 
-@pytest.mark.parametrize("route", ["kernel", "tiled"])
-def test_backward_holds_no_stream_long_id_gather_or_scatter(route):
+@pytest.mark.parametrize("route,n,rows", [
+    ("kernel", 55296 * 26, 2_605_056),      # xdeepfm-criteo
+    ("kernel", 8192 * 26, 33_800_192),      # deepfm-criteo
+    ("tiled", 55296 * 26, 2_605_056),
+])
+def test_backward_holds_no_stream_long_id_gather_or_scatter(route, n, rows):
     """At xDeepFM's shape (55 296 x 26 = 1 437 696 ids x 11 columns into
-    2 605 056 rows; abstract values, nothing runs) the backward holds ONE
-    sort, of (ids, positions), and neither of the two operations that
-    cost it 102 ms a step there: no gather of N ids out of the N-long id
-    vector (`flat[order]`, 10.25 ms: the sort's own first output is that)
-    and no scatter-add into an N-row output (the dedupe's run sums, 92.3
-    ms: `_run_sums` goes in chunks that fit the fast zone). Neither can
-    come back unnoticed by a CPU-only check."""
+    2 605 056 rows) and deepfm's (212 992 ids into 33.8M rows; abstract
+    values, nothing runs) the backward holds ONE stable sort, of (ids,
+    positions), and none of the operations that cost it 102 ms a step
+    there: no gather of N ids out of the N-long id vector (`flat[order]`,
+    10.25 ms: the sort's own first output is that) and no scatter-add into
+    an N-row output past the fast zone (the dedupe's run sums, 92.3 ms:
+    `_run_sums` goes in chunks that fit). The kernel route moreover holds
+    no scatter of any kind over the N-long id vector (the distinct ids'
+    `segment_max` was a scatter-max, 12.6 ms: they are one keys-only sort
+    of the run starts) and no loop but `_run_sums`' scan (the block starts'
+    two `searchsorted` loops, 2 x 1.97 ms on deepfm: `_block_starts` is
+    straight-line). None can come back unnoticed by a CPU-only check."""
     from elasticdl_tpu.ops import pallas_scatter as ps
     from elasticdl_tpu.ops.pallas_attention import interpret_mode
 
-    n, d, rows = 55296 * 26, 11, 2_605_056
+    d = 11
     # the kernel's route where it can run, the tiled one where it cannot
     with interpret_mode() if route == "kernel" else contextlib.nullcontext():
         assert emb_ops.backward_route(n, rows, ps.runnable()) == route
@@ -263,20 +301,39 @@ def test_backward_holds_no_stream_long_id_gather_or_scatter(route):
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n, d), jnp.float32))
     eqns = list(_all_eqns(jaxpr.jaxpr))
-    sorts = [e for e in eqns if e.primitive.name == "sort"]
-    assert [(len(e.invars), e.params["num_keys"], e.params["is_stable"])
-            for e in sorts] == [(2, 1, True)]
+    sorts = sorted(
+        (len(e.invars), e.params["num_keys"], e.params["is_stable"])
+        for e in eqns if e.primitive.name == "sort")
+    assert sorts[-1] == (2, 1, True)
+    # beside it at most the dedupe's keys-only sort of the run starts
+    assert [s[0] for s in sorts[:-1]] == [1] * (route == "kernel")
     id_gathers = [
         e for e in eqns if e.primitive.name == "gather"
         and e.invars[0].aval.shape == (n,)
         and e.outvars[0].aval.shape == (n,)]
     assert not id_gathers
+    run_sums_chunks = n * 512 > emb_ops.FAST_SCATTER_BYTES
     stream_scatters = [
         e for e in eqns if e.primitive.name == "scatter-add"
         and e.invars[0].aval.shape == (n, d)]
-    assert not stream_scatters
+    # deepfm's stream fits the fast zone: its run sums are one segment_sum
+    assert len(stream_scatters) == (
+        route == "kernel" and not run_sums_chunks)
     assert any(e.primitive.name == "pallas_call" for e in eqns) == (
         route == "kernel")
+    if route != "kernel":     # `tiled` keeps its searchsorted: no cell
+        return
+    id_scatters = [
+        e for e in eqns if e.primitive.name.startswith("scatter")
+        and any(v.aval.shape == (n,)
+                and jnp.issubdtype(v.aval.dtype, jnp.integer)
+                for v in e.invars)]
+    assert not id_scatters
+    loops = [e for e in eqns if e.primitive.name in ("while", "scan")]
+    assert [e.primitive.name for e in loops] == ["scan"] * run_sums_chunks
+    # that scan is `_run_sums`': it consumes the (chunks, rows, d) stream
+    assert all(any(v.aval.shape[2:] == (d,) for v in e.invars)
+               for e in loops)
 
 
 @pytest.mark.parametrize("n,chunk_rows", [
@@ -320,6 +377,84 @@ def test_run_sums_in_chunks_equal_one_segment_sum(monkeypatch, n, d, fast_rows):
     assert ("scan" in str(jaxpr)) == (n > fast_rows)
     got = jax.jit(lambda a, b: emb_ops._run_sums(a, b))(cf, seg)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _block_starts_case(kind, r):
+    """(sorted int32 ids, rows, block) for test_block_starts_equal_searchsorted."""
+    imax = np.iinfo(np.int32).max
+    n, rows, bs = 5000, 16384, 256
+    if kind == "zipf_runs":
+        ids = np.minimum(r.zipf(1.1, n) - 1, rows - 1)
+    elif kind == "all_equal":
+        ids = np.full(n, 4321)
+    elif kind == "empty_blocks_in_the_middle":
+        ids = np.where(r.rand(n) < 0.5, r.randint(0, bs, n),
+                       r.randint(rows - 3 * bs, rows - 2 * bs, n))
+    elif kind == "all_in_the_last_block":
+        ids = r.randint(rows - bs, rows, n)
+    elif kind == "int32max_tail":        # the deduped stream's empty slots
+        ids = np.where(np.arange(n) < 1500, r.randint(0, rows, n), imax)
+    elif kind == "oob_pad":
+        ids = np.where(r.rand(n) < 0.3, imax // 2, r.randint(0, rows, n))
+    elif kind == "shard_sentinels":
+        ids = np.where(r.rand(n) < 0.75, 2 * rows, r.randint(0, rows, n))
+    elif kind == "negative_pads":        # sort first, before block 0
+        ids = np.where(r.rand(n) < 0.2, -1, r.randint(0, rows, n))
+    elif kind == "rows_not_whole_blocks":
+        rows = 16384 - 100
+        ids = r.randint(0, rows, n)
+    elif kind == "below_one_line":
+        ids = r.randint(0, rows, 50)
+    elif kind == "one_id":
+        ids = np.asarray([rows - 1])
+    elif kind == "not_whole_lines":
+        ids = r.randint(0, rows, 5001)
+    else:
+        assert kind == "wider_line"      # 70 001 ids: lines of 256
+        ids = np.minimum(r.zipf(1.1, 70001) - 1, rows - 1)
+    return np.sort(ids).astype(np.int32), rows, bs
+
+
+@pytest.mark.parametrize("kind", [
+    "zipf_runs", "all_equal", "empty_blocks_in_the_middle",
+    "all_in_the_last_block", "int32max_tail", "oob_pad", "shard_sentinels",
+    "negative_pads", "rows_not_whole_blocks", "below_one_line", "one_id",
+    "not_whole_lines", "wider_line"])
+def test_block_starts_equal_searchsorted(kind):
+    """`_block_starts` IS `searchsorted(ids, arange(0, vpad + 1, bs))`,
+    side "left", on every stream the kernel route can see: the raw sorted
+    ids and the deduped ones with their int32max tail."""
+    ids, rows, bs = _block_starts_case(kind, np.random.RandomState(17))
+    vpad = -(-rows // bs) * bs
+    got = jax.jit(emb_ops._block_starts, static_argnums=(1, 2))(
+        jnp.asarray(ids), vpad, bs)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(
+        np.asarray(got), np.searchsorted(ids, np.arange(0, vpad + 1, bs)))
+
+
+@pytest.mark.parametrize("n,rows,line", [
+    (8192 * 26, 33_800_192, 512),       # deepfm-criteo: 16 504 blocks
+    (32768 * 26, 23_472_128, 1024),     # deepfm-criteo1tb, a shard: 11 461
+    (55296 * 26, 2_605_056, 1024),      # xdeepfm-criteo: 1 272
+])
+def test_block_starts_at_the_benchmark_streams(n, rows, line):
+    """At the benchmark's three streams (abstract values, nothing runs)
+    the block starts are straight-line code: no loop, no sort, no scatter;
+    one gather, of a whole line of about sqrt(n) ids per block."""
+    from elasticdl_tpu.ops import pallas_scatter as ps
+
+    bs = ps.BLOCK_ROWS
+    jaxpr = jax.make_jaxpr(
+        lambda ids: emb_ops._block_starts(ids, rows, bs))(
+        jax.ShapeDtypeStruct((n,), jnp.int32))
+    assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [(rows // bs + 1,)]
+    eqns = list(_all_eqns(jaxpr.jaxpr))
+    names = {e.primitive.name for e in eqns}
+    assert not names & {"while", "scan", "sort", "cond"}
+    assert not any(name.startswith("scatter") for name in names)
+    assert [e.outvars[0].aval.shape for e in eqns
+            if e.primitive.name == "gather"] == [(rows // bs + 1, line)]
 
 
 def _block_firsts_and_pops(ids_np, V, bs):

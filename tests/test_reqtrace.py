@@ -262,7 +262,9 @@ def _populate_singleton():
     rec.finish(d, "error", "boom")
     d = rec.start("pull", owner=1)
     with reqtrace.stage("hedge"):
-        _spin(0.004)
+        # ten times the wire stage, not twice: under six loaded workers a
+        # preemption inside the 2 ms spin made "wire" the dominant stage
+        _spin(0.02)
     reqtrace.event("hedge_win", owner=1)
     rec.finish(d, "degraded")
     return rec
