@@ -41,7 +41,9 @@ NEG_BIG = -1e30  # finite "-inf": avoids nan from (-inf) - (-inf) in softmax
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    causal: bool = True,
                    q_offset: int = 0, kv_offset: int = 0) -> jax.Array:
-    """Plain softmax attention. q,k,v: (B, T, H, D). The offsets position the
+    """Plain softmax attention. q: (B, T, H, D); k, v: (B, T, Hkv, D) with H a
+    multiple of Hkv (grouped-query attention: query head h attends with
+    key-value head h // (H/Hkv); Hkv == H is the ordinary case). The offsets position the
     local q/kv blocks in the GLOBAL sequence for causal masking (used by the
     sequence-parallel paths; leave 0 for unsharded attention).
 
@@ -63,6 +65,8 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return pallas_attention.flash_attention(
             q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset)
     scale = q.shape[-1] ** -0.5
+    if k.shape[2] != q.shape[2]:
+        return _grouped_query_attention(q, k, v, causal, q_offset, kv_offset)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     s = s * scale
     if causal:
@@ -74,6 +78,27 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
+
+
+def _grouped_query_attention(q, k, v, causal, q_offset, kv_offset):
+    """The XLA fallback with fewer key-value heads than query heads: the
+    query heads are viewed as (Hkv, group) and each group shares its k, v."""
+    b, tq, h, d = q.shape
+    kv_heads = k.shape[2]
+    if h % kv_heads:
+        raise ValueError(f"{h} query heads do not divide over {kv_heads} "
+                         "key-value heads")
+    qg = q.reshape(b, tq, kv_heads, h // kv_heads, d)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                   preferred_element_type=jnp.float32) * d ** -0.5
+    if causal:
+        q_pos = q_offset + jnp.arange(tq)
+        kv_pos = kv_offset + jnp.arange(k.shape[1])
+        s = jnp.where(kv_pos[None, :] <= q_pos[:, None], s, NEG_BIG)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(q.shape).astype(q.dtype)
 
 
 def _ring_scan(k, v, axis_name: str, manual_axes, consume, carry0):

@@ -18,11 +18,28 @@ add to the task loss.
 
 `dropless_moe` is the other dispatch: top-k, no capacity, no drops — what
 sparse-expert LMs are trained with today (OLMoE, `model_zoo/transformer/
-olmoe.py`). The N·k (token, slot) pairs are sorted by expert, the rows
-gathered into that order, each expert's contiguous group multiplied by its
-own matrices (`jax.lax.ragged_dot`, which libtpu lowers to a Mosaic grouped
-matmul), and the result gathered back and summed over the k slots. Shapes
-are static: always N·k rows, whatever the routing.
+olmoe.py`; Nemotron-H, `nemotron_h.py`). The N·k (token, slot) pairs are
+sorted by expert, the rows gathered into that order, each expert's contiguous
+group multiplied by its own matrices (`jax.lax.ragged_dot`, which libtpu
+lowers to a Mosaic grouped matmul), and the result brought back and summed
+over the k slots. The expert body is what the caller's matrices make it:
+three of them a gated SiLU unit (`W_down(silu(W_gate x) ⊙ W_up x)`), two a
+relu² unit (`W_down relu(W_up x)²`). Two routers come with it: `topk_route`
+(softmax, weights as they are) and `sigmoid_topk_route` (sigmoid scores, a
+selection bias, weights renormalised and scaled).
+
+`held = (first, count)` tells the function WHICH experts it holds, the cut
+that expert parallelism makes: the router still chooses among all experts,
+the pairs of the `count` held ones are computed — every one of them — and
+the others add nothing here (on their own chips they would). Then the work
+follows the pairs held, not N·k: the held pairs sort to the front and are
+taken in equal PASSES of `held_pass_rows` rows — twice the held experts' even
+share of the pairs — each gathered, multiplied, weighted and scatter-added to
+its tokens, as many passes as the held pairs fill: a `lax.while_loop`, forward
+and backward, so nothing is ever dropped, nothing is sized for the worst case,
+and a step on which more pairs land is slower by the passes it adds, not
+wrong. Without `held` every expert is held and the shapes are static at N·k
+rows, whatever the routing.
 """
 
 from __future__ import annotations
@@ -119,6 +136,19 @@ def topk_route(logits: jax.Array, k: int):
     return probs, weights, expert_idx
 
 
+def sigmoid_topk_route(logits: jax.Array, bias: jax.Array, k: int, scale: float):
+    """Sigmoid router over float32 logits (N, E): (scores (N, E), weights
+    (N, k), expert_idx (N, k)). The k experts with the largest `score + bias`
+    are chosen — the bias selects and does not weigh — and their weights are
+    the scores renormalised to sum to one, times `scale`
+    (`norm_topk_prob: true`, `routed_scaling_factor`)."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, expert_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return scores, weights, expert_idx
+
+
 def router_aux_losses(logits: jax.Array, probs: jax.Array,
                       expert_idx: jax.Array):
     """(load balance, router z-loss), both unweighted. Load balance is
@@ -185,25 +215,142 @@ def _rows_to_pair_order_bwd(order, g):
 _rows_to_pair_order.defvjp(_rows_to_pair_order_fwd, _rows_to_pair_order_bwd)
 
 
+def _expert_body(xs, experts, group_sizes, dt):
+    """Rows in expert order through their experts' matrices: three matrices
+    are a gated SiLU unit, two a relu² unit."""
+    if len(experts) == 3:
+        w_gate, w_up, w_down = experts
+        gate = jax.lax.ragged_dot(xs, w_gate.astype(dt), group_sizes)
+        up = jax.lax.ragged_dot(xs, w_up.astype(dt), group_sizes)
+        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(dt)
+    else:
+        w_up, w_down = experts
+        up = jax.lax.ragged_dot(xs, w_up.astype(dt), group_sizes)
+        hidden = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dt)
+    return jax.lax.ragged_dot(hidden, w_down.astype(dt), group_sizes)
+
+
+def held_pass_rows(pairs: int, num_experts: int, count: int) -> int:
+    """The rows of one pass of a held dispatch: twice the held experts' even
+    share of the pairs, in whole 512s (a grouped matmul's row tile), and never
+    more than all pairs. The grouped matmuls' time follows the rows of the
+    passes run, not the pairs in them."""
+    return min(pairs, 512 * max(1, -(-2 * pairs * count // (512 * num_experts))))
+
+
+def _held_pass(y, xd, flat_weights, experts, order, starts, ends, lo, k, rows):
+    """y (N, C) float32 plus rows lo..lo+rows of the sorted order through
+    their experts, each weighted and added to its token. The rows past the
+    last held pair ride in the last group with weight zero, so every row is
+    defined (a `ragged_dot` leaves rows outside its groups undefined)."""
+    hi = lo + rows
+    with jax.named_scope("dispatch"):
+        pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+        group_sizes = jnp.clip(ends, lo, hi) - jnp.clip(starts, lo, hi)
+        group_sizes = group_sizes.at[-1].add(rows - jnp.sum(group_sizes))
+        tokens = pair // k
+        xs = _take_rows(xd, tokens)
+    with jax.named_scope("experts"):
+        ys = _expert_body(xs, experts, group_sizes, xd.dtype)
+    with jax.named_scope("combine"):
+        live = lo + jnp.arange(rows, dtype=jnp.int32) < ends[-1]
+        w = jnp.where(live, _take_rows(flat_weights, pair), 0.0)
+        return y.at[tokens].add(ys.astype(jnp.float32) * w[:, None])
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _held_passes(xd, flat_weights, experts, order, starts, ends, k, rows):
+    """Σ over the passes the held pairs fill of `_held_pass`: xd (N, C),
+    flat_weights (N·k,) float32, `order` the pairs with the held ones first
+    and `rows` entries of padding, starts / ends (count,) of the held experts'
+    groups in it. (N, C) float32."""
+    def one_more(carry):
+        i, y = carry
+        return i + 1, _held_pass(y, xd, flat_weights, experts, order, starts, ends,
+                                 i * rows, k, rows)
+
+    zero = jnp.zeros(xd.shape, jnp.float32)
+    return jax.lax.while_loop(lambda c: c[0] * rows < ends[-1], one_more,
+                              (jnp.int32(0), zero))[1]
+
+
+def _held_passes_fwd(xd, flat_weights, experts, order, starts, ends, k, rows):
+    return (_held_passes(xd, flat_weights, experts, order, starts, ends, k, rows),
+            (xd, flat_weights, experts, order, starts, ends))
+
+
+def _held_passes_bwd(k, rows, res, g):
+    # the same passes again: each is recomputed and transposed, its
+    # cotangents added up in float32 — nothing is kept from the forward loop
+    xd, flat_weights, experts, order, starts, ends = res
+    zero = jnp.zeros_like(g)
+    f32 = lambda tree: jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, jnp.float32), tree)
+
+    def one_more(carry):
+        i, sums = carry
+        _, transpose = jax.vjp(
+            lambda a, b, c: _held_pass(zero, a, b, c, order, starts, ends,
+                                       i * rows, k, rows),
+            xd, flat_weights, experts)
+        with jax.named_scope("experts"):
+            return i + 1, jax.tree_util.tree_map(
+                lambda s, d: s + d.astype(jnp.float32), sums, transpose(g))
+
+    _, sums = jax.lax.while_loop(
+        lambda c: c[0] * rows < ends[-1], one_more,
+        (jnp.int32(0), f32((xd, flat_weights, experts))))
+    dx, dw, dexperts = jax.tree_util.tree_map(
+        lambda s, a: s.astype(a.dtype), sums, (xd, flat_weights, experts))
+    return dx, dw, dexperts, None, None, None
+
+
+_held_passes.defvjp(_held_passes_fwd, _held_passes_bwd)
+
+
+def _held_moe(x, expert_idx, weights, experts, num_experts, held, dt):
+    k = expert_idx.shape[1]
+    first, count = held
+    rows = held_pass_rows(expert_idx.size, num_experts, count)
+    with jax.named_scope("dispatch"):
+        local = expert_idx.reshape(-1).astype(jnp.int32) - first
+        is_held = (local >= 0) & (local < count)
+        key = jnp.where(is_held, local, count)               # the others sort last
+        # a pass reads `rows` entries wherever it starts
+        order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32), (0, rows))
+        sizes = pairs_per_expert(key, count)
+        ends = jnp.cumsum(sizes)
+    return _held_passes(
+        x.astype(dt), weights.reshape(-1).astype(jnp.float32),
+        tuple(w.astype(dt) for w in experts), order, ends - sizes, ends, k, rows)
+
+
 def dropless_moe(
     x: jax.Array,            # (N, C) tokens
     expert_idx: jax.Array,   # (N, k) int32, the experts of each token
     weights: jax.Array,      # (N, k) float32, the weight of each slot
-    w_gate: jax.Array,       # (E, C, H)
-    w_up: jax.Array,         # (E, C, H)
-    w_down: jax.Array,       # (E, H, C)
+    experts,                 # (w_gate, w_up, w_down) or (w_up, w_down); up-type
+                             # (E, C, H), down (E, H, C)
+    held=None,               # (first, count) of `num_experts`; None: all held
+    num_experts: int = 0,    # what the router chose among; needed with `held`
     compute_dtype=jnp.bfloat16,
 ) -> jax.Array:
-    """y_n = Σ_slot weights[n, slot] · W_down,e( silu(W_gate,e x_n) ⊙ W_up,e x_n )
-    with e = expert_idx[n, slot]: every (token, slot) pair is computed, no
-    capacity, no padding token. Returns (N, C) float32.
+    """y_n = Σ_slot weights[n, slot] · expert_e(x_n) with e = expert_idx[n,
+    slot], over the experts held here: every (token, slot) pair of a held
+    expert is computed, no capacity, no padding token. Returns (N, C) float32.
 
     Matmuls run in `compute_dtype` (float32 accumulation on the MXU), the
     weighted sum over slots in float32."""
     n, c = x.shape
     k = expert_idx.shape[1]
-    e = w_gate.shape[0]
+    e = experts[0].shape[0]
     dt = compute_dtype
+    if held is not None and tuple(held) != (0, num_experts or e):
+        if held[1] != e or not num_experts:
+            raise ValueError(f"held={held} of num_experts={num_experts} with "
+                             f"matrices of {e} experts")
+        return _held_moe(x, expert_idx, weights, experts, num_experts, held, dt)
     with jax.named_scope("dispatch"):
         flat = expert_idx.reshape(-1).astype(jnp.int32)      # pair p = (p // k, p % k)
         order = jnp.argsort(flat, stable=True)               # pairs by expert
@@ -211,11 +358,7 @@ def dropless_moe(
         group_sizes = pairs_per_expert(flat, e)
         xs = _rows_to_expert_order(x.astype(dt), order, inverse, k)
     with jax.named_scope("experts"):
-        gate = jax.lax.ragged_dot(xs, w_gate.astype(dt), group_sizes)
-        up = jax.lax.ragged_dot(xs, w_up.astype(dt), group_sizes)
-        hidden = (jax.nn.silu(gate.astype(jnp.float32))
-                  * up.astype(jnp.float32)).astype(dt)
-        ys = jax.lax.ragged_dot(hidden, w_down.astype(dt), group_sizes)
+        ys = _expert_body(xs, experts, group_sizes, dt)
     with jax.named_scope("combine"):
         pairs = _rows_to_pair_order(ys, order, inverse).reshape(n, k, c)
         return jnp.sum(pairs.astype(jnp.float32)

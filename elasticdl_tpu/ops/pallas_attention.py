@@ -24,6 +24,14 @@ passes a different kv offset each ppermute rotation. `flash_attention_lse`
 additionally returns the logsumexp, which is what lets ring attention merge
 per-block flash results exactly (see ops.attention._ring_attention_flash).
 
+Grouped-query attention: k and v may carry FEWER heads than q (B, T, Hkv, D
+with H a multiple of Hkv); query head h reads key-value head h // (H/Hkv)
+through the K/V BlockSpec index maps, so no copy of K or V widened to H
+heads exists in HBM, forward or backward. dK/dV of a key-value head are
+summed over its group's query heads inside the dkv kernel (the group's heads
+and q blocks share the innermost, revisiting grid axis). With H == Hkv the
+three kernels are what they were.
+
 Fully-masked causal blocks are skipped (`pl.when`), giving the ~2x causal
 FLOP saving without dynamic shapes. Fully-masked ROWS (a q block entirely
 before every kv position) return 0 with lse=NEG_BIG, unlike the XLA path's
@@ -177,6 +185,17 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _kv_head_of(heads: int, kv_heads: int):
+    """query head -> the key-value head it reads (grouped-query attention:
+    `heads // kv_heads` query heads share one). The identity, with no
+    arithmetic in the index map, when every query head has its own."""
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads do not divide over "
+                         f"{kv_heads} key-value heads")
+    group = heads // kv_heads
+    return (lambda h: h) if group == 1 else (lambda h: h // group)
+
+
 # ---------------------------------------------------------------- forward
 
 
@@ -244,13 +263,15 @@ def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret):
     Tk = kt.shape[2]
     num_q, num_kv = Tq // bq, Tk // bk
     scale = D ** -0.5
+    kv_head = _kv_head_of(H, kt.shape[1])
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
         block_q=bq, block_k=bk, num_kv=num_kv,
     )
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, offs: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j, offs: (b, h, j, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D),
+                           lambda b, h, i, j, offs: (b, kv_head(h), j, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, H, num_q, num_kv),
@@ -345,13 +366,16 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                     glse_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                    causal, block_q, block_k, num_q):
+                    causal, block_q, block_k, num_q, group=1):
     kv = pl.program_id(2)
-    qi = pl.program_id(3)
+    # the innermost axis walks the q blocks of every query head that reads
+    # this key-value head: `group` heads of num_q blocks each
+    inner = pl.program_id(3)
+    qi = inner if group == 1 else inner % num_q
     q_off = offs_ref[0]
     kv_off = offs_ref[1]
 
-    @pl.when(qi == 0)
+    @pl.when(inner == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -382,7 +406,7 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         ) * scale                                          # (bk, D)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(inner == group * num_q - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -393,9 +417,10 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
     or None (out-only variant)."""
     offs, qt, kt, vt, ot, lse = res              # (B, H, T, D) / lse 4D
     B, H, Tq, D = qt.shape
-    Tk = kt.shape[2]
+    Hkv, Tk = kt.shape[1], kt.shape[2]
     num_q, num_kv = Tq // bq, Tk // bk
     scale = D ** -0.5
+    kv_head, group = _kv_head_of(H, Hkv), H // Hkv
     gt = g.transpose(0, 2, 1, 3)                 # (B, H, Tq, D)
     with_glse = g_lse is not None
     extra = ()
@@ -411,7 +436,8 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
                        block_q=bq, block_k=bk, num_kv=num_kv)
 
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, offs: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j, offs: (b, h, j, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D),
+                           lambda b, h, i, j, offs: (b, kv_head(h), j, 0))
     lse_spec = pl.BlockSpec((1, 1, bq, _LANE),
                             lambda b, h, i, j, offs: (b, h, i, 0))
 
@@ -439,17 +465,22 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
         glse_ref, tail = (rest[0], rest[1:]) if with_glse else (None, rest)
         _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
                         lse_ref, glse_ref, *tail, scale=scale, causal=causal,
-                        block_q=bq, block_k=bk, num_q=num_q)
+                        block_q=bq, block_k=bk, num_q=num_q, group=group)
 
-    q_spec2 = pl.BlockSpec((1, 1, bq, D), lambda b, h, x, y, offs: (b, h, y, 0))
+    # grid axis 1 counts KEY-VALUE heads; y walks the group's query heads and
+    # their q blocks (y = head_in_group * num_q + q block)
+    if group == 1:
+        q_at = lambda b, h, x, y, offs: (b, h, y, 0)
+    else:
+        q_at = lambda b, h, x, y, offs: (b, h * group + y // num_q, y % num_q, 0)
+    q_spec2 = pl.BlockSpec((1, 1, bq, D), q_at)
     kv_spec2 = pl.BlockSpec((1, 1, bk, D), lambda b, h, x, y, offs: (b, h, x, 0))
-    lse_spec2 = pl.BlockSpec((1, 1, bq, _LANE),
-                             lambda b, h, x, y, offs: (b, h, y, 0))
+    lse_spec2 = pl.BlockSpec((1, 1, bq, _LANE), q_at)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, num_kv, num_q),
+            grid=(B, Hkv, num_kv, group * num_q),
             in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, q_spec2,
                       lse_spec2] + ([lse_spec2] if with_glse else []),
             out_specs=[kv_spec2, kv_spec2],
@@ -515,8 +546,8 @@ def flash_attention_lse(
     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Flash attention over (B, T, H, D) q/k/v returning (out, lse) with
-    lse (B, H, Tq) float32. Offsets may be Python ints OR traced int32
+    """Flash attention over q (B, T, H, D) and k/v (B, T, Hkv, D), H a
+    multiple of Hkv, returning (out, lse) with lse (B, H, Tq) float32. Offsets may be Python ints OR traced int32
     scalars (they ride scalar prefetch). Raises ValueError when the shapes
     can't be blocked — use `can_flash` first."""
     flash, offs = _plan_call(q, k, causal, q_offset, kv_offset,

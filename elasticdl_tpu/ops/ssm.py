@@ -1,0 +1,116 @@
+"""State-space (Mamba-2) mixer operations: the causal depthwise convolution,
+the chunked state-space scan (SSD: Dao & Gu, arXiv:2405.21060) and the gated
+group RMSNorm. Plain `jax.numpy` / `lax`: no Pallas kernel here.
+
+The recurrence, per head (P channels, N state columns; B and C shared by the
+heads of a group):
+
+    S_t = a_t · S_{t-1} + Δ_t · x_t ⊗ B_t,   a_t = exp(Δ_t · A),   S_0 = 0
+    y_t = S_t · C_t
+
+`ssd_chunked` computes it in chunks of L tokens. Inside a chunk the answer is
+a masked matrix product: `y_t = Σ_{s≤t} (C_t·B_s) · Π_{s<r≤t} a_r · Δ_s x_s`,
+the decay matrix `Λ_{ts} = exp(cum_t − cum_s)` from one cumulative sum of
+`Δ·A`. Each chunk also leaves a state (`Σ_s exp(cum_L − cum_s) · Δ_s x_s ⊗
+B_s`), a recurrence over the T/L chunks carries the state across, and the
+state a chunk starts from reaches its outputs through C
+(`exp(cum_t) · C_t · S`).
+
+Precision: Δ, the decays, every cumulative sum and the recurrence over
+chunks are float32; the matmuls inside a chunk take `compute_dtype` operands
+(bfloat16 on the chip) and accumulate in float32. The (T/L, H, L, L) decay
+matrices are the largest intermediates (268 MB in float32 at 8192 tokens, 64
+heads): the function is a `jax.checkpoint`, so the backward recomputes them
+from the inputs and nothing of that shape is kept between the passes.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+
+def causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array) -> jax.Array:
+    """Depthwise causal convolution over time: x (B, T, Ch), weight (K, Ch),
+    bias (Ch) -> y_t = Σ_{j<K} weight_j · x_{t-K+1+j} + bias, zeros before the
+    sequence. float32."""
+    k, t = weight.shape[0], x.shape[1]
+    x = x.astype(jnp.float32)
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = bias.astype(jnp.float32)
+    for j in range(k):
+        y = y + padded[:, j:j + t] * weight[j].astype(jnp.float32)
+    return y
+
+
+def gated_group_rmsnorm(y: jax.Array, z: jax.Array, weight: jax.Array,
+                        groups: int, eps: float) -> jax.Array:
+    """rmsnorm_grouped(y · silu(z)) · weight: the RMS is taken over each of
+    `groups` equal runs of the last axis. float32."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    shape = g.shape
+    g = g.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return g.reshape(shape) * weight
+
+
+@partial(jax.checkpoint, static_argnums=(5, 6))
+def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+                c: jax.Array, chunk: int, compute_dtype=jnp.bfloat16) -> jax.Array:
+    """The state-space scan, chunked.
+
+    x (B, T, H, P); dt (B, T, H) float32, already positive (Δ); a (H,)
+    negative (A); b, c (B, T, G, N) with H a multiple of G (head i uses group
+    i // (H/G)). Returns y (B, T, H, P) float32, `D·x` not included. T need
+    not be a multiple of `chunk`: the tail is padded with Δ = 0, which leaves
+    the state as it is."""
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    r = h // g                                   # heads a group
+    pad = -t % chunk
+    if pad:
+        widen = lambda v: jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+        x, dt, b, c = widen(x), widen(dt), widen(b), widen(c)
+    nc, l, dt_c = (t + pad) // chunk, chunk, compute_dtype
+    dt = dt.astype(jnp.float32)
+    xd = (x.astype(jnp.float32) * dt[..., None]).reshape(bsz, nc, l, g, r, p)
+    bc = b.astype(dt_c).reshape(bsz, nc, l, g, n)
+    cc = c.astype(dt_c).reshape(bsz, nc, l, g, n)
+    # cum_t = Σ_{r≤t} Δ_r A inside the chunk: (B, nc, L, H), ≤ 0 and falling
+    cum = jnp.cumsum((dt * a.astype(jnp.float32)).reshape(bsz, nc, l, h), axis=2)
+
+    # inside a chunk: (C Bᵀ ⊙ Λ) · Δx, Λ_ts = exp(cum_t − cum_s) for s ≤ t
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc,
+                    preferred_element_type=jnp.float32)
+    cum_h = cum.transpose(0, 1, 3, 2).reshape(bsz, nc, g, r, l)
+    diff = cum_h[..., :, None] - cum_h[..., None, :]         # (B, nc, G, R, L, L)
+    lower = jnp.tril(jnp.ones((l, l), bool))
+    decay = jnp.exp(jnp.where(lower, diff, -jnp.inf))
+    m = (cb[:, :, :, None] * decay).astype(dt_c)
+    y = jnp.einsum("bcgrls,bcsgrp->bclgrp", m, xd.astype(dt_c),
+                   preferred_element_type=jnp.float32)
+
+    # what each chunk adds to the state: Σ_s exp(cum_L − cum_s) Δ_s x_s ⊗ B_s
+    to_end = jnp.exp(cum[:, :, -1:, :] - cum).reshape(bsz, nc, l, g, r, 1)
+    added = jnp.einsum("bclgn,bclgrp->bcgrpn", bc, (xd * to_end).astype(dt_c),
+                       preferred_element_type=jnp.float32)
+
+    # the recurrence over chunks, float32: S_c = exp(cum_L of chunk c) S_{c-1} + added_c
+    through = jnp.exp(cum[:, :, -1, :]).reshape(bsz, nc, g, r, 1, 1)
+
+    def carry_state(state, chunk_terms):
+        keep, add = chunk_terms
+        return keep * state + add, state         # emits the state a chunk STARTS from
+
+    _, starts = jax.lax.scan(
+        carry_state, jnp.zeros((bsz, g, r, p, n), jnp.float32),
+        (jnp.moveaxis(through, 1, 0), jnp.moveaxis(added, 1, 0)))
+    starts = jnp.moveaxis(starts, 0, 1)                      # (B, nc, G, R, P, N)
+
+    # the starting state reaches token t through exp(cum_t) · C_t
+    from_start = jnp.einsum("bclgn,bcgrpn->bclgrp", cc, starts.astype(dt_c),
+                            preferred_element_type=jnp.float32)
+    y = y + from_start * jnp.exp(cum).reshape(bsz, nc, l, g, r, 1)
+    return y.reshape(bsz, nc * l, h, p)[:, :t]

@@ -130,7 +130,7 @@ def moe(p: Dict[str, jax.Array], x: jax.Array, cfg: Config):
         h, logits, probs, weights, expert_idx = route(p, x, cfg)
         balance, z = moe_ops.router_aux_losses(logits, probs, expert_idx)
     y = moe_ops.dropless_moe(
-        h, expert_idx, weights, p["w_gate"], p["w_up"], p["w_down"],
+        h, expert_idx, weights, (p["w_gate"], p["w_up"], p["w_down"]),
         compute_dtype=jnp.dtype(cfg.compute_dtype))
     return y.reshape(x.shape), {
         "load_balance": balance, "router_z": z, "expert_idx": expert_idx,

@@ -160,6 +160,39 @@ def test_local_olmoe_job_end_to_end(tmp_path):
     assert master.servicer.mean_training_loss() < 7.0     # ln 256 = 5.5, + aux
 
 
+def test_local_nemotron_h_job_end_to_end(tmp_path):
+    """Nemotron-H (Mamba-2 mixers, a held share of sigmoid-routed relu²
+    experts, grouped-query attention; the routers' selection bias riding in
+    `extra_vars` through every step and task) through the same master/worker
+    path: what the benchmark's cell runs at width, as a job."""
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.nemotron_h.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 48, "num_hidden_layers": 5,
+            "hybrid_override_pattern": "ME*ME", "mamba_num_heads": 8,
+            "mamba_head_dim": 8, "ssm_state_size": 16, "n_groups": 2,
+            "chunk_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2,
+            "head_dim": 16, "n_routed_experts": 4, "router_experts": 16,
+            "first_expert": 4, "num_experts_per_tok": 3,
+            "moe_intermediate_size": 24,
+            "moe_shared_expert_intermediate_size": 40,
+            "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+    )
+    master, _, counts = run_job(cfg, tmp_path)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    results = master.evaluation.latest_results()
+    assert 0.0 <= results["token_accuracy"] <= 1.0
+    assert master.servicer.mean_training_loss() < 6.0     # ln 256 = 5.5, no aux
+
+
 def test_run_job_stops_when_the_job_is_dead(tmp_path):
     """The harness itself (tests/jobs.py): a one-process worker started as
     cohort member 2 of 1 dies at world formation on every launch. run_job

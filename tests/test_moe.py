@@ -261,3 +261,128 @@ def test_moe_transformer_lm_trains():
         state, logs = trainer.train_step(state, batch(i % 8))
         losses.append(float(logs["loss"]))
     assert np.mean(losses[-3:]) < np.mean(losses[:3]), losses
+
+
+# ------------------------------------------------------------------ #
+# the sigmoid router and the held dispatch (Nemotron-H, DeepSeek-V3 style)
+
+
+def test_sigmoid_topk_route_by_hand():
+    logits = jnp.asarray([[2.0, 0.0, -1.0, 1.0], [0.1, 0.2, 0.3, 0.4]], jnp.float32)
+    bias = jnp.asarray([0.0, 0.6, 0.0, 0.0], jnp.float32)
+    scores, weights, idx = moe_ops.sigmoid_topk_route(logits, bias, 2, 2.5)
+    s = 1.0 / (1.0 + np.exp(-np.asarray(logits)))
+    np.testing.assert_allclose(scores, s, rtol=1e-6)
+    # token 0: 0.881, 0.5 + 0.6, 0.269, 0.731 -> experts 1 and 0: the bias
+    # selects expert 1 over expert 3 ...
+    assert sorted(np.asarray(idx[0]).tolist()) == [0, 1]
+    # ... and does not weigh: the weights are the SCORES, renormalised, x 2.5
+    chosen = s[0, np.asarray(idx[0])]
+    np.testing.assert_allclose(weights[0], 2.5 * chosen / chosen.sum(), rtol=1e-6)
+    np.testing.assert_allclose(np.sum(np.asarray(weights), axis=-1), 2.5, rtol=1e-6)
+    assert sorted(np.asarray(idx[1]).tolist()) == [1, 3]
+
+
+def relu2_loop(x, expert_idx, weights, w_up, w_down, held):
+    """Every held expert on every token, a mask on its output."""
+    first, count = held
+    y = jnp.zeros_like(x)
+    for e in range(count):
+        out = jnp.square(jax.nn.relu(x @ w_up[e])) @ w_down[e]
+        y = y + jnp.sum(jnp.where(expert_idx == first + e, weights, 0.0), axis=1)[:, None] * out
+    return y
+
+
+def held_routings(n, k, num_experts, held):
+    first, count = held
+    even = np.stack([(np.arange(n) * k + s) % num_experts for s in range(k)], axis=1)
+    on_held = first + even % count                     # every pair on a held expert
+    off_held = np.where(even % num_experts < first, even,
+                        (first + count + even) % num_experts)
+    off_held = np.where((off_held >= first) & (off_held < first + count), 0, off_held)
+    return {"even": even, "every_pair_on_a_held_expert": on_held,
+            "no_pair_on_a_held_expert": off_held}
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("pass_rows", [0, 40])
+@pytest.mark.parametrize("routing", ["even", "every_pair_on_a_held_expert",
+                                     "no_pair_on_a_held_expert"])
+def test_held_dispatch_matches_loop_over_held_experts(routing, pass_rows, direction,
+                                                      monkeypatch):
+    """16 experts, experts 4-7 held, relu² bodies. At 40 rows a pass the 192
+    pairs that all land on held experts take five passes, the last one part
+    full; with none on a held expert no pass runs: nothing is dropped."""
+    n, c, f, e, k, held = 64, 16, 8, 16, 3, (4, 4)
+    r = np.random.default_rng(0)
+    x = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    w = tuple(jnp.asarray(r.normal(size=s) * 0.3, jnp.float32)
+              for s in ((held[1], c, f), (held[1], f, c)))
+    weights = jnp.asarray(r.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
+    idx = jnp.asarray(held_routings(n, k, e, held)[routing], jnp.int32)
+    on_held = int(np.sum((np.asarray(idx) >= 4) & (np.asarray(idx) < 8)))
+    assert moe_ops.held_pass_rows(n * k, e, held[1]) == n * k
+    assert moe_ops.held_pass_rows(49152, 128, 8) == 6144    # twice the even share
+    if pass_rows:
+        monkeypatch.setattr(moe_ops, "held_pass_rows", lambda pairs, e, count: pass_rows)
+    if routing == "no_pair_on_a_held_expert":
+        assert on_held == 0
+    if routing == "every_pair_on_a_held_expert":
+        assert on_held == n * k
+    run = lambda x, weights, *w: moe_ops.dropless_moe(
+        x, idx, weights, w, held=held, num_experts=e, compute_dtype=jnp.float32)
+    loop = lambda x, weights, *w: relu2_loop(x, idx, weights, *w, held)
+    if direction == "forward":
+        np.testing.assert_allclose(run(x, weights, *w), loop(x, weights, *w),
+                                   rtol=1e-4, atol=1e-5)
+        return
+    probe = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    got = jax.grad(lambda *a: jnp.sum(probe * run(*a)), argnums=(0, 1, 2, 3))(x, weights, *w)
+    want = jax.grad(lambda *a: jnp.sum(probe * loop(*a)), argnums=(0, 1, 2, 3))(x, weights, *w)
+    for g, h in zip(got, want):
+        np.testing.assert_allclose(g, h, rtol=1e-4, atol=1e-5)
+    if routing == "no_pair_on_a_held_expert":
+        assert not np.any(np.asarray(got[2])) and not np.any(np.asarray(got[0]))
+
+
+@pytest.mark.parametrize("pass_rows", [8, 11, 64])
+def test_as_many_passes_as_the_held_pairs_fill(pass_rows, monkeypatch):
+    """Random routing, passes smaller than the held pairs (8 and 11 rows:
+    several passes, group boundaries inside a pass and across passes) and
+    larger (64): values and every gradient are the loop's over held experts,
+    under `jit` as in a step."""
+    monkeypatch.setattr(moe_ops, "held_pass_rows", lambda pairs, e, count: pass_rows)
+    n, c, f, e, k, held = 32, 8, 4, 8, 2, (2, 2)
+    r = np.random.default_rng(1)
+    x = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    w = (jnp.asarray(r.normal(size=(2, c, f)), jnp.float32),
+         jnp.asarray(r.normal(size=(2, f, c)), jnp.float32))
+    weights = jnp.asarray(r.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
+    idx = jnp.asarray(r.integers(0, e, size=(n, k)), jnp.int32)
+    assert 11 < int(np.sum((np.asarray(idx) >= 2) & (np.asarray(idx) < 4))) < 64
+    probe = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    run = lambda x, weights, *w: jnp.sum(probe * moe_ops.dropless_moe(
+        x, idx, weights, w, held=held, num_experts=e, compute_dtype=jnp.float32))
+    loop = lambda x, weights, *w: jnp.sum(probe * relu2_loop(x, idx, weights, *w, held))
+    got = jax.jit(jax.value_and_grad(run, argnums=(0, 1, 2, 3)))(x, weights, *w)
+    want = jax.value_and_grad(loop, argnums=(0, 1, 2, 3))(x, weights, *w)
+    for g, h in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, h, rtol=1e-4, atol=1e-5)
+
+
+def test_held_all_is_the_plain_dispatch_and_a_wrong_share_is_refused():
+    n, c, f, e, k = 16, 8, 4, 4, 2
+    r = np.random.default_rng(2)
+    x = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    w = (jnp.asarray(r.normal(size=(e, c, f)), jnp.float32),
+         jnp.asarray(r.normal(size=(e, f, c)), jnp.float32))
+    weights = jnp.asarray(r.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
+    idx = jnp.asarray(r.integers(0, e, size=(n, k)), jnp.int32)
+    plain = moe_ops.dropless_moe(x, idx, weights, w, compute_dtype=jnp.float32)
+    all_held = moe_ops.dropless_moe(x, idx, weights, w, held=(0, e), num_experts=e,
+                                    compute_dtype=jnp.float32)
+    np.testing.assert_array_equal(plain, all_held)
+    np.testing.assert_allclose(plain, relu2_loop(x, idx, weights, *w, (0, e)),
+                               rtol=1e-4, atol=1e-5)
+    with pytest.raises(ValueError, match="held="):
+        moe_ops.dropless_moe(x, idx, weights, w, held=(0, 2), num_experts=8)

@@ -162,7 +162,7 @@ def test_dropless_dispatch_matches_loop_over_experts(routing, direction):
     weights = jnp.asarray(r.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
     idx = jnp.asarray(routings(n, k, e)[routing], jnp.int32)
     if direction == "forward":
-        got = moe_ops.dropless_moe(x, idx, weights, *w, compute_dtype=jnp.float32)
+        got = moe_ops.dropless_moe(x, idx, weights, tuple(w), compute_dtype=jnp.float32)
         want = loop_over_experts(x, idx, weights, *w)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
         return
@@ -171,8 +171,8 @@ def test_dropless_dispatch_matches_loop_over_experts(routing, direction):
     def scalar(fn):
         return lambda x, weights, *w: jnp.sum(probe * fn(x, idx, weights, *w))
 
-    got = jax.grad(scalar(lambda *a: moe_ops.dropless_moe(
-        *a, compute_dtype=jnp.float32)), argnums=(0, 1, 2, 3, 4))(x, weights, *w)
+    got = jax.grad(scalar(lambda x, idx, weights, *w: moe_ops.dropless_moe(
+        x, idx, weights, w, compute_dtype=jnp.float32)), argnums=(0, 1, 2, 3, 4))(x, weights, *w)
     want = jax.grad(scalar(loop_over_experts), argnums=(0, 1, 2, 3, 4))(x, weights, *w)
     for g, h in zip(got, want):
         np.testing.assert_allclose(g, h, rtol=1e-4, atol=1e-5)
@@ -299,7 +299,7 @@ def _capacity_bound(monkeypatch):
 
     def capped(x, expert_idx, weights, *rest, **kw):
         n, k = expert_idx.shape
-        e = rest[0].shape[0]
+        e = rest[0][0].shape[0]
         cap = n * k // e                                # capacity factor 1.0
         hit = jax.nn.one_hot(expert_idx.reshape(-1), e, dtype=jnp.int32)
         place = jnp.sum((jnp.cumsum(hit, axis=0) - 1) * hit, axis=-1).reshape(n, k)

@@ -372,3 +372,57 @@ def test_flash_rejects_unblockable():
     q, k, v = _qkv(t_q=100, t_k=64)
     with pytest.raises(ValueError, match="cannot block"):
         flash_attention(q, k, v, interpret=True)
+
+
+# ------------------------------------------------------------------ #
+# grouped-query attention: fewer key-value heads than query heads
+
+
+def _gqa(heads, kv_heads, seed=3):
+    r = np.random.RandomState(seed)
+    q = jnp.asarray(r.randn(B, T, heads, D), jnp.float32)
+    k = jnp.asarray(r.randn(B, T, kv_heads, D), jnp.float32)
+    v = jnp.asarray(r.randn(B, T, kv_heads, D), jnp.float32)
+    return q, k, v
+
+
+@pytest.mark.parametrize("group", [1, 4, 16])
+def test_grouped_query_fallback_is_attention_with_repeated_heads(group):
+    """Query head i attends with key-value head i // group."""
+    q, k, v = _gqa(16, 16 // group)
+    want = full_attention(q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2))
+    np.testing.assert_allclose(np.asarray(full_attention(q, k, v)), np.asarray(want),
+                               atol=2e-6, rtol=2e-6)
+    if 1 < group < 16:  # and NOT with head i % kv_heads (one kv head: the same)
+        other = full_attention(q, jnp.tile(k, (1, 1, group, 1)), jnp.tile(v, (1, 1, group, 1)))
+        assert float(jnp.max(jnp.abs(other - want))) > 1e-2
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("group", [1, 4, 16])
+def test_grouped_query_flash_matches_fallback(group, direction):
+    """The three kernels with K/V indexed by `h // group` against the XLA
+    fallback; dK and dV are summed over the group's query heads inside the
+    dkv kernel."""
+    q, k, v = _gqa(16, 16 // group)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=16,
+                                            block_k=32, interpret=True)
+    if direction == "forward":
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(full_attention(q, k, v)),
+                                   atol=2e-5, rtol=2e-5)
+        return
+    probe = jnp.asarray(np.random.RandomState(4).randn(*q.shape), jnp.float32)
+    g_ref = jax.grad(lambda *a: jnp.sum(probe * full_attention(*a)), argnums=(0, 1, 2))(q, k, v)
+    g_got = jax.grad(lambda *a: jnp.sum(probe * flash(*a)), argnums=(0, 1, 2))(q, k, v)
+    assert g_got[1].shape == k.shape and g_got[2].shape == v.shape
+    for a, b in zip(g_got, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
+
+
+def test_grouped_query_heads_must_divide():
+    q, k, v = _gqa(6, 4)
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention(q, k, v, block_q=16, block_k=16, interpret=True)
+    with pytest.raises(ValueError, match="do not divide"):
+        full_attention(q, k, v)
