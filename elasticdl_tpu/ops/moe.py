@@ -20,9 +20,10 @@ add to the task loss.
 sparse-expert LMs are trained with today (OLMoE, `model_zoo/transformer/
 olmoe.py`; Nemotron-H, `nemotron_h.py`). The N·k (token, slot) pairs are
 sorted by expert, the rows gathered into that order, each expert's contiguous
-group multiplied by its own matrices (`jax.lax.ragged_dot`, which libtpu
-lowers to a Mosaic grouped matmul), and the result brought back and summed
-over the k slots. The expert body is what the caller's matrices make it:
+group multiplied by its own matrices (the grouped matmul of `ops/pallas_gmm.py`
+on a TPU: row tiles by group, the whole contraction in one block, tiles from
+the shapes; `jax.lax.ragged_dot` elsewhere), and the result brought back and
+summed over the k slots. The expert body is what the caller's matrices make it:
 three of them a gated SiLU unit (`W_down(silu(W_gate x) ⊙ W_up x)`), two a
 relu² unit (`W_down relu(W_up x)²`). Two routers come with it: `topk_route`
 (softmax, weights as they are) and `sigmoid_topk_route` (sigmoid scores, a
@@ -38,7 +39,10 @@ share of the pairs — each gathered, multiplied, weighted and scatter-added to
 its tokens, as many passes as the held pairs fill: a `lax.while_loop`, forward
 and backward, so nothing is ever dropped, nothing is sized for the worst case,
 and a step on which more pairs land is slower by the passes it adds, not
-wrong. Without `held` every expert is held and the shapes are static at N·k
+wrong. Inside a pass the grouped matmul visits only the row tiles that hold a
+held pair and returns the rows past the last one as zeros, so the experts'
+time follows the pairs held and a pass's rows size only its gather and its
+scatter-add. Without `held` every expert is held and the shapes are static at N·k
 rows, whatever the routing.
 """
 
@@ -51,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from elasticdl_tpu.common.constants import MeshAxis
+from elasticdl_tpu.ops import pallas_gmm
 
 EXPERT_AXIS = MeshAxis.EXPERT
 
@@ -217,38 +222,55 @@ _rows_to_pair_order.defvjp(_rows_to_pair_order_fwd, _rows_to_pair_order_bwd)
 
 def _expert_body(xs, experts, group_sizes, dt):
     """Rows in expert order through their experts' matrices: three matrices
-    are a gated SiLU unit, two a relu² unit."""
+    are a gated SiLU unit, two a relu² unit. The grouped matmul is the repo's
+    own kernel where it can run (a TPU; interpret mode in the CPU tests) and
+    `jax.lax.ragged_dot` elsewhere. Rows past the last group: zeros from the
+    kernel, undefined from `ragged_dot`."""
+    gmm = pallas_gmm.grouped_matmul if pallas_gmm.runnable() else jax.lax.ragged_dot
     if len(experts) == 3:
         w_gate, w_up, w_down = experts
-        gate = jax.lax.ragged_dot(xs, w_gate.astype(dt), group_sizes)
-        up = jax.lax.ragged_dot(xs, w_up.astype(dt), group_sizes)
+        gate = gmm(xs, w_gate.astype(dt), group_sizes)
+        up = gmm(xs, w_up.astype(dt), group_sizes)
         hidden = (jax.nn.silu(gate.astype(jnp.float32))
                   * up.astype(jnp.float32)).astype(dt)
     else:
         w_up, w_down = experts
-        up = jax.lax.ragged_dot(xs, w_up.astype(dt), group_sizes)
+        up = gmm(xs, w_up.astype(dt), group_sizes)
         hidden = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dt)
-    return jax.lax.ragged_dot(hidden, w_down.astype(dt), group_sizes)
+    return gmm(hidden, w_down.astype(dt), group_sizes)
 
 
 def held_pass_rows(pairs: int, num_experts: int, count: int) -> int:
     """The rows of one pass of a held dispatch: twice the held experts' even
-    share of the pairs, in whole 512s (a grouped matmul's row tile), and never
-    more than all pairs. The grouped matmuls' time follows the rows of the
-    passes run, not the pairs in them."""
+    share of the pairs, in whole 512s, and never more than all pairs. It
+    sizes the pass's gather, weights and scatter-add; the grouped matmuls'
+    time follows the pairs the pass holds (`pallas_gmm`: the row tiles past
+    the last held pair are skipped), where `ragged_dot`'s followed the rows."""
     return min(pairs, 512 * max(1, -(-2 * pairs * count // (512 * num_experts))))
+
+
+def held_row_tiles(on_held, pairs: int, num_experts: int, count: int) -> jax.Array:
+    """() int32: the row tiles that hold a held pair, over the passes a held
+    dispatch of `pairs` pairs runs when `on_held` of them are on its `count`
+    held experts (they sort first: a pass holds the next `held_pass_rows` of
+    them) — what a grouped matmul of the pass visits of `passes x
+    held_pass_rows / row tile`, by the kernel's own `pallas_gmm.row_tiles`
+    at the kernel's own row tile."""
+    rows = held_pass_rows(pairs, num_experts, count)
+    tm = pallas_gmm.row_tile(rows)
+    return sum(pallas_gmm.row_tiles(jnp.clip(on_held - lo, 0, rows), tm)
+               for lo in range(0, pairs, rows))
 
 
 def _held_pass(y, xd, flat_weights, experts, order, starts, ends, lo, k, rows):
     """y (N, C) float32 plus rows lo..lo+rows of the sorted order through
     their experts, each weighted and added to its token. The rows past the
-    last held pair ride in the last group with weight zero, so every row is
-    defined (a `ragged_dot` leaves rows outside its groups undefined)."""
-    hi = lo + rows
+    last held pair are in no group: the grouped matmul skips their row tiles
+    and returns them as zeros, forward and backward, and their weight is
+    zero."""
     with jax.named_scope("dispatch"):
         pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
-        group_sizes = jnp.clip(ends, lo, hi) - jnp.clip(starts, lo, hi)
-        group_sizes = group_sizes.at[-1].add(rows - jnp.sum(group_sizes))
+        group_sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
         tokens = pair // k
         xs = _take_rows(xd, tokens)
     with jax.named_scope("experts"):

@@ -23,7 +23,10 @@ anywhere except the convolution's (hidden C):
   weigh); expert e is `W_down,e relu(W_up,e h)²`; this chip holds experts
   `first_expert … first_expert + n_routed_experts − 1` and computes every
   pair routed to them (`ops.moe.dropless_moe`, `held`, in passes of
-  `ops.moe.held_pass_rows` rows; `router_state/held_passes` counts them);
+  `ops.moe.held_pass_rows` rows through the grouped matmul of
+  `ops.pallas_gmm`, which skips the row tiles past the last held pair;
+  `router_state/held_passes` counts the passes and
+  `router_state/held_row_tiles` the row tiles that held a pair);
   what the other experts would add is left out; plus one shared expert of
   the same body on every token. b is no parameter: after each training step
   `b_e ← b_e + bias_update_speed · sign(mean load − load_e)` over this
@@ -288,6 +291,12 @@ def updated_bias(bias, expert_idx, cfg: Config):
     return bias + cfg.bias_update_speed * jnp.sign(mean - load)
 
 
+def _pairs_on_held(expert_idx, cfg: Config):
+    first, count = cfg.held
+    return jnp.sum((expert_idx >= first) & (expert_idx < first + count),
+                   axis=(1, 2), dtype=jnp.int32)
+
+
 def held_passes(expert_idx, cfg: Config):
     """(E layers,) int32: the passes the held dispatch ran at this routing,
     expert_idx (E layers, N, k) — one where the pairs on held experts fit a
@@ -297,9 +306,19 @@ def held_passes(expert_idx, cfg: Config):
         return jnp.zeros(expert_idx.shape[0], jnp.int32)
     pairs = expert_idx.shape[1] * expert_idx.shape[2]
     rows = moe_ops.held_pass_rows(pairs, cfg.num_experts, count)
-    on_held = jnp.sum((expert_idx >= first) & (expert_idx < first + count),
-                      axis=(1, 2), dtype=jnp.int32)
-    return -(-on_held // rows)
+    return -(-_pairs_on_held(expert_idx, cfg) // rows)
+
+
+def held_row_tiles(expert_idx, cfg: Config):
+    """(E layers,) int32: the row tiles the held experts' grouped matmul
+    visited at this routing (`ops.moe.held_row_tiles`), of `held_passes x
+    held_pass_rows / row tile`: the rest were skipped. None where every
+    expert is held."""
+    if cfg.held[1] == cfg.num_experts:
+        return jnp.zeros(expert_idx.shape[0], jnp.int32)
+    return moe_ops.held_row_tiles(
+        _pairs_on_held(expert_idx, cfg), expert_idx.shape[1] * expert_idx.shape[2],
+        cfg.num_experts, cfg.held[1])
 
 
 # ------------------------------------------------------------------ #
@@ -368,10 +387,12 @@ class NemotronH(nn.Module):
         bias = self.variable("router_state", "e_score_correction_bias",
                              jnp.zeros, (E, c.num_experts), jnp.float32)
         passes = self.variable("router_state", "held_passes", jnp.zeros, (E,), jnp.int32)
+        row_tiles = self.variable("router_state", "held_row_tiles", jnp.zeros, (E,), jnp.int32)
         logits, stats = forward(params, bias.value, features, c)
         if training and not self.is_initializing():
             bias.value = updated_bias(bias.value, stats["expert_idx"], c)
             passes.value = passes.value + held_passes(stats["expert_idx"], c)
+            row_tiles.value = row_tiles.value + held_row_tiles(stats["expert_idx"], c)
         return logits
 
 

@@ -185,8 +185,11 @@ def test_eval_leaves_the_bias_alone_and_training_moves_it():
 def test_several_passes_a_layer_give_the_same_step_and_are_counted(pass_rows, monkeypatch):
     """A pass made smaller than the pairs on held experts: the step's loss
     and parameters are those of one pass a layer, and `router_state/
-    held_passes` holds ceil(pairs on held experts / pass) of every E layer."""
+    held_passes` holds ceil(pairs on held experts / pass) of every E layer,
+    `router_state/held_row_tiles` the row tiles the grouped matmul's own
+    metadata counts for those passes."""
     from elasticdl_tpu.ops import moe as moe_ops
+    from elasticdl_tpu.ops import pallas_gmm
 
     data = batches(steps=1)[0]
 
@@ -196,16 +199,28 @@ def test_several_passes_a_layer_give_the_same_step_and_are_counted(pass_rows, mo
         idx = np.asarray(zoo().expert_assignments(
             state.params, jnp.zeros((2, 16)), data["features"], spec.model.cfg)[0])
         state, m = trainer.train_step(state, data)
+        counters = state.extra_vars[reference.PASSES[0]]
         return (float(m["loss"]), jax.device_get(state.params), idx,
-                np.asarray(state.extra_vars[reference.PASSES[0]][reference.PASSES[1]]))
+                np.asarray(counters[reference.PASSES[1]]),
+                np.asarray(counters["held_row_tiles"]), spec.model.cfg)
 
-    loss_one, params_one, idx, passes_one = one_step()
+    def row_tiles(on_held, rows):
+        """What the kernel's own metadata counts for each pass's rows."""
+        tm = pallas_gmm.row_tile(rows)
+        return [sum(int(pallas_gmm.row_tile_visits(
+            jnp.asarray([min(max(held - lo, 0), rows)], jnp.int32), rows, tm).row_tiles)
+            for lo in range(0, idx[0].size, rows)) for held in on_held]
+
+    loss_one, params_one, idx, passes_one, tiles_one, cfg = one_step()
     on_held = np.sum((idx >= 4) & (idx < 8), axis=(1, 2))
     assert on_held.min() > pass_rows
     np.testing.assert_array_equal(passes_one, [1, 1])
+    one_pass = moe_ops.held_pass_rows(idx[0].size, cfg.num_experts, cfg.held[1])
+    np.testing.assert_array_equal(tiles_one, row_tiles(on_held, one_pass))
     monkeypatch.setattr(moe_ops, "held_pass_rows", lambda pairs, e, count: pass_rows)
-    loss_many, params_many, _, passes_many = one_step()
+    loss_many, params_many, _, passes_many, tiles_many, _ = one_step()
     np.testing.assert_array_equal(passes_many, -(-on_held // pass_rows))
+    np.testing.assert_array_equal(tiles_many, row_tiles(on_held, pass_rows))
     np.testing.assert_allclose(loss_many, loss_one, rtol=1e-6)
     for name in LEAVES:
         np.testing.assert_allclose(params_many[name], params_one[name], rtol=1e-4,

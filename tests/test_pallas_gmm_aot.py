@@ -1,0 +1,63 @@
+"""REHEARSAL, no chip: the grouped matmul's three forms compile for a
+described v5e at the widths the benchmark's two language-model cells run
+(`ops/pallas_gmm.py`; the on-chip-measurement guide, section 2). What
+interpret mode cannot see — a block Mosaic refuses, more VMEM than a kernel
+may use — fails here and costs no chip time. Nothing runs: no time, no result.
+
+The topology is described inside a fixture, never at import: only the xdist
+worker that is given this file loads libtpu."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from elasticdl_tpu.ops import pallas_gmm
+
+# (rows of a pass or of all pairs, K, N, groups): the held experts of
+# nemotron-3-nano-30b-a3b.resident-8k, up and down; olmoe-1b-7b.resident-4k's
+SHAPES = {
+    "nemotron_up": (6144, 2688, 1856, 8),
+    "nemotron_down": (6144, 1856, 2688, 8),
+    "olmoe_up": (65536, 2048, 1024, 64),
+    "olmoe_down": (65536, 1024, 2048, 64),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """An AOT compile for a described chip is written to the persistent cache
+    and cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("name", sorted(SHAPES))
+def test_forward_and_backward_compile_for_a_v5e(name, one_chip, no_compile_cache):
+    m, k, n, g = SHAPES[name]
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    def forward_and_backward(lhs, rhs, sizes, dy):
+        out, vjp = jax.vjp(lambda a, b: pallas_gmm.grouped_matmul(a, b, sizes), lhs, rhs)
+        return out, vjp(dy)
+
+    text = jax.jit(forward_and_backward).lower(
+        shape((m, k), jnp.bfloat16), shape((g, k, n), jnp.bfloat16),
+        shape((g,), jnp.int32), shape((m, n), jnp.bfloat16)).compile().as_text()
+    assert text.count("%grouped_matmul_t") >= 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
