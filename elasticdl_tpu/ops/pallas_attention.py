@@ -589,7 +589,17 @@ def _plan_call(q, k, causal, q_offset, kv_offset, block_q, block_k,
 def _plan_blocks(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
                  block_q: int, block_k: int,
                  dtype=None) -> Optional[Tuple[int, int]]:
+    """(block_q, block_k) for these shapes, or None. The targets are for a
+    head of at most the lane width; a wider head takes a key block smaller in
+    proportion, so that a key block's rows times the head size stay what they
+    are at 128. At head 256 and (1024, 1024) the dq kernel's blocks and
+    scratch need 16.9 MB of the 16 MB a kernel may use; T=8192, 20 heads,
+    bfloat16 on a v5e, forward / forward + backward: (1024, 512) 6.66 / 25.6
+    ms, (512, 1024) 7.20 / 25.6, (512, 512) 8.44 / 28.3, (256, 1024) 9.46 /
+    30.2 (PERF.md section 6, PR 32)."""
     mb = _min_block(dtype)
+    if q_shape[-1] > _LANE:
+        block_k = max(mb, block_k * _LANE // q_shape[-1])
     bq = pick_block(q_shape[1], block_q, mb)
     bk = pick_block(k_shape[1], block_k, mb)
     if bq is None or bk is None:

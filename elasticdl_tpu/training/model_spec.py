@@ -7,7 +7,10 @@ Reference parity: the reference's model-zoo module contract — module-level
 
 Rebuilt in JAX terms:
 - `custom_model(**model_params)` returns a `flax.linen.Module`,
-- `loss(labels, outputs)` returns a scalar `jnp` loss (mean over batch),
+- `loss(labels, outputs)` returns a scalar `jnp` loss (mean over batch) or a
+  per-example vector, or a dict of them whose `loss` entry is minimised and
+  whose other entries the train step reports beside it; `outputs` is what the
+  module returns, one array or a pytree of them,
 - `optimizer(**model_params)` returns an `optax.GradientTransformation`,
 - `dataset_fn(mode, metadata)` returns a `parse_fn(raw_record) -> (features,
   label)` of numpy values with static shapes (XLA needs static shapes; the

@@ -64,10 +64,8 @@ def _split_batch(batch: Dict[str, Any]):
     return features, labels, mask
 
 
-def _masked_scalar_loss(loss_fn, labels, outputs, mask):
-    """Apply the user loss; accept per-example vectors (masked mean) or
-    scalars (used as-is)."""
-    value = loss_fn(labels, outputs)
+def _masked_mean(value, mask):
+    """A per-example vector's masked mean; a scalar as it is."""
     value = jnp.asarray(value)
     if value.ndim == 0:
         return value
@@ -76,6 +74,21 @@ def _masked_scalar_loss(loss_fn, labels, outputs, mask):
         return jnp.mean(value)
     m = jnp.asarray(mask, jnp.float32).reshape(-1)
     return jnp.sum(value * m) / jnp.maximum(jnp.sum(m), 1.0)
+
+
+def _loss_terms(value):
+    """A user loss's value as (what is minimised, the terms reported beside
+    it): a loss may return a dict whose `loss` entry is minimised and whose
+    other entries (the terms of a sum, say) only ride along."""
+    if isinstance(value, dict):
+        return value["loss"], {k: v for k, v in value.items() if k != "loss"}
+    return value, {}
+
+
+def _masked_scalar_loss(loss_fn, labels, outputs, mask):
+    """Apply the user loss; accept per-example vectors (masked mean) or
+    scalars (used as-is)."""
+    return _masked_mean(_loss_terms(loss_fn(labels, outputs))[0], mask)
 
 
 def _aux_loss(new_vars, weight: float):
@@ -162,7 +175,7 @@ def _accumulated_grads(forward, loss_fn, state, features, labels, mask,
         def sum_loss(params):
             variables = {"params": params, **vars_c}
             outputs, new_vars = forward(variables, f, rng)
-            value = jnp.asarray(loss_fn(l, outputs))
+            value = jnp.asarray(_loss_terms(loss_fn(l, outputs))[0])
             if value.ndim == 0:
                 # pre-reduced scalar: weigh micro-batches equally (ndim is
                 # static, so this warning fires once at trace time)
@@ -574,16 +587,20 @@ class Trainer:
             def compute_loss(params):
                 variables = {"params": params, **state.extra_vars}
                 outputs, new_vars = forward(variables, features, step_rng)
-                loss = _masked_scalar_loss(loss_fn, labels, outputs, mask)
-                return loss + _aux_loss(new_vars, aux_weight), new_vars
+                value, terms = _loss_terms(loss_fn(labels, outputs))
+                loss = _masked_mean(value, mask)
+                terms = {k: _masked_mean(v, mask).astype(jnp.float32)
+                         for k, v in terms.items()}
+                return loss + _aux_loss(new_vars, aux_weight), (new_vars, terms)
 
+            terms = {}      # accumulated micro-batches report the sum alone
             if accum > 1:
                 loss_value, new_vars, grads = _accumulated_grads(
                     forward, loss_fn, state, features, labels, mask,
                     step_rng, accum, aux_weight=aux_weight,
                 )
             else:
-                (loss_value, new_vars), grads = jax.value_and_grad(
+                (loss_value, (new_vars, terms)), grads = jax.value_and_grad(
                     compute_loss, has_aux=True
                 )(state.params)
             with jax.named_scope("optimizer"):     # a name a trace can find
@@ -596,7 +613,7 @@ class Trainer:
                 opt_state=new_opt_state,
                 extra_vars=new_vars,
             )
-            return new_state, {"loss": loss_value.astype(jnp.float32)}
+            return new_state, {**terms, "loss": loss_value.astype(jnp.float32)}
 
         return step_fn
 

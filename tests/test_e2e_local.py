@@ -193,6 +193,39 @@ def test_local_nemotron_h_job_end_to_end(tmp_path):
     assert master.servicer.mean_training_loss() < 6.0     # ln 256 = 5.5, no aux
 
 
+def test_local_glm4_moe_lite_job_end_to_end(tmp_path):
+    """GLM-4.7-Flash's block (latent attention, a dense layer, a held share
+    of sigmoid-routed gated-SiLU experts, the multi-token-prediction module:
+    `outputs` a dict of two logit streams, the loss a dict of its terms)
+    through the same master/worker path, evaluation included."""
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.glm4_moe_lite.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 48, "num_hidden_layers": 3,
+            "first_k_dense_replace": 1, "intermediate_size": 96,
+            "num_attention_heads": 4, "q_lora_rank": 24, "kv_lora_rank": 16,
+            "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 16,
+            "n_routed_experts": 4, "router_experts": 16, "first_expert": 4,
+            "num_experts_per_tok": 3, "moe_intermediate_size": 24,
+            "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+    )
+    master, _, counts = run_job(cfg, tmp_path)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    results = master.evaluation.latest_results()
+    assert 0.0 <= results["token_accuracy"] <= 1.0
+    assert 0.0 <= results["mtp_token_accuracy"] <= 1.0
+    # ln 256 = 5.5 for each stream: main + 0.3 x the module's
+    assert master.servicer.mean_training_loss() < 1.3 * 6.0
+
+
 def test_run_job_stops_when_the_job_is_dead(tmp_path):
     """The harness itself (tests/jobs.py): a one-process worker started as
     cohort member 2 of 1 dies at world formation on every launch. run_job

@@ -293,6 +293,16 @@ def relu2_loop(x, expert_idx, weights, w_up, w_down, held):
     return y
 
 
+def gated_silu_loop(x, expert_idx, weights, w_gate, w_up, w_down, held):
+    """The three-matrix body, every held expert on every token, masked."""
+    first, count = held
+    y = jnp.zeros_like(x)
+    for e in range(count):
+        out = (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]
+        y = y + jnp.sum(jnp.where(expert_idx == first + e, weights, 0.0), axis=1)[:, None] * out
+    return y
+
+
 def held_routings(n, k, num_experts, held):
     first, count = held
     even = np.stack([(np.arange(n) * k + s) % num_experts for s in range(k)], axis=1)
@@ -308,16 +318,20 @@ def held_routings(n, k, num_experts, held):
 @pytest.mark.parametrize("pass_rows", [0, 40])
 @pytest.mark.parametrize("routing", ["even", "every_pair_on_a_held_expert",
                                      "no_pair_on_a_held_expert"])
-def test_held_dispatch_matches_loop_over_held_experts(routing, pass_rows, direction,
+@pytest.mark.parametrize("body", ["relu2", "gated_silu"])
+def test_held_dispatch_matches_loop_over_held_experts(body, routing, pass_rows, direction,
                                                       monkeypatch):
-    """16 experts, experts 4-7 held, relu² bodies. At 40 rows a pass the 192
-    pairs that all land on held experts take five passes, the last one part
-    full; with none on a held expert no pass runs: nothing is dropped."""
+    """16 experts, experts 4-7 held, relu² bodies (two matrices) and gated
+    SiLU bodies (three). At 40 rows a pass the 192 pairs that all land on
+    held experts take five passes, the last one part full; with none on a
+    held expert no pass runs: nothing is dropped."""
     n, c, f, e, k, held = 64, 16, 8, 16, 3, (4, 4)
     r = np.random.default_rng(0)
     x = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    up_like = ((held[1], c, f),) * (2 if body == "gated_silu" else 1)
     w = tuple(jnp.asarray(r.normal(size=s) * 0.3, jnp.float32)
-              for s in ((held[1], c, f), (held[1], f, c)))
+              for s in up_like + ((held[1], f, c),))
+    by_loop = gated_silu_loop if body == "gated_silu" else relu2_loop
     weights = jnp.asarray(r.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
     idx = jnp.asarray(held_routings(n, k, e, held)[routing], jnp.int32)
     on_held = int(np.sum((np.asarray(idx) >= 4) & (np.asarray(idx) < 8)))
@@ -331,14 +345,15 @@ def test_held_dispatch_matches_loop_over_held_experts(routing, pass_rows, direct
         assert on_held == n * k
     run = lambda x, weights, *w: moe_ops.dropless_moe(
         x, idx, weights, w, held=held, num_experts=e, compute_dtype=jnp.float32)
-    loop = lambda x, weights, *w: relu2_loop(x, idx, weights, *w, held)
+    loop = lambda x, weights, *w: by_loop(x, idx, weights, *w, held)
     if direction == "forward":
         np.testing.assert_allclose(run(x, weights, *w), loop(x, weights, *w),
                                    rtol=1e-4, atol=1e-5)
         return
     probe = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
-    got = jax.grad(lambda *a: jnp.sum(probe * run(*a)), argnums=(0, 1, 2, 3))(x, weights, *w)
-    want = jax.grad(lambda *a: jnp.sum(probe * loop(*a)), argnums=(0, 1, 2, 3))(x, weights, *w)
+    every = tuple(range(2 + len(w)))
+    got = jax.grad(lambda *a: jnp.sum(probe * run(*a)), argnums=every)(x, weights, *w)
+    want = jax.grad(lambda *a: jnp.sum(probe * loop(*a)), argnums=every)(x, weights, *w)
     for g, h in zip(got, want):
         np.testing.assert_allclose(g, h, rtol=1e-4, atol=1e-5)
     if routing == "no_pair_on_a_held_expert":

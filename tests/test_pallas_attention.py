@@ -426,3 +426,45 @@ def test_grouped_query_heads_must_divide():
         flash_attention(q, k, v, block_q=16, block_k=16, interpret=True)
     with pytest.raises(ValueError, match="do not divide"):
         full_attention(q, k, v)
+
+
+# ------------------------------------------------------------------ #
+# a head wider than the lane width (latent attention's 256)
+
+
+@pytest.mark.parametrize("head,want", [
+    (64, (1024, 1024)), (128, (1024, 1024)),        # today's answer, unchanged
+    (192, (1024, 512)), (256, (1024, 512)), (512, (1024, 256))])
+def test_the_block_plan_follows_the_head_size(head, want):
+    """At most 128 wide the plan is what it was; a wider head takes a key
+    block smaller in proportion (rounded down to a power of two that divides
+    T): at 256 and (1024, 1024) the dq kernel does not fit the chip's VMEM."""
+    from elasticdl_tpu.ops.pallas_attention import (
+        DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, _plan_blocks)
+    shape = (1, 8192, 20, head)
+    assert _plan_blocks(shape, shape, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K,
+                        dtype=jnp.bfloat16) == want
+    # a short sequence is one block whatever the head
+    short = (1, 64, 4, head)
+    assert _plan_blocks(short, short, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K,
+                        dtype=jnp.bfloat16) == (64, 64)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_at_head_256_matches_the_fallback(direction):
+    """The three kernels at the head size of latent attention, several key
+    blocks a query block (the plan halves the key block asked for)."""
+    r = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(r.randn(1, 64, 2, 256) * 0.5, jnp.float32) for _ in range(3))
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=32,
+                                            block_k=32, interpret=True)
+    if direction == "forward":
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(full_attention(q, k, v)),
+                                   atol=5e-5, rtol=5e-5)
+        return
+    probe = jnp.asarray(r.randn(*q.shape), jnp.float32)
+    g_ref = jax.grad(lambda *a: jnp.sum(probe * full_attention(*a)), argnums=(0, 1, 2))(q, k, v)
+    g_got = jax.grad(lambda *a: jnp.sum(probe * flash(*a)), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_got, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)

@@ -1,6 +1,8 @@
 """REHEARSAL, no chip: the grouped matmul's three forms compile for a
-described v5e at the widths the benchmark's two language-model cells run
-(`ops/pallas_gmm.py`; the on-chip-measurement guide, section 2). What
+described v5e at the widths the benchmark's three language-model cells run
+(`ops/pallas_gmm.py`; the on-chip-measurement guide, section 2), and the
+three flash-attention kernels at the head of 256 that latent attention has
+(`ops/pallas_attention.py`: the block plan follows the head size). What
 interpret mode cannot see — a block Mosaic refuses, more VMEM than a kernel
 may use — fails here and costs no chip time. Nothing runs: no time, no result.
 
@@ -21,6 +23,10 @@ SHAPES = {
     "nemotron_down": (6144, 1856, 2688, 8),
     "olmoe_up": (65536, 2048, 1024, 64),
     "olmoe_down": (65536, 1024, 2048, 64),
+    # glm-4.7-flash.resident-8k: a pass of 8192 rows (twice the 8 held
+    # experts' even share of 32 768 pairs)
+    "glm_up": (8192, 2048, 1536, 8),
+    "glm_down": (8192, 1536, 2048, 8),
 }
 
 
@@ -60,4 +66,27 @@ def test_forward_and_backward_compile_for_a_v5e(name, one_chip, no_compile_cache
         shape((m, k), jnp.bfloat16), shape((g, k, n), jnp.bfloat16),
         shape((g,), jnp.int32), shape((m, n), jnp.bfloat16)).compile().as_text()
     assert text.count("%grouped_matmul_t") >= 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_flash_kernels_compile_at_head_256_for_a_v5e(one_chip, no_compile_cache):
+    """glm-4.7-flash.resident-8k's attention: T = 8192, 20 heads of 256,
+    bfloat16, forward and both backward kernels at the blocks the plan gives
+    (1024, 512). At (1024, 1024) the dq kernel asks for 16.9 MB of the 16 MB
+    of VMEM a kernel may use: that is what the plan avoids."""
+    from elasticdl_tpu.ops import pallas_attention
+
+    shape = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16, sharding=one_chip)
+    assert pallas_attention._plan_blocks(
+        shape.shape, shape.shape, pallas_attention.DEFAULT_BLOCK_Q,
+        pallas_attention.DEFAULT_BLOCK_K, dtype=jnp.bfloat16) == (1024, 512)
+
+    def forward_and_backward(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: pallas_attention.flash_attention(
+            q, k, v, causal=True, interpret=False), q, k, v)
+        return out, vjp(do)
+
+    text = jax.jit(forward_and_backward).lower(shape, shape, shape, shape).compile().as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        assert kernel in text, kernel        # in the instruction's name
     assert text.count('custom_call_target="tpu_custom_call"') == 3
