@@ -1,6 +1,9 @@
 """State-space (Mamba-2) mixer operations: the causal depthwise convolution,
 the chunked state-space scan (SSD: Dao & Gu, arXiv:2405.21060) and the gated
-group RMSNorm. Plain `jax.numpy` / `lax`: no Pallas kernel here.
+group RMSNorm. Plain `jax.numpy` / `lax`, but for the scan: on a TPU
+`ssd_chunked` runs as the Mosaic kernels of `ops/pallas_ssd.py`
+(`scan_route`); its plain body is the path everywhere else and the tests'
+reference for the kernels.
 
 The recurrence, per head (P channels, N state columns; B and C shared by the
 heads of a group):
@@ -20,16 +23,24 @@ Precision: Δ, the decays, every cumulative sum and the recurrence over
 chunks are float32; the matmuls inside a chunk take `compute_dtype` operands
 (bfloat16 on the chip) and accumulate in float32. The (T/L, H, L, L) decay
 matrices are the largest intermediates (268 MB in float32 at 8192 tokens, 64
-heads): the function is a `jax.checkpoint`, so the backward recomputes them
-from the inputs and nothing of that shape is kept between the passes.
+heads). In the plain body they pass through HBM, and the body is a
+`jax.checkpoint`, so the backward recomputes them from the inputs and nothing
+of that shape is kept between the passes; in the kernels a chunk's matrix is
+built and consumed in VMEM, forward and backward, and the backward first
+sweeps the chunks once more for the state each starts from.
 """
 
 from __future__ import annotations
 
+import logging
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from elasticdl_tpu.ops import pallas_ssd
+
+logger = logging.getLogger(__name__)
 
 
 def causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array) -> jax.Array:
@@ -56,7 +67,24 @@ def gated_group_rmsnorm(y: jax.Array, z: jax.Array, weight: jax.Array,
     return g.reshape(shape) * weight
 
 
-@partial(jax.checkpoint, static_argnums=(5, 6))
+def scan_route(x_shape, bc_shape, chunk: int, kernel_runnable: bool,
+               dtype=jnp.float32, compute_dtype=jnp.bfloat16) -> str:
+    """Which body a scan of x (B, T, H, P) with b, c (B, T, G, N) takes —
+    "kernel" or "plain": a pure function of the shapes and of whether the
+    kernels can run here (a TPU, or interpret mode in the CPU tests)."""
+    (_, t, h, p), (g, n) = x_shape, bc_shape[2:]
+    fits = pallas_ssd.blocks(h, p, g, n, chunk, dtype, compute_dtype)
+    route = "kernel" if kernel_runnable and fits else "plain"
+    # trace-time, once per compiled program: which route this shape took
+    logger.info(
+        "state-space scan (%d tokens, %d heads of %d, %d groups of %d, chunks "
+        "of %d) takes the %s route (the Pallas kernels need a TPU or interpret "
+        "mode: %s; chunk and state whole lanes, whole heads a lane tile, a "
+        "visit's blocks inside VMEM: %s)", t, h, p, g, n, chunk, route,
+        kernel_runnable, f"{fits.vmem_bytes} bytes" if fits else "no")
+    return route
+
+
 def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                 c: jax.Array, chunk: int, compute_dtype=jnp.bfloat16) -> jax.Array:
     """The state-space scan, chunked.
@@ -65,7 +93,19 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     negative (A); b, c (B, T, G, N) with H a multiple of G (head i uses group
     i // (H/G)). Returns y (B, T, H, P) float32, `D·x` not included. T need
     not be a multiple of `chunk`: the tail is padded with Δ = 0, which leaves
-    the state as it is."""
+    the state as it is. One algorithm on two routes (`scan_route`). Neither
+    keeps anything of a chunk's (L, L) size for its backward: the plain body
+    is a `jax.checkpoint` (its inputs), the kernels' `custom_vjp` keeps its
+    operands (the inputs and the per-head vectors made from Δ and A)."""
+    route = scan_route(x.shape, b.shape, chunk, pallas_ssd.runnable(), x.dtype,
+                       compute_dtype)
+    body = pallas_ssd.ssd_scan if route == "kernel" else _ssd_plain
+    return body(x, dt, a, b, c, chunk, compute_dtype)
+
+
+@partial(jax.checkpoint, static_argnums=(5, 6))
+def _ssd_plain(x, dt, a, b, c, chunk, compute_dtype):
+    """`ssd_chunked` in `jax.numpy`: every intermediate an array."""
     bsz, t, h, p = x.shape
     g, n = b.shape[2], b.shape[3]
     r = h // g                                   # heads a group

@@ -87,3 +87,17 @@ def _environment_is_an_input():
             + ", ".join(f"{k}={after.get(k)!r} (was {before.get(k)!r})"
                         for k in leaked)
         )
+
+
+@pytest.fixture
+def route_log(caplog):
+    """What `ops/ssm.py::scan_route` logs at trace time. The package's logger
+    may be configured `propagate=False` (`common/log_utils.py`), so caplog
+    listens on the module's logger itself."""
+    import logging
+
+    log = logging.getLogger("elasticdl_tpu.ops.ssm")
+    log.addHandler(caplog.handler)
+    with caplog.at_level(logging.INFO, log.name):
+        yield caplog
+    log.removeHandler(caplog.handler)

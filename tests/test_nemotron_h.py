@@ -319,6 +319,75 @@ def test_ssd_chunked_in_bfloat16_stays_near_float32():
     assert 1e-4 < np.linalg.norm(got - want) / np.linalg.norm(want) < 2e-2
 
 
+def mamba_layer(chunk, seed=3):
+    """One Mamba mixer wide enough for the scan's kernels where `chunk` is
+    128 (4 heads of 64 in 2 groups of 128 columns, hidden 48, 300 tokens: two
+    chunks and a tail), its parameters away from their constants, and the
+    mean of a probe times its output under `jax.checkpoint`, as
+    `forward` runs it."""
+    build_trainer()                    # loads the zoo's module
+    cfg = zoo().Config(
+        hidden_size=48, mamba_num_heads=4, mamba_head_dim=64, n_groups=2,
+        ssm_state_size=128, chunk_size=chunk, compute_dtype="float32",
+        num_hidden_layers=1, hybrid_override_pattern="M")
+    r = np.random.default_rng(seed)
+    normal = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)
+    p = {"mamba_norm": 1 + 0.1 * normal(48),
+         "mamba_in_proj": 0.2 * normal(48, cfg.d_inner + cfg.conv_dim + 4),
+         "mamba_conv_w": 0.5 * normal(4, cfg.conv_dim), "mamba_conv_b": 0.1 * normal(cfg.conv_dim),
+         "mamba_dt_bias": normal(4) - 4.0, "mamba_A_log": 0.3 * normal(4),
+         "mamba_D": 1 + 0.1 * normal(4), "mamba_gate_norm": 1 + 0.1 * normal(cfg.d_inner),
+         "mamba_out_proj": 0.1 * normal(cfg.d_inner, 48)}
+    x, probe = normal(2, 300, 48), normal(2, 300, 48)
+    block = jax.checkpoint(lambda p, x: zoo().mamba(p, x, cfg))
+    return lambda: jax.value_and_grad(lambda p, x: jnp.mean(probe * block(p, x)),
+                                      argnums=(0, 1))(p, x)
+
+
+def interpret_kernels(monkeypatch):
+    """`pallas_attention.interpret_mode()`'s signal WITHOUT its
+    `force_tpu_interpret_mode`: the kernels then run by
+    `pallas_call(interpret=True)`, which has no callbacks. A kernel under
+    `jax.checkpoint` needs that: remat refuses the TPU interpreter's ordered
+    effects."""
+    from elasticdl_tpu.ops import pallas_attention
+
+    monkeypatch.setenv(pallas_attention._INTERPRET_ENV, "1")
+
+
+def test_mamba_under_checkpoint_is_the_same_on_the_kernel_route(route_log, monkeypatch):
+    """`forward` checkpoints every block: loss, parameter gradients and the
+    gradient of the residual stream, kernels (interpret mode) against the
+    plain body; the log says which route each trace took."""
+    # a layer for each route: `jax.checkpoint` keeps a function's trace
+    with jax.default_matmul_precision("highest"):
+        want_loss, (want_p, want_x) = mamba_layer(chunk=128)()
+        assert "takes the plain route" in route_log.text
+        assert "takes the kernel route" not in route_log.text
+        interpret_kernels(monkeypatch)
+        got_loss, (got_p, got_x) = mamba_layer(chunk=128)()
+        assert "takes the kernel route" in route_log.text
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+    for leaf in want_p:
+        np.testing.assert_allclose(got_p[leaf], want_p[leaf], rtol=2e-4,
+                                   atol=2e-5 * float(jnp.max(jnp.abs(want_p[leaf]))),
+                                   err_msg=leaf)
+    np.testing.assert_allclose(got_x, want_x, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(want_x))))
+
+
+def test_the_scan_s_route_follows_backend_and_shapes_alone(route_log, monkeypatch):
+    """Chunks of 8 are no whole lanes: inside interpret mode too the mixer
+    takes the plain body, says so, and traces no kernel."""
+    interpret_kernels(monkeypatch)
+    text = str(jax.make_jaxpr(mamba_layer(chunk=8))())
+    assert "takes the plain route" in route_log.text and "chunks of 8" in route_log.text
+    assert "takes the kernel route" not in route_log.text and "pallas_call" not in text
+    text = str(jax.make_jaxpr(mamba_layer(chunk=128))())
+    assert "takes the kernel route" in route_log.text
+    for kernel in ("ssd_chunk_fwd", "ssd_chunk_starts", "ssd_chunk_bwd"):
+        assert kernel in text, kernel
+
+
 def test_causal_conv1d_by_hand():
     r = np.random.default_rng(1)
     x, w, b = r.normal(size=(2, 7, 3)), r.normal(size=(4, 3)), r.normal(size=(3,))

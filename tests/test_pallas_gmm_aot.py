@@ -2,19 +2,24 @@
 described v5e at the widths the benchmark's three language-model cells run
 (`ops/pallas_gmm.py`; the on-chip-measurement guide, section 2), and the
 three flash-attention kernels at the head of 256 that latent attention has
-(`ops/pallas_attention.py`: the block plan follows the head size). What
+(`ops/pallas_attention.py`: the block plan follows the head size), and the
+state-space scan's three kernels at the Nemotron cell's shapes
+(`ops/pallas_ssd.py`), alone and inside a checkpointed Mamba mixer, where
+every one of them has to carry the scope the benchmark reads it by. What
 interpret mode cannot see — a block Mosaic refuses, more VMEM than a kernel
 may use — fails here and costs no chip time. Nothing runs: no time, no result.
 
 The topology is described inside a fixture, never at import: only the xdist
 worker that is given this file loads libtpu."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from elasticdl_tpu.ops import pallas_gmm
+from elasticdl_tpu.ops import pallas_gmm, pallas_ssd
 
 # (rows of a pass or of all pairs, K, N, groups): the held experts of
 # nemotron-3-nano-30b-a3b.resident-8k, up and down; olmoe-1b-7b.resident-4k's
@@ -90,3 +95,64 @@ def test_flash_kernels_compile_at_head_256_for_a_v5e(one_chip, no_compile_cache)
     for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
         assert kernel in text, kernel        # in the instruction's name
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+# nemotron-3-nano-30b-a3b.resident-8k's scan: one sequence of 8192 tokens, 64
+# heads of 64 in 8 groups of 128 state columns, chunks of 128
+SCAN = dict(tokens=8192, heads=64, head_dim=64, groups=8, state=128, chunk=128)
+
+
+def test_scan_kernels_compile_for_a_v5e(one_chip, no_compile_cache):
+    """Float32 in, bfloat16 operands: forward, the backward's sweep for the
+    chunk states and the backward, at blocks the rule finds room for."""
+    t, h, p, g, n, l = SCAN.values()
+    plan = pallas_ssd.blocks(h, p, g, n, l, jnp.float32, jnp.bfloat16)
+    assert plan is not None and plan.vmem_bytes <= pallas_ssd._vmem_bytes() // 2
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def forward_and_backward(x, dt, a, b, c, dy):
+        y, vjp = jax.vjp(lambda *v: pallas_ssd.ssd_scan(*v, l, jnp.bfloat16), x, dt, a, b, c)
+        return y, vjp(dy)
+
+    text = jax.jit(forward_and_backward).lower(
+        shape(1, t, h, p), shape(1, t, h), shape(h), shape(1, t, g, n), shape(1, t, g, n),
+        shape(1, t, h, p)).compile().as_text()
+    for kernel in ("ssd_chunk_fwd", "ssd_chunk_starts", "ssd_chunk_bwd"):
+        assert text.count("%" + kernel) >= 1, kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_every_scan_kernel_of_a_checkpointed_mamba_mixer_carries_its_scope(
+        one_chip, no_compile_cache, monkeypatch):
+    """`nemotron_h.forward` checkpoints the mixer: the gradient program runs
+    the forward kernel twice (forward, the block's recomputation), the sweep
+    and the backward kernel once — and `benchmark/drivers/resident_lm_share.py::scope_map` finds their time
+    by `mamba/ssd` in each one's `op_name`, or `ssm_scan_roofline` divides a
+    fixed floor by a scope that lost its kernels."""
+    from model_zoo.transformer import nemotron_h
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the route asks
+    cfg = nemotron_h.Config(num_hidden_layers=1, hybrid_override_pattern="M")
+    assert (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.ssm_state_size,
+            cfg.chunk_size) == tuple(SCAN.values())[1:]
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    c, h = cfg.hidden_size, cfg.mamba_num_heads
+    p = {"mamba_norm": shape(c), "mamba_in_proj": shape(c, cfg.d_inner + cfg.conv_dim + h),
+         "mamba_conv_w": shape(cfg.conv_kernel, cfg.conv_dim), "mamba_conv_b": shape(cfg.conv_dim),
+         "mamba_dt_bias": shape(h), "mamba_A_log": shape(h), "mamba_D": shape(h),
+         "mamba_gate_norm": shape(cfg.d_inner), "mamba_out_proj": shape(cfg.d_inner, c)}
+
+    def loss(p, x):
+        with jax.named_scope("nemotron_h"), jax.named_scope("mamba"):
+            y = jax.checkpoint(lambda p, x: nemotron_h.mamba(p, x, cfg))(p, x)
+        return jnp.sum(jnp.square(x + y))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        p, shape(1, SCAN["tokens"], c)).compile().as_text()
+    calls = re.findall(r"^\s*%?(ssd_[\w.]+) = ", text, re.M)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name in calls) == [
+        "ssd_chunk_bwd", "ssd_chunk_fwd", "ssd_chunk_fwd", "ssd_chunk_starts"]
+    from benchmark import common
+    scope_map = common.load_module("drivers", "resident_lm_share").scope_map
+    scopes = scope_map(text, common.load_module("flops", "nemotron_h").SCOPES)
+    assert [scopes.get(name) for name in calls] == ["nemotron_h/mamba/ssd"] * 4
