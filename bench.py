@@ -620,7 +620,8 @@ def bench_observability_overhead(mesh, np):
     observability hot-path instrumentation OFF vs ON. The ON leg mirrors
     (and slightly over-states) what a real worker step pays:
 
-    - step profiler: a data_wait attribution + the compute add +
+    - step profiler: a data_wait phase + the compute phase with its
+      dispatch and readback spans (each an `edl.*` trace annotation) +
       step_done() rolling-window update (observability/profile.py);
     - worker step stats: one observe_step into the heartbeat window;
     - flight ring: the tracer sink attached AND one explicit ring record
@@ -718,15 +719,19 @@ def bench_observability_overhead(mesh, np):
                 # read ~0% overhead no matter how expensive they got)
                 t0 = time.perf_counter()
                 if instrumented:
-                    # nonzero, so the add takes its real (locked) path
-                    prof.add("data_wait", 1e-9)
-                    state, logs = trainer.train_step(state, batch)
-                    # the scalar readback is the completion barrier —
-                    # deliberate per-step sync, it IS the measurement:
-                    # edl-lint: disable=EDL201
-                    loss = float(logs["loss"])
-                    compute_s = time.perf_counter() - t0
-                    prof.add("compute", compute_s)
+                    # the worker loop's own phases, as worker.py opens them:
+                    # each a timer, a (locked) add and an `edl.*` annotation
+                    with prof.phase("data_wait"):
+                        pass
+                    with prof.phase("compute", steps=1) as region:
+                        with prof.span("compute.dispatch"):
+                            state, logs = trainer.train_step(state, batch)
+                        with prof.span("compute.readback"):
+                            # the scalar readback is the completion barrier
+                            # — deliberate per-step sync, it IS the
+                            # measurement: edl-lint: disable=EDL201
+                            loss = float(logs["loss"])
+                    compute_s = region.seconds
                     prof.step_done()
                     stats.observe_step(compute_s, batch_size)
                     rec.record("step", "bench.step", i=i, loss=loss)

@@ -40,8 +40,6 @@ from typing import Any, Iterable, Iterator, List, Optional
 
 import numpy as np
 
-import time
-
 from elasticdl_tpu.common.log_utils import default_logger
 from elasticdl_tpu.observability import profile as profile_lib
 from elasticdl_tpu.observability import tracing
@@ -142,30 +140,23 @@ class DevicePrefetcher:
     def _put(self, host_batch):
         # h2d attribution (observability/profile.py): the cast + sharded
         # device_put dispatch is the transfer half of the input path. The
-        # profiler add is two perf_counter reads + a float add — cheap
-        # enough for the always-on contract.
-        t0 = time.perf_counter()
-        try:
+        # phase is two perf_counter reads, a float add and an annotation —
+        # cheap enough for the always-on contract.
+        with profile_lib.get_profiler().phase("h2d"):
             return mesh_lib.shard_batch(
                 self._mesh, _wire_cast(host_batch, self.cast), self._partition
-            )
-        finally:
-            profile_lib.get_profiler().add(
-                "h2d", time.perf_counter() - t0
             )
 
     def _fill(self) -> None:
         prof = profile_lib.get_profiler()
         while not self._exhausted and len(self._buf) < max(1, self.depth):
-            t0 = time.perf_counter()
             try:
-                host = next(self.source)
+                # blocking on the reader/parse pipeline IS the data wait
+                with prof.phase("data_wait"):
+                    host = next(self.source)
             except StopIteration:
                 self._exhausted = True
                 return
-            finally:
-                # blocking on the reader/parse pipeline IS the data wait
-                prof.add("data_wait", time.perf_counter() - t0)
             self._buf.append((host, self._put(host)))
 
     def __iter__(self) -> "DevicePrefetcher":
@@ -175,11 +166,8 @@ class DevicePrefetcher:
         if self._drained:
             raise StopIteration
         if self.depth <= 0:
-            t0 = time.perf_counter()
-            host = next(self.source)
-            profile_lib.get_profiler().add(
-                "data_wait", time.perf_counter() - t0
-            )
+            with profile_lib.get_profiler().phase("data_wait"):
+                host = next(self.source)
             _PF_BATCHES.inc()
             return self._put(host)
         self._fill()

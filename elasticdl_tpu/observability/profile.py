@@ -16,6 +16,16 @@ module attributes each train step's wall time to phases —
 is a few perf_counter reads and float adds under a leaf lock (bench.py's
 `obs_overhead` leg gates it at <= 2% median step time).
 
+It is also the program's one door into the device profiler's trace:
+`annotation(name)` opens a `jax.profiler.TraceAnnotation` named `edl.<name>`
+— a host span on the clock the device's events are on — and is the only
+place in the program that does. `phase()` and `timed_iter` open one per
+region (`edl.data_wait`, `edl.h2d`, `edl.compute`, `edl.handoff`);
+`StepProfiler.span()` opens one that feeds no rolling window (the task turn:
+`edl.task_turn`, `edl.lease`, `edl.task`, `edl.report`). An annotation is
+always opened: with no profiler session running it costs a `TraceMe` (under a
+microsecond), and in a process that never imported jax it is a no-op.
+
 Exports:
 
 - gauges `edl_step_phase_seconds{phase=...}` (rolling per-step mean over
@@ -26,13 +36,15 @@ Exports:
   *why* a straggler is slow, not just that it is;
 - flight-bundle integration: FlightRecorder.bundle() embeds the snapshot.
 
-Stdlib-only at import; the device-memory probe lazily asks jax (guarded —
-absence degrades to host-only watermarks).
+Stdlib-only at import; the device-memory probe and the annotations read jax
+from `sys.modules` (guarded — absence degrades to host-only watermarks and
+no annotations).
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -68,6 +80,55 @@ PHASE_TO_GOODPUT = {
     "h2d": "h2d",
     "compute": "train_compute",
 }
+
+
+#: every annotation's name starts with this (never `bench.`: the benchmark's
+#: trace reduction takes its window from annotations under that prefix)
+ANNOTATION_PREFIX = "edl."
+
+
+class _NoAnnotation:
+    """What `annotation` returns in a process without jax."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **attrs) -> None:
+        pass
+
+
+_NO_ANNOTATION = _NoAnnotation()
+
+
+def annotation(name: str, **attrs):
+    """A context manager that is the span `edl.<name>` in the device
+    profiler's trace while a session runs (`jax.profiler.start_trace`, the
+    worker's `--profile_dir` window), with `attrs` as the event's stats;
+    `set_metadata(**attrs)` on it adds those known only inside the span."""
+    jax = sys.modules.get("jax")    # never IMPORT jax from here
+    if jax is None:
+        return _NO_ANNOTATION
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **attrs)
+
+
+class Region:
+    """What `StepProfiler.phase` yields. `seconds` is the region's wall once
+    it has closed — the one reading the caller's own figures use too (the
+    task line's `ms/step`), so that no second timer runs. `carve(s)` takes
+    out of this phase's bill what a phase nested in it has billed already
+    (`h2d` inside `compute`): the two then sum to the region's wall."""
+
+    __slots__ = ("seconds", "_carved")
+
+    def __init__(self):
+        self.seconds = 0.0
+        self._carved = 0.0
+
+    def carve(self, seconds: float) -> None:
+        self._carved += seconds
 
 
 class StepProfiler:
@@ -112,12 +173,24 @@ class StepProfiler:
                 self._ledger.add(category, seconds)
 
     @contextmanager
-    def phase(self, name: str) -> Iterator[None]:
+    def phase(self, name: str, **attrs) -> Iterator[Region]:
+        """Time the body into `name`'s bucket and show it as `edl.<name>`
+        in a device trace. The annotation lies inside the timed region."""
+        region = Region()
         t0 = time.perf_counter()
         try:
-            yield
+            with annotation(name, **attrs):
+                yield region
         finally:
-            self.add(name, time.perf_counter() - t0)
+            region.seconds = time.perf_counter() - t0
+            self.add(name, region.seconds - region._carved)
+
+    @staticmethod
+    def span(name: str, **attrs):
+        """`edl.<name>` around something that is no step phase (the task
+        turn, a dispatch inside `compute`): an annotation and nothing
+        else — no bucket, no window, no goodput."""
+        return annotation(name, **attrs)
 
     def step_done(self, steps: int = 1) -> None:
         """Close the current step (or group of `steps` steps — grouped
@@ -229,13 +302,11 @@ def timed_iter(iterable: Iterable, profiler: "StepProfiler",
     self-times on the k == 1 paths)."""
     it = iter(iterable)
     while True:
-        t0 = time.perf_counter()
         try:
-            item = next(it)
+            with profiler.phase(phase):
+                item = next(it)
         except StopIteration:
             return
-        finally:
-            profiler.add(phase, time.perf_counter() - t0)
         yield item
 
 

@@ -41,6 +41,8 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, Iterator, List, Optional, Tuple
 
+from elasticdl_tpu.observability import profile
+
 #: gRPC metadata keys the trace context rides on (lowercase per gRPC spec)
 TRACE_ID_KEY = "edl-trace-id"
 SPAN_ID_KEY = "edl-span-id"
@@ -195,7 +197,9 @@ class Tracer:
         t0 = time.perf_counter()
         error: Optional[str] = None
         try:
-            yield handle
+            # the same span in a device profiler's trace, as `edl.<name>`
+            with profile.annotation(name):
+                yield handle
         except BaseException as e:
             error = repr(e)
             raise

@@ -83,7 +83,8 @@ def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
     What each piece costs on the chip, per benchmark cell: PERF.md §5; why
     it is built this way: PERF.md §6.
     """
-    return jnp.take(table, ids, axis=0)
+    with jax.named_scope("emb/fwd/gather"):
+        return jnp.take(table, ids, axis=0)
 
 
 def _gather_rows_fwd(table, ids):

@@ -29,6 +29,7 @@ from flax import struct
 from jax.sharding import Mesh
 
 from elasticdl_tpu.common.log_utils import default_logger
+from elasticdl_tpu.observability import profile as profile_lib
 from elasticdl_tpu.parallel import mesh as mesh_lib
 from elasticdl_tpu.training import compile_cache as cc
 from elasticdl_tpu.training.model_spec import ModelSpec
@@ -381,7 +382,9 @@ class Trainer:
         exe = self._cache.peek(key)
         if exe is not None:
             return exe
-        with jax.set_mesh(self.mesh):
+        # `edl.compile` in a device trace: a compile inside a traced window
+        # says so (the speculative compiler's thread shows on its own line)
+        with jax.set_mesh(self.mesh), profile_lib.annotation("compile", kind=kind):
             exe = fn.lower(*args).compile()
         return self._cache.store_aot(key, exe, speculative=speculative)
 

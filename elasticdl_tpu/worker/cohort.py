@@ -636,13 +636,14 @@ class CohortWorker:
             task = self._lease_queue.popleft()
         else:
             try:
-                resp = self._stub.GetTask(
-                    pb.GetTaskRequest(
-                        worker_id=self.worker_id,
-                        max_tasks=self.cfg.task_lease_batch,
-                    ),
-                    timeout=30,
-                )
+                with profile_lib.get_profiler().span("lease"):
+                    resp = self._stub.GetTask(
+                        pb.GetTaskRequest(
+                            worker_id=self.worker_id,
+                            max_tasks=self.cfg.task_lease_batch,
+                        ),
+                        timeout=30,
+                    )
             except Exception as e:
                 logger.warning("cohort get_task failed: %s", e)
                 if self._maybe_reconnect(e):
@@ -882,6 +883,7 @@ class CohortWorker:
         metric_states = None
         k = max(1, self.cfg.steps_per_dispatch)
         buf: List[Any] = []   # host batches awaiting one grouped dispatch
+        prof = profile_lib.get_profiler()
 
         def flush_training_group():
             """Run the buffered host batches: one train_many dispatch for a
@@ -903,7 +905,6 @@ class CohortWorker:
             import jax
             import jax.numpy as jnp
 
-            prof = profile_lib.get_profiler()
             # batch assembly stays OUTSIDE the timed region — step_time_ms
             # has always meant dispatch + device compute, and host-side
             # stack/H2D would otherwise read as a phantom slowdown (the
@@ -913,14 +914,17 @@ class CohortWorker:
                     stacked = make_global_batch_stack(
                         self._mesh, buf, self._spec.batch_partition
                     )
-                t0 = time.perf_counter()
-                self._state, m = self._trainer.train_many(self._state, stacked)
-                if self.ctx.is_leader:
-                    loss_sum += float(jnp.sum(m["loss"]))
-                else:
-                    # follower-local completion barrier (see docstring):
-                    # edl-lint: disable=EDL201
-                    jax.block_until_ready(m["loss"])
+                with prof.phase("compute", steps=len(buf)) as region:
+                    with prof.span("compute.dispatch"):
+                        self._state, m = self._trainer.train_many(
+                            self._state, stacked)
+                    with prof.span("compute.readback"):
+                        if self.ctx.is_leader:
+                            loss_sum += float(jnp.sum(m["loss"]))
+                        else:
+                            # follower-local completion barrier (see
+                            # docstring): edl-lint: disable=EDL201
+                            jax.block_until_ready(m["loss"])
             else:
                 with prof.phase("h2d"):
                     globals_ = [
@@ -928,22 +932,24 @@ class CohortWorker:
                             self._mesh, b, self._spec.batch_partition)
                         for b in buf
                     ]
-                t0 = time.perf_counter()
-                for gb in globals_:
-                    self._state, logs = self._trainer.train_step(
-                        self._state, gb)
-                    if self.ctx.is_leader:
-                        # deliberate sync: forces the collective dispatch so
-                        # step_time is honest (see comment below):
-                        # edl-lint: disable=EDL201
-                        loss_sum += float(logs["loss"])
-                    else:
-                        # follower twin of the leader's float():
-                        # edl-lint: disable=EDL201
-                        jax.block_until_ready(logs["loss"])
+                with prof.phase("compute", steps=len(buf)) as region:
+                    for gb in globals_:
+                        with prof.span("compute.dispatch"):
+                            self._state, logs = self._trainer.train_step(
+                                self._state, gb)
+                        with prof.span("compute.readback"):
+                            if self.ctx.is_leader:
+                                # deliberate sync: forces the collective
+                                # dispatch so step_time is honest (see
+                                # comment below): edl-lint: disable=EDL201
+                                loss_sum += float(logs["loss"])
+                            else:
+                                # follower twin of the leader's float():
+                                # edl-lint: disable=EDL201
+                                jax.block_until_ready(logs["loss"])
             # wall time covers dispatch + device compute on THIS process
             # (every process forced its own view above)
-            group_s = time.perf_counter() - t0
+            group_s = region.seconds
             if self.ctx.is_leader:
                 step_time_sum += group_s
                 loss_count += len(buf)
@@ -953,7 +959,6 @@ class CohortWorker:
             self._step_stats.observe_step(
                 group_s / max(1, len(buf)), self.cfg.minibatch_size
             )
-            prof.add("compute", group_s)
             prof.step_done(len(buf))
             self._model_version += len(buf)
             buf.clear()
@@ -1017,62 +1022,66 @@ class CohortWorker:
 
         from elasticdl_tpu.data.prefetch import _wire_cast
 
-        # data-wait attribution: blocking on the reader/parse pipeline is
-        # this process's OWN input path (exactly what the follower-local
-        # exchange exists to surface)
-        for host_batch in profile_lib.timed_iter(
-            svc.batches(shard, start, end), profile_lib.get_profiler()
-        ):
-            # same bf16 wire compression the single-process worker applies
-            # (mask exempted by _wire_cast; cohort reports count by span,
-            # not mask, so accounting is unaffected either way)
-            host_batch = _wire_cast(host_batch, self.cfg.wire_dtype)
-            if task_type == pb.TRAINING and self._example_host_batch is None:
-                # the speculative compiler's example input: post-cast, so
-                # neighbor-world programs lower with the real wire dtypes
-                self._example_host_batch = host_batch
-            if task_type == pb.TRAINING:
-                if self._state is None:
-                    self._ensure_state(make_global_batch(
-                        self._mesh, host_batch, self._spec.batch_partition))
-                    self._maybe_apply_ctrl_lr()
-                buf.append(host_batch)
-                if len(buf) == k:
-                    flush_training_group()
-                continue
-            if k > 1 and task_type in (pb.EVALUATION, pb.PREDICTION):
-                # grouped eval/prediction: same collective scan dispatch on
-                # every process, mirroring training groups
-                if self._state is None:
-                    self._ensure_state(make_global_batch(
-                        self._mesh, host_batch, self._spec.batch_partition))
-                    self._maybe_apply_ctrl_lr()
-                if task_type == pb.EVALUATION:
-                    eval_buf.append(host_batch)
-                    if len(eval_buf) == k:
-                        metric_states = flush_eval_group(metric_states)
-                else:
-                    pred_buf.append(host_batch)
-                    if len(pred_buf) == k:
-                        flush_predict_group()
-                continue
-            batch = make_global_batch(
-                self._mesh, host_batch, self._spec.batch_partition
-            )
-            self._ensure_state(batch)
-            self._maybe_apply_ctrl_lr()
-            if task_type == pb.PREDICTION:
-                outputs = self._trainer.predict_step(self._state, batch)
-                self._process_predictions(outputs, host_batch)
-            else:
-                if metric_states is None:
-                    metric_states = self._trainer.new_metric_states()
-                metric_states = self._trainer.eval_step(
-                    self._state, batch, metric_states
+        with prof.span("task", records=end - start):
+            # data-wait attribution: blocking on the reader/parse pipeline is
+            # this process's OWN input path (exactly what the follower-local
+            # exchange exists to surface)
+            for host_batch in profile_lib.timed_iter(
+                svc.batches(shard, start, end), prof
+            ):
+                # same bf16 wire compression the single-process worker applies
+                # (mask exempted by _wire_cast; cohort reports count by span,
+                # not mask, so accounting is unaffected either way)
+                if self.cfg.wire_dtype:
+                    with prof.phase("h2d"):
+                        host_batch = _wire_cast(
+                            host_batch, self.cfg.wire_dtype)
+                if task_type == pb.TRAINING and self._example_host_batch is None:
+                    # the speculative compiler's example input: post-cast, so
+                    # neighbor-world programs lower with the real wire dtypes
+                    self._example_host_batch = host_batch
+                if task_type == pb.TRAINING:
+                    if self._state is None:
+                        self._ensure_state(make_global_batch(
+                            self._mesh, host_batch, self._spec.batch_partition))
+                        self._maybe_apply_ctrl_lr()
+                    buf.append(host_batch)
+                    if len(buf) == k:
+                        flush_training_group()
+                    continue
+                if k > 1 and task_type in (pb.EVALUATION, pb.PREDICTION):
+                    # grouped eval/prediction: same collective scan dispatch on
+                    # every process, mirroring training groups
+                    if self._state is None:
+                        self._ensure_state(make_global_batch(
+                            self._mesh, host_batch, self._spec.batch_partition))
+                        self._maybe_apply_ctrl_lr()
+                    if task_type == pb.EVALUATION:
+                        eval_buf.append(host_batch)
+                        if len(eval_buf) == k:
+                            metric_states = flush_eval_group(metric_states)
+                    else:
+                        pred_buf.append(host_batch)
+                        if len(pred_buf) == k:
+                            flush_predict_group()
+                    continue
+                batch = make_global_batch(
+                    self._mesh, host_batch, self._spec.batch_partition
                 )
-        flush_training_group()   # trailing partial group (single steps)
-        metric_states = flush_eval_group(metric_states)  # trailing partial
-        flush_predict_group()                            # trailing partial
+                self._ensure_state(batch)
+                self._maybe_apply_ctrl_lr()
+                if task_type == pb.PREDICTION:
+                    outputs = self._trainer.predict_step(self._state, batch)
+                    self._process_predictions(outputs, host_batch)
+                else:
+                    if metric_states is None:
+                        metric_states = self._trainer.new_metric_states()
+                    metric_states = self._trainer.eval_step(
+                        self._state, batch, metric_states
+                    )
+            flush_training_group()   # trailing partial group (single steps)
+            metric_states = flush_eval_group(metric_states)  # trailing partial
+            flush_predict_group()                            # trailing partial
 
         if task_type == pb.TRAINING:
             # COLLECTIVE member-stats exchange at the task boundary (every
@@ -1108,18 +1117,19 @@ class CohortWorker:
             step_time_sum=step_time_sum, step_count=loss_count,
         )
         try:
-            self._stub.ReportTaskResult(report, timeout=30)
-            if task_type == pb.EVALUATION and metric_states is not None:
-                msg = pb.ReportEvaluationMetricsRequest(
-                    worker_id=self.worker_id, eval_job_id=eval_job,
-                    task_id=task_id,
-                )
-                for name, state in metric_states.items():
-                    arr = np.asarray(jax.device_get(state), np.float32)
-                    msg.states.append(
-                        pb.MetricState(name=name, data=arr.tobytes())
+            with prof.span("report"):
+                self._stub.ReportTaskResult(report, timeout=30)
+                if task_type == pb.EVALUATION and metric_states is not None:
+                    msg = pb.ReportEvaluationMetricsRequest(
+                        worker_id=self.worker_id, eval_job_id=eval_job,
+                        task_id=task_id,
                     )
-                self._stub.ReportEvaluationMetrics(msg, timeout=30)
+                    for name, state in metric_states.items():
+                        arr = np.asarray(jax.device_get(state), np.float32)
+                        msg.states.append(
+                            pb.MetricState(name=name, data=arr.tobytes())
+                        )
+                    self._stub.ReportEvaluationMetrics(msg, timeout=30)
         except Exception as e:
             logger.warning("cohort report failed for task %d: %s", task_id, e)
             # fenced = the restarted master requeued this lease; re-register
@@ -1229,42 +1239,50 @@ class CohortWorker:
                     target=self._heartbeat_loop, daemon=True
                 ).start()
             backoff = max(0.5, self.cfg.worker_heartbeat_s / 4)
+            prof = profile_lib.get_profiler()
+            # one iteration is one task turn (lease, broadcast, the task and
+            # its report): spans of the device profiler's trace, as in
+            # worker.py's loop
             while True:
-                leader_ctrl = (
-                    self._lease_control()
-                    if self.ctx.is_leader
-                    else [0] * CTRL_LEN
-                )
-                ctrl = [int(x) for x in self.ctx.broadcast_ints(leader_ctrl)]
-                op = ctrl[0]
-                if self.ctx.is_leader and self._tier is not None:
-                    # replica delta sync at the collective poll boundary
-                    # (leader-only — the tier is the leader's; cheap
-                    # no-op when this cohort replicates nothing)
-                    try:
-                        self._tier.sync_replicas()
-                    except Exception:
-                        logger.exception("embedding replica sync failed")
-                if op == OP_NOOP:
-                    # jittered on the LEADER only (followers just follow
-                    # the broadcast), so idle cohorts de-phase their
-                    # polls. Goodput: idle-with-no-task is `lease_wait`.
-                    with goodput_lib.get_ledger().phase("lease_wait"):
-                        time.sleep(
-                            jittered(backoff) if self.ctx.is_leader
-                            else backoff
-                        )
-                    continue
-                if op == OP_TASK:
-                    self._run_task(ctrl)
-                    # steady state (a task ran): arm the neighbor-world
-                    # precompiler so a future reform lands on a warm cache
-                    self._maybe_start_speculative_compiler()
-                    continue
-                if op in (OP_DONE, OP_ABORT):
-                    if op == OP_DONE:
-                        self._export_final_model()
-                    break
+                with prof.span("task_turn") as turn:
+                    leader_ctrl = (
+                        self._lease_control()
+                        if self.ctx.is_leader
+                        else [0] * CTRL_LEN
+                    )
+                    ctrl = [int(x) for x in self.ctx.broadcast_ints(leader_ctrl)]
+                    op = ctrl[0]
+                    if self.ctx.is_leader and self._tier is not None:
+                        # replica delta sync at the collective poll boundary
+                        # (leader-only — the tier is the leader's; cheap
+                        # no-op when this cohort replicates nothing)
+                        try:
+                            self._tier.sync_replicas()
+                        except Exception:
+                            logger.exception("embedding replica sync failed")
+                    if op == OP_NOOP:
+                        # jittered on the LEADER only (followers just follow
+                        # the broadcast), so idle cohorts de-phase their
+                        # polls. Goodput: idle-with-no-task is `lease_wait`.
+                        with goodput_lib.get_ledger().phase("lease_wait"), \
+                                prof.span("lease.wait"):
+                            time.sleep(
+                                jittered(backoff) if self.ctx.is_leader
+                                else backoff
+                            )
+                        continue
+                    if op == OP_TASK:
+                        turn.set_metadata(
+                            task_id=ctrl[1], type=pb.TaskType.Name(ctrl[2]))
+                        self._run_task(ctrl)
+                        # steady state (a task ran): arm the neighbor-world
+                        # precompiler so a future reform lands on a warm cache
+                        self._maybe_start_speculative_compiler()
+                        continue
+                    if op in (OP_DONE, OP_ABORT):
+                        if op == OP_DONE:
+                            self._export_final_model()
+                        break
 
             def finish():
                 """Post-loop teardown (runs UNDER the drain checkpoint's

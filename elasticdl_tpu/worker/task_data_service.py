@@ -28,6 +28,7 @@ import numpy as np
 
 from elasticdl_tpu.data import parsing
 from elasticdl_tpu.data.reader import AbstractDataReader
+from elasticdl_tpu.observability import profile
 
 
 def _pad_batch(feats, labels, count: int, batch_size: int):
@@ -81,13 +82,18 @@ class TaskDataService:
             self._pool = None
 
     def _make_batch(self, shard_name: str, start: int, end: int) -> Dict[str, Any]:
-        records = None
-        if getattr(self._parse_batch, "accepts_blob", False):
-            # fixed-width fast path: one contiguous read, no record splitting
-            records = self._reader.read_block(shard_name, start, end)
-        if records is None:
-            records = self._reader.read_span(shard_name, start, end)
-        feats, labels = self._parse_batch(records)
+        # `edl.input.make_batch` in a device trace, on the pool's threads:
+        # how long a batch takes to read and parse, beside the task loop's
+        # `edl.data_wait`, which is how long the loop waited for one
+        with profile.annotation("input.make_batch", records=end - start):
+            records = None
+            if getattr(self._parse_batch, "accepts_blob", False):
+                # fixed-width fast path: one contiguous read, no record
+                # splitting
+                records = self._reader.read_block(shard_name, start, end)
+            if records is None:
+                records = self._reader.read_span(shard_name, start, end)
+            feats, labels = self._parse_batch(records)
         count = len(labels)
         if count == self._batch_size:
             mask = np.ones((self._batch_size,), np.float32)

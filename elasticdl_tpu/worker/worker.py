@@ -827,24 +827,27 @@ class Worker:
                 break
             self._ensure_state(batch)
             self._maybe_profile()
-            t0 = time.perf_counter()
-            # straggler-injection site (per-worker so a chaos schedule can
-            # slow EXACTLY one worker: worker.train_step.<id>, or all via
-            # the worker.train_step.* wildcard); inside the timed region,
-            # so an injected delay reads as a slow step — which is the
-            # point: the health layer must detect it
-            faults.fire(f"worker.train_step.{self.worker_id}")
-            self._state, logs = self._trainer.train_step(self._state, batch)
-            # float() forces the step's result, so this wall time covers the
-            # whole step (dispatch + device compute), not just dispatch —
-            # the sync IS the measurement: edl-lint: disable=EDL201
-            loss_sum += float(logs["loss"])
-            step_s = time.perf_counter() - t0
+            # the timed region IS the compute phase: one timer feeds the
+            # profiler, the histogram and the task line's ms/step
+            with prof.phase("compute", steps=1) as region:
+                # straggler-injection site (per-worker so a chaos schedule
+                # can slow EXACTLY one worker: worker.train_step.<id>, or
+                # all via the worker.train_step.* wildcard); inside the
+                # timed region, so an injected delay reads as a slow step —
+                # which is the point: the health layer must detect it
+                faults.fire(f"worker.train_step.{self.worker_id}")
+                with prof.span("compute.dispatch"):
+                    self._state, logs = self._trainer.train_step(
+                        self._state, batch)
+                with prof.span("compute.readback"):
+                    # float() forces the step's result, so this wall time
+                    # covers the whole step (dispatch + device compute),
+                    # not just dispatch — the sync IS the measurement:
+                    # edl-lint: disable=EDL201
+                    loss_sum += float(logs["loss"])
+            step_s = region.seconds
             step_time_sum += step_s
             _TRAIN_STEP_S.observe(step_s)
-            # the already-measured region IS the compute phase — no second
-            # timer on the hot path
-            prof.add("compute", step_s)
             prof.step_done()
             loss_count += 1
             self._global_step += 1
@@ -878,20 +881,20 @@ class Worker:
         from elasticdl_tpu.data.prefetch import _wire_cast
 
         buf = []
+        prof = profile_lib.get_profiler()
         if k == 1:
             stream = self._prefetched(stream)
         else:
             # grouped mode consumes host batches directly (no prefetcher
             # to self-time): attribute each pull to data_wait here
-            stream = profile_lib.timed_iter(
-                stream, profile_lib.get_profiler()
-            )
+            stream = profile_lib.timed_iter(stream, prof)
         for batch in stream:
             if self._shutdown.is_set():
                 interrupted.append(True)
                 return
-            if k > 1:
-                batch = _wire_cast(batch, self.cfg.wire_dtype)
+            if k > 1 and self.cfg.wire_dtype:
+                with prof.phase("h2d"):
+                    batch = _wire_cast(batch, self.cfg.wire_dtype)
             self._ensure_state(batch)
             buf.append(batch)
             if len(buf) == k:
@@ -918,35 +921,47 @@ class Worker:
         self._mid_training_task = True
         interrupted: list = []
 
+        prof = profile_lib.get_profiler()
         for buf in self._grouped_stream(
             svc.batches(task.shard_name, task.start, task.end), k, interrupted
         ):
             self._maybe_profile()
-            t0 = time.perf_counter()
-            # straggler-injection site (one per GROUP dispatch — see the
-            # single-step path for the per-worker addressing rationale)
-            faults.fire(f"worker.train_step.{self.worker_id}")
-            if len(buf) == k:
-                stacked = shard_batch_stack(
-                    self._mesh, buf, self._spec.batch_partition)
-                self._state, m = self._trainer.train_many(self._state, stacked)
-                # one sync per GROUP (k steps), deliberate — it forces the
-                # dispatch so step_time covers device compute, and grouped
-                # mode amortizes it k-fold: edl-lint: disable=EDL201
-                stats["loss_sum"] += float(jnp.sum(m["loss"]))
-            else:
-                for b in buf:
-                    self._state, logs = self._trainer.train_step(self._state, b)
-                    # trailing-partial fallback, same rationale as above:
-                    # edl-lint: disable=EDL201
-                    stats["loss_sum"] += float(logs["loss"])
-            group_s = time.perf_counter() - t0
+            # the timed region (the task line's ms/step): batch assembly,
+            # dispatch and the readback that ends it. The assembly is billed
+            # to h2d and the rest to compute; their sum is the region.
+            with prof.phase("compute", steps=len(buf)) as region:
+                # straggler-injection site (one per GROUP dispatch — see the
+                # single-step path for the per-worker addressing rationale)
+                faults.fire(f"worker.train_step.{self.worker_id}")
+                if len(buf) == k:
+                    with prof.phase("h2d") as put:
+                        stacked = shard_batch_stack(
+                            self._mesh, buf, self._spec.batch_partition)
+                    region.carve(put.seconds)
+                    with prof.span("compute.dispatch"):
+                        self._state, m = self._trainer.train_many(
+                            self._state, stacked)
+                    with prof.span("compute.readback"):
+                        # one sync per GROUP (k steps), deliberate — it
+                        # forces the dispatch so step_time covers device
+                        # compute, and grouped mode amortizes it k-fold:
+                        # edl-lint: disable=EDL201
+                        stats["loss_sum"] += float(jnp.sum(m["loss"]))
+                else:
+                    for b in buf:
+                        with prof.span("compute.dispatch"):
+                            self._state, logs = self._trainer.train_step(
+                                self._state, b)
+                        with prof.span("compute.readback"):
+                            # trailing-partial fallback, same rationale as
+                            # above: edl-lint: disable=EDL201
+                            stats["loss_sum"] += float(logs["loss"])
+            group_s = region.seconds
             stats["step_time_sum"] += group_s
             _TRAIN_STEP_S.observe(group_s / max(1, len(buf)))
             # one profile record per group, normalized per step inside
             # step_done (grouped and single-step workers stay comparable)
-            profile_lib.get_profiler().add("compute", group_s)
-            profile_lib.get_profiler().step_done(len(buf))
+            prof.step_done(len(buf))
             stats["loss_count"] += len(buf)
             self._global_step += len(buf)
             self._model_version += len(buf)
@@ -1217,166 +1232,179 @@ class Worker:
 
         tasks_done = 0
         wait_backoff = 1.0
+        prof = profile_lib.get_profiler()
+        # one iteration is one task turn: lease, the task, its report. Each
+        # is a span of the device profiler's trace (observability/profile.py)
+        # so that a gap on the device can be put down to one of them.
         while not self._shutdown.is_set():
-            if self._lease_queue:
-                # drain locally held leases before re-polling (batched
-                # leases: N tasks per GetTask round-trip)
-                task = self._lease_queue.popleft()
-            else:
-                try:
-                    resp = self._stub.GetTask(
-                        pb.GetTaskRequest(
-                            worker_id=self.worker_id,
-                            max_tasks=self.cfg.task_lease_batch,
-                        ),
-                        timeout=30,
-                    )
-                except Exception as e:
-                    logger.warning("get_task failed: %s; retrying", e)
-                    if self._maybe_reconnect(e):
-                        # master restarted: the handshake landed, re-lease
-                        # immediately under the new generation
+            with prof.span("task_turn") as turn:
+                if self._lease_queue:
+                    # drain locally held leases before re-polling (batched
+                    # leases: N tasks per GetTask round-trip)
+                    task = self._lease_queue.popleft()
+                else:
+                    try:
+                        with prof.span("lease"):
+                            resp = self._stub.GetTask(
+                                pb.GetTaskRequest(
+                                    worker_id=self.worker_id,
+                                    max_tasks=self.cfg.task_lease_batch,
+                                ),
+                                timeout=30,
+                            )
+                    except Exception as e:
+                        logger.warning("get_task failed: %s; retrying", e)
+                        if self._maybe_reconnect(e):
+                            # master restarted: the handshake landed, re-lease
+                            # immediately under the new generation
+                            continue
+                        if self._master_unreachable():
+                            break
+                        # jittered: a cohort of relaunched workers retrying a
+                        # recovering master on the same constant beat is a
+                        # thundering herd (edl-lint EDL304). Goodput: time
+                        # spent riding out an unreachable master is the
+                        # `reconnect` category.
+                        with goodput_lib.get_ledger().phase("reconnect"):
+                            time.sleep(jittered(2))
                         continue
-                    if self._master_unreachable():
+                    if resp.job_done:
+                        logger.info("job done after %d tasks", tasks_done)
+                        self._job_done = True
                         break
-                    # jittered: a cohort of relaunched workers retrying a
-                    # recovering master on the same constant beat is a
-                    # thundering herd (edl-lint EDL304). Goodput: time
-                    # spent riding out an unreachable master is the
-                    # `reconnect` category.
-                    with goodput_lib.get_ledger().phase("reconnect"):
-                        time.sleep(jittered(2))
-                    continue
-                if resp.job_done:
-                    logger.info("job done after %d tasks", tasks_done)
-                    self._job_done = True
-                    break
-                # an old master never fills `tasks`; fall back to the
-                # classic singular field (WAIT only ever arrives alone)
-                leased = list(resp.tasks) or [resp.task]
-                task = leased[0]
-                self._lease_queue.extend(leased[1:])
-                wait_backoff = resp.backoff_seconds or 1.0
-            pending_lr, self._pending_lr = self._pending_lr, None
-            if pending_lr is not None and self._state is not None:
-                from elasticdl_tpu.training.lr_modulation import (
-                    apply_learning_rate,
-                )
-
-                self._state = apply_learning_rate(
-                    self._trainer, self._state, pending_lr
-                )
-                logger.info("runtime LR set to %.6g", pending_lr)
-            elif pending_lr is not None:
-                # state not built yet: keep it pending for the next loop
-                self._pending_lr = pending_lr
-            if self._ckpt_requested and not self._mid_training_task:
-                # master-requested checkpoint (heartbeat should_checkpoint),
-                # taken at a task boundary only
-                self._ckpt_requested = False
-                try:
-                    self._maybe_checkpoint(force=True)
-                except Exception:
-                    logger.exception("master-requested checkpoint failed")
-            if self._pending_rescale is not None:
-                # planned in-place rescale at a clean task boundary: live
-                # handoff + executable-cache reuse, no teardown (the
-                # pending target is consumed either way — no retry loop)
-                try:
-                    with profile_lib.get_profiler().phase("handoff"):
-                        self._rescale_in_place()
-                except Exception:
-                    logger.exception("in-place rescale failed; mesh kept")
-            if self._tier is not None and self._tier_refresh_pending:
-                # resharding reaction at a clean task boundary: refetch
-                # the map, promote/install newly-owned shards (replica
-                # promotion first — see WorkerTierRuntime), confirm the
-                # moves, adopt new replica assignments
-                self._tier_refresh_pending = False
-                try:
-                    self._tier.on_world_change()
-                except Exception:
-                    logger.exception("embedding tier refresh failed")
-            elif self._tier is not None:
-                # replica delta sync rides the task boundary (cheap
-                # no-op when this worker replicates nothing): replicas
-                # stay within the staleness bound of their primaries
-                # without a dedicated thread contending with the step
-                try:
-                    self._tier.sync_replicas()
-                except Exception:
-                    logger.exception("embedding replica sync failed")
-            if task.type == pb.WAIT:
-                # jittered so an idle swarm does not re-poll in phase
-                # (epoch boundaries unblock every worker at once).
-                # Goodput: idle-with-no-task is the `lease_wait` category
-                # — the autoscaler's shrink signal.
-                with goodput_lib.get_ledger().phase("lease_wait"):
-                    time.sleep(jittered(wait_backoff))
-                continue
-
-            report = pb.ReportTaskResultRequest(
-                worker_id=self.worker_id, task_id=task.task_id, success=True
-            )
-            try:
-                if task.type == pb.TRAINING:
-                    stats = self._run_training_task(task)
-                    _TRAIN_STEPS.inc(int(stats["loss_count"]))
-                    _TRAIN_RECORDS.inc(int(stats["records_done"]))
-                    if stats["step_time_sum"] > 0:
-                        _TRAIN_THROUGHPUT.set(
-                            stats["records_done"] / stats["step_time_sum"]
-                        )
-                    if stats["loss_count"]:
-                        # the first task's figure includes the compile
-                        logger.info(
-                            "training task %d: %d step(s), %.1f ms/step, "
-                            "mean loss %.4f", task.task_id,
-                            stats["loss_count"],
-                            1e3 * stats["step_time_sum"] / stats["loss_count"],
-                            stats["loss_sum"] / stats["loss_count"],
-                        )
-                    if stats["interrupted"]:
-                        self._report_preempted_task(task, stats)
-                        break
-                    report.loss_sum = stats["loss_sum"]
-                    report.loss_count = int(stats["loss_count"])
-                    report.step_time_sum = stats["step_time_sum"]
-                    report.step_count = int(stats["loss_count"])
-                elif task.type == pb.EVALUATION:
-                    if self._run_evaluation_task(task):
-                        break
-                elif task.type == pb.PREDICTION:
-                    if self._run_prediction_task(task):
-                        break
-                elif task.type == pb.SAVE_MODEL:
-                    self._save_checkpoint()
-                report.records_processed = task.end - task.start
-                if self._state is not None:
-                    report.model_version = self._model_version
-            except Exception as e:
-                logger.exception("task %d failed", task.task_id)
-                report.success = False
-                report.err_message = str(e)[:512]
-            try:
-                faults.fire("worker.report_task")
-                self._stub.ReportTaskResult(report, timeout=30)
-                if task.type == pb.TRAINING and report.success:
-                    # state and task queue agree here: safe checkpoint point
-                    self._mid_training_task = False
-                    self._maybe_checkpoint()
-            except Exception as e:
-                logger.warning("report failed for task %d: %s", task.task_id, e)
-                if self._maybe_reconnect(e):
-                    # fenced report from before the crash: the restarted
-                    # master requeued this lease, so the task re-runs and
-                    # retires exactly once there — never resend the report
-                    # under the new generation (that WOULD double-count)
-                    logger.warning(
-                        "task %d report was fenced by the restarted master; "
-                        "the requeued lease re-runs it", task.task_id,
+                    # an old master never fills `tasks`; fall back to the
+                    # classic singular field (WAIT only ever arrives alone)
+                    leased = list(resp.tasks) or [resp.task]
+                    task = leased[0]
+                    self._lease_queue.extend(leased[1:])
+                    wait_backoff = resp.backoff_seconds or 1.0
+                turn.set_metadata(
+                    task_id=task.task_id, type=pb.TaskType.Name(task.type))
+                pending_lr, self._pending_lr = self._pending_lr, None
+                if pending_lr is not None and self._state is not None:
+                    from elasticdl_tpu.training.lr_modulation import (
+                        apply_learning_rate,
                     )
-            tasks_done += 1
+
+                    self._state = apply_learning_rate(
+                        self._trainer, self._state, pending_lr
+                    )
+                    logger.info("runtime LR set to %.6g", pending_lr)
+                elif pending_lr is not None:
+                    # state not built yet: keep it pending for the next loop
+                    self._pending_lr = pending_lr
+                if self._ckpt_requested and not self._mid_training_task:
+                    # master-requested checkpoint (heartbeat should_checkpoint),
+                    # taken at a task boundary only
+                    self._ckpt_requested = False
+                    try:
+                        self._maybe_checkpoint(force=True)
+                    except Exception:
+                        logger.exception("master-requested checkpoint failed")
+                if self._pending_rescale is not None:
+                    # planned in-place rescale at a clean task boundary: live
+                    # handoff + executable-cache reuse, no teardown (the
+                    # pending target is consumed either way — no retry loop)
+                    try:
+                        with prof.phase("handoff"):
+                            self._rescale_in_place()
+                    except Exception:
+                        logger.exception("in-place rescale failed; mesh kept")
+                if self._tier is not None and self._tier_refresh_pending:
+                    # resharding reaction at a clean task boundary: refetch
+                    # the map, promote/install newly-owned shards (replica
+                    # promotion first — see WorkerTierRuntime), confirm the
+                    # moves, adopt new replica assignments
+                    self._tier_refresh_pending = False
+                    try:
+                        self._tier.on_world_change()
+                    except Exception:
+                        logger.exception("embedding tier refresh failed")
+                elif self._tier is not None:
+                    # replica delta sync rides the task boundary (cheap
+                    # no-op when this worker replicates nothing): replicas
+                    # stay within the staleness bound of their primaries
+                    # without a dedicated thread contending with the step
+                    try:
+                        self._tier.sync_replicas()
+                    except Exception:
+                        logger.exception("embedding replica sync failed")
+                if task.type == pb.WAIT:
+                    # jittered so an idle swarm does not re-poll in phase
+                    # (epoch boundaries unblock every worker at once).
+                    # Goodput: idle-with-no-task is the `lease_wait` category
+                    # — the autoscaler's shrink signal.
+                    with goodput_lib.get_ledger().phase("lease_wait"), \
+                            prof.span("lease.wait"):
+                        time.sleep(jittered(wait_backoff))
+                    continue
+
+                report = pb.ReportTaskResultRequest(
+                    worker_id=self.worker_id, task_id=task.task_id, success=True
+                )
+                try:
+                    if task.type == pb.TRAINING:
+                        with prof.span("task", records=task.end - task.start):
+                            stats = self._run_training_task(task)
+                        _TRAIN_STEPS.inc(int(stats["loss_count"]))
+                        _TRAIN_RECORDS.inc(int(stats["records_done"]))
+                        if stats["step_time_sum"] > 0:
+                            _TRAIN_THROUGHPUT.set(
+                                stats["records_done"] / stats["step_time_sum"]
+                            )
+                        if stats["loss_count"]:
+                            # the first task's figure includes the compile
+                            logger.info(
+                                "training task %d: %d step(s), %.1f ms/step, "
+                                "mean loss %.4f", task.task_id,
+                                stats["loss_count"],
+                                1e3 * stats["step_time_sum"] / stats["loss_count"],
+                                stats["loss_sum"] / stats["loss_count"],
+                            )
+                        if stats["interrupted"]:
+                            with prof.span("report"):
+                                self._report_preempted_task(task, stats)
+                            break
+                        report.loss_sum = stats["loss_sum"]
+                        report.loss_count = int(stats["loss_count"])
+                        report.step_time_sum = stats["step_time_sum"]
+                        report.step_count = int(stats["loss_count"])
+                    elif task.type == pb.EVALUATION:
+                        if self._run_evaluation_task(task):
+                            break
+                    elif task.type == pb.PREDICTION:
+                        if self._run_prediction_task(task):
+                            break
+                    elif task.type == pb.SAVE_MODEL:
+                        self._save_checkpoint()
+                    report.records_processed = task.end - task.start
+                    if self._state is not None:
+                        report.model_version = self._model_version
+                except Exception as e:
+                    logger.exception("task %d failed", task.task_id)
+                    report.success = False
+                    report.err_message = str(e)[:512]
+                try:
+                    with prof.span("report"):
+                        faults.fire("worker.report_task")
+                        self._stub.ReportTaskResult(report, timeout=30)
+                        if task.type == pb.TRAINING and report.success:
+                            # state and task queue agree here: safe
+                            # checkpoint point
+                            self._mid_training_task = False
+                            self._maybe_checkpoint()
+                except Exception as e:
+                    logger.warning("report failed for task %d: %s", task.task_id, e)
+                    if self._maybe_reconnect(e):
+                        # fenced report from before the crash: the restarted
+                        # master requeued this lease, so the task re-runs and
+                        # retires exactly once there — never resend the report
+                        # under the new generation (that WOULD double-count)
+                        logger.warning(
+                            "task %d report was fenced by the restarted master; "
+                            "the requeued lease re-runs it", task.task_id,
+                        )
+                tasks_done += 1
 
         # A trace window still open at exit (short job / preemption) must be
         # flushed — an unstopped trace writes nothing.

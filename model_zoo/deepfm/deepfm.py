@@ -18,6 +18,7 @@ import functools
 from typing import Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -73,23 +74,30 @@ class DeepFM(nn.Module):
         )(ids)                                                  # (B, 26, D+1)
         emb, lin = emb_all[..., :-1], emb_all[..., -1]
 
-        # FM second order: 0.5 * ((Σ_f v_f)^2 − Σ_f v_f^2), summed over D
-        sum_v = jnp.sum(emb, axis=1)
-        fm2 = 0.5 * jnp.sum(sum_v * sum_v - jnp.sum(emb * emb, axis=1), axis=-1)
+        # the scopes below are names in a device trace (metadata only), as
+        # ops/embedding.py's `emb/bwd/*` are
+        with jax.named_scope("criteo/fm"):
+            # FM second order: 0.5 * ((Σ_f v_f)^2 − Σ_f v_f^2), summed over D
+            sum_v = jnp.sum(emb, axis=1)
+            fm2 = 0.5 * jnp.sum(
+                sum_v * sum_v - jnp.sum(emb * emb, axis=1), axis=-1)
 
-        first_order = jnp.sum(lin, axis=1) + nn.Dense(
-            1, dtype=jnp.float32, name="dense_linear"
-        )(dense).reshape(-1)
+            first_order = jnp.sum(lin, axis=1) + nn.Dense(
+                1, dtype=jnp.float32, name="dense_linear"
+            )(dense).reshape(-1)
 
-        x = jnp.concatenate(
-            [emb.reshape(emb.shape[0], -1), dense], axis=-1
-        ).astype(self.compute_dtype)
-        for i, h in enumerate(self.hidden):
-            x = nn.Dense(h, dtype=self.compute_dtype, name=f"dnn_{i}")(x)
-            x = nn.relu(x)
-            if self.dropout > 0:
-                x = nn.Dropout(self.dropout, deterministic=not training)(x)
-        dnn_out = nn.Dense(1, dtype=jnp.float32, name="dnn_out")(x).reshape(-1)
+        with jax.named_scope("criteo/tower"):
+            x = jnp.concatenate(
+                [emb.reshape(emb.shape[0], -1), dense], axis=-1
+            ).astype(self.compute_dtype)
+            for i, h in enumerate(self.hidden):
+                x = nn.Dense(h, dtype=self.compute_dtype, name=f"dnn_{i}")(x)
+                x = nn.relu(x)
+                if self.dropout > 0:
+                    x = nn.Dropout(
+                        self.dropout, deterministic=not training)(x)
+            dnn_out = nn.Dense(
+                1, dtype=jnp.float32, name="dnn_out")(x).reshape(-1)
 
         bias = self.param("bias", nn.initializers.zeros, (1,), jnp.float32)
         return first_order + fm2.astype(jnp.float32) + dnn_out + bias[0]
@@ -109,9 +117,10 @@ def custom_model(**kwargs):
 
 
 def loss(labels, outputs):
-    return optax.sigmoid_binary_cross_entropy(
-        outputs, jnp.asarray(labels, jnp.float32).reshape(-1)
-    )
+    with jax.named_scope("criteo/loss"):
+        return optax.sigmoid_binary_cross_entropy(
+            outputs, jnp.asarray(labels, jnp.float32).reshape(-1)
+        )
 
 
 def optimizer(**kwargs):

@@ -8,6 +8,7 @@ is built for (batched matmuls over (field, dim) planes), in bfloat16.
 from typing import Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -74,22 +75,27 @@ class XDeepFM(nn.Module):
         )(ids)
         emb, lin = emb_all[..., :-1], emb_all[..., -1]
 
-        first = jnp.sum(lin, axis=1) + nn.Dense(
-            1, dtype=jnp.float32, name="dense_linear"
-        )(dense).reshape(-1)
+        # scopes: names in a device trace (metadata only), as DeepFM's
+        with jax.named_scope("criteo/fm"):
+            first = jnp.sum(lin, axis=1) + nn.Dense(
+                1, dtype=jnp.float32, name="dense_linear"
+            )(dense).reshape(-1)
 
-        cin_out = CIN(self.cin_sizes, base.compute_dtype)(emb)
-        cin_logit = nn.Dense(1, dtype=jnp.float32, name="cin_out")(
-            cin_out.astype(jnp.float32)
-        ).reshape(-1)
+        with jax.named_scope("criteo/cin"):
+            cin_out = CIN(self.cin_sizes, base.compute_dtype)(emb)
+            cin_logit = nn.Dense(1, dtype=jnp.float32, name="cin_out")(
+                cin_out.astype(jnp.float32)
+            ).reshape(-1)
 
-        x = jnp.concatenate([emb.reshape(emb.shape[0], -1), dense], axis=-1).astype(
-            base.compute_dtype
-        )
-        for i, h in enumerate(base.hidden):
-            x = nn.Dense(h, dtype=base.compute_dtype, name=f"dnn_{i}")(x)
-            x = nn.relu(x)
-        dnn_logit = nn.Dense(1, dtype=jnp.float32, name="dnn_out")(x).reshape(-1)
+        with jax.named_scope("criteo/tower"):
+            x = jnp.concatenate(
+                [emb.reshape(emb.shape[0], -1), dense], axis=-1
+            ).astype(base.compute_dtype)
+            for i, h in enumerate(base.hidden):
+                x = nn.Dense(h, dtype=base.compute_dtype, name=f"dnn_{i}")(x)
+                x = nn.relu(x)
+            dnn_logit = nn.Dense(
+                1, dtype=jnp.float32, name="dnn_out")(x).reshape(-1)
 
         bias = self.param("bias", nn.initializers.zeros, (1,), jnp.float32)
         return first + cin_logit + dnn_logit + bias[0]
