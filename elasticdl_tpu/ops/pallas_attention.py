@@ -17,6 +17,22 @@ logsumexp, lane-broadcast to (B, H, Tq, 128) (TPU scratch/IO wants a 128
 lane minor); `delta = rowsum(do·o)` is recomputed in-kernel from the o/do
 blocks rather than stored.
 
+The backward kernels rebuild a block's probabilities as exp(q·k − lse) and
+read `delta` off `out`: their five array residuals — q, k, v as the forward
+kernel took them, its output and its logsumexp — have to come from ONE run
+of the forward, else a row's probabilities no longer sum to one and `delta`
+belongs to another output. So all five carry
+`jax.ad_checkpoint.checkpoint_name`s (`RESIDUAL_NAMES`), and a caller that
+recomputes its layer in the backward pass (`jax.checkpoint`) passes
+`policy=KEEP_RESIDUALS`: the five are then kept from the forward pass, the
+recomputation holds no second run of the forward kernel and rebuilds no q, k
+or v (on a TPU a recomputed projection is not the forward's to the last bit:
+XLA fuses and tiles it differently), and the gradient is that of the
+attention the loss was read from. The price is their bytes, from a layer's
+forward to its backward: (2·H + 2·Hkv)·Tq·D operand-sized values and H·Tq·128
+float32 a batch row. Under a plain `jax.checkpoint`, or none, the names are
+the identity.
+
 `q_offset`/`kv_offset` position the local blocks in a GLOBAL sequence for
 causal masking, mirroring `full_attention`'s contract. They enter the kernel
 as SCALAR-PREFETCH values (SMEM), so they may be TRACED — ring attention
@@ -48,6 +64,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -60,6 +77,14 @@ _LANE = 128      # TPU lane width: minor dims of scratch/residuals
 # (the f32 score block dominates: bq*bk*4 = 4 MB).
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
+
+# The names of the custom rule's array residuals, in its order: q, k, v as
+# (B, H, T, D), out likewise, logsumexp (B, H, Tq, 128) float32; and the
+# `jax.checkpoint` policy that keeps them (the module docstring says why all
+# five or none).
+RESIDUAL_NAMES = tuple(
+    "flash_attention_" + x for x in ("q", "k", "v", "out", "lse"))
+KEEP_RESIDUALS = jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
 
 
 def pick_block(t: int, target: int, min_block: int = 8) -> Optional[int]:
@@ -517,7 +542,10 @@ def _make_flash(causal: bool, bq: int, bk: int, interpret: bool,
         vt = v.transpose(0, 2, 1, 3)
         out, lse = _flash_fwd(offs, qt, kt, vt, causal=causal, bq=bq, bk=bk,
                               interpret=interpret)
-        return (offs, qt, kt, vt, out, lse)
+        # named here, where the residuals are made, so that nothing reads an
+        # un-named one
+        return (offs, *map(checkpoint_name, (qt, kt, vt, out, lse),
+                           RESIDUAL_NAMES))
 
     @jax.custom_vjp
     def flash(offs, q, k, v):

@@ -66,10 +66,16 @@ experts, ., .), the names `benchmark/check_lm.py` judges expert by expert);
 
 Every layer, the module's among them, is recomputed in the backward pass
 (`jax.checkpoint` around the pair of sub-blocks): of a layer's activations
-only the residual stream it started from is kept. It is fixed here, not a
-setting. The layers are walked one by one, not scanned: a `lax.scan` over the
-stacked sparse layers compiles in half the time (36 Mosaic calls for 84) and
-costs 3.7 GiB of stacked gradients and sliced stacks (REHEARSAL, PR 32).
+only the residual stream it started from is kept — and, by the policy
+`pallas_attention.KEEP_RESIDUALS`, what the flash kernels' backward reads: q,
+k, v, the output and the logsumexp (5 × 84 MB a layer at 8192 tokens). The
+recomputation then neither runs the forward kernel again nor rebuilds q, k
+and v, and the attention's gradient is taken at the forward pass's own
+operands (that module's docstring says why it is all five or none). It is
+fixed here, not a setting. The layers are walked one by one, not scanned: a
+`lax.scan` over the stacked sparse layers compiles in half the time (36 Mosaic
+calls for 84) and costs 3.7 GiB of stacked gradients and sliced stacks
+(REHEARSAL, PR 32).
 
 Zoo contract: custom_model / loss / optimizer / dataset_fn / eval_metrics_fn
 / batch_partition; the optimizer, the batch partition, `rmsnorm` and `rope`
@@ -87,6 +93,7 @@ import jax.numpy as jnp
 import optax
 
 from elasticdl_tpu.ops import moe as moe_ops
+from elasticdl_tpu.ops import pallas_attention
 from elasticdl_tpu.ops.attention import full_attention
 from model_zoo.transformer.nemotron_h import (
     _matmul, held_passes, held_row_tiles, updated_bias)
@@ -269,11 +276,13 @@ def forward(params: Dict[str, jax.Array], bias: jax.Array, tokens: jax.Array,
         for i in range(cfg.num_hidden_layers):
             if i < dense:
                 p = _layer_params(params, i, DENSE_KEYS, i)
-                x = jax.checkpoint(lambda p, x: layer(p, x, None, cfg)[0])(p, x)
+                x = jax.checkpoint(lambda p, x: layer(p, x, None, cfg)[0],
+                                   policy=pallas_attention.KEEP_RESIDUALS)(p, x)
             else:
                 p = _layer_params(params, i, SPARSE_KEYS, i - dense)
                 x, s = jax.checkpoint(
-                    lambda p, x, b: layer(p, x, b, cfg))(p, x, bias[i - dense])
+                    lambda p, x, b: layer(p, x, b, cfg),
+                    policy=pallas_attention.KEEP_RESIDUALS)(p, x, bias[i - dense])
                 stats.append(s)
         outputs = {}
         with jax.named_scope("head_loss"):
@@ -292,7 +301,8 @@ def forward(params: Dict[str, jax.Array], bias: jax.Array, tokens: jax.Array,
                                 jnp.dtype(cfg.compute_dtype), jnp.float32)
                 p = _layer_params(params, cfg.num_hidden_layers, SPARSE_KEYS, sparse)
                 y, s = jax.checkpoint(
-                    lambda p, x, b: layer(p, x, b, cfg))(p, y, bias[sparse])
+                    lambda p, x, b: layer(p, x, b, cfg),
+                    policy=pallas_attention.KEEP_RESIDUALS)(p, y, bias[sparse])
                 stats.append(s)
                 with jax.named_scope("head_loss"):
                     outputs["mtp_logits"] = _head(

@@ -58,7 +58,12 @@ expert by expert).
 Every block is recomputed in the backward pass (`jax.checkpoint` around each
 mixer): of a block's activations only the residual stream it started from is
 kept, so that one 8192-token sequence fits beside 667M parameters' optimizer
-state on a 16 GB chip. It is fixed here, not a setting.
+state on a 16 GB chip — and, by the policy `pallas_attention.KEEP_RESIDUALS`,
+what the flash kernels' backward reads of an attention block: q, k, v, the
+output and the logsumexp (277 MB at 8192 tokens), so that its recomputation
+neither runs the forward kernel again nor rebuilds q, k and v (that module's
+docstring says why it is all five or none); a Mamba block holds none of the
+names and the policy is inert there. It is fixed here, not a setting.
 
 Zoo contract: custom_model / loss / optimizer / dataset_fn / eval_metrics_fn
 / batch_partition; the optimizer (AdamW (0.9, 0.95), 1e-8, decay 0.1, linear
@@ -78,7 +83,7 @@ import jax.numpy as jnp
 import optax
 
 from elasticdl_tpu.ops import moe as moe_ops
-from elasticdl_tpu.ops import ssm
+from elasticdl_tpu.ops import pallas_attention, ssm
 from elasticdl_tpu.ops.attention import full_attention
 from model_zoo.transformer.olmoe import (  # noqa: F401
     batch_partition, eval_metrics_fn, optimizer, rmsnorm)
@@ -264,7 +269,8 @@ def forward(params: Dict[str, jax.Array], bias: jax.Array, tokens: jax.Array,
                     stats.append(s)
                 else:
                     mixer = mamba if kind == "M" else attention
-                    y = jax.checkpoint(lambda p, x: mixer(p, x, cfg))(p, x)
+                    y = jax.checkpoint(lambda p, x: mixer(p, x, cfg),
+                                       policy=pallas_attention.KEEP_RESIDUALS)(p, x)
                 x = x + y
         with jax.named_scope("head_loss"):
             h = rmsnorm(x, params["final_norm"], cfg.layer_norm_epsilon)

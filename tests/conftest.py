@@ -40,6 +40,28 @@ import pytest  # noqa: E402
 heavy_on_cpu = pytest.mark.slow
 
 
+def equations(jaxpr, wanted):
+    """How many equations of a jaxpr `wanted(eqn)` holds for, sub-jaxprs
+    (a remat's, a custom rule's, a jit's) included, kernels' bodies not."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += bool(wanted(eqn))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += equations(sub, wanted)
+    return n
+
+
+def pallas_calls(jaxpr, name):
+    """How many `pallas_call`s called `name` a jaxpr holds."""
+    return equations(jaxpr, lambda eqn: eqn.primitive.name == "pallas_call"
+                     and eqn.params["name"] == name)
+
+
 @pytest.fixture(scope="session")
 def mesh8():
     from elasticdl_tpu.parallel.mesh import build_mesh

@@ -27,6 +27,7 @@ from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.parallel.mesh import build_mesh
 from elasticdl_tpu.training.model_spec import ModelSpec
 from elasticdl_tpu.training.trainer import Trainer
+from tests.conftest import pallas_calls
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = common.load_json("rehearse", "tiny-lm-model.json")["model_params"]
@@ -150,6 +151,47 @@ def gradients():
         got = jax.jit(jax.value_and_grad(program_loss, has_aux=True))(params)
         want = jax.jit(jax.value_and_grad(reference_loss, has_aux=True))(params)
     return got, want
+
+
+@pytest.mark.parametrize("route", ["fallback", "kernel"])
+def test_keeping_the_flash_residuals_changes_no_value_on_the_cpu(route, monkeypatch):
+    """`forward` checkpoints every layer with `pallas_attention.
+    KEEP_RESIDUALS`: where a recomputation repeats the forward pass to the
+    bit, as here on the CPU, the loss terms and every gradient leaf are those
+    of the plain `jax.checkpoint` it had before, exactly. On the kernel route
+    (64 tokens, interpret mode) a layer's recomputation then holds no second
+    forward call — four blocks, four calls for eight; on the XLA fallback (36
+    tokens) none of the names occurs and the policy is inert."""
+    from elasticdl_tpu.ops import pallas_attention
+
+    spec, trainer = build_trainer()
+    batch = batches(steps=1, seq=64 if route == "kernel" else 36)[0]
+    params = lively(trainer.init_state(batch)).params
+    bias = jnp.asarray(np.random.default_rng(2).normal(size=(3, 16)) * 0.02, jnp.float32)
+    if route == "kernel":
+        # the signal without `force_tpu_interpret_mode`, whose callbacks a
+        # remat refuses (tests/test_nemotron_h.py::interpret_kernels)
+        monkeypatch.setenv(pallas_attention._INTERPRET_ENV, "1")
+        monkeypatch.setenv("EDL_FLASH", "1")
+
+    def value_and_grad():       # a new closure each time: a new trace
+        def program_loss(p):
+            terms = program_terms(spec, p, bias, batch)
+            return terms["loss"], terms
+        return jax.value_and_grad(program_loss, has_aux=True)
+
+    forward_calls = lambda: pallas_calls(
+        jax.make_jaxpr(value_and_grad())(params).jaxpr, "flash_attention_fwd")
+    got_calls, ((_, got_terms), got) = forward_calls(), jax.jit(value_and_grad())(params)
+    monkeypatch.setattr(pallas_attention, "KEEP_RESIDUALS", None)   # the plain form
+    want_calls, ((_, want_terms), want) = forward_calls(), jax.jit(value_and_grad())(params)
+    assert (got_calls, want_calls) == ((4, 8) if route == "kernel" else (0, 0))
+    for term in ("loss", "loss_main", "loss_mtp"):
+        assert float(got_terms[term]) == float(want_terms[term]), term
+    assert sorted(got) == sorted(LEAVES)
+    for leaf in LEAVES:
+        assert float(jnp.max(jnp.abs(want[leaf]))) > 0, leaf
+        np.testing.assert_array_equal(np.asarray(got[leaf]), np.asarray(want[leaf]), err_msg=leaf)
 
 
 @pytest.mark.parametrize("term", ["loss", "loss_main", "loss_mtp"])

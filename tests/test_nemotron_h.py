@@ -26,6 +26,7 @@ from elasticdl_tpu.ops import ssm
 from elasticdl_tpu.parallel.mesh import build_mesh
 from elasticdl_tpu.training.model_spec import ModelSpec
 from elasticdl_tpu.training.trainer import Trainer
+from tests.conftest import pallas_calls
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = common.load_json("rehearse", "tiny-lm-share.json")["model_params"]
@@ -373,6 +374,46 @@ def test_mamba_under_checkpoint_is_the_same_on_the_kernel_route(route_log, monke
                                    atol=2e-5 * float(jnp.max(jnp.abs(want_p[leaf]))),
                                    err_msg=leaf)
     np.testing.assert_allclose(got_x, want_x, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(want_x))))
+
+
+@pytest.mark.parametrize("route", ["fallback", "kernel"])
+def test_keeping_the_flash_residuals_changes_no_value_on_the_cpu(route, monkeypatch):
+    """`forward` checkpoints the mixers with `pallas_attention.
+    KEEP_RESIDUALS`: where a recomputation repeats the forward pass to the
+    bit, as here on the CPU, the loss and every gradient leaf are those of the
+    plain `jax.checkpoint` it had before, exactly. On the kernel route (64
+    tokens, interpret mode) the attention block's recomputation then holds no
+    second forward call; on the XLA fallback (36 tokens) none of the names
+    occurs and the policy is inert."""
+    from elasticdl_tpu.ops import pallas_attention
+
+    spec, trainer = build_trainer()
+    batch = batches(steps=1, seq=64 if route == "kernel" else 36)[0]
+    params = lively(trainer.init_state(batch)).params
+    bias = jnp.asarray(np.random.default_rng(2).normal(size=(2, 16)) * 0.02, jnp.float32)
+    extra = {"router_state": {"e_score_correction_bias": bias}}
+    if route == "kernel":
+        interpret_kernels(monkeypatch)
+        monkeypatch.setenv("EDL_FLASH", "1")
+
+    def value_and_grad():       # a new closure each time: a new trace
+        def program_loss(p):
+            logits, _ = spec.model.apply({"params": p, **extra}, batch["features"],
+                                         training=True, mutable=["router_state"])
+            return jnp.mean(spec.loss(batch["labels"], logits))
+        return jax.value_and_grad(program_loss)
+
+    forward_calls = lambda: pallas_calls(
+        jax.make_jaxpr(value_and_grad())(params).jaxpr, "flash_attention_fwd")
+    got_calls, (got_loss, got) = forward_calls(), jax.jit(value_and_grad())(params)
+    monkeypatch.setattr(pallas_attention, "KEEP_RESIDUALS", None)   # the plain form
+    want_calls, (want_loss, want) = forward_calls(), jax.jit(value_and_grad())(params)
+    assert (got_calls, want_calls) == ((1, 2) if route == "kernel" else (0, 0))
+    assert float(got_loss) == float(want_loss)
+    assert sorted(got) == sorted(LEAVES)
+    for leaf in LEAVES:
+        assert float(jnp.max(jnp.abs(want[leaf]))) > 0, leaf
+        np.testing.assert_array_equal(np.asarray(got[leaf]), np.asarray(want[leaf]), err_msg=leaf)
 
 
 def test_the_scan_s_route_follows_backend_and_shapes_alone(route_log, monkeypatch):

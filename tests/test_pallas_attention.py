@@ -11,11 +11,12 @@ import pytest
 
 from elasticdl_tpu.ops.attention import full_attention
 from elasticdl_tpu.ops.pallas_attention import (
+    KEEP_RESIDUALS,
     can_flash,
     flash_attention,
     pick_block,
 )
-from tests.conftest import heavy_on_cpu
+from tests.conftest import equations, heavy_on_cpu, pallas_calls
 
 B, T, H, D = 2, 64, 2, 16
 
@@ -468,3 +469,159 @@ def test_flash_at_head_256_matches_the_fallback(direction):
     g_got = jax.grad(lambda *a: jnp.sum(probe * flash(*a)), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_got, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)
+
+
+# ------------------------------------------------------------------ #
+# the kernels' residuals kept across a caller's recomputation
+
+
+def _kept(f):
+    return jax.checkpoint(f, policy=KEEP_RESIDUALS)
+
+
+# (query heads, key-value heads, head size, block_k asked for, with_lse)
+RECOMPUTED = {
+    "head256": (2, 2, 256, 32, False),   # the plan halves the key block: (32, 16)
+    "gqa32of2": (32, 2, 128, 16, False),
+    "with_lse": (2, 2, 16, 16, True),
+}
+
+
+def _recomputed_layer(case):
+    """(a new closure of a small "layer" — projections in front of the
+    kernel, as a checkpointed block has them — returning a scalar loss; its
+    arguments x, wq, wk). The loss weighs the logsumexp too where the case
+    returns it."""
+    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
+
+    heads, kv_heads, head, block_k, with_lse = RECOMPUTED[case]
+    r = np.random.RandomState(11)
+    x = jnp.asarray(r.randn(1, 64, kv_heads, head) * 0.5, jnp.float32)
+    wq = jnp.asarray(r.randn(head, heads // kv_heads * head) / head ** 0.5, jnp.float32)
+    wk = jnp.asarray(r.randn(head, head) / head ** 0.5, jnp.float32)
+    probe = jnp.asarray(r.randn(1, 64, heads, head), jnp.float32)
+    probe_lse = jnp.asarray(r.randn(1, heads, 64), jnp.float32)
+
+    def layer(x, wq, wk):
+        q = (x @ wq).reshape(1, 64, heads, head)
+        kw = dict(causal=True, block_q=32, block_k=block_k, interpret=True)
+        if not with_lse:
+            return flash_attention(q, x @ wk, x, **kw), None
+        return flash_attention_lse(q, x @ wk, x, **kw)
+
+    def loss(wrap):
+        def f(x, wq, wk):
+            out, lse = wrap(layer)(x, wq, wk)
+            total = jnp.sum(probe * out)
+            return (total if lse is None else total + jnp.sum(probe_lse * lse)), out
+        return f
+
+    return loss, (x, wq, wk)
+
+
+@pytest.mark.parametrize("case", sorted(RECOMPUTED))
+def test_kept_residuals_leave_one_forward_call_in_a_recomputed_layer(case):
+    """The engagement counter: differentiating through a `jax.checkpoint`
+    with `KEEP_RESIDUALS` holds ONE `flash_attention_fwd` call, through a
+    plain one two, through none one."""
+    loss, args = _recomputed_layer(case)
+    calls = lambda wrap, kernel: pallas_calls(
+        jax.make_jaxpr(jax.grad(loss(wrap), argnums=(0, 1, 2), has_aux=True))(
+            *args).jaxpr, "flash_attention_" + kernel)
+    assert calls(lambda f: f, "fwd") == 1
+    assert calls(jax.checkpoint, "fwd") == 2
+    assert [calls(_kept, kernel) for kernel in ("fwd", "bwd_dq", "bwd_dkv")] == [1, 1, 1]
+
+
+@pytest.mark.parametrize("case", sorted(RECOMPUTED))
+def test_kept_residuals_leave_no_q_k_v_to_rebuild(case):
+    """All five residuals are the forward pass's own: the recomputation
+    rebuilds neither projection (their backward wants x and the weights, not
+    q and k). Two matmuls forward and four backward — dx and dw of each — in
+    every form but the plain checkpoint's, which repeats the two."""
+    loss, args = _recomputed_layer(case)
+    matmuls = lambda wrap: equations(
+        jax.make_jaxpr(jax.grad(loss(wrap), argnums=(0, 1, 2), has_aux=True))(
+            *args).jaxpr, lambda eqn: eqn.primitive.name == "dot_general")
+    assert [matmuls(wrap) for wrap in (lambda f: f, jax.checkpoint, _kept)] == [6, 8, 6]
+
+
+@pytest.mark.parametrize("case", sorted(RECOMPUTED))
+def test_kept_residuals_change_no_value_where_recomputing_is_exact(case):
+    """out and the gradients under the policy are the un-checkpointed ones
+    bit for bit, and the plain checkpoint's too. The second half is the CPU's
+    property, where a recomputation repeats the forward pass to the bit: on a
+    TPU the plain checkpoint differentiates a recomputed forward whose q, k, v
+    are not the first one's in their last bits (PERF.md section 6, PR 35)."""
+    loss, args = _recomputed_layer(case)
+    run = lambda wrap: jax.jit(jax.grad(loss(wrap), argnums=(0, 1, 2), has_aux=True))(*args)
+    want_grads, want_out = run(lambda f: f)
+    for wrap in (jax.checkpoint, _kept):
+        grads, out = run(wrap)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want_out))
+        for got, want in zip(grads, want_grads):
+            assert float(jnp.max(jnp.abs(want))) > 0
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("scale", [2.0, 4.0])
+def test_residuals_of_two_forward_runs_do_not_mix(scale):
+    """Why the policy keeps all five or none. A recomputation whose q and k
+    come out one bfloat16 step off (as a TPU's do) and the backward kernels
+    handed the FIRST run's out and logsumexp beside them: exp(q·k − lse) no
+    longer sums to one along a row. Against the gradient at the first run's
+    operands, that mixture lies half as far again or more in dq and dk, and two
+    to five times as far in dv, as the gradient taken consistently at the
+    perturbed operands — which is what a plain checkpoint computes."""
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    r = np.random.RandomState(21)
+    mk = lambda s: jnp.asarray(r.randn(1, 2, 128, 64) * s, jnp.float32)     # (B, H, T, D)
+    q, k, v, g = mk(scale), mk(scale), mk(1.0), mk(1.0)
+    step = lambda x: x * (1 + 2.0 ** -8 * jnp.asarray(
+        r.choice([-1.0, 0.0, 1.0], x.shape), jnp.float32))
+    offs = jnp.zeros((2,), jnp.int32)
+    plan = dict(causal=True, bq=32, bk=32, interpret=True)
+
+    def residuals(q, k):
+        return (offs, q, k, v) + tuple(pa._flash_fwd(offs, q, k, v, **plan))
+
+    backward = lambda res: pa._flash_bwd(res, g.transpose(0, 2, 1, 3), None, **plan)[1:]
+    first, second = residuals(q, k), residuals(step(q), step(k))
+    want = backward(first)
+    far = lambda got: [float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+                       for a, b in zip(got, want)]
+    consistent, mixed = far(backward(second)), far(backward(second[:4] + first[4:]))
+    assert all(x < 0.02 * scale for x in consistent), consistent
+    assert all(m > 1.3 * c for m, c in zip(mixed, consistent)), (mixed, consistent)
+    assert mixed[2] > scale * consistent[2]
+
+
+@pytest.mark.parametrize("wrap", ["none", "plain", "kept"])
+def test_logsumexp_carries_its_cotangent_through_a_recomputed_layer(wrap):
+    """With a cotangent on the logsumexp ALONE the gradients are those of the
+    XLA logsumexp of the masked scores, whatever keeps the residuals."""
+    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
+
+    q, k, v = _qkv(t_q=32, t_k=32, seed=12)
+    probe = jnp.asarray(np.random.RandomState(13).randn(B, H, 32), jnp.float32)
+    wrapped = {"none": lambda f: f, "plain": jax.checkpoint, "kept": _kept}[wrap]
+
+    def lse_flash(q, k, v):
+        lse = wrapped(lambda q, k, v: flash_attention_lse(
+            q, k, v, causal=True, block_q=16, block_k=16, interpret=True)[1])(q, k, v)
+        assert lse.shape == (B, H, 32) and lse.dtype == jnp.float32
+        return jnp.sum(probe * lse)
+
+    def lse_ref(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+        mask = jnp.arange(32)[None, :] <= jnp.arange(32)[:, None]
+        return jnp.sum(probe * jax.scipy.special.logsumexp(
+            jnp.where(mask[None, None], s, -1e30), axis=-1))
+
+    got = jax.grad(lse_flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lse_ref, argnums=(0, 1, 2))(q, k, v)
+    assert float(jnp.max(jnp.abs(got[2]))) == 0.0      # v does not move the lse
+    for a, b in zip(got[:2], want[:2]):
+        assert float(jnp.max(jnp.abs(b))) > 1e-3
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)

@@ -5,7 +5,9 @@ three flash-attention kernels at the head of 256 that latent attention has
 (`ops/pallas_attention.py`: the block plan follows the head size), and the
 state-space scan's three kernels at the Nemotron cell's shapes
 (`ops/pallas_ssd.py`), alone and inside a checkpointed Mamba mixer, where
-every one of them has to carry the scope the benchmark reads it by. What
+every one of them has to carry the scope the benchmark reads it by — as the
+flash kernels have to inside the checkpointed layers of GLM and Nemotron,
+which keep the forward kernel's results and so hold ONE forward call a block. What
 interpret mode cannot see — a block Mosaic refuses, more VMEM than a kernel
 may use — fails here and costs no chip time. Nothing runs: no time, no result.
 
@@ -156,3 +158,58 @@ def test_every_scan_kernel_of_a_checkpointed_mamba_mixer_carries_its_scope(
     scope_map = common.load_module("drivers", "resident_lm_share").scope_map
     scopes = scope_map(text, common.load_module("flops", "nemotron_h").SCOPES)
     assert [scopes.get(name) for name in calls] == ["nemotron_h/mamba/ssd"] * 4
+
+
+# (the zoo's module, a configuration of few layers at the cell's attention
+# shapes, the scope of each attention block's kernels in program order)
+RECOMPUTED_ATTENTION = {
+    # glm-4.7-flash.resident-8k: the dense layer and the module's own sparse
+    # layer, 20 heads of 192 + 64 / 256
+    "glm": ("glm4_moe_lite", dict(
+        num_hidden_layers=1, first_k_dense_replace=1, num_nextn_predict_layers=1,
+        n_routed_experts=8, router_experts=64, vocab_size=512),
+        ["glm4_moe_lite/mla/attn", "glm4_moe_lite/mtp/mla/attn"]),
+    # nemotron-3-nano-30b-a3b.resident-8k: 32 query heads on 2 key-value heads
+    # of 128 (a sparse-expert layer after it: `forward` stacks their statistics)
+    "nemotron": ("nemotron_h", dict(
+        num_hidden_layers=2, hybrid_override_pattern="*E", n_routed_experts=8,
+        router_experts=128, vocab_size=512), ["nemotron_h/attn"]),
+}
+
+
+@pytest.mark.parametrize("model", sorted(RECOMPUTED_ATTENTION))
+def test_a_recomputed_layer_runs_the_flash_forward_once_under_its_scope(
+        model, one_chip, no_compile_cache, monkeypatch):
+    """`forward` checkpoints its layers with `pallas_attention.
+    KEEP_RESIDUALS`: the gradient program at 8192 tokens compiles with one
+    `flash_attention_fwd` call a block (two under the plain checkpoint), one
+    `bwd_dq` and one `bwd_dkv`, and `scope_map` finds each under the block's
+    own `attn` scope — the benchmark's `mla_ms`, `mtp_ms` read them there."""
+    import importlib
+
+    from benchmark import common
+
+    module, config, scopes = RECOMPUTED_ATTENTION[model]
+    zoo = importlib.import_module(f"model_zoo.transformer.{module}")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+    net = zoo.custom_model(**config)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    variables = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
+
+    def loss(params, router_state, tokens):
+        outputs = net.apply({"params": params, "router_state": router_state}, tokens)
+        return sum(jnp.sum(jnp.square(x)) for x in jax.tree_util.tree_leaves(outputs))
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        variables["params"], variables["router_state"], tokens).compile().as_text()
+    calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
+    kinds = [re.sub(r"\.\d+$", "", name) for name in calls]
+    assert sorted(kinds) == sorted(
+        ["flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"]
+        * len(scopes))
+    scope_map = common.load_module("drivers", "resident_lm_share").scope_map
+    found = scope_map(text, common.load_module("flops", module).SCOPES)
+    for kind in set(kinds):
+        assert sorted(found.get(name) for name, k in zip(calls, kinds) if k == kind) == scopes, kind
