@@ -38,14 +38,33 @@ from elasticdl_tpu.common.constants import MeshAxis
 NEG_BIG = -1e30  # finite "-inf": avoids nan from (-inf) - (-inf) in softmax
 
 
+def _visible(q_len: int, kv_len: int, q_offset, kv_offset,
+             window: Optional[int]) -> jax.Array:
+    """(Tq, Tk) bool: key j is visible to query i iff j <= i, and under a
+    `window` W iff also j > i - W (W keys, the query's own position among
+    them), in GLOBAL positions."""
+    q_pos = (q_offset + jnp.arange(q_len))[:, None]
+    kv_pos = (kv_offset + jnp.arange(kv_len))[None, :]
+    mask = kv_pos <= q_pos
+    if window is not None:
+        mask &= kv_pos > q_pos - window
+    return mask
+
+
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    causal: bool = True,
-                   q_offset: int = 0, kv_offset: int = 0) -> jax.Array:
+                   q_offset: int = 0, kv_offset: int = 0,
+                   window: Optional[int] = None) -> jax.Array:
     """Plain softmax attention. q: (B, T, H, D); k, v: (B, T, Hkv, D) with H a
     multiple of Hkv (grouped-query attention: query head h attends with
     key-value head h // (H/Hkv); Hkv == H is the ordinary case). The offsets position the
     local q/kv blocks in the GLOBAL sequence for causal masking (used by the
     sequence-parallel paths; leave 0 for unsharded attention).
+
+    `window=W` (sliding-window attention; causal only): query i sees the W
+    keys i - W < j <= i. With zero offsets the flash kernel runs its banded
+    grids (`flash_attention_swa_*`); with offsets — a sequence-parallel
+    caller — the kernel declines and this XLA path applies the same mask.
 
     On TPU this dispatches to the Pallas flash kernel
     (ops/pallas_attention.py) when shapes/offsets allow — 3-6x faster
@@ -53,26 +72,31 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     score matrix. EDL_FLASH=0 forces this XLA fallback everywhere.
 
     Backend-divergence caveat: for a FULLY-masked row (possible only with
-    offset geometries where kv_offset > q_offset + Tq - 1) the kernel
+    offset geometries where kv_offset > q_offset + Tq - 1, or under a window
+    where every key of the block lies before it) the kernel
     returns zeros while this XLA path returns the uniform softmax over
     NEG_BIG scores. No in-tree caller produces such rows (the
-    sequence-parallel paths always include the diagonal); external callers
+    sequence-parallel paths always include the diagonal, and under a window
+    with zero offsets none can arise: a query's own position is always
+    visible); external callers
     passing exotic offsets should not rely on either value."""
     from elasticdl_tpu.ops import pallas_attention
 
+    if window is not None and not causal:
+        raise ValueError("a window is the lower bound of a CAUSAL mask")
     if pallas_attention.can_flash(q.shape, k.shape, q_offset, kv_offset,
-                                  dtype=q.dtype):
+                                  dtype=q.dtype, window=window):
         return pallas_attention.flash_attention(
-            q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset)
+            q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset,
+            window=window)
     scale = q.shape[-1] ** -0.5
     if k.shape[2] != q.shape[2]:
-        return _grouped_query_attention(q, k, v, causal, q_offset, kv_offset)
+        return _grouped_query_attention(q, k, v, causal, q_offset, kv_offset,
+                                        window)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     s = s * scale
     if causal:
-        q_pos = q_offset + jnp.arange(q.shape[1])
-        kv_pos = kv_offset + jnp.arange(k.shape[1])
-        mask = kv_pos[None, :] <= q_pos[:, None]
+        mask = _visible(q.shape[1], k.shape[1], q_offset, kv_offset, window)
         s = jnp.where(mask[None, None], s, NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
@@ -80,7 +104,8 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.astype(q.dtype)
 
 
-def _grouped_query_attention(q, k, v, causal, q_offset, kv_offset):
+def _grouped_query_attention(q, k, v, causal, q_offset, kv_offset,
+                             window=None):
     """The XLA fallback with fewer key-value heads than query heads: the
     query heads are viewed as (Hkv, group) and each group shares its k, v."""
     b, tq, h, d = q.shape
@@ -92,9 +117,8 @@ def _grouped_query_attention(q, k, v, causal, q_offset, kv_offset):
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                    preferred_element_type=jnp.float32) * d ** -0.5
     if causal:
-        q_pos = q_offset + jnp.arange(tq)
-        kv_pos = kv_offset + jnp.arange(k.shape[1])
-        s = jnp.where(kv_pos[None, :] <= q_pos[:, None], s, NEG_BIG)
+        s = jnp.where(_visible(tq, k.shape[1], q_offset, kv_offset, window),
+                      s, NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)

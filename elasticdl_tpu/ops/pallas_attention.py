@@ -49,10 +49,34 @@ and q blocks share the innermost, revisiting grid axis). With H == Hkv the
 three kernels are what they were.
 
 Fully-masked causal blocks are skipped (`pl.when`), giving the ~2x causal
-FLOP saving without dynamic shapes. Fully-masked ROWS (a q block entirely
+FLOP saving without dynamic shapes — of the arithmetic only: the unbanded grid
+still has a step for every (q block, kv block) pair, and a skipped step still
+fetches its K/V block. Fully-masked ROWS (a q block entirely
 before every kv position) return 0 with lse=NEG_BIG, unlike the XLA path's
 finite-NEG_BIG uniform softmax — zero is the defensible answer, the ring
 merge relies on the NEG_BIG lse, and no real caller consumes such rows.
+
+Sliding-window attention (`window=W`: key j is visible to query i iff
+i − W < j ≤ i, W keys with the query's own position among them) runs a BANDED
+grid: the kv axis of the forward's and the dq kernel's grids has only as many
+steps as a q block's band spans kv blocks (`_kv_band`: 2 at bq = bk = W), the
+K/V index maps start at the first block the q block can see, and the dkv
+kernel's q axis is banded the same way (`_q_band`). So a windowed layer pays
+neither the DMA nor the grid steps of the keys it cannot see. A step past the
+band's end (the first q blocks' bands are shorter) is skipped and its index
+map repeats the previous block, which fetches nothing. The element mask is
+applied in the band's edge blocks only. The windowed kernels carry names of
+their own (`flash_attention_swa_fwd`, `_swa_bwd_dq`, `_swa_bwd_dkv`), so that
+a trace tells a model's windowed layers from its full ones. Windows are for
+UNSHARDED attention: the band's index maps are computed from the block index
+alone, so a windowed call takes no offsets (`can_flash` declines one, and
+`ops.attention.full_attention` then takes its XLA path). A window of at least
+the key length is the causal call, kernel for kernel. Under a window with
+zero offsets no row is fully masked (the diagonal is always visible), but a
+row can be fully masked WITHIN the first block of its band: the running
+maximum then stays NEG_BIG, the block's garbage is multiplied by
+exp(NEG_BIG − m) = 0 when the row's first visible key arrives, and nothing of
+it is left.
 """
 
 from __future__ import annotations
@@ -75,6 +99,17 @@ _LANE = 128      # TPU lane width: minor dims of scratch/residuals
 # (512,512) 7.6ms, (512,1024) 5.9ms, (1024,1024) 5.5ms. Large KV blocks
 # amortize the per-grid-step overhead; VMEM at (1024,1024) stays ~10 MB
 # (the f32 score block dominates: bq*bk*4 = 4 MB).
+# Under a window (the banded kernels) the same targets win: T=16384, 32/4
+# heads of 128, bfloat16, W=1024 on a v5e, forward / forward + backward ms
+# (my chip run, PR 36): (1024,1024) 6.08 / 18.11 — two kv blocks a q block,
+# 2048 keys computed for 1024 visible — (512,1024) 6.53 / 18.94, (512,512)
+# 8.62 / 18.91, (256,1024) 7.92 / 20.96, (256,512) 9.59 / 22.22, (1024,512)
+# 10.28 / 22.91 (FOUR kv blocks of 512 a q block, not three: a q block's
+# rows span 2047 keys), (512,256) 14.41 / 28.22, (256,256) 14.33 / 29.75,
+# (1024,256) 17.85 / 33.08; (2048,1024) does not fit VMEM. Fewer keys
+# computed does not pay for more grid steps. The causal call on the same
+# operands: 19.65 / 74.90 at (1024,1024), 4.1 times the banded one for 8.26
+# times the visible pairs.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 
@@ -194,10 +229,69 @@ def kernel_interpret(requested: Optional[bool] = None) -> bool:
     return interpret
 
 
-def _causal_p_mask(p, q_start, kv_start, block_q, block_k):
+def _causal_p_mask(p, q_start, kv_start, block_q, block_k, window=None):
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    return jnp.where(kv_pos <= q_pos, p, 0.0) if p is not None else kv_pos <= q_pos
+    visible = kv_pos <= q_pos
+    if window is not None:
+        visible &= kv_pos > q_pos - window
+    return jnp.where(visible, p, 0.0) if p is not None else visible
+
+
+# ------------------------------------------------------------------ the band
+# Under a window W (zero offsets) q block i sees the kv blocks
+# first_kv(i) .. last_kv(i), and kv block x is seen by the q blocks
+# first_q(x) .. last_q(x). The same expressions serve the index maps and the
+# kernels (traced block indices) and, with Python's own `max` and `min`, the
+# static step counts.
+
+
+def _first_kv(i, bq, bk, window, maximum=jnp.maximum):
+    return maximum(i * bq - window + 1, 0) // bk
+
+
+def _last_kv(i, bq, bk, num_kv, minimum=jnp.minimum):
+    return minimum(((i + 1) * bq - 1) // bk, num_kv - 1)
+
+
+def _first_q(x, bq, bk):
+    return (x * bk) // bq
+
+
+def _last_q(x, bq, bk, window, num_q, minimum=jnp.minimum):
+    return minimum(((x + 1) * bk + window - 2) // bq, num_q - 1)
+
+
+def _kv_band(num_q, num_kv, bq, bk, window):
+    """(steps of the banded kv axis, blocks that compute a head): the most kv
+    blocks any q block's band spans, and their sum over the q blocks."""
+    spans = [_last_kv(i, bq, bk, num_kv, min) - _first_kv(i, bq, bk, window, max) + 1
+             for i in range(num_q)]
+    return max(spans), sum(spans)
+
+
+def _q_band(num_q, num_kv, bq, bk, window):
+    """The most q blocks that see any one kv block."""
+    return max(_last_q(x, bq, bk, window, num_q, min) - _first_q(x, bq, bk) + 1
+               for x in range(num_kv))
+
+
+def _block_kind(q_start, kv_start, block_q, block_k, window, in_range):
+    """(live, inner) of a windowed block: `live` if any of its (query, key)
+    pairs is visible and the block exists (`in_range`: a step past the band's
+    end), `inner` if every pair is, so that it needs no element mask."""
+    live = (in_range & (kv_start <= q_start + block_q - 1)
+            & (kv_start + block_k - 1 > q_start - window))
+    inner = ((kv_start + block_k - 1 <= q_start)
+             & (kv_start >= q_start + block_q - window))
+    return live, inner
+
+
+def _when_banded(live, inner, body):
+    """Run `body(masked)` for a live block: with the element mask in an edge
+    block, without it inside the band."""
+    pl.when(live & inner)(lambda: body(False))
+    pl.when(live & jnp.logical_not(inner))(lambda: body(True))
 
 
 def _sds(shape, dtype, like):
@@ -224,9 +318,15 @@ def _kv_head_of(heads: int, kv_heads: int):
 # ---------------------------------------------------------------- forward
 
 
+def _kernel_name(part: str, window) -> str:
+    return f"flash_attention_{'swa_' if window is not None else ''}{part}"
+
+
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc, m_scr, l_scr, *, scale, causal, block_q, block_k,
-                num_kv):
+                num_kv, window=None, kv_blocks=None):
+    """`num_kv`: the steps of the grid's kv axis — every kv block, or under a
+    `window` the band's steps, of `kv_blocks` kv blocks in all."""
     i = pl.program_id(2)
     j = pl.program_id(3)
     q_off = offs_ref[0]
@@ -238,14 +338,13 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[:] = jnp.full_like(m_scr, NEG_BIG)
         l_scr[:] = jnp.zeros_like(l_scr)
 
+    # the kv block of this step: under a window the band starts at the first
+    # block the q block can see
+    jb = j if window is None else _first_kv(i, block_q, block_k, window) + j
     q_start = q_off + i * block_q
-    kv_start = kv_off + j * block_k
-    # causal: skip KV blocks entirely above the diagonal (traced predicate
-    # — offsets come from SMEM, so this is runtime block skipping)
-    live = True if not causal else kv_start <= q_start + block_q - 1
+    kv_start = kv_off + jb * block_k
 
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(masked):
         q = q_ref[0, 0]                             # (bq, D)
         k = k_ref[0, 0]                             # (bk, D)
         v = v_ref[0, 0]
@@ -253,8 +352,9 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                                   # (bq, bk)
-        if causal:
-            mask = _causal_p_mask(None, q_start, kv_start, block_q, block_k)
+        if masked:
+            mask = _causal_p_mask(None, q_start, kv_start, block_q, block_k,
+                                  window)
             s = jnp.where(mask, s, NEG_BIG)
 
         m_prev = m_scr[:, :1]                       # (bq, 1)
@@ -270,6 +370,15 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
+    if window is None:
+        # causal: skip KV blocks entirely above the diagonal (traced predicate
+        # — offsets come from SMEM, so this is runtime block skipping)
+        live = True if not causal else kv_start <= q_start + block_q - 1
+        pl.when(live)(lambda: _accumulate(causal))
+    else:
+        _when_banded(*_block_kind(q_start, kv_start, block_q, block_k, window,
+                                  jb <= kv_blocks - 1), _accumulate)
+
     @pl.when(j == num_kv - 1)
     def _finalize():
         l = l_scr[:, :1]
@@ -282,24 +391,36 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         )
 
 
-def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret):
+def _banded_kv_at(bq, bk, window, num_kv):
+    """(q block, step) -> the kv block a banded grid's step reads: the band's
+    own, or past its end the band's last again (no new fetch)."""
+    return lambda i, j: jnp.minimum(_first_kv(i, bq, bk, window) + j,
+                                    _last_kv(i, bq, bk, num_kv))
+
+
+def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret, window=None):
     """offs: (2,) int32 [q_off, kv_off]; qt/kt/vt: (B, H, T, D)."""
     B, H, Tq, D = qt.shape
     Tk = kt.shape[2]
     num_q, num_kv = Tq // bq, Tk // bk
     scale = D ** -0.5
     kv_head = _kv_head_of(H, kt.shape[1])
+    if window is None:
+        steps, kv_at = num_kv, lambda i, j: j
+    else:
+        steps = _kv_band(num_q, num_kv, bq, bk, window)[0]
+        kv_at = _banded_kv_at(bq, bk, window, num_kv)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
-        block_q=bq, block_k=bk, num_kv=num_kv,
+        block_q=bq, block_k=bk, num_kv=steps, window=window, kv_blocks=num_kv,
     )
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, offs: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, D),
-                           lambda b, h, i, j, offs: (b, kv_head(h), j, 0))
+                           lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, H, num_q, num_kv),
+        grid=(B, H, num_q, steps),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
             q_spec,
@@ -320,7 +441,7 @@ def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret):
             _sds((B, H, Tq, _LANE), jnp.float32, qt),
         ],
         interpret=interpret,
-        name="flash_attention_fwd",
+        name=_kernel_name("fwd", window),
     )(offs, qt, kt, vt)
     return out, lse
 
@@ -328,16 +449,17 @@ def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret):
 # ---------------------------------------------------------------- backward
 
 
-def _p_and_ds(q, k, v, do, lse, delta, *, scale, causal, q_start, kv_start,
-              block_q, block_k):
+def _p_and_ds(q, k, v, do, lse, delta, *, scale, masked, q_start, kv_start,
+              block_q, block_k, window=None):
     """Recompute the (bq, bk) p block from saved lse, and ds = p*(dp-delta).
-    lse/delta: (bq, 1) float32."""
+    lse/delta: (bq, 1) float32. `masked`: apply the element mask (causal, and
+    the window's lower bound if there is one)."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
     ) * scale
     p = jnp.exp(s - lse)
-    if causal:
-        p = _causal_p_mask(p, q_start, kv_start, block_q, block_k)
+    if masked:
+        p = _causal_p_mask(p, q_start, kv_start, block_q, block_k, window)
     dp = jax.lax.dot_general(
         do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -348,7 +470,8 @@ def _p_and_ds(q, k, v, do, lse, delta, *, scale, causal, q_start, kv_start,
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                    glse_ref, dq_ref, dq_acc, delta_scr, *, scale, causal,
-                   block_q, block_k, num_kv):
+                   block_q, block_k, num_kv, window=None, kv_blocks=None):
+    """`num_kv`, `window`, `kv_blocks`: as `_fwd_kernel`'s."""
     i = pl.program_id(2)
     j = pl.program_id(3)
     q_off = offs_ref[0]
@@ -366,23 +489,29 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             - (glse_ref[0, 0, :, :1] if glse_ref is not None else 0.0),
             delta_scr.shape)
 
+    jb = j if window is None else _first_kv(i, block_q, block_k, window) + j
     q_start = q_off + i * block_q
-    kv_start = kv_off + j * block_k
-    live = True if not causal else kv_start <= q_start + block_q - 1
+    kv_start = kv_off + jb * block_k
 
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         do = do_ref[0, 0].astype(jnp.float32)
         _, ds = _p_and_ds(
             q, k, v_ref[0, 0], do, lse_ref[0, 0, :, :1], delta_scr[:, :1],
-            scale=scale, causal=causal, q_start=q_start, kv_start=kv_start,
-            block_q=block_q, block_k=block_k)
+            scale=scale, masked=masked, q_start=q_start, kv_start=kv_start,
+            block_q=block_q, block_k=block_k, window=window)
         dq_acc[:] += jax.lax.dot_general(
             ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
+
+    if window is None:
+        live = True if not causal else kv_start <= q_start + block_q - 1
+        pl.when(live)(lambda: _accumulate(causal))
+    else:
+        _when_banded(*_block_kind(q_start, kv_start, block_q, block_k, window,
+                                  jb <= kv_blocks - 1), _accumulate)
 
     @pl.when(j == num_kv - 1)
     def _finalize():
@@ -391,12 +520,18 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                     glse_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                    causal, block_q, block_k, num_q, group=1):
+                    causal, block_q, block_k, num_q, group=1, window=None,
+                    q_blocks=None):
+    """`num_q`: the steps a query head takes of the innermost axis — every q
+    block, or under a `window` the steps of the band of q blocks that see
+    this kv block, of `q_blocks` q blocks in all."""
     kv = pl.program_id(2)
     # the innermost axis walks the q blocks of every query head that reads
     # this key-value head: `group` heads of num_q blocks each
     inner = pl.program_id(3)
     qi = inner if group == 1 else inner % num_q
+    if window is not None:
+        qi = _first_q(kv, block_q, block_k) + qi
     q_off = offs_ref[0]
     kv_off = offs_ref[1]
 
@@ -407,10 +542,8 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
     q_start = q_off + qi * block_q
     kv_start = kv_off + kv * block_k
-    live = True if not causal else kv_start <= q_start + block_q - 1
 
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         do = do_ref[0, 0].astype(jnp.float32)
@@ -420,8 +553,8 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             delta = delta - glse_ref[0, 0, :, :1]
         p, ds = _p_and_ds(
             q, k, v_ref[0, 0], do, lse_ref[0, 0, :, :1], delta,
-            scale=scale, causal=causal, q_start=q_start, kv_start=kv_start,
-            block_q=block_q, block_k=block_k)
+            scale=scale, masked=masked, q_start=q_start, kv_start=kv_start,
+            block_q=block_q, block_k=block_k, window=window)
         dv_acc[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -431,13 +564,20 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         ) * scale                                          # (bk, D)
 
+    if window is None:
+        live = True if not causal else kv_start <= q_start + block_q - 1
+        pl.when(live)(lambda: _accumulate(causal))
+    else:
+        _when_banded(*_block_kind(q_start, kv_start, block_q, block_k, window,
+                                  qi <= q_blocks - 1), _accumulate)
+
     @pl.when(inner == group * num_q - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
+def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
     """g: cotangent of out (B, T, H, D); g_lse: cotangent of lse (B, H, Tq)
     or None (out-only variant)."""
     offs, qt, kt, vt, ot, lse = res              # (B, H, T, D) / lse 4D
@@ -446,6 +586,16 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
     num_q, num_kv = Tq // bq, Tk // bk
     scale = D ** -0.5
     kv_head, group = _kv_head_of(H, Hkv), H // Hkv
+    if window is None:
+        kv_steps, kv_at = num_kv, lambda i, j: j
+        q_steps, q_block_at = num_q, lambda x, y: y
+    else:
+        kv_steps = _kv_band(num_q, num_kv, bq, bk, window)[0]
+        kv_at = _banded_kv_at(bq, bk, window, num_kv)
+        q_steps = _q_band(num_q, num_kv, bq, bk, window)
+        # past the band's end its last q block again: no new fetch
+        q_block_at = lambda x, y: jnp.minimum(
+            _first_q(x, bq, bk) + y, _last_q(x, bq, bk, window, num_q))
     gt = g.transpose(0, 2, 1, 3)                 # (B, H, Tq, D)
     with_glse = g_lse is not None
     extra = ()
@@ -458,11 +608,12 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
         glse_ref, tail = (rest[0], rest[1:]) if with_glse else (None, rest)
         _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
                        lse_ref, glse_ref, *tail, scale=scale, causal=causal,
-                       block_q=bq, block_k=bk, num_kv=num_kv)
+                       block_q=bq, block_k=bk, num_kv=kv_steps, window=window,
+                       kv_blocks=num_kv)
 
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, offs: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, D),
-                           lambda b, h, i, j, offs: (b, kv_head(h), j, 0))
+                           lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
     lse_spec = pl.BlockSpec((1, 1, bq, _LANE),
                             lambda b, h, i, j, offs: (b, h, i, 0))
 
@@ -470,7 +621,7 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
         dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, num_q, num_kv),
+            grid=(B, H, num_q, kv_steps),
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec]
             + ([lse_spec] if with_glse else []),
             out_specs=[q_spec],
@@ -481,7 +632,7 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
         ),
         out_shape=[_sds(qt.shape, qt.dtype, qt)],
         interpret=interpret,
-        name="flash_attention_bwd_dq",
+        name=_kernel_name("bwd_dq", window),
     )(offs, qt, kt, vt, ot, gt, lse, *extra)[0]
 
     # dk/dv sweep: kv block outer (revisited output), q block inner
@@ -490,14 +641,17 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
         glse_ref, tail = (rest[0], rest[1:]) if with_glse else (None, rest)
         _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
                         lse_ref, glse_ref, *tail, scale=scale, causal=causal,
-                        block_q=bq, block_k=bk, num_q=num_q, group=group)
+                        block_q=bq, block_k=bk, num_q=q_steps, group=group,
+                        window=window, q_blocks=num_q)
 
     # grid axis 1 counts KEY-VALUE heads; y walks the group's query heads and
-    # their q blocks (y = head_in_group * num_q + q block)
+    # their q blocks (y = head_in_group * q_steps + step: the q block itself,
+    # or under a window the step of the band that sees kv block x)
     if group == 1:
-        q_at = lambda b, h, x, y, offs: (b, h, y, 0)
+        q_at = lambda b, h, x, y, offs: (b, h, q_block_at(x, y), 0)
     else:
-        q_at = lambda b, h, x, y, offs: (b, h * group + y // num_q, y % num_q, 0)
+        q_at = lambda b, h, x, y, offs: (
+            b, h * group + y // q_steps, q_block_at(x, y % q_steps), 0)
     q_spec2 = pl.BlockSpec((1, 1, bq, D), q_at)
     kv_spec2 = pl.BlockSpec((1, 1, bk, D), lambda b, h, x, y, offs: (b, h, x, 0))
     lse_spec2 = pl.BlockSpec((1, 1, bq, _LANE), q_at)
@@ -505,7 +659,7 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, Hkv, num_kv, group * num_q),
+            grid=(B, Hkv, num_kv, group * q_steps),
             in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, q_spec2,
                       lse_spec2] + ([lse_spec2] if with_glse else []),
             out_specs=[kv_spec2, kv_spec2],
@@ -519,7 +673,7 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
             _sds(vt.shape, vt.dtype, vt),
         ],
         interpret=interpret,
-        name="flash_attention_bwd_dkv",
+        name=_kernel_name("bwd_dkv", window),
     )(offs, qt, kt, vt, ot, gt, lse, *extra)
 
     back = lambda x: x.transpose(0, 2, 1, 3)
@@ -531,17 +685,18 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret):
 
 @functools.lru_cache(maxsize=None)
 def _make_flash(causal: bool, bq: int, bk: int, interpret: bool,
-                with_lse: bool):
+                with_lse: bool, window: Optional[int] = None):
     """Returns flash(offs, q, k, v) -> out, or (out, lse(B, H, Tq)) when
     `with_lse` — the lse variant also backpropagates lse's cotangent (the
-    ring merge differentiates through it)."""
+    ring merge differentiates through it). With a `window` the three kernels
+    run their banded grids."""
 
     def _fwd_transposed(offs, q, k, v):
         qt = q.transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)
         vt = v.transpose(0, 2, 1, 3)
         out, lse = _flash_fwd(offs, qt, kt, vt, causal=causal, bq=bq, bk=bk,
-                              interpret=interpret)
+                              interpret=interpret, window=window)
         # named here, where the residuals are made, so that nothing reads an
         # un-named one
         return (offs, *map(checkpoint_name, (qt, kt, vt, out, lse),
@@ -561,7 +716,7 @@ def _make_flash(causal: bool, bq: int, bk: int, interpret: bool,
     def bwd(res, ct):
         g, g_lse = ct if with_lse else (ct, None)
         return _flash_bwd(res, g, g_lse, causal=causal, bq=bq, bk=bk,
-                          interpret=interpret)
+                          interpret=interpret, window=window)
 
     flash.defvjp(fwd, bwd)
     return flash
@@ -571,15 +726,18 @@ def flash_attention_lse(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool = True,
     q_offset=0, kv_offset=0,
-    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Flash attention over q (B, T, H, D) and k/v (B, T, Hkv, D), H a
     multiple of Hkv, returning (out, lse) with lse (B, H, Tq) float32. Offsets may be Python ints OR traced int32
-    scalars (they ride scalar prefetch). Raises ValueError when the shapes
-    can't be blocked — use `can_flash` first."""
+    scalars (they ride scalar prefetch). `window=W` (causal, zero offsets):
+    query i sees keys i − W < j ≤ i, through the banded kernels. Raises
+    ValueError when the shapes can't be blocked — use `can_flash` first."""
     flash, offs = _plan_call(q, k, causal, q_offset, kv_offset,
-                             block_q, block_k, interpret, with_lse=True)
+                             block_q, block_k, interpret, with_lse=True,
+                             window=window)
     return flash(offs, q, k, v)
 
 
@@ -587,19 +745,42 @@ def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool = True,
     q_offset=0, kv_offset=0,
-    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Same contract as `ops.attention.full_attention` (output only; the
     cheaper backward — no lse cotangent input)."""
     flash, offs = _plan_call(q, k, causal, q_offset, kv_offset,
-                             block_q, block_k, interpret, with_lse=False)
+                             block_q, block_k, interpret, with_lse=False,
+                             window=window)
     return flash(offs, q, k, v)
 
 
+def _no_offset(offset) -> bool:
+    return isinstance(offset, int) and offset == 0
+
+
+def _effective_window(window, causal, q_offset, kv_offset, t_k):
+    """`window` as the kernels take it: None for no window and for one that
+    hides no key (W >= Tk: the causal call, kernel for kernel)."""
+    if window is None:
+        return None
+    if window < 1:
+        raise ValueError(f"window={window}: a query sees at least itself")
+    if not causal:
+        raise ValueError("a window is the lower bound of a CAUSAL mask")
+    if not (_no_offset(q_offset) and _no_offset(kv_offset)):
+        raise ValueError(
+            "windowed flash attention is for unsharded attention: it takes "
+            f"no offsets (q_offset={q_offset!r}, kv_offset={kv_offset!r})")
+    return None if window >= t_k else int(window)
+
+
 def _plan_call(q, k, causal, q_offset, kv_offset, block_q, block_k,
-               interpret, with_lse):
+               interpret, with_lse, window=None):
     interpret = kernel_interpret(interpret)
+    window = _effective_window(window, causal, q_offset, kv_offset, k.shape[1])
     blocks = _plan_blocks(q.shape, k.shape, block_q, block_k,
                           dtype=q.dtype)
     if blocks is None:
@@ -611,13 +792,31 @@ def _plan_call(q, k, causal, q_offset, kv_offset, block_q, block_k,
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(kv_offset, jnp.int32)])
     return _make_flash(bool(causal), bq, bk, interpret,
-                       bool(with_lse)), offs
+                       bool(with_lse), window), offs
+
+
+def kv_block_visits(t_q: int, t_k: int, window: Optional[int],
+                    head_dim: int = _LANE, dtype=None) -> Tuple[int, int]:
+    """((q block, kv block) pairs a head's forward grid COMPUTES under
+    `window`, the pairs the causal grid computes), at the blocks the call
+    would plan: 31 and 136 at 16 384 tokens, 1024-blocks and a window of
+    1024. (0, 0) where the shapes cannot be blocked."""
+    window = _effective_window(window, True, 0, 0, t_k)
+    blocks = _plan_blocks((1, t_q, 1, head_dim), (1, t_k, 1, head_dim), None, None, dtype)
+    if blocks is None:
+        return 0, 0
+    bq, bk = blocks
+    # a window of every key is the causal band
+    banded, causal = (_kv_band(t_q // bq, t_k // bk, bq, bk, w)[1]
+                      for w in (window or t_q + t_k, t_q + t_k))
+    return banded, causal
 
 
 def _plan_blocks(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
-                 block_q: int, block_k: int,
+                 block_q: Optional[int], block_k: Optional[int],
                  dtype=None) -> Optional[Tuple[int, int]]:
-    """(block_q, block_k) for these shapes, or None. The targets are for a
+    """(block_q, block_k) for these shapes, or None. Targets not given are
+    `DEFAULT_BLOCK_*`, with or without a window. The targets are for a
     head of at most the lane width; a wider head takes a key block smaller in
     proportion, so that a key block's rows times the head size stay what they
     are at 128. At head 256 and (1024, 1024) the dq kernel's blocks and
@@ -625,6 +824,8 @@ def _plan_blocks(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
     bfloat16 on a v5e, forward / forward + backward: (1024, 512) 6.66 / 25.6
     ms, (512, 1024) 7.20 / 25.6, (512, 512) 8.44 / 28.3, (256, 1024) 9.46 /
     30.2 (PERF.md section 6, PR 32)."""
+    block_q = block_q or DEFAULT_BLOCK_Q
+    block_k = block_k or DEFAULT_BLOCK_K
     mb = _min_block(dtype)
     if q_shape[-1] > _LANE:
         block_k = max(mb, block_k * _LANE // q_shape[-1])
@@ -636,20 +837,23 @@ def _plan_blocks(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
 
 
 def can_flash(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
-              q_offset=0, kv_offset=0, dtype=None) -> bool:
+              q_offset=0, kv_offset=0, dtype=None,
+              window: Optional[int] = None) -> bool:
     """True when flash_attention supports these shapes/dtype AND a backend
     that can run the Mosaic kernel is active: real TPU, or CPU inside
     `force_tpu_interpret_mode` (tests). EDL_FLASH=0 force-disables;
     EDL_FLASH=1 force-enables but ONLY on those backends — on plain CPU/GPU
     the kernel has no compile path, so forcing it there would crash rather
-    than fall back. Offsets may be traced — they are accepted for API
-    symmetry and ignored."""
-    del q_offset, kv_offset
+    than fall back. Offsets may be traced; without a window they are
+    accepted for API symmetry and ignored. A `window` is DECLINED with any
+    offset that is not the Python integer 0 (ring attention's are traced):
+    the banded grids are for unsharded attention."""
+    if window is not None and not (_no_offset(q_offset) and _no_offset(kv_offset)):
+        return False
     flag = os.environ.get("EDL_FLASH", "")
     if flag == "0":
         return False
-    if _plan_blocks(q_shape, k_shape, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K,
-                    dtype=dtype) is None:
+    if _plan_blocks(q_shape, k_shape, None, None, dtype=dtype) is None:
         return False
     runnable = jax.default_backend() == "tpu" or _interpret_active()
     if flag == "1":

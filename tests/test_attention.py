@@ -95,3 +95,69 @@ def test_ulysses_rejects_indivisible_heads(seq_mesh):
                     q, k, v, mode="ulysses"
                 )
             )(x, x, x)
+
+
+# ------------------------------------------------------------------ #
+# sliding-window attention on the XLA path
+
+
+def _dense_window(q, k, v, window, q_offset=0, kv_offset=0):
+    """Softmax over the keys j with i - window < j <= i, written out densely
+    from GLOBAL positions, key-value heads repeated for their groups."""
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    i = (q_offset + jnp.arange(q.shape[1]))[:, None]
+    j = (kv_offset + jnp.arange(k.shape[1]))[None, :]
+    s = jnp.where((j <= i) & (j > i - window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _heads(t, heads, kv_heads, seed=0):
+    r = np.random.RandomState(seed)
+    draw = lambda h: jnp.asarray(r.randn(2, t, h, 8), jnp.float32)
+    return draw(heads), draw(kv_heads), draw(kv_heads)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 1), (8, 1), (8, 2)])
+@pytest.mark.parametrize("window", [1, 5, 15, 16, 17, 40, 64])
+def test_windowed_fallback_matches_a_dense_mask(window, heads, kv_heads):
+    """`full_attention`'s XLA path and `_grouped_query_attention` (fewer
+    key-value heads) under a window, T = 40 not a multiple of it."""
+    q, k, v = _heads(40, heads, kv_heads)
+    np.testing.assert_allclose(np.asarray(full_attention(q, k, v, window=window)),
+                               np.asarray(_dense_window(q, k, v, window)), atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (8, 2)])
+def test_windowed_fallback_gradients_match_a_dense_mask(heads, kv_heads):
+    q, k, v = _heads(40, heads, kv_heads, seed=1)
+    probe = jnp.asarray(np.random.RandomState(2).randn(*q.shape), jnp.float32)
+    got = jax.grad(lambda *a: jnp.sum(probe * full_attention(*a, window=7)),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(probe * _dense_window(*a, 7)), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 2)])
+def test_windowed_fallback_positions_blocks_by_their_offsets(heads, kv_heads):
+    """A sequence-parallel caller's local blocks: the window counts GLOBAL
+    positions (the q block starts 24 after the kv block; window 30 reaches
+    back into it, and every row sees at least one key)."""
+    q, k, v = _heads(16, heads, kv_heads, seed=3)
+    got = full_attention(q, k, v, q_offset=40, kv_offset=16, window=30)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_dense_window(q, k, v, 30, 40, 16)),
+                               atol=2e-6, rtol=2e-6)
+
+
+def test_a_window_of_the_whole_length_is_causal_attention():
+    q, k, v = _heads(40, 4, 2, seed=4)
+    np.testing.assert_array_equal(np.asarray(full_attention(q, k, v, window=40)),
+                                  np.asarray(full_attention(q, k, v)))
+
+
+def test_a_window_needs_a_causal_mask():
+    q, k, v = _heads(16, 2, 2)
+    with pytest.raises(ValueError, match="CAUSAL"):
+        full_attention(q, k, v, causal=False, window=4)

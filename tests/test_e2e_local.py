@@ -226,6 +226,37 @@ def test_local_glm4_moe_lite_job_end_to_end(tmp_path):
     assert master.servicer.mean_training_loss() < 1.3 * 6.0
 
 
+def test_local_mellum_job_end_to_end(tmp_path):
+    """Mellum2's block (three sliding-window layers to one full layer under
+    two rotary tables, 4/2 grouped-query heads, a held share of softmax-routed
+    experts with renormalised weights, the loss a dict beside a sown auxiliary
+    term) through the same master/worker path, evaluation included; the
+    window (8 keys) is shorter than the sequence (32)."""
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.mellum.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 48, "num_hidden_layers": 4,
+            "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+            "sliding_window": 8, "original_max_position_embeddings": 16,
+            "num_experts": 4, "router_experts": 16, "first_expert": 4,
+            "num_experts_per_tok": 3, "moe_intermediate_size": 24,
+            "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+    )
+    master, _, counts = run_job(cfg, tmp_path)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    assert 0.0 <= master.evaluation.latest_results()["token_accuracy"] <= 1.0
+    # ln 256 = 5.5, and four layers' load-balance terms at 0.01 each
+    assert master.servicer.mean_training_loss() < 6.0
+
+
 def test_run_job_stops_when_the_job_is_dead(tmp_path):
     """The harness itself (tests/jobs.py): a one-process worker started as
     cohort member 2 of 1 dies at world formation on every launch. run_job
