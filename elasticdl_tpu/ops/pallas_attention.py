@@ -9,6 +9,29 @@ blocks through VMEM with the online-softmax recurrence so scores never leave
 the chip's vector memory, and the backward recomputes them blockwise
 (flash-attention style) instead of saving them.
 
+The backward is ONE kernel (`flash_attention_bwd`, `_bwd_kernel`) wherever a
+key-value head fits the chip's VMEM whole (`bwd_route`: `resident`). Its grid
+is (B, key-value heads, the group's query heads x q blocks), the last axis
+sequential; the head's whole k and v (fetched once a head) and float32
+accumulators of its whole dk and dv stay in VMEM across that axis. A step
+takes one q block and loops over the kv blocks it sees — 0..i under the causal
+mask, the band under a window, from the offsets in SMEM — slicing k and v out
+of the resident block: s = q·kT, p = exp(s − lse), dp = do·vT and ds are
+computed ONCE for the pair and feed dv += pT·do, dk += dsT·q and the step's dq
++= ds·k. Five matmuls a pair, where the `split` route's two kernels
+(`flash_attention_bwd_dq`, `_bwd_dkv`: each streams kv blocks through Mosaic's
+default 16 MB and each recomputes s and dp) run seven, a second exp and a
+second fetch of q, k, v, do, o. The whole blocks and the masked edge blocks
+(the diagonal's; under a window also the band's first) are separate loops with
+straight-line bodies. The operands' dtypes and the order of every sum are the
+split route's — dq over kv blocks ascending, dk and dv over the group's heads,
+then q blocks — so the two routes agree to the bit, on the CPU and on a v5e
+(PERF.md section 6, PR 37). `bwd_route` decides from the keys, the head, the
+dtype and the chip's VMEM alone and logs its answer once a shape; a bfloat16
+head of 128 fits up to 16 384 keys, a head of 256 up to 8192, on a v5e's
+128 MiB. The call passes `vmem_limit_bytes` (three quarters of the chip's);
+the forward and the split kernels pass none.
+
 Layout: the public contract is (B, T, H, D) like `full_attention`; the
 kernel internally works on (B, H, T, D) because Mosaic requires the last two
 block dims to be (8·k, 128·k)-tiled or full — a per-head (…, 1, D) block in
@@ -44,9 +67,9 @@ Grouped-query attention: k and v may carry FEWER heads than q (B, T, Hkv, D
 with H a multiple of Hkv); query head h reads key-value head h // (H/Hkv)
 through the K/V BlockSpec index maps, so no copy of K or V widened to H
 heads exists in HBM, forward or backward. dK/dV of a key-value head are
-summed over its group's query heads inside the dkv kernel (the group's heads
-and q blocks share the innermost, revisiting grid axis). With H == Hkv the
-three kernels are what they were.
+summed over its group's query heads inside the backward kernel (the group's
+heads and q blocks share the innermost, revisiting grid axis; the split
+route's dkv kernel likewise). With H == Hkv the kernels are what they were.
 
 Fully-masked causal blocks are skipped (`pl.when`), giving the ~2x causal
 FLOP saving without dynamic shapes — of the arithmetic only: the unbanded grid
@@ -58,18 +81,20 @@ merge relies on the NEG_BIG lse, and no real caller consumes such rows.
 
 Sliding-window attention (`window=W`: key j is visible to query i iff
 i − W < j ≤ i, W keys with the query's own position among them) runs a BANDED
-grid: the kv axis of the forward's and the dq kernel's grids has only as many
-steps as a q block's band spans kv blocks (`_kv_band`: 2 at bq = bk = W), the
-K/V index maps start at the first block the q block can see, and the dkv
-kernel's q axis is banded the same way (`_q_band`). So a windowed layer pays
-neither the DMA nor the grid steps of the keys it cannot see. A step past the
-band's end (the first q blocks' bands are shorter) is skipped and its index
-map repeats the previous block, which fetches nothing. The element mask is
-applied in the band's edge blocks only. The windowed kernels carry names of
-their own (`flash_attention_swa_fwd`, `_swa_bwd_dq`, `_swa_bwd_dkv`), so that
-a trace tells a model's windowed layers from its full ones. Windows are for
-UNSHARDED attention: the band's index maps are computed from the block index
-alone, so a windowed call takes no offsets (`can_flash` declines one, and
+grid: the kv axis of the forward's grid (and of the split route's dq kernel's)
+has only as many steps as a q block's band spans kv blocks (`_kv_band`: 2 at
+bq = bk = W), the K/V index maps start at the first block the q block can see,
+and the split route's dkv kernel's q axis is banded the same way (`_q_band`);
+the resident backward's loop over a q block's kv blocks runs over its band
+(`_visible_kv`). So a windowed layer pays neither the DMA nor the grid steps of
+the keys it cannot see. A step of a banded grid past the band's end (the first
+q blocks' bands are shorter) is skipped and its index map repeats the previous
+block, which fetches nothing. The element mask is applied in the band's edge
+blocks only. The windowed kernels carry names of their own
+(`flash_attention_swa_fwd`, `_swa_bwd`; split: `_swa_bwd_dq`, `_swa_bwd_dkv`),
+so that a trace tells a model's windowed layers from its full ones. Windows
+are for UNSHARDED attention: the band's index maps are computed from the block
+index alone, so a windowed call takes no offsets (`can_flash` declines one, and
 `ops.attention.full_attention` then takes its XLA path). A window of at least
 the key length is the causal call, kernel for kernel. Under a window with
 zero offsets no row is fully masked (the diagonal is always visible), but a
@@ -83,8 +108,9 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import logging
 import os
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -92,8 +118,21 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+logger = logging.getLogger(__name__)
+
 NEG_BIG = -1e30  # finite "-inf", matches ops.attention
 _LANE = 128      # TPU lane width: minor dims of scratch/residuals
+# what the described chip of the rehearsals has (v5e: 128 MiB a core); a
+# visible TPU answers for itself
+_V5E_VMEM_BYTES = 128 << 20
+
+
+def _vmem_bytes() -> int:
+    try:
+        return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+    except Exception:   # no TPU visible: a rehearsal or interpret mode
+        return _V5E_VMEM_BYTES
+
 
 # Tuned on TPU v5 lite, T=4096 H8 D64 fwd+bwd: (256,256) 14.0ms,
 # (512,512) 7.6ms, (512,1024) 5.9ms, (1024,1024) 5.5ms. Large KV blocks
@@ -577,15 +616,222 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _visible_kv(q_start, kv_off, *, causal, block_q, block_k, num_kv, window):
+    """(lo, lo_in, hi_in, hi) of the kv blocks a q block whose first row is
+    `q_start` sees among `num_kv` that start at `kv_off`: lo..hi-1 hold a
+    visible pair, lo_in..hi_in-1 of them only visible pairs (no element mask),
+    so that lo..lo_in-1 (the band's lower edge, under a window) and
+    hi_in..hi-1 (the diagonal's blocks) are the masked ones. Traced: the
+    offsets come from SMEM."""
+    if not causal:
+        return 0, 0, num_kv, num_kv
+    rel = q_start - kv_off                      # the q block's first row, in keys
+    # kv block j is live iff j·bk <= rel + bq − 1, whole iff j·bk + bk − 1 <= rel
+    hi = jnp.minimum(jnp.maximum(rel + block_q - 1 + block_k, 0) // block_k, num_kv)
+    hi_in = jnp.minimum(jnp.maximum(rel + 1, 0) // block_k, hi)
+    if window is None:
+        return 0, 0, hi_in, hi
+    # ... and j·bk + bk − 1 > rel − W, whole iff j·bk >= rel + bq − W
+    lo = jnp.minimum(jnp.maximum(rel - window + 1, 0) // block_k, hi)
+    lo_in = jnp.clip((jnp.maximum(rel + block_q - window, 0) + block_k - 1) // block_k,
+                     lo, hi)
+    return lo, lo_in, jnp.maximum(hi_in, lo_in), hi
+
+
+def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                glse_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                scale, causal, block_q, block_k, num_q, num_kv, group=1,
+                window=None):
+    """One step: a q block of one query head against every kv block it sees
+    of its key-value head's WHOLE k and v, which stay in VMEM — as the float32
+    dk and dv of the head do — while the innermost axis walks the `group`
+    query heads that read them, `num_q` q blocks each. The score block of a
+    (q block, kv block) pair is computed once and feeds dq, dk and dv."""
+    y = pl.program_id(2)
+    i = y if group == 1 else y % num_q
+    q_start = offs_ref[0] + i * block_q
+    kv_off = offs_ref[1]
+
+    @pl.when(y == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    q = q_ref[0, 0]
+    q32 = q.astype(jnp.float32)
+    do = do_ref[0, 0].astype(jnp.float32)
+    o = o_ref[0, 0].astype(jnp.float32)
+    # dL/ds = p*(dp - delta) + g_lse*p = p*(dp - (delta - g_lse)):
+    # the lse cotangent folds into delta (dlse/ds_k = p_k)
+    delta = jnp.sum(do * o, axis=-1, keepdims=True)         # (bq, 1)
+    if glse_ref is not None:
+        delta = delta - glse_ref[0, 0, :, :1]
+    lse = lse_ref[0, 0, :, :1]
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def visit(masked):
+        def body(j, carry):
+            rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+            k = k_ref[0, 0, rows, :]
+            p, ds = _p_and_ds(
+                q, k, v_ref[0, 0, rows, :], do, lse, delta, scale=scale,
+                masked=masked, q_start=q_start, kv_start=kv_off + j * block_k,
+                block_q=block_q, block_k=block_k, window=window)
+            dv_acc[rows, :] += jax.lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                # (bk, D)
+            dk_acc[rows, :] += jax.lax.dot_general(
+                ds, q32, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                        # (bk, D)
+            dq_acc[:] += jax.lax.dot_general(
+                ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                        # (bq, D)
+            return carry
+        return body
+
+    # kv blocks ascending; each loop's body is straight-line: the band's
+    # masked lower edge (a window's), the whole blocks, the diagonal's
+    lo, lo_in, hi_in, hi = _visible_kv(
+        q_start, kv_off, causal=causal, block_q=block_q, block_k=block_k,
+        num_kv=num_kv, window=window)
+    if window is not None:
+        jax.lax.fori_loop(lo, lo_in, visit(True), 0)
+    jax.lax.fori_loop(lo_in, hi_in, visit(False), 0)
+    if causal:
+        jax.lax.fori_loop(hi_in, hi, visit(True), 0)
+    dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+
+    @pl.when(y == group * num_q - 1)
+    def _finalize():
+        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+class BwdPlan(NamedTuple):
+    route: str       # "resident" (one kernel) or "split" (dq, then dk and dv)
+    vmem_bytes: int  # what the resident kernel's blocks, scratch and values take
+    vmem_limit: int  # what Mosaic may use
+
+
+@functools.lru_cache(maxsize=None)
+def _bwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
+              vmem: int) -> BwdPlan:
+    size = jnp.dtype(dtype_name).itemsize
+    head = t_k * head_dim
+    # two buffers each of k, v in and dk, dv out; dk, dv in float32
+    resident = head * (8 * size + 8)
+    # a step: two buffers each of q, o, do in and dq out, of lse and its
+    # cotangent; dq in float32; the float32 (bq, bk) values (s, p, dp, ds and
+    # a transposed operand) and float32 forms of q, do, o, k, v
+    step = (8 * bq * head_dim * size + 16 * bq * _LANE + 4 * bq * head_dim
+            + 20 * bq * bk + 4 * (3 * bq + 2 * bk) * head_dim)
+    need, limit = resident + step, vmem * 3 // 4
+    plan = BwdPlan("resident" if need <= limit else "split", need, limit)
+    # once a shape and process: which backward this shape takes
+    logger.info(
+        "flash attention's backward (%d keys, head %d, %s, blocks %d x %d) "
+        "takes the %s route: a head's k, v, dk and dv resident in VMEM need "
+        "%d bytes of the %d a kernel may use here", t_k, head_dim, dtype_name,
+        bq, bk, plan.route, need, limit)
+    return plan
+
+
+def bwd_route(t_k: int, head_dim: int, dtype, bq: int, bk: int) -> BwdPlan:
+    """Which backward a call of `t_k` keys a head takes — `resident`: ONE
+    kernel, the key-value head's whole k and v and its float32 dk and dv in
+    VMEM, a pair's score block computed once for dq, dk and dv; or `split`: a
+    dq kernel and a dkv kernel that each stream kv blocks and each recompute
+    the score block, where the head does not fit. A pure function of the
+    shapes, the dtype and the chip's VMEM; nothing a caller sets."""
+    return _bwd_plan(t_k, head_dim, jnp.dtype(dtype).name, bq, bk, _vmem_bytes())
+
+
 def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
     """g: cotangent of out (B, T, H, D); g_lse: cotangent of lse (B, H, Tq)
     or None (out-only variant)."""
     offs, qt, kt, vt, ot, lse = res              # (B, H, T, D) / lse 4D
     B, H, Tq, D = qt.shape
+    gt = g.transpose(0, 2, 1, 3)                 # (B, H, Tq, D)
+    operands = (offs, qt, kt, vt, ot, gt, lse)
+    if g_lse is not None:
+        operands += (jnp.broadcast_to(
+            g_lse.astype(jnp.float32)[..., None], (B, H, Tq, _LANE)),)
+    plan = bwd_route(kt.shape[2], D, qt.dtype, bq, bk)
+    static = dict(causal=causal, bq=bq, bk=bk, interpret=interpret, window=window)
+    if plan.route == "resident":
+        dq, dk, dv = _bwd_resident(operands, plan.vmem_limit, **static)
+    else:
+        dq, dk, dv = _bwd_split(operands, **static)
+    back = lambda x: x.transpose(0, 2, 1, 3)
+    return None, back(dq), back(dk), back(dv)
+
+
+def _with_glse(kernel, with_glse, **static):
+    """`kernel` taking the logsumexp's cotangent as its eighth reference, or
+    None there where the call has none."""
+    def call(*refs):
+        if not with_glse:
+            refs = refs[:7] + (None,) + refs[7:]
+        kernel(*refs, **static)
+    return call
+
+
+def _bwd_resident(operands, vmem_limit, *, causal, bq, bk, interpret, window):
+    """(dq, dk, dv) as (B, H, T, D) by `_bwd_kernel`: grid axis 1 counts
+    KEY-VALUE heads, the sequential axis 2 walks the group's query heads and
+    their q blocks (y = head in group · num_q + q block)."""
+    _, qt, kt, vt = operands[:4]
+    B, H, Tq, D = qt.shape
+    Hkv, Tk = kt.shape[1], kt.shape[2]
+    num_q, group = Tq // bq, H // Hkv
+    with_glse = len(operands) == 8
+    if group == 1:
+        q_at = lambda b, h, y, offs: (b, h, y, 0)
+    else:
+        q_at = lambda b, h, y, offs: (b, h * group + y // num_q, y % num_q, 0)
+    q_spec = pl.BlockSpec((1, 1, bq, D), q_at)
+    lse_spec = pl.BlockSpec((1, 1, bq, _LANE), q_at)
+    kv_spec = pl.BlockSpec((1, 1, Tk, D), lambda b, h, y, offs: (b, h, 0, 0))
+    return pl.pallas_call(
+        _with_glse(_bwd_kernel, with_glse, scale=D ** -0.5, causal=causal,
+                   block_q=bq, block_k=bk, num_q=num_q, num_kv=Tk // bk,
+                   group=group, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, group * num_q),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec]
+            + ([lse_spec] if with_glse else []),
+            out_specs=[q_spec, kv_spec, kv_spec],
+            scratch_shapes=[
+                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((Tk, D), jnp.float32),
+                pltpu.VMEM((Tk, D), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            _sds(qt.shape, qt.dtype, qt),
+            _sds(kt.shape, kt.dtype, kt),
+            _sds(vt.shape, vt.dtype, vt),
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+        name=_kernel_name("bwd", window),
+    )(*operands)
+
+
+def _bwd_split(operands, *, causal, bq, bk, interpret, window):
+    """(dq, dk, dv) as (B, H, T, D) by the dq kernel and the dkv kernel, each
+    streaming kv (q) blocks through Mosaic's default VMEM."""
+    _, qt, kt, vt = operands[:4]
+    B, H, Tq, D = qt.shape
     Hkv, Tk = kt.shape[1], kt.shape[2]
     num_q, num_kv = Tq // bq, Tk // bk
     scale = D ** -0.5
     kv_head, group = _kv_head_of(H, Hkv), H // Hkv
+    with_glse = len(operands) == 8
     if window is None:
         kv_steps, kv_at = num_kv, lambda i, j: j
         q_steps, q_block_at = num_q, lambda x, y: y
@@ -596,20 +842,6 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
         # past the band's end its last q block again: no new fetch
         q_block_at = lambda x, y: jnp.minimum(
             _first_q(x, bq, bk) + y, _last_q(x, bq, bk, window, num_q))
-    gt = g.transpose(0, 2, 1, 3)                 # (B, H, Tq, D)
-    with_glse = g_lse is not None
-    extra = ()
-    if with_glse:
-        extra = (jnp.broadcast_to(
-            g_lse.astype(jnp.float32)[..., None], (B, H, Tq, _LANE)),)
-
-    def dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                  *rest):
-        glse_ref, tail = (rest[0], rest[1:]) if with_glse else (None, rest)
-        _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
-                       lse_ref, glse_ref, *tail, scale=scale, causal=causal,
-                       block_q=bq, block_k=bk, num_kv=kv_steps, window=window,
-                       kv_blocks=num_kv)
 
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, offs: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, D),
@@ -618,7 +850,9 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
                             lambda b, h, i, j, offs: (b, h, i, 0))
 
     dq = pl.pallas_call(
-        dq_kernel,
+        _with_glse(_bwd_dq_kernel, with_glse, scale=scale, causal=causal,
+                   block_q=bq, block_k=bk, num_kv=kv_steps, window=window,
+                   kv_blocks=num_kv),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, num_q, kv_steps),
@@ -633,17 +867,9 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
         out_shape=[_sds(qt.shape, qt.dtype, qt)],
         interpret=interpret,
         name=_kernel_name("bwd_dq", window),
-    )(offs, qt, kt, vt, ot, gt, lse, *extra)[0]
+    )(*operands)[0]
 
-    # dk/dv sweep: kv block outer (revisited output), q block inner
-    def dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                   *rest):
-        glse_ref, tail = (rest[0], rest[1:]) if with_glse else (None, rest)
-        _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
-                        lse_ref, glse_ref, *tail, scale=scale, causal=causal,
-                        block_q=bq, block_k=bk, num_q=q_steps, group=group,
-                        window=window, q_blocks=num_q)
-
+    # dk/dv sweep: kv block outer (revisited output), q block inner.
     # grid axis 1 counts KEY-VALUE heads; y walks the group's query heads and
     # their q blocks (y = head_in_group * q_steps + step: the q block itself,
     # or under a window the step of the band that sees kv block x)
@@ -656,7 +882,9 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
     kv_spec2 = pl.BlockSpec((1, 1, bk, D), lambda b, h, x, y, offs: (b, h, x, 0))
     lse_spec2 = pl.BlockSpec((1, 1, bq, _LANE), q_at)
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        _with_glse(_bwd_dkv_kernel, with_glse, scale=scale, causal=causal,
+                   block_q=bq, block_k=bk, num_q=q_steps, group=group,
+                   window=window, q_blocks=num_q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, Hkv, num_kv, group * q_steps),
@@ -674,10 +902,8 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
         ],
         interpret=interpret,
         name=_kernel_name("bwd_dkv", window),
-    )(offs, qt, kt, vt, ot, gt, lse, *extra)
-
-    back = lambda x: x.transpose(0, 2, 1, 3)
-    return None, back(dq), back(dk), back(dv)
+    )(*operands)
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------- public

@@ -40,24 +40,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from elasticdl_tpu.ops.pallas_attention import _interpret_active, kernel_interpret
+from elasticdl_tpu.ops.pallas_attention import (
+    _interpret_active, _vmem_bytes, kernel_interpret)
 
 LANES = 128
-# what the described chip of the rehearsals has (v5e: 128 MiB a core); a
-# visible TPU answers for itself
-_V5E_VMEM_BYTES = 128 << 20
 
 
 def runnable() -> bool:
     """The kernel needs a real TPU or interpret mode (CPU tests)."""
     return jax.default_backend() == "tpu" or _interpret_active()
-
-
-def _vmem_bytes() -> int:
-    try:
-        return int(pltpu.get_tpu_info().vmem_capacity_bytes)
-    except Exception:   # no TPU visible: a rehearsal or interpret mode
-        return _V5E_VMEM_BYTES
 
 
 class Tiles(NamedTuple):

@@ -55,8 +55,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from elasticdl_tpu.ops.pallas_attention import _interpret_active, kernel_interpret
-from elasticdl_tpu.ops.pallas_gmm import LANES, _vmem_bytes
+from elasticdl_tpu.ops.pallas_attention import (
+    _interpret_active, _vmem_bytes, kernel_interpret)
+from elasticdl_tpu.ops.pallas_gmm import LANES
 
 _F32 = jnp.float32
 

@@ -9,6 +9,7 @@ the suite tests the CPU mesh on a machine with a chip too; set
 EDL_TEST_PLATFORM to run it elsewhere.
 """
 
+import contextlib
 import os
 import sys
 
@@ -111,15 +112,23 @@ def _environment_is_an_input():
         )
 
 
-@pytest.fixture
-def route_log(caplog):
-    """What `ops/ssm.py::scan_route` logs at trace time. The package's logger
-    may be configured `propagate=False` (`common/log_utils.py`), so caplog
-    listens on the module's logger itself."""
+@contextlib.contextmanager
+def listening(caplog, logger_name):
+    """caplog at INFO on one module's own logger: the package's logger may be
+    configured `propagate=False` (`common/log_utils.py`)."""
     import logging
 
-    log = logging.getLogger("elasticdl_tpu.ops.ssm")
+    log = logging.getLogger(logger_name)
     log.addHandler(caplog.handler)
-    with caplog.at_level(logging.INFO, log.name):
+    try:
+        with caplog.at_level(logging.INFO, log.name):
+            yield caplog
+    finally:
+        log.removeHandler(caplog.handler)
+
+
+@pytest.fixture
+def route_log(caplog):
+    """What `ops/ssm.py::scan_route` logs at trace time."""
+    with listening(caplog, "elasticdl_tpu.ops.ssm"):
         yield caplog
-    log.removeHandler(caplog.handler)

@@ -306,7 +306,7 @@ def test_keeping_the_flash_residuals_changes_no_value_on_the_cpu(route, monkeypa
         counts = lambda keep: [     # kernels under a gradient take minutes
             pallas_calls(jaxpr(keep), name) for name in (
                 "flash_attention_swa_fwd", "flash_attention_fwd",
-                "flash_attention_swa_bwd_dq", "flash_attention_bwd_dkv")]
+                "flash_attention_swa_bwd", "flash_attention_bwd")]
         assert counts(True) == [3, 1, 3, 1]
         assert counts(False) == [6, 2, 3, 1]
         return

@@ -16,7 +16,7 @@ from elasticdl_tpu.ops.pallas_attention import (
     flash_attention,
     pick_block,
 )
-from tests.conftest import equations, heavy_on_cpu, pallas_calls
+from tests.conftest import equations, heavy_on_cpu, listening, pallas_calls
 
 B, T, H, D = 2, 64, 2, 16
 
@@ -530,7 +530,7 @@ def test_kept_residuals_leave_one_forward_call_in_a_recomputed_layer(case):
             *args).jaxpr, "flash_attention_" + kernel)
     assert calls(lambda f: f, "fwd") == 1
     assert calls(jax.checkpoint, "fwd") == 2
-    assert [calls(_kept, kernel) for kernel in ("fwd", "bwd_dq", "bwd_dkv")] == [1, 1, 1]
+    assert [calls(_kept, kernel) for kernel in ("fwd", "bwd", "bwd_dq", "bwd_dkv")] == [1, 1, 0, 0]
 
 
 @pytest.mark.parametrize("case", sorted(RECOMPUTED))
@@ -704,8 +704,7 @@ def test_a_window_of_the_whole_length_is_the_causal_call_to_the_bit(window):
                     jax.grad(f(None), argnums=(0, 1, 2))(q, k, v)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     names = _kernel_grids(jax.make_jaxpr(jax.grad(f(window)))(q, k, v).jaxpr)
-    assert sorted(names) == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
-                             "flash_attention_fwd"]
+    assert sorted(names) == ["flash_attention_bwd", "flash_attention_fwd"]
 
 
 def _kernel_grids(jaxpr):
@@ -720,19 +719,29 @@ def _kernel_grids(jaxpr):
     return out
 
 
-@pytest.mark.parametrize("window,want", [
-    (None, {"flash_attention_fwd": (1, 8, 6, 6), "flash_attention_bwd_dq": (1, 8, 6, 6),
-            "flash_attention_bwd_dkv": (1, 2, 6, 24)}),
+@pytest.mark.parametrize("route,window,want", [
+    # one backward call: (B, key-value heads, 4 heads a group x 6 q blocks)
+    ("resident", None, {"flash_attention_fwd": (1, 8, 6, 6), "flash_attention_bwd": (1, 2, 24)}),
+    # W = a block: 2 kv blocks a q block; the backward's grid does not band,
+    # its loop over a q block's kv blocks does
+    ("resident", 16, {"flash_attention_swa_fwd": (1, 8, 6, 2),
+                      "flash_attention_swa_bwd": (1, 2, 24)}),
+    ("resident", 40, {"flash_attention_swa_fwd": (1, 8, 6, 4),
+                      "flash_attention_swa_bwd": (1, 2, 24)}),
+    ("split", None, {"flash_attention_fwd": (1, 8, 6, 6), "flash_attention_bwd_dq": (1, 8, 6, 6),
+                     "flash_attention_bwd_dkv": (1, 2, 6, 24)}),
     # W = a block: 2 kv blocks a q block, 2 q blocks a kv block (x 4 heads a group)
-    (16, {"flash_attention_swa_fwd": (1, 8, 6, 2), "flash_attention_swa_bwd_dq": (1, 8, 6, 2),
-          "flash_attention_swa_bwd_dkv": (1, 2, 6, 8)}),
-    (40, {"flash_attention_swa_fwd": (1, 8, 6, 4), "flash_attention_swa_bwd_dq": (1, 8, 6, 4),
-          "flash_attention_swa_bwd_dkv": (1, 2, 6, 16)}),
+    ("split", 16, {"flash_attention_swa_fwd": (1, 8, 6, 2), "flash_attention_swa_bwd_dq": (1, 8, 6, 2),
+                   "flash_attention_swa_bwd_dkv": (1, 2, 6, 8)}),
+    ("split", 40, {"flash_attention_swa_fwd": (1, 8, 6, 4), "flash_attention_swa_bwd_dq": (1, 8, 6, 4),
+                   "flash_attention_swa_bwd_dkv": (1, 2, 6, 16)}),
 ])
-def test_the_grid_is_banded_under_a_window_and_as_it_was_without(window, want):
+def test_the_grid_is_banded_under_a_window_and_as_it_was_without(route, window, want, monkeypatch):
     """Read off the lowered calls: `window=None` keeps the unbanded grid and
-    the three old kernel names; a window shortens the kv axis of the forward
-    and dq grids and the q axis of the dkv grid, under names of their own."""
+    the plain kernel names; a window shortens the kv axis of the forward grid
+    (and of the split route's dq grid, and the q axis of its dkv grid), under
+    names of their own."""
+    take_route(monkeypatch, route)
     q, k, v = _windowed_case(96, 8, 2)
     f = lambda *a: jnp.sum(flash_attention(*a, window=window, block_q=16, block_k=16,
                                            interpret=True) ** 2)
@@ -772,7 +781,7 @@ def test_windowed_layer_under_checkpoint_keeps_one_forward():
         jax.make_jaxpr(jax.grad(loss(wrap), argnums=(0, 1)))(x, w).jaxpr,
         "flash_attention_swa_" + kernel)
     assert calls(jax.checkpoint, "fwd") == 2
-    assert [calls(_kept, kernel) for kernel in ("fwd", "bwd_dq", "bwd_dkv")] == [1, 1, 1]
+    assert [calls(_kept, kernel) for kernel in ("fwd", "bwd", "bwd_dq", "bwd_dkv")] == [1, 1, 0, 0]
     for a, b in zip(jax.grad(loss(_kept), argnums=(0, 1))(x, w),
                     jax.grad(loss(lambda f: f), argnums=(0, 1))(x, w)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -810,3 +819,150 @@ def test_full_attention_passes_its_window_to_the_kernel(monkeypatch):
     assert pallas_calls(jaxpr, "flash_attention_swa_fwd") == 1
     np.testing.assert_allclose(np.asarray(full_attention(q, k, v, window=8)),
                                np.asarray(_dense_window(q, k, v, 8)[0]), atol=2e-5, rtol=2e-5)
+
+
+
+# ------------------------------------------------------------------ #
+# the backward's two routes
+
+
+def take_route(monkeypatch, route):
+    """`bwd_route` reads the chip's VMEM: describe one with room for a head's
+    k, v, dk and dv (a v5e's) or one with none. JAX keeps the trace of a
+    custom rule's backward: a new rule for each route."""
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    pa._make_flash.cache_clear()
+    monkeypatch.setattr(pa, "_vmem_bytes", lambda: {"resident": 128 << 20, "split": 1 << 10}[route])
+
+
+@pytest.fixture
+def bwd_log(caplog):
+    """What `bwd_route` logs, from an empty cache: it logs once a shape."""
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    pa._bwd_plan.cache_clear()
+    with listening(caplog, pa.__name__):
+        yield caplog
+
+
+# (T, heads, key-value heads, head size, block_q, block_k, causal, window,
+#  (q_offset, kv_offset), with a cotangent on the logsumexp)
+BACKWARD = {
+    "mha": (64, 2, 2, 16, 16, 16, True, None, (0, 0), False),
+    "acausal": (64, 2, 2, 16, 16, 32, False, None, (0, 0), False),
+    "one_block": (32, 2, 2, 16, 32, 32, True, None, (0, 0), False),     # ONE kv block a q block
+    "group16": (64, 32, 2, 16, 32, 16, True, None, (0, 0), True),        # Nemotron's 32 on 2
+    "head256": (64, 2, 1, 256, 32, 16, True, None, (0, 0), False),       # two diagonal blocks
+    "window_in_a_block": (96, 4, 2, 16, 32, 32, True, 5, (0, 0), True),  # the band in ONE kv block
+    "window_a_block": (96, 4, 1, 16, 16, 16, True, 16, (0, 0), False),
+    "window_off_block": (96, 8, 2, 16, 16, 32, True, 40, (0, 0), True),  # whole blocks in the band
+    "window_wide": (128, 2, 2, 16, 16, 16, True, 50, (0, 0), False),
+    "offsets": (64, 2, 2, 16, 16, 16, True, None, (64, 32), True),       # ring attention's
+    "offsets_before": (64, 2, 2, 16, 16, 16, True, None, (0, 48), True), # q blocks seeing NO key
+    "offsets_unaligned": (64, 4, 2, 16, 32, 16, True, None, (40, 8), False),
+}
+
+
+def _backward_case(name):
+    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
+
+    t, heads, kv_heads, head, bq, bk, causal, window, (q_off, kv_off), with_lse = BACKWARD[name]
+    r = np.random.RandomState(31)
+    draw = lambda *shape: jnp.asarray(r.randn(*shape) * 0.5, jnp.float32)
+    q, k, v = draw(1, t, heads, head), draw(1, t, kv_heads, head), draw(1, t, kv_heads, head)
+    probe, probe_lse = draw(1, t, heads, head), draw(1, heads, t) * float(with_lse)
+
+    def weigh(out, lse):
+        return jnp.sum(probe * out) + jnp.sum(probe_lse * jnp.where(lse > -1e29, lse, 0.0))
+
+    def flash(q, k, v):
+        # traced offsets, as ring attention passes them (a window takes none)
+        offsets = {} if window is not None else dict(
+            q_offset=jnp.int32(q_off), kv_offset=jnp.int32(kv_off))
+        if with_lse:
+            return weigh(*flash_attention_lse(q, k, v, causal=causal, window=window, block_q=bq,
+                                              block_k=bk, interpret=True, **offsets))
+        return jnp.sum(probe * flash_attention(q, k, v, causal=causal, window=window, block_q=bq,
+                                               block_k=bk, interpret=True, **offsets))
+
+    def dense(q, k, v):
+        group = heads // kv_heads
+        kk, vv = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
+                       precision=jax.lax.Precision.HIGHEST) * head ** -0.5
+        i, j = q_off + jnp.arange(t)[:, None], kv_off + jnp.arange(t)[None, :]
+        mask = (j <= i) if causal else jnp.ones((t, t), bool)
+        if window is not None:
+            mask &= j > i - window
+        s = jnp.where(mask, s, -jnp.inf)
+        rows = jnp.any(mask, axis=1)[None, None, :, None]        # a row with no key: zeros
+        p = jnp.where(rows, jax.nn.softmax(jnp.where(rows, s, 0.0), axis=-1), 0.0)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, vv, precision=jax.lax.Precision.HIGHEST)
+        lse = jnp.where(rows[..., 0], jax.nn.logsumexp(jnp.where(rows, s, 0.0), axis=-1), 0.0)
+        return weigh(out, lse)
+
+    return flash, dense, (q, k, v)
+
+
+@pytest.mark.parametrize("name", sorted(BACKWARD))
+def test_the_resident_backward_is_the_split_one_to_the_bit(name, monkeypatch):
+    """dq, dk, dv by the one kernel — a head's k and v resident, a pair's
+    score block computed once — against a dense mask, and against the dq and
+    dkv kernels bit for bit: the same operands, the same order of sums."""
+    flash, dense, args = _backward_case(name)
+    got = {}
+    for route in ("resident", "split"):
+        take_route(monkeypatch, route)
+        jaxpr = jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(*args).jaxpr
+        names = sorted(n.replace("swa_", "") for n in _kernel_grids(jaxpr))
+        assert names == {"resident": ["flash_attention_bwd", "flash_attention_fwd"],
+                         "split": ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                                   "flash_attention_fwd"]}[route]
+        got[route] = jax.grad(flash, argnums=(0, 1, 2))(*args)
+    want = jax.grad(dense, argnums=(0, 1, 2))(*args)
+    for a, b, c in zip(got["resident"], got["split"], want):
+        assert float(jnp.max(jnp.abs(c))) > 1e-3
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-4, rtol=1e-4)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# (keys, head, dtype) -> the blocks the plan gives and the route on a v5e's
+# 128 MiB of VMEM: the four language-model cells of BENCHMARK.json, then what
+# does not fit
+ROUTES = {
+    "olmoe-1b-7b.resident-4k": (4096, 128, jnp.bfloat16, "resident"),
+    "nemotron-3-nano-30b-a3b.resident-8k": (8192, 128, jnp.bfloat16, "resident"),
+    "glm-4.7-flash.resident-8k": (8192, 256, jnp.bfloat16, "resident"),
+    "mellum2-12b-a2.5b.resident-16k": (16384, 128, jnp.bfloat16, "resident"),
+    "32k_keys": (32768, 128, jnp.bfloat16, "split"),
+    "16k_keys_of_256": (16384, 256, jnp.bfloat16, "split"),
+    "16k_keys_float32": (16384, 128, jnp.float32, "split"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ROUTES))
+def test_the_backward_route_follows_the_head_s_bytes_and_logs_once_a_shape(
+        name, bwd_log, monkeypatch):
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    take_route(monkeypatch, "resident")            # a described v5e
+    t_k, head, dtype, want = ROUTES[name]
+    shape = (1, t_k, 4, head)
+    bq, bk = pa._plan_blocks(shape, shape, None, None, dtype=dtype)
+    plan = pa.bwd_route(t_k, head, dtype, bq, bk)
+    assert plan.route == want
+    assert (plan.vmem_bytes <= plan.vmem_limit) == (want == "resident")
+    assert plan.vmem_limit == (128 << 20) * 3 // 4
+    # k, v, dk, dv twice buffered and the two float32 accumulators at least
+    assert plan.vmem_bytes > t_k * head * (8 * jnp.dtype(dtype).itemsize + 8)
+    assert pa.bwd_route(t_k, head, dtype, bq, bk) == plan
+    # (a record that also propagates to the root logger is listed twice)
+    lines = list({id(r): r.getMessage() for r in bwd_log.records
+                  if "backward" in r.getMessage()}.values())
+    assert len(lines) == 1 and f"takes the {want} route" in lines[0]
+    assert f"{t_k} keys, head {head}" in lines[0]
+    # a smaller chip: the same function, the other answer
+    monkeypatch.setattr(pa, "_vmem_bytes", lambda: 16 << 20)
+    assert pa.bwd_route(t_k, head, dtype, bq, bk).route == "split"
+

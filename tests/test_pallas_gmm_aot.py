@@ -1,8 +1,10 @@
 """REHEARSAL, no chip: the grouped matmul's three forms compile for a
 described v5e at the widths the benchmark's three language-model cells run
 (`ops/pallas_gmm.py`; the on-chip-measurement guide, section 2), and the
-three flash-attention kernels at the head of 256 that latent attention has
-(`ops/pallas_attention.py`: the block plan follows the head size), and the
+flash-attention kernels at the four language-model cells' shapes, the ONE
+backward kernel with a key-value head's keys resident in VMEM, and at a head
+that does not fit, the two that stream it (`ops/pallas_attention.py`: the
+block plan follows the head size, `bwd_route` the head's bytes), and the
 state-space scan's three kernels at the Nemotron cell's shapes
 (`ops/pallas_ssd.py`), alone and inside a checkpointed Mamba mixer, where
 every one of them has to carry the scope the benchmark reads it by — as the
@@ -76,27 +78,49 @@ def test_forward_and_backward_compile_for_a_v5e(name, one_chip, no_compile_cache
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
-def test_flash_kernels_compile_at_head_256_for_a_v5e(one_chip, no_compile_cache):
-    """glm-4.7-flash.resident-8k's attention: T = 8192, 20 heads of 256,
-    bfloat16, forward and both backward kernels at the blocks the plan gives
-    (1024, 512). At (1024, 1024) the dq kernel asks for 16.9 MB of the 16 MB
-    of VMEM a kernel may use: that is what the plan avoids."""
+# (batch, tokens, query heads, key-value heads, head, window) -> the plan's
+# blocks and the backward's route: the attention of BENCHMARK.json's four
+# language-model cells, then a head that does not fit VMEM whole
+FLASH = {
+    "olmoe-1b-7b.resident-4k": ((2, 4096, 16, 16, 128, None), (1024, 1024), "resident"),
+    "nemotron-3-nano-30b-a3b.resident-8k": ((1, 8192, 32, 2, 128, None), (1024, 1024), "resident"),
+    # the plan halves the key block at head 256, for every kernel alike: at
+    # (1024, 1024) the dq kernel asked for 16.9 MB of Mosaic's default 16 (PR 32)
+    "glm-4.7-flash.resident-8k": ((1, 8192, 20, 20, 256, None), (1024, 512), "resident"),
+    "mellum2-12b-a2.5b.resident-16k/full": ((1, 16384, 32, 4, 128, None), (1024, 1024), "resident"),
+    "mellum2-12b-a2.5b.resident-16k/sliding": ((1, 16384, 32, 4, 128, 1024), (1024, 1024), "resident"),
+    "32k_keys": ((1, 32768, 8, 2, 128, None), (1024, 1024), "split"),
+    "32k_keys/sliding": ((1, 32768, 8, 2, 128, 1024), (1024, 1024), "split"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FLASH))
+def test_flash_kernels_compile_for_a_v5e(name, one_chip, no_compile_cache):
+    """Forward and backward in bfloat16 at the blocks the plan gives: ONE
+    backward kernel where a key-value head's k, v, dk and dv fit the VMEM
+    `bwd_route` allows it (`vmem_limit_bytes`: Mosaic's default 16 MB hold
+    none of these heads), the dq and the dkv kernel where they do not."""
     from elasticdl_tpu.ops import pallas_attention
 
-    shape = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16, sharding=one_chip)
-    assert pallas_attention._plan_blocks(
-        shape.shape, shape.shape, pallas_attention.DEFAULT_BLOCK_Q,
-        pallas_attention.DEFAULT_BLOCK_K, dtype=jnp.bfloat16) == (1024, 512)
+    (b, t, h, hkv, d, window), blocks, route = FLASH[name]
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16, sharding=one_chip)
+    assert pallas_attention._plan_blocks(q.shape, k.shape, None, None, dtype=jnp.bfloat16) == blocks
+    assert pallas_attention.bwd_route(t, d, jnp.bfloat16, *blocks).route == route
 
     def forward_and_backward(q, k, v, do):
         out, vjp = jax.vjp(lambda q, k, v: pallas_attention.flash_attention(
-            q, k, v, causal=True, interpret=False), q, k, v)
+            q, k, v, causal=True, window=window, interpret=False), q, k, v)
         return out, vjp(do)
 
-    text = jax.jit(forward_and_backward).lower(shape, shape, shape, shape).compile().as_text()
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
-        assert kernel in text, kernel        # in the instruction's name
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    text = jax.jit(forward_and_backward).lower(q, k, k, q).compile().as_text()
+    # outside a named scope the instruction is `%jvp_flash_attention_fwd_.1`,
+    # `%transpose_jvp_flash_attention_bwd__.1`
+    calls = re.findall(r"^\s*%\w*?(flash_attention_[a-z_]*?)_*\.\d+ = .*tpu_custom_call", text, re.M)
+    prefix = "flash_attention_swa_" if window else "flash_attention_"
+    assert sorted(calls) == [prefix + part for part in (
+        ["bwd", "fwd"] if route == "resident" else ["bwd_dkv", "bwd_dq", "fwd"])]
+    assert text.count('custom_call_target="tpu_custom_call"') == len(calls)
 
 
 # nemotron-3-nano-30b-a3b.resident-8k's scan: one sequence of 8192 tokens, 64
@@ -161,19 +185,26 @@ def test_every_scan_kernel_of_a_checkpointed_mamba_mixer_carries_its_scope(
 
 
 # (the zoo's module, a configuration of few layers at the cell's attention
-# shapes, the scope of each attention block's kernels in program order)
+# shapes, tokens, (the scope of an attention block's kernels, their names'
+# prefix) in program order)
+_CAUSAL, _BANDED = "flash_attention_", "flash_attention_swa_"
 RECOMPUTED_ATTENTION = {
     # glm-4.7-flash.resident-8k: the dense layer and the module's own sparse
     # layer, 20 heads of 192 + 64 / 256
     "glm": ("glm4_moe_lite", dict(
         num_hidden_layers=1, first_k_dense_replace=1, num_nextn_predict_layers=1,
-        n_routed_experts=8, router_experts=64, vocab_size=512),
-        ["glm4_moe_lite/mla/attn", "glm4_moe_lite/mtp/mla/attn"]),
+        n_routed_experts=8, router_experts=64, vocab_size=512), 8192,
+        [("glm4_moe_lite/mla/attn", _CAUSAL), ("glm4_moe_lite/mtp/mla/attn", _CAUSAL)]),
     # nemotron-3-nano-30b-a3b.resident-8k: 32 query heads on 2 key-value heads
     # of 128 (a sparse-expert layer after it: `forward` stacks their statistics)
     "nemotron": ("nemotron_h", dict(
         num_hidden_layers=2, hybrid_override_pattern="*E", n_routed_experts=8,
-        router_experts=128, vocab_size=512), ["nemotron_h/attn"]),
+        router_experts=128, vocab_size=512), 8192, [("nemotron_h/attn", _CAUSAL)]),
+    # mellum2-12b-a2.5b.resident-16k: three sliding-window layers of 1024 keys
+    # and one full layer, 32 query heads on 4 key-value heads of 128
+    "mellum": ("mellum", dict(
+        num_hidden_layers=4, num_experts=8, router_experts=64, vocab_size=512), 16384,
+        [("mellum/sliding/attn", _BANDED)] * 3 + [("mellum/full/attn", _CAUSAL)]),
 }
 
 
@@ -181,35 +212,35 @@ RECOMPUTED_ATTENTION = {
 def test_a_recomputed_layer_runs_the_flash_forward_once_under_its_scope(
         model, one_chip, no_compile_cache, monkeypatch):
     """`forward` checkpoints its layers with `pallas_attention.
-    KEEP_RESIDUALS`: the gradient program at 8192 tokens compiles with one
-    `flash_attention_fwd` call a block (two under the plain checkpoint), one
-    `bwd_dq` and one `bwd_dkv`, and `scope_map` finds each under the block's
-    own `attn` scope — the benchmark's `mla_ms`, `mtp_ms` read them there."""
+    KEEP_RESIDUALS`: the gradient program at the cell's tokens compiles with
+    one `flash_attention_fwd` call a block (two under the plain checkpoint)
+    and ONE `flash_attention_bwd` — no `bwd_dq`, no `bwd_dkv`: a head's keys
+    fit VMEM — `_swa_fwd` and `_swa_bwd` in a windowed block, and `scope_map`
+    finds each under the block's own `attn` scope: the benchmark's `mla_ms`,
+    `mtp_ms`, `swa_ms` and the name-prefix readers (`mla_attn_ms`,
+    `gqa_attn_ms`, `swa_attn_ms`, `global_attn_ms`) read them there."""
     import importlib
 
     from benchmark import common
 
-    module, config, scopes = RECOMPUTED_ATTENTION[model]
+    module, config, length, blocks = RECOMPUTED_ATTENTION[model]
     zoo = importlib.import_module(f"model_zoo.transformer.{module}")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
     net = zoo.custom_model(**config)
-    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
     variables = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
 
-    def loss(params, router_state, tokens):
-        outputs = net.apply({"params": params, "router_state": router_state}, tokens)
+    def loss(params, state, tokens):
+        outputs = net.apply({"params": params, **state}, tokens)
         return sum(jnp.sum(jnp.square(x)) for x in jax.tree_util.tree_leaves(outputs))
 
-    text = jax.jit(jax.value_and_grad(loss)).lower(
-        variables["params"], variables["router_state"], tokens).compile().as_text()
+    params = variables.pop("params")
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
     calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
     kinds = [re.sub(r"\.\d+$", "", name) for name in calls]
-    assert sorted(kinds) == sorted(
-        ["flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"]
-        * len(scopes))
     scope_map = common.load_module("drivers", "resident_lm_share").scope_map
     found = scope_map(text, common.load_module("flops", module).SCOPES)
-    for kind in set(kinds):
-        assert sorted(found.get(name) for name, k in zip(calls, kinds) if k == kind) == scopes, kind
+    assert sorted((kind, found.get(name)) for kind, name in zip(kinds, calls)) == sorted(
+        (prefix + part, scope) for scope, prefix in blocks for part in ("fwd", "bwd"))
