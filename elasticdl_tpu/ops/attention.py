@@ -54,7 +54,9 @@ def _visible(q_len: int, kv_len: int, q_offset, kv_offset,
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    causal: bool = True,
                    q_offset: int = 0, kv_offset: int = 0,
-                   window: Optional[int] = None) -> jax.Array:
+                   window: Optional[int] = None,
+                   keep: Optional[jax.Array] = None,
+                   with_lse: bool = False):
     """Plain softmax attention. q: (B, T, H, D); k, v: (B, T, Hkv, D) with H a
     multiple of Hkv (grouped-query attention: query head h attends with
     key-value head h // (H/Hkv); Hkv == H is the ordinary case). The offsets position the
@@ -65,6 +67,15 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     keys i - W < j <= i. With zero offsets the flash kernel runs its banded
     grids (`flash_attention_swa_*`); with offsets — a sequence-parallel
     caller — the kernel declines and this XLA path applies the same mask.
+
+    `keep` (B, Tq, Tk) int8 or bool, one plane a batch row shared by every
+    head (learned sparse attention: `ops/sparse_attention.py` makes it): query
+    i sees key j iff also keep[i, j]. Without a window and with zero offsets
+    the flash kernels take it as an operand (`flash_attention_sel_*`);
+    otherwise this XLA path applies the same mask. Every row must keep a key
+    of its prefix. `with_lse`: return (out, the softmax's log-normaliser
+    (B, H, Tq) float32) — what rebuilds the probabilities exp(s − lse) of the
+    attention the output came from.
 
     On TPU this dispatches to the Pallas flash kernel
     (ops/pallas_attention.py) when shapes/offsets allow — 3-6x faster
@@ -85,29 +96,34 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if window is not None and not causal:
         raise ValueError("a window is the lower bound of a CAUSAL mask")
     if pallas_attention.can_flash(q.shape, k.shape, q_offset, kv_offset,
-                                  dtype=q.dtype, window=window):
-        return pallas_attention.flash_attention(
-            q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset,
-            window=window)
-    scale = q.shape[-1] ** -0.5
-    if k.shape[2] != q.shape[2]:
-        return _grouped_query_attention(q, k, v, causal, q_offset, kv_offset,
-                                        window)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
-    s = s * scale
+                                  dtype=q.dtype, window=window,
+                                  keep=keep is not None):
+        flash = (pallas_attention.flash_attention_lse if with_lse
+                 else pallas_attention.flash_attention)
+        return flash(q, k, v, causal=causal, q_offset=q_offset,
+                     kv_offset=kv_offset, window=window, keep=keep)
+    mask = None
     if causal:
-        mask = _visible(q.shape[1], k.shape[1], q_offset, kv_offset, window)
-        s = jnp.where(mask[None, None], s, NEG_BIG)
+        mask = _visible(q.shape[1], k.shape[1], q_offset, kv_offset, window)[None]
+    if keep is not None:
+        mask = keep.astype(bool) if mask is None else mask & keep.astype(bool)
+    if k.shape[2] != q.shape[2]:
+        return _grouped_query_attention(q, k, v, mask, with_lse)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
+    s = s * q.shape[-1] ** -0.5
+    if mask is not None:
+        s = jnp.where(mask[:, None], s, NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    out = out.astype(q.dtype)
+    return (out, jax.nn.logsumexp(s, axis=-1)) if with_lse else out
 
 
-def _grouped_query_attention(q, k, v, causal, q_offset, kv_offset,
-                             window=None):
+def _grouped_query_attention(q, k, v, mask, with_lse=False):
     """The XLA fallback with fewer key-value heads than query heads: the
-    query heads are viewed as (Hkv, group) and each group shares its k, v."""
+    query heads are viewed as (Hkv, group) and each group shares its k, v.
+    `mask`: None or (1 or B, Tq, Tk) bool, the visible pairs."""
     b, tq, h, d = q.shape
     kv_heads = k.shape[2]
     if h % kv_heads:
@@ -116,13 +132,15 @@ def _grouped_query_attention(q, k, v, causal, q_offset, kv_offset,
     qg = q.reshape(b, tq, kv_heads, h // kv_heads, d)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                    preferred_element_type=jnp.float32) * d ** -0.5
-    if causal:
-        s = jnp.where(_visible(tq, k.shape[1], q_offset, kv_offset, window),
-                      s, NEG_BIG)
+    if mask is not None:
+        s = jnp.where(mask[:, None, None], s, NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(q.shape).astype(q.dtype)
+    out = out.reshape(q.shape).astype(q.dtype)
+    if with_lse:
+        return out, jax.nn.logsumexp(s, axis=-1).reshape(b, h, tq)
+    return out
 
 
 def _ring_scan(k, v, axis_name: str, manual_axes, consume, carry0):

@@ -102,6 +102,25 @@ row can be fully masked WITHIN the first block of its band: the running
 maximum then stays NEG_BIG, the block's garbage is multiplied by
 exp(NEG_BIG − m) = 0 when the row's first visible key arrives, and nothing of
 it is left.
+
+A mask that is DATA (`keep`: int8 or bool, (B, Tq, Tk), one plane a batch row
+shared by every head; key j is visible to query i iff j ≤ i AND keep[i, j]) is
+a fifth operand of the four kernels, read tile by tile through a BlockSpec of
+its own — in the resident backward the q block's whole strip of it, (bq, Tk),
+beside the resident k and v. The causal block skip stays and NO block is
+skipped for being empty of kept keys: the mask is applied in every block a q
+block visits, not in edge blocks only. A row with no kept key WITHIN a block
+behaves as the windowed kernels' rows above do, so the contract is that every
+row keeps at least one key of its causal prefix (a selection that always keeps
+the query's own position does); a row that keeps none returns the mean of the
+values it visited, not zero. The kernels carry names of their own
+(`flash_attention_sel_fwd`, `_sel_bwd`; split: `_sel_bwd_dq`, `_sel_bwd_dkv`).
+The strip costs the resident backward 2·bq·Tk bytes of VMEM, so a `keep` call
+plans q blocks of `SEL_BLOCK_Q` (at 1024 a head of 128 with 16 384 keys no
+longer fits and takes the split route). `keep` is for UNSHARDED attention
+without a window: with a `window` or with offsets `can_flash` declines the
+call and `ops.attention.full_attention` takes its XLA path. `keep=None`
+traces and compiles exactly what it did before `keep` existed.
 """
 
 from __future__ import annotations
@@ -151,6 +170,10 @@ def _vmem_bytes() -> int:
 # times the visible pairs.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
+# With a `keep` mask the resident backward also holds the q block's (bq, Tk)
+# int8 strip of it, twice: at 16 384 keys, a bfloat16 head of 128 and bq = 1024
+# that is 32 MiB more than the 96 MiB a kernel may use leave, at 512 it fits.
+SEL_BLOCK_Q = 512
 
 # The names of the custom rule's array residuals, in its order: q, k, v as
 # (B, H, T, D), out likewise, logsumexp (B, H, Tq, 128) float32; and the
@@ -277,6 +300,18 @@ def _causal_p_mask(p, q_start, kv_start, block_q, block_k, window=None):
     return jnp.where(visible, p, 0.0) if p is not None else visible
 
 
+def _visible(masked, keep, q_start, kv_start, block_q, block_k, window=None):
+    """The element mask of a block, or None where every pair is visible: the
+    positions' (`masked`: an edge block) and the data's (`keep`: the block of
+    the int8 plane, applied in EVERY block)."""
+    visible = (_causal_p_mask(None, q_start, kv_start, block_q, block_k, window)
+               if masked else None)
+    if keep is not None:
+        kept = keep.astype(jnp.int32) != 0
+        visible = kept if visible is None else visible & kept
+    return visible
+
+
 # ------------------------------------------------------------------ the band
 # Under a window W (zero offsets) q block i sees the kv blocks
 # first_kv(i) .. last_kv(i), and kv block x is seen by the q blocks
@@ -357,15 +392,17 @@ def _kv_head_of(heads: int, kv_heads: int):
 # ---------------------------------------------------------------- forward
 
 
-def _kernel_name(part: str, window) -> str:
-    return f"flash_attention_{'swa_' if window is not None else ''}{part}"
+def _kernel_name(part: str, window, keep: bool = False) -> str:
+    kind = "swa_" if window is not None else "sel_" if keep else ""
+    return f"flash_attention_{kind}{part}"
 
 
-def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
                 acc, m_scr, l_scr, *, scale, causal, block_q, block_k,
                 num_kv, window=None, kv_blocks=None):
     """`num_kv`: the steps of the grid's kv axis — every kv block, or under a
-    `window` the band's steps, of `kv_blocks` kv blocks in all."""
+    `window` the band's steps, of `kv_blocks` kv blocks in all. `keep_ref`:
+    the (1, bq, bk) block of the data mask, or None."""
     i = pl.program_id(2)
     j = pl.program_id(3)
     q_off = offs_ref[0]
@@ -391,9 +428,9 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                                   # (bq, bk)
-        if masked:
-            mask = _causal_p_mask(None, q_start, kv_start, block_q, block_k,
-                                  window)
+        mask = _visible(masked, None if keep_ref is None else keep_ref[0],
+                        q_start, kv_start, block_q, block_k, window)
+        if mask is not None:
             s = jnp.where(mask, s, NEG_BIG)
 
         m_prev = m_scr[:, :1]                       # (bq, 1)
@@ -437,8 +474,31 @@ def _banded_kv_at(bq, bk, window, num_kv):
                                     _last_kv(i, bq, bk, num_kv))
 
 
-def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret, window=None):
-    """offs: (2,) int32 [q_off, kv_off]; qt/kt/vt: (B, H, T, D)."""
+def _with_optional(kernel, first, present, **static):
+    """`kernel` taking its optional references — one for each entry of
+    `present`, from position `first` on — or None where the call has none."""
+    def call(*refs):
+        refs = list(refs)
+        for at, there in enumerate(present, first):
+            if not there:
+                refs.insert(at, None)
+        kernel(*refs, **static)
+    return call
+
+
+def _keep_kv_at(causal, bq, bk, num_kv):
+    """(q block, step) -> the kv block of the data mask a step of an unbanded
+    grid reads: a step above the diagonal is skipped, and its index repeats the
+    diagonal's block, which fetches nothing (a `keep` call has no offsets)."""
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(j, _last_kv(i, bq, bk, num_kv))
+
+
+def _flash_fwd(offs, qt, kt, vt, keep=None, *, causal, bq, bk, interpret,
+               window=None):
+    """offs: (2,) int32 [q_off, kv_off]; qt/kt/vt: (B, H, T, D); keep: None or
+    (B, Tq, Tk) int8."""
     B, H, Tq, D = qt.shape
     Tk = kt.shape[2]
     num_q, num_kv = Tq // bq, Tk // bk
@@ -450,17 +510,21 @@ def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret, window=None):
         steps = _kv_band(num_q, num_kv, bq, bk, window)[0]
         kv_at = _banded_kv_at(bq, bk, window, num_kv)
 
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal,
+    kernel = _with_optional(
+        _fwd_kernel, 4, (keep is not None,), scale=scale, causal=causal,
         block_q=bq, block_k=bk, num_kv=steps, window=window, kv_blocks=num_kv,
     )
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, offs: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, D),
                            lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
+    keep_at = _keep_kv_at(causal, bq, bk, num_kv)
+    keep_spec = pl.BlockSpec((1, bq, bk),
+                             lambda b, h, i, j, offs: (b, i, keep_at(i, j)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, H, num_q, steps),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, kv_spec, kv_spec]
+        + ([keep_spec] if keep is not None else []),
         out_specs=[
             q_spec,
             pl.BlockSpec((1, 1, bq, _LANE),
@@ -480,8 +544,8 @@ def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret, window=None):
             _sds((B, H, Tq, _LANE), jnp.float32, qt),
         ],
         interpret=interpret,
-        name=_kernel_name("fwd", window),
-    )(offs, qt, kt, vt)
+        name=_kernel_name("fwd", window, keep is not None),
+    )(offs, qt, kt, vt, *(() if keep is None else (keep,)))
     return out, lse
 
 
@@ -489,16 +553,18 @@ def _flash_fwd(offs, qt, kt, vt, *, causal, bq, bk, interpret, window=None):
 
 
 def _p_and_ds(q, k, v, do, lse, delta, *, scale, masked, q_start, kv_start,
-              block_q, block_k, window=None):
+              block_q, block_k, window=None, keep=None):
     """Recompute the (bq, bk) p block from saved lse, and ds = p*(dp-delta).
     lse/delta: (bq, 1) float32. `masked`: apply the element mask (causal, and
-    the window's lower bound if there is one)."""
+    the window's lower bound if there is one); `keep`: the (bq, bk) block of
+    the data mask, applied whether `masked` or not."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
     ) * scale
     p = jnp.exp(s - lse)
-    if masked:
-        p = _causal_p_mask(p, q_start, kv_start, block_q, block_k, window)
+    visible = _visible(masked, keep, q_start, kv_start, block_q, block_k, window)
+    if visible is not None:
+        p = jnp.where(visible, p, 0.0)
     dp = jax.lax.dot_general(
         do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -508,7 +574,7 @@ def _p_and_ds(q, k, v, do, lse, delta, *, scale, masked, q_start, kv_start,
 
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                   glse_ref, dq_ref, dq_acc, delta_scr, *, scale, causal,
+                   glse_ref, keep_ref, dq_ref, dq_acc, delta_scr, *, scale, causal,
                    block_q, block_k, num_kv, window=None, kv_blocks=None):
     """`num_kv`, `window`, `kv_blocks`: as `_fwd_kernel`'s."""
     i = pl.program_id(2)
@@ -539,7 +605,8 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         _, ds = _p_and_ds(
             q, k, v_ref[0, 0], do, lse_ref[0, 0, :, :1], delta_scr[:, :1],
             scale=scale, masked=masked, q_start=q_start, kv_start=kv_start,
-            block_q=block_q, block_k=block_k, window=window)
+            block_q=block_q, block_k=block_k, window=window,
+            keep=None if keep_ref is None else keep_ref[0])
         dq_acc[:] += jax.lax.dot_general(
             ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -558,7 +625,7 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                    glse_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
+                    glse_ref, keep_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
                     causal, block_q, block_k, num_q, group=1, window=None,
                     q_blocks=None):
     """`num_q`: the steps a query head takes of the innermost axis — every q
@@ -593,7 +660,8 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         p, ds = _p_and_ds(
             q, k, v_ref[0, 0], do, lse_ref[0, 0, :, :1], delta,
             scale=scale, masked=masked, q_start=q_start, kv_start=kv_start,
-            block_q=block_q, block_k=block_k, window=window)
+            block_q=block_q, block_k=block_k, window=window,
+            keep=None if keep_ref is None else keep_ref[0])
         dv_acc[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -639,14 +707,15 @@ def _visible_kv(q_start, kv_off, *, causal, block_q, block_k, num_kv, window):
 
 
 def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                glse_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                glse_ref, keep_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                 scale, causal, block_q, block_k, num_q, num_kv, group=1,
                 window=None):
     """One step: a q block of one query head against every kv block it sees
     of its key-value head's WHOLE k and v, which stay in VMEM — as the float32
     dk and dv of the head do — while the innermost axis walks the `group`
     query heads that read them, `num_q` q blocks each. The score block of a
-    (q block, kv block) pair is computed once and feeds dq, dk and dv."""
+    (q block, kv block) pair is computed once and feeds dq, dk and dv.
+    `keep_ref`: the q block's (1, bq, Tk) strip of the data mask, or None."""
     y = pl.program_id(2)
     i = y if group == 1 else y % num_q
     q_start = offs_ref[0] + i * block_q
@@ -676,7 +745,8 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             p, ds = _p_and_ds(
                 q, k, v_ref[0, 0, rows, :], do, lse, delta, scale=scale,
                 masked=masked, q_start=q_start, kv_start=kv_off + j * block_k,
-                block_q=block_q, block_k=block_k, window=window)
+                block_q=block_q, block_k=block_k, window=window,
+                keep=None if keep_ref is None else keep_ref[0, :, rows])
             dv_acc[rows, :] += jax.lax.dot_general(
                 p, do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -718,76 +788,76 @@ class BwdPlan(NamedTuple):
 
 @functools.lru_cache(maxsize=None)
 def _bwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
-              vmem: int) -> BwdPlan:
+              vmem: int, keep: bool = False) -> BwdPlan:
     size = jnp.dtype(dtype_name).itemsize
     head = t_k * head_dim
     # two buffers each of k, v in and dk, dv out; dk, dv in float32
     resident = head * (8 * size + 8)
     # a step: two buffers each of q, o, do in and dq out, of lse and its
     # cotangent; dq in float32; the float32 (bq, bk) values (s, p, dp, ds and
-    # a transposed operand) and float32 forms of q, do, o, k, v
+    # a transposed operand) and float32 forms of q, do, o, k, v; with a data
+    # mask two buffers of the q block's int8 strip and a block of it widened
     step = (8 * bq * head_dim * size + 16 * bq * _LANE + 4 * bq * head_dim
-            + 20 * bq * bk + 4 * (3 * bq + 2 * bk) * head_dim)
+            + 20 * bq * bk + 4 * (3 * bq + 2 * bk) * head_dim
+            + (2 * bq * t_k + 4 * bq * bk if keep else 0))
     need, limit = resident + step, vmem * 3 // 4
     plan = BwdPlan("resident" if need <= limit else "split", need, limit)
     # once a shape and process: which backward this shape takes
     logger.info(
-        "flash attention's backward (%d keys, head %d, %s, blocks %d x %d) "
+        "flash attention's backward (%d keys, head %d, %s, blocks %d x %d%s) "
         "takes the %s route: a head's k, v, dk and dv resident in VMEM need "
         "%d bytes of the %d a kernel may use here", t_k, head_dim, dtype_name,
-        bq, bk, plan.route, need, limit)
+        bq, bk, ", a data mask" if keep else "", plan.route, need, limit)
     return plan
 
 
-def bwd_route(t_k: int, head_dim: int, dtype, bq: int, bk: int) -> BwdPlan:
+def bwd_route(t_k: int, head_dim: int, dtype, bq: int, bk: int,
+              keep: bool = False) -> BwdPlan:
     """Which backward a call of `t_k` keys a head takes — `resident`: ONE
     kernel, the key-value head's whole k and v and its float32 dk and dv in
     VMEM, a pair's score block computed once for dq, dk and dv; or `split`: a
     dq kernel and a dkv kernel that each stream kv blocks and each recompute
     the score block, where the head does not fit. A pure function of the
-    shapes, the dtype and the chip's VMEM; nothing a caller sets."""
-    return _bwd_plan(t_k, head_dim, jnp.dtype(dtype).name, bq, bk, _vmem_bytes())
+    shapes, the dtype, whether the call has a data mask (`keep`: its strip
+    sits beside k and v) and the chip's VMEM; nothing a caller sets."""
+    return _bwd_plan(t_k, head_dim, jnp.dtype(dtype).name, bq, bk, _vmem_bytes(),
+                     bool(keep))
 
 
 def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
     """g: cotangent of out (B, T, H, D); g_lse: cotangent of lse (B, H, Tq)
-    or None (out-only variant)."""
-    offs, qt, kt, vt, ot, lse = res              # (B, H, T, D) / lse 4D
+    or None (out-only variant). `res` ends with the data mask where the call
+    had one."""
+    offs, qt, kt, vt, ot, lse, *keep = res       # (B, H, T, D) / lse 4D
     B, H, Tq, D = qt.shape
     gt = g.transpose(0, 2, 1, 3)                 # (B, H, Tq, D)
     operands = (offs, qt, kt, vt, ot, gt, lse)
     if g_lse is not None:
         operands += (jnp.broadcast_to(
             g_lse.astype(jnp.float32)[..., None], (B, H, Tq, _LANE)),)
-    plan = bwd_route(kt.shape[2], D, qt.dtype, bq, bk)
-    static = dict(causal=causal, bq=bq, bk=bk, interpret=interpret, window=window)
+    operands += tuple(keep)
+    plan = bwd_route(kt.shape[2], D, qt.dtype, bq, bk, bool(keep))
+    static = dict(causal=causal, bq=bq, bk=bk, interpret=interpret, window=window,
+                  optional=(g_lse is not None, bool(keep)))
     if plan.route == "resident":
         dq, dk, dv = _bwd_resident(operands, plan.vmem_limit, **static)
     else:
         dq, dk, dv = _bwd_split(operands, **static)
     back = lambda x: x.transpose(0, 2, 1, 3)
-    return None, back(dq), back(dk), back(dv)
+    return (None, back(dq), back(dk), back(dv)) + (None,) * len(keep)
 
 
-def _with_glse(kernel, with_glse, **static):
-    """`kernel` taking the logsumexp's cotangent as its eighth reference, or
-    None there where the call has none."""
-    def call(*refs):
-        if not with_glse:
-            refs = refs[:7] + (None,) + refs[7:]
-        kernel(*refs, **static)
-    return call
-
-
-def _bwd_resident(operands, vmem_limit, *, causal, bq, bk, interpret, window):
+def _bwd_resident(operands, vmem_limit, *, causal, bq, bk, interpret, window,
+                  optional):
     """(dq, dk, dv) as (B, H, T, D) by `_bwd_kernel`: grid axis 1 counts
     KEY-VALUE heads, the sequential axis 2 walks the group's query heads and
-    their q blocks (y = head in group · num_q + q block)."""
+    their q blocks (y = head in group · num_q + q block). `optional`: whether
+    the operands end with (the logsumexp's cotangent, the data mask)."""
     _, qt, kt, vt = operands[:4]
     B, H, Tq, D = qt.shape
     Hkv, Tk = kt.shape[1], kt.shape[2]
     num_q, group = Tq // bq, H // Hkv
-    with_glse = len(operands) == 8
+    with_glse, with_keep = optional
     if group == 1:
         q_at = lambda b, h, y, offs: (b, h, y, 0)
     else:
@@ -795,15 +865,18 @@ def _bwd_resident(operands, vmem_limit, *, causal, bq, bk, interpret, window):
     q_spec = pl.BlockSpec((1, 1, bq, D), q_at)
     lse_spec = pl.BlockSpec((1, 1, bq, _LANE), q_at)
     kv_spec = pl.BlockSpec((1, 1, Tk, D), lambda b, h, y, offs: (b, h, 0, 0))
+    # the q block's strip of the mask: every key, as k and v are whole
+    keep_spec = pl.BlockSpec((1, bq, Tk), lambda b, h, y, offs: (b, y % num_q, 0))
     return pl.pallas_call(
-        _with_glse(_bwd_kernel, with_glse, scale=D ** -0.5, causal=causal,
-                   block_q=bq, block_k=bk, num_q=num_q, num_kv=Tk // bk,
-                   group=group, window=window),
+        _with_optional(_bwd_kernel, 7, optional, scale=D ** -0.5, causal=causal,
+                       block_q=bq, block_k=bk, num_q=num_q, num_kv=Tk // bk,
+                       group=group, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, Hkv, group * num_q),
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec]
-            + ([lse_spec] if with_glse else []),
+            + ([lse_spec] if with_glse else [])
+            + ([keep_spec] if with_keep else []),
             out_specs=[q_spec, kv_spec, kv_spec],
             scratch_shapes=[
                 pltpu.VMEM((bq, D), jnp.float32),
@@ -818,20 +891,21 @@ def _bwd_resident(operands, vmem_limit, *, causal, bq, bk, interpret, window):
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
-        name=_kernel_name("bwd", window),
+        name=_kernel_name("bwd", window, with_keep),
     )(*operands)
 
 
-def _bwd_split(operands, *, causal, bq, bk, interpret, window):
+def _bwd_split(operands, *, causal, bq, bk, interpret, window, optional):
     """(dq, dk, dv) as (B, H, T, D) by the dq kernel and the dkv kernel, each
-    streaming kv (q) blocks through Mosaic's default VMEM."""
+    streaming kv (q) blocks through Mosaic's default VMEM. `optional`: as
+    `_bwd_resident`'s."""
     _, qt, kt, vt = operands[:4]
     B, H, Tq, D = qt.shape
     Hkv, Tk = kt.shape[1], kt.shape[2]
     num_q, num_kv = Tq // bq, Tk // bk
     scale = D ** -0.5
     kv_head, group = _kv_head_of(H, Hkv), H // Hkv
-    with_glse = len(operands) == 8
+    with_glse, with_keep = optional
     if window is None:
         kv_steps, kv_at = num_kv, lambda i, j: j
         q_steps, q_block_at = num_q, lambda x, y: y
@@ -848,16 +922,20 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window):
                            lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
     lse_spec = pl.BlockSpec((1, 1, bq, _LANE),
                             lambda b, h, i, j, offs: (b, h, i, 0))
+    keep_kv = _keep_kv_at(causal, bq, bk, num_kv)
+    keep_spec = pl.BlockSpec((1, bq, bk),
+                             lambda b, h, i, j, offs: (b, i, keep_kv(i, j)))
 
     dq = pl.pallas_call(
-        _with_glse(_bwd_dq_kernel, with_glse, scale=scale, causal=causal,
-                   block_q=bq, block_k=bk, num_kv=kv_steps, window=window,
-                   kv_blocks=num_kv),
+        _with_optional(_bwd_dq_kernel, 7, optional, scale=scale, causal=causal,
+                       block_q=bq, block_k=bk, num_kv=kv_steps, window=window,
+                       kv_blocks=num_kv),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, num_q, kv_steps),
             in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec]
-            + ([lse_spec] if with_glse else []),
+            + ([lse_spec] if with_glse else [])
+            + ([keep_spec] if with_keep else []),
             out_specs=[q_spec],
             scratch_shapes=[
                 pltpu.VMEM((bq, D), jnp.float32),
@@ -866,7 +944,7 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window):
         ),
         out_shape=[_sds(qt.shape, qt.dtype, qt)],
         interpret=interpret,
-        name=_kernel_name("bwd_dq", window),
+        name=_kernel_name("bwd_dq", window, with_keep),
     )(*operands)[0]
 
     # dk/dv sweep: kv block outer (revisited output), q block inner.
@@ -881,15 +959,20 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window):
     q_spec2 = pl.BlockSpec((1, 1, bq, D), q_at)
     kv_spec2 = pl.BlockSpec((1, 1, bk, D), lambda b, h, x, y, offs: (b, h, x, 0))
     lse_spec2 = pl.BlockSpec((1, 1, bq, _LANE), q_at)
+    keep_q = (lambda x, y: jnp.maximum(y, _first_q(x, bq, bk))) if causal \
+        else (lambda x, y: y)
+    keep_spec2 = pl.BlockSpec(
+        (1, bq, bk), lambda b, h, x, y, offs: (b, keep_q(x, y % q_steps), x))
     dk, dv = pl.pallas_call(
-        _with_glse(_bwd_dkv_kernel, with_glse, scale=scale, causal=causal,
-                   block_q=bq, block_k=bk, num_q=q_steps, group=group,
-                   window=window, q_blocks=num_q),
+        _with_optional(_bwd_dkv_kernel, 7, optional, scale=scale, causal=causal,
+                       block_q=bq, block_k=bk, num_q=q_steps, group=group,
+                       window=window, q_blocks=num_q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, Hkv, num_kv, group * q_steps),
             in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, q_spec2,
-                      lse_spec2] + ([lse_spec2] if with_glse else []),
+                      lse_spec2] + ([lse_spec2] if with_glse else [])
+            + ([keep_spec2] if with_keep else []),
             out_specs=[kv_spec2, kv_spec2],
             scratch_shapes=[
                 pltpu.VMEM((bk, D), jnp.float32),
@@ -901,7 +984,7 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window):
             _sds(vt.shape, vt.dtype, vt),
         ],
         interpret=interpret,
-        name=_kernel_name("bwd_dkv", window),
+        name=_kernel_name("bwd_dkv", window, with_keep),
     )(*operands)
     return dq, dk, dv
 
@@ -915,27 +998,29 @@ def _make_flash(causal: bool, bq: int, bk: int, interpret: bool,
     """Returns flash(offs, q, k, v) -> out, or (out, lse(B, H, Tq)) when
     `with_lse` — the lse variant also backpropagates lse's cotangent (the
     ring merge differentiates through it). With a `window` the three kernels
-    run their banded grids."""
+    run their banded grids. A fifth argument, where a call gives one, is the
+    data mask `keep` (B, Tq, Tk) int8: it rides to the kernels and into the
+    residuals, and has no cotangent."""
 
-    def _fwd_transposed(offs, q, k, v):
+    def _fwd_transposed(offs, q, k, v, *keep):
         qt = q.transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)
         vt = v.transpose(0, 2, 1, 3)
-        out, lse = _flash_fwd(offs, qt, kt, vt, causal=causal, bq=bq, bk=bk,
-                              interpret=interpret, window=window)
+        out, lse = _flash_fwd(offs, qt, kt, vt, *keep, causal=causal, bq=bq,
+                              bk=bk, interpret=interpret, window=window)
         # named here, where the residuals are made, so that nothing reads an
         # un-named one
         return (offs, *map(checkpoint_name, (qt, kt, vt, out, lse),
-                           RESIDUAL_NAMES))
+                           RESIDUAL_NAMES), *keep)
 
     @jax.custom_vjp
-    def flash(offs, q, k, v):
-        res = _fwd_transposed(offs, q, k, v)
+    def flash(offs, q, k, v, *keep):
+        res = _fwd_transposed(offs, q, k, v, *keep)
         out = res[4].transpose(0, 2, 1, 3)
         return (out, res[5][..., 0]) if with_lse else out
 
-    def fwd(offs, q, k, v):
-        res = _fwd_transposed(offs, q, k, v)
+    def fwd(offs, q, k, v, *keep):
+        res = _fwd_transposed(offs, q, k, v, *keep)
         out = res[4].transpose(0, 2, 1, 3)
         return ((out, res[5][..., 0]) if with_lse else out), res
 
@@ -955,16 +1040,19 @@ def flash_attention_lse(
     block_q: Optional[int] = None, block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
+    keep: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Flash attention over q (B, T, H, D) and k/v (B, T, Hkv, D), H a
     multiple of Hkv, returning (out, lse) with lse (B, H, Tq) float32. Offsets may be Python ints OR traced int32
     scalars (they ride scalar prefetch). `window=W` (causal, zero offsets):
-    query i sees keys i − W < j ≤ i, through the banded kernels. Raises
+    query i sees keys i − W < j ≤ i, through the banded kernels. `keep` (B,
+    Tq, Tk) int8 or bool (no window, zero offsets): query i sees key j iff
+    also keep[i, j], through the `flash_attention_sel_*` kernels. Raises
     ValueError when the shapes can't be blocked — use `can_flash` first."""
-    flash, offs = _plan_call(q, k, causal, q_offset, kv_offset,
+    flash, args = _plan_call(q, k, causal, q_offset, kv_offset,
                              block_q, block_k, interpret, with_lse=True,
-                             window=window)
-    return flash(offs, q, k, v)
+                             window=window, keep=keep)
+    return flash(args[0], q, k, v, *args[1:])
 
 
 def flash_attention(
@@ -974,13 +1062,14 @@ def flash_attention(
     block_q: Optional[int] = None, block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
+    keep: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Same contract as `ops.attention.full_attention` (output only; the
     cheaper backward — no lse cotangent input)."""
-    flash, offs = _plan_call(q, k, causal, q_offset, kv_offset,
+    flash, args = _plan_call(q, k, causal, q_offset, kv_offset,
                              block_q, block_k, interpret, with_lse=False,
-                             window=window)
-    return flash(offs, q, k, v)
+                             window=window, keep=keep)
+    return flash(args[0], q, k, v, *args[1:])
 
 
 def _no_offset(offset) -> bool:
@@ -1003,12 +1092,30 @@ def _effective_window(window, causal, q_offset, kv_offset, t_k):
     return None if window >= t_k else int(window)
 
 
+def _checked_keep(keep, q, k, window, q_offset, kv_offset):
+    """`keep` as the kernels take it: (B, Tq, Tk) int8."""
+    if window is not None or not (_no_offset(q_offset) and _no_offset(kv_offset)):
+        raise ValueError(
+            "a data mask is for unsharded attention without a window: it takes "
+            f"neither (window={window!r}, q_offset={q_offset!r}, "
+            f"kv_offset={kv_offset!r})")
+    want = (q.shape[0], q.shape[1], k.shape[1])
+    if keep.shape != want or keep.dtype not in (jnp.int8, jnp.bool_):
+        raise ValueError(f"keep is {keep.dtype}{keep.shape}; the kernels take "
+                         f"int8 or bool {want}: one plane a batch row, no head axis")
+    return keep.astype(jnp.int8)
+
+
 def _plan_call(q, k, causal, q_offset, kv_offset, block_q, block_k,
-               interpret, with_lse, window=None):
+               interpret, with_lse, window=None, keep=None):
+    """(the rule of this call's plan, its leading arguments: the offsets and,
+    where the call has one, the data mask)."""
     interpret = kernel_interpret(interpret)
+    if keep is not None:
+        keep = _checked_keep(keep, q, k, window, q_offset, kv_offset)
     window = _effective_window(window, causal, q_offset, kv_offset, k.shape[1])
     blocks = _plan_blocks(q.shape, k.shape, block_q, block_k,
-                          dtype=q.dtype)
+                          dtype=q.dtype, keep=keep is not None)
     if blocks is None:
         raise ValueError(
             f"flash_attention cannot block Tq={q.shape[1]}, Tk={k.shape[1]} "
@@ -1017,8 +1124,8 @@ def _plan_call(q, k, causal, q_offset, kv_offset, block_q, block_k,
     bq, bk = blocks
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(kv_offset, jnp.int32)])
-    return _make_flash(bool(causal), bq, bk, interpret,
-                       bool(with_lse), window), offs
+    return (_make_flash(bool(causal), bq, bk, interpret, bool(with_lse), window),
+            (offs,) if keep is None else (offs, keep))
 
 
 def kv_block_visits(t_q: int, t_k: int, window: Optional[int],
@@ -1040,9 +1147,11 @@ def kv_block_visits(t_q: int, t_k: int, window: Optional[int],
 
 def _plan_blocks(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
                  block_q: Optional[int], block_k: Optional[int],
-                 dtype=None) -> Optional[Tuple[int, int]]:
+                 dtype=None, keep: bool = False) -> Optional[Tuple[int, int]]:
     """(block_q, block_k) for these shapes, or None. Targets not given are
-    `DEFAULT_BLOCK_*`, with or without a window. The targets are for a
+    `DEFAULT_BLOCK_*`, with or without a window, and `SEL_BLOCK_Q` for the q
+    blocks of a call with a data mask (`keep`), whose int8 tiles also set the
+    least block. The targets are for a
     head of at most the lane width; a wider head takes a key block smaller in
     proportion, so that a key block's rows times the head size stay what they
     are at 128. At head 256 and (1024, 1024) the dq kernel's blocks and
@@ -1050,9 +1159,9 @@ def _plan_blocks(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
     bfloat16 on a v5e, forward / forward + backward: (1024, 512) 6.66 / 25.6
     ms, (512, 1024) 7.20 / 25.6, (512, 512) 8.44 / 28.3, (256, 1024) 9.46 /
     30.2 (PERF.md section 6, PR 32)."""
-    block_q = block_q or DEFAULT_BLOCK_Q
+    block_q = block_q or (SEL_BLOCK_Q if keep else DEFAULT_BLOCK_Q)
     block_k = block_k or DEFAULT_BLOCK_K
-    mb = _min_block(dtype)
+    mb = max(_min_block(dtype), _min_block(jnp.int8)) if keep else _min_block(dtype)
     if q_shape[-1] > _LANE:
         block_k = max(mb, block_k * _LANE // q_shape[-1])
     bq = pick_block(q_shape[1], block_q, mb)
@@ -1064,7 +1173,7 @@ def _plan_blocks(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
 
 def can_flash(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
               q_offset=0, kv_offset=0, dtype=None,
-              window: Optional[int] = None) -> bool:
+              window: Optional[int] = None, keep: bool = False) -> bool:
     """True when flash_attention supports these shapes/dtype AND a backend
     that can run the Mosaic kernel is active: real TPU, or CPU inside
     `force_tpu_interpret_mode` (tests). EDL_FLASH=0 force-disables;
@@ -1073,13 +1182,15 @@ def can_flash(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
     than fall back. Offsets may be traced; without a window they are
     accepted for API symmetry and ignored. A `window` is DECLINED with any
     offset that is not the Python integer 0 (ring attention's are traced):
-    the banded grids are for unsharded attention."""
-    if window is not None and not (_no_offset(q_offset) and _no_offset(kv_offset)):
+    the banded grids are for unsharded attention. A data mask (`keep`) is
+    declined with any such offset too, and with a window."""
+    offsets = not (_no_offset(q_offset) and _no_offset(kv_offset))
+    if (window is not None or keep) and offsets or (keep and window is not None):
         return False
     flag = os.environ.get("EDL_FLASH", "")
     if flag == "0":
         return False
-    if _plan_blocks(q_shape, k_shape, None, None, dtype=dtype) is None:
+    if _plan_blocks(q_shape, k_shape, None, None, dtype=dtype, keep=keep) is None:
         return False
     runnable = jax.default_backend() == "tpu" or _interpret_active()
     if flag == "1":

@@ -55,6 +55,12 @@ class ModelSpec:
     # loss, so the aux regularizes training. Zoo modules export it as a
     # module-level `aux_loss_weight` float.
     aux_loss_weight: float = 0.0
+    # Sown terms the step reports BY NAME beside their sum: {name in the
+    # "losses" collection: name in the step's metrics}, each times
+    # `aux_loss_weight` (a model with two auxiliary terms — a load balance and
+    # an indexer's loss, say — whose step would else report one number for
+    # both). Zoo modules export it as a module-level `aux_loss_terms` dict.
+    aux_loss_terms: Dict[str, str] = field(default_factory=dict)
 
     @classmethod
     def from_config(cls, cfg: JobConfig) -> "ModelSpec":
@@ -104,4 +110,5 @@ class ModelSpec:
             ),
             aux_loss_weight=float(
                 getattr(module, "aux_loss_weight", 0.0) or 0.0),
+            aux_loss_terms=dict(getattr(module, "aux_loss_terms", None) or {}),
         )

@@ -106,6 +106,16 @@ def _aux_loss(new_vars, weight: float):
         jnp.sum(jnp.asarray(l, jnp.float32)) for l in leaves)
 
 
+def _aux_terms(new_vars, weight: float, names: Dict[str, str]) -> Dict[str, Any]:
+    """{reported name: weight * the sown term of that name}, for the terms a
+    zoo module asks to see apart (`ModelSpec.aux_loss_terms`)."""
+    sown = new_vars.get("losses", {})
+    return {reported: jnp.float32(weight) * sum(
+                jnp.sum(jnp.asarray(l, jnp.float32))
+                for l in jax.tree_util.tree_leaves(sown[name]))
+            for name, reported in names.items() if name in sown}
+
+
 _warned_scalar_accum = False
 
 
@@ -567,6 +577,7 @@ class Trainer:
         remat_policy = self._resolved_remat_policy
         accum = self.grad_accum
         aux_weight = float(self.spec.aux_loss_weight or 0.0)
+        aux_names = dict(self.spec.aux_loss_terms or {})
 
         def step_fn(state: TrainState, batch):
             features, labels, mask = _split_batch(batch)
@@ -594,6 +605,7 @@ class Trainer:
                 loss = _masked_mean(value, mask)
                 terms = {k: _masked_mean(v, mask).astype(jnp.float32)
                          for k, v in terms.items()}
+                terms.update(_aux_terms(new_vars, aux_weight, aux_names))
                 return loss + _aux_loss(new_vars, aux_weight), (new_vars, terms)
 
             terms = {}      # accumulated micro-batches report the sum alone

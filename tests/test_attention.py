@@ -161,3 +161,57 @@ def test_a_window_needs_a_causal_mask():
     q, k, v = _heads(16, 2, 2)
     with pytest.raises(ValueError, match="CAUSAL"):
         full_attention(q, k, v, causal=False, window=4)
+
+
+# ------------------------------------------------------------------ #
+# a mask that is data (`keep`), in the XLA path
+
+
+def _dense_keep(q, k, v, keep):
+    b, t, h, d = q.shape
+    k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    mask = jnp.tril(jnp.ones((t, t), bool))[None] & keep
+    s = jnp.where(mask[:, None], s, -jnp.inf)
+    return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+def _keep(t, seed=6):
+    r = np.random.RandomState(seed)
+    return jnp.asarray((r.rand(2, t, t) < 0.4) | np.eye(t, dtype=bool)[None])
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 1), (8, 2)])
+@pytest.mark.parametrize("dtype", ["bool", "int8"])
+def test_keep_fallback_matches_a_dense_mask(heads, kv_heads, dtype):
+    """`full_attention`'s XLA path and `_grouped_query_attention` under a data
+    mask, output and logsumexp."""
+    q, k, v = _heads(40, heads, kv_heads, seed=5)
+    keep = _keep(40)
+    got = full_attention(q, k, v, keep=keep.astype(dtype), with_lse=True)
+    for a, b in zip(got, _dense_keep(q, k, v, keep)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6, rtol=2e-6)
+    np.testing.assert_array_equal(np.asarray(full_attention(q, k, v, keep=keep)),
+                                  np.asarray(got[0]))
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (8, 2)])
+def test_keep_fallback_gradients_match_a_dense_mask(heads, kv_heads):
+    q, k, v = _heads(40, heads, kv_heads, seed=7)
+    keep = _keep(40)
+    probe = jnp.asarray(np.random.RandomState(2).randn(*q.shape), jnp.float32)
+    got = jax.grad(lambda *a: jnp.sum(probe * full_attention(*a, keep=keep)),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(probe * _dense_keep(*a, keep)[0]), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6, rtol=2e-6)
+
+
+def test_keep_of_all_ones_is_causal_attention_and_joins_a_window():
+    q, k, v = _heads(40, 4, 2, seed=8)
+    ones = jnp.ones((2, 40, 40), bool)
+    np.testing.assert_array_equal(np.asarray(full_attention(q, k, v, keep=ones)),
+                                  np.asarray(full_attention(q, k, v)))
+    np.testing.assert_array_equal(np.asarray(full_attention(q, k, v, keep=ones, window=7)),
+                                  np.asarray(full_attention(q, k, v, window=7)))

@@ -257,6 +257,37 @@ def test_local_mellum_job_end_to_end(tmp_path):
     assert master.servicer.mean_training_loss() < 6.0
 
 
+def test_local_keye_vl2_job_end_to_end(tmp_path):
+    """Keye-VL-2.0's block (a learned selection of 8 keys a query by a 3-head
+    indexer with its own KL loss, 4/2 grouped-query heads with q/k norms, a
+    held share of softmax-routed experts, two sown auxiliary terms reported by
+    name) through the same master/worker path, evaluation included."""
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.keye_vl2.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 48, "num_hidden_layers": 2,
+            "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+            "indexer_num_heads": 3, "indexer_head_dim": 8, "index_topk": 8,
+            "num_experts": 4, "router_experts": 16, "first_expert": 4,
+            "num_experts_per_tok": 3, "moe_intermediate_size": 24,
+            "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+    )
+    master, _, counts = run_job(cfg, tmp_path)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    assert 0.0 <= master.evaluation.latest_results()["token_accuracy"] <= 1.0
+    # ln 256 = 5.5, two layers' index losses (each well under one at the
+    # seed) and their load-balance terms at 0.001 each
+    assert master.servicer.mean_training_loss() < 7.0
+
+
 def test_run_job_stops_when_the_job_is_dead(tmp_path):
     """The harness itself (tests/jobs.py): a one-process worker started as
     cohort member 2 of 1 dies at world formation on every launch. run_job

@@ -1,0 +1,293 @@
+"""Keye-VL-2.0's language model (model_zoo/transformer/keye_vl2.py: a learned
+selection of keys — a lightning indexer, the K best keys of a query's causal
+prefix, the indexer's own KL loss — over grouped-query heads, a held share of
+softmax-routed gated-SiLU experts) against its plain reference
+(benchmark/reference/keye_vl2.py) on seeded weights, at a tiny size on the CPU:
+hidden 48, two layers, 4/2 heads of 16, 3 index heads of 8, 8 keys a query of
+40, 16 experts top-3 of which experts 4-7 are held, vocabulary 256, float32.
+
+The benchmark's own comparison, and the departures it must catch, are in
+`tests/test_keye_vl2_check.py`.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import common
+from elasticdl_tpu.common.config import JobConfig
+from elasticdl_tpu.ops import pallas_attention, sparse_attention
+from elasticdl_tpu.parallel.mesh import build_mesh
+from elasticdl_tpu.training.model_spec import ModelSpec
+from elasticdl_tpu.training.trainer import Trainer
+from tests.conftest import equations
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TINY = common.load_json("rehearse", "tiny-lm-keye.json")["model_params"]
+INDEX = ("index_wq", "index_wk", "index_k_scale", "index_k_bias", "index_w")
+REST = ("embed", "final_norm", "head", "attn_norm", "wq", "wk", "wv", "wo", "q_norm",
+        "k_norm", "moe_norm", "moe_router", "w_gate", "w_up", "w_down")
+reference = common.load_module("reference", "keye_vl2")
+flops = common.load_module("flops", "keye_vl2")
+
+
+def tiny_params(**more):
+    return {k: str(v) for k, v in {**TINY, **more}.items()}
+
+
+def build_trainer(seed=0, **more):
+    cfg = JobConfig.from_argv([
+        "--model_zoo", os.path.join(ROOT, "model_zoo"),
+        "--model_def", "transformer.keye_vl2.custom_model",
+        "--model_params", common.format_model_params(tiny_params(**more))])
+    spec = ModelSpec.from_config(cfg)
+    return spec, Trainer(spec, build_mesh(devices=jax.devices()[:1]), seed=seed)
+
+
+def batches(steps=2, batch=2, seq=40, seed=1):
+    toks = np.random.default_rng(seed).integers(
+        0, TINY["vocab_size"], (steps, batch, seq + 1)).astype(np.int32)
+    return [{"features": t[:, :-1], "labels": t[:, 1:],
+             "mask": np.ones((batch,), np.float32)} for t in toks]
+
+
+def zoo():
+    return sys.modules["transformer.keye_vl2"]
+
+
+def lively(state, seed=5):
+    """Parameters as a trained model has them rather than as the seed leaves
+    them: router logits and index scores of order one, every norm's weight
+    away from one (the index keys' bias away from zero), projections large
+    enough that attention's softmax is far from a running mean."""
+    r = np.random.default_rng(seed)
+    p = dict(state.params)
+    p["moe_router"] = p["moe_router"] * 8.0
+    for name in p:
+        if name.endswith(("norm", "k_scale")):
+            p[name] = p[name] * jnp.asarray(r.uniform(0.5, 1.5, p[name].shape), jnp.float32)
+    p["index_k_bias"] = jnp.asarray(r.normal(size=p["index_k_bias"].shape) * 0.3, jnp.float32)
+    for name in ("wq", "wk", "wv", "w_gate", "w_up", "index_wq", "index_wk"):
+        p[name] = p[name] * 6.0
+    p["index_w"] = p["index_w"] * 30.0
+    for name in ("wo", "w_down"):
+        p[name] = p[name] * 45.0
+    return state.replace(params=p)
+
+
+def program_terms(spec, params, batch):
+    outputs, sown = spec.model.apply(
+        {"params": params}, batch["features"], training=False,
+        mutable=["losses", "router_state", "dsa"])
+    terms = {k: jnp.mean(v) for k, v in spec.loss(batch["labels"], outputs).items()}
+    terms["loss_balance"] = sown["losses"]["load_balance"]
+    terms["loss_index"] = sown["losses"]["index_kl"]
+    terms["loss"] = terms["loss_ce"] + terms["loss_balance"] + terms["loss_index"]
+    return terms
+
+
+@pytest.fixture(scope="module")
+def case():
+    spec, trainer = build_trainer()
+    batch = batches(steps=1)[0]
+    return spec, trainer, batch, lively(trainer.init_state(batch)).params
+
+
+@pytest.fixture(scope="module")
+def gradients(case):
+    """(program's, reference's) loss terms and gradients of one batch from
+    the same lively parameters, and the program's gradients of the index loss
+    alone and of the rest alone."""
+    spec, _, batch, params = case
+    hp = reference.hyper(tiny_params())
+    ref_batch = {"tokens": batch["features"], "labels": batch["labels"],
+                 "mask": batch["mask"]}
+
+    def program_loss(p, pick=lambda t: t["loss"]):
+        terms = program_terms(spec, p, batch)
+        return pick(terms), terms
+
+    def reference_loss(p):
+        total, terms, _ = reference.loss_terms(p, ref_batch, hp)
+        return total, terms
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(program_loss, has_aux=True))(params)
+        want = jax.jit(jax.value_and_grad(reference_loss, has_aux=True))(params)
+        index_alone = jax.jit(jax.grad(lambda p: program_loss(p, lambda t: t["loss_index"])[0]))(params)
+        rest_alone = jax.jit(jax.grad(lambda p: program_loss(
+            p, lambda t: t["loss_ce"] + t["loss_balance"])[0]))(params)
+    return got, want, index_alone, rest_alone
+
+
+def test_the_three_terms_are_the_reference_s(gradients):
+    (got, got_terms), _ = gradients[0]
+    (want, want_terms), _ = gradients[1]
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for name in ("loss_ce", "loss_balance", "loss_index"):
+        np.testing.assert_allclose(got_terms[name], want_terms[name], rtol=2e-5, err_msg=name)
+    # the selection selects: most rows keep 8 of up to 40 keys, and the
+    # indexer is far from the attention it should imitate
+    assert float(want_terms["loss_index"]) > 0.1
+
+
+@pytest.mark.parametrize("leaf", INDEX + REST)
+def test_every_gradient_is_the_reference_s(gradients, leaf):
+    got, want = gradients[0][1][leaf], gradients[1][1][leaf]
+    assert float(jnp.linalg.norm(want)) > 1e-6
+    assert float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want)) < 2e-5
+
+
+def test_the_two_gradient_paths_do_not_touch(gradients):
+    """The indexer's four parameters receive gradient from the index loss
+    ALONE, and every other parameter receives none from it."""
+    _, _, index_alone, rest_alone = gradients
+    for leaf in INDEX:
+        assert float(jnp.abs(index_alone[leaf]).max()) > 1e-7, leaf
+        assert float(jnp.abs(rest_alone[leaf]).max()) == 0.0, leaf
+    for leaf in REST:
+        assert float(jnp.abs(index_alone[leaf]).max()) == 0.0, leaf
+        assert float(jnp.abs(rest_alone[leaf]).max()) > 1e-7, leaf
+
+
+def test_the_step_reports_each_sown_term_by_name(case):
+    spec, trainer, batch, _ = case
+    state = trainer.init_state(batch)
+    state, metrics = trainer.train_step(state, batch)
+    metrics = {k: float(v) for k, v in metrics.items()}
+    assert sorted(metrics) == ["loss", "loss_balance", "loss_ce", "loss_index"]
+    assert metrics["loss_balance"] > 0 and metrics["loss_index"] > 0
+    assert metrics["loss"] == pytest.approx(
+        metrics["loss_ce"] + metrics["loss_balance"] + metrics["loss_index"], rel=1e-6)
+
+
+def _selections_in(jaxpr):
+    """`select`'s tie rule is the model's only `cond`: one a run of it."""
+    return equations(jaxpr, lambda eqn: eqn.primitive.name == "cond")
+
+
+def test_thresholds_and_keep_are_kept_across_the_recomputation(case, monkeypatch):
+    """Under `KEEP_SELECTION` a layer's backward pass holds no second search:
+    two layers, two `select`s in the whole gradient; under the flash kernels'
+    policy alone, four. The values are the same where recomputing is exact."""
+    spec, _, batch, params = case
+
+    def loss(policy):
+        monkeypatch.setattr(sparse_attention, "KEEP_SELECTION", policy)
+        return lambda p: program_terms(spec, p, batch)["loss"]     # a new closure each time
+
+    kept, flash_only = sparse_attention.KEEP_SELECTION, pallas_attention.KEEP_RESIDUALS
+    count = lambda policy: _selections_in(jax.make_jaxpr(jax.grad(loss(policy)))(params).jaxpr)
+    assert (count(kept), count(flash_only)) == (2, 4)
+    a, b = jax.grad(loss(kept))(params), jax.grad(loss(flash_only))(params)
+    for leaf in INDEX + REST:
+        np.testing.assert_array_equal(np.asarray(a[leaf]), np.asarray(b[leaf]))
+
+
+def test_the_table_built_by_mrope_sections_is_the_plain_one():
+    """A text sequence's three position components are equal, so the table
+    the reference builds BY `mrope_section` from a (3, T) position array is
+    the program's plain table — and is not, as soon as a component differs."""
+    build_trainer()
+    hp = reference.hyper(tiny_params(head_dim=128, rope_theta=10000000))
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(1, 24, 2, 128)), jnp.float32)
+    positions = reference.text_positions(24)
+    assert positions.shape == (3, 24)
+    plain = zoo().rotate(x, zoo().rotary_table(10000000.0, 128, 24))
+    np.testing.assert_allclose(reference.rotary(x, positions, hp), plain, rtol=1e-5, atol=1e-5)
+    # sections [16, 24, 24] of the 64 pairs: pair 20 follows the height
+    moved = positions.at[1].add(3.0)
+    angles = reference.mrope_angles(moved, 128, hp) - reference.mrope_angles(positions, 128, hp)
+    assert np.flatnonzero(np.abs(np.asarray(angles)).max(0) > 0).tolist() == list(range(16, 40))
+    # the index heads' 32 pairs split in proportion: 8, 12, 12
+    angles = reference.mrope_angles(moved, 64, hp) - reference.mrope_angles(positions, 64, hp)
+    assert np.flatnonzero(np.abs(np.asarray(angles)).max(0) > 0).tolist() == list(range(8, 20))
+
+
+def test_the_program_counts_its_selection(case):
+    spec, trainer, batch, _ = case
+    state = trainer.init_state(batch)
+    for _ in range(2):
+        state, _ = trainer.train_step(state, batch)
+    counted = jax.device_get(state.extra_vars)["dsa"]
+    selected = 2 * sum(min(t + 1, 8) for t in range(40))
+    assert counted["selected_pairs"].tolist() == [selected, selected]
+    assert counted["causal_pairs"].tolist() == [2 * 40 * 41 // 2] * 2
+    assert counted["causal_blocks"].tolist() == [2 * 5 * 6 // 2] * 2   # blocks of 8 here
+    assert np.all(counted["live_blocks"] <= counted["causal_blocks"])
+    assert counted["tie_rows"].shape == (2,)
+    share = jax.device_get(state.extra_vars)["router_state"]["pairs_held_share"]
+    assert share.shape == (2,) and np.all((share > 0) & (share < 1))
+
+
+def test_selections_are_what_the_forward_pass_selected(case):
+    spec, _, batch, params = case
+    cfg = spec.model.cfg
+    layer_input, threshold, keep = zoo().selections(params, batch["features"], cfg)
+    assert layer_input.shape == (2, 2, 40, 48) and threshold.shape == (2, 2, 40)
+    assert keep.shape == (2, 2, 40, 40) and keep.dtype == jnp.int8
+    for layer in range(2):
+        plane = zoo().index_plane({k: params[k][layer] for k in zoo().LAYER_KEYS},
+                                  layer_input[layer], cfg)
+        want = reference.own_selection(plane, 8)
+        np.testing.assert_array_equal(np.asarray(keep[layer]) != 0, np.asarray(want))
+
+
+def test_custom_model_ignores_the_harness_keys():
+    spec, _ = build_trainer()
+    assert zoo().custom_model(field_vocab="512", **tiny_params()).cfg == spec.model.cfg
+
+
+@pytest.mark.parametrize("more,count", [
+    ({}, 465_391_104),
+    ({"num_hidden_layers": "48", "num_experts": "128", "vocab_size": "151936"}, 30_640_656_384),
+])
+def test_parameter_count_at_the_cut_and_uncut(more, count):
+    config = common.load_json("configs", "keye-vl-2.0-30b-a3b.json")
+    assert flops.parameter_count({**common.model_params(config), **more}) == count
+
+
+def test_the_program_holds_the_parameters_the_shape_functions_count(case):
+    params = case[3]
+    assert sum(int(np.prod(v.shape)) for v in params.values()) \
+        == flops.parameter_count(tiny_params())
+
+
+# the share of a deployment, tied to the whole (model-configs guide §4)
+
+
+def test_eight_held_shares_make_the_uncut_layer():
+    """One layer at 16 experts top-3: the expert outputs of 8 shares of 2
+    experts (the program's held dispatch) add up to what the reference gives
+    for the layer with every expert held; the attention sub-block and the
+    index loss, which every chip computes alike, are counted once."""
+    r = np.random.default_rng(3)
+    _, trainer = build_trainer(num_hidden_layers=1, num_experts=16, first_expert=0,
+                               router_experts=16)
+    m, data = zoo(), batches(steps=1)[0]
+    whole = {k: v[0] for k, v in lively(trainer.init_state(data)).params.items()
+             if k in m.LAYER_KEYS}
+    x = jnp.asarray(r.normal(size=(2, 40, 48)), jnp.float32)
+    hp_whole = reference.hyper(tiny_params(num_experts=16, first_expert=0))
+    cfg_whole = m.Config(**{**TINY, "num_experts": 16, "first_expert": 0})
+    tables = m.rotary_tables(cfg_whole, 40)
+    with jax.default_matmul_precision("highest"):
+        update, index_kl, _, _ = reference.attention(whole, x, None, hp_whole)
+        mid = x + update
+        want = mid + reference.moe(whole, mid, None, hp_whole)[0]
+        ours, stats = m.attention(whole, x, tables, cfg_whole)
+        np.testing.assert_allclose(ours, update, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(stats["index_kl"], index_kl, rtol=1e-5)
+        total = mid                                 # what every chip computes alike, once
+        for share in range(8):
+            cfg = m.Config(**{**TINY, "num_experts": 2, "first_expert": 2 * share})
+            held = slice(2 * share, 2 * share + 2)
+            part = {**whole, "w_gate": whole["w_gate"][held], "w_up": whole["w_up"][held],
+                    "w_down": whole["w_down"][held]}
+            total = total + m.moe(part, mid, cfg)[0]
+    assert float(jnp.abs(want - mid).max()) > 0.1
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)

@@ -966,3 +966,143 @@ def test_the_backward_route_follows_the_head_s_bytes_and_logs_once_a_shape(
     monkeypatch.setattr(pa, "_vmem_bytes", lambda: 16 << 20)
     assert pa.bwd_route(t_k, head, dtype, bq, bk).route == "split"
 
+
+
+# ------------------------------------------------------------------ #
+# a mask that is data (`keep`)
+
+
+def _keep_case(t=128, heads=4, kv_heads=2, seed=11, share=0.3):
+    """(q, k, v, keep): a random plane that keeps every query's own position
+    and NO key of one whole (32 x 32) block below the diagonal."""
+    r = np.random.RandomState(seed)
+    draw = lambda h: jnp.asarray(r.randn(2, t, h, 16), jnp.float32)
+    keep = r.rand(2, t, t) < share
+    keep |= np.eye(t, dtype=bool)[None]
+    keep[:, 64:96, 0:32] = False
+    return draw(heads), draw(kv_heads), draw(kv_heads), jnp.asarray(keep)
+
+
+def _dense_keep(q, k, v, keep):
+    """(out, lse) by a dense mask: j <= i and keep[i, j]."""
+    b, t, h, d = q.shape
+    k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    mask = jnp.tril(jnp.ones((t, t), bool))[None] & keep
+    s = jnp.where(mask[:, None], s, -jnp.inf)
+    return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+@pytest.mark.parametrize("route", ["resident", "split"])
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (8, 2)])
+def test_keep_forward_and_both_backward_routes_match_a_dense_mask(route, heads, kv_heads,
+                                                                  monkeypatch):
+    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
+
+    take_route(monkeypatch, route)
+    q, k, v, keep = _keep_case(heads=heads, kv_heads=kv_heads)
+    probe = jnp.asarray(np.random.RandomState(5).randn(*q.shape), jnp.float32)
+
+    def loss(f):
+        def value(q, k, v):
+            out, lse = f(q, k, v)
+            return jnp.sum(probe * out) + jnp.sum(jnp.sin(lse)), (out, lse)
+        return jax.value_and_grad(value, argnums=(0, 1, 2), has_aux=True)
+
+    flash = lambda *a: flash_attention_lse(*a, keep=keep, block_q=32, block_k=32,
+                                           interpret=True)
+    ((_, got), got_grads), ((_, want), want_grads) = loss(flash)(q, k, v), loss(
+        lambda *a: _dense_keep(*a, keep))(q, k, v)
+    for a, b in zip(got + got_grads, want + want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("route", ["resident", "split"])
+def test_keep_of_all_ones_is_the_causal_call_to_the_bit(route, monkeypatch):
+    take_route(monkeypatch, route)
+    q, k, v, _ = _keep_case(t=96)
+    ones = jnp.ones((2, 96, 96), jnp.int8)
+    f = lambda keep: jax.value_and_grad(lambda *a: jnp.sum(flash_attention(
+        *a, keep=keep, block_q=32, block_k=32, interpret=True) ** 2), argnums=(0, 1, 2))
+    for a, b in zip(jax.tree_util.tree_leaves(f(ones)(q, k, v)),
+                    jax.tree_util.tree_leaves(f(None)(q, k, v))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("route,keep,want", [
+    ("resident", False, {"flash_attention_fwd": (2, 4, 4, 4), "flash_attention_bwd": (2, 2, 8)}),
+    ("split", False, {"flash_attention_fwd": (2, 4, 4, 4), "flash_attention_bwd_dq": (2, 4, 4, 4),
+                      "flash_attention_bwd_dkv": (2, 2, 4, 8)}),
+    ("resident", True, {"flash_attention_sel_fwd": (2, 4, 4, 4),
+                        "flash_attention_sel_bwd": (2, 2, 8)}),
+    ("split", True, {"flash_attention_sel_fwd": (2, 4, 4, 4),
+                     "flash_attention_sel_bwd_dq": (2, 4, 4, 4),
+                     "flash_attention_sel_bwd_dkv": (2, 2, 4, 8)}),
+])
+def test_keep_none_lowers_to_the_kernels_it_always_did(route, keep, want, monkeypatch):
+    """Read off the lowered calls: without `keep` the names and grids of
+    before, with it names of its own on the SAME grids (no block is skipped for
+    being empty of kept keys), and one operand more."""
+    take_route(monkeypatch, route)
+    q, k, v, plane = _keep_case()
+    f = lambda *a: jnp.sum(flash_attention(*a, keep=plane if keep else None, block_q=32,
+                                           block_k=32, interpret=True) ** 2)
+    jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, k, v).jaxpr
+    assert _kernel_grids(jaxpr) == want
+    operands = []
+    equations(jaxpr, lambda eqn: eqn.primitive.name == "pallas_call"
+              and operands.append(len(eqn.invars)))
+    # offsets, q, k, v (+ keep); offsets, q, k, v, out, do, lse (+ keep)
+    assert sorted(set(operands)) == ([5, 8] if keep else [4, 7])
+
+
+def test_a_keep_call_plans_smaller_q_blocks_and_counts_its_strip():
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    shape = (1, 16384, 32, 128)
+    assert pa._plan_blocks(shape, shape, None, None, dtype=jnp.bfloat16) == (1024, 1024)
+    assert pa._plan_blocks(shape, shape, None, None, dtype=jnp.bfloat16, keep=True) == (512, 1024)
+    vmem = 128 << 20
+    plan = lambda bq, keep: pa._bwd_plan(16384, 128, "bfloat16", bq, 1024, vmem, keep)
+    assert plan(1024, False).route == "resident" and plan(512, True).route == "resident"
+    assert plan(1024, True).route == "split"
+    assert plan(512, True).vmem_bytes - plan(512, False).vmem_bytes \
+        == 2 * 512 * 16384 + 4 * 512 * 1024
+    # an int8 tile has 32 rows: a sequence with no such block is declined
+    assert pa._plan_blocks((1, 48, 2, 16), (1, 48, 2, 16), None, None, keep=True) is None
+
+
+def test_keep_takes_no_window_and_no_offsets(monkeypatch):
+    q, k, v, keep = _keep_case(t=64)
+    keep = keep[:, :64, :64]
+    with pytest.raises(ValueError, match="without a window"):
+        flash_attention(q, k, v, keep=keep, window=8, interpret=True)
+    with pytest.raises(ValueError, match="without a window"):
+        flash_attention(q, k, v, keep=keep, q_offset=64, interpret=True)
+    with pytest.raises(ValueError, match="no head axis"):
+        flash_attention(q, k, v, keep=keep[:, None], interpret=True)
+    with pytest.raises(ValueError, match="int8 or bool"):
+        flash_attention(q, k, v, keep=keep.astype(jnp.float32), interpret=True)
+    monkeypatch.setenv("EDL_FLASH", "1")
+    monkeypatch.setenv("EDL_FLASH_INTERPRET", "1")
+    assert can_flash(q.shape, k.shape, keep=True)
+    assert not can_flash(q.shape, k.shape, keep=True, window=8)
+    assert not can_flash(q.shape, k.shape, keep=True, q_offset=64)
+    assert not can_flash(q.shape, k.shape, keep=True, kv_offset=jnp.int32(0))
+    # `full_attention` then takes its XLA path, with the same mask
+    jaxpr = jax.make_jaxpr(lambda *a: full_attention(*a, keep=keep, window=8))(q, k, v).jaxpr
+    assert not _kernel_grids(jaxpr)
+
+
+def test_full_attention_passes_its_keep_to_the_kernel(monkeypatch):
+    monkeypatch.setenv("EDL_FLASH", "1")
+    monkeypatch.setenv("EDL_FLASH_INTERPRET", "1")
+    monkeypatch.setattr("elasticdl_tpu.ops.pallas_attention.SEL_BLOCK_Q", 32)
+    monkeypatch.setattr("elasticdl_tpu.ops.pallas_attention.DEFAULT_BLOCK_K", 32)
+    q, k, v, keep = _keep_case()
+    jaxpr = jax.make_jaxpr(lambda *a: full_attention(*a, keep=keep, with_lse=True))(q, k, v).jaxpr
+    assert pallas_calls(jaxpr, "flash_attention_sel_fwd") == 1
+    got, want = full_attention(q, k, v, keep=keep, with_lse=True), _dense_keep(q, k, v, keep)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)

@@ -123,6 +123,38 @@ def test_flash_kernels_compile_for_a_v5e(name, one_chip, no_compile_cache):
     assert text.count('custom_call_target="tpu_custom_call"') == len(calls)
 
 
+@pytest.mark.parametrize("route", ["resident", "split"])
+def test_masked_flash_kernels_compile_for_a_v5e(route, one_chip, no_compile_cache, monkeypatch):
+    """keye-vl-2.0-30b-a3b.resident-16k's attention: 32/4 heads of 128, 16 384
+    keys and an int8 `keep` plane as a fifth operand, with the logsumexp
+    returned. The plan gives q blocks of 512 so that the resident backward
+    holds the q block's (512, 16 384) strip of the mask beside the head's k,
+    v, dk and dv; the split route's two kernels take the mask tile by tile."""
+    from elasticdl_tpu.ops import pallas_attention
+
+    b, t, h, hkv, d = 1, 16384, 32, 4, 128
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16, sharding=one_chip)
+    keep = jax.ShapeDtypeStruct((b, t, t), jnp.int8, sharding=one_chip)
+    assert pallas_attention._plan_blocks(q.shape, k.shape, None, None, dtype=jnp.bfloat16,
+                                         keep=True) == (512, 1024)
+    if route == "split":
+        pallas_attention._make_flash.cache_clear()
+        monkeypatch.setattr(pallas_attention, "_vmem_bytes", lambda: 1 << 10)
+    assert pallas_attention.bwd_route(t, d, jnp.bfloat16, 512, 1024, keep=True).route == route
+
+    def forward_and_backward(q, k, v, keep, do):
+        (out, lse), vjp = jax.vjp(lambda q, k, v: pallas_attention.flash_attention_lse(
+            q, k, v, keep=keep, interpret=False), q, k, v)
+        return out, lse, vjp((do, jnp.zeros_like(lse)))
+
+    text = jax.jit(forward_and_backward).lower(q, k, k, keep, q).compile().as_text()
+    calls = re.findall(r"^\s*%\w*?(flash_attention_[a-z_]*?)_*\.\d+ = .*tpu_custom_call", text, re.M)
+    assert sorted(calls) == ["flash_attention_sel_" + part for part in (
+        ["bwd", "fwd"] if route == "resident" else ["bwd_dkv", "bwd_dq", "fwd"])]
+    pallas_attention._make_flash.cache_clear()
+
+
 # nemotron-3-nano-30b-a3b.resident-8k's scan: one sequence of 8192 tokens, 64
 # heads of 64 in 8 groups of 128 state columns, chunks of 128
 SCAN = dict(tokens=8192, heads=64, head_dim=64, groups=8, state=128, chunk=128)
