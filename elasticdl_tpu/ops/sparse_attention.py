@@ -5,8 +5,10 @@ each query's K best keys as a mask that is DATA, and the indexer's own loss.
 Three functions, each over BLOCKS OF QUERY ROWS so that no (heads, T, T) array
 exists, and of the (T, T) planes only `keep` (int8) — `select` and `index_kl`
 each make the score plane's blocks as they need them (`_score_block`) and hold
-no plane of scores or of their gradient. Blocked XLA, not kernels: what each
-costs on the chip is in PERF.md sections 5 and 7.
+no plane of scores or of their gradient. Blocked XLA, but for ONE kernel: the
+pull-back of a score block in the index loss's backward (`index_score_bwd`),
+where XLA's own form writes the 16 heads' float32 scores and their cotangent
+to HBM. What each costs on the chip is in PERF.md sections 5 and 7.
 
 - `index_scores(q_index, k_index, w)`: `I[t, s] = Σ_j w[t, j] ·
   relu(q_index[t, j] · k_index[s])` over the indexer's heads j against ONE key
@@ -31,7 +33,14 @@ costs on the chip is in PERF.md sections 5 and 7.
   rule: `∂L/∂I = (π − p̂) / T` on the kept keys, pulled back block by block to
   the indexer's operands (the scores of a block are computed again there, so
   neither the plane nor its gradient is ever held in the backward pass), and
-  NOTHING for q, k or lse — p̂ is a target, not a path.
+  NOTHING for q, k or lse — p̂ is a target, not a path. The pull-back has two
+  forms that share no logic, chosen by what the code can see (`pullback_keys`,
+  as `pallas_attention.can_flash` chooses for attention): on a TPU (or inside
+  `pallas_attention.interpret_mode()`) at shapes that tile, the Mosaic kernel
+  `index_score_bwd` — a grid over key tiles; per tile and head the score block
+  again in VMEM, `ds = dL/dI · w · (s > 0)`, `dq += ds · k`, `dk += dsᵀ · q`,
+  `dw += Σ dL/dI · relu(s)`; a tile in the rows' future skipped — and
+  everywhere else, and under `EDL_FLASH=0`, `jax.vjp(_score_block)`.
 
 `SELECTION_NAMES` are the `checkpoint_name`s of what `select` decides, and
 `KEEP_SELECTION` the `jax.checkpoint` policy that keeps them beside the flash
@@ -43,12 +52,16 @@ threshold would change sides).
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+import functools
+import os
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.ops import pallas_attention
 
@@ -59,12 +72,15 @@ KEEP_SELECTION = jax.checkpoint_policies.save_only_these_names(
 # Rows a block: the score plane's block (the selection's too: it ranks a block
 # as it makes it) holds (index heads, rows, T) float32 before its sum over
 # heads — 268 MB at 16 heads, 256 rows and 16 384 keys; the index loss's holds
-# that and the target's (heads, rows, T), 134 + 268 MB at 128 rows, and their
-# cotangents in its backward. `LIVE_BLOCK`: the square blocks `live_blocks`
-# counts, the flash kernels' key block.
+# that and the target's (heads, rows, T), 134 + 268 MB at 128 rows, and (where
+# the pull-back is the vjp) the scores' cotangent in its backward.
+# `LIVE_BLOCK`: the square blocks `live_blocks` counts, the flash kernels' key
+# block.
 SCORE_ROWS = 256
 KL_ROWS = 128
 LIVE_BLOCK = 1024
+# Keys a grid step of `index_score_bwd`: PERF.md section 6 (PR 39) has the sweep.
+PULLBACK_KEYS = 1024
 
 
 def _rows(t: int, target: int) -> int:
@@ -248,27 +264,168 @@ def _index_kl_fwd(q_index, k_index, w, q, k, lse, keep):
             (q_index, k_index, w, q, k, lse, keep))
 
 
+def pullback_keys(rows: int, t: int, head_dim: int, q_dtype, k_dtype) -> Optional[int]:
+    """The key tile `index_score_bwd` takes a block of `rows` query rows
+    against `t` keys in, or None where the pull-back of a score block is
+    `jax.vjp(_score_block)`: off a TPU (outside `pallas_attention.
+    interpret_mode()`), under EDL_FLASH=0, and at shapes the kernel has no
+    tiles for. From what the code can see; nothing a caller sets."""
+    if os.environ.get("EDL_FLASH", "") == "0":
+        return None
+    if jax.default_backend() != "tpu" and not pallas_attention._interpret_active():
+        return None
+    if jnp.dtype(q_dtype) != jnp.dtype(k_dtype) or head_dim % 8:
+        return None
+    if rows % pallas_attention._min_block(q_dtype):
+        return None
+    return pallas_attention.pick_block(t, PULLBACK_KEYS, pallas_attention._LANE)
+
+
+def _index_score_bwd_kernel(first_ref, q_ref, k_ref, w_ref, d_ref, dq_ref, dk_ref,
+                            dw_ref, dq_acc, *, rows, block_k):
+    """One key tile of a block of query rows: every head's score block again
+    in VMEM, the relu's mask from it, and the three gradients it feeds. q_ref
+    (1, Hi, R, Di), k_ref (1, bk, Di), w_ref (1, Hi, R, 128) the head weights
+    along the lanes, d_ref (1, bk, R) the tile of dL/dI, keys first (the
+    layout XLA makes a block of the plane in: turning a tile here costs less
+    than XLA's copy of the block for a (R, bk) operand); dq_ref as q_ref,
+    written at the last tile from `dq_acc` (float32); dk_ref (1, Di, bk)
+    float32, this tile's own, the keys along the lanes (so that the operand
+    transposed for it is the head's q, not its (R, bk) block); dw_ref (1, Hi,
+    R, 128) float32 sums by lane, held across the tiles. A tile wholly in the
+    rows' future holds no kept key (dL/dI is exactly zero there) and is
+    skipped. The heads are unrolled: a `fori_loop` over them cost 2.9 ms a
+    layer more (PERF.md section 6, PR 39)."""
+    j = pl.program_id(1)
+    lanes = pallas_attention._LANE
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dw_ref[:] = jnp.zeros_like(dw_ref)
+
+    dk_ref[:] = jnp.zeros_like(dk_ref)
+
+    @pl.when(j * block_k < first_ref[0] + rows)
+    def _tile():
+        k = k_ref[0]
+        d = d_ref[0].T
+        for h in range(q_ref.shape[1]):
+            q = q_ref[0, h]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)    # (R, bk)
+            # jax.nn.relu's gradient: nothing at exactly 0
+            ds = jnp.where(s > 0, d * w_ref[0, h][:, :1], 0.0).astype(k.dtype)
+            dq_acc[h] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+            dk_ref[0] += jax.lax.dot_general(q, ds, (((0,), (0,)), ((), ())),
+                                             preferred_element_type=jnp.float32)
+            weighted = d * jnp.maximum(s, 0.0)
+            dw_ref[0, h] += sum(weighted[:, c:c + lanes] for c in range(0, block_k, lanes))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def index_score_bwd(q_rows, k_index, w_rows, d_scores, first_row, *, block_k, interpret):
+    """`_score_block`'s pull-back as ONE kernel: q_rows (B, R, Hi, Di) and
+    k_index (B, T, Di) in one dtype, w_rows (B, R, Hi) float32, d_scores (B, R,
+    T) float32 — zero on every key past the rows' own positions, the first of
+    which is `first_row` (int32 scalar) — -> the three gradients in the
+    KERNEL'S layouts, which a scan stacks and sums without a transpose a
+    block: dq (B, Hi, R, Di) in q_rows' dtype, dk (B, Di, T) float32, dw (B,
+    Hi, R) float32. The scores are float32 from the operands as they come, as
+    the forward's; mask, head weights and dw are float32; the two matmuls that
+    pull dL/dI back take it rounded to the operands' dtype, which is what the
+    MXU does to a float32 operand."""
+    b, rows, heads, d = q_rows.shape
+    t = k_index.shape[1]
+    lanes = pallas_attention._LANE
+    # the last tile that holds a key of the rows' causal prefix: a later step
+    # names it again, which fetches nothing
+    tile_at = lambda j, first: jnp.minimum(j, (first[0] + rows - 1) // block_k)
+    by_head = pl.BlockSpec((1, heads, rows, d), lambda i, j, first: (i, 0, 0, 0))
+    by_lane = pl.BlockSpec((1, heads, rows, lanes), lambda i, j, first: (i, 0, 0, 0))
+    with jax.named_scope("scores"):
+        dq, dk, dw = pl.pallas_call(
+            functools.partial(_index_score_bwd_kernel, rows=rows, block_k=block_k),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, t // block_k),
+                in_specs=[
+                    by_head,
+                    pl.BlockSpec((1, block_k, d), lambda i, j, first: (i, tile_at(j, first), 0)),
+                    by_lane,
+                    pl.BlockSpec((1, block_k, rows),
+                                 lambda i, j, first: (i, tile_at(j, first), 0)),
+                ],
+                out_specs=[
+                    by_head,
+                    pl.BlockSpec((1, d, block_k), lambda i, j, first: (i, 0, j)),
+                    by_lane,
+                ],
+                scratch_shapes=[pltpu.VMEM((heads, rows, d), jnp.float32)],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((b, heads, rows, d), q_rows.dtype),
+                jax.ShapeDtypeStruct((b, d, t), jnp.float32),
+                jax.ShapeDtypeStruct((b, heads, rows, lanes), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name="index_score_bwd",
+        )(jnp.reshape(first_row, (1,)).astype(jnp.int32),
+          jnp.moveaxis(q_rows, 2, 1), k_index,
+          jnp.broadcast_to(jnp.moveaxis(w_rows, 2, 1)[..., None], (b, heads, rows, lanes)),
+          jnp.swapaxes(d_scores, 1, 2))
+        return dq, dk, jnp.sum(dw, axis=-1)
+
+
 def _index_kl_bwd(res, g):
-    """A block of query rows at a time: its scores again with their pullback,
-    ∂L/∂I = (π − p̂) / T on the kept keys, and that pulled back to the
-    indexer's operands — no (T, T) plane of scores or of their gradient."""
+    """A block of query rows at a time: its scores again, ∂L/∂I = (π − p̂) / T
+    on the kept keys, and that pulled back to the indexer's operands — no
+    (T, T) plane of scores or of their gradient. Two forms of the pull-back,
+    chosen by `pullback_keys`: the kernel `index_score_bwd` beside ONE forward
+    evaluation of the block (the heads' scores and their cotangent then live
+    in VMEM alone), or `jax.vjp(_score_block)`, which writes both."""
     q_index, k_index, w, q, k, lse, keep = res
     b, t = keep.shape[:2]
     rows = _rows(t, KL_ROWS)
+    block_k = pullback_keys(rows, t, q_index.shape[-1], q_index.dtype, k_index.dtype)
+    blocks = _kl_blocks(q_index, w, q, lse, keep, rows)
+
+    def d_scores(q_rows, lse_rows, keep_rows, score_rows):
+        target, log_pi, kept = _target_and_log_pi(q_rows, k, lse_rows, keep_rows, score_rows)
+        return (jnp.where(kept, jnp.exp(log_pi), 0.0) - target) * (g / (t * b))
 
     def block(dk_index, args):
         q_index_rows, w_rows, q_rows, lse_rows, keep_rows = args
         score_rows, pull = jax.vjp(_score_block, q_index_rows, k_index, w_rows)
-        target, log_pi, kept = _target_and_log_pi(q_rows, k, lse_rows, keep_rows, score_rows)
-        d_scores = (jnp.where(kept, jnp.exp(log_pi), 0.0) - target) * (g / (t * b))
-        dq_rows, dk_rows, dw_rows = pull(d_scores)
+        dq_rows, dk_rows, dw_rows = pull(d_scores(q_rows, lse_rows, keep_rows, score_rows))
         return dk_index + dk_rows.astype(jnp.float32), (dq_rows, dw_rows)
 
-    dk_index, (dq_index, dw) = jax.lax.scan(
-        block, jnp.zeros(k_index.shape, jnp.float32),
-        _kl_blocks(q_index, w, q, lse, keep, rows))
-    return (_unblocked(dq_index, 1), dk_index.astype(k_index.dtype),
-            _unblocked(dw, 1).astype(w.dtype),
+    def kernel_block(dk_index, args):
+        (q_index_rows, w_rows, q_rows, lse_rows, keep_rows), first_row = args
+        dq_rows, dk_rows, dw_rows = index_score_bwd(
+            q_index_rows, k_index, w_rows,
+            d_scores(q_rows, lse_rows, keep_rows,
+                     _score_block(q_index_rows, k_index, w_rows)),
+            first_row, block_k=block_k, interpret=pallas_attention.kernel_interpret())
+        return dk_index + dk_rows, (dq_rows, dw_rows)
+
+    if block_k is None:
+        dk_index, (dq_index, dw) = jax.lax.scan(
+            block, jnp.zeros(k_index.shape, jnp.float32), blocks)
+        dq_index, dw = _unblocked(dq_index, 1), _unblocked(dw, 1)
+    else:
+        # the kernel's layouts: heads before rows, dk's keys along the lanes
+        dk_index, (dq_index, dw) = jax.lax.scan(
+            kernel_block, jnp.zeros((b, k_index.shape[2], t), jnp.float32),
+            (blocks, jnp.arange(0, t, rows, dtype=jnp.int32)))
+        dq_index, dw = (jnp.moveaxis(_unblocked(x, 2), 1, 2) for x in (dq_index, dw))
+        dk_index = jnp.swapaxes(dk_index, 1, 2)
+    return (dq_index, dk_index.astype(k_index.dtype), dw.astype(w.dtype),
             jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(lse), None)
 
 

@@ -24,7 +24,7 @@ from elasticdl_tpu.ops import pallas_attention, sparse_attention
 from elasticdl_tpu.parallel.mesh import build_mesh
 from elasticdl_tpu.training.model_spec import ModelSpec
 from elasticdl_tpu.training.trainer import Trainer
-from tests.conftest import equations
+from tests.conftest import equations, pallas_calls
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = common.load_json("rehearse", "tiny-lm-keye.json")["model_params"]
@@ -186,6 +186,43 @@ def test_thresholds_and_keep_are_kept_across_the_recomputation(case, monkeypatch
     a, b = jax.grad(loss(kept))(params), jax.grad(loss(flash_only))(params)
     for leaf in INDEX + REST:
         np.testing.assert_array_equal(np.asarray(a[leaf]), np.asarray(b[leaf]))
+
+
+@pytest.fixture(scope="module")
+def pull_back_routes(case):
+    """(jaxpr, gradients) of a batch of 128 tokens — one block of `KL_ROWS`
+    rows against one key tile — with the index loss's pull-back as
+    `jax.vjp(_score_block)` (a plain CPU) and as the kernel `index_score_bwd`
+    (the interpret signal, which also sends the held experts through their
+    grouped-matmul kernel)."""
+    spec, _, _, params = case
+    batch = batches(steps=1, seq=128)[0]
+
+    def traced_and_run():
+        loss = lambda p: program_terms(spec, p, batch)["loss"]      # a new closure each time
+        return jax.make_jaxpr(jax.grad(loss))(params).jaxpr, jax.grad(loss)(params)
+
+    plain = traced_and_run()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv(pallas_attention._INTERPRET_ENV, "1")
+        return plain, traced_and_run()
+
+
+def test_the_model_s_backward_holds_the_pull_back_kernel_once_a_layer(pull_back_routes):
+    """Two layers: two `index_score_bwd` on the kernel's route, none on a
+    plain CPU, and on both ONE `select` a layer under `KEEP_SELECTION`."""
+    (plain, _), (kernel, _) = pull_back_routes
+    assert pallas_calls(plain, "index_score_bwd") == 0
+    assert pallas_calls(kernel, "index_score_bwd") == 2
+    assert (_selections_in(plain), _selections_in(kernel)) == (2, 2)
+
+
+@pytest.mark.parametrize("leaf", INDEX)
+def test_the_indexer_learns_the_same_by_either_pull_back(pull_back_routes, leaf):
+    (_, plain), (_, kernel) = pull_back_routes
+    assert float(jnp.linalg.norm(plain[leaf])) > 1e-6
+    assert float(jnp.linalg.norm(kernel[leaf] - plain[leaf])
+                 / jnp.linalg.norm(plain[leaf])) < 2e-5
 
 
 def test_the_table_built_by_mrope_sections_is_the_plain_one():

@@ -9,7 +9,9 @@ state-space scan's three kernels at the Nemotron cell's shapes
 (`ops/pallas_ssd.py`), alone and inside a checkpointed Mamba mixer, where
 every one of them has to carry the scope the benchmark reads it by — as the
 flash kernels have to inside the checkpointed layers of GLM and Nemotron,
-which keep the forward kernel's results and so hold ONE forward call a block. What
+which keep the forward kernel's results and so hold ONE forward call a block; and
+the pull-back of the Keye cell's index scores (`ops/sparse_attention.py::
+index_score_bwd`) at that cell's shape. What
 interpret mode cannot see — a block Mosaic refuses, more VMEM than a kernel
 may use — fails here and costs no chip time. Nothing runs: no time, no result.
 
@@ -153,6 +155,29 @@ def test_masked_flash_kernels_compile_for_a_v5e(route, one_chip, no_compile_cach
     assert sorted(calls) == ["flash_attention_sel_" + part for part in (
         ["bwd", "fwd"] if route == "resident" else ["bwd_dkv", "bwd_dq", "fwd"])]
     pallas_attention._make_flash.cache_clear()
+
+
+def test_the_index_loss_s_pull_back_compiles_for_a_v5e(one_chip, no_compile_cache, monkeypatch):
+    """keye-vl-2.0-30b-a3b.resident-16k's indexer: a block of 128 query rows,
+    16 heads of 64 against 16 384 keys in bfloat16, at the key tile the route
+    gives there (`sparse_attention.pullback_keys` asks which backend it is on,
+    so the test answers for the described chip)."""
+    from elasticdl_tpu.ops import sparse_attention
+
+    b, rows, t, heads, d = 1, 128, 16384, 16, 64
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    block_k = sparse_attention.pullback_keys(rows, t, d, jnp.bfloat16, jnp.bfloat16)
+    assert block_k == sparse_attention.PULLBACK_KEYS
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    text = jax.jit(lambda *operands: sparse_attention.index_score_bwd(
+        *operands, block_k=block_k, interpret=False)).lower(
+        shape((b, rows, heads, d), jnp.bfloat16), shape((b, t, d), jnp.bfloat16),
+        shape((b, rows, heads), jnp.float32), shape((b, rows, t), jnp.float32),
+        shape((), jnp.int32)).compile().as_text()
+    # under its scope: `%scores_index_score_bwd.1`-like, one call
+    assert len(re.findall(r"^\s*%\w*index_score_bwd\w*\.\d+ = .*tpu_custom_call", text, re.M)) == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 # nemotron-3-nano-30b-a3b.resident-8k's scan: one sequence of 8192 tokens, 64

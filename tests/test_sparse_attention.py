@@ -8,18 +8,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from elasticdl_tpu.ops import pallas_attention
 from elasticdl_tpu.ops import sparse_attention as sa
 from elasticdl_tpu.ops.attention import full_attention
-from tests.conftest import equations
+from tests.conftest import equations, pallas_calls
 
 B, T, H, HKV, D, HI, DI = 2, 64, 4, 2, 16, 3, 8
 
 
-def _draw(seed=0):
+def _draw(seed=0, t=T):
     r = np.random.default_rng(seed)
     f = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)
-    return dict(q_index=f(B, T, HI, DI), k_index=f(B, T, DI), w=f(B, T, HI),
-                q=f(B, T, H, D), k=f(B, T, HKV, D), v=f(B, T, HKV, D))
+    return dict(q_index=f(B, t, HI, DI), k_index=f(B, t, DI), w=f(B, t, HI),
+                q=f(B, t, H, D), k=f(B, t, HKV, D), v=f(B, t, HKV, D))
 
 
 def _plain_scores(q_index, k_index, w):
@@ -58,7 +59,7 @@ def _plain_kl(scores, q, k, keep, detach=True):
     log_pi = jnp.where(kept, jax.nn.log_softmax(
         jnp.where(kept, scores, -jnp.inf), axis=-1), 0.0)
     log_target = jnp.log(jnp.where(target > 0, target, 1.0))
-    return jnp.sum(jnp.where(kept, target * (log_target - log_pi), 0.0)) / (T * B)
+    return jnp.sum(jnp.where(kept, target * (log_target - log_pi), 0.0)) / (keep.shape[1] * B)
 
 
 @pytest.mark.parametrize("rows", [256, 16, 1])
@@ -124,17 +125,35 @@ def test_live_blocks_count_the_blocks_that_hold_a_kept_key(monkeypatch):
     assert int(counts["causal_blocks"]) == 4 * 5 // 2
 
 
-def _kl_case(seed=1, k=16):
-    d = _draw(seed)
+def _kl_case(seed=1, k=16, t=T):
+    d = _draw(seed, t)
     _, keep, _ = sa.select(d["q_index"], d["k_index"], d["w"], k)
     _, lse = full_attention(d["q"], d["k"], d["v"], keep=keep, with_lse=True)
     return d, sa.index_scores(d["q_index"], d["k_index"], d["w"]), keep, lse
 
 
-@pytest.mark.parametrize("rows", [128, 8])
-def test_index_kl_and_its_gradient_are_the_plain_form_s(rows, monkeypatch):
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """The Pallas kernels' route on the CPU: the signal alone, so that they
+    run by `pallas_call(interpret=True)`; key tiles of 128, so that a plane of
+    256 keys has two."""
+    monkeypatch.setenv(pallas_attention._INTERPRET_ENV, "1")
+    monkeypatch.setattr(sa, "PULLBACK_KEYS", 128)
+
+
+def _pulled_back(jaxpr):
+    return pallas_calls(jaxpr, "index_score_bwd")
+
+
+# the pull-back of a score block by `jax.vjp(_score_block)` (64 keys have no
+# key tile) and by the kernel, two tiles a block of rows
+@pytest.mark.parametrize("route, t, rows", [
+    ("vjp", 64, 128), ("vjp", 64, 8), ("kernel", 256, 128), ("kernel", 256, 32)])
+def test_index_kl_and_its_gradient_are_the_plain_form_s(route, t, rows, monkeypatch, request):
+    if route == "kernel":
+        request.getfixturevalue("kernel_route")
     monkeypatch.setattr(sa, "KL_ROWS", rows)
-    d, scores, keep, lse = _kl_case()
+    d, scores, keep, lse = _kl_case(t=t, k=16 * t // 64)
 
     def ours(q_index, k_index, w):
         return sa.index_kl(q_index, k_index, w, d["q"], d["k"], lse, keep)
@@ -143,6 +162,7 @@ def test_index_kl_and_its_gradient_are_the_plain_form_s(rows, monkeypatch):
         return _plain_kl(_plain_scores(q_index, k_index, w), d["q"], d["k"], keep)
 
     operands = (d["q_index"], d["k_index"], d["w"])
+    assert _pulled_back(jax.make_jaxpr(jax.grad(ours))(*operands).jaxpr) == (route == "kernel")
     got, got_grads = jax.value_and_grad(ours, argnums=(0, 1, 2))(*operands)
     want, want_grads = jax.value_and_grad(plain, argnums=(0, 1, 2))(*operands)
     assert float(want) > 0.05
@@ -150,6 +170,124 @@ def test_index_kl_and_its_gradient_are_the_plain_form_s(rows, monkeypatch):
     for a, b in zip(got_grads, want_grads):
         assert float(jnp.abs(b).max()) > 1e-5
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-7)
+
+
+def _pull_back_case(rows, t, heads, first_row, dtype, draw):
+    """A block of `rows` query rows from `first_row` on against t keys, and a
+    cotangent that is zero on the keys of their future, as dL/dI is."""
+    r = np.random.default_rng(rows + t + heads + first_row)
+    q_rows, k_index = (jnp.asarray(draw(r, shape), dtype)
+                       for shape in ((B, rows, heads, 64), (B, t, 64)))
+    w_rows = jnp.asarray(r.normal(size=(B, rows, heads)), jnp.float32)
+    causal = np.arange(t)[None, :] <= first_row + np.arange(rows)[:, None]
+    d_scores = jnp.asarray(r.normal(size=(B, rows, t)) * causal, jnp.float32)
+    return q_rows, k_index, w_rows, d_scores
+
+
+def _both_pull_backs(q_rows, k_index, w_rows, d_scores, first_row, block_k):
+    want = jax.vjp(sa._score_block, q_rows, k_index, w_rows)[1](d_scores)
+    dq, dk, dw = sa.index_score_bwd(q_rows, k_index, w_rows, d_scores, jnp.int32(first_row),
+                                    block_k=block_k, interpret=True)
+    assert (dq.dtype, dk.dtype, dw.dtype) == (q_rows.dtype, jnp.float32, jnp.float32)
+    # the kernel's layouts: heads before rows, dk's keys along the lanes
+    return (jnp.moveaxis(dq, 1, 2), jnp.swapaxes(dk, 1, 2), jnp.moveaxis(dw, 1, 2)), want
+
+
+_normal = lambda r, shape: r.normal(size=shape)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("which", ["first", "middle", "last"])
+@pytest.mark.parametrize("heads", [16, 4])
+@pytest.mark.parametrize("t", [512, 2048])
+def test_the_pull_back_kernel_is_the_vjp_of_a_score_block(t, heads, which, dtype):
+    """dq, dk and dw of `index_score_bwd` (interpret mode) against
+    `jax.vjp(_score_block)`, four key tiles a block: the first block of rows
+    skips three of them, the last none, and nothing changes. With bfloat16
+    operands the kernel rounds dL/dI · w · mask to bfloat16 where the CPU's vjp
+    keeps float32 (the chip rounds both): a bfloat16's width apart."""
+    rows, first_row = 128, {"first": 0, "middle": t // 2, "last": t - 128}[which]
+    operands = _pull_back_case(rows, t, heads, first_row, jnp.dtype(dtype), _normal)
+    got, want = _both_pull_backs(*operands, first_row, t // 4)
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(b).max() > 1.0
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-4)
+        else:
+            assert np.abs(a - b).max() <= 0.01 * np.abs(b).max()
+
+
+def test_the_pull_back_kernel_gives_a_score_of_exactly_zero_no_gradient():
+    """The relu's edge: operands of −1, 0 and 1 make a fifth of the scores
+    exactly 0, where `jax.nn.relu` passes nothing back; sums of small integers
+    are exact, so the two forms agree to the bit in dq and dk."""
+    small = lambda r, shape: r.integers(-1, 2, size=shape) * (r.random(shape) < 0.05)
+    q_rows, k_index, w_rows, d_scores = _pull_back_case(128, 512, 4, 384, jnp.float32, small)
+    w_rows, d_scores = jnp.round(4 * w_rows), jnp.round(4 * d_scores)
+    scores = jnp.einsum("brhd,bsd->bhrs", q_rows, k_index)
+    assert 0.05 < float(jnp.mean(scores == 0)) < 0.99 and float(jnp.mean(scores > 0)) > 0.005
+    got, want = _both_pull_backs(q_rows, k_index, w_rows, d_scores, 384, 128)
+    for a, b in zip(got, want):
+        assert float(jnp.abs(b).max()) > 0
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _planes(jaxpr, shape):
+    """Equations of a jaxpr (kernels' bodies not) with a result of `shape`."""
+    return equations(jaxpr, lambda eqn: any(
+        getattr(v.aval, "shape", None) == shape for v in eqn.outvars))
+
+
+def test_the_gradient_s_program_holds_the_kernel_or_the_vjp(monkeypatch, request):
+    """On the kernel's route the backward's scan holds ONE `index_score_bwd` and,
+    of arrays the size of the heads' scores (B, Hi, R, T), only those of one
+    forward evaluation of the block — no cotangent of them, no mask; on a plain
+    CPU it holds no kernel and is the program it is with the kernels switched
+    off, the heads' scores written and read."""
+    monkeypatch.setattr(sa, "KL_ROWS", 128)
+    d, _, keep, lse = _kl_case(t=256, k=64)
+    operands = (d["q_index"], d["k_index"], d["w"])
+    loss = lambda *a: sa.index_kl(*a, d["q"], d["k"], lse, keep)
+    heads_scores = (B, HI, 128, 256)
+    forward = _planes(jax.make_jaxpr(loss)(*operands).jaxpr, heads_scores)
+    assert forward >= 1
+
+    plain = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*operands)
+    assert _pulled_back(plain.jaxpr) == 0
+    assert _planes(plain.jaxpr, heads_scores) > 2 * forward
+
+    request.getfixturevalue("kernel_route")
+    assert sa.pullback_keys(128, 256, DI, jnp.float32, jnp.float32) == 128
+    kernel = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*operands).jaxpr
+    assert _pulled_back(kernel) == 1
+    # the scan that holds the kernel holds, of the heads' scores, ONE forward
+    # evaluation's; the loss's forward pass has the other
+    bodies = []
+    equations(kernel, lambda eqn: eqn.primitive.name == "scan" and _pulled_back(
+        eqn.params["jaxpr"].jaxpr) == 1 and bodies.append(eqn.params["jaxpr"].jaxpr))
+    assert len(bodies) == 1 and _planes(bodies[0], heads_scores) == forward
+    assert _planes(kernel, heads_scores) == 2 * forward
+
+    monkeypatch.setenv("EDL_FLASH", "0")
+    assert sa.pullback_keys(128, 256, DI, jnp.float32, jnp.float32) is None
+    assert str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*operands)) == str(plain)
+
+
+@pytest.mark.parametrize("rows, t, q_dtype, k_dtype, keys", [
+    (128, 16384, "bfloat16", "bfloat16", 1024),      # the cell's
+    (128, 512, "float32", "float32", 512),
+    (128, 40, "float32", "float32", None),           # the tiny preset: no key tile
+    (128, 1024 + 64, "float32", "float32", None),
+    (8, 512, "bfloat16", "bfloat16", None),          # half a bfloat16 tile of rows
+    (8, 512, "float32", "float32", 512),
+    (128, 512, "float32", "bfloat16", None),
+])
+def test_the_kernel_takes_the_shapes_it_has_tiles_for(rows, t, q_dtype, k_dtype, keys,
+                                                       monkeypatch):
+    assert sa.pullback_keys(rows, t, 64, q_dtype, k_dtype) is None     # a plain CPU
+    monkeypatch.setenv(pallas_attention._INTERPRET_ENV, "1")
+    assert sa.pullback_keys(rows, t, 64, q_dtype, k_dtype) == keys
 
 
 def test_index_kl_gives_the_attention_no_gradient():
