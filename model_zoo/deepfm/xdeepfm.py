@@ -1,8 +1,13 @@
 """xDeepFM — parity config #4b (reference model_zoo xdeepfm variant).
 
 DeepFM plus a Compressed Interaction Network (CIN): explicit high-order
-feature interactions computed as einsums — exactly the shape of work the MXU
-is built for (batched matmuls over (field, dim) planes), in bfloat16.
+feature interactions, one matrix product over the (feature map, field)
+outer-product plane for each embedding coordinate, in bfloat16 with float32
+accumulation. Most of the step's arithmetic and, as XLA runs its backward,
+most of its time (PERF.md sections 5 and 6, PR 40). Two routes, picked by
+`ops/pallas_cin.py::cin_route` from what the code can see: on one TPU (or in
+interpret mode) the Pallas kernels, which make the plane and its pulled-back
+twin a tile at a time in VMEM; anywhere else the three-operand einsum below.
 """
 
 from typing import Tuple
@@ -12,12 +17,28 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from elasticdl_tpu.ops import pallas_cin
 from model_zoo.deepfm.deepfm import (
     DeepFM,
     dataset_fn,  # noqa: F401  (same Criteo record format)
     eval_metrics_fn,  # noqa: F401
     loss,  # noqa: F401
 )
+
+
+def cin_einsum(ws, x0):
+    """CIN in `jax.numpy`: ws[i] (O_i, H_i * F) in the compute dtype, x0
+    (B, F, D) -> (B, sum of O_i). One three-operand einsum a layer; XLA
+    contracts it pairwise and, in the backward, writes the pulled-back
+    (B, H, F, D) plane to HBM and reduces it twice (PERF.md section 6,
+    PR 40). The route off the chip and across devices, and the reference
+    `tests/test_pallas_cin.py` holds the kernels to."""
+    xk, outs = x0, []
+    for w in ws:
+        wr = w.reshape(w.shape[0], xk.shape[1], x0.shape[1])
+        xk = jnp.einsum("ohf,bhd,bfd->bod", wr, xk, x0)  # (B, O, D)
+        outs.append(jnp.sum(xk, axis=-1))                # (B, O)
+    return jnp.concatenate(outs, axis=-1)
 
 
 class CIN(nn.Module):
@@ -28,25 +49,21 @@ class CIN(nn.Module):
     def __call__(self, x0):
         # x0: (B, F, D)
         x0 = x0.astype(self.compute_dtype)
-        xk = x0
-        outs = []
+        ws, hk = [], x0.shape[1]
         for i, h in enumerate(self.layer_sizes):
-            hk = xk.shape[1]
-            w = self.param(
+            ws.append(self.param(
                 f"w{i}",
                 nn.initializers.glorot_uniform(),
                 (h, hk * x0.shape[1]),
                 jnp.float32,
-            ).astype(self.compute_dtype)
-            # ONE 3-operand einsum per layer instead of materializing the
-            # (B, Hk, F, D) outer-product plane z and re-contracting it:
-            # XLA's pairwise decomposition avoids the ~437 MB intermediate
-            # round-trip (chip-measured 1.5x on fwd+bwd; param shape and
-            # math unchanged — w reshapes to (h, Hk, F))
-            wr = w.reshape(h, hk, x0.shape[1])
-            xk = jnp.einsum("ohf,bhd,bfd->bod", wr, xk, x0)  # (B, h, D)
-            outs.append(jnp.sum(xk, axis=-1))                # (B, h)
-        return jnp.concatenate(outs, axis=-1)
+            ))
+            hk = h
+        route = pallas_cin.cin_route(
+            x0.shape, self.layer_sizes, self.compute_dtype,
+            pallas_cin.runnable(), pallas_cin.ambient_devices())
+        if route == "kernel":
+            return pallas_cin.cin(ws, x0)
+        return cin_einsum([w.astype(self.compute_dtype) for w in ws], x0)
 
 
 class XDeepFM(nn.Module):

@@ -11,7 +11,8 @@ every one of them has to carry the scope the benchmark reads it by — as the
 flash kernels have to inside the checkpointed layers of GLM and Nemotron,
 which keep the forward kernel's results and so hold ONE forward call a block; and
 the pull-back of the Keye cell's index scores (`ops/sparse_attention.py::
-index_score_bwd`) at that cell's shape. What
+index_score_bwd`) at that cell's shape; and xDeepFM's CIN kernels
+(`ops/pallas_cin.py`) at the Criteo cell's. What
 interpret mode cannot see — a block Mosaic refuses, more VMEM than a kernel
 may use — fails here and costs no chip time. Nothing runs: no time, no result.
 
@@ -178,6 +179,34 @@ def test_the_index_loss_s_pull_back_compiles_for_a_v5e(one_chip, no_compile_cach
     # under its scope: `%scores_index_score_bwd.1`-like, one call
     assert len(re.findall(r"^\s*%\w*index_score_bwd\w*\.\d+ = .*tpu_custom_call", text, re.M)) == 1
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_the_cin_kernels_compile_for_a_v5e(one_chip, no_compile_cache):
+    """xdeepfm-criteo.resident's CIN: 200-200-200 feature maps over 26 fields
+    x 10 coordinates at batch 55 296 in bfloat16, forward and backward, at
+    the tiles the rule gives there: three calls of each kernel, in two
+    shapes (the first layer's 26 maps in, the others' 200), and no array of
+    the (B, H, F, D) plane's size outside them."""
+    from elasticdl_tpu.ops import pallas_cin
+
+    b, f, d, sizes = 55296, 26, 10, (200, 200, 200)
+    assert pallas_cin.network_tiles((b, f, d), sizes, jnp.bfloat16) == 512
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+    ws = [shape((o, h * f), jnp.float32) for h, o in zip((f,) + sizes, sizes)]
+
+    def forward_and_backward(ws, x0, ct):
+        out, vjp = jax.vjp(pallas_cin.cin, ws, x0)
+        return out, vjp(ct)
+
+    exe = jax.jit(forward_and_backward).lower(
+        ws, shape((b, f, d), jnp.bfloat16), shape((b, sum(sizes)), jnp.bfloat16)).compile()
+    text = exe.as_text()
+    for kernel in ("cin_fwd", "cin_bwd"):
+        assert len(re.findall(rf"^\s*%{kernel}\.\d+ = .*tpu_custom_call", text, re.M)) == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    # the plane is 5.75 GB in bfloat16; the program's temporaries are the
+    # layers' (208, 552 960) activations and gradients
+    assert exe.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
 # nemotron-3-nano-30b-a3b.resident-8k's scan: one sequence of 8192 tokens, 64
