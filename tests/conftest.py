@@ -23,6 +23,24 @@ import jax  # noqa: E402
 # accelerator host; the meshes here want 8 devices).
 _TEST_PLATFORM = (os.environ.get("EDL_TEST_PLATFORM") or "cpu").strip()
 jax.config.update("jax_platforms", _TEST_PLATFORM)
+# Nobody times a CPU program here (a CPU number is never a measurement), and
+# the tiny programs run for milliseconds after compiling for seconds: XLA's
+# backend optimisation level 0 and LLVM's expensive passes off. A constant of
+# the harness, set in this process alone: the jobs' worker processes, whose
+# environment is an input, compile as a deployment does.
+jax.config.update("jax_disable_most_optimizations", True)
+
+
+@contextlib.contextmanager
+def default_pipeline():
+    """XLA's own optimisations back, for the few cases the constant above
+    does not suit: the AOT compiles for a described v5e, which read what
+    XLA:TPU and Mosaic emit, and a case whose seconds are execution."""
+    jax.config.update("jax_disable_most_optimizations", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_disable_most_optimizations", True)
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO_ROOT not in sys.path:
@@ -61,6 +79,14 @@ def pallas_calls(jaxpr, name):
     """How many `pallas_call`s called `name` a jaxpr holds."""
     return equations(jaxpr, lambda eqn: eqn.primitive.name == "pallas_call"
                      and eqn.params["name"] == name)
+
+
+@pytest.fixture(scope="module")
+def xla_optimises():
+    """`default_pipeline()` for a module (`pytestmark = pytest.mark.
+    usefixtures("xla_optimises")`): the AOT compiles' two files."""
+    with default_pipeline():
+        yield
 
 
 @pytest.fixture(scope="session")

@@ -8,6 +8,7 @@ into os.environ must show (tests/conftest.py fails the test that leaked it),
 not be papered over here.
 """
 
+import dataclasses
 import glob
 import time
 
@@ -28,8 +29,17 @@ def all_logs(tmp_path) -> str:
     return "\n".join(out)
 
 
+def patient_master(cfg, reap_after_s=90.0) -> Master:
+    """A master whose reaper waits `reap_after_s` for a heartbeat whatever
+    cadence `cfg` gives the workers: a worker's first compile beside five
+    other xdist workers outlasts the 1-3 s that three test-sized heartbeats
+    make, and a worker reaped while it compiles fails a case that is about
+    something else. The workers keep `cfg`'s own heartbeat."""
+    return Master(dataclasses.replace(cfg, worker_heartbeat_s=reap_after_s / 3))
+
+
 def run_job(cfg, tmp_path, *, mid_job=None, observer=None, timeout_s=420,
-            extra_env=None):
+            extra_env=None, master_of=Master):
     """Run `cfg` to completion; returns (master, manager, counts), both
     already shut down.
 
@@ -39,8 +49,9 @@ def run_job(cfg, tmp_path, *, mid_job=None, observer=None, timeout_s=420,
     Raises AssertionError, with the dispatcher's counts and the tail of the
     worker logs, as soon as every worker is dead with its relaunch budget
     spent — a dead job costs its launches, not the deadline — or, as the
-    last resort, at `timeout_s`."""
-    master = Master(cfg)
+    last resort, at `timeout_s`. `master_of(cfg)` builds the master
+    (`patient_master` where no case of the test needs the reaper)."""
+    master = master_of(cfg)
     manager = ProcessManager(
         cfg,
         membership=master.membership,

@@ -365,14 +365,35 @@ def test_data_plane_leg_smoke(bench, monkeypatch, tmp_path):
     through the incident CLI (what the chaos-data-plane CI job runs).
     The 3x-p99 boundedness gate belongs to the real bench run — a
     throttled CI box can't hold a tight percentile — so the smoke pins
-    an ABSOLUTE ceiling far under the deadline the control pays."""
+    a ceiling far under the deadline the control pays: half of it on a
+    quiet machine, and on a shared one as much more as the run's own
+    median read says every read was stretched."""
+    import statistics
+    import time
+
+    from elasticdl_tpu.embedding import tier
+
     art = str(tmp_path / "art")
     monkeypatch.setenv("EDL_BENCH_ARTIFACT_DIR", art)
     monkeypatch.setattr(bench, "DP_STEPS", 20)
+    read_ms, plain = [], tier.EmbeddingTierClient.pull_unique
+
+    def timed(self, *args, **kwargs):
+        t0 = time.perf_counter()
+        try:
+            return plain(self, *args, **kwargs)
+        finally:
+            read_ms.append(1e3 * (time.perf_counter() - t0))
+
+    monkeypatch.setattr(tier.EmbeddingTierClient, "pull_unique", timed)
     res = bench.bench_data_plane()
     budget_ms = res["deadline_budget_ms"]
-    # hedging kept reads served and bounded while the control blocked
-    assert res["read_p99_under_partition_ms"] < budget_ms / 2, res
+    # hedging kept reads served and bounded while the control blocked: a
+    # quiet machine's median read is 2-4 ms, so the ceiling is half the
+    # deadline there; six xdist workers on eight cores stretch both
+    ceiling_ms = max(budget_ms / 2, 60 * statistics.median(read_ms))
+    assert res["read_p99_under_partition_ms"] < ceiling_ms, (res, ceiling_ms)
+    assert res["read_p99_under_partition_ms"] < res["control_blocked_p99_ms"], res
     assert res["control_blocked_to_deadline"] is True, res
     assert res["control_blocked_p99_ms"] >= 0.8 * budget_ms
     assert res["hedged_pulls"] >= 1

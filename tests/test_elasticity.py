@@ -12,7 +12,7 @@ from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.master.main import Master
 from elasticdl_tpu.master.process_manager import ProcessManager
 from elasticdl_tpu.client.local import free_port
-from tests.jobs import HERMETIC_ENV, all_logs, run_job
+from tests.jobs import HERMETIC_ENV, all_logs, patient_master, run_job
 
 
 def job_config(tmp_path, **overrides):
@@ -121,7 +121,9 @@ def test_sigterm_preemption_checkpoints_and_resumes(tmp_path):
         checkpoint_dir=str(tmp_path / "ckpt"),
         checkpoint_steps=0,          # only the preemption save writes
     )
-    *_, counts = run_job(cfg, tmp_path, mid_job=kill_after(2, graceful=True))
+    # the watcher sees the exit and relaunches: nothing here is the reaper's
+    *_, counts = run_job(cfg, tmp_path, mid_job=kill_after(2, graceful=True),
+                         master_of=patient_master)
     assert counts["finished_training"] == 12, counts
     assert counts["failed_permanently"] == 0, counts
     log = all_logs(tmp_path)
@@ -145,7 +147,10 @@ def test_worker_exits_when_master_vanishes(tmp_path):
         worker_heartbeat_s=0.3,
         master_unreachable_timeout_s=4.0,
     )
-    master = Master(cfg)
+    # the worker beats every 0.3 s so that it notices a vanished master in
+    # seconds; this master, which only has to hand out a task and vanish,
+    # must not reap it while its first step compiles
+    master = patient_master(cfg)
     master.start()
     worker = Worker(cfg)
     rc = {}

@@ -6,16 +6,9 @@ the CPU: hidden 48, one dense and two sparse layers and the module, 4 heads of
 8 + 8 (values 16), ranks 24 and 16, 16 experts top-3 of which experts 4-7 are
 held, vocabulary 256, 36 tokens, float32.
 
-The comparison is the benchmark's own (`ModelStepCheck` of
-`benchmark/drivers/resident_lm_model.py` over `benchmark/check_lm.py`), so the
-cases at the bottom hold it to its purpose: each departure the cell's check
-must catch on the chip is patched into the program
-(`benchmark/rehearse/departures_glm4_moe_lite.py`) and the comparison must
-FAIL.
+The benchmark's own comparison is in `tests/test_glm4_moe_lite_tight.py`, the
+departures it must catch in `tests/test_glm4_moe_lite_check.py`.
 """
-
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -23,97 +16,42 @@ import numpy as np
 import pytest
 
 from benchmark import check_lm, common
-from elasticdl_tpu.common.config import JobConfig
-from elasticdl_tpu.parallel.mesh import build_mesh
-from elasticdl_tpu.training.model_spec import ModelSpec
-from elasticdl_tpu.training.trainer import Trainer
+from tests import zoo_lm
 from tests.conftest import pallas_calls
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TINY = common.load_json("rehearse", "tiny-lm-model.json")["model_params"]
+TINY = zoo_lm.preset("tiny-lm-model.json")
 LEAVES = ("embed", "final_norm", "head",
           "attn_norm", "q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm", "kv_b", "wo",
           "mlp_norm", "mlp_gate", "mlp_up", "mlp_down",
           "moe_norm", "moe_router", "shared_gate", "shared_up", "shared_down",
           "w_gate", "w_up", "w_down",
           "mtp_hnorm", "mtp_enorm", "mtp_eh_proj", "mtp_final_norm")
-# float32 against float32: the only differences are the order of sums
-TIGHT = {"loss_rel": 1e-5, "loss_main_rel": 1e-5, "loss_mtp_rel": 1e-5,
-         "routing_agreement_min": 1.0,
-         "router_same_input_agreement_min": 1.0, "router_weight_rel_median": 1e-5,
-         "mu_rel_l2": {"default": 1e-4, "experts": 1e-4},
-         "update_rel_l2": {"default": 2e-3, "experts": 2e-3},
-         "bias_entries_off_share": 0.0}
 
 reference = common.load_module("reference", "glm4_moe_lite")
 flops = common.load_module("flops", "glm4_moe_lite")
 driver = common.load_module("drivers", "resident_lm_model")
 departures = common.load_module("rehearse", "departures_glm4_moe_lite")
 
-
-def tiny_params(**more):
-    return {k: str(v) for k, v in {**TINY, **more}.items()}
-
-
-def build_trainer(seed=0, **more):
-    cfg = JobConfig.from_argv([
-        "--model_zoo", os.path.join(ROOT, "model_zoo"),
-        "--model_def", "transformer.glm4_moe_lite.custom_model",
-        "--model_params", common.format_model_params(tiny_params(**more))])
-    spec = ModelSpec.from_config(cfg)
-    return spec, Trainer(spec, build_mesh(devices=jax.devices()[:1]), seed=seed)
-
-
-def batches(steps=2, batch=2, seq=36, seed=1):
-    toks = np.random.default_rng(seed).integers(
-        0, TINY["vocab_size"], (steps, batch, seq + 1)).astype(np.int32)
-    return [{"features": t[:, :-1], "labels": t[:, 1:],
-             "mask": np.ones((batch,), np.float32)} for t in toks]
+lm = zoo_lm.ZooLM(
+    "glm4_moe_lite", tiny=TINY, reference=reference, driver=driver, departures=departures,
+    seq=36, mutable=("router_state",), training=True,
+    # router logits of order one (as at the published width), every norm's
+    # weight away from one, projections large enough that attention's softmax
+    # is far from a running mean, so that positions matter
+    lively=[(("moe_router",), zoo_lm.scaled(8.0)),
+            (tuple(name for name in LEAVES if name.endswith("norm")), zoo_lm.jittered),
+            (("q_a", "q_b", "kv_a", "kv_b", "wo", "mlp_gate", "mlp_up", "mlp_down",
+              "shared_gate", "shared_up", "shared_down", "w_gate", "w_up", "w_down",
+              "mtp_eh_proj"), zoo_lm.scaled(6.0))],
+    # the check's cases run one dense layer, ONE sparse layer and the module:
+    # every mechanism, and three blocks to trace and compile for the preset's four
+    short={"num_hidden_layers": 2})
+# a selection bias that is not zero
+BIAS = jnp.asarray(np.random.default_rng(2).normal(size=(3, 16)) * 0.02, jnp.float32)
 
 
 def zoo():
-    return sys.modules["transformer.glm4_moe_lite"]
-
-
-def lively(state, seed=5):
-    """Parameters as a trained model has them rather than as the seed leaves
-    them: router logits of order one (as at the published width), every
-    norm's weight away from one, projections large enough that attention's
-    softmax is far from a running mean, so that positions matter."""
-    r = np.random.default_rng(seed)
-    p = dict(state.params)
-    p["moe_router"] = p["moe_router"] * 8.0
-    for name in LEAVES:
-        if name.endswith("norm"):
-            p[name] = p[name] * jnp.asarray(r.uniform(0.5, 1.5, p[name].shape), jnp.float32)
-    for name in ("q_a", "q_b", "kv_a", "kv_b", "wo", "mlp_gate", "mlp_up", "mlp_down",
-                 "shared_gate", "shared_up", "shared_down", "w_gate", "w_up", "w_down",
-                 "mtp_eh_proj"):
-        p[name] = p[name] * 6.0
-    return state.replace(params=p)
-
-
-def run_check(departure=None):
-    """The benchmark's check, as `drivers/resident_lm_model.py` drives it,
-    under the reference's `TOLERANCES` and `EXPERT_PAIRS_FLOOR` as the test
-    has set them."""
-    spec, trainer = build_trainer()
-    data = batches()
-
-    def fresh_state():
-        return lively(trainer.init_state(data[0]))
-
-    with departures.applied(departure, zoo()):
-        return driver.program_check(trainer, spec, trainer.mesh, zoo(), reference,
-                                    tiny_params(), data, fresh_state, lambda text: None)
-
-
-def lively_setup(seed=2):
-    spec, trainer = build_trainer()
-    batch = batches(steps=1)[0]
-    state = lively(trainer.init_state(batch))
-    bias = jnp.asarray(np.random.default_rng(seed).normal(size=(3, 16)) * 0.02, jnp.float32)
-    return spec, batch, state.params, bias
+    return lm.zoo
 
 
 def router_state(bias):
@@ -122,35 +60,13 @@ def router_state(bias):
                              "held_row_tiles": zeros}}
 
 
-def program_terms(spec, params, bias, batch):
-    outputs, _ = spec.model.apply(
-        {"params": params, **router_state(bias)},
-        batch["features"], training=True, mutable=["router_state"])
-    return {k: jnp.mean(v) for k, v in spec.loss(batch["labels"], outputs).items()}
-
-
 @pytest.fixture(scope="module")
 def gradients():
     """(program's, reference's) loss terms and gradients of one batch from
     the same lively parameters and a selection bias that is not zero."""
-    spec, batch, params, bias = lively_setup()
-
-    def program_loss(p):
-        terms = program_terms(spec, p, bias, batch)
-        return terms["loss"], terms
-
-    hp = reference.hyper(tiny_params())
-    ref_batch = {"tokens": batch["features"], "labels": batch["labels"],
-                 "mask": batch["mask"]}
-
-    def reference_loss(p):
-        total, terms, _ = reference.loss_terms(p, ref_batch, hp, None, bias)
-        return total, terms
-
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.value_and_grad(program_loss, has_aux=True))(params)
-        want = jax.jit(jax.value_and_grad(reference_loss, has_aux=True))(params)
-    return got, want
+    return lm.gradients(
+        lambda p, batch, hp: reference.loss_terms(p, batch, hp, None, BIAS)[:2],
+        router_state(BIAS))
 
 
 @pytest.mark.parametrize("route", ["fallback", "kernel"])
@@ -164,10 +80,8 @@ def test_keeping_the_flash_residuals_changes_no_value_on_the_cpu(route, monkeypa
     tokens) none of the names occurs and the policy is inert."""
     from elasticdl_tpu.ops import pallas_attention
 
-    spec, trainer = build_trainer()
-    batch = batches(steps=1, seq=64 if route == "kernel" else 36)[0]
-    params = lively(trainer.init_state(batch)).params
-    bias = jnp.asarray(np.random.default_rng(2).normal(size=(3, 16)) * 0.02, jnp.float32)
+    spec, _ = lm.fresh_trainer()
+    batch, params = lm.batches(steps=1, seq=64 if route == "kernel" else 36)[0], lm.params()
     if route == "kernel":
         # the signal without `force_tpu_interpret_mode`, whose callbacks a
         # remat refuses (tests/test_nemotron_h.py::interpret_kernels)
@@ -176,7 +90,7 @@ def test_keeping_the_flash_residuals_changes_no_value_on_the_cpu(route, monkeypa
 
     def value_and_grad():       # a new closure each time: a new trace
         def program_loss(p):
-            terms = program_terms(spec, p, bias, batch)
+            terms = lm.terms(spec, p, batch, router_state(BIAS))
             return terms["loss"], terms
         return jax.value_and_grad(program_loss, has_aux=True)
 
@@ -216,22 +130,6 @@ def test_gradient_leaf_matches_reference(gradients, leaf):
     assert check_lm._rel_l2(np.asarray(got[leaf]), np.asarray(want[leaf])) < 1e-4
 
 
-def test_two_adamw_steps_with_the_bias_update_match_reference(monkeypatch):
-    monkeypatch.setattr(reference, "TOLERANCES", TIGHT)
-    monkeypatch.setattr(reference, "EXPERT_PAIRS_FLOOR", 8)
-    verdict = run_check()
-    assert verdict["ok"], verdict["failures"]
-    figures = verdict["figures"]
-    assert figures["leaves_compared"] == len(LEAVES)
-    assert figures["experts_compared"] == TINY["n_routed_experts"]
-    assert figures["bias_entries_off_share"] == 0.0
-    assert abs(figures["bias_abs_max"] - 2e-3) < 1e-8       # two steps of ±1e-3
-    assert len(figures["router_same_input"]) == 2           # every step, not the first alone
-    # the two terms apart, at both steps
-    assert len(figures["loss_main_program"]) == len(figures["loss_mtp_reference"]) == 2
-    assert figures["loss_main_rel"] < 1e-5 and figures["loss_mtp_rel"] < 1e-5
-
-
 # ------------------------------------------------------------------ #
 # latent attention, by hand
 
@@ -262,7 +160,6 @@ def by_hand_attention(p, x, cfg, rotary_key_columns=None):
 
 
 def one_attention_layer(seed=7):
-    build_trainer()
     m = zoo()
     cfg = m.Config(**TINY)
     r = np.random.default_rng(seed)
@@ -282,7 +179,7 @@ def test_latent_attention_is_plain_attention_over_the_low_rank_factors():
     with jax.default_matmul_precision("highest"):
         np.testing.assert_allclose(m.latent_attention(p, x, cfg),
                                    by_hand_attention(p, x, cfg), rtol=2e-4, atol=2e-5)
-        hp = reference.hyper(tiny_params())
+        hp = reference.hyper(lm.tiny_params())
         np.testing.assert_allclose(reference.attention(p, x, hp),
                                    by_hand_attention(p, x, cfg), rtol=2e-4, atol=2e-5)
 
@@ -302,7 +199,6 @@ def test_the_shared_rotary_key_takes_the_sum_of_the_heads_gradients():
 
 
 def test_queries_and_values_must_share_a_head_size():
-    build_trainer()
     with pytest.raises(ValueError, match="one head size"):
         zoo().Config(qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=128)
     with pytest.raises(ValueError, match="0 or 1"):
@@ -317,11 +213,10 @@ def test_the_module_s_shift_mask_and_shared_head_by_hand():
     """Position i of the module sees the embedding of token i + 1 and
     predicts token i + 2; T − 1 positions have a target; the head and the
     embedding each take both streams' gradients."""
-    spec, batch, params, bias = lively_setup()
+    (spec, _), batch, params = lm.trainer(), lm.batches(steps=1)[0], lm.params()
     m = zoo()
-    variables = {"params": params, **router_state(bias)}
-    apply = lambda p, feats: spec.model.apply(
-        {**variables, "params": p}, feats, training=False)
+    apply = jax.jit(lambda p, feats: spec.model.apply(      # traced once for every use below
+        {"params": p, **router_state(BIAS)}, feats, training=False))
     outputs = apply(params, batch["features"])
     t = batch["features"].shape[1]
     assert outputs["logits"].shape == outputs["mtp_logits"].shape == (2, t, 256)
@@ -345,19 +240,18 @@ def test_the_module_s_shift_mask_and_shared_head_by_hand():
     assert not differs("logits")[:j].any() and differs("logits")[j]
     assert not differs("mtp_logits")[:j - 1].any() and differs("mtp_logits")[j - 1]
     # one head, one embedding: each term alone reaches both
-    for term in ("loss_main", "loss_mtp"):
-        g = jax.grad(lambda p: jnp.mean(
-            spec.loss(batch["labels"], apply(p, batch["features"]))[term]))(params)
+    grads = {term: jax.grad(lambda p: jnp.mean(
+        spec.loss(batch["labels"], apply(p, batch["features"]))[term]))(params)
+        for term in ("loss_main", "loss_mtp")}
+    for g in grads.values():
         assert np.linalg.norm(g["head"]) > 0 and np.linalg.norm(g["embed"]) > 0
-    g_main = jax.grad(lambda p: jnp.mean(
-        spec.loss(batch["labels"], apply(p, batch["features"]))["loss_main"]))(params)
-    assert not np.any(np.asarray(g_main["mtp_eh_proj"]))
+    assert not np.any(np.asarray(grads["loss_main"]["mtp_eh_proj"]))
     assert m.MTP_LOSS_WEIGHT == reference.MTP_LOSS_WEIGHT == 0.3
 
 
 def test_the_step_reports_both_terms_and_evaluation_reads_both_streams():
-    spec, trainer = build_trainer(warmup_steps=1)
-    data = batches(steps=1)[0]
+    spec, trainer = lm.trainer(warmup_steps=1)
+    data = lm.batches(steps=1)[0]
     state = trainer.init_state(data)
     bias = lambda s: np.asarray(s.extra_vars["router_state"]["e_score_correction_bias"])
     assert bias(state).shape == (3, 16) and not bias(state).any()
@@ -376,10 +270,10 @@ def test_the_step_reports_both_terms_and_evaluation_reads_both_streams():
 
 
 def test_custom_model_ignores_the_harness_keys_and_trains():
-    spec, trainer = build_trainer(warmup_steps=1)
-    model = zoo().custom_model(field_vocab="512", **tiny_params())
+    spec, trainer = lm.trainer(warmup_steps=1)
+    model = zoo().custom_model(field_vocab="512", **lm.tiny_params())
     assert model.cfg == spec.model.cfg
-    data = batches(steps=1)[0]
+    data = lm.batches(steps=1)[0]
     state = trainer.init_state(data)
     losses = []
     for _ in range(8):
@@ -390,7 +284,6 @@ def test_custom_model_ignores_the_harness_keys_and_trains():
 
 @pytest.mark.parametrize("modules,card", [(0, 29_943_390_976), (1, 30_587_097_088)])
 def test_published_defaults_count_the_card_s_parameters(modules, card):
-    build_trainer()
     model = zoo().custom_model(num_nextn_predict_layers=modules)
     assert model.cfg.held == (0, 64) and model.cfg.sparse_layers == 46
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
@@ -414,7 +307,6 @@ def test_four_shares_and_the_shared_expert_once_make_the_uncut_layer():
     expert taken away) plus the shared expert ONCE equal what the reference
     gives for the layer with every expert held — as the cell's eight shares
     of 8 make its 64."""
-    build_trainer()
     m = zoo()
     r = np.random.default_rng(3)
     c, f, e = 48, 24, 16
@@ -426,9 +318,9 @@ def test_four_shares_and_the_shared_expert_once_make_the_uncut_layer():
     whole = {k: jnp.asarray(v, jnp.float32) for k, v in whole.items()}
     x = jnp.asarray(r.normal(size=(2, 9, c)), jnp.float32)
     bias = jnp.asarray(r.normal(size=(e,)) * 0.05, jnp.float32)
-    hp_whole = reference.hyper(tiny_params(n_routed_experts=16, first_expert=0))
+    hp_whole = reference.hyper(lm.tiny_params(n_routed_experts=16, first_expert=0))
     with jax.default_matmul_precision("highest"):
-        want, _, _ = reference.moe(whole, x, bias, None, hp_whole)
+        want, _, _ = jax.jit(lambda p, x: reference.moe(p, x, bias, None, hp_whole))(whole, x)
         shared = m.gated_mlp(
             m.rmsnorm(x, whole["moe_norm"], 1e-5).reshape(-1, c), whole["shared_gate"],
             whole["shared_up"], whole["shared_down"], jnp.float32).reshape(x.shape)
@@ -438,62 +330,10 @@ def test_four_shares_and_the_shared_expert_once_make_the_uncut_layer():
             held = slice(4 * share, 4 * share + 4)
             part = {**whole, "w_gate": whole["w_gate"][held], "w_up": whole["w_up"][held],
                     "w_down": whole["w_down"][held]}
-            y, _ = m.moe(part, x, bias, cfg)
+            y, _ = jax.jit(lambda p, x: m.moe(p, x, bias, cfg))(part, x)
             total = total + (y - shared)
             # and the reference, given the same share, gives the same part
-            hp = reference.hyper(tiny_params(first_expert=4 * share))
-            ref_part, _, _ = reference.moe(part, x, bias, None, hp)
+            hp = reference.hyper(lm.tiny_params(first_expert=4 * share))
+            ref_part, _, _ = jax.jit(lambda p, x: reference.moe(p, x, bias, None, hp))(part, x)
             np.testing.assert_allclose(y, ref_part, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
-
-
-# ------------------------------------------------------------------ #
-# what the cell's check must catch, under the chip's own tolerances
-
-
-@pytest.mark.parametrize("departure", [None] + sorted(departures.DEPARTURES))
-def test_the_check_fails_on(departure, monkeypatch):
-    monkeypatch.setattr(reference, "EXPERT_PAIRS_FLOOR", 8)
-    verdict = run_check(departure)
-    assert verdict["ok"] == (departure is None), (verdict["failures"], verdict["figures"])
-
-
-@pytest.mark.parametrize("control", sorted(departures.CONTROLS)
-                         + sorted(departures.BELOW_THE_NOISE))
-def test_a_precision_control_shows_in_the_figures(control, monkeypatch):
-    """A part stated float32 kept in bfloat16 (the router's scores; what a
-    sub-block adds to the residual stream; the latents before their norms):
-    here every matmul is float32, so the control alone makes the noise, and
-    the float32-against-float32 limits must catch it (on the chip it is read
-    against the bfloat16 matmuls' own noise, and the last of the three drowns
-    in it: PERF.md §6)."""
-    monkeypatch.setattr(reference, "TOLERANCES", TIGHT)
-    monkeypatch.setattr(reference, "EXPERT_PAIRS_FLOOR", 8)
-    verdict = run_check(control)
-    assert not verdict["ok"]
-    assert any(f.startswith(("mu_rel_l2.", "router_")) for f in verdict["failures"]), \
-        verdict["failures"]
-
-
-def test_a_departure_s_trainer_does_not_get_another_s_compiled_step():
-    """`drivers/resident.py::build_trainer` gives its trainers the job's
-    program token, under which a second trainer of the same configuration is
-    handed the first one's compiled step: a patched program would run
-    unpatched. The departures' trainers take a token of their own."""
-    from elasticdl_tpu.parallel.mesh import shard_batch_stack
-
-    config = {"model_def": "transformer.glm4_moe_lite.custom_model",
-              "model_params": common.format_model_params(tiny_params())}
-    data = batches(steps=1)[0]
-    losses = {}
-    for name in (None, "mtp_weight_zero"):
-        spec, mesh, trainer, module = departures.fresh_trainer(driver, config, 3)
-        with departures.applied(name, module):
-            state = trainer.init_state(data)
-            _, m = trainer.train_many(state, shard_batch_stack(
-                mesh, [data], spec.batch_partition))
-        losses[name] = {k: float(v[0]) for k, v in m.items()}
-    assert abs(losses[None]["loss"] - losses[None]["loss_main"]
-               - 0.3 * losses[None]["loss_mtp"]) < 1e-5
-    assert abs(losses["mtp_weight_zero"]["loss"]
-               - losses["mtp_weight_zero"]["loss_main"]) < 1e-6

@@ -6,10 +6,9 @@ backward kernel with a key-value head's keys resident in VMEM, and at a head
 that does not fit, the two that stream it (`ops/pallas_attention.py`: the
 block plan follows the head size, `bwd_route` the head's bytes), and the
 state-space scan's three kernels at the Nemotron cell's shapes
-(`ops/pallas_ssd.py`), alone and inside a checkpointed Mamba mixer, where
-every one of them has to carry the scope the benchmark reads it by — as the
-flash kernels have to inside the checkpointed layers of GLM and Nemotron,
-which keep the forward kernel's results and so hold ONE forward call a block; and
+(`ops/pallas_ssd.py`) — the same kernels inside the zoo's checkpointed layers,
+under the scopes the benchmark reads them by, are
+`tests/test_kernels_aot_layers.py`'s; and
 the pull-back of the Keye cell's index scores (`ops/sparse_attention.py::
 index_score_bwd`) at that cell's shape; and xDeepFM's CIN kernels
 (`ops/pallas_cin.py`) at the Criteo cell's. What
@@ -17,7 +16,8 @@ interpret mode cannot see — a block Mosaic refuses, more VMEM than a kernel
 may use — fails here and costs no chip time. Nothing runs: no time, no result.
 
 The topology is described inside a fixture, never at import: only the xdist
-worker that is given this file loads libtpu."""
+workers that are given this file and `tests/test_kernels_aot_layers.py` load
+libtpu (the driver's command sets `ALLOW_MULTIPLE_LIBTPU_LOAD=1`)."""
 
 import re
 
@@ -40,6 +40,11 @@ SHAPES = {
     "glm_up": (8192, 2048, 1536, 8),
     "glm_down": (8192, 1536, 2048, 8),
 }
+
+
+# what is read here is what XLA:TPU and Mosaic emit for the chip: these compiles
+# keep the optimisations `tests/conftest.py` turns off for the CPU's programs
+pytestmark = pytest.mark.usefixtures("xla_optimises")
 
 
 @pytest.fixture(scope="module")
@@ -232,101 +237,3 @@ def test_scan_kernels_compile_for_a_v5e(one_chip, no_compile_cache):
     for kernel in ("ssd_chunk_fwd", "ssd_chunk_starts", "ssd_chunk_bwd"):
         assert text.count("%" + kernel) >= 1, kernel
     assert text.count('custom_call_target="tpu_custom_call"') == 3
-
-
-def test_every_scan_kernel_of_a_checkpointed_mamba_mixer_carries_its_scope(
-        one_chip, no_compile_cache, monkeypatch):
-    """`nemotron_h.forward` checkpoints the mixer: the gradient program runs
-    the forward kernel twice (forward, the block's recomputation), the sweep
-    and the backward kernel once — and `benchmark/drivers/resident_lm_share.py::scope_map` finds their time
-    by `mamba/ssd` in each one's `op_name`, or `ssm_scan_roofline` divides a
-    fixed floor by a scope that lost its kernels."""
-    from model_zoo.transformer import nemotron_h
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the route asks
-    cfg = nemotron_h.Config(num_hidden_layers=1, hybrid_override_pattern="M")
-    assert (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.ssm_state_size,
-            cfg.chunk_size) == tuple(SCAN.values())[1:]
-    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
-    c, h = cfg.hidden_size, cfg.mamba_num_heads
-    p = {"mamba_norm": shape(c), "mamba_in_proj": shape(c, cfg.d_inner + cfg.conv_dim + h),
-         "mamba_conv_w": shape(cfg.conv_kernel, cfg.conv_dim), "mamba_conv_b": shape(cfg.conv_dim),
-         "mamba_dt_bias": shape(h), "mamba_A_log": shape(h), "mamba_D": shape(h),
-         "mamba_gate_norm": shape(cfg.d_inner), "mamba_out_proj": shape(cfg.d_inner, c)}
-
-    def loss(p, x):
-        with jax.named_scope("nemotron_h"), jax.named_scope("mamba"):
-            y = jax.checkpoint(lambda p, x: nemotron_h.mamba(p, x, cfg))(p, x)
-        return jnp.sum(jnp.square(x + y))
-
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        p, shape(1, SCAN["tokens"], c)).compile().as_text()
-    calls = re.findall(r"^\s*%?(ssd_[\w.]+) = ", text, re.M)
-    assert sorted(re.sub(r"\.\d+$", "", name) for name in calls) == [
-        "ssd_chunk_bwd", "ssd_chunk_fwd", "ssd_chunk_fwd", "ssd_chunk_starts"]
-    from benchmark import common
-    scope_map = common.load_module("drivers", "resident_lm_share").scope_map
-    scopes = scope_map(text, common.load_module("flops", "nemotron_h").SCOPES)
-    assert [scopes.get(name) for name in calls] == ["nemotron_h/mamba/ssd"] * 4
-
-
-# (the zoo's module, a configuration of few layers at the cell's attention
-# shapes, tokens, (the scope of an attention block's kernels, their names'
-# prefix) in program order)
-_CAUSAL, _BANDED = "flash_attention_", "flash_attention_swa_"
-RECOMPUTED_ATTENTION = {
-    # glm-4.7-flash.resident-8k: the dense layer and the module's own sparse
-    # layer, 20 heads of 192 + 64 / 256
-    "glm": ("glm4_moe_lite", dict(
-        num_hidden_layers=1, first_k_dense_replace=1, num_nextn_predict_layers=1,
-        n_routed_experts=8, router_experts=64, vocab_size=512), 8192,
-        [("glm4_moe_lite/mla/attn", _CAUSAL), ("glm4_moe_lite/mtp/mla/attn", _CAUSAL)]),
-    # nemotron-3-nano-30b-a3b.resident-8k: 32 query heads on 2 key-value heads
-    # of 128 (a sparse-expert layer after it: `forward` stacks their statistics)
-    "nemotron": ("nemotron_h", dict(
-        num_hidden_layers=2, hybrid_override_pattern="*E", n_routed_experts=8,
-        router_experts=128, vocab_size=512), 8192, [("nemotron_h/attn", _CAUSAL)]),
-    # mellum2-12b-a2.5b.resident-16k: three sliding-window layers of 1024 keys
-    # and one full layer, 32 query heads on 4 key-value heads of 128
-    "mellum": ("mellum", dict(
-        num_hidden_layers=4, num_experts=8, router_experts=64, vocab_size=512), 16384,
-        [("mellum/sliding/attn", _BANDED)] * 3 + [("mellum/full/attn", _CAUSAL)]),
-}
-
-
-@pytest.mark.parametrize("model", sorted(RECOMPUTED_ATTENTION))
-def test_a_recomputed_layer_runs_the_flash_forward_once_under_its_scope(
-        model, one_chip, no_compile_cache, monkeypatch):
-    """`forward` checkpoints its layers with `pallas_attention.
-    KEEP_RESIDUALS`: the gradient program at the cell's tokens compiles with
-    one `flash_attention_fwd` call a block (two under the plain checkpoint)
-    and ONE `flash_attention_bwd` — no `bwd_dq`, no `bwd_dkv`: a head's keys
-    fit VMEM — `_swa_fwd` and `_swa_bwd` in a windowed block, and `scope_map`
-    finds each under the block's own `attn` scope: the benchmark's `mla_ms`,
-    `mtp_ms`, `swa_ms` and the name-prefix readers (`mla_attn_ms`,
-    `gqa_attn_ms`, `swa_attn_ms`, `global_attn_ms`) read them there."""
-    import importlib
-
-    from benchmark import common
-
-    module, config, length, blocks = RECOMPUTED_ATTENTION[model]
-    zoo = importlib.import_module(f"model_zoo.transformer.{module}")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
-    net = zoo.custom_model(**config)
-    tokens = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
-    variables = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
-
-    def loss(params, state, tokens):
-        outputs = net.apply({"params": params, **state}, tokens)
-        return sum(jnp.sum(jnp.square(x)) for x in jax.tree_util.tree_leaves(outputs))
-
-    params = variables.pop("params")
-    text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
-    calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
-    kinds = [re.sub(r"\.\d+$", "", name) for name in calls]
-    scope_map = common.load_module("drivers", "resident_lm_share").scope_map
-    found = scope_map(text, common.load_module("flops", module).SCOPES)
-    assert sorted((kind, found.get(name)) for kind, name in zip(kinds, calls)) == sorted(
-        (prefix + part, scope) for scope, prefix in blocks for part in ("fwd", "bwd"))

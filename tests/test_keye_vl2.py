@@ -10,91 +10,52 @@ The benchmark's own comparison, and the departures it must catch, are in
 `tests/test_keye_vl2_check.py`.
 """
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmark import common
-from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.ops import pallas_attention, sparse_attention
-from elasticdl_tpu.parallel.mesh import build_mesh
-from elasticdl_tpu.training.model_spec import ModelSpec
-from elasticdl_tpu.training.trainer import Trainer
+from tests import zoo_lm
 from tests.conftest import equations, pallas_calls
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TINY = common.load_json("rehearse", "tiny-lm-keye.json")["model_params"]
+TINY = zoo_lm.preset("tiny-lm-keye.json")
 INDEX = ("index_wq", "index_wk", "index_k_scale", "index_k_bias", "index_w")
 REST = ("embed", "final_norm", "head", "attn_norm", "wq", "wk", "wv", "wo", "q_norm",
         "k_norm", "moe_norm", "moe_router", "w_gate", "w_up", "w_down")
 reference = common.load_module("reference", "keye_vl2")
 flops = common.load_module("flops", "keye_vl2")
 
-
-def tiny_params(**more):
-    return {k: str(v) for k, v in {**TINY, **more}.items()}
-
-
-def build_trainer(seed=0, **more):
-    cfg = JobConfig.from_argv([
-        "--model_zoo", os.path.join(ROOT, "model_zoo"),
-        "--model_def", "transformer.keye_vl2.custom_model",
-        "--model_params", common.format_model_params(tiny_params(**more))])
-    spec = ModelSpec.from_config(cfg)
-    return spec, Trainer(spec, build_mesh(devices=jax.devices()[:1]), seed=seed)
-
-
-def batches(steps=2, batch=2, seq=40, seed=1):
-    toks = np.random.default_rng(seed).integers(
-        0, TINY["vocab_size"], (steps, batch, seq + 1)).astype(np.int32)
-    return [{"features": t[:, :-1], "labels": t[:, 1:],
-             "mask": np.ones((batch,), np.float32)} for t in toks]
+lm = zoo_lm.ZooLM(
+    "keye_vl2", tiny=TINY, reference=reference, seq=40,
+    driver=common.load_module("drivers", "resident_lm_dsa"),
+    departures=common.load_module("rehearse", "departures_keye_vl2"),
+    mutable=("losses", "router_state", "dsa"),
+    sown={"loss_balance": "load_balance", "loss_index": "index_kl"},
+    # router logits and index scores of order one, every norm's weight away
+    # from one (the index keys' bias away from zero), projections large enough
+    # that attention's softmax is far from a running mean
+    lively=[(("moe_router",), zoo_lm.scaled(8.0)),
+            (("attn_norm", "final_norm", "index_k_scale", "k_norm", "moe_norm", "q_norm"),
+             zoo_lm.jittered),
+            (("index_k_bias",), zoo_lm.drawn(0.3)),
+            (("wq", "wk", "wv", "w_gate", "w_up", "index_wq", "index_wk"), zoo_lm.scaled(6.0)),
+            (("index_w",), zoo_lm.scaled(30.0)),
+            (("wo", "w_down"), zoo_lm.scaled(45.0))],
+    # the check's cases run ONE layer: every mechanism, and half the compile
+    # time of the preset's two
+    short={"num_hidden_layers": 1})
 
 
 def zoo():
-    return sys.modules["transformer.keye_vl2"]
-
-
-def lively(state, seed=5):
-    """Parameters as a trained model has them rather than as the seed leaves
-    them: router logits and index scores of order one, every norm's weight
-    away from one (the index keys' bias away from zero), projections large
-    enough that attention's softmax is far from a running mean."""
-    r = np.random.default_rng(seed)
-    p = dict(state.params)
-    p["moe_router"] = p["moe_router"] * 8.0
-    for name in p:
-        if name.endswith(("norm", "k_scale")):
-            p[name] = p[name] * jnp.asarray(r.uniform(0.5, 1.5, p[name].shape), jnp.float32)
-    p["index_k_bias"] = jnp.asarray(r.normal(size=p["index_k_bias"].shape) * 0.3, jnp.float32)
-    for name in ("wq", "wk", "wv", "w_gate", "w_up", "index_wq", "index_wk"):
-        p[name] = p[name] * 6.0
-    p["index_w"] = p["index_w"] * 30.0
-    for name in ("wo", "w_down"):
-        p[name] = p[name] * 45.0
-    return state.replace(params=p)
-
-
-def program_terms(spec, params, batch):
-    outputs, sown = spec.model.apply(
-        {"params": params}, batch["features"], training=False,
-        mutable=["losses", "router_state", "dsa"])
-    terms = {k: jnp.mean(v) for k, v in spec.loss(batch["labels"], outputs).items()}
-    terms["loss_balance"] = sown["losses"]["load_balance"]
-    terms["loss_index"] = sown["losses"]["index_kl"]
-    terms["loss"] = terms["loss_ce"] + terms["loss_balance"] + terms["loss_index"]
-    return terms
+    return lm.zoo
 
 
 @pytest.fixture(scope="module")
 def case():
-    spec, trainer = build_trainer()
-    batch = batches(steps=1)[0]
-    return spec, trainer, batch, lively(trainer.init_state(batch)).params
+    spec, trainer = lm.trainer()
+    return spec, trainer, lm.batches(steps=1)[0], lm.params()
 
 
 @pytest.fixture(scope="module")
@@ -103,24 +64,12 @@ def gradients(case):
     the same lively parameters, and the program's gradients of the index loss
     alone and of the rest alone."""
     spec, _, batch, params = case
-    hp = reference.hyper(tiny_params())
-    ref_batch = {"tokens": batch["features"], "labels": batch["labels"],
-                 "mask": batch["mask"]}
-
-    def program_loss(p, pick=lambda t: t["loss"]):
-        terms = program_terms(spec, p, batch)
-        return pick(terms), terms
-
-    def reference_loss(p):
-        total, terms, _ = reference.loss_terms(p, ref_batch, hp)
-        return total, terms
-
+    got, want = lm.gradients(lambda p, batch, hp: reference.loss_terms(p, batch, hp)[:2])
     with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.value_and_grad(program_loss, has_aux=True))(params)
-        want = jax.jit(jax.value_and_grad(reference_loss, has_aux=True))(params)
-        index_alone = jax.jit(jax.grad(lambda p: program_loss(p, lambda t: t["loss_index"])[0]))(params)
-        rest_alone = jax.jit(jax.grad(lambda p: program_loss(
-            p, lambda t: t["loss_ce"] + t["loss_balance"])[0]))(params)
+        index_alone = jax.jit(jax.grad(
+            lambda p: lm.terms(spec, p, batch)["loss_index"]))(params)
+        rest_alone = jax.jit(jax.grad(lambda p: (lambda t: t["loss_ce"] + t["loss_balance"])(
+            lm.terms(spec, p, batch))))(params)
     return got, want, index_alone, rest_alone
 
 
@@ -178,12 +127,12 @@ def test_thresholds_and_keep_are_kept_across_the_recomputation(case, monkeypatch
 
     def loss(policy):
         monkeypatch.setattr(sparse_attention, "KEEP_SELECTION", policy)
-        return lambda p: program_terms(spec, p, batch)["loss"]     # a new closure each time
+        return lambda p: lm.terms(spec, p, batch)["loss"]     # a new closure each time
 
     kept, flash_only = sparse_attention.KEEP_SELECTION, pallas_attention.KEEP_RESIDUALS
     count = lambda policy: _selections_in(jax.make_jaxpr(jax.grad(loss(policy)))(params).jaxpr)
     assert (count(kept), count(flash_only)) == (2, 4)
-    a, b = jax.grad(loss(kept))(params), jax.grad(loss(flash_only))(params)
+    a, b = jax.jit(jax.grad(loss(kept)))(params), jax.jit(jax.grad(loss(flash_only)))(params)
     for leaf in INDEX + REST:
         np.testing.assert_array_equal(np.asarray(a[leaf]), np.asarray(b[leaf]))
 
@@ -196,11 +145,11 @@ def pull_back_routes(case):
     (the interpret signal, which also sends the held experts through their
     grouped-matmul kernel)."""
     spec, _, _, params = case
-    batch = batches(steps=1, seq=128)[0]
+    batch = lm.batches(steps=1, seq=128)[0]
 
     def traced_and_run():
-        loss = lambda p: program_terms(spec, p, batch)["loss"]      # a new closure each time
-        return jax.make_jaxpr(jax.grad(loss))(params).jaxpr, jax.grad(loss)(params)
+        loss = lambda p: lm.terms(spec, p, batch)["loss"]      # a new closure each time
+        return jax.make_jaxpr(jax.grad(loss))(params).jaxpr, jax.jit(jax.grad(loss))(params)
 
     plain = traced_and_run()
     with pytest.MonkeyPatch.context() as patch:
@@ -229,8 +178,7 @@ def test_the_table_built_by_mrope_sections_is_the_plain_one():
     """A text sequence's three position components are equal, so the table
     the reference builds BY `mrope_section` from a (3, T) position array is
     the program's plain table — and is not, as soon as a component differs."""
-    build_trainer()
-    hp = reference.hyper(tiny_params(head_dim=128, rope_theta=10000000))
+    hp = reference.hyper(lm.tiny_params(head_dim=128, rope_theta=10000000))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(1, 24, 2, 128)), jnp.float32)
     positions = reference.text_positions(24)
     assert positions.shape == (3, 24)
@@ -264,7 +212,8 @@ def test_the_program_counts_its_selection(case):
 def test_selections_are_what_the_forward_pass_selected(case):
     spec, _, batch, params = case
     cfg = spec.model.cfg
-    layer_input, threshold, keep = zoo().selections(params, batch["features"], cfg)
+    layer_input, threshold, keep = jax.jit(
+        lambda p, toks: zoo().selections(p, toks, cfg))(params, batch["features"])
     assert layer_input.shape == (2, 2, 40, 48) and threshold.shape == (2, 2, 40)
     assert keep.shape == (2, 2, 40, 40) and keep.dtype == jnp.int8
     for layer in range(2):
@@ -275,8 +224,8 @@ def test_selections_are_what_the_forward_pass_selected(case):
 
 
 def test_custom_model_ignores_the_harness_keys():
-    spec, _ = build_trainer()
-    assert zoo().custom_model(field_vocab="512", **tiny_params()).cfg == spec.model.cfg
+    spec, _ = lm.trainer()
+    assert zoo().custom_model(field_vocab="512", **lm.tiny_params()).cfg == spec.model.cfg
 
 
 @pytest.mark.parametrize("more,count", [
@@ -291,7 +240,7 @@ def test_parameter_count_at_the_cut_and_uncut(more, count):
 def test_the_program_holds_the_parameters_the_shape_functions_count(case):
     params = case[3]
     assert sum(int(np.prod(v.shape)) for v in params.values()) \
-        == flops.parameter_count(tiny_params())
+        == flops.parameter_count(lm.tiny_params())
 
 
 # the share of a deployment, tied to the whole (model-configs guide §4)
@@ -303,20 +252,20 @@ def test_eight_held_shares_make_the_uncut_layer():
     for the layer with every expert held; the attention sub-block and the
     index loss, which every chip computes alike, are counted once."""
     r = np.random.default_rng(3)
-    _, trainer = build_trainer(num_hidden_layers=1, num_experts=16, first_expert=0,
-                               router_experts=16)
-    m, data = zoo(), batches(steps=1)[0]
-    whole = {k: v[0] for k, v in lively(trainer.init_state(data)).params.items()
-             if k in m.LAYER_KEYS}
+    m = zoo()
+    whole = {k: v[0] for k, v in lm.params(
+        num_hidden_layers=1, num_experts=16, first_expert=0, router_experts=16).items()
+        if k in m.LAYER_KEYS}
     x = jnp.asarray(r.normal(size=(2, 40, 48)), jnp.float32)
-    hp_whole = reference.hyper(tiny_params(num_experts=16, first_expert=0))
+    hp_whole = reference.hyper(lm.tiny_params(num_experts=16, first_expert=0))
     cfg_whole = m.Config(**{**TINY, "num_experts": 16, "first_expert": 0})
     tables = m.rotary_tables(cfg_whole, 40)
     with jax.default_matmul_precision("highest"):
-        update, index_kl, _, _ = reference.attention(whole, x, None, hp_whole)
+        update, index_kl, _, _ = jax.jit(
+            lambda p, x: reference.attention(p, x, None, hp_whole))(whole, x)
         mid = x + update
-        want = mid + reference.moe(whole, mid, None, hp_whole)[0]
-        ours, stats = m.attention(whole, x, tables, cfg_whole)
+        want = mid + jax.jit(lambda p, x: reference.moe(p, x, None, hp_whole)[0])(whole, mid)
+        ours, stats = jax.jit(lambda p, x: m.attention(p, x, tables, cfg_whole))(whole, x)
         np.testing.assert_allclose(ours, update, rtol=1e-4, atol=1e-5)
         np.testing.assert_allclose(stats["index_kl"], index_kl, rtol=1e-5)
         total = mid                                 # what every chip computes alike, once
@@ -325,6 +274,6 @@ def test_eight_held_shares_make_the_uncut_layer():
             held = slice(2 * share, 2 * share + 2)
             part = {**whole, "w_gate": whole["w_gate"][held], "w_up": whole["w_up"][held],
                     "w_down": whole["w_down"][held]}
-            total = total + m.moe(part, mid, cfg)[0]
+            total = total + jax.jit(lambda p, x: m.moe(p, x, cfg)[0])(part, mid)
     assert float(jnp.abs(want - mid).max()) > 0.1
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)

@@ -8,10 +8,9 @@ comparison must FAIL; the program as it is must pass.
 
 import pytest
 
-from benchmark import common
-from tests.test_keye_vl2 import (
-    batches, build_trainer, lively, reference, tiny_params, zoo)
+from tests.test_keye_vl2 import lm, reference
 
+departures = lm.departures
 # float32 against float32: the only differences are the order of sums
 TIGHT = {"loss_rel": 1e-5, "loss_ce_rel": 1e-5, "loss_balance_rel": 2e-4,
          "loss_index_rel": 2e-4, "routing_agreement_min": 1.0,
@@ -21,28 +20,6 @@ TIGHT = {"loss_rel": 1e-5, "loss_ce_rel": 1e-5, "loss_balance_rel": 2e-4,
          "selection_outside_error_max": 0,
          "mu_rel_l2": {"default": 1e-4, "experts": 1e-4},
          "update_rel_l2": {"default": 2e-3, "experts": 2e-3}}
-# ONE layer: every mechanism, and half the compile time of the preset's two
-SHORT = {"num_hidden_layers": 1}
-
-driver = common.load_module("drivers", "resident_lm_dsa")
-departures = common.load_module("rehearse", "departures_keye_vl2")
-
-
-def run_check(departure=None):
-    """The benchmark's check, as `drivers/resident_lm_dsa.py` drives it, under
-    the reference's `TOLERANCES` and `EXPERT_PAIRS_FLOOR` as the test has set
-    them."""
-    spec, trainer = build_trainer(**SHORT)
-    data = batches()
-
-    def fresh_state():
-        return lively(trainer.init_state(data[0]))
-
-    with departures.applied(departure, zoo()):
-        return driver.program_check(trainer, spec, trainer.mesh, zoo(), reference,
-                                    tiny_params(**SHORT), data, fresh_state, lambda text: None)
-
-
 
 # bit for bit the same on the CPU, where a recomputed score is the forward's:
 # its guard here is `test_thresholds_and_keep_are_kept_across_the_recomputation`,
@@ -57,7 +34,7 @@ def test_the_check_fails_on(departure, monkeypatch):
     each part stated float32 kept in bfloat16, must fail one of them."""
     monkeypatch.setattr(reference, "TOLERANCES", TIGHT)
     monkeypatch.setattr(reference, "EXPERT_PAIRS_FLOOR", 8)
-    verdict = run_check(departure)
+    verdict = lm.run_check(departure)
     assert verdict["ok"] == (departure is None), (verdict["failures"], verdict["figures"])
 
 

@@ -10,22 +10,16 @@ top-(k-1), a capacity bound — is patched into the program here and the
 comparison must FAIL, under the chip's own tolerances.
 """
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmark import check_lm, common
-from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.ops import moe as moe_ops
-from elasticdl_tpu.parallel.mesh import build_mesh, shard_batch_stack
-from elasticdl_tpu.training.model_spec import ModelSpec
-from elasticdl_tpu.training.trainer import Trainer
+from elasticdl_tpu.parallel.mesh import shard_batch_stack
+from tests import zoo_lm
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = {"vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 2,
         "num_attention_heads": 4, "intermediate_size": 32, "num_experts": 8,
         "num_experts_per_tok": 2, "compute_dtype": "float32"}
@@ -38,38 +32,29 @@ TIGHT = {"loss_rel": 1e-5, "routing_agreement_min": 1.0,
 
 reference = common.load_module("reference", "olmoe")
 
-
-def build_trainer(seed=0, **more_params):
-    cfg = JobConfig.from_argv([
-        "--model_zoo", os.path.join(ROOT, "model_zoo"),
-        "--model_def", "transformer.olmoe.custom_model",
-        "--model_params", common.format_model_params({**TINY, **more_params})])
-    spec = ModelSpec.from_config(cfg)
-    return spec, Trainer(spec, build_mesh(devices=jax.devices()[:1]), seed=seed)
-
-
-def batches(steps=2, batch=2, seq=32, seed=1):
-    toks = np.random.default_rng(seed).integers(
-        0, TINY["vocab_size"], (steps, batch, seq + 1)).astype(np.int32)
-    return [{"features": t[:, :-1], "labels": t[:, 1:],
-             "mask": np.ones((batch,), np.float32)} for t in toks]
+# the seed's own parameters (no rule of `lively`), and no driver: this cell's
+# check is older than `program_check` and `departures_*.py`, so the file keeps
+# its own `two_steps_compared` and its own patches
+lm = zoo_lm.ZooLM(
+    "olmoe", tiny=TINY, reference=reference, seq=32, mutable=("losses",), training=True,
+    sown={"loss_balance": "load_balance", "loss_z": "router_z"})
 
 
 def zoo():
-    return sys.modules["transformer.olmoe"]
+    return lm.zoo
 
 
-def run_check(tolerances, router_scale=1.0):
-    """The benchmark's check, as `drivers/resident_lm.py` drives it."""
-    spec, trainer = build_trainer()
-    data = batches()
-    state = trainer.init_state(data[0])
-    if router_scale != 1.0:
-        # router logits of order one, as at the published width (2048-wide
-        # tokens against normal(0.02) weights), where rounding them matters
-        state = state.replace(params={
-            **state.params, "router": state.params["router"] * router_scale})
-    checker = check_lm.LMStepCheck(reference, {k: str(v) for k, v in TINY.items()}, data)
+def two_steps_compared(tolerances, router_scale=1.0, trainer_of=lm.trainer):
+    """The benchmark's check, as `drivers/resident_lm.py` drives it; a case
+    that has patched the program hands in `lm.fresh_trainer`."""
+    spec, trainer = trainer_of()
+    data = lm.batches()
+    state = lm.state()
+    # router logits of order one, as at the published width (2048-wide tokens
+    # against normal(0.02) weights), where rounding them matters
+    state = state.replace(params={
+        **state.params, "router": state.params["router"] * router_scale})
+    checker = check_lm.LMStepCheck(reference, lm.tiny_params(), data)
     checker.before(state)
     assignments = jax.jit(lambda p, t: zoo().expert_assignments(p, t, spec.model.cfg))
     losses, routings = [], []
@@ -87,25 +72,9 @@ def run_check(tolerances, router_scale=1.0):
 def gradients():
     """(program's, reference's) loss and gradients of one batch from the
     same seeded parameters."""
-    spec, trainer = build_trainer()
-    batch = batches(steps=1)[0]
-    state = trainer.init_state(batch)
-    params = state.params
-
-    def program_loss(p):
-        logits, new_vars = spec.model.apply(
-            {"params": p, **state.extra_vars}, batch["features"], training=True,
-            mutable=["losses"])
-        aux = sum(jax.tree_util.tree_leaves(new_vars["losses"]))
-        return jnp.mean(spec.loss(batch["labels"], logits)) + spec.aux_loss_weight * aux
-
-    hp = reference.hyper({k: str(v) for k, v in TINY.items()})
-    ref_batch = {"tokens": batch["features"], "labels": batch["labels"],
-                 "mask": batch["mask"]}
-    got = jax.jit(jax.value_and_grad(program_loss))(params)
-    want = jax.jit(jax.value_and_grad(
-        lambda p: reference.loss(p, ref_batch, hp)[0]))(params)
-    return got, want
+    ((got, _), got_grads), ((want, _), want_grads) = lm.gradients(
+        lambda p, batch, hp: (reference.loss(p, batch, hp)[0], {}))
+    return (got, got_grads), (want, want_grads)
 
 
 def test_loss_matches_reference(gradients):
@@ -123,7 +92,7 @@ def test_gradient_leaf_matches_reference(gradients, leaf):
 
 
 def test_two_adamw_steps_match_reference():
-    verdict = run_check(TIGHT)
+    verdict = two_steps_compared(TIGHT)
     assert verdict["ok"], verdict["failures"]
     assert verdict["figures"]["experts_compared"] == TINY["num_experts"]
     assert verdict["figures"]["leaves_compared"] == len(LEAVES)
@@ -218,7 +187,6 @@ def test_qk_norm_is_over_the_whole_width(norm_over):
     """`attention` against a numpy twin whose q and k are normalised over all
     C = 64 columns before the split into heads; the per-head twin must NOT
     agree."""
-    build_trainer()                                     # imports the zoo module
     m = zoo()
     cfg = m.Config(**TINY)
     r = np.random.default_rng(4)
@@ -251,10 +219,10 @@ def test_qk_norm_is_over_the_whole_width(norm_over):
 
 
 def test_custom_model_ignores_the_harness_keys_and_trains():
-    spec, trainer = build_trainer(warmup_steps=1)     # the full step size at once
-    model = zoo().custom_model(field_vocab="512", **{k: str(v) for k, v in TINY.items()})
+    spec, trainer = lm.trainer(warmup_steps=1)        # the full step size at once
+    model = zoo().custom_model(field_vocab="512", **lm.tiny_params())
     assert model.cfg == spec.model.cfg
-    data = batches(steps=1)[0]
+    data = lm.batches(steps=1)[0]
     state = trainer.init_state(data)
     losses = []
     for _ in range(8):
@@ -316,8 +284,8 @@ DEPARTURES = {"a_bfloat16_router": _bf16_router,
 
 @pytest.mark.parametrize("departure", [None] + sorted(DEPARTURES))
 def test_the_check_fails_on(departure, monkeypatch):
-    build_trainer()                                     # imports the zoo module
     if departure:
         DEPARTURES[departure](monkeypatch)
-    verdict = run_check(reference.TOLERANCES, router_scale=6.0)
+    verdict = two_steps_compared(reference.TOLERANCES, router_scale=6.0,
+                                 trainer_of=lm.fresh_trainer if departure else lm.trainer)
     assert verdict["ok"] == (departure is None), (verdict["failures"], verdict["figures"])

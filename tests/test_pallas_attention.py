@@ -1,6 +1,9 @@
 """Pallas flash-attention kernel (ops/pallas_attention.py) vs the naive
 reference, forward and backward, in interpret mode on CPU (the kernel's
-compiled path needs a real TPU; numerics are identical by construction)."""
+compiled path needs a real TPU; numerics are identical by construction).
+The banded grids are in `tests/test_pallas_attention_window.py`, the
+backward's two routes and the `keep` plane in
+`tests/test_pallas_attention_routes.py`."""
 
 import os
 
@@ -628,202 +631,7 @@ def test_logsumexp_carries_its_cotangent_through_a_recomputed_layer(wrap):
 
 
 # ------------------------------------------------------------------ #
-# sliding-window attention: the banded grids
-
-
-def _dense_window(q, k, v, window):
-    """A dense-mask float32 computation, independent of `full_attention`:
-    (out (B, T, H, D), logsumexp (B, H, T))."""
-    t, group = q.shape[1], q.shape[2] // k.shape[2]
-    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   precision=jax.lax.Precision.HIGHEST) * q.shape[-1] ** -0.5
-    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
-    s = jnp.where((j <= i) & (j > i - window), s, -jnp.inf)
-    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v,
-                     precision=jax.lax.Precision.HIGHEST)
-    return out, jax.nn.logsumexp(s, axis=-1)
-
-
-def _windowed_case(t, heads, kv_heads, seed=7):
-    r = np.random.RandomState(seed)
-    draw = lambda h: jnp.asarray(r.randn(1, t, h, D), jnp.float32)
-    return draw(heads), draw(kv_heads), draw(kv_heads)
-
-
-# a block is 16 here: W in {1, 5, a block, a block -+ 1, >= T}
-WINDOWS = [1, 5, 15, 16, 17, 40, 96, 200]
-# (T, H, Hkv, block_q, block_k): T not a multiple of W, bq != bk, groups 1, 4, 8
-GEOMETRIES = [(96, 2, 2, 16, 16), (96, 4, 1, 32, 16), (96, 8, 1, 16, 32)]
-
-
-@pytest.mark.parametrize("geometry", GEOMETRIES, ids=lambda g: "T%d-H%d/%d-b%dx%d" % g)
-@pytest.mark.parametrize("window", WINDOWS)
-def test_windowed_forward_and_logsumexp_match_a_dense_mask(window, geometry):
-    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
-
-    t, heads, kv_heads, bq, bk = geometry
-    q, k, v = _windowed_case(t, heads, kv_heads)
-    out, lse = flash_attention_lse(q, k, v, window=window, block_q=bq, block_k=bk,
-                                   interpret=True)
-    want, want_lse = _dense_window(q, k, v, window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("geometry", GEOMETRIES, ids=lambda g: "T%d-H%d/%d-b%dx%d" % g)
-@pytest.mark.parametrize("window", [1, 5, 16, 17, 40])
-def test_windowed_gradients_match_a_dense_mask(window, geometry):
-    """dq, dk, dv of both outputs (the logsumexp's cotangent too), through the
-    banded dq and dkv kernels."""
-    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
-
-    t, heads, kv_heads, bq, bk = geometry
-    q, k, v = _windowed_case(t, heads, kv_heads)
-    r = np.random.RandomState(8)
-    probe = jnp.asarray(r.randn(1, t, heads, D), jnp.float32)
-    probe_lse = jnp.asarray(r.randn(1, heads, t), jnp.float32)
-    weigh = lambda f: lambda *a: (lambda out, lse: jnp.sum(probe * out)
-                                  + jnp.sum(probe_lse * lse))(*f(*a))
-    got = jax.grad(weigh(lambda *a: flash_attention_lse(
-        *a, window=window, block_q=bq, block_k=bk, interpret=True)), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(weigh(lambda *a: _dense_window(*a, window)), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(got, want):
-        assert a.shape == b.shape
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
-
-
-@pytest.mark.parametrize("window", [96, 97, 4096])
-def test_a_window_of_the_whole_length_is_the_causal_call_to_the_bit(window):
-    q, k, v = _windowed_case(96, 4, 2)
-    kw = dict(block_q=16, block_k=32, interpret=True)
-    f = lambda window: (lambda *a: jnp.sum(flash_attention(*a, window=window, **kw) ** 2))
-    np.testing.assert_array_equal(np.asarray(flash_attention(q, k, v, window=window, **kw)),
-                                  np.asarray(flash_attention(q, k, v, **kw)))
-    for a, b in zip(jax.grad(f(window), argnums=(0, 1, 2))(q, k, v),
-                    jax.grad(f(None), argnums=(0, 1, 2))(q, k, v)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    names = _kernel_grids(jax.make_jaxpr(jax.grad(f(window)))(q, k, v).jaxpr)
-    assert sorted(names) == ["flash_attention_bwd", "flash_attention_fwd"]
-
-
-def _kernel_grids(jaxpr):
-    """{kernel name: grid} of every pallas_call of a jaxpr."""
-    out = {}
-
-    def note(eqn):
-        if eqn.primitive.name == "pallas_call":
-            out[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
-
-    equations(jaxpr, note)
-    return out
-
-
-@pytest.mark.parametrize("route,window,want", [
-    # one backward call: (B, key-value heads, 4 heads a group x 6 q blocks)
-    ("resident", None, {"flash_attention_fwd": (1, 8, 6, 6), "flash_attention_bwd": (1, 2, 24)}),
-    # W = a block: 2 kv blocks a q block; the backward's grid does not band,
-    # its loop over a q block's kv blocks does
-    ("resident", 16, {"flash_attention_swa_fwd": (1, 8, 6, 2),
-                      "flash_attention_swa_bwd": (1, 2, 24)}),
-    ("resident", 40, {"flash_attention_swa_fwd": (1, 8, 6, 4),
-                      "flash_attention_swa_bwd": (1, 2, 24)}),
-    ("split", None, {"flash_attention_fwd": (1, 8, 6, 6), "flash_attention_bwd_dq": (1, 8, 6, 6),
-                     "flash_attention_bwd_dkv": (1, 2, 6, 24)}),
-    # W = a block: 2 kv blocks a q block, 2 q blocks a kv block (x 4 heads a group)
-    ("split", 16, {"flash_attention_swa_fwd": (1, 8, 6, 2), "flash_attention_swa_bwd_dq": (1, 8, 6, 2),
-                   "flash_attention_swa_bwd_dkv": (1, 2, 6, 8)}),
-    ("split", 40, {"flash_attention_swa_fwd": (1, 8, 6, 4), "flash_attention_swa_bwd_dq": (1, 8, 6, 4),
-                   "flash_attention_swa_bwd_dkv": (1, 2, 6, 16)}),
-])
-def test_the_grid_is_banded_under_a_window_and_as_it_was_without(route, window, want, monkeypatch):
-    """Read off the lowered calls: `window=None` keeps the unbanded grid and
-    the plain kernel names; a window shortens the kv axis of the forward grid
-    (and of the split route's dq grid, and the q axis of its dkv grid), under
-    names of their own."""
-    take_route(monkeypatch, route)
-    q, k, v = _windowed_case(96, 8, 2)
-    f = lambda *a: jnp.sum(flash_attention(*a, window=window, block_q=16, block_k=16,
-                                           interpret=True) ** 2)
-    assert _kernel_grids(jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, k, v).jaxpr) == want
-
-
-@pytest.mark.parametrize("t,window,blocks,want", [
-    (16384, 1024, (1024, 1024), (31, 136)),      # the benchmark's cell, 1024-blocks
-    (16384, 1024, (1024, 512), (62, 272)),     # 4 kv blocks of 512 a q block, not 3
-    (16384, None, (1024, 1024), (136, 136)),
-    (96, 16, (16, 16), (11, 21)),
-    (100, 16, (16, 16), (0, 0)),                 # cannot be blocked
-])
-def test_kv_block_visits_counts_the_blocks_that_compute(monkeypatch, t, window, blocks, want):
-    from elasticdl_tpu.ops import pallas_attention as pa
-
-    for name, value in zip(("DEFAULT_BLOCK_Q", "DEFAULT_BLOCK_K"), blocks):
-        monkeypatch.setattr(pa, name, value)
-    assert pa.kv_block_visits(t, t, window) == want
-
-
-def test_windowed_layer_under_checkpoint_keeps_one_forward():
-    """`KEEP_RESIDUALS` holds for a windowed call: ONE `flash_attention_swa_fwd`
-    in a recomputed layer's gradient, two under a plain `jax.checkpoint`, and
-    the same values."""
-    r = np.random.RandomState(12)
-    x = jnp.asarray(r.randn(1, 64, 2, 16) * 0.5, jnp.float32)
-    w = jnp.asarray(r.randn(16, 4 * 16) / 4, jnp.float32)
-
-    def loss(wrap):
-        def layer(x, w):
-            q = (x @ w).reshape(1, 64, 8, 16)
-            return flash_attention(q, x, x, window=24, block_q=16, block_k=16, interpret=True)
-        return lambda x, w: jnp.sum(wrap(layer)(x, w) ** 2)
-
-    calls = lambda wrap, kernel: pallas_calls(
-        jax.make_jaxpr(jax.grad(loss(wrap), argnums=(0, 1)))(x, w).jaxpr,
-        "flash_attention_swa_" + kernel)
-    assert calls(jax.checkpoint, "fwd") == 2
-    assert [calls(_kept, kernel) for kernel in ("fwd", "bwd", "bwd_dq", "bwd_dkv")] == [1, 1, 0, 0]
-    for a, b in zip(jax.grad(loss(_kept), argnums=(0, 1))(x, w),
-                    jax.grad(loss(lambda f: f), argnums=(0, 1))(x, w)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_a_window_takes_no_offsets_and_no_acausal_mask(monkeypatch):
-    q, k, v = _windowed_case(64, 2, 2)
-    with pytest.raises(ValueError, match="unsharded"):
-        flash_attention(q, k, v, window=8, q_offset=64, interpret=True)
-    with pytest.raises(ValueError, match="unsharded"):
-        flash_attention(q, k, v, window=8, kv_offset=jnp.int32(0), interpret=True)
-    with pytest.raises(ValueError, match="CAUSAL"):
-        flash_attention(q, k, v, window=8, causal=False, interpret=True)
-    with pytest.raises(ValueError, match="at least itself"):
-        flash_attention(q, k, v, window=0, interpret=True)
-    # and `can_flash` declines one, so `full_attention` takes its XLA path
-    monkeypatch.setenv("EDL_FLASH", "1")
-    monkeypatch.setenv("EDL_FLASH_INTERPRET", "1")
-    assert can_flash(q.shape, k.shape, window=8)
-    assert not can_flash(q.shape, k.shape, q_offset=64, window=8)
-    assert not can_flash(q.shape, k.shape, kv_offset=jnp.int32(0), window=8)
-    assert can_flash(q.shape, k.shape, q_offset=64)
-    got = full_attention(q, k, v, q_offset=64, kv_offset=32, window=40)
-    jaxpr = jax.make_jaxpr(lambda *a: full_attention(*a, q_offset=64, kv_offset=32,
-                                                     window=40))(q, k, v).jaxpr
-    assert not _kernel_grids(jaxpr)
-    assert got.shape == q.shape
-
-
-def test_full_attention_passes_its_window_to_the_kernel(monkeypatch):
-    monkeypatch.setenv("EDL_FLASH", "1")
-    monkeypatch.setenv("EDL_FLASH_INTERPRET", "1")
-    q, k, v = _windowed_case(64, 4, 2)
-    jaxpr = jax.make_jaxpr(lambda *a: full_attention(*a, window=8))(q, k, v).jaxpr
-    assert pallas_calls(jaxpr, "flash_attention_swa_fwd") == 1
-    np.testing.assert_allclose(np.asarray(full_attention(q, k, v, window=8)),
-                               np.asarray(_dense_window(q, k, v, 8)[0]), atol=2e-5, rtol=2e-5)
-
-
-
-# ------------------------------------------------------------------ #
-# the backward's two routes
+# what the window's and the routes' files share
 
 
 def take_route(monkeypatch, route):
@@ -844,265 +652,3 @@ def bwd_log(caplog):
     pa._bwd_plan.cache_clear()
     with listening(caplog, pa.__name__):
         yield caplog
-
-
-# (T, heads, key-value heads, head size, block_q, block_k, causal, window,
-#  (q_offset, kv_offset), with a cotangent on the logsumexp)
-BACKWARD = {
-    "mha": (64, 2, 2, 16, 16, 16, True, None, (0, 0), False),
-    "acausal": (64, 2, 2, 16, 16, 32, False, None, (0, 0), False),
-    "one_block": (32, 2, 2, 16, 32, 32, True, None, (0, 0), False),     # ONE kv block a q block
-    "group16": (64, 32, 2, 16, 32, 16, True, None, (0, 0), True),        # Nemotron's 32 on 2
-    "head256": (64, 2, 1, 256, 32, 16, True, None, (0, 0), False),       # two diagonal blocks
-    "window_in_a_block": (96, 4, 2, 16, 32, 32, True, 5, (0, 0), True),  # the band in ONE kv block
-    "window_a_block": (96, 4, 1, 16, 16, 16, True, 16, (0, 0), False),
-    "window_off_block": (96, 8, 2, 16, 16, 32, True, 40, (0, 0), True),  # whole blocks in the band
-    "window_wide": (128, 2, 2, 16, 16, 16, True, 50, (0, 0), False),
-    "offsets": (64, 2, 2, 16, 16, 16, True, None, (64, 32), True),       # ring attention's
-    "offsets_before": (64, 2, 2, 16, 16, 16, True, None, (0, 48), True), # q blocks seeing NO key
-    "offsets_unaligned": (64, 4, 2, 16, 32, 16, True, None, (40, 8), False),
-}
-
-
-def _backward_case(name):
-    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
-
-    t, heads, kv_heads, head, bq, bk, causal, window, (q_off, kv_off), with_lse = BACKWARD[name]
-    r = np.random.RandomState(31)
-    draw = lambda *shape: jnp.asarray(r.randn(*shape) * 0.5, jnp.float32)
-    q, k, v = draw(1, t, heads, head), draw(1, t, kv_heads, head), draw(1, t, kv_heads, head)
-    probe, probe_lse = draw(1, t, heads, head), draw(1, heads, t) * float(with_lse)
-
-    def weigh(out, lse):
-        return jnp.sum(probe * out) + jnp.sum(probe_lse * jnp.where(lse > -1e29, lse, 0.0))
-
-    def flash(q, k, v):
-        # traced offsets, as ring attention passes them (a window takes none)
-        offsets = {} if window is not None else dict(
-            q_offset=jnp.int32(q_off), kv_offset=jnp.int32(kv_off))
-        if with_lse:
-            return weigh(*flash_attention_lse(q, k, v, causal=causal, window=window, block_q=bq,
-                                              block_k=bk, interpret=True, **offsets))
-        return jnp.sum(probe * flash_attention(q, k, v, causal=causal, window=window, block_q=bq,
-                                               block_k=bk, interpret=True, **offsets))
-
-    def dense(q, k, v):
-        group = heads // kv_heads
-        kk, vv = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
-                       precision=jax.lax.Precision.HIGHEST) * head ** -0.5
-        i, j = q_off + jnp.arange(t)[:, None], kv_off + jnp.arange(t)[None, :]
-        mask = (j <= i) if causal else jnp.ones((t, t), bool)
-        if window is not None:
-            mask &= j > i - window
-        s = jnp.where(mask, s, -jnp.inf)
-        rows = jnp.any(mask, axis=1)[None, None, :, None]        # a row with no key: zeros
-        p = jnp.where(rows, jax.nn.softmax(jnp.where(rows, s, 0.0), axis=-1), 0.0)
-        out = jnp.einsum("bhqk,bkhd->bqhd", p, vv, precision=jax.lax.Precision.HIGHEST)
-        lse = jnp.where(rows[..., 0], jax.nn.logsumexp(jnp.where(rows, s, 0.0), axis=-1), 0.0)
-        return weigh(out, lse)
-
-    return flash, dense, (q, k, v)
-
-
-@pytest.mark.parametrize("name", sorted(BACKWARD))
-def test_the_resident_backward_is_the_split_one_to_the_bit(name, monkeypatch):
-    """dq, dk, dv by the one kernel — a head's k and v resident, a pair's
-    score block computed once — against a dense mask, and against the dq and
-    dkv kernels bit for bit: the same operands, the same order of sums."""
-    flash, dense, args = _backward_case(name)
-    got = {}
-    for route in ("resident", "split"):
-        take_route(monkeypatch, route)
-        jaxpr = jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(*args).jaxpr
-        names = sorted(n.replace("swa_", "") for n in _kernel_grids(jaxpr))
-        assert names == {"resident": ["flash_attention_bwd", "flash_attention_fwd"],
-                         "split": ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
-                                   "flash_attention_fwd"]}[route]
-        got[route] = jax.grad(flash, argnums=(0, 1, 2))(*args)
-    want = jax.grad(dense, argnums=(0, 1, 2))(*args)
-    for a, b, c in zip(got["resident"], got["split"], want):
-        assert float(jnp.max(jnp.abs(c))) > 1e-3
-        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-4, rtol=1e-4)
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# (keys, head, dtype) -> the blocks the plan gives and the route on a v5e's
-# 128 MiB of VMEM: the four language-model cells of BENCHMARK.json, then what
-# does not fit
-ROUTES = {
-    "olmoe-1b-7b.resident-4k": (4096, 128, jnp.bfloat16, "resident"),
-    "nemotron-3-nano-30b-a3b.resident-8k": (8192, 128, jnp.bfloat16, "resident"),
-    "glm-4.7-flash.resident-8k": (8192, 256, jnp.bfloat16, "resident"),
-    "mellum2-12b-a2.5b.resident-16k": (16384, 128, jnp.bfloat16, "resident"),
-    "32k_keys": (32768, 128, jnp.bfloat16, "split"),
-    "16k_keys_of_256": (16384, 256, jnp.bfloat16, "split"),
-    "16k_keys_float32": (16384, 128, jnp.float32, "split"),
-}
-
-
-@pytest.mark.parametrize("name", sorted(ROUTES))
-def test_the_backward_route_follows_the_head_s_bytes_and_logs_once_a_shape(
-        name, bwd_log, monkeypatch):
-    from elasticdl_tpu.ops import pallas_attention as pa
-
-    take_route(monkeypatch, "resident")            # a described v5e
-    t_k, head, dtype, want = ROUTES[name]
-    shape = (1, t_k, 4, head)
-    bq, bk = pa._plan_blocks(shape, shape, None, None, dtype=dtype)
-    plan = pa.bwd_route(t_k, head, dtype, bq, bk)
-    assert plan.route == want
-    assert (plan.vmem_bytes <= plan.vmem_limit) == (want == "resident")
-    assert plan.vmem_limit == (128 << 20) * 3 // 4
-    # k, v, dk, dv twice buffered and the two float32 accumulators at least
-    assert plan.vmem_bytes > t_k * head * (8 * jnp.dtype(dtype).itemsize + 8)
-    assert pa.bwd_route(t_k, head, dtype, bq, bk) == plan
-    # (a record that also propagates to the root logger is listed twice)
-    lines = list({id(r): r.getMessage() for r in bwd_log.records
-                  if "backward" in r.getMessage()}.values())
-    assert len(lines) == 1 and f"takes the {want} route" in lines[0]
-    assert f"{t_k} keys, head {head}" in lines[0]
-    # a smaller chip: the same function, the other answer
-    monkeypatch.setattr(pa, "_vmem_bytes", lambda: 16 << 20)
-    assert pa.bwd_route(t_k, head, dtype, bq, bk).route == "split"
-
-
-
-# ------------------------------------------------------------------ #
-# a mask that is data (`keep`)
-
-
-def _keep_case(t=128, heads=4, kv_heads=2, seed=11, share=0.3):
-    """(q, k, v, keep): a random plane that keeps every query's own position
-    and NO key of one whole (32 x 32) block below the diagonal."""
-    r = np.random.RandomState(seed)
-    draw = lambda h: jnp.asarray(r.randn(2, t, h, 16), jnp.float32)
-    keep = r.rand(2, t, t) < share
-    keep |= np.eye(t, dtype=bool)[None]
-    keep[:, 64:96, 0:32] = False
-    return draw(heads), draw(kv_heads), draw(kv_heads), jnp.asarray(keep)
-
-
-def _dense_keep(q, k, v, keep):
-    """(out, lse) by a dense mask: j <= i and keep[i, j]."""
-    b, t, h, d = q.shape
-    k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
-    mask = jnp.tril(jnp.ones((t, t), bool))[None] & keep
-    s = jnp.where(mask[:, None], s, -jnp.inf)
-    return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v),
-            jax.nn.logsumexp(s, axis=-1))
-
-
-@pytest.mark.parametrize("route", ["resident", "split"])
-@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (8, 2)])
-def test_keep_forward_and_both_backward_routes_match_a_dense_mask(route, heads, kv_heads,
-                                                                  monkeypatch):
-    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
-
-    take_route(monkeypatch, route)
-    q, k, v, keep = _keep_case(heads=heads, kv_heads=kv_heads)
-    probe = jnp.asarray(np.random.RandomState(5).randn(*q.shape), jnp.float32)
-
-    def loss(f):
-        def value(q, k, v):
-            out, lse = f(q, k, v)
-            return jnp.sum(probe * out) + jnp.sum(jnp.sin(lse)), (out, lse)
-        return jax.value_and_grad(value, argnums=(0, 1, 2), has_aux=True)
-
-    flash = lambda *a: flash_attention_lse(*a, keep=keep, block_q=32, block_k=32,
-                                           interpret=True)
-    ((_, got), got_grads), ((_, want), want_grads) = loss(flash)(q, k, v), loss(
-        lambda *a: _dense_keep(*a, keep))(q, k, v)
-    for a, b in zip(got + got_grads, want + want_grads):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("route", ["resident", "split"])
-def test_keep_of_all_ones_is_the_causal_call_to_the_bit(route, monkeypatch):
-    take_route(monkeypatch, route)
-    q, k, v, _ = _keep_case(t=96)
-    ones = jnp.ones((2, 96, 96), jnp.int8)
-    f = lambda keep: jax.value_and_grad(lambda *a: jnp.sum(flash_attention(
-        *a, keep=keep, block_q=32, block_k=32, interpret=True) ** 2), argnums=(0, 1, 2))
-    for a, b in zip(jax.tree_util.tree_leaves(f(ones)(q, k, v)),
-                    jax.tree_util.tree_leaves(f(None)(q, k, v))):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-@pytest.mark.parametrize("route,keep,want", [
-    ("resident", False, {"flash_attention_fwd": (2, 4, 4, 4), "flash_attention_bwd": (2, 2, 8)}),
-    ("split", False, {"flash_attention_fwd": (2, 4, 4, 4), "flash_attention_bwd_dq": (2, 4, 4, 4),
-                      "flash_attention_bwd_dkv": (2, 2, 4, 8)}),
-    ("resident", True, {"flash_attention_sel_fwd": (2, 4, 4, 4),
-                        "flash_attention_sel_bwd": (2, 2, 8)}),
-    ("split", True, {"flash_attention_sel_fwd": (2, 4, 4, 4),
-                     "flash_attention_sel_bwd_dq": (2, 4, 4, 4),
-                     "flash_attention_sel_bwd_dkv": (2, 2, 4, 8)}),
-])
-def test_keep_none_lowers_to_the_kernels_it_always_did(route, keep, want, monkeypatch):
-    """Read off the lowered calls: without `keep` the names and grids of
-    before, with it names of its own on the SAME grids (no block is skipped for
-    being empty of kept keys), and one operand more."""
-    take_route(monkeypatch, route)
-    q, k, v, plane = _keep_case()
-    f = lambda *a: jnp.sum(flash_attention(*a, keep=plane if keep else None, block_q=32,
-                                           block_k=32, interpret=True) ** 2)
-    jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, k, v).jaxpr
-    assert _kernel_grids(jaxpr) == want
-    operands = []
-    equations(jaxpr, lambda eqn: eqn.primitive.name == "pallas_call"
-              and operands.append(len(eqn.invars)))
-    # offsets, q, k, v (+ keep); offsets, q, k, v, out, do, lse (+ keep)
-    assert sorted(set(operands)) == ([5, 8] if keep else [4, 7])
-
-
-def test_a_keep_call_plans_smaller_q_blocks_and_counts_its_strip():
-    from elasticdl_tpu.ops import pallas_attention as pa
-
-    shape = (1, 16384, 32, 128)
-    assert pa._plan_blocks(shape, shape, None, None, dtype=jnp.bfloat16) == (1024, 1024)
-    assert pa._plan_blocks(shape, shape, None, None, dtype=jnp.bfloat16, keep=True) == (512, 1024)
-    vmem = 128 << 20
-    plan = lambda bq, keep: pa._bwd_plan(16384, 128, "bfloat16", bq, 1024, vmem, keep)
-    assert plan(1024, False).route == "resident" and plan(512, True).route == "resident"
-    assert plan(1024, True).route == "split"
-    assert plan(512, True).vmem_bytes - plan(512, False).vmem_bytes \
-        == 2 * 512 * 16384 + 4 * 512 * 1024
-    # an int8 tile has 32 rows: a sequence with no such block is declined
-    assert pa._plan_blocks((1, 48, 2, 16), (1, 48, 2, 16), None, None, keep=True) is None
-
-
-def test_keep_takes_no_window_and_no_offsets(monkeypatch):
-    q, k, v, keep = _keep_case(t=64)
-    keep = keep[:, :64, :64]
-    with pytest.raises(ValueError, match="without a window"):
-        flash_attention(q, k, v, keep=keep, window=8, interpret=True)
-    with pytest.raises(ValueError, match="without a window"):
-        flash_attention(q, k, v, keep=keep, q_offset=64, interpret=True)
-    with pytest.raises(ValueError, match="no head axis"):
-        flash_attention(q, k, v, keep=keep[:, None], interpret=True)
-    with pytest.raises(ValueError, match="int8 or bool"):
-        flash_attention(q, k, v, keep=keep.astype(jnp.float32), interpret=True)
-    monkeypatch.setenv("EDL_FLASH", "1")
-    monkeypatch.setenv("EDL_FLASH_INTERPRET", "1")
-    assert can_flash(q.shape, k.shape, keep=True)
-    assert not can_flash(q.shape, k.shape, keep=True, window=8)
-    assert not can_flash(q.shape, k.shape, keep=True, q_offset=64)
-    assert not can_flash(q.shape, k.shape, keep=True, kv_offset=jnp.int32(0))
-    # `full_attention` then takes its XLA path, with the same mask
-    jaxpr = jax.make_jaxpr(lambda *a: full_attention(*a, keep=keep, window=8))(q, k, v).jaxpr
-    assert not _kernel_grids(jaxpr)
-
-
-def test_full_attention_passes_its_keep_to_the_kernel(monkeypatch):
-    monkeypatch.setenv("EDL_FLASH", "1")
-    monkeypatch.setenv("EDL_FLASH_INTERPRET", "1")
-    monkeypatch.setattr("elasticdl_tpu.ops.pallas_attention.SEL_BLOCK_Q", 32)
-    monkeypatch.setattr("elasticdl_tpu.ops.pallas_attention.DEFAULT_BLOCK_K", 32)
-    q, k, v, keep = _keep_case()
-    jaxpr = jax.make_jaxpr(lambda *a: full_attention(*a, keep=keep, with_lse=True))(q, k, v).jaxpr
-    assert pallas_calls(jaxpr, "flash_attention_sel_fwd") == 1
-    got, want = full_attention(q, k, v, keep=keep, with_lse=True), _dense_keep(q, k, v, keep)
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)

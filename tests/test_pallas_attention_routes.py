@@ -1,0 +1,279 @@
+"""The flash backward's two routes (`bwd_route`: one kernel with a head's keys
+resident in VMEM, or the two that stream them), held to each other bit for
+bit, and a mask that is data (`keep`) on both (`ops/pallas_attention.py`), in
+interpret mode on the CPU. A file of its own so that three xdist workers
+share the kernel's cases (`tests/test_pallas_attention.py`,
+`tests/test_pallas_attention_window.py`).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticdl_tpu.ops.attention import full_attention
+from elasticdl_tpu.ops.pallas_attention import can_flash, flash_attention
+from tests.conftest import equations, pallas_calls
+from tests.test_pallas_attention import bwd_log, take_route  # noqa: F401  (a fixture)
+from tests.test_pallas_attention_window import _kernel_grids
+
+# (T, heads, key-value heads, head size, block_q, block_k, causal, window,
+#  (q_offset, kv_offset), with a cotangent on the logsumexp)
+BACKWARD = {
+    "mha": (64, 2, 2, 16, 16, 16, True, None, (0, 0), False),
+    "acausal": (64, 2, 2, 16, 16, 32, False, None, (0, 0), False),
+    "one_block": (32, 2, 2, 16, 32, 32, True, None, (0, 0), False),     # ONE kv block a q block
+    "group16": (64, 32, 2, 16, 32, 16, True, None, (0, 0), True),        # Nemotron's 32 on 2
+    "head256": (64, 2, 1, 256, 32, 16, True, None, (0, 0), False),       # two diagonal blocks
+    "window_in_a_block": (96, 4, 2, 16, 32, 32, True, 5, (0, 0), True),  # the band in ONE kv block
+    "window_a_block": (96, 4, 1, 16, 16, 16, True, 16, (0, 0), False),
+    "window_off_block": (96, 8, 2, 16, 16, 32, True, 40, (0, 0), True),  # whole blocks in the band
+    "window_wide": (128, 2, 2, 16, 16, 16, True, 50, (0, 0), False),
+    "offsets": (64, 2, 2, 16, 16, 16, True, None, (64, 32), True),       # ring attention's
+    "offsets_before": (64, 2, 2, 16, 16, 16, True, None, (0, 48), True), # q blocks seeing NO key
+    "offsets_unaligned": (64, 4, 2, 16, 32, 16, True, None, (40, 8), False),
+}
+
+
+def _backward_case(name):
+    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
+
+    t, heads, kv_heads, head, bq, bk, causal, window, (q_off, kv_off), with_lse = BACKWARD[name]
+    r = np.random.RandomState(31)
+    draw = lambda *shape: jnp.asarray(r.randn(*shape) * 0.5, jnp.float32)
+    q, k, v = draw(1, t, heads, head), draw(1, t, kv_heads, head), draw(1, t, kv_heads, head)
+    probe, probe_lse = draw(1, t, heads, head), draw(1, heads, t) * float(with_lse)
+
+    def weigh(out, lse):
+        return jnp.sum(probe * out) + jnp.sum(probe_lse * jnp.where(lse > -1e29, lse, 0.0))
+
+    def flash(q, k, v):
+        # traced offsets, as ring attention passes them (a window takes none)
+        offsets = {} if window is not None else dict(
+            q_offset=jnp.int32(q_off), kv_offset=jnp.int32(kv_off))
+        if with_lse:
+            return weigh(*flash_attention_lse(q, k, v, causal=causal, window=window, block_q=bq,
+                                              block_k=bk, interpret=True, **offsets))
+        return jnp.sum(probe * flash_attention(q, k, v, causal=causal, window=window, block_q=bq,
+                                               block_k=bk, interpret=True, **offsets))
+
+    def dense(q, k, v):
+        group = heads // kv_heads
+        kk, vv = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
+                       precision=jax.lax.Precision.HIGHEST) * head ** -0.5
+        i, j = q_off + jnp.arange(t)[:, None], kv_off + jnp.arange(t)[None, :]
+        mask = (j <= i) if causal else jnp.ones((t, t), bool)
+        if window is not None:
+            mask &= j > i - window
+        s = jnp.where(mask, s, -jnp.inf)
+        rows = jnp.any(mask, axis=1)[None, None, :, None]        # a row with no key: zeros
+        p = jnp.where(rows, jax.nn.softmax(jnp.where(rows, s, 0.0), axis=-1), 0.0)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, vv, precision=jax.lax.Precision.HIGHEST)
+        lse = jnp.where(rows[..., 0], jax.nn.logsumexp(jnp.where(rows, s, 0.0), axis=-1), 0.0)
+        return weigh(out, lse)
+
+    return flash, dense, (q, k, v)
+
+
+@pytest.mark.parametrize("name", sorted(BACKWARD))
+def test_the_resident_backward_is_the_split_one_to_the_bit(name, monkeypatch):
+    """dq, dk, dv by the one kernel — a head's k and v resident, a pair's
+    score block computed once — against a dense mask, and against the dq and
+    dkv kernels bit for bit: the same operands, the same order of sums."""
+    flash, dense, args = _backward_case(name)
+    got = {}
+    for route in ("resident", "split"):
+        take_route(monkeypatch, route)
+        jaxpr = jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(*args).jaxpr
+        names = sorted(n.replace("swa_", "") for n in _kernel_grids(jaxpr))
+        assert names == {"resident": ["flash_attention_bwd", "flash_attention_fwd"],
+                         "split": ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                                   "flash_attention_fwd"]}[route]
+        got[route] = jax.grad(flash, argnums=(0, 1, 2))(*args)
+    want = jax.grad(dense, argnums=(0, 1, 2))(*args)
+    for a, b, c in zip(got["resident"], got["split"], want):
+        assert float(jnp.max(jnp.abs(c))) > 1e-3
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-4, rtol=1e-4)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# (keys, head, dtype) -> the blocks the plan gives and the route on a v5e's
+# 128 MiB of VMEM: the four language-model cells of BENCHMARK.json, then what
+# does not fit
+ROUTES = {
+    "olmoe-1b-7b.resident-4k": (4096, 128, jnp.bfloat16, "resident"),
+    "nemotron-3-nano-30b-a3b.resident-8k": (8192, 128, jnp.bfloat16, "resident"),
+    "glm-4.7-flash.resident-8k": (8192, 256, jnp.bfloat16, "resident"),
+    "mellum2-12b-a2.5b.resident-16k": (16384, 128, jnp.bfloat16, "resident"),
+    "32k_keys": (32768, 128, jnp.bfloat16, "split"),
+    "16k_keys_of_256": (16384, 256, jnp.bfloat16, "split"),
+    "16k_keys_float32": (16384, 128, jnp.float32, "split"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ROUTES))
+def test_the_backward_route_follows_the_head_s_bytes_and_logs_once_a_shape(
+        name, bwd_log, monkeypatch):
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    take_route(monkeypatch, "resident")            # a described v5e
+    t_k, head, dtype, want = ROUTES[name]
+    shape = (1, t_k, 4, head)
+    bq, bk = pa._plan_blocks(shape, shape, None, None, dtype=dtype)
+    plan = pa.bwd_route(t_k, head, dtype, bq, bk)
+    assert plan.route == want
+    assert (plan.vmem_bytes <= plan.vmem_limit) == (want == "resident")
+    assert plan.vmem_limit == (128 << 20) * 3 // 4
+    # k, v, dk, dv twice buffered and the two float32 accumulators at least
+    assert plan.vmem_bytes > t_k * head * (8 * jnp.dtype(dtype).itemsize + 8)
+    assert pa.bwd_route(t_k, head, dtype, bq, bk) == plan
+    # (a record that also propagates to the root logger is listed twice)
+    lines = list({id(r): r.getMessage() for r in bwd_log.records
+                  if "backward" in r.getMessage()}.values())
+    assert len(lines) == 1 and f"takes the {want} route" in lines[0]
+    assert f"{t_k} keys, head {head}" in lines[0]
+    # a smaller chip: the same function, the other answer
+    monkeypatch.setattr(pa, "_vmem_bytes", lambda: 16 << 20)
+    assert pa.bwd_route(t_k, head, dtype, bq, bk).route == "split"
+
+
+
+# ------------------------------------------------------------------ #
+# a mask that is data (`keep`)
+
+
+def _keep_case(t=128, heads=4, kv_heads=2, seed=11, share=0.3):
+    """(q, k, v, keep): a random plane that keeps every query's own position
+    and NO key of one whole (32 x 32) block below the diagonal."""
+    r = np.random.RandomState(seed)
+    draw = lambda h: jnp.asarray(r.randn(2, t, h, 16), jnp.float32)
+    keep = r.rand(2, t, t) < share
+    keep |= np.eye(t, dtype=bool)[None]
+    keep[:, 64:96, 0:32] = False
+    return draw(heads), draw(kv_heads), draw(kv_heads), jnp.asarray(keep)
+
+
+def _dense_keep(q, k, v, keep):
+    """(out, lse) by a dense mask: j <= i and keep[i, j]."""
+    b, t, h, d = q.shape
+    k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    mask = jnp.tril(jnp.ones((t, t), bool))[None] & keep
+    s = jnp.where(mask[:, None], s, -jnp.inf)
+    return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+@pytest.mark.parametrize("route", ["resident", "split"])
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (8, 2)])
+def test_keep_forward_and_both_backward_routes_match_a_dense_mask(route, heads, kv_heads,
+                                                                  monkeypatch):
+    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
+
+    take_route(monkeypatch, route)
+    q, k, v, keep = _keep_case(heads=heads, kv_heads=kv_heads)
+    probe = jnp.asarray(np.random.RandomState(5).randn(*q.shape), jnp.float32)
+
+    def loss(f):
+        def value(q, k, v):
+            out, lse = f(q, k, v)
+            return jnp.sum(probe * out) + jnp.sum(jnp.sin(lse)), (out, lse)
+        return jax.value_and_grad(value, argnums=(0, 1, 2), has_aux=True)
+
+    flash = lambda *a: flash_attention_lse(*a, keep=keep, block_q=32, block_k=32,
+                                           interpret=True)
+    ((_, got), got_grads), ((_, want), want_grads) = loss(flash)(q, k, v), loss(
+        lambda *a: _dense_keep(*a, keep))(q, k, v)
+    for a, b in zip(got + got_grads, want + want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("route", ["resident", "split"])
+def test_keep_of_all_ones_is_the_causal_call_to_the_bit(route, monkeypatch):
+    take_route(monkeypatch, route)
+    q, k, v, _ = _keep_case(t=96)
+    ones = jnp.ones((2, 96, 96), jnp.int8)
+    f = lambda keep: jax.value_and_grad(lambda *a: jnp.sum(flash_attention(
+        *a, keep=keep, block_q=32, block_k=32, interpret=True) ** 2), argnums=(0, 1, 2))
+    for a, b in zip(jax.tree_util.tree_leaves(f(ones)(q, k, v)),
+                    jax.tree_util.tree_leaves(f(None)(q, k, v))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("route,keep,want", [
+    ("resident", False, {"flash_attention_fwd": (2, 4, 4, 4), "flash_attention_bwd": (2, 2, 8)}),
+    ("split", False, {"flash_attention_fwd": (2, 4, 4, 4), "flash_attention_bwd_dq": (2, 4, 4, 4),
+                      "flash_attention_bwd_dkv": (2, 2, 4, 8)}),
+    ("resident", True, {"flash_attention_sel_fwd": (2, 4, 4, 4),
+                        "flash_attention_sel_bwd": (2, 2, 8)}),
+    ("split", True, {"flash_attention_sel_fwd": (2, 4, 4, 4),
+                     "flash_attention_sel_bwd_dq": (2, 4, 4, 4),
+                     "flash_attention_sel_bwd_dkv": (2, 2, 4, 8)}),
+])
+def test_keep_none_lowers_to_the_kernels_it_always_did(route, keep, want, monkeypatch):
+    """Read off the lowered calls: without `keep` the names and grids of
+    before, with it names of its own on the SAME grids (no block is skipped for
+    being empty of kept keys), and one operand more."""
+    take_route(monkeypatch, route)
+    q, k, v, plane = _keep_case()
+    f = lambda *a: jnp.sum(flash_attention(*a, keep=plane if keep else None, block_q=32,
+                                           block_k=32, interpret=True) ** 2)
+    jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, k, v).jaxpr
+    assert _kernel_grids(jaxpr) == want
+    operands = []
+    equations(jaxpr, lambda eqn: eqn.primitive.name == "pallas_call"
+              and operands.append(len(eqn.invars)))
+    # offsets, q, k, v (+ keep); offsets, q, k, v, out, do, lse (+ keep)
+    assert sorted(set(operands)) == ([5, 8] if keep else [4, 7])
+
+
+def test_a_keep_call_plans_smaller_q_blocks_and_counts_its_strip():
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    shape = (1, 16384, 32, 128)
+    assert pa._plan_blocks(shape, shape, None, None, dtype=jnp.bfloat16) == (1024, 1024)
+    assert pa._plan_blocks(shape, shape, None, None, dtype=jnp.bfloat16, keep=True) == (512, 1024)
+    vmem = 128 << 20
+    plan = lambda bq, keep: pa._bwd_plan(16384, 128, "bfloat16", bq, 1024, vmem, keep)
+    assert plan(1024, False).route == "resident" and plan(512, True).route == "resident"
+    assert plan(1024, True).route == "split"
+    assert plan(512, True).vmem_bytes - plan(512, False).vmem_bytes \
+        == 2 * 512 * 16384 + 4 * 512 * 1024
+    # an int8 tile has 32 rows: a sequence with no such block is declined
+    assert pa._plan_blocks((1, 48, 2, 16), (1, 48, 2, 16), None, None, keep=True) is None
+
+
+def test_keep_takes_no_window_and_no_offsets(monkeypatch):
+    q, k, v, keep = _keep_case(t=64)
+    keep = keep[:, :64, :64]
+    with pytest.raises(ValueError, match="without a window"):
+        flash_attention(q, k, v, keep=keep, window=8, interpret=True)
+    with pytest.raises(ValueError, match="without a window"):
+        flash_attention(q, k, v, keep=keep, q_offset=64, interpret=True)
+    with pytest.raises(ValueError, match="no head axis"):
+        flash_attention(q, k, v, keep=keep[:, None], interpret=True)
+    with pytest.raises(ValueError, match="int8 or bool"):
+        flash_attention(q, k, v, keep=keep.astype(jnp.float32), interpret=True)
+    monkeypatch.setenv("EDL_FLASH", "1")
+    monkeypatch.setenv("EDL_FLASH_INTERPRET", "1")
+    assert can_flash(q.shape, k.shape, keep=True)
+    assert not can_flash(q.shape, k.shape, keep=True, window=8)
+    assert not can_flash(q.shape, k.shape, keep=True, q_offset=64)
+    assert not can_flash(q.shape, k.shape, keep=True, kv_offset=jnp.int32(0))
+    # `full_attention` then takes its XLA path, with the same mask
+    jaxpr = jax.make_jaxpr(lambda *a: full_attention(*a, keep=keep, window=8))(q, k, v).jaxpr
+    assert not _kernel_grids(jaxpr)
+
+
+def test_full_attention_passes_its_keep_to_the_kernel(monkeypatch):
+    monkeypatch.setenv("EDL_FLASH", "1")
+    monkeypatch.setenv("EDL_FLASH_INTERPRET", "1")
+    monkeypatch.setattr("elasticdl_tpu.ops.pallas_attention.SEL_BLOCK_Q", 32)
+    monkeypatch.setattr("elasticdl_tpu.ops.pallas_attention.DEFAULT_BLOCK_K", 32)
+    q, k, v, keep = _keep_case()
+    jaxpr = jax.make_jaxpr(lambda *a: full_attention(*a, keep=keep, with_lse=True))(q, k, v).jaxpr
+    assert pallas_calls(jaxpr, "flash_attention_sel_fwd") == 1
+    got, want = full_attention(q, k, v, keep=keep, with_lse=True), _dense_keep(q, k, v, keep)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)

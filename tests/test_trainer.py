@@ -56,20 +56,23 @@ def test_train_many_matches_stepwise(mesh8):
     trajectory as K individual train_step dispatches — same final loss and
     model_version (dispatch amortization is a pure packaging change)."""
     from elasticdl_tpu.parallel.mesh import shard_batch_stack
+    from tests.conftest import default_pipeline
 
     batches = [synthetic_batch(seed=i) for i in range(6)]
 
-    t1 = Trainer(make_spec(learning_rate=0.01), mesh8, seed=0)
-    s1 = t1.init_state(batches[0])
-    stepwise = []
-    for b in batches:
-        s1, logs = t1.train_step(s1, b)
-        stepwise.append(float(logs["loss"]))
+    # twelve CNN steps over eight devices: this case's seconds are execution
+    with default_pipeline():
+        t1 = Trainer(make_spec(learning_rate=0.01), mesh8, seed=0)
+        s1 = t1.init_state(batches[0])
+        stepwise = []
+        for b in batches:
+            s1, logs = t1.train_step(s1, b)
+            stepwise.append(float(logs["loss"]))
 
-    t2 = Trainer(make_spec(learning_rate=0.01), mesh8, seed=0)
-    s2 = t2.init_state(batches[0])
-    s2, metrics = t2.train_many(s2, shard_batch_stack(mesh8, batches))
-    scanned = [float(x) for x in metrics["loss"]]
+        t2 = Trainer(make_spec(learning_rate=0.01), mesh8, seed=0)
+        s2 = t2.init_state(batches[0])
+        s2, metrics = t2.train_many(s2, shard_batch_stack(mesh8, batches))
+        scanned = [float(x) for x in metrics["loss"]]
 
     assert s2.model_version == s1.model_version == 6
     np.testing.assert_allclose(scanned, stepwise, rtol=2e-4, atol=2e-4)
