@@ -88,7 +88,7 @@ from elasticdl_tpu.ops import sparse_attention
 from elasticdl_tpu.ops.attention import full_attention
 from model_zoo.transformer.mellum import rotate
 from model_zoo.transformer.nemotron_h import (
-    _matmul, _pairs_on_held, held_passes, held_row_tiles)
+    matmul, pairs_on_held, held_passes, held_row_tiles)
 from model_zoo.transformer.olmoe import (  # noqa: F401
     batch_partition, eval_metrics_fn, optimizer, rmsnorm)
 from model_zoo.transformer.transformer_lm import dataset_fn  # noqa: F401
@@ -190,11 +190,11 @@ def indexer(p: Dict[str, jax.Array], h: jax.Array, table, cfg: Config):
     b, t, _ = h.shape
     heads, d = cfg.indexer_num_heads, cfg.indexer_head_dim
     h = detached(h)
-    q = _matmul(h, p["index_wq"], dt, jnp.float32).reshape(b, t, heads, d)
-    k = layernorm(_matmul(h, p["index_wk"], dt, jnp.float32),
+    q = matmul(h, p["index_wq"], dt, jnp.float32).reshape(b, t, heads, d)
+    k = layernorm(matmul(h, p["index_wk"], dt, jnp.float32),
                   p["index_k_scale"], p["index_k_bias"], cfg.rms_norm_eps)
     q, k = rotate(q, table), rotate(k[:, :, None, :], table)[:, :, 0, :]
-    w = _matmul(h, p["index_w"], dt, jnp.float32) * (heads * d) ** -0.5
+    w = matmul(h, p["index_w"], dt, jnp.float32) * (heads * d) ** -0.5
     return q.astype(dt), k.astype(dt), w
 
 
@@ -207,9 +207,9 @@ def attention(p: Dict[str, jax.Array], x: jax.Array, tables, cfg: Config):
     heads, kv_heads, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     h = rmsnorm(x, p["attn_norm"], cfg.rms_norm_eps)
     with jax.named_scope("qkv"):
-        q = _matmul(h, p["wq"], dt, jnp.float32).reshape(b, t, heads, d)
-        k = _matmul(h, p["wk"], dt, jnp.float32).reshape(b, t, kv_heads, d)
-        v = _matmul(h, p["wv"], dt).reshape(b, t, kv_heads, d)
+        q = matmul(h, p["wq"], dt, jnp.float32).reshape(b, t, heads, d)
+        k = matmul(h, p["wk"], dt, jnp.float32).reshape(b, t, kv_heads, d)
+        v = matmul(h, p["wv"], dt).reshape(b, t, kv_heads, d)
         q = rmsnorm(q, p["q_norm"], cfg.rms_norm_eps)
         k = rmsnorm(k, p["k_norm"], cfg.rms_norm_eps)
     with jax.named_scope("rope"):
@@ -224,7 +224,7 @@ def attention(p: Dict[str, jax.Array], x: jax.Array, tables, cfg: Config):
         # its rule gives q, k and lse no gradient: the target is detached there
         index_kl = sparse_attention.index_kl(*index, q, k, lse, keep)
     with jax.named_scope("out"):
-        update = _matmul(out.reshape(b, t, heads * d), p["wo"], dt, jnp.float32)
+        update = matmul(out.reshape(b, t, heads * d), p["wo"], dt, jnp.float32)
     return update, {"index_kl": index_kl, "threshold": threshold, "keep": keep, **counts}
 
 
@@ -297,7 +297,7 @@ def forward(params: Dict[str, jax.Array], tokens: jax.Array, cfg: Config,
             stats.append(s)
         with jax.named_scope("head_loss"):
             h = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
-            logits = _matmul(h, params["head"], jnp.dtype(cfg.compute_dtype), jnp.float32)
+            logits = matmul(h, params["head"], jnp.dtype(cfg.compute_dtype), jnp.float32)
     return logits, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
 
 
@@ -387,7 +387,7 @@ class Keye(nn.Module):
             idx, routing = stats["expert_idx"], c.routing
             passes.value = passes.value + held_passes(idx, routing)
             row_tiles.value = row_tiles.value + held_row_tiles(idx, routing)
-            held_share.value = (_pairs_on_held(idx, routing).astype(jnp.float32)
+            held_share.value = (pairs_on_held(idx, routing).astype(jnp.float32)
                                 / (idx.shape[1] * idx.shape[2]))
             tie_rows.value = tie_rows.value + stats["tie_rows"]
             for name, variable in last_step.items():

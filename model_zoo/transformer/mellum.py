@@ -90,7 +90,7 @@ from elasticdl_tpu.ops import moe as moe_ops
 from elasticdl_tpu.ops import pallas_attention
 from elasticdl_tpu.ops.attention import full_attention
 from model_zoo.transformer.nemotron_h import (
-    _matmul, _pairs_on_held, held_passes, held_row_tiles)
+    matmul, pairs_on_held, held_passes, held_row_tiles)
 from model_zoo.transformer.olmoe import (  # noqa: F401
     batch_partition, eval_metrics_fn, optimizer, rmsnorm)
 from model_zoo.transformer.transformer_lm import dataset_fn  # noqa: F401
@@ -216,15 +216,15 @@ def attention(p: Dict[str, jax.Array], x: jax.Array, table, window, cfg: Config)
     heads, kv_heads, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     h = rmsnorm(x, p["attn_norm"], cfg.rms_norm_eps)
     with jax.named_scope("qkv"):
-        q = _matmul(h, p["wq"], dt, jnp.float32).reshape(b, t, heads, d)
-        k = _matmul(h, p["wk"], dt, jnp.float32).reshape(b, t, kv_heads, d)
-        v = _matmul(h, p["wv"], dt).reshape(b, t, kv_heads, d)
+        q = matmul(h, p["wq"], dt, jnp.float32).reshape(b, t, heads, d)
+        k = matmul(h, p["wk"], dt, jnp.float32).reshape(b, t, kv_heads, d)
+        v = matmul(h, p["wv"], dt).reshape(b, t, kv_heads, d)
     with jax.named_scope("rope"):
         q, k = rotate(q, table).astype(dt), rotate(k, table).astype(dt)
     with jax.named_scope("attn"):
         out = full_attention(q, k, v, causal=True, window=window)
     with jax.named_scope("out"):
-        return _matmul(out.reshape(b, t, heads * d), p["wo"], dt, jnp.float32)
+        return matmul(out.reshape(b, t, heads * d), p["wo"], dt, jnp.float32)
 
 
 def route(p: Dict[str, jax.Array], x: jax.Array, cfg: Config):
@@ -288,7 +288,7 @@ def forward(params: Dict[str, jax.Array], tokens: jax.Array, cfg: Config):
             stats.append(s)
         with jax.named_scope("head_loss"):
             h = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
-            logits = _matmul(h, params["head"], jnp.dtype(cfg.compute_dtype), jnp.float32)
+            logits = matmul(h, params["head"], jnp.dtype(cfg.compute_dtype), jnp.float32)
     return logits, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
 
 
@@ -380,7 +380,7 @@ class Mellum(nn.Module):
             idx, routing = stats["expert_idx"], c.routing
             passes.value = passes.value + held_passes(idx, routing)
             row_tiles.value = row_tiles.value + held_row_tiles(idx, routing)
-            held_share.value = (_pairs_on_held(idx, routing).astype(jnp.float32)
+            held_share.value = (pairs_on_held(idx, routing).astype(jnp.float32)
                                 / (idx.shape[1] * idx.shape[2]))
             banded, causal = kv_block_visits(c, features.shape[1])
             visits.value = visits.value + jnp.asarray(banded, jnp.int32)

@@ -157,7 +157,7 @@ class Config:
 # The mathematics: pure functions of (parameters, activations)
 
 
-def _matmul(x, w, dt, out=None):
+def matmul(x, w, dt, out=None):
     """x·w with `dt` operands; the MXU accumulates in float32, `out` is the
     dtype the product is written in (default: `dt`)."""
     return jnp.dot(x.astype(dt), w.astype(dt), preferred_element_type=out or dt)
@@ -171,7 +171,7 @@ def mamba(p: Dict[str, jax.Array], x: jax.Array, cfg: Config) -> jax.Array:
                        cfg.ssm_state_size)
     h = rmsnorm(x, p["mamba_norm"], cfg.layer_norm_epsilon)
     with jax.named_scope("in_proj"):
-        zxbcdt = _matmul(h, p["mamba_in_proj"], dt_c, jnp.float32)
+        zxbcdt = matmul(h, p["mamba_in_proj"], dt_c, jnp.float32)
         z, xbc, dt = jnp.split(
             zxbcdt, [cfg.d_inner, cfg.d_inner + cfg.conv_dim], axis=-1)
     with jax.named_scope("conv"):
@@ -188,7 +188,7 @@ def mamba(p: Dict[str, jax.Array], x: jax.Array, cfg: Config) -> jax.Array:
         y = ssm.gated_group_rmsnorm(y, z, p["mamba_gate_norm"], g,
                                     cfg.layer_norm_epsilon)
     with jax.named_scope("out_proj"):
-        return _matmul(y, p["mamba_out_proj"], dt_c, jnp.float32)
+        return matmul(y, p["mamba_out_proj"], dt_c, jnp.float32)
 
 
 def attention(p: Dict[str, jax.Array], x: jax.Array, cfg: Config) -> jax.Array:
@@ -196,12 +196,12 @@ def attention(p: Dict[str, jax.Array], x: jax.Array, cfg: Config) -> jax.Array:
     dt = jnp.dtype(cfg.compute_dtype)
     b, t, _ = x.shape
     h = rmsnorm(x, p["attn_norm"], cfg.layer_norm_epsilon)
-    q = _matmul(h, p["attn_wq"], dt).reshape(b, t, cfg.num_attention_heads, cfg.head_dim)
+    q = matmul(h, p["attn_wq"], dt).reshape(b, t, cfg.num_attention_heads, cfg.head_dim)
     kv = (b, t, cfg.num_key_value_heads, cfg.head_dim)
-    k = _matmul(h, p["attn_wk"], dt).reshape(kv)
-    v = _matmul(h, p["attn_wv"], dt).reshape(kv)
+    k = matmul(h, p["attn_wk"], dt).reshape(kv)
+    v = matmul(h, p["attn_wv"], dt).reshape(kv)
     out = full_attention(q, k, v, causal=True)
-    return _matmul(out.reshape(b, t, -1), p["attn_wo"], dt, jnp.float32)
+    return matmul(out.reshape(b, t, -1), p["attn_wo"], dt, jnp.float32)
 
 
 def route(p: Dict[str, jax.Array], x: jax.Array, bias: jax.Array, cfg: Config):
@@ -218,8 +218,8 @@ def route(p: Dict[str, jax.Array], x: jax.Array, bias: jax.Array, cfg: Config):
 def relu2_expert(h, w_up, w_down, dt):
     """`W_down relu(W_up h)²`, the body of every expert, on all rows of h;
     float32 out."""
-    up = _matmul(h, w_up, dt, jnp.float32)
-    return _matmul(jnp.square(jax.nn.relu(up)), w_down, dt, jnp.float32)
+    up = matmul(h, w_up, dt, jnp.float32)
+    return matmul(jnp.square(jax.nn.relu(up)), w_down, dt, jnp.float32)
 
 
 def moe(p: Dict[str, jax.Array], x: jax.Array, bias: jax.Array, cfg: Config):
@@ -274,8 +274,8 @@ def forward(params: Dict[str, jax.Array], bias: jax.Array, tokens: jax.Array,
                 x = x + y
         with jax.named_scope("head_loss"):
             h = rmsnorm(x, params["final_norm"], cfg.layer_norm_epsilon)
-            logits = _matmul(h, params["head"], jnp.dtype(cfg.compute_dtype),
-                             jnp.float32)
+            logits = matmul(h, params["head"], jnp.dtype(cfg.compute_dtype),
+                            jnp.float32)
     return logits, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
 
 
@@ -297,7 +297,8 @@ def updated_bias(bias, expert_idx, cfg: Config):
     return bias + cfg.bias_update_speed * jnp.sign(mean - load)
 
 
-def _pairs_on_held(expert_idx, cfg: Config):
+def pairs_on_held(expert_idx, cfg: Config):
+    """(E layers,) int32: the (token, slot) pairs that fell on held experts."""
     first, count = cfg.held
     return jnp.sum((expert_idx >= first) & (expert_idx < first + count),
                    axis=(1, 2), dtype=jnp.int32)
@@ -312,7 +313,7 @@ def held_passes(expert_idx, cfg: Config):
         return jnp.zeros(expert_idx.shape[0], jnp.int32)
     pairs = expert_idx.shape[1] * expert_idx.shape[2]
     rows = moe_ops.held_pass_rows(pairs, cfg.num_experts, count)
-    return -(-_pairs_on_held(expert_idx, cfg) // rows)
+    return -(-pairs_on_held(expert_idx, cfg) // rows)
 
 
 def held_row_tiles(expert_idx, cfg: Config):
@@ -323,7 +324,7 @@ def held_row_tiles(expert_idx, cfg: Config):
     if cfg.held[1] == cfg.num_experts:
         return jnp.zeros(expert_idx.shape[0], jnp.int32)
     return moe_ops.held_row_tiles(
-        _pairs_on_held(expert_idx, cfg), expert_idx.shape[1] * expert_idx.shape[2],
+        pairs_on_held(expert_idx, cfg), expert_idx.shape[1] * expert_idx.shape[2],
         cfg.num_experts, cfg.held[1])
 
 

@@ -189,3 +189,36 @@ def test_local_keye_vl2_job_end_to_end(tmp_path):
     # ln 256 = 5.5, two layers' index losses (each well under one at the
     # seed) and their load-balance terms at 0.001 each
     assert master.servicer.mean_training_loss() < 7.0
+
+
+def test_local_afmoe_job_end_to_end(tmp_path):
+    """Trinity's block (attention gated on its output, q/k head norms, rotary
+    positions in the sliding layers only, four norms a layer; published layers
+    0, 2, 3 at a period of 2: a dense sliding layer, a sparse sliding one, a
+    sparse full one; a held share of sigmoid-routed experts with a centred
+    selection bias, a shared expert) through the same master/worker path,
+    evaluation — the gates' means among its metrics — included."""
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.afmoe.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 3,
+            "kept_layers": "0,2,3", "num_dense_layers": 2, "global_attn_every_n_layers": 2,
+            "intermediate_size": 96, "num_attention_heads": 4, "num_key_value_heads": 2,
+            "head_dim": 16, "sliding_window": 8, "num_experts": 4, "router_experts": 16,
+            "first_expert": 4, "num_experts_per_tok": 3, "moe_intermediate_size": 24,
+            "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+    )
+    master, _, counts = run_job(cfg, tmp_path)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    results = master.evaluation.latest_results()
+    assert 0.0 <= results["token_accuracy"] <= 1.0
+    assert abs(results["gate_mean_sliding"] - 0.5) < 0.1 and abs(results["gate_mean_full"] - 0.5) < 0.1
+    assert master.servicer.mean_training_loss() < 6.0       # ln 256 = 5.5, no auxiliary term

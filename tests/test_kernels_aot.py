@@ -39,6 +39,10 @@ SHAPES = {
     # experts' even share of 32 768 pairs)
     "glm_up": (8192, 2048, 1536, 8),
     "glm_down": (8192, 1536, 2048, 8),
+    # trinity-mini.resident-16k: 16 of 128 held at OLMoE's expert shape, a pass
+    # of 32 768 rows (twice the even share of 131 072 pairs)
+    "trinity_up": (32768, 2048, 1024, 16),
+    "trinity_down": (32768, 1024, 2048, 16),
 }
 
 
@@ -97,6 +101,9 @@ FLASH = {
     "glm-4.7-flash.resident-8k": ((1, 8192, 20, 20, 256, None), (1024, 512), "resident"),
     "mellum2-12b-a2.5b.resident-16k/full": ((1, 16384, 32, 4, 128, None), (1024, 1024), "resident"),
     "mellum2-12b-a2.5b.resident-16k/sliding": ((1, 16384, 32, 4, 128, 1024), (1024, 1024), "resident"),
+    # three key blocks a query block where Mellum2's window of 1024 has two;
+    # its full layer is Mellum2's (unrotated q and k are no other shape)
+    "trinity-mini.resident-16k/sliding": ((1, 16384, 32, 4, 128, 2048), (1024, 1024), "resident"),
     "32k_keys": ((1, 32768, 8, 2, 128, None), (1024, 1024), "split"),
     "32k_keys/sliding": ((1, 32768, 8, 2, 128, 1024), (1024, 1024), "split"),
 }

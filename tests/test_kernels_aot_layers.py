@@ -116,3 +116,45 @@ def test_a_recomputed_layer_runs_the_flash_forward_once_under_its_scope(
     found = scope_map(text, common.load_module("flops", module).SCOPES)
     assert sorted((kind, found.get(name)) for kind, name in zip(kinds, calls)) == sorted(
         (prefix + part, scope) for scope, prefix in blocks for part in ("fwd", "bwd"))
+
+
+def test_trinity_s_layers_keep_the_full_layer_s_residuals_and_not_the_sliding_one_s(
+        one_chip, no_compile_cache, monkeypatch):
+    """trinity-mini.resident-16k (`afmoe.KEEP_RESIDUALS_KINDS`: the full layer
+    alone — all five layers' do not fit beside 705M parameters' state):
+    published layers 2 and 3, a sliding and a full one at 16 384 tokens, window
+    2048, 32/4 heads of 128 and 16 of 128 experts held. The sliding layer's
+    recomputation runs its banded forward kernel again, the full layer's does
+    not, every kernel under its kind's `attn` scope — and no `rope` scope
+    under `afmoe/full`."""
+    from benchmark import common
+    from model_zoo.transformer import afmoe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+    assert afmoe.KEEP_RESIDUALS_KINDS == ("full",)
+    net = afmoe.custom_model(num_hidden_layers=2, kept_layers="2,3", num_experts=16,
+                             router_experts=128, vocab_size=512)
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    variables = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
+
+    def loss(params, state, tokens):
+        return jnp.sum(jnp.square(net.apply({"params": params, **state}, tokens)["logits"]))
+
+    params = variables.pop("params")
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
+    calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
+    kinds = [re.sub(r"\.\d+$", "", name) for name in calls]
+    scope_map = common.load_module("drivers", "resident_lm_share").scope_map
+    found = scope_map(text, common.load_module("flops", "afmoe").SCOPES)
+    assert sorted((kind, found.get(name)) for kind, name in zip(kinds, calls)) == sorted(
+        [("flash_attention_swa_fwd", "afmoe/sliding/attn")] * 2
+        + [("flash_attention_swa_bwd", "afmoe/sliding/attn"),
+           ("flash_attention_fwd", "afmoe/full/attn"),
+           ("flash_attention_bwd", "afmoe/full/attn")])
+    scopes = set(found.values())
+    assert "afmoe/sliding/rope" in scopes and "afmoe/full/qk_norm" in scopes
+    assert {"afmoe/sliding/gate", "afmoe/full/gate", "afmoe/moe/experts"} <= scopes
+    assert not any(s.startswith("afmoe/full/rope") for s in scopes)
+    assert not re.search(r'op_name="[^"]*full/rope', text)

@@ -116,6 +116,9 @@ def _kernel_grids(jaxpr):
                       "flash_attention_swa_bwd": (1, 2, 24)}),
     ("resident", 40, {"flash_attention_swa_fwd": (1, 8, 6, 4),
                       "flash_attention_swa_bwd": (1, 2, 24)}),
+    # W = two blocks (trinity-mini's 2048 over 1024-blocks): 3 kv blocks a q block
+    ("resident", 32, {"flash_attention_swa_fwd": (1, 8, 6, 3),
+                      "flash_attention_swa_bwd": (1, 2, 24)}),
     ("split", None, {"flash_attention_fwd": (1, 8, 6, 6), "flash_attention_bwd_dq": (1, 8, 6, 6),
                      "flash_attention_bwd_dkv": (1, 2, 6, 24)}),
     # W = a block: 2 kv blocks a q block, 2 q blocks a kv block (x 4 heads a group)
@@ -140,6 +143,13 @@ def test_the_grid_is_banded_under_a_window_and_as_it_was_without(route, window, 
     (16384, 1024, (1024, 1024), (31, 136)),      # the benchmark's cell, 1024-blocks
     (16384, 1024, (1024, 512), (62, 272)),     # 4 kv blocks of 512 a q block, not 3
     (16384, None, (1024, 1024), (136, 136)),
+    # trinity-mini.resident-16k: W = two blocks, three kv blocks a q block
+    # (one edge half masked, one whole, the diagonal), 67% of the pairs visible
+    (16384, 2048, (1024, 1024), (45, 136)),
+    (16384, 2048, (1024, 512), (90, 272)),
+    (16384, 2047, (1024, 1024), (45, 136)),      # one key fewer: the same blocks
+    (16384, 2049, (1024, 1024), (45, 136)),      # one key more: the third block's first key
+    (16384, 2050, (1024, 1024), (58, 136)),      # two more: a fourth block's corner
     (96, 16, (16, 16), (11, 21)),
     (100, 16, (16, 16), (0, 0)),                 # cannot be blocked
 ])
