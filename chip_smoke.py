@@ -24,9 +24,14 @@ child, not a CPU run).
               attention agrees with the XLA attention; transformer_lm steps.
   C  four chips (only when four are visible; otherwise reported as not
               run): the job of A — 8 steps, each costs four chips — on
-              data=4 and on data=2,model=2, and the table's shards on four
-              distinct devices. Several worker processes on one host are
-              reported as not brought up (ROADMAP A3), not attempted.
+              data=4 and on data=2,model=2, the table's shards on four
+              distinct devices, and the manual lookup on data=4 against
+              numpy on BOTH schedules: ids every shard owns its share of
+              (ids and rows exchanged all-to-all) and ids one shard owns too
+              many of (the overflow branch, the gathered schedule), the
+              placement kernel under `shard_map` in each. Several worker
+              processes on one host are reported as not brought up (ROADMAP
+              A3), not attempted.
 
 Logs land in chiprun_out/chip_smoke/; checkpoints go to a temporary
 directory that is removed on exit.
@@ -270,6 +275,7 @@ def main(argv=None):
                 "phaseC_data2_model2", FULL_C, "tpu", work_dir, left(),
                 mesh_shape="data=2,model=2", data_shards=2))
             passed("C shards", _phase_child("phaseC_shards", "shards", left()))
+            passed("C lookup", _phase_child("phaseC_lookup", "lookup", left()))
             not_run("C processes",
                     "NOT BROUGHT UP: several worker processes on one host "
                     "(--num_processes N) have not trained on chips; see "
@@ -295,7 +301,7 @@ def main(argv=None):
 
 def _child_main(phase):
     result = {"probe": probe_devices, "kernels": check_kernels,
-              "shards": check_shards}[phase](FULL)
+              "shards": check_shards, "lookup": check_lookup}[phase](FULL)
     print(json.dumps(result), flush=True)
     return 0
 
@@ -521,6 +527,66 @@ def check_shards(size):
     if not all(in_use.values()):
         raise RuntimeError(f"a device holds nothing: {in_use}")
     return {"shard_shape": list(shapes[0]), "bytes_in_use": in_use}
+
+
+def check_lookup(size):
+    """Phase C: rows and table gradient of the manual lookup on data=4
+    against numpy, once on each of its schedules (ops/embedding.py): ids
+    spread over the shards, which are exchanged all-to-all, and the same
+    ids with the first device's all owned by shard 0, more than a bucket
+    holds, which take the overflow branch. Which branch a step takes is
+    `fullest > cap` by the code's own rule, recomputed here; the CPU tests
+    tie the rule to the branch that runs."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from elasticdl_tpu.common.runtime import configure_jax_runtime
+    from elasticdl_tpu.ops import embedding
+    from elasticdl_tpu.parallel.mesh import build_mesh
+
+    configure_jax_runtime()
+    mesh = build_mesh({"data": 4}, jax.devices()[:4])
+    rows = embedding.padded_vocab(26 * size["field_vocab"])
+    dim, batch = 17, 4 * size["batch"]
+    cap = embedding.route_cap(size["batch"] * 26, 4)
+    rng = np.random.default_rng(0)
+    table_np = rng.standard_normal((rows, dim), dtype=np.float32)
+    w_np = rng.standard_normal((batch, 26, dim), dtype=np.float32)
+    spread = rng.integers(0, rows, (batch, 26), dtype=np.int32)
+    piled = spread.copy()
+    piled[:size["batch"]] %= rows // 4
+
+    def fn(table, ids, w):
+        def total(t):
+            return jnp.sum(embedding.embedding_lookup(t, ids) * w)
+
+        return embedding.embedding_lookup(table, ids), jax.grad(total)(table)
+
+    result = {"cap": cap}
+    with jax.set_mesh(mesh):
+        by_data = NamedSharding(mesh, P("data"))
+        table = jax.device_put(table_np, by_data)
+        w = jax.device_put(w_np, by_data)
+        step = jax.jit(fn)
+        for name, ids_np in (("routed", spread), ("overflow", piled)):
+            fullest = max(int(np.bincount(src // (rows // 4)).max())
+                          for src in ids_np.reshape(4, -1))
+            if (fullest > cap) != (name == "overflow"):
+                raise RuntimeError(
+                    f"{name}: fullest bucket {fullest}, cap {cap}")
+            got, grad = step(table, jax.device_put(ids_np, by_data), w)
+            if not np.array_equal(np.asarray(got), table_np[ids_np]):
+                raise RuntimeError(f"{name}: looked-up rows differ")
+            want = np.zeros_like(table_np)
+            np.add.at(want, ids_np.reshape(-1), w_np.reshape(-1, dim))
+            err = _scaled_err(grad, want)
+            if err > PLACEMENT_TOL:
+                raise RuntimeError(
+                    f"{name}: table gradient err {err:.3g} > {PLACEMENT_TOL}")
+            result[name] = {"fullest": fullest, "grad_err": err}
+    return result
 
 
 if __name__ == "__main__":

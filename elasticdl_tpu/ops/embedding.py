@@ -11,13 +11,32 @@ Rebuilt TPU-native: the table is ONE `jax.Array` whose rows are sharded
 contiguously over every mesh axis. Lookup and gradient scatter-add happen
 *inside* the jitted train step, so "pull" and "push" become ICI collectives:
 
-  manual mode (shard_map):
-    all_gather(ids over data axis)         # tiny int32 traffic
-    local dense gather on each row shard   # MXU-friendly, static shapes
-    psum_scatter(partials over data axis)  # returns each device its batch rows
-    psum(over model axis)                  # combine row-shard contributions
-  backward is the exact transpose (autodiff through shard_map): all_gather of
-  output grads + local scatter-add into the row shard.
+  manual mode (shard_map), two schedules; `owner_route` picks one from the
+  ambient mesh at trace time:
+
+    routed — the table's rows are sharded over the data axis alone:
+      bucket the LOCAL ids by the shard that owns them  # one sort, no scatter
+      all_to_all(ids over data axis)                    # (shards, cap) int32
+      local gather of the ids a shard OWNS              # shards x cap rows
+      all_to_all(rows back over data axis)              # (shards, cap, D)
+      un-bucket to batch order                          # a gather of B/d x L rows
+    A bucket holds `cap` ids (`route_cap`: ROUTE_SLACK x the even share,
+    static). A step in which any (source, owner) pair overflows it — agreed
+    by a pmax over the data axis — takes the gathered schedule under a
+    `lax.cond`: nothing is ever dropped, skewed ownership only runs slower.
+
+    gathered — rows sharded over further axes too (ids are replicated over
+    those, so an exchange over `data` would not reach every row shard), and
+    the routed schedule's overflow branch:
+      all_gather(ids over data axis)         # tiny int32 traffic
+      local gather of ALL ids, the non-owned ones masked to zero rows
+      psum_scatter(partials over data axis)  # returns each device its batch rows
+      psum(over model axis)                  # combine row-shard contributions
+
+  backward is the exact transpose (autodiff through shard_map): the cotangent
+  rows go back the way the rows came — into the buckets by a gather, never a
+  scatter-add of a row at a time — and `gather_rows`' backward sums them into
+  the row shard.
 
   auto mode: `jnp.take` on the sharded table; XLA's SPMD partitioner inserts
   an equivalent collective schedule. Kept as the fallback/baseline; `manual`
@@ -562,6 +581,115 @@ def table_partition_axes(axes: Optional[Sequence[str]] = None) -> Tuple[str, ...
     return ambient_axes()
 
 
+# The routed lookup's buckets hold this many times a shard's even share of
+# a source's ids. Hashed fields over contiguous rows put 1/n of every
+# sample's ids on each of n shards, give or take the field a shard boundary
+# cuts: 26 fields over 4 shards is 6.5 fields a shard, so 7/26 = 1.08 x the
+# share at the fullest. 1.5 leaves that room and still gathers 0.375 of the
+# global batch a shard where the gathered schedule gathers all of it; what
+# overflows takes the gathered schedule for that step.
+ROUTE_SLACK = 1.5
+
+
+def route_cap(n_local: int, n_shards: int) -> int:
+    """Ids a (source, owner) bucket of the routed lookup holds: ROUTE_SLACK x
+    the even share of a source's `n_local` ids, in whole 512s. Static."""
+    return -(-math.ceil(ROUTE_SLACK * n_local / n_shards) // 512) * 512
+
+
+def owner_route(n_local: int, n_shards: int,
+                other_axes: Sequence[str]) -> str:
+    """Which schedule the manual lookup of `n_local` ids a device takes on a
+    mesh of `n_shards` row shards, `other_axes` of them not the data axis:
+    "auto" (one device: nothing to exchange), "gathered" (rows sharded over
+    more than the data axis) or "routed" (module docstring). A pure function
+    of what the trace can see."""
+    if n_shards == 1:
+        # a 1-device mesh has nothing to shard: the shard_map schedule
+        # only adds manual-axes bookkeeping around the same local
+        # gather/scatter (measured round 5: ~8 ms/step of pure
+        # overhead in the DeepFM backward) — route to auto
+        return "auto"
+    route = "gathered" if other_axes else "routed"
+    # trace-time, once per compiled program: which schedule this mesh took
+    logger.info(
+        "embedding lookup (%d local ids over %d row shards, axes beside "
+        "data: %s) takes the %s schedule, cap %s", n_local, n_shards,
+        tuple(other_axes), route,
+        route_cap(n_local, n_shards) if route == "routed" else "n/a")
+    return route
+
+
+def _owner_plan(flat, rows_per_shard, n_shards, cap):
+    """Where each of a device's ids goes in its `(n_shards, cap)` send
+    buffer. flat: (n,) int32 global ids, the out-of-range ones beyond every
+    shard. Returns
+      sf      (n,)  the ids ascending — so by owner: rows are contiguous;
+      order   (n,)  their positions in `flat` (a stable sort);
+      starts  (n_shards,) where owner o's run starts in `sf`;
+      counts  (n_shards,) how long it is;
+      slot    (n,)  position p's slot o * cap + j in the buffer, j its rank
+                    in o's run; `n_shards * cap` for an id no shard owns.
+    Counting is a compare and a sum and the inverse arrangement a second
+    sort (`ops/moe.py`'s idiom): a TPU scatters one element at a time."""
+    n = flat.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    sf, order = jax.lax.sort((flat, pos), is_stable=True, num_keys=1)
+    bounds = jnp.arange(n_shards + 1, dtype=jnp.int32) * rows_per_shard
+    past = bounds[:, None] <= sf[None, :]              # (n_shards + 1, n)
+    first = n - jnp.sum(past, axis=1, dtype=jnp.int32)
+    starts, counts = first[:-1], first[1:] - first[:-1]
+    # slot of the i-th sorted id: i + (o * cap - starts[o]) for its owner o
+    shift = jnp.arange(n_shards, dtype=jnp.int32) * cap - starts
+    mine = past[:-1] & ~past[1:]                       # (n_shards, n) one-hot
+    slot_sorted = jnp.where(
+        past[-1], n_shards * cap,
+        pos + jnp.sum(jnp.where(mine, shift[:, None], 0), axis=0))
+    _, slot = jax.lax.sort((order, slot_sorted), is_stable=False, num_keys=1)
+    return sf, order, starts, counts, slot
+
+
+def _owner_buckets(x_sorted, starts, counts, cap, fill):
+    """x_sorted (n, ...) in `_owner_plan`'s order -> (n_shards, cap, ...):
+    bucket o holds owner o's run, then `fill`. Contiguous slices of the
+    sorted rows, no gather."""
+    tail = (1,) * (x_sorted.ndim - 1)
+    x = jnp.concatenate([
+        x_sorted, jnp.full((cap,) + x_sorted.shape[1:], fill, x_sorted.dtype)])
+    live = jnp.arange(cap, dtype=jnp.int32)[None, :] < counts[:, None]
+    return jnp.stack([
+        jnp.where(live[o].reshape((cap,) + tail),
+                  jax.lax.dynamic_slice_in_dim(x, starts[o], cap), fill)
+        for o in range(starts.shape[0])])
+
+
+@jax.custom_vjp
+def _unbucket(buf, slot, order, starts, counts):
+    """buf (n_shards, cap, D), the rows of each owner's bucket -> (n, D) in
+    batch order: row p is slot[p] of the buffer, zeros where p has no slot."""
+    rows = buf.reshape(-1, buf.shape[-1])
+    out = rows.at[jnp.minimum(slot, rows.shape[0] - 1)].get(
+        mode="promise_in_bounds")
+    return jnp.where((slot < rows.shape[0])[:, None], out, 0)
+
+
+def _unbucket_fwd(buf, slot, order, starts, counts):
+    return _unbucket(buf, slot, order, starts, counts), (
+        order, starts, counts, buf.shape[1])
+
+
+def _unbucket_bwd(res, g):
+    # the transpose of a gather is a scatter-add; every position takes one
+    # slot of its own, so it is also the gather into the plan's sorted order
+    # cut into the owners' runs — which a TPU does at memory speed
+    order, starts, counts, cap = res
+    g_sorted = g.at[order].get(mode="promise_in_bounds")
+    return _owner_buckets(g_sorted, starts, counts, cap, 0), None, None, None, None
+
+
+_unbucket.defvjp(_unbucket_fwd, _unbucket_bwd)
+
+
 def embedding_lookup(
     table: jax.Array,
     ids: jax.Array,
@@ -587,77 +715,104 @@ def embedding_lookup(
     oob = jnp.iinfo(jnp.int32).max // 2
     safe_ids = jnp.where(in_range, ids, oob).astype(jnp.int32)
 
-    if mode == "manual" and axes:
-        mesh_ = jax.sharding.get_abstract_mesh()
-        if int(np.prod([mesh_.shape[a] for a in axes])) == 1:
-            # a 1-device mesh has nothing to shard: the shard_map schedule
-            # only adds manual-axes bookkeeping around the same local
-            # gather/scatter (measured round 5: ~8 ms/step of pure
-            # overhead in the DeepFM backward) — route to auto
-            mode = "auto"
-
-    if mode == "auto" or not axes:
-        out = gather_rows(table, safe_ids)
-        return jnp.where(in_range[..., None], out, 0.0)
-
-    if mode != "manual":
+    if mode not in ("manual", "auto"):
         raise ValueError(f"unknown embedding lookup mode {mode!r}")
 
-    data_ax = MeshAxis.DATA if MeshAxis.DATA in axes else axes[0]
-    other_axes = tuple(a for a in axes if a != data_ax)
-    mesh = jax.sharding.get_abstract_mesh()
-    n_shards = 1
-    for a in axes:
-        n_shards *= mesh.shape[a]
-    if table.shape[0] % n_shards:
-        # The table's padded vocab is fixed at creation time (and baked into
-        # checkpoints), but dynamic world resizing can re-form the mesh with
-        # a shard count that doesn't divide it (e.g. 1792 rows over 6
-        # devices). shard_map needs even shards; XLA's auto partitioner does
-        # not — fall back to the auto schedule for this (rare) geometry.
-        logger.warning(
-            "table rows (%d) not divisible by %d shards; using auto-sharded "
-            "lookup for this mesh (align the vocab via padded_vocab for the "
-            "manual schedule)", table.shape[0], n_shards,
-        )
+    route = "auto"
+    if mode == "manual" and axes:
+        mesh = jax.sharding.get_abstract_mesh()
+        data_ax = MeshAxis.DATA if MeshAxis.DATA in axes else axes[0]
+        other_axes = tuple(a for a in axes if a != data_ax)
+        n_shards = int(np.prod([mesh.shape[a] for a in axes]))
+        n_local = safe_ids.size // mesh.shape[data_ax]
+        route = owner_route(n_local, n_shards, other_axes)
+        if route != "auto" and table.shape[0] % n_shards:
+            # The table's padded vocab is fixed at creation time (and baked
+            # into checkpoints), but dynamic world resizing can re-form the
+            # mesh with a shard count that doesn't divide it (e.g. 1792 rows
+            # over 6 devices). shard_map needs even shards; XLA's auto
+            # partitioner does not — fall back to the auto schedule for this
+            # (rare) geometry.
+            logger.warning(
+                "table rows (%d) not divisible by %d shards; using "
+                "auto-sharded lookup for this mesh (align the vocab via "
+                "padded_vocab for the manual schedule)",
+                table.shape[0], n_shards,
+            )
+            route = "auto"
+
+    if route == "auto":
         out = gather_rows(table, safe_ids)
         return jnp.where(in_range[..., None], out, 0.0)
 
     ids2d = safe_ids.reshape(safe_ids.shape[0], -1)  # (B, L)
+    rows_per_shard = table.shape[0] // n_shards
+    # Ids a shard does not own map OUT of its range (not to row 0): the
+    # forward clamps/masks them either way, but the backward's tiled
+    # scatter sorts the raw ids — a row-0 pile of every non-owned id
+    # (up to (n_shards-1)/n_shards of the batch) would overflow tile
+    # 0's window and trip the lax.cond flat fallback EVERY step,
+    # silently making the sorted routes slower than the flat scatter
+    # on exactly the multi-chip manual path (code-review r5 pt3).
+    # 2x the shard size specifically: the tiled backward's padded
+    # vocab is < 1.5x num_rows (tile_rows < num_rows/2 on that path),
+    # so 2x sits beyond the last searchsorted edge and the sentinels
+    # count toward NO tile's window population; every route drops
+    # out-of-range cotangent rows.
+    sentinel = jnp.int32(2 * rows_per_shard)
 
-    def shard_fn(table_shard, ids_local):
+    def owned_rows(table_shard, global_ids):
+        """The rows of `global_ids` this shard owns, zeros for the rest."""
+        local = global_ids - jax.lax.axis_index(axes) * rows_per_shard
+        owned = (local >= 0) & (local < rows_per_shard)
+        return jnp.where(
+            owned[..., None],
+            gather_rows(table_shard, jnp.where(owned, local, sentinel)), 0.0)
+
+    def gathered(table_shard, ids_local):
         # table_shard: (V/n, D); ids_local: (B/d, L)
         all_ids = jax.lax.all_gather(ids_local, data_ax, tiled=True)  # (B, L)
-        shard = jax.lax.axis_index(axes)  # linear index over all axes, row-major
-        offset = shard * table_shard.shape[0]
-        local = all_ids - offset
-        owned = (local >= 0) & (local < table_shard.shape[0])
-        # Non-owned ids map OUT of the shard's range (not to row 0): the
-        # forward clamps/masks them either way, but the backward's tiled
-        # scatter sorts the raw ids — a row-0 pile of every non-owned id
-        # (up to (n_shards-1)/n_shards of the batch) would overflow tile
-        # 0's window and trip the lax.cond flat fallback EVERY step,
-        # silently making the sorted routes slower than the flat scatter
-        # on exactly the multi-chip manual path (code-review r5 pt3).
-        # 2x the shard size specifically: the tiled backward's padded
-        # vocab is < 1.5x num_rows (tile_rows < num_rows/2 on that path),
-        # so 2x sits beyond the last searchsorted edge and the sentinels
-        # count toward NO tile's window population; every route drops
-        # out-of-range cotangent rows.
-        sentinel = jnp.int32(2 * table_shard.shape[0])
-        part = jnp.where(
-            owned[..., None],
-            gather_rows(table_shard, jnp.where(owned, local, sentinel)), 0.0
-        )  # (B, L, D)
         out = jax.lax.psum_scatter(
-            part, data_ax, scatter_dimension=0, tiled=True
-        )  # (B/d, L, D)
+            owned_rows(table_shard, all_ids), data_ax,
+            scatter_dimension=0, tiled=True)  # (B/d, L, D)
         if other_axes:
             out = jax.lax.psum(out, other_axes)
         return out
 
+    def routed(table_shard, ids_local, sf, order, starts, counts, slot):
+        with jax.named_scope("emb/route/bucket"):
+            # an empty slot holds `oob`, which no shard owns
+            send = _owner_buckets(sf, starts, counts, cap, oob)
+        with jax.named_scope("emb/route/exchange"):
+            asked = jax.lax.all_to_all(send, data_ax, 0, 0)  # (n_shards, cap)
+        with jax.named_scope("emb/route/gather"):
+            rows = owned_rows(table_shard, asked)       # (n_shards, cap, D)
+        with jax.named_scope("emb/route/exchange"):
+            rows = jax.lax.all_to_all(rows, data_ax, 0, 0)
+        with jax.named_scope("emb/route/unbucket"):
+            out = _unbucket(rows, slot, order, starts, counts)
+        return out.reshape(*ids_local.shape, table_shard.shape[1])
+
+    def overflow(table_shard, ids_local, *plan):
+        del plan
+        with jax.named_scope("emb/route/overflow"):
+            return gathered(table_shard, ids_local)
+
+    def routed_or_overflow(table_shard, ids_local):
+        with jax.named_scope("emb/route/bucket"):
+            plan = _owner_plan(
+                ids_local.reshape(-1), rows_per_shard, n_shards, cap)
+            counts = plan[3]
+            # every shard takes the same branch: the branches hold
+            # collectives
+            full = jax.lax.pmax(jnp.max(counts), data_ax) > cap
+        return jax.lax.cond(
+            full, overflow, routed, table_shard, ids_local, *plan)
+
+    if route == "routed":
+        cap = route_cap(n_local, n_shards)
     out = jax.shard_map(
-        shard_fn,
+        routed_or_overflow if route == "routed" else gathered,
         in_specs=(P(axes, None), P(data_ax, None)),
         out_specs=P(data_ax, None, None),
     )(table, ids2d)

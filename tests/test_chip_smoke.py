@@ -57,6 +57,11 @@ def test_phases_run_tiny_on_cpu(tmp_path, monkeypatch):
     monkeypatch.setattr(chip_smoke, "_bytes_in_use", lambda device: 1)
     assert chip_smoke.check_shards(tiny)["shard_shape"] == [13312 // 4, 17]
 
+    # outside interpret mode: the kernel inside shard_map never returns here
+    lookup = chip_smoke.check_lookup(tiny)
+    assert lookup["routed"]["fullest"] <= lookup["cap"] < (
+        lookup["overflow"]["fullest"])
+
 
 def test_interpret_mode_on_a_tpu_backend_is_an_error(monkeypatch):
     import jax.numpy as jnp

@@ -3,9 +3,10 @@ paths on the 8-device virtual mesh and assert the COLLECTIVES in the
 optimized HLO move only small buffers.
 
 This pins the framework's scaling claims the same way a numerics test pins
-correctness: the docstring schedules (ops/embedding.py: "all_gather ids →
-local gather → psum_scatter"; ops/attention.py ring: "KV blocks rotate via
-ppermute") are only worth anything if a refactor can't silently regress
+correctness: the docstring schedules (ops/embedding.py: "all_to_all ids →
+gather of the owned ids → all_to_all rows" on a data-only mesh, "all_gather
+ids → local gather → psum_scatter" elsewhere; ops/attention.py ring: "KV
+blocks rotate via ppermute") are only worth anything if a refactor can't silently regress
 into a table-sized all-reduce or a full-sequence all-gather — on a real
 pod that is the difference between ICI-bound scaling and not scaling.
 The reference's analog constraint: PS traffic was per-touched-row pulls and
@@ -83,6 +84,53 @@ def test_manual_embedding_backward_moves_no_table_sized_buffers(mesh8):
     assert biggest * 8 <= table_elems, (biggest, table_elems, sizes)
     # schedule sanity: the tiny ids all-gather is present
     assert any(op.startswith("all-gather") for op, _ in sizes), sizes
+
+
+def test_routed_embedding_exchanges_only_owned_ids_and_rows(mesh8):
+    """fwd+bwd of the manual lookup on a data-only mesh (data=4): ids and
+    rows cross the mesh all-to-all in buckets of `cap` — every collective
+    is <= n_shards * cap * D elements, UNDER the (B, L, D) block the
+    gathered schedule reduces — and a shard's table gather takes
+    n_shards * cap indices, a fraction of the global batch's B * L, which
+    only the overflow branch (`emb/route/overflow`) still gathers."""
+    n_shards = 4
+    mesh = build_mesh({"data": n_shards}, list(mesh8.devices.flat)[:n_shards])
+    V, D, B, L = emb.padded_vocab(4096), 16, 256, 64
+    cap = emb.route_cap(B // n_shards * L, n_shards)
+    assert n_shards * cap < B * L
+    table = jnp.asarray(np.random.RandomState(0).randn(V, D).astype(np.float32))
+    ids = jnp.asarray(
+        np.random.RandomState(1).randint(0, V, (B, L)).astype(np.int32))
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    with jax.set_mesh(mesh):
+        table_s = jax.device_put(table, NamedSharding(mesh, P(("data",), None)))
+        ids_s = jax.device_put(ids, NamedSharding(mesh, P("data", None)))
+        f = jax.jit(jax.grad(
+            lambda t, i: jnp.sum(emb.embedding_lookup(t, i, mode="manual") ** 2)
+        ))
+        txt = f.lower(table_s, ids_s).compile().as_text()
+
+    sizes = collective_sizes(txt)
+    assert any(op.startswith("all-to-all") for op, _ in sizes), sizes
+    # outside the overflow branch nothing is larger than the rows' exchange
+    routed = collective_sizes("\n".join(
+        line for line in txt.splitlines() if "emb/route/overflow" not in line))
+    assert max(n for _, n in routed) <= n_shards * cap * D, routed
+    assert n_shards * cap * D < B * L * D
+    assert max(n for _, n in sizes) <= B * L * D, sizes
+
+    # the table gathers (`gather_rows`' scope), by how many rows they fetch
+    fetched = {}
+    for line in txt.splitlines():
+        m = re.search(r"= f32\[([\d,]+)\]\S* gather\(", line)
+        if m and "emb/fwd/gather" in line:
+            rows = int(np.prod([int(x) for x in m.group(1).split(",")])) // D
+            fetched.setdefault(rows, []).append(line)
+    assert sorted(fetched) == [n_shards * cap, B * L], sorted(fetched)
+    assert all("emb/route/gather" in l for l in fetched[n_shards * cap])
+    assert all("emb/route/overflow" in l for l in fetched[B * L])
 
 
 def test_ring_attention_backward_moves_only_kv_blocks(mesh8):
@@ -164,7 +212,9 @@ def test_grad_accum_adds_no_resharding_collectives(mesh8):
 
     base = coll_counts(1)
     acc = coll_counts(4)
-    assert acc.get("all-to-all", 0) == 0, acc
+    # the lookup's own exchange of ids and rows is all-to-all (in the scan's
+    # body once, as in the accum=1 step); a reshard of the batch would add
+    assert acc.get("all-to-all", 0) == base.get("all-to-all", 0), (acc, base)
     # the split adds no gathers; grad reduction happens ONCE after the scan
     # (not per micro-batch), so nothing should exceed the accum=1 counts
     for op, n in acc.items():

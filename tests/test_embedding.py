@@ -9,6 +9,7 @@ row lookup, padding ids, combiners, sparse-gradient correctness — but the
 
 import contextlib
 import re
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -201,7 +202,9 @@ def test_gather_rows_tiled_backward_matches_xla(monkeypatch, ids_np):
 
 def test_tiled_backward_on_manual_shard_path(monkeypatch, mesh8):
     """Code-review r5 pt3 regression: the manual shard_map schedule feeds
-    gather_rows non-owned sentinel ids (up to 7/8 of the batch on mesh8).
+    gather_rows sentinel ids (the gathered schedule up to 7/8 of the batch
+    on eight shards; the routed one, which mesh8 takes, its empty bucket
+    slots: 8 x 512 slots for a device's 208 ids).
     The tiled backward must (a) stay exact and (b) keep those sentinels
     out of every tile's window population — mapping them to row 0 (the
     old behavior) piled them into tile 0 and permanently tripped the flat
@@ -213,7 +216,8 @@ def test_tiled_backward_on_manual_shard_path(monkeypatch, mesh8):
     ids = jax.device_put(ids_np, NamedSharding(mesh8, P("data", None)))
     w_np = np.random.RandomState(13).randn(64, 26, D).astype(np.float32)
 
-    with _route(monkeypatch, "tiled", 64 * 26, V // 8), jax.set_mesh(mesh8):
+    stream = 8 * emb_ops.route_cap(64 * 26 // 8, 8)
+    with _route(monkeypatch, "tiled", stream, V // 8), jax.set_mesh(mesh8):
         g = jax.jit(
             jax.grad(
                 lambda t: jnp.sum(
@@ -627,8 +631,9 @@ def test_pallas_backward_clustered_distinct_ids_flat_branch(monkeypatch):
 
 def test_pallas_backward_on_manual_shard_path(monkeypatch, mesh8):
     """The backward must stay exact under the manual shard_map schedule
-    with the kernel runnable, whose non-owned ids arrive as 2*shard_rows
-    sentinels. Its 1664 ids stay under the kernel's gates and take the
+    with the kernel runnable, whose empty bucket slots (routed: 8 buckets
+    of 512 for a device's 208 ids) arrive as 2*shard_rows sentinels. Its
+    256-row shards stay under the kernel's gates and take the
     flat route: the Mosaic kernel in interpret mode INSIDE shard_map on
     the CPU mesh never returns (PERF.md §7), so the kernel under
     shard_map is proven by the AOT compile for v5e:2x2 and on the chips."""
@@ -636,7 +641,8 @@ def test_pallas_backward_on_manual_shard_path(monkeypatch, mesh8):
     from elasticdl_tpu.ops.pallas_attention import interpret_mode
 
     monkeypatch.setattr(ps, "BLOCK_ROWS", 256)
-    assert emb_ops.backward_route(64 * 26, 2048 // 8, True) == "flat"
+    stream = 8 * emb_ops.route_cap(64 * 26 // 8, 8)
+    assert emb_ops.backward_route(stream, 2048 // 8, True) == "flat"
     V, D = 2048, 8
     table_np, table = make_table(mesh8, V=V, D=D, seed=41)
     ids_np = np.random.RandomState(42).randint(0, V, (64, 26)).astype(np.int32)
@@ -929,6 +935,179 @@ def test_nondivisible_table_falls_back_to_auto_with_parity(mesh8):
         for l in range(3):
             expected[ids_np[b, l]] += w_np[b, l]
     np.testing.assert_allclose(np.asarray(g), expected, rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------- #
+# The routed schedule (PR 45): a shard looks up only the ids it owns. Ids
+# and rows are exchanged all-to-all over the data axis; a step whose ids
+# overflow a (source, owner) bucket takes the gathered schedule.
+
+ROUTED_B, ROUTED_L, ROUTED_V, ROUTED_D = 128, 64, 2048, 8
+
+
+@pytest.mark.parametrize("n_local,n_shards,other_axes,want", [
+    (8192 * 26, 4, (), "routed"),            # deepfm-criteo1tb.resident-dp4
+    (8192 * 26, 8, ("model",), "gathered"),  # data=4 x model=2
+    (8192 * 26, 1, (), "auto"),              # one device
+    (10, 8, (), "routed"),                   # the tiny shapes of the tests
+])
+def test_owner_route(n_local, n_shards, other_axes, want):
+    assert emb_ops.owner_route(n_local, n_shards, other_axes) == want
+
+
+@pytest.mark.parametrize("n_local,n_shards,want", [
+    (8192 * 26, 4, 79872),    # the cell: 4 x 79 872 = 0.375 of 851 968
+    (1024, 8, 512),           # whole 512s
+    (10, 8, 512),
+    (512 * 8, 8, 1024),       # 1.5 x 512 = 768 -> 1024
+])
+def test_route_cap(n_local, n_shards, want):
+    assert emb_ops.route_cap(n_local, n_shards) == want
+
+
+@pytest.mark.parametrize("mesh_name,want", [
+    ("mesh8", "routed"), ("mesh_4x2", "gathered"), ("one", "auto")])
+def test_lookup_schedule_follows_the_ambient_mesh(mesh_name, want, request):
+    """The schedule is chosen from the mesh the trace sees: all-to-all on a
+    data-only mesh, the ids' all-gather where rows are sharded over `model`
+    too, no shard_map at all on one device."""
+    from elasticdl_tpu.parallel.mesh import build_mesh
+
+    mesh = (build_mesh({"data": 1}, jax.devices()[:1]) if mesh_name == "one"
+            else request.getfixturevalue(mesh_name))
+    with jax.set_mesh(mesh):
+        jaxpr = jax.make_jaxpr(
+            lambda t, i: emb_ops.embedding_lookup(t, i, mode="manual"))(
+            jax.ShapeDtypeStruct((512, 8), jnp.float32),
+            jax.ShapeDtypeStruct((16, 5), jnp.int32))
+    names = {e.primitive.name for e in _all_eqns(jaxpr.jaxpr)}
+    assert ("shard_map" in names) == (want != "auto")
+    assert ("all_to_all" in names) == (want == "routed")
+    assert ("cond" in names) == (want == "routed")
+    assert ("all_gather" in names) == (want != "auto")   # the other branch
+
+
+def _routed_case_ids(kind, r):
+    """(B, L) ids on mesh8: source s holds rows [16 s, 16 s + 16), shard o
+    owns table rows [256 o, 256 o + 256); a bucket's cap is 512 of a
+    source's 1024 ids."""
+    B, L, V = ROUTED_B, ROUTED_L, ROUTED_V
+    rows_per = V // 8
+    if kind == "uniform":
+        return r.randint(0, V, (B, L))
+    if kind == "zipf":
+        return np.minimum(r.zipf(1.3, (B, L)) - 1, V - 1) * 977 % V
+    if kind == "boundaries":
+        edges = np.arange(0, V + 1, rows_per)
+        return r.choice(
+            np.clip(np.concatenate([edges - 1, edges, edges + 1]), 0, V - 1),
+            (B, L))
+    if kind == "padding":
+        ids = r.randint(0, V, (B, L))
+        ids[:, L // 4:] = -1
+        ids[::3, 0] = V + 5          # past the table: a zero row as well
+        return ids
+    if kind == "one_shard":
+        return r.randint(3 * rows_per, 4 * rows_per, (B, L))
+    # source 2 sends shard 5 `cap - 1` / `cap + 1` ids, the rest elsewhere
+    cap = emb_ops.route_cap(B // 8 * L, 8)
+    ids = r.randint(0, 5 * rows_per, (B, L))
+    mine = ids[32:48].reshape(-1)
+    k = cap + (1 if kind == "over_cap" else -1)
+    mine[:k] = r.randint(5 * rows_per, 6 * rows_per, k)
+    ids[32:48] = r.permutation(mine).reshape(16, L)
+    return ids
+
+
+@pytest.mark.parametrize("kind", [
+    "uniform", "zipf", "boundaries", "padding", "under_cap", "over_cap",
+    "one_shard"])
+def test_routed_lookup_matches_dense(monkeypatch, mesh8, kind):
+    """Forward rows and table gradient of the manual lookup on a data-only
+    mesh equal the dense numpy result for any id distribution, and the
+    branch that ran is the one the counts call for: the exchange while every
+    (source, owner) bucket holds its ids, the gathered schedule from one id
+    over (nothing is dropped)."""
+    B, L, V, D = ROUTED_B, ROUTED_L, ROUTED_V, ROUTED_D
+    r = np.random.RandomState(zlib.crc32(kind.encode()))
+    ids_np = _routed_case_ids(kind, r).astype(np.int32)
+    table_np, table = make_table(mesh8, V=V, D=D, seed=51)
+    w_np = r.randn(B, L, D).astype(np.float32)
+    ids = jax.device_put(ids_np, NamedSharding(mesh8, P("data", None)))
+
+    valid = (ids_np >= 0) & (ids_np < V)
+    cap = emb_ops.route_cap(B // 8 * L, 8)
+    fullest = max(
+        np.bincount(src[ok] // (V // 8), minlength=8).max()
+        for src, ok in zip(ids_np.reshape(8, -1), valid.reshape(8, -1)))
+    if kind in ("under_cap", "over_cap"):
+        assert fullest == cap + (1 if kind == "over_cap" else -1)
+    if kind == "padding":      # a pad slot takes no room in any bucket
+        assert valid.reshape(8, -1).sum(axis=1).max() < 1024 - cap
+
+    ran = []
+    unbucket = emb_ops._unbucket
+
+    def spy(*args):
+        jax.debug.callback(lambda: ran.append("routed"))
+        return unbucket(*args)
+
+    monkeypatch.setattr(emb_ops, "_unbucket", spy)
+    with jax.set_mesh(mesh8):
+        out, g = jax.jit(lambda t: (
+            emb_ops.embedding_lookup(t, ids, mode="manual"),
+            jax.grad(lambda t: jnp.sum(
+                emb_ops.embedding_lookup(t, ids, mode="manual") * w_np))(t),
+        ))(table)
+        jax.block_until_ready((out, g))
+        jax.effects_barrier()
+    assert bool(ran) == (fullest <= cap), (kind, fullest, cap, len(ran))
+
+    want = np.where(valid[..., None], table_np[np.where(valid, ids_np, 0)], 0)
+    np.testing.assert_array_equal(np.asarray(out), want)
+    expected = np.zeros_like(table_np)
+    np.add.at(expected, ids_np[valid], w_np[valid])
+    np.testing.assert_allclose(np.asarray(g), expected, rtol=1e-5, atol=1e-5)
+
+
+def test_owner_plan_and_unbucket_hold_no_scatter():
+    """At the four-chip cell's shape (212 992 local ids, 4 shards, buckets
+    of 79 872, 11 columns; abstract values, nothing runs) the plan is two
+    sorts and compares, the buckets are contiguous slices, and `_unbucket`
+    and its backward are one gather of 212 992 rows each: no scatter of an
+    id or a row at a time (8-45 ns each on the chip), no loop."""
+    n, shards, d = 8192 * 26, 4, 11
+    cap = emb_ops.route_cap(n, shards)
+
+    def fn(flat, buf, g):
+        sf, order, starts, counts, slot = emb_ops._owner_plan(
+            flat, 23_472_128, shards, cap)
+        send = emb_ops._owner_buckets(sf, starts, counts, cap, 7)
+        out, vjp = jax.vjp(
+            lambda b: emb_ops._unbucket(b, slot, order, starts, counts), buf)
+        return send, out, vjp(g)
+
+    jaxpr = jax.make_jaxpr(fn)(
+        jax.ShapeDtypeStruct((n,), jnp.int32),
+        jax.ShapeDtypeStruct((shards, cap, d), jnp.float32),
+        jax.ShapeDtypeStruct((n, d), jnp.float32))
+    assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [
+        (shards, cap), (n, d), (shards, cap, d)]
+    eqns = list(_all_eqns(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert not [p for p in names if p.startswith("scatter")]
+    assert not [p for p in names if p in ("while", "scan")]
+    assert names.count("sort") == 2
+    row_gathers = sorted(
+        (e.invars[0].aval.shape, e.outvars[0].aval.shape) for e in eqns
+        if e.primitive.name == "gather" and e.outvars[0].aval.ndim == 2)
+    assert row_gathers == [
+        ((n, d), (n, d)), ((shards * cap, d), (n, d))]
+    # the buckets: one slice a shard of the ids and of the cotangent rows
+    slices = [e.outvars[0].aval.shape for e in eqns
+              if e.primitive.name == "dynamic_slice"
+              and e.outvars[0].aval.shape[0] == cap]
+    assert sorted(slices) == [(cap,)] * shards + [(cap, d)] * shards
 
 
 # ---------------------------------------------------------------------- #
