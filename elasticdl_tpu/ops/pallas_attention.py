@@ -9,28 +9,48 @@ blocks through VMEM with the online-softmax recurrence so scores never leave
 the chip's vector memory, and the backward recomputes them blockwise
 (flash-attention style) instead of saving them.
 
-The backward is ONE kernel (`flash_attention_bwd`, `_bwd_kernel`) wherever a
-key-value head fits the chip's VMEM whole (`bwd_route`: `resident`). Its grid
-is (B, key-value heads, the group's query heads x q blocks), the last axis
-sequential; the head's whole k and v (fetched once a head) and float32
-accumulators of its whole dk and dv stay in VMEM across that axis. A step
-takes one q block and loops over the kv blocks it sees — 0..i under the causal
-mask, the band under a window, from the offsets in SMEM — slicing k and v out
-of the resident block: s = q·kT, p = exp(s − lse), dp = do·vT and ds are
-computed ONCE for the pair and feed dv += pT·do, dk += dsT·q and the step's dq
+Forward and backward are ONE kernel each (`flash_attention_fwd`,
+`_fwd_resident_kernel`; `flash_attention_bwd`, `_bwd_kernel`) wherever a
+key-value head fits the chip's VMEM whole (`fwd_route`, `bwd_route`:
+`resident`). Their grids are (B, key-value heads, the group's query heads x q
+blocks), the last axis sequential; the head's whole k and v (fetched once a
+head: a group's query heads repeat the block index and fetch nothing) stay in
+VMEM across that axis, and in the backward float32 accumulators of its whole
+dk and dv too. A step takes one q block and loops over the kv blocks it sees —
+0..i under the causal mask, the band under a window, from the offsets in SMEM
+(`_visible_kv`) — slicing k and v out of the resident block. The whole blocks
+and the masked edge blocks (the diagonal's; under a window also the band's
+first) are separate loops with straight-line bodies (`_for_visible_kv`), kv
+blocks ascending: no grid step, no fetch and no `pl.when` for a pair above the
+diagonal or outside the band, and no element mask where every pair is visible.
+
+The forward's step runs the online-softmax recurrence (`_softmax_step`: two
+matmuls a pair). Where the head does not fit — ring attention's long keys —
+the `streaming` forward (`_fwd_kernel`) takes a grid step and a K/V fetch for
+every (q block, kv block) pair and skips the dead ones by `pl.when`; both
+kernels run the SAME step on the same blocks in the same order, so the two
+routes agree to the bit, output and logsumexp, on the CPU and on a v5e
+(PERF.md section 6, PR 46). The forward holds no dk and dv, so every shape
+that takes the resident backward takes the resident forward (a bfloat16 head
+of 128 up to 65 536 keys on a v5e's 128 MiB).
+
+The backward's step computes s = q·kT, p = exp(s − lse), dp = do·vT and ds
+ONCE for the pair and feeds dv += pT·do, dk += dsT·q and the step's dq
 += ds·k. Five matmuls a pair, where the `split` route's two kernels
 (`flash_attention_bwd_dq`, `_bwd_dkv`: each streams kv blocks through Mosaic's
 default 16 MB and each recomputes s and dp) run seven, a second exp and a
-second fetch of q, k, v, do, o. The whole blocks and the masked edge blocks
-(the diagonal's; under a window also the band's first) are separate loops with
-straight-line bodies. The operands' dtypes and the order of every sum are the
-split route's — dq over kv blocks ascending, dk and dv over the group's heads,
-then q blocks — so the two routes agree to the bit, on the CPU and on a v5e
-(PERF.md section 6, PR 37). `bwd_route` decides from the keys, the head, the
-dtype and the chip's VMEM alone and logs its answer once a shape; a bfloat16
-head of 128 fits up to 16 384 keys, a head of 256 up to 8192, on a v5e's
-128 MiB. The call passes `vmem_limit_bytes` (three quarters of the chip's);
-the forward and the split kernels pass none.
+second fetch of q, k, v, do, o. The operands' dtypes and the order of every
+sum are the split route's — dq over kv blocks ascending, dk and dv over the
+group's heads, then q blocks — so the two routes agree to the bit, on the CPU
+and on a v5e (PERF.md section 6, PR 37). A bfloat16 head of 128 fits the
+resident backward up to 16 384 keys, a head of 256 up to 8192.
+
+`fwd_route` and `bwd_route` decide from the keys, the head, the dtype, whether
+the call has a data mask and the chip's VMEM alone and log their answer once a
+shape. The forward's kernels and the resident backward pass `vmem_limit_bytes`
+(three quarters of the chip's); the split kernels pass none. The forward's and
+the backward's blocks are planned apart (`_plan_blocks`): the residuals are q,
+k, v, out and the logsumexp whatever blocks made them.
 
 Layout: the public contract is (B, T, H, D) like `full_attention`; the
 kernel internally works on (B, H, T, D) because Mosaic requires the last two
@@ -71,24 +91,27 @@ summed over its group's query heads inside the backward kernel (the group's
 heads and q blocks share the innermost, revisiting grid axis; the split
 route's dkv kernel likewise). With H == Hkv the kernels are what they were.
 
-Fully-masked causal blocks are skipped (`pl.when`), giving the ~2x causal
-FLOP saving without dynamic shapes — of the arithmetic only: the unbanded grid
-still has a step for every (q block, kv block) pair, and a skipped step still
-fetches its K/V block. Fully-masked ROWS (a q block entirely
-before every kv position) return 0 with lse=NEG_BIG, unlike the XLA path's
+Blocks wholly above the diagonal are never visited — the resident kernels'
+loops end at the diagonal, the streaming ones skip the step (`pl.when`), of
+the arithmetic only: their unbanded grid still has a step for every (q block,
+kv block) pair, and a skipped step still fetches its K/V block.
+Fully-masked ROWS (a q block entirely before every kv position: its loops run
+no block) return 0 with lse=NEG_BIG, unlike the XLA path's
 finite-NEG_BIG uniform softmax — zero is the defensible answer, the ring
 merge relies on the NEG_BIG lse, and no real caller consumes such rows.
 
 Sliding-window attention (`window=W`: key j is visible to query i iff
-i − W < j ≤ i, W keys with the query's own position among them) runs a BANDED
-grid: the kv axis of the forward's grid (and of the split route's dq kernel's)
-has only as many steps as a q block's band spans kv blocks (`_kv_band`: 2 at
-bq = bk = W), the K/V index maps start at the first block the q block can see,
-and the split route's dkv kernel's q axis is banded the same way (`_q_band`);
-the resident backward's loop over a q block's kv blocks runs over its band
-(`_visible_kv`). So a windowed layer pays neither the DMA nor the grid steps of
-the keys it cannot see. A step of a banded grid past the band's end (the first
-q blocks' bands are shorter) is skipped and its index map repeats the previous
+i − W < j ≤ i, W keys with the query's own position among them) visits a q
+block's BAND only: the resident kernels' loops over a q block's kv blocks run
+from the band's first block to the diagonal (`_visible_kv`), and where the
+head does not fit the grids are banded — the kv axis of the streaming
+forward's grid (and of the split route's dq kernel's) has only as many steps
+as a q block's band spans kv blocks (`_kv_band`: 2 at bq = bk = W), the K/V
+index maps start at the first block the q block can see, and the split
+route's dkv kernel's q axis is banded the same way (`_q_band`). So a windowed
+layer pays neither the DMA nor the grid steps of the keys it cannot see. A
+step of a banded grid past the band's end (the first q blocks' bands are
+shorter) is skipped and its index map repeats the previous
 block, which fetches nothing. The element mask is applied in the band's edge
 blocks only. The windowed kernels carry names of their own
 (`flash_attention_swa_fwd`, `_swa_bwd`; split: `_swa_bwd_dq`, `_swa_bwd_dkv`),
@@ -105,8 +128,8 @@ it is left.
 
 A mask that is DATA (`keep`: int8 or bool, (B, Tq, Tk), one plane a batch row
 shared by every head; key j is visible to query i iff j ≤ i AND keep[i, j]) is
-a fifth operand of the four kernels, read tile by tile through a BlockSpec of
-its own — in the resident backward the q block's whole strip of it, (bq, Tk),
+a fifth operand of every kernel, read tile by tile through a BlockSpec of
+its own — in the resident kernels the q block's whole strip of it, (bq, Tk),
 beside the resident k and v. The causal block skip stays and NO block is
 skipped for being empty of kept keys: the mask is applied in every block a q
 block visits, not in edge blocks only. A row with no kept key WITHIN a block
@@ -115,9 +138,15 @@ row keeps at least one key of its causal prefix (a selection that always keeps
 the query's own position does); a row that keeps none returns the mean of the
 values it visited, not zero. The kernels carry names of their own
 (`flash_attention_sel_fwd`, `_sel_bwd`; split: `_sel_bwd_dq`, `_sel_bwd_dkv`).
-The strip costs the resident backward 2·bq·Tk bytes of VMEM, so a `keep` call
-plans q blocks of `SEL_BLOCK_Q` (at 1024 a head of 128 with 16 384 keys no
-longer fits and takes the split route). `keep` is for UNSHARDED attention
+The strip costs the resident backward 2·bq·Tk bytes of VMEM, so the backward
+of a `keep` call plans q blocks of `SEL_BLOCK_Q` (at 1024 a head of 128 with
+16 384 keys no longer fits and takes the split route); the forward, with no
+dk and dv to hold, keeps the default. The resident forward walks the q
+blocks in its outer order and the group's heads within (the backward sums dk
+and dv over heads first and cannot), so that a strip is fetched once a group
+and not once a head: at 16 384 keys and 32 heads on 4, 1.07 GB a call, where
+(bq, bk) tiles for the live pairs of every head are 4.4 GB and a strip a head
+8.6 GB. `keep` is for UNSHARDED attention
 without a window: with a `window` or with offsets `can_flash` declines the
 call and `ops.attention.full_attention` takes its XLA path. `keep=None`
 traces and compiles exactly what it did before `keep` existed.
@@ -368,6 +397,43 @@ def _when_banded(live, inner, body):
     pl.when(live & jnp.logical_not(inner))(lambda: body(True))
 
 
+def _visible_kv(q_start, kv_off, *, causal, block_q, block_k, num_kv, window):
+    """(lo, lo_in, hi_in, hi) of the kv blocks a q block whose first row is
+    `q_start` sees among `num_kv` that start at `kv_off`: lo..hi-1 hold a
+    visible pair, lo_in..hi_in-1 of them only visible pairs (no element mask),
+    so that lo..lo_in-1 (the band's lower edge, under a window) and
+    hi_in..hi-1 (the diagonal's blocks) are the masked ones. Traced: the
+    offsets come from SMEM."""
+    if not causal:
+        return 0, 0, num_kv, num_kv
+    rel = q_start - kv_off                      # the q block's first row, in keys
+    # kv block j is live iff j·bk <= rel + bq − 1, whole iff j·bk + bk − 1 <= rel
+    hi = jnp.minimum(jnp.maximum(rel + block_q - 1 + block_k, 0) // block_k, num_kv)
+    hi_in = jnp.minimum(jnp.maximum(rel + 1, 0) // block_k, hi)
+    if window is None:
+        return 0, 0, hi_in, hi
+    # ... and j·bk + bk − 1 > rel − W, whole iff j·bk >= rel + bq − W
+    lo = jnp.minimum(jnp.maximum(rel - window + 1, 0) // block_k, hi)
+    lo_in = jnp.clip((jnp.maximum(rel + block_q - window, 0) + block_k - 1) // block_k,
+                     lo, hi)
+    return lo, lo_in, jnp.maximum(hi_in, lo_in), hi
+
+
+def _for_visible_kv(visit, q_start, kv_off, *, causal, block_q, block_k, num_kv,
+                    window):
+    """Run `visit(masked)`'s loop body over the kv blocks a q block sees, kv
+    blocks ascending, each loop's body straight-line: the band's masked lower
+    edge (a window's), the whole blocks, the diagonal's."""
+    lo, lo_in, hi_in, hi = _visible_kv(
+        q_start, kv_off, causal=causal, block_q=block_q, block_k=block_k,
+        num_kv=num_kv, window=window)
+    if window is not None:
+        jax.lax.fori_loop(lo, lo_in, visit(True), 0)
+    jax.lax.fori_loop(lo_in, hi_in, visit(False), 0)
+    if causal:
+        jax.lax.fori_loop(hi_in, hi, visit(True), 0)
+
+
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct that propagates `like`'s varying-mesh-axes set —
     required for pallas_call outputs inside a shard_map manual region
@@ -397,10 +463,53 @@ def _kernel_name(part: str, window, keep: bool = False) -> str:
     return f"flash_attention_{kind}{part}"
 
 
+def _softmax_init(acc, m_scr, l_scr):
+    acc[:] = jnp.zeros_like(acc)
+    m_scr[:] = jnp.full_like(m_scr, NEG_BIG)
+    l_scr[:] = jnp.zeros_like(l_scr)
+
+
+def _softmax_step(q, k, v, mask, acc, m_scr, l_scr, scale):
+    """One (q block, kv block) pair of the online-softmax recurrence, the
+    same values on both forward routes. q (bq, D), k and v (bk, D), `mask`:
+    the block's element mask or None."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale                                   # (bq, bk)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_BIG)
+
+    m_prev = m_scr[:, :1]                       # (bq, 1)
+    l_prev = l_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)                      # (bq, bk)
+    alpha = jnp.exp(m_prev - m_new)             # (bq, 1)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc[:] = acc[:] * alpha + jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _softmax_finalize(o_ref, lse_ref, acc, m_scr, l_scr):
+    l = l_scr[:, :1]
+    o_ref[0, 0] = (acc[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    # lse of a fully-masked row: m stays NEG_BIG and l stays 0 -> the
+    # log floor keeps it at ~NEG_BIG, which the ring merge treats as
+    # "no contribution"
+    lse_ref[0, 0] = jnp.broadcast_to(
+        m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30)), lse_ref.shape[2:]
+    )
+
+
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
                 acc, m_scr, l_scr, *, scale, causal, block_q, block_k,
                 num_kv, window=None, kv_blocks=None):
-    """`num_kv`: the steps of the grid's kv axis — every kv block, or under a
+    """The STREAMING forward: a grid step a (q block, kv block) pair.
+    `num_kv`: the steps of the grid's kv axis — every kv block, or under a
     `window` the band's steps, of `kv_blocks` kv blocks in all. `keep_ref`:
     the (1, bq, bk) block of the data mask, or None."""
     i = pl.program_id(2)
@@ -408,11 +517,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
     q_off = offs_ref[0]
     kv_off = offs_ref[1]
 
-    @pl.when(j == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_BIG)
-        l_scr[:] = jnp.zeros_like(l_scr)
+    pl.when(j == 0)(lambda: _softmax_init(acc, m_scr, l_scr))
 
     # the kv block of this step: under a window the band starts at the first
     # block the q block can see
@@ -421,30 +526,10 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
     kv_start = kv_off + jb * block_k
 
     def _accumulate(masked):
-        q = q_ref[0, 0]                             # (bq, D)
-        k = k_ref[0, 0]                             # (bk, D)
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                   # (bq, bk)
         mask = _visible(masked, None if keep_ref is None else keep_ref[0],
                         q_start, kv_start, block_q, block_k, window)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_BIG)
-
-        m_prev = m_scr[:, :1]                       # (bq, 1)
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                      # (bq, bk)
-        alpha = jnp.exp(m_prev - m_new)             # (bq, 1)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        _softmax_step(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], mask,
+                      acc, m_scr, l_scr, scale)
 
     if window is None:
         # causal: skip KV blocks entirely above the diagonal (traced predicate
@@ -455,16 +540,38 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
         _when_banded(*_block_kind(q_start, kv_start, block_q, block_k, window,
                                   jb <= kv_blocks - 1), _accumulate)
 
-    @pl.when(j == num_kv - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        o_ref[0, 0] = (acc[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        # lse of a fully-masked row: m stays NEG_BIG and l stays 0 -> the
-        # log floor keeps it at ~NEG_BIG, which the ring merge treats as
-        # "no contribution"
-        lse_ref[0, 0] = jnp.broadcast_to(
-            m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30)), lse_ref.shape[2:]
-        )
+    pl.when(j == num_kv - 1)(
+        lambda: _softmax_finalize(o_ref, lse_ref, acc, m_scr, l_scr))
+
+
+def _fwd_resident_kernel(offs_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
+                         acc, m_scr, l_scr, *, scale, causal, block_q, block_k,
+                         num_kv, group=1, window=None):
+    """The RESIDENT forward. One step: a q block of one query head against
+    every kv block it sees of its key-value head's WHOLE k and v, which stay
+    in VMEM while the innermost axis walks the q blocks and, for each, the
+    `group` query heads that read them. `keep_ref`: the q block's (1, bq, Tk)
+    strip of the data mask, or None."""
+    y = pl.program_id(2)
+    i = y if group == 1 else y // group
+    q_start = offs_ref[0] + i * block_q
+    kv_off = offs_ref[1]
+    _softmax_init(acc, m_scr, l_scr)
+    q = q_ref[0, 0]
+
+    def visit(masked):
+        def body(j, carry):
+            rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+            mask = _visible(masked, None if keep_ref is None else keep_ref[0, :, rows],
+                            q_start, kv_off + j * block_k, block_q, block_k, window)
+            _softmax_step(q, k_ref[0, 0, rows, :], v_ref[0, 0, rows, :], mask,
+                          acc, m_scr, l_scr, scale)
+            return carry
+        return body
+
+    _for_visible_kv(visit, q_start, kv_off, causal=causal, block_q=block_q,
+                    block_k=block_k, num_kv=num_kv, window=window)
+    _softmax_finalize(o_ref, lse_ref, acc, m_scr, l_scr)
 
 
 def _banded_kv_at(bq, bk, window, num_kv):
@@ -495,54 +602,115 @@ def _keep_kv_at(causal, bq, bk, num_kv):
     return lambda i, j: jnp.minimum(j, _last_kv(i, bq, bk, num_kv))
 
 
+class Plan(NamedTuple):
+    route: str       # a head's keys "resident" in VMEM, or the other route
+    vmem_bytes: int  # what the resident kernel's blocks, scratch and values take
+    vmem_limit: int  # what Mosaic may use
+
+
+def _plan(part, other, what, need, t_k, head_dim, dtype_name, bq, bk, vmem, keep):
+    limit = vmem * 3 // 4
+    plan = Plan("resident" if need <= limit else other, need, limit)
+    # once a shape and process: which kernel this shape takes
+    logger.info(
+        "flash attention's %s (%d keys, head %d, %s, blocks %d x %d%s) "
+        "takes the %s route: a head's %s resident in VMEM need "
+        "%d bytes of the %d a kernel may use here", part, t_k, head_dim, dtype_name,
+        bq, bk, ", a data mask" if keep else "", plan.route, what, need, limit)
+    return plan
+
+
+@functools.lru_cache(maxsize=None)
+def _fwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
+              vmem: int, keep: bool = False) -> Plan:
+    size = jnp.dtype(dtype_name).itemsize
+    # two buffers each of k and v; a step: two buffers each of q and out, of
+    # the logsumexp; the float32 accumulator, maximum and sum; the float32
+    # (bq, bk) values (s, p and two more) and v's float32 form; with a data
+    # mask two buffers of the q block's int8 strip and a block of it widened
+    need = (4 * t_k * head_dim * size
+            + 4 * bq * head_dim * size + 16 * bq * _LANE + 4 * bq * head_dim
+            + 16 * bq * bk + 4 * bk * head_dim
+            + (2 * bq * t_k + 4 * bq * bk if keep else 0))
+    return _plan("forward", "streaming", "k and v", need, t_k, head_dim, dtype_name,
+                 bq, bk, vmem, keep)
+
+
+def fwd_route(t_k: int, head_dim: int, dtype, bq: int, bk: int,
+              keep: bool = False) -> Plan:
+    """Which forward a call of `t_k` keys a head takes — `resident`: the
+    key-value head's whole k and v in VMEM, fetched once a head, and a grid
+    step a q block, which loops over the kv blocks it sees; or `streaming`: a
+    grid step and a K/V fetch a (q block, kv block) pair, where the head does
+    not fit. A pure function of the shapes, the dtype, whether the call has a
+    data mask (`keep`: the q block's strip of it sits beside k and v) and the
+    chip's VMEM; nothing a caller sets."""
+    return _fwd_plan(t_k, head_dim, jnp.dtype(dtype).name, bq, bk, _vmem_bytes(),
+                     bool(keep))
+
+
 def _flash_fwd(offs, qt, kt, vt, keep=None, *, causal, bq, bk, interpret,
                window=None):
     """offs: (2,) int32 [q_off, kv_off]; qt/kt/vt: (B, H, T, D); keep: None or
-    (B, Tq, Tk) int8."""
+    (B, Tq, Tk) int8. (out (B, H, Tq, D), logsumexp (B, H, Tq, 128) float32),
+    by the route `fwd_route` gives: the same values to the bit by either."""
     B, H, Tq, D = qt.shape
-    Tk = kt.shape[2]
+    Hkv, Tk = kt.shape[1], kt.shape[2]
     num_q, num_kv = Tq // bq, Tk // bk
-    scale = D ** -0.5
-    kv_head = _kv_head_of(H, kt.shape[1])
-    if window is None:
-        steps, kv_at = num_kv, lambda i, j: j
+    kv_head = _kv_head_of(H, Hkv)
+    plan = fwd_route(Tk, D, qt.dtype, bq, bk, keep is not None)
+    static = dict(scale=D ** -0.5, causal=causal, block_q=bq, block_k=bk,
+                  window=window)
+    if plan.route == "resident":
+        # grid axis 1 counts KEY-VALUE heads; the sequential axis 2 walks the
+        # q blocks and, for each, the group's query heads (y = q block · group
+        # + head in group): the q block's strip of a data mask is then
+        # fetched once a group and not once a head
+        group = H // Hkv
+        kernel = _with_optional(_fwd_resident_kernel, 4, (keep is not None,),
+                                num_kv=num_kv, group=group, **static)
+        grid = (B, Hkv, num_q * group)
+        if group == 1:
+            q_at = lambda b, h, y, offs: (b, h, y, 0)
+        else:
+            q_at = lambda b, h, y, offs: (b, h * group + y % group, y // group, 0)
+        kv_spec = pl.BlockSpec((1, 1, Tk, D), lambda b, h, y, offs: (b, h, 0, 0))
+        keep_spec = pl.BlockSpec((1, bq, Tk), lambda b, h, y, offs: (b, y // group, 0))
     else:
-        steps = _kv_band(num_q, num_kv, bq, bk, window)[0]
-        kv_at = _banded_kv_at(bq, bk, window, num_kv)
-
-    kernel = _with_optional(
-        _fwd_kernel, 4, (keep is not None,), scale=scale, causal=causal,
-        block_q=bq, block_k=bk, num_kv=steps, window=window, kv_blocks=num_kv,
-    )
-    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, offs: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, D),
-                           lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
-    keep_at = _keep_kv_at(causal, bq, bk, num_kv)
-    keep_spec = pl.BlockSpec((1, bq, bk),
-                             lambda b, h, i, j, offs: (b, i, keep_at(i, j)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, H, num_q, steps),
-        in_specs=[q_spec, kv_spec, kv_spec]
-        + ([keep_spec] if keep is not None else []),
-        out_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, bq, _LANE),
-                         lambda b, h, i, j, offs: (b, h, i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, _LANE), jnp.float32),
-            pltpu.VMEM((bq, _LANE), jnp.float32),
-        ],
-    )
+        if window is None:
+            steps, kv_at = num_kv, lambda i, j: j
+        else:
+            steps = _kv_band(num_q, num_kv, bq, bk, window)[0]
+            kv_at = _banded_kv_at(bq, bk, window, num_kv)
+        kernel = _with_optional(_fwd_kernel, 4, (keep is not None,), num_kv=steps,
+                                kv_blocks=num_kv, **static)
+        grid = (B, H, num_q, steps)
+        q_at = lambda b, h, i, j, offs: (b, h, i, 0)
+        kv_spec = pl.BlockSpec(
+            (1, 1, bk, D), lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
+        keep_at = _keep_kv_at(causal, bq, bk, num_kv)
+        keep_spec = pl.BlockSpec((1, bq, bk),
+                                 lambda b, h, i, j, offs: (b, i, keep_at(i, j)))
+    q_spec = pl.BlockSpec((1, 1, bq, D), q_at)
     out, lse = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[q_spec, kv_spec, kv_spec]
+            + ([keep_spec] if keep is not None else []),
+            out_specs=[q_spec, pl.BlockSpec((1, 1, bq, _LANE), q_at)],
+            scratch_shapes=[
+                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, _LANE), jnp.float32),
+                pltpu.VMEM((bq, _LANE), jnp.float32),
+            ],
+        ),
         out_shape=[
             _sds(qt.shape, qt.dtype, qt),
             _sds((B, H, Tq, _LANE), jnp.float32, qt),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=plan.vmem_limit),
         interpret=interpret,
         name=_kernel_name("fwd", window, keep is not None),
     )(offs, qt, kt, vt, *(() if keep is None else (keep,)))
@@ -684,28 +852,6 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _visible_kv(q_start, kv_off, *, causal, block_q, block_k, num_kv, window):
-    """(lo, lo_in, hi_in, hi) of the kv blocks a q block whose first row is
-    `q_start` sees among `num_kv` that start at `kv_off`: lo..hi-1 hold a
-    visible pair, lo_in..hi_in-1 of them only visible pairs (no element mask),
-    so that lo..lo_in-1 (the band's lower edge, under a window) and
-    hi_in..hi-1 (the diagonal's blocks) are the masked ones. Traced: the
-    offsets come from SMEM."""
-    if not causal:
-        return 0, 0, num_kv, num_kv
-    rel = q_start - kv_off                      # the q block's first row, in keys
-    # kv block j is live iff j·bk <= rel + bq − 1, whole iff j·bk + bk − 1 <= rel
-    hi = jnp.minimum(jnp.maximum(rel + block_q - 1 + block_k, 0) // block_k, num_kv)
-    hi_in = jnp.minimum(jnp.maximum(rel + 1, 0) // block_k, hi)
-    if window is None:
-        return 0, 0, hi_in, hi
-    # ... and j·bk + bk − 1 > rel − W, whole iff j·bk >= rel + bq − W
-    lo = jnp.minimum(jnp.maximum(rel - window + 1, 0) // block_k, hi)
-    lo_in = jnp.clip((jnp.maximum(rel + block_q - window, 0) + block_k - 1) // block_k,
-                     lo, hi)
-    return lo, lo_in, jnp.maximum(hi_in, lo_in), hi
-
-
 def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                 glse_ref, keep_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                 scale, causal, block_q, block_k, num_q, num_kv, group=1,
@@ -762,16 +908,8 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             return carry
         return body
 
-    # kv blocks ascending; each loop's body is straight-line: the band's
-    # masked lower edge (a window's), the whole blocks, the diagonal's
-    lo, lo_in, hi_in, hi = _visible_kv(
-        q_start, kv_off, causal=causal, block_q=block_q, block_k=block_k,
-        num_kv=num_kv, window=window)
-    if window is not None:
-        jax.lax.fori_loop(lo, lo_in, visit(True), 0)
-    jax.lax.fori_loop(lo_in, hi_in, visit(False), 0)
-    if causal:
-        jax.lax.fori_loop(hi_in, hi, visit(True), 0)
+    _for_visible_kv(visit, q_start, kv_off, causal=causal, block_q=block_q,
+                    block_k=block_k, num_kv=num_kv, window=window)
     dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
     @pl.when(y == group * num_q - 1)
@@ -780,15 +918,9 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-class BwdPlan(NamedTuple):
-    route: str       # "resident" (one kernel) or "split" (dq, then dk and dv)
-    vmem_bytes: int  # what the resident kernel's blocks, scratch and values take
-    vmem_limit: int  # what Mosaic may use
-
-
 @functools.lru_cache(maxsize=None)
 def _bwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
-              vmem: int, keep: bool = False) -> BwdPlan:
+              vmem: int, keep: bool = False) -> Plan:
     size = jnp.dtype(dtype_name).itemsize
     head = t_k * head_dim
     # two buffers each of k, v in and dk, dv out; dk, dv in float32
@@ -800,19 +932,12 @@ def _bwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
     step = (8 * bq * head_dim * size + 16 * bq * _LANE + 4 * bq * head_dim
             + 20 * bq * bk + 4 * (3 * bq + 2 * bk) * head_dim
             + (2 * bq * t_k + 4 * bq * bk if keep else 0))
-    need, limit = resident + step, vmem * 3 // 4
-    plan = BwdPlan("resident" if need <= limit else "split", need, limit)
-    # once a shape and process: which backward this shape takes
-    logger.info(
-        "flash attention's backward (%d keys, head %d, %s, blocks %d x %d%s) "
-        "takes the %s route: a head's k, v, dk and dv resident in VMEM need "
-        "%d bytes of the %d a kernel may use here", t_k, head_dim, dtype_name,
-        bq, bk, ", a data mask" if keep else "", plan.route, need, limit)
-    return plan
+    return _plan("backward", "split", "k, v, dk and dv", resident + step, t_k,
+                 head_dim, dtype_name, bq, bk, vmem, keep)
 
 
 def bwd_route(t_k: int, head_dim: int, dtype, bq: int, bk: int,
-              keep: bool = False) -> BwdPlan:
+              keep: bool = False) -> Plan:
     """Which backward a call of `t_k` keys a head takes — `resident`: ONE
     kernel, the key-value head's whole k and v and its float32 dk and dv in
     VMEM, a pair's score block computed once for dq, dk and dv; or `split`: a
@@ -993,12 +1118,14 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window, optional):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash(causal: bool, bq: int, bk: int, interpret: bool,
-                with_lse: bool, window: Optional[int] = None):
+def _make_flash(causal: bool, fwd_blocks: Tuple[int, int], bq: int, bk: int,
+                interpret: bool, with_lse: bool, window: Optional[int] = None):
     """Returns flash(offs, q, k, v) -> out, or (out, lse(B, H, Tq)) when
     `with_lse` — the lse variant also backpropagates lse's cotangent (the
-    ring merge differentiates through it). With a `window` the three kernels
-    run their banded grids. A fifth argument, where a call gives one, is the
+    ring merge differentiates through it). `fwd_blocks` are the forward
+    kernel's (block_q, block_k), `bq` and `bk` the backward's: the residuals
+    are what they are whatever blocks made them. With a `window` the kernels
+    run their bands. A fifth argument, where a call gives one, is the
     data mask `keep` (B, Tq, Tk) int8: it rides to the kernels and into the
     residuals, and has no cotangent."""
 
@@ -1006,8 +1133,9 @@ def _make_flash(causal: bool, bq: int, bk: int, interpret: bool,
         qt = q.transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)
         vt = v.transpose(0, 2, 1, 3)
-        out, lse = _flash_fwd(offs, qt, kt, vt, *keep, causal=causal, bq=bq,
-                              bk=bk, interpret=interpret, window=window)
+        out, lse = _flash_fwd(offs, qt, kt, vt, *keep, causal=causal,
+                              bq=fwd_blocks[0], bk=fwd_blocks[1],
+                              interpret=interpret, window=window)
         # named here, where the residuals are made, so that nothing reads an
         # un-named one
         return (offs, *map(checkpoint_name, (qt, kt, vt, out, lse),
@@ -1114,8 +1242,10 @@ def _plan_call(q, k, causal, q_offset, kv_offset, block_q, block_k,
     if keep is not None:
         keep = _checked_keep(keep, q, k, window, q_offset, kv_offset)
     window = _effective_window(window, causal, q_offset, kv_offset, k.shape[1])
-    blocks = _plan_blocks(q.shape, k.shape, block_q, block_k,
-                          dtype=q.dtype, keep=keep is not None)
+    blocks, fwd_blocks = (
+        _plan_blocks(q.shape, k.shape, block_q, block_k, dtype=q.dtype,
+                     keep=keep is not None, forward=forward)
+        for forward in (False, True))
     if blocks is None:
         raise ValueError(
             f"flash_attention cannot block Tq={q.shape[1]}, Tk={k.shape[1]} "
@@ -1124,21 +1254,23 @@ def _plan_call(q, k, causal, q_offset, kv_offset, block_q, block_k,
     bq, bk = blocks
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(kv_offset, jnp.int32)])
-    return (_make_flash(bool(causal), bq, bk, interpret, bool(with_lse), window),
+    return (_make_flash(bool(causal), fwd_blocks, bq, bk, interpret, bool(with_lse),
+                        window),
             (offs,) if keep is None else (offs, keep))
 
 
 def kv_block_visits(t_q: int, t_k: int, window: Optional[int],
                     head_dim: int = _LANE, dtype=None) -> Tuple[int, int]:
-    """((q block, kv block) pairs a head's forward grid COMPUTES under
-    `window`, the pairs the causal grid computes), at the blocks the call
+    """((q block, kv block) pairs a head's forward COMPUTES under
+    `window`, the pairs the causal call computes), at the blocks the call
     would plan: at 16 384 tokens and 1024-blocks 31 and 136 under a window of
     1024 (two blocks a q block, each half masked) and 45 and 136 under one of
     2048 (three: an edge half masked, a whole one, the diagonal — 67% of the
     computed pairs visible, where 1024 shows 50%). (0, 0) where the shapes
     cannot be blocked."""
     window = _effective_window(window, True, 0, 0, t_k)
-    blocks = _plan_blocks((1, t_q, 1, head_dim), (1, t_k, 1, head_dim), None, None, dtype)
+    blocks = _plan_blocks((1, t_q, 1, head_dim), (1, t_k, 1, head_dim), None, None, dtype,
+                          forward=True)
     if blocks is None:
         return 0, 0
     bq, bk = blocks
@@ -1150,22 +1282,31 @@ def kv_block_visits(t_q: int, t_k: int, window: Optional[int],
 
 def _plan_blocks(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
                  block_q: Optional[int], block_k: Optional[int],
-                 dtype=None, keep: bool = False) -> Optional[Tuple[int, int]]:
-    """(block_q, block_k) for these shapes, or None. Targets not given are
-    `DEFAULT_BLOCK_*`, with or without a window, and `SEL_BLOCK_Q` for the q
-    blocks of a call with a data mask (`keep`), whose int8 tiles also set the
-    least block. The targets are for a
-    head of at most the lane width; a wider head takes a key block smaller in
-    proportion, so that a key block's rows times the head size stay what they
-    are at 128. At head 256 and (1024, 1024) the dq kernel's blocks and
-    scratch need 16.9 MB of the 16 MB a kernel may use; T=8192, 20 heads,
-    bfloat16 on a v5e, forward / forward + backward: (1024, 512) 6.66 / 25.6
-    ms, (512, 1024) 7.20 / 25.6, (512, 512) 8.44 / 28.3, (256, 1024) 9.46 /
-    30.2 (PERF.md section 6, PR 32)."""
-    block_q = block_q or (SEL_BLOCK_Q if keep else DEFAULT_BLOCK_Q)
+                 dtype=None, keep: bool = False,
+                 forward: bool = False) -> Optional[Tuple[int, int]]:
+    """(block_q, block_k) for these shapes, or None: the backward's, or with
+    `forward` the forward's. Targets not given are `DEFAULT_BLOCK_*`, with or
+    without a window. The FORWARD takes them as they are, whatever the head
+    and with or without a data mask (`keep`, whose int8 tiles also set the
+    least block): it passes a VMEM limit of its own and holds no dk and dv.
+    The BACKWARD takes q blocks of `SEL_BLOCK_Q` with a data mask, and at a
+    head wider than the lane width a key block smaller in proportion, so that
+    a key block's rows times the head size stay what they are at 128: at head
+    256 and (1024, 1024) the split dq kernel's blocks and scratch need 16.9 MB
+    of the 16 MB it may use; T=8192, 20 heads, bfloat16 on a v5e, forward +
+    backward at one plan for both: (1024, 512) 25.6 ms, (512, 1024) 25.6,
+    (512, 512) 28.3, (256, 1024) 30.2 (PERF.md section 6, PR 32). The forward
+    alone at that shape, resident / streaming ms a call (my chip run, PR 46,
+    host clock over back-to-back calls): (1024, 1024) 4.84 / 5.89, (512,
+    1024) 5.07 / 7.09, (2048, 1024) 5.23 / 5.68, (1024, 512) 5.63 / 6.56,
+    (2048, 512) 5.88 / 6.30, (512, 512) 6.21 / 8.32 — the accumulator is
+    rescaled once a key block; and with a data mask at 16 384 keys, 32 heads
+    on 4 of 128: (1024, 1024) 17.90 / 22.32, (512, 2048) 18.16 / 25.77, (512,
+    1024) 19.84 / 27.69, (256, 1024) 24.10 / 39.24."""
+    block_q = block_q or (SEL_BLOCK_Q if keep and not forward else DEFAULT_BLOCK_Q)
     block_k = block_k or DEFAULT_BLOCK_K
     mb = max(_min_block(dtype), _min_block(jnp.int8)) if keep else _min_block(dtype)
-    if q_shape[-1] > _LANE:
+    if q_shape[-1] > _LANE and not forward:
         block_k = max(mb, block_k * _LANE // q_shape[-1])
     bq = pick_block(q_shape[1], block_q, mb)
     bk = pick_block(k_shape[1], block_k, mb)

@@ -91,13 +91,15 @@ def test_forward_and_backward_compile_for_a_v5e(name, one_chip, no_compile_cache
 
 
 # (batch, tokens, query heads, key-value heads, head, window) -> the plan's
-# blocks and the backward's route: the attention of BENCHMARK.json's four
-# language-model cells, then a head that does not fit VMEM whole
+# blocks for the backward and the backward's route: the attention of
+# BENCHMARK.json's language-model cells, then a head that does not fit VMEM
+# whole (its forward, with no dk and dv to hold, still does)
 FLASH = {
     "olmoe-1b-7b.resident-4k": ((2, 4096, 16, 16, 128, None), (1024, 1024), "resident"),
     "nemotron-3-nano-30b-a3b.resident-8k": ((1, 8192, 32, 2, 128, None), (1024, 1024), "resident"),
-    # the plan halves the key block at head 256, for every kernel alike: at
-    # (1024, 1024) the dq kernel asked for 16.9 MB of Mosaic's default 16 (PR 32)
+    # the plan halves the backward's key block at head 256: at (1024, 1024) the
+    # dq kernel asked for 16.9 MB of Mosaic's default 16 (PR 32); the forward's
+    # blocks are its own (`FORWARD_BLOCKS`)
     "glm-4.7-flash.resident-8k": ((1, 8192, 20, 20, 256, None), (1024, 512), "resident"),
     "mellum2-12b-a2.5b.resident-16k/full": ((1, 16384, 32, 4, 128, None), (1024, 1024), "resident"),
     "mellum2-12b-a2.5b.resident-16k/sliding": ((1, 16384, 32, 4, 128, 1024), (1024, 1024), "resident"),
@@ -107,12 +109,15 @@ FLASH = {
     "32k_keys": ((1, 32768, 8, 2, 128, None), (1024, 1024), "split"),
     "32k_keys/sliding": ((1, 32768, 8, 2, 128, 1024), (1024, 1024), "split"),
 }
+# the forward's blocks where they are not the backward's
+FORWARD_BLOCKS = {"glm-4.7-flash.resident-8k": (1024, 1024)}
 
 
 @pytest.mark.parametrize("name", sorted(FLASH))
 def test_flash_kernels_compile_for_a_v5e(name, one_chip, no_compile_cache):
-    """Forward and backward in bfloat16 at the blocks the plan gives: ONE
-    backward kernel where a key-value head's k, v, dk and dv fit the VMEM
+    """Forward and backward in bfloat16 at the blocks the plan gives: the
+    forward with the key-value head's k and v resident in VMEM at every one of
+    these shapes, ONE backward kernel where its k, v, dk and dv fit the VMEM
     `bwd_route` allows it (`vmem_limit_bytes`: Mosaic's default 16 MB hold
     none of these heads), the dq and the dkv kernel where they do not."""
     from elasticdl_tpu.ops import pallas_attention
@@ -122,6 +127,10 @@ def test_flash_kernels_compile_for_a_v5e(name, one_chip, no_compile_cache):
     k = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16, sharding=one_chip)
     assert pallas_attention._plan_blocks(q.shape, k.shape, None, None, dtype=jnp.bfloat16) == blocks
     assert pallas_attention.bwd_route(t, d, jnp.bfloat16, *blocks).route == route
+    forward = FORWARD_BLOCKS.get(name, blocks)
+    assert pallas_attention._plan_blocks(q.shape, k.shape, None, None, dtype=jnp.bfloat16,
+                                         forward=True) == forward
+    assert pallas_attention.fwd_route(t, d, jnp.bfloat16, *forward).route == "resident"
 
     def forward_and_backward(q, k, v, do):
         out, vjp = jax.vjp(lambda q, k, v: pallas_attention.flash_attention(
@@ -142,9 +151,11 @@ def test_flash_kernels_compile_for_a_v5e(name, one_chip, no_compile_cache):
 def test_masked_flash_kernels_compile_for_a_v5e(route, one_chip, no_compile_cache, monkeypatch):
     """keye-vl-2.0-30b-a3b.resident-16k's attention: 32/4 heads of 128, 16 384
     keys and an int8 `keep` plane as a fifth operand, with the logsumexp
-    returned. The plan gives q blocks of 512 so that the resident backward
+    returned. The plan gives the backward q blocks of 512 so that it
     holds the q block's (512, 16 384) strip of the mask beside the head's k,
-    v, dk and dv; the split route's two kernels take the mask tile by tile."""
+    v, dk and dv, as the resident forward does beside k and v; on a chip of 32
+    MiB the streaming forward and the split route's two kernels take the mask
+    tile by tile."""
     from elasticdl_tpu.ops import pallas_attention
 
     b, t, h, hkv, d = 1, 16384, 32, 4, 128
@@ -155,8 +166,12 @@ def test_masked_flash_kernels_compile_for_a_v5e(route, one_chip, no_compile_cach
                                          keep=True) == (512, 1024)
     if route == "split":
         pallas_attention._make_flash.cache_clear()
-        monkeypatch.setattr(pallas_attention, "_vmem_bytes", lambda: 1 << 10)
+        monkeypatch.setattr(pallas_attention, "_vmem_bytes", lambda: 32 << 20)
     assert pallas_attention.bwd_route(t, d, jnp.bfloat16, 512, 1024, keep=True).route == route
+    assert pallas_attention._plan_blocks(q.shape, k.shape, None, None, dtype=jnp.bfloat16,
+                                         keep=True, forward=True) == (1024, 1024)
+    assert pallas_attention.fwd_route(t, d, jnp.bfloat16, 1024, 1024, keep=True).route == (
+        "resident" if route == "resident" else "streaming")
 
     def forward_and_backward(q, k, v, keep, do):
         (out, lse), vjp = jax.vjp(lambda q, k, v: pallas_attention.flash_attention_lse(

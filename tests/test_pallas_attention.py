@@ -646,9 +646,11 @@ def take_route(monkeypatch, route):
 
 @pytest.fixture
 def bwd_log(caplog):
-    """What `bwd_route` logs, from an empty cache: it logs once a shape."""
+    """What `bwd_route` and `fwd_route` log, from empty caches: each logs once
+    a shape."""
     from elasticdl_tpu.ops import pallas_attention as pa
 
     pa._bwd_plan.cache_clear()
+    pa._fwd_plan.cache_clear()
     with listening(caplog, pa.__name__):
         yield caplog

@@ -1,7 +1,9 @@
 """The flash backward's two routes (`bwd_route`: one kernel with a head's keys
-resident in VMEM, or the two that stream them), held to each other bit for
-bit, and a mask that is data (`keep`) on both (`ops/pallas_attention.py`), in
-interpret mode on the CPU. A file of its own so that three xdist workers
+resident in VMEM, or the two that stream them) and the forward's two
+(`fwd_route`: the head's keys resident and a q block's own loop over its kv
+blocks, or a grid step a pair), held to each other bit for bit, and a mask
+that is data (`keep`) on all (`ops/pallas_attention.py`), in interpret mode on
+the CPU. A file of its own so that three xdist workers
 share the kernel's cases (`tests/test_pallas_attention.py`,
 `tests/test_pallas_attention_window.py`).
 """
@@ -14,7 +16,11 @@ import pytest
 from elasticdl_tpu.ops.attention import full_attention
 from elasticdl_tpu.ops.pallas_attention import can_flash, flash_attention
 from tests.conftest import equations, pallas_calls
-from tests.test_pallas_attention import bwd_log, take_route  # noqa: F401  (a fixture)
+from tests.test_pallas_attention import (  # noqa: F401  (bwd_log: a fixture)
+    RECOMPUTED, _kept, _recomputed_layer, bwd_log, take_route)
+
+# the forward's blocks at a head of 256: its own, not the backward's (1024, 512)
+FWD_BLOCKS_256 = (1024, 1024)
 from tests.test_pallas_attention_window import _kernel_grids
 
 # (T, heads, key-value heads, head size, block_q, block_k, causal, window,
@@ -140,6 +146,176 @@ def test_the_backward_route_follows_the_head_s_bytes_and_logs_once_a_shape(
 
 
 # ------------------------------------------------------------------ #
+# the forward's two routes
+
+
+def take_fwd_route(monkeypatch, route):
+    """The forward's route alone, on a described v5e: the backward stays the
+    resident one, so that what differs between two runs is the forward kernel."""
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    take_route(monkeypatch, "resident")
+    if route == "streaming":
+        monkeypatch.setattr(pa, "fwd_route", lambda *a, **kw: pa.Plan("streaming", 0, 96 << 20))
+
+
+def _forward_case(name):
+    """(f(q, k, v) -> (out, lse), (q, k, v), the rows that see no key) of a
+    `BACKWARD` case, or of `keep`: a data mask over 4-on-2 heads."""
+    from elasticdl_tpu.ops.pallas_attention import flash_attention_lse
+
+    if name == "keep":
+        q, k, v, keep = _keep_case()
+        return (lambda q, k, v: flash_attention_lse(
+            q, k, v, keep=keep, block_q=32, block_k=32, interpret=True)), (q, k, v), 0
+    t, heads, kv_heads, head, bq, bk, causal, window, (q_off, kv_off), _ = BACKWARD[name]
+    r = np.random.RandomState(37)
+    draw = lambda h: jnp.asarray(r.randn(1, t, h, head) * 0.5, jnp.float32)
+    # traced offsets, as ring attention passes them (a window takes none)
+    offsets = {} if window is not None else dict(
+        q_offset=jnp.int32(q_off), kv_offset=jnp.int32(kv_off))
+    f = lambda q, k, v: flash_attention_lse(q, k, v, causal=causal, window=window, block_q=bq,
+                                            block_k=bk, interpret=True, **offsets)
+    return f, (draw(heads), draw(kv_heads), draw(kv_heads)), max(0, min(t, kv_off - q_off))
+
+
+FORWARD = sorted(BACKWARD) + ["keep"]
+
+
+@pytest.mark.parametrize("name", FORWARD)
+def test_the_resident_forward_is_the_streaming_one_to_the_bit(name, monkeypatch):
+    """Output AND logsumexp by the kernel that holds a head's k and v in VMEM
+    and loops over a q block's kv blocks, against the one that takes a grid
+    step a pair: the same recurrence on the same blocks in the same order.
+    The grids tell the routes apart: the resident one has no kv axis."""
+    f, args, unseen = _forward_case(name)
+    got = {}
+    for route in ("resident", "streaming"):
+        take_fwd_route(monkeypatch, route)
+        # (JAX keeps a function's trace: a new one for each route)
+        (kernel, grid), = _kernel_grids(jax.make_jaxpr(lambda *a: f(*a))(*args).jaxpr).items()
+        assert kernel.endswith("_fwd") and len(grid) == {"resident": 3, "streaming": 4}[route]
+        got[route] = f(*args)
+    for a, b in zip(got["resident"], got["streaming"]):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    out, lse = got["resident"]
+    # rows that see no key: zero, and a logsumexp the ring merge reads as nothing
+    assert unseen == {"offsets_before": 48}.get(name, 0)
+    np.testing.assert_array_equal(np.asarray(out[:, :unseen]), 0.0)
+    np.testing.assert_array_equal(np.asarray(lse[..., :unseen]), np.float32(-1e30))
+    assert float(jnp.min(lse[..., unseen:])) > -1e3
+    assert float(jnp.min(jnp.max(jnp.abs(out[:, unseen:]), axis=-1))) > 0.0
+
+
+@pytest.mark.parametrize("name", FORWARD)
+def test_gradients_through_either_forward_route_are_equal_to_the_bit(name, monkeypatch):
+    """The residuals are q, k, v, out and the logsumexp whatever kernel made
+    the last two: one backward kernel after either forward, the same dq, dk,
+    dv, with a cotangent on both outputs."""
+    f, args, unseen = _forward_case(name)
+    r = np.random.RandomState(41)
+    out, lse = jax.eval_shape(f, *args)
+    probe, probe_lse = (jnp.asarray(r.randn(*x.shape), jnp.float32) for x in (out, lse))
+
+    def loss(*a):
+        out, lse = f(*a)
+        return jnp.sum(probe * out) + jnp.sum(probe_lse[..., unseen:] * lse[..., unseen:])
+
+    got = {}
+    for route in ("resident", "streaming"):
+        take_fwd_route(monkeypatch, route)
+        grids = _kernel_grids(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*args).jaxpr)
+        assert sorted(len(g) for g in grids.values()) == {"resident": [3, 3],
+                                                          "streaming": [3, 4]}[route]
+        got[route] = jax.grad(loss, argnums=(0, 1, 2))(*args)
+    for a, b in zip(got["resident"], got["streaming"]):
+        assert float(jnp.max(jnp.abs(a))) > 1e-3
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# (batch, tokens, query heads, key-value heads, head, window, a data mask) ->
+# the forward's blocks and route on a v5e's 128 MiB: the attention of
+# BENCHMARK.json's six language-model cells as their configurations give it,
+# then what does not fit
+FORWARD_ROUTES = {
+    "olmoe-1b-7b.resident-4k": ((2, 4096, 16, 16, 128, None, False), (1024, 1024), "resident"),
+    "nemotron-3-nano-30b-a3b.resident-8k": ((1, 8192, 32, 2, 128, None, False), (1024, 1024),
+                                            "resident"),
+    "glm-4.7-flash.resident-8k": ((1, 8192, 20, 20, 256, None, False), FWD_BLOCKS_256, "resident"),
+    "mellum2-12b-a2.5b.resident-16k/full": ((1, 16384, 32, 4, 128, None, False), (1024, 1024),
+                                            "resident"),
+    "mellum2-12b-a2.5b.resident-16k/sliding": ((1, 16384, 32, 4, 128, 1024, False), (1024, 1024),
+                                               "resident"),
+    "trinity-mini.resident-16k/sliding": ((1, 16384, 32, 4, 128, 2048, False), (1024, 1024),
+                                          "resident"),
+    # the q blocks of 512 are the backward's, whose strip sits beside dk and dv
+    "keye-vl-2.0-30b-a3b.resident-16k": ((1, 16384, 32, 4, 128, None, True), (1024, 1024),
+                                         "resident"),
+    # no dk and dv to hold: the forward fits where the backward splits
+    "32k_keys": ((1, 32768, 8, 2, 128, None, False), (1024, 1024), "resident"),
+    "128k_keys": ((1, 131072, 8, 2, 128, None, False), (1024, 1024), "streaming"),
+    "64k_keys_of_256": ((1, 65536, 2, 2, 256, None, False), FWD_BLOCKS_256, "streaming"),
+    "64k_keys_float32": ((1, 65536, 2, 2, 128, None, False), (1024, 1024), "streaming"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FORWARD_ROUTES))
+def test_the_forward_route_follows_the_head_s_bytes_and_logs_once_a_shape(
+        name, bwd_log, monkeypatch):
+    """`fwd_route` from the shapes alone, and the call as it lowers at the
+    cell's own shape (traced, nothing runs): the resident forward's grid is
+    (B, key-value heads, q blocks x the group's heads)."""
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    take_route(monkeypatch, "resident")            # a described v5e
+    (b, t, heads, kv_heads, head, window, keep), blocks, want = FORWARD_ROUTES[name]
+    dtype = jnp.float32 if name.endswith("float32") else jnp.bfloat16
+    q, k = ((b, t, h, head) for h in (heads, kv_heads))
+    assert pa._plan_blocks(q, k, None, None, dtype=dtype, keep=keep, forward=True) == blocks
+    plan = pa.fwd_route(t, head, dtype, *blocks, keep=keep)
+    assert plan.route == want
+    assert (plan.vmem_bytes <= plan.vmem_limit) == (want == "resident")
+    assert plan.vmem_limit == (128 << 20) * 3 // 4
+    # k and v twice buffered at least, and with a data mask the q block's strip
+    assert plan.vmem_bytes > 4 * t * head * jnp.dtype(dtype).itemsize + 2 * blocks[0] * t * keep
+    # every shape that takes the resident backward takes the resident forward
+    bwd = pa._plan_blocks(q, k, None, None, dtype=dtype, keep=keep)
+    assert want == "resident" or pa.bwd_route(t, head, dtype, *bwd, keep=keep).route == "split"
+
+    shaped = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, *plane: flash_attention(
+        q, k, v, window=window, keep=plane[0] if plane else None, interpret=False))(
+        shaped(q), shaped(k), shaped(k), *([shaped((b, t, t), jnp.int8)] if keep else [])).jaxpr
+    (kernel, grid), = _kernel_grids(jaxpr).items()
+    assert kernel == "flash_attention_" + ("swa_" if window else "sel_" if keep else "") + "fwd"
+    num_q = t // blocks[0]
+    assert grid == ((b, kv_heads, num_q * heads // kv_heads) if want == "resident"
+                    else (b, heads, num_q, t // blocks[1]))
+    lines = list({id(r): r.getMessage() for r in bwd_log.records
+                  if "forward" in r.getMessage()}.values())
+    assert len(lines) == 1 and f"takes the {want} route" in lines[0]
+    assert f"{t} keys, head {head}" in lines[0] and ("a data mask" in lines[0]) == keep
+    # a smaller chip: the same function, the other answer
+    monkeypatch.setattr(pa, "_vmem_bytes", lambda: 16 << 20)
+    assert pa.fwd_route(t, head, dtype, *blocks, keep=keep).route == "streaming"
+
+
+@pytest.mark.parametrize("route", ["resident", "streaming"])
+@pytest.mark.parametrize("case", sorted(RECOMPUTED))
+def test_a_kept_layer_holds_one_forward_call_on_either_forward_route(case, route, monkeypatch):
+    """A recomputed layer under `KEEP_RESIDUALS` holds ONE forward kernel call
+    whichever kernel that is, under a plain checkpoint two."""
+    take_fwd_route(monkeypatch, route)
+    loss, args = _recomputed_layer(case)
+    for wrap, calls in ((_kept, 1), (jax.checkpoint, 2)):
+        jaxpr = jax.make_jaxpr(jax.grad(loss(wrap), argnums=(0, 1, 2), has_aux=True))(*args).jaxpr
+        assert pallas_calls(jaxpr, "flash_attention_fwd") == calls
+        assert len(_kernel_grids(jaxpr)["flash_attention_fwd"]) == {"resident": 3,
+                                                                    "streaming": 4}[route]
+
+
+# ------------------------------------------------------------------ #
 # a mask that is data (`keep`)
 
 
@@ -202,10 +378,10 @@ def test_keep_of_all_ones_is_the_causal_call_to_the_bit(route, monkeypatch):
 
 
 @pytest.mark.parametrize("route,keep,want", [
-    ("resident", False, {"flash_attention_fwd": (2, 4, 4, 4), "flash_attention_bwd": (2, 2, 8)}),
+    ("resident", False, {"flash_attention_fwd": (2, 2, 8), "flash_attention_bwd": (2, 2, 8)}),
     ("split", False, {"flash_attention_fwd": (2, 4, 4, 4), "flash_attention_bwd_dq": (2, 4, 4, 4),
                       "flash_attention_bwd_dkv": (2, 2, 4, 8)}),
-    ("resident", True, {"flash_attention_sel_fwd": (2, 4, 4, 4),
+    ("resident", True, {"flash_attention_sel_fwd": (2, 2, 8),
                         "flash_attention_sel_bwd": (2, 2, 8)}),
     ("split", True, {"flash_attention_sel_fwd": (2, 4, 4, 4),
                      "flash_attention_sel_bwd_dq": (2, 4, 4, 4),
@@ -234,6 +410,8 @@ def test_a_keep_call_plans_smaller_q_blocks_and_counts_its_strip():
     shape = (1, 16384, 32, 128)
     assert pa._plan_blocks(shape, shape, None, None, dtype=jnp.bfloat16) == (1024, 1024)
     assert pa._plan_blocks(shape, shape, None, None, dtype=jnp.bfloat16, keep=True) == (512, 1024)
+    assert pa._plan_blocks(shape, shape, None, None, dtype=jnp.bfloat16, keep=True,
+                           forward=True) == (1024, 1024)
     vmem = 128 << 20
     plan = lambda bq, keep: pa._bwd_plan(16384, 128, "bfloat16", bq, 1024, vmem, keep)
     assert plan(1024, False).route == "resident" and plan(512, True).route == "resident"

@@ -108,17 +108,17 @@ def _kernel_grids(jaxpr):
 
 
 @pytest.mark.parametrize("route,window,want", [
-    # one backward call: (B, key-value heads, 4 heads a group x 6 q blocks)
-    ("resident", None, {"flash_attention_fwd": (1, 8, 6, 6), "flash_attention_bwd": (1, 2, 24)}),
-    # W = a block: 2 kv blocks a q block; the backward's grid does not band,
-    # its loop over a q block's kv blocks does
-    ("resident", 16, {"flash_attention_swa_fwd": (1, 8, 6, 2),
+    # one call each way: (B, key-value heads, 6 q blocks x 4 heads a group)
+    ("resident", None, {"flash_attention_fwd": (1, 2, 24), "flash_attention_bwd": (1, 2, 24)}),
+    # W = a block: 2 kv blocks a q block; the resident kernels' grids do not
+    # band, their loops over a q block's kv blocks do
+    ("resident", 16, {"flash_attention_swa_fwd": (1, 2, 24),
                       "flash_attention_swa_bwd": (1, 2, 24)}),
-    ("resident", 40, {"flash_attention_swa_fwd": (1, 8, 6, 4),
+    ("resident", 40, {"flash_attention_swa_fwd": (1, 2, 24),
                       "flash_attention_swa_bwd": (1, 2, 24)}),
-    # W = two blocks (trinity-mini's 2048 over 1024-blocks): 3 kv blocks a q block
-    ("resident", 32, {"flash_attention_swa_fwd": (1, 8, 6, 3),
+    ("resident", 32, {"flash_attention_swa_fwd": (1, 2, 24),
                       "flash_attention_swa_bwd": (1, 2, 24)}),
+    # a head that does not fit: the streaming forward, the dq and the dkv kernel
     ("split", None, {"flash_attention_fwd": (1, 8, 6, 6), "flash_attention_bwd_dq": (1, 8, 6, 6),
                      "flash_attention_bwd_dkv": (1, 2, 6, 24)}),
     # W = a block: 2 kv blocks a q block, 2 q blocks a kv block (x 4 heads a group)
@@ -126,12 +126,16 @@ def _kernel_grids(jaxpr):
                    "flash_attention_swa_bwd_dkv": (1, 2, 6, 8)}),
     ("split", 40, {"flash_attention_swa_fwd": (1, 8, 6, 4), "flash_attention_swa_bwd_dq": (1, 8, 6, 4),
                    "flash_attention_swa_bwd_dkv": (1, 2, 6, 16)}),
+    # W = two blocks (trinity-mini's 2048 over 1024-blocks): 3 kv blocks a q block
+    ("split", 32, {"flash_attention_swa_fwd": (1, 8, 6, 3), "flash_attention_swa_bwd_dq": (1, 8, 6, 3),
+                   "flash_attention_swa_bwd_dkv": (1, 2, 6, 12)}),
 ])
 def test_the_grid_is_banded_under_a_window_and_as_it_was_without(route, window, want, monkeypatch):
     """Read off the lowered calls: `window=None` keeps the unbanded grid and
-    the plain kernel names; a window shortens the kv axis of the forward grid
-    (and of the split route's dq grid, and the q axis of its dkv grid), under
-    names of their own."""
+    the plain kernel names; a window shortens the kv axis of the streaming
+    forward's grid (and of the split route's dq grid, and the q axis of its dkv
+    grid), under names of their own; the resident kernels' grids have no kv
+    axis."""
     take_route(monkeypatch, route)
     q, k, v = _windowed_case(96, 8, 2)
     f = lambda *a: jnp.sum(flash_attention(*a, window=window, block_q=16, block_k=16,
