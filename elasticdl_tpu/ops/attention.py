@@ -57,9 +57,11 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    window: Optional[int] = None,
                    keep: Optional[jax.Array] = None,
                    with_lse: bool = False):
-    """Plain softmax attention. q: (B, T, H, D); k, v: (B, T, Hkv, D) with H a
-    multiple of Hkv (grouped-query attention: query head h attends with
-    key-value head h // (H/Hkv); Hkv == H is the ordinary case). The offsets position the
+    """Plain softmax attention. q: (B, T, H, D); k: (B, T, Hkv, D); v: (B, T,
+    Hkv, Dv) with H a multiple of Hkv (grouped-query attention: query head h
+    attends with key-value head h // (H/Hkv); Hkv == H is the ordinary case)
+    and Dv any width (latent attention's values are narrower than its keys):
+    the output is (B, T, H, Dv), the scale D^-1/2. The offsets position the
     local q/kv blocks in the GLOBAL sequence for causal masking (used by the
     sequence-parallel paths; leave 0 for unsharded attention).
 
@@ -137,7 +139,7 @@ def _grouped_query_attention(q, k, v, mask, with_lse=False):
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    out = out.reshape(q.shape).astype(q.dtype)
+    out = out.reshape(b, tq, h, v.shape[-1]).astype(q.dtype)
     if with_lse:
         return out, jax.nn.logsumexp(s, axis=-1).reshape(b, h, tq)
     return out
@@ -203,7 +205,7 @@ def _ring_attention_sharded(q, k, v, axis_name: str, causal: bool,
 
     o, m, l = _ring_scan(
         k, v, axis_name, manual_axes, accumulate,
-        (jnp.zeros((B, H, Lq, D), jnp.float32),
+        (jnp.zeros((B, H, Lq, v.shape[-1]), jnp.float32),
          jnp.full((B, H, Lq), NEG_BIG, jnp.float32),
          jnp.zeros((B, H, Lq), jnp.float32)),
     )
@@ -245,7 +247,7 @@ def _ring_attention_flash(q, k, v, axis_name: str, causal: bool,
     # zero-weight initial carry: lse=NEG_BIG merges to "no contribution"
     o, _ = _ring_scan(
         k, v, axis_name, manual_axes, accumulate,
-        (jnp.zeros((B, Lq, H, D), jnp.float32),
+        (jnp.zeros((B, Lq, H, v.shape[-1]), jnp.float32),
          jnp.full((B, H, Lq), NEG_BIG, jnp.float32)),
     )
     return o.astype(q.dtype)

@@ -83,6 +83,16 @@ passes a different kv offset each ppermute rotation. `flash_attention_lse`
 additionally returns the logsumexp, which is what lets ring attention merge
 per-block flash results exactly (see ops.attention._ring_attention_flash).
 
+Two head widths: q and k share one (D), v and the output another (Dv) —
+latent attention's keys carry a rotary part their values lack (192 beside 128).
+`out`, its cotangent, dv and the forward's accumulator are Dv wide; q, k, dq
+and dk D wide; the softmax scale is D^-1/2. Every BlockSpec, scratch shape and
+both plans take the two apart, and nothing is padded: a v widened to D would
+spend D/Dv of the p·v matmuls, of `out` and of the kept residuals. With
+Dv == D the calls, grids, blocks and plans are what they were. A width over
+the lane tile that is no multiple of it (192) is a full-dimension block, which
+Mosaic lays out in whole tiles: the plans count it so (`_in_vmem`).
+
 Grouped-query attention: k and v may carry FEWER heads than q (B, T, Hkv, D
 with H a multiple of Hkv); query head h reads key-value head h // (H/Hkv)
 through the K/V BlockSpec index maps, so no copy of K or V widened to H
@@ -471,8 +481,8 @@ def _softmax_init(acc, m_scr, l_scr):
 
 def _softmax_step(q, k, v, mask, acc, m_scr, l_scr, scale):
     """One (q block, kv block) pair of the online-softmax recurrence, the
-    same values on both forward routes. q (bq, D), k and v (bk, D), `mask`:
-    the block's element mask or None."""
+    same values on both forward routes. q (bq, D), k (bk, D), v (bk, Dv), the
+    accumulator (bq, Dv); `mask`: the block's element mask or None."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -608,57 +618,71 @@ class Plan(NamedTuple):
     vmem_limit: int  # what Mosaic may use
 
 
-def _plan(part, other, what, need, t_k, head_dim, dtype_name, bq, bk, vmem, keep):
+def _plan(part, other, what, need, t_k, head_dim, v_dim, dtype_name, bq, bk, vmem,
+          keep):
     limit = vmem * 3 // 4
     plan = Plan("resident" if need <= limit else other, need, limit)
     # once a shape and process: which kernel this shape takes
+    head = f"head {head_dim}" if v_dim == head_dim else f"head {head_dim} | v {v_dim}"
     logger.info(
-        "flash attention's %s (%d keys, head %d, %s, blocks %d x %d%s) "
+        "flash attention's %s (%d keys, %s, %s, blocks %d x %d%s) "
         "takes the %s route: a head's %s resident in VMEM need "
-        "%d bytes of the %d a kernel may use here", part, t_k, head_dim, dtype_name,
+        "%d bytes of the %d a kernel may use here", part, t_k, head, dtype_name,
         bq, bk, ", a data mask" if keep else "", plan.route, what, need, limit)
     return plan
 
 
+def _in_vmem(dim: int) -> int:
+    """The lanes a minor dimension of `dim` takes in VMEM where it is wider
+    than the lane tile and no multiple of it (q and k heads of 192: two whole
+    tiles); a narrower one is counted as it is."""
+    return dim if dim <= _LANE else -(-dim // _LANE) * _LANE
+
+
 @functools.lru_cache(maxsize=None)
 def _fwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
-              vmem: int, keep: bool = False) -> Plan:
+              vmem: int, keep: bool = False, v_dim: Optional[int] = None) -> Plan:
+    """`head_dim`: q's and k's; `v_dim`: v's and the output's (None: the same)."""
     size = jnp.dtype(dtype_name).itemsize
+    v_dim = v_dim or head_dim
+    qk, vo = _in_vmem(head_dim), _in_vmem(v_dim)
     # two buffers each of k and v; a step: two buffers each of q and out, of
     # the logsumexp; the float32 accumulator, maximum and sum; the float32
     # (bq, bk) values (s, p and two more) and v's float32 form; with a data
     # mask two buffers of the q block's int8 strip and a block of it widened
-    need = (4 * t_k * head_dim * size
-            + 4 * bq * head_dim * size + 16 * bq * _LANE + 4 * bq * head_dim
-            + 16 * bq * bk + 4 * bk * head_dim
+    need = (2 * t_k * (qk + vo) * size
+            + 2 * bq * (qk + vo) * size + 16 * bq * _LANE + 4 * bq * vo
+            + 16 * bq * bk + 4 * bk * vo
             + (2 * bq * t_k + 4 * bq * bk if keep else 0))
-    return _plan("forward", "streaming", "k and v", need, t_k, head_dim, dtype_name,
-                 bq, bk, vmem, keep)
+    return _plan("forward", "streaming", "k and v", need, t_k, head_dim, v_dim,
+                 dtype_name, bq, bk, vmem, keep)
 
 
 def fwd_route(t_k: int, head_dim: int, dtype, bq: int, bk: int,
-              keep: bool = False) -> Plan:
+              keep: bool = False, v_dim: Optional[int] = None) -> Plan:
     """Which forward a call of `t_k` keys a head takes — `resident`: the
     key-value head's whole k and v in VMEM, fetched once a head, and a grid
     step a q block, which loops over the kv blocks it sees; or `streaming`: a
     grid step and a K/V fetch a (q block, kv block) pair, where the head does
     not fit. A pure function of the shapes, the dtype, whether the call has a
     data mask (`keep`: the q block's strip of it sits beside k and v) and the
-    chip's VMEM; nothing a caller sets."""
+    chip's VMEM; nothing a caller sets. `head_dim` is q's and k's, `v_dim` v's
+    and the output's where it is another (latent attention's 192 and 128)."""
     return _fwd_plan(t_k, head_dim, jnp.dtype(dtype).name, bq, bk, _vmem_bytes(),
-                     bool(keep))
+                     bool(keep), v_dim or head_dim)
 
 
 def _flash_fwd(offs, qt, kt, vt, keep=None, *, causal, bq, bk, interpret,
                window=None):
-    """offs: (2,) int32 [q_off, kv_off]; qt/kt/vt: (B, H, T, D); keep: None or
-    (B, Tq, Tk) int8. (out (B, H, Tq, D), logsumexp (B, H, Tq, 128) float32),
-    by the route `fwd_route` gives: the same values to the bit by either."""
+    """offs: (2,) int32 [q_off, kv_off]; qt/kt: (B, H, T, D), vt: (B, H, T,
+    Dv); keep: None or (B, Tq, Tk) int8. (out (B, H, Tq, Dv), logsumexp (B, H,
+    Tq, 128) float32), by the route `fwd_route` gives: the same values to the
+    bit by either."""
     B, H, Tq, D = qt.shape
-    Hkv, Tk = kt.shape[1], kt.shape[2]
+    Hkv, Tk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     num_q, num_kv = Tq // bq, Tk // bk
     kv_head = _kv_head_of(H, Hkv)
-    plan = fwd_route(Tk, D, qt.dtype, bq, bk, keep is not None)
+    plan = fwd_route(Tk, D, qt.dtype, bq, bk, keep is not None, Dv)
     static = dict(scale=D ** -0.5, causal=causal, block_q=bq, block_k=bk,
                   window=window)
     if plan.route == "resident":
@@ -674,7 +698,7 @@ def _flash_fwd(offs, qt, kt, vt, keep=None, *, causal, bq, bk, interpret,
             q_at = lambda b, h, y, offs: (b, h, y, 0)
         else:
             q_at = lambda b, h, y, offs: (b, h * group + y % group, y // group, 0)
-        kv_spec = pl.BlockSpec((1, 1, Tk, D), lambda b, h, y, offs: (b, h, 0, 0))
+        kv_spec = lambda d: pl.BlockSpec((1, 1, Tk, d), lambda b, h, y, offs: (b, h, 0, 0))
         keep_spec = pl.BlockSpec((1, bq, Tk), lambda b, h, y, offs: (b, y // group, 0))
     else:
         if window is None:
@@ -686,28 +710,28 @@ def _flash_fwd(offs, qt, kt, vt, keep=None, *, causal, bq, bk, interpret,
                                 kv_blocks=num_kv, **static)
         grid = (B, H, num_q, steps)
         q_at = lambda b, h, i, j, offs: (b, h, i, 0)
-        kv_spec = pl.BlockSpec(
-            (1, 1, bk, D), lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
+        kv_spec = lambda d: pl.BlockSpec(
+            (1, 1, bk, d), lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
         keep_at = _keep_kv_at(causal, bq, bk, num_kv)
         keep_spec = pl.BlockSpec((1, bq, bk),
                                  lambda b, h, i, j, offs: (b, i, keep_at(i, j)))
-    q_spec = pl.BlockSpec((1, 1, bq, D), q_at)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec]
+            in_specs=[pl.BlockSpec((1, 1, bq, D), q_at), kv_spec(D), kv_spec(Dv)]
             + ([keep_spec] if keep is not None else []),
-            out_specs=[q_spec, pl.BlockSpec((1, 1, bq, _LANE), q_at)],
+            out_specs=[pl.BlockSpec((1, 1, bq, Dv), q_at),
+                       pl.BlockSpec((1, 1, bq, _LANE), q_at)],
             scratch_shapes=[
-                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, Dv), jnp.float32),
                 pltpu.VMEM((bq, _LANE), jnp.float32),
                 pltpu.VMEM((bq, _LANE), jnp.float32),
             ],
         ),
         out_shape=[
-            _sds(qt.shape, qt.dtype, qt),
+            _sds((B, H, Tq, Dv), qt.dtype, qt),
             _sds((B, H, Tq, _LANE), jnp.float32, qt),
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=plan.vmem_limit),
@@ -920,48 +944,52 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 @functools.lru_cache(maxsize=None)
 def _bwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
-              vmem: int, keep: bool = False) -> Plan:
+              vmem: int, keep: bool = False, v_dim: Optional[int] = None) -> Plan:
+    """`head_dim`: q's, k's, dq's and dk's; `v_dim`: v's, the output's, its
+    cotangent's and dv's (None: the same)."""
     size = jnp.dtype(dtype_name).itemsize
-    head = t_k * head_dim
+    v_dim = v_dim or head_dim
+    qk, vo = _in_vmem(head_dim), _in_vmem(v_dim)
     # two buffers each of k, v in and dk, dv out; dk, dv in float32
-    resident = head * (8 * size + 8)
+    resident = t_k * (qk + vo) * (4 * size + 4)
     # a step: two buffers each of q, o, do in and dq out, of lse and its
     # cotangent; dq in float32; the float32 (bq, bk) values (s, p, dp, ds and
     # a transposed operand) and float32 forms of q, do, o, k, v; with a data
     # mask two buffers of the q block's int8 strip and a block of it widened
-    step = (8 * bq * head_dim * size + 16 * bq * _LANE + 4 * bq * head_dim
-            + 20 * bq * bk + 4 * (3 * bq + 2 * bk) * head_dim
+    step = (4 * bq * (qk + vo) * size + 16 * bq * _LANE + 4 * bq * qk
+            + 20 * bq * bk + 4 * (bq * (qk + 2 * vo) + bk * (qk + vo))
             + (2 * bq * t_k + 4 * bq * bk if keep else 0))
     return _plan("backward", "split", "k, v, dk and dv", resident + step, t_k,
-                 head_dim, dtype_name, bq, bk, vmem, keep)
+                 head_dim, v_dim, dtype_name, bq, bk, vmem, keep)
 
 
 def bwd_route(t_k: int, head_dim: int, dtype, bq: int, bk: int,
-              keep: bool = False) -> Plan:
+              keep: bool = False, v_dim: Optional[int] = None) -> Plan:
     """Which backward a call of `t_k` keys a head takes — `resident`: ONE
     kernel, the key-value head's whole k and v and its float32 dk and dv in
     VMEM, a pair's score block computed once for dq, dk and dv; or `split`: a
     dq kernel and a dkv kernel that each stream kv blocks and each recompute
     the score block, where the head does not fit. A pure function of the
     shapes, the dtype, whether the call has a data mask (`keep`: its strip
-    sits beside k and v) and the chip's VMEM; nothing a caller sets."""
+    sits beside k and v) and the chip's VMEM; nothing a caller sets. `head_dim`
+    and `v_dim` as `fwd_route`'s."""
     return _bwd_plan(t_k, head_dim, jnp.dtype(dtype).name, bq, bk, _vmem_bytes(),
-                     bool(keep))
+                     bool(keep), v_dim or head_dim)
 
 
 def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
-    """g: cotangent of out (B, T, H, D); g_lse: cotangent of lse (B, H, Tq)
+    """g: cotangent of out (B, T, H, Dv); g_lse: cotangent of lse (B, H, Tq)
     or None (out-only variant). `res` ends with the data mask where the call
     had one."""
-    offs, qt, kt, vt, ot, lse, *keep = res       # (B, H, T, D) / lse 4D
+    offs, qt, kt, vt, ot, lse, *keep = res       # (B, H, T, D or Dv) / lse 4D
     B, H, Tq, D = qt.shape
-    gt = g.transpose(0, 2, 1, 3)                 # (B, H, Tq, D)
+    gt = g.transpose(0, 2, 1, 3)                 # (B, H, Tq, Dv)
     operands = (offs, qt, kt, vt, ot, gt, lse)
     if g_lse is not None:
         operands += (jnp.broadcast_to(
             g_lse.astype(jnp.float32)[..., None], (B, H, Tq, _LANE)),)
     operands += tuple(keep)
-    plan = bwd_route(kt.shape[2], D, qt.dtype, bq, bk, bool(keep))
+    plan = bwd_route(kt.shape[2], D, qt.dtype, bq, bk, bool(keep), vt.shape[3])
     static = dict(causal=causal, bq=bq, bk=bk, interpret=interpret, window=window,
                   optional=(g_lse is not None, bool(keep)))
     if plan.route == "resident":
@@ -980,16 +1008,17 @@ def _bwd_resident(operands, vmem_limit, *, causal, bq, bk, interpret, window,
     the operands end with (the logsumexp's cotangent, the data mask)."""
     _, qt, kt, vt = operands[:4]
     B, H, Tq, D = qt.shape
-    Hkv, Tk = kt.shape[1], kt.shape[2]
+    Hkv, Tk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     num_q, group = Tq // bq, H // Hkv
     with_glse, with_keep = optional
     if group == 1:
         q_at = lambda b, h, y, offs: (b, h, y, 0)
     else:
         q_at = lambda b, h, y, offs: (b, h * group + y // num_q, y % num_q, 0)
-    q_spec = pl.BlockSpec((1, 1, bq, D), q_at)
+    q_spec, o_spec = (pl.BlockSpec((1, 1, bq, d), q_at) for d in (D, Dv))
     lse_spec = pl.BlockSpec((1, 1, bq, _LANE), q_at)
-    kv_spec = pl.BlockSpec((1, 1, Tk, D), lambda b, h, y, offs: (b, h, 0, 0))
+    k_spec, v_spec = (pl.BlockSpec((1, 1, Tk, d), lambda b, h, y, offs: (b, h, 0, 0))
+                      for d in (D, Dv))
     # the q block's strip of the mask: every key, as k and v are whole
     keep_spec = pl.BlockSpec((1, bq, Tk), lambda b, h, y, offs: (b, y % num_q, 0))
     return pl.pallas_call(
@@ -999,14 +1028,14 @@ def _bwd_resident(operands, vmem_limit, *, causal, bq, bk, interpret, window,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, Hkv, group * num_q),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec]
+            in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, lse_spec]
             + ([lse_spec] if with_glse else [])
             + ([keep_spec] if with_keep else []),
-            out_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec, k_spec, v_spec],
             scratch_shapes=[
                 pltpu.VMEM((bq, D), jnp.float32),
                 pltpu.VMEM((Tk, D), jnp.float32),
-                pltpu.VMEM((Tk, D), jnp.float32),
+                pltpu.VMEM((Tk, Dv), jnp.float32),
             ],
         ),
         out_shape=[
@@ -1026,7 +1055,7 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window, optional):
     `_bwd_resident`'s."""
     _, qt, kt, vt = operands[:4]
     B, H, Tq, D = qt.shape
-    Hkv, Tk = kt.shape[1], kt.shape[2]
+    Hkv, Tk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     num_q, num_kv = Tq // bq, Tk // bk
     scale = D ** -0.5
     kv_head, group = _kv_head_of(H, Hkv), H // Hkv
@@ -1042,9 +1071,12 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window, optional):
         q_block_at = lambda x, y: jnp.minimum(
             _first_q(x, bq, bk) + y, _last_q(x, bq, bk, window, num_q))
 
-    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, offs: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, D),
-                           lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
+    q_spec, o_spec = (pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j, offs: (b, h, i, 0))
+                      for d in (D, Dv))
+    k_spec, v_spec = (
+        pl.BlockSpec((1, 1, bk, d),
+                     lambda b, h, i, j, offs: (b, kv_head(h), kv_at(i, j), 0))
+        for d in (D, Dv))
     lse_spec = pl.BlockSpec((1, 1, bq, _LANE),
                             lambda b, h, i, j, offs: (b, h, i, 0))
     keep_kv = _keep_kv_at(causal, bq, bk, num_kv)
@@ -1058,7 +1090,7 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window, optional):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, num_q, kv_steps),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec]
+            in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, lse_spec]
             + ([lse_spec] if with_glse else [])
             + ([keep_spec] if with_keep else []),
             out_specs=[q_spec],
@@ -1081,8 +1113,9 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window, optional):
     else:
         q_at = lambda b, h, x, y, offs: (
             b, h * group + y // q_steps, q_block_at(x, y % q_steps), 0)
-    q_spec2 = pl.BlockSpec((1, 1, bq, D), q_at)
-    kv_spec2 = pl.BlockSpec((1, 1, bk, D), lambda b, h, x, y, offs: (b, h, x, 0))
+    q_spec2, o_spec2 = (pl.BlockSpec((1, 1, bq, d), q_at) for d in (D, Dv))
+    k_spec2, v_spec2 = (pl.BlockSpec((1, 1, bk, d), lambda b, h, x, y, offs: (b, h, x, 0))
+                        for d in (D, Dv))
     lse_spec2 = pl.BlockSpec((1, 1, bq, _LANE), q_at)
     keep_q = (lambda x, y: jnp.maximum(y, _first_q(x, bq, bk))) if causal \
         else (lambda x, y: y)
@@ -1095,13 +1128,13 @@ def _bwd_split(operands, *, causal, bq, bk, interpret, window, optional):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, Hkv, num_kv, group * q_steps),
-            in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, q_spec2,
+            in_specs=[q_spec2, k_spec2, v_spec2, o_spec2, o_spec2,
                       lse_spec2] + ([lse_spec2] if with_glse else [])
             + ([keep_spec2] if with_keep else []),
-            out_specs=[kv_spec2, kv_spec2],
+            out_specs=[k_spec2, v_spec2],
             scratch_shapes=[
                 pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, Dv), jnp.float32),
             ],
         ),
         out_shape=[
@@ -1170,8 +1203,9 @@ def flash_attention_lse(
     window: Optional[int] = None,
     keep: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Flash attention over q (B, T, H, D) and k/v (B, T, Hkv, D), H a
-    multiple of Hkv, returning (out, lse) with lse (B, H, Tq) float32. Offsets may be Python ints OR traced int32
+    """Flash attention over q (B, T, H, D), k (B, T, Hkv, D) and v (B, T, Hkv,
+    Dv), H a multiple of Hkv, returning (out (B, T, H, Dv), lse) with lse
+    (B, H, Tq) float32. Offsets may be Python ints OR traced int32
     scalars (they ride scalar prefetch). `window=W` (causal, zero offsets):
     query i sees keys i − W < j ≤ i, through the banded kernels. `keep` (B,
     Tq, Tk) int8 or bool (no window, zero offsets): query i sees key j iff
@@ -1290,8 +1324,9 @@ def _plan_blocks(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...],
     and with or without a data mask (`keep`, whose int8 tiles also set the
     least block): it passes a VMEM limit of its own and holds no dk and dv.
     The BACKWARD takes q blocks of `SEL_BLOCK_Q` with a data mask, and at a
-    head wider than the lane width a key block smaller in proportion, so that
-    a key block's rows times the head size stay what they are at 128: at head
+    head (q's and k's) wider than the lane width a key block smaller in
+    proportion, so that a key block's rows times the head size stay what they
+    are at 128 (at 192 the power of two under 682: 512): at head
     256 and (1024, 1024) the split dq kernel's blocks and scratch need 16.9 MB
     of the 16 MB it may use; T=8192, 20 heads, bfloat16 on a v5e, forward +
     backward at one plan for both: (1024, 512) 25.6 ms, (512, 1024) 25.6,

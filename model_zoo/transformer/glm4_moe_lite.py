@@ -167,11 +167,19 @@ class Config:
 # The mathematics: pure functions of (parameters, activations)
 
 
-def latent_attention(p: Dict[str, jax.Array], x: jax.Array, cfg: Config) -> jax.Array:
-    """The attention sub-block's update of the residual stream x (B, T, C)."""
+def latent_attention(p: Dict[str, jax.Array], x: jax.Array, cfg, rotate=None,
+                     q_scale=None) -> jax.Array:
+    """The attention sub-block's update of the residual stream x (B, T, C).
+    `rotate`: the rotary map of q's rotary part (B, T, H, rot) and of the one
+    rotary key (B, T, 1, rot), where it is not the plain table at
+    `cfg.rope_theta` (`xing4.py`: YaRN's); `q_scale`: a factor on the scores
+    beside (nope + rope)^-1/2, applied to q in float32 before it is rounded
+    for the kernel. v may be narrower than q and k (`v_head_dim`)."""
     dt = jnp.dtype(cfg.compute_dtype)
     b, t, _ = x.shape
     heads, nope, rot = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    if rotate is None:
+        rotate = lambda part: rope(part, cfg.rope_theta)
     h = rmsnorm(x, p["attn_norm"], cfg.rms_norm_eps)
     with jax.named_scope("q_lora"):
         c_q = rmsnorm(_matmul(h, p["q_a"], dt, jnp.float32), p["q_a_norm"],
@@ -183,10 +191,10 @@ def latent_attention(p: Dict[str, jax.Array], x: jax.Array, cfg: Config) -> jax.
         k_r = kv_a[..., cfg.kv_lora_rank:].reshape(b, t, 1, rot)
         kv = _matmul(c_kv, p["kv_b"], dt).reshape(b, t, heads, nope + cfg.v_head_dim)
     with jax.named_scope("rope"):
-        q = jnp.concatenate(
-            [q[..., :nope], rope(q[..., nope:], cfg.rope_theta)], axis=-1).astype(dt)
+        q = jnp.concatenate([q[..., :nope], rotate(q[..., nope:])], axis=-1)
+        q = (q if q_scale is None else q * q_scale).astype(dt)
         # one rotary key head, used by every query head
-        k_r = jnp.broadcast_to(rope(k_r, cfg.rope_theta).astype(dt), (b, t, heads, rot))
+        k_r = jnp.broadcast_to(rotate(k_r).astype(dt), (b, t, heads, rot))
         k = jnp.concatenate([kv[..., :nope], k_r], axis=-1)
     with jax.named_scope("attn"):
         out = full_attention(q, k, kv[..., nope:], causal=True)

@@ -43,6 +43,10 @@ SHAPES = {
     # of 32 768 rows (twice the even share of 131 072 pairs)
     "trinity_up": (32768, 2048, 1024, 16),
     "trinity_down": (32768, 1024, 2048, 16),
+    # xing4.0-29b-a4b.resident-4k: 8 of 64 held at hidden 3584, a pass of 4096
+    # rows (twice the even share of 16 384 pairs)
+    "xing_up": (4096, 3584, 1024, 8),
+    "xing_down": (4096, 1024, 3584, 8),
 }
 
 
@@ -143,6 +147,39 @@ def test_flash_kernels_compile_for_a_v5e(name, one_chip, no_compile_cache):
     calls = re.findall(r"^\s*%\w*?(flash_attention_[a-z_]*?)_*\.\d+ = .*tpu_custom_call", text, re.M)
     prefix = "flash_attention_swa_" if window else "flash_attention_"
     assert sorted(calls) == [prefix + part for part in (
+        ["bwd", "fwd"] if route == "resident" else ["bwd_dkv", "bwd_dq", "fwd"])]
+    assert text.count('custom_call_target="tpu_custom_call"') == len(calls)
+
+
+@pytest.mark.parametrize("route", ["resident", "split"])
+def test_flash_kernels_at_two_head_widths_compile_for_a_v5e(route, one_chip, no_compile_cache,
+                                                            monkeypatch):
+    """xing4.0-29b-a4b.resident-4k's attention: 32 heads whose q and k are 192
+    wide (no multiple of the 128-lane tile: a full-dimension block, two tiles in
+    VMEM) and whose v is 128, 4096 keys. One forward and one backward kernel
+    with the head resident; on a chip of 32 MiB the streaming forward and the
+    split route's two kernels, the backward's key block 512 either way."""
+    from elasticdl_tpu.ops import pallas_attention
+
+    if route == "split":
+        monkeypatch.setattr(pallas_attention, "_vmem_bytes", lambda: 32 << 20)
+    shape = lambda d: jax.ShapeDtypeStruct((1, 4096, 32, d), jnp.bfloat16, sharding=one_chip)
+    q, v = shape(192), shape(128)
+    assert pallas_attention._plan_blocks(q.shape, q.shape, None, None,
+                                         dtype=jnp.bfloat16) == (1024, 512)
+    assert pallas_attention.bwd_route(4096, 192, jnp.bfloat16, 1024, 512,
+                                      v_dim=128).route == route
+    assert pallas_attention.fwd_route(4096, 192, jnp.bfloat16, 1024, 1024, v_dim=128).route == (
+        "resident" if route == "resident" else "streaming")
+
+    def forward_and_backward(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: pallas_attention.flash_attention(
+            q, k, v, causal=True, interpret=False), q, k, v)
+        return out, vjp(do)
+
+    text = jax.jit(forward_and_backward).lower(q, q, v, v).compile().as_text()
+    calls = re.findall(r"^\s*%\w*?(flash_attention_[a-z_]*?)_*\.\d+ = .*tpu_custom_call", text, re.M)
+    assert sorted(calls) == ["flash_attention_" + part for part in (
         ["bwd", "fwd"] if route == "resident" else ["bwd_dkv", "bwd_dq", "fwd"])]
     assert text.count('custom_call_target="tpu_custom_call"') == len(calls)
 

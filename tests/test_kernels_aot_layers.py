@@ -77,6 +77,11 @@ RECOMPUTED_ATTENTION = {
     "mellum": ("mellum", dict(
         num_hidden_layers=4, num_experts=8, router_experts=64, vocab_size=512), 16384,
         [("mellum/sliding/attn", _BANDED)] * 3 + [("mellum/full/attn", _CAUSAL)]),
+    # xing4.0-29b-a4b.resident-4k: the dense layer and a sparse one around four
+    # streams, 32 heads with q and k of 128 + 64 and v of 128
+    "xing4": ("xing4", dict(
+        num_hidden_layers=2, first_k_dense_replace=1, n_routed_experts=8,
+        router_experts=64, vocab_size=512), 4096, [("xing4/mla/attn", _CAUSAL)] * 2),
 }
 
 
