@@ -6,7 +6,7 @@ Three functions, each over BLOCKS OF QUERY ROWS so that no (heads, T, T) array
 exists, and of the (T, T) planes only `keep` (int8) — `select` and `index_kl`
 each make the score plane's blocks as they need them (`_score_block`) and hold
 no plane of scores or of their gradient. Blocked XLA, but for ONE kernel: the
-pull-back of a score block in the index loss's backward (`index_score_bwd`),
+pull-back of a score block to the indexer's operands (`index_score_bwd`),
 where XLA's own form writes the 16 heads' float32 scores and their cotangent
 to HBM. What each costs on the chip is in PERF.md sections 5 and 7.
 
@@ -30,24 +30,34 @@ to HBM. What each costs on the chip is in PERF.md sections 5 and 7.
   kept keys (the probabilities of the attention whose logsumexp `lse` is),
   p̂[t, s] = (1/H) Σ_h P and π[t, ·] the softmax of I[t, ·] over the kept keys:
   `L = (1/T) Σ_t Σ_s p̂ (log p̂ − log π)`, averaged over the batch. A custom
-  rule: `∂L/∂I = (π − p̂) / T` on the kept keys, pulled back block by block to
-  the indexer's operands (the scores of a block are computed again there, so
-  neither the plane nor its gradient is ever held in the backward pass), and
-  NOTHING for q, k or lse — p̂ is a target, not a path. The pull-back has two
-  forms that share no logic, chosen by what the code can see (`pullback_keys`,
-  as `pallas_attention.can_flash` chooses for attention): on a TPU (or inside
-  `pallas_attention.interpret_mode()`) at shapes that tile, the Mosaic kernel
-  `index_score_bwd` — a grid over key tiles; per tile and head the score block
-  again in VMEM, `ds = dL/dI · w · (s > 0)`, `dq += ds · k`, `dk += dsᵀ · q`,
-  `dw += Σ dL/dI · relu(s)`; a tile in the rows' future skipped — and
-  everywhere else, and under `EDL_FLASH=0`, `jax.vjp(_score_block)`.
+  rule: `∂L/∂I = (π − p̂) / T` on the kept keys, pulled back to the indexer's
+  operands, and NOTHING for q, k or lse — p̂ is a target, not a path. The
+  rule's cotangent enters as a scalar factor and nowhere else, so the loss is
+  evaluated ONCE: the FORWARD rule makes the loss and its three gradients at a
+  unit cotangent in one sweep over the blocks of 128 query rows (a block's
+  scores, target and π once; neither the plane nor its gradient is ever held)
+  and keeps the gradients — no operand — under `INDEX_GRADIENT_NAMES`; the
+  backward rule multiplies them by its cotangent and evaluates nothing. The
+  pull-back has two forms that share no logic, chosen by what the code can
+  see (`pullback_keys`, as `pallas_attention.can_flash` chooses for
+  attention): on a TPU (or inside `pallas_attention.interpret_mode()`) at
+  shapes that tile, the Mosaic kernel `index_score_bwd` — a grid over key
+  tiles; per tile and head the score block again in VMEM, `ds = dL/dI · w ·
+  (s > 0)`, `dq += ds · k`, `dk += dsᵀ · q`, `dw += Σ dL/dI · relu(s)`; a tile
+  in the rows' future skipped — and everywhere else, and under `EDL_FLASH=0`,
+  `jax.vjp(_score_block)`. A caller that is not differentiated runs the loss
+  alone.
 
-`SELECTION_NAMES` are the `checkpoint_name`s of what `select` decides, and
-`KEEP_SELECTION` the `jax.checkpoint` policy that keeps them beside the flash
+`SELECTION_NAMES` are the `checkpoint_name`s of what `select` decides,
+`INDEX_GRADIENT_NAMES` those of the index loss's three gradients, and
+`KEEP_SELECTION` the `jax.checkpoint` policy that keeps both beside the flash
 kernels' residuals: a layer recomputed in the backward pass then neither
 searches again nor can select differently from its forward pass (on a TPU a
 recomputed projection is not the forward's to the last bit, and a key at the
-threshold would change sides).
+threshold would change sides), and holds nothing of the index loss (146 MB
+kept over the cell's four layers, for a score block, a target and a pull-back
+a layer not run a second time). Under a policy that saves none of the loss's
+names the recomputation runs the forward rule again, to the same values.
 """
 
 from __future__ import annotations
@@ -66,14 +76,16 @@ from jax.experimental.pallas import tpu as pltpu
 from elasticdl_tpu.ops import pallas_attention
 
 SELECTION_NAMES = ("dsa_threshold", "dsa_keep")
+# the index loss's gradients for q_index, k_index and w at a unit cotangent
+INDEX_GRADIENT_NAMES = ("dsa_index_kl_dq", "dsa_index_kl_dk", "dsa_index_kl_dw")
 KEEP_SELECTION = jax.checkpoint_policies.save_only_these_names(
-    *pallas_attention.RESIDUAL_NAMES, *SELECTION_NAMES)
+    *pallas_attention.RESIDUAL_NAMES, *SELECTION_NAMES, *INDEX_GRADIENT_NAMES)
 
 # Rows a block: the score plane's block (the selection's too: it ranks a block
 # as it makes it) holds (index heads, rows, T) float32 before its sum over
 # heads — 268 MB at 16 heads, 256 rows and 16 384 keys; the index loss's holds
 # that and the target's (heads, rows, T), 134 + 268 MB at 128 rows, and (where
-# the pull-back is the vjp) the scores' cotangent in its backward.
+# the pull-back is the vjp) the scores' cotangent beside them.
 # `LIVE_BLOCK`: the square blocks `live_blocks` counts, the flash kernels' key
 # block.
 SCORE_ROWS = 256
@@ -239,6 +251,16 @@ def _kl_blocks(q_index, w, q, lse, keep, rows):
             _blocked(q, 1, rows), _blocked(lse, 2, rows), _blocked(keep, 1, rows))
 
 
+def _kl_block(score_rows, q_rows, k, lse_rows, keep_rows):
+    """One block of query rows from its scores (B, R, T): (Σ p̂ (log p̂ − log π)
+    over the block, and that sum's gradient for the scores, π − p̂ on the kept
+    keys and zero elsewhere)."""
+    target, log_pi, kept = _target_and_log_pi(q_rows, k, lse_rows, keep_rows, score_rows)
+    return (jnp.sum(jax.scipy.special.xlogy(target, target)
+                    - target * jnp.where(kept, log_pi, 0.0)),
+            jnp.where(kept, jnp.exp(log_pi), 0.0) - target)
+
+
 @jax.custom_vjp
 def index_kl(q_index: jax.Array, k_index: jax.Array, w: jax.Array,
              q: jax.Array, k: jax.Array, lse: jax.Array, keep: jax.Array) -> jax.Array:
@@ -251,17 +273,10 @@ def index_kl(q_index: jax.Array, k_index: jax.Array, w: jax.Array,
 
     def block(args):
         q_index_rows, w_rows, q_rows, lse_rows, keep_rows = args
-        target, log_pi, kept = _target_and_log_pi(
-            q_rows, k, lse_rows, keep_rows, _score_block(q_index_rows, k_index, w_rows))
-        return jnp.sum(jax.scipy.special.xlogy(target, target)
-                       - target * jnp.where(kept, log_pi, 0.0))
+        return _kl_block(_score_block(q_index_rows, k_index, w_rows),
+                         q_rows, k, lse_rows, keep_rows)[0]
 
     return jnp.sum(jax.lax.map(block, _kl_blocks(q_index, w, q, lse, keep, rows))) / (t * b)
-
-
-def _index_kl_fwd(q_index, k_index, w, q, k, lse, keep):
-    return (index_kl(q_index, k_index, w, q, k, lse, keep),
-            (q_index, k_index, w, q, k, lse, keep))
 
 
 def pullback_keys(rows: int, t: int, head_dim: int, q_dtype, k_dtype) -> Optional[int]:
@@ -382,51 +397,59 @@ def index_score_bwd(q_rows, k_index, w_rows, d_scores, first_row, *, block_k, in
         return dq, dk, jnp.sum(dw, axis=-1)
 
 
-def _index_kl_bwd(res, g):
-    """A block of query rows at a time: its scores again, ∂L/∂I = (π − p̂) / T
-    on the kept keys, and that pulled back to the indexer's operands — no
-    (T, T) plane of scores or of their gradient. Two forms of the pull-back,
-    chosen by `pullback_keys`: the kernel `index_score_bwd` beside ONE forward
-    evaluation of the block (the heads' scores and their cotangent then live
-    in VMEM alone), or `jax.vjp(_score_block)`, which writes both."""
-    q_index, k_index, w, q, k, lse, keep = res
+def _index_kl_fwd(q_index, k_index, w, q, k, lse, keep):
+    """The loss and, in the SAME sweep over the blocks of query rows, its three
+    gradients at a unit cotangent: a block's scores and target once, its KL
+    sum, ∂L/∂I = (π − p̂) / T on the kept keys, and that pulled back to the
+    indexer's operands — no (T, T) plane of scores or of their gradient. Two
+    forms of the pull-back, chosen by `pullback_keys`: the kernel
+    `index_score_bwd` beside ONE forward evaluation of the block (the heads'
+    scores and their cotangent then live in VMEM alone), or
+    `jax.vjp(_score_block)`, which writes both. The gradients, in the
+    operands' layouts and dtypes under `INDEX_GRADIENT_NAMES`, are ALL the
+    rule keeps: a recomputation under a policy that saves those names runs
+    nothing of this."""
     b, t = keep.shape[:2]
     rows = _rows(t, KL_ROWS)
     block_k = pullback_keys(rows, t, q_index.shape[-1], q_index.dtype, k_index.dtype)
     blocks = _kl_blocks(q_index, w, q, lse, keep, rows)
 
-    def d_scores(q_rows, lse_rows, keep_rows, score_rows):
-        target, log_pi, kept = _target_and_log_pi(q_rows, k, lse_rows, keep_rows, score_rows)
-        return (jnp.where(kept, jnp.exp(log_pi), 0.0) - target) * (g / (t * b))
-
     def block(dk_index, args):
         q_index_rows, w_rows, q_rows, lse_rows, keep_rows = args
         score_rows, pull = jax.vjp(_score_block, q_index_rows, k_index, w_rows)
-        dq_rows, dk_rows, dw_rows = pull(d_scores(q_rows, lse_rows, keep_rows, score_rows))
-        return dk_index + dk_rows.astype(jnp.float32), (dq_rows, dw_rows)
+        kl, d_scores = _kl_block(score_rows, q_rows, k, lse_rows, keep_rows)
+        dq_rows, dk_rows, dw_rows = pull(d_scores * (1.0 / (t * b)))
+        return dk_index + dk_rows.astype(jnp.float32), (kl, dq_rows, dw_rows)
 
     def kernel_block(dk_index, args):
         (q_index_rows, w_rows, q_rows, lse_rows, keep_rows), first_row = args
+        kl, d_scores = _kl_block(_score_block(q_index_rows, k_index, w_rows),
+                                 q_rows, k, lse_rows, keep_rows)
         dq_rows, dk_rows, dw_rows = index_score_bwd(
-            q_index_rows, k_index, w_rows,
-            d_scores(q_rows, lse_rows, keep_rows,
-                     _score_block(q_index_rows, k_index, w_rows)),
-            first_row, block_k=block_k, interpret=pallas_attention.kernel_interpret())
-        return dk_index + dk_rows, (dq_rows, dw_rows)
+            q_index_rows, k_index, w_rows, d_scores * (1.0 / (t * b)), first_row,
+            block_k=block_k, interpret=pallas_attention.kernel_interpret())
+        return dk_index + dk_rows, (kl, dq_rows, dw_rows)
 
     if block_k is None:
-        dk_index, (dq_index, dw) = jax.lax.scan(
+        dk_index, (kl, dq_index, dw) = jax.lax.scan(
             block, jnp.zeros(k_index.shape, jnp.float32), blocks)
         dq_index, dw = _unblocked(dq_index, 1), _unblocked(dw, 1)
     else:
         # the kernel's layouts: heads before rows, dk's keys along the lanes
-        dk_index, (dq_index, dw) = jax.lax.scan(
+        dk_index, (kl, dq_index, dw) = jax.lax.scan(
             kernel_block, jnp.zeros((b, k_index.shape[2], t), jnp.float32),
             (blocks, jnp.arange(0, t, rows, dtype=jnp.int32)))
         dq_index, dw = (jnp.moveaxis(_unblocked(x, 2), 1, 2) for x in (dq_index, dw))
         dk_index = jnp.swapaxes(dk_index, 1, 2)
-    return (dq_index, dk_index.astype(k_index.dtype), dw.astype(w.dtype),
-            jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(lse), None)
+    gradients = (dq_index, dk_index.astype(k_index.dtype), dw.astype(w.dtype))
+    return jnp.sum(kl) / (t * b), tuple(map(checkpoint_name, gradients, INDEX_GRADIENT_NAMES))
+
+
+def _index_kl_bwd(gradients, g):
+    """`g` times what the forward rule made (the product in float32, rounded
+    to the operand's dtype); nothing for q, k, lse (p̂ is a target) or keep."""
+    return (*((g * x.astype(jnp.float32)).astype(x.dtype) for x in gradients),
+            None, None, None, None)
 
 
 index_kl.defvjp(_index_kl_fwd, _index_kl_bwd)

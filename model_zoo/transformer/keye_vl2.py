@@ -56,9 +56,11 @@ operands (bfloat16 on the chip) and accumulate in float32.
 Every layer is recomputed in the backward pass (`jax.checkpoint`) under
 `policy=sparse_attention.KEEP_SELECTION`: kept are the residual stream the
 layer started from, what the flash kernels' backward reads (q, k, v, output,
-logsumexp) and what the selection decided (thresholds and the `keep` plane), so
-the recomputation runs no forward kernel and no search again and cannot select
-differently from the forward pass.
+logsumexp), what the selection decided (thresholds and the `keep` plane) and
+the index loss's gradients for the indexer's three operands, which its forward
+rule makes with the loss, so the recomputation runs no forward kernel, no
+search and nothing of the index loss again and cannot select differently from
+the forward pass.
 
 Counters, in collections the trainer threads through every step:
 `router_state/held_passes`, `held_row_tiles`, `pairs_held_share` (as

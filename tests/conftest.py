@@ -81,6 +81,21 @@ def pallas_calls(jaxpr, name):
                      and eqn.params["name"] == name)
 
 
+def matmuls(jaxpr, shape):
+    """How many `dot_general`s of a jaxpr give a result with `shape`'s axes
+    (a `dot_general` puts them in an order of its own)."""
+    return equations(jaxpr, lambda eqn: eqn.primitive.name == "dot_general"
+                     and sorted(eqn.outvars[0].aval.shape) == sorted(shape))
+
+
+def scans_with(jaxpr, wanted):
+    """The bodies of the scans of a jaxpr that `wanted(body)` holds for."""
+    bodies = []
+    equations(jaxpr, lambda eqn: eqn.primitive.name == "scan" and wanted(
+        eqn.params["jaxpr"].jaxpr) and bodies.append(eqn.params["jaxpr"].jaxpr))
+    return bodies
+
+
 @pytest.fixture(scope="module")
 def xla_optimises():
     """`default_pipeline()` for a module (`pytestmark = pytest.mark.

@@ -18,7 +18,7 @@ import pytest
 from benchmark import common
 from elasticdl_tpu.ops import pallas_attention, sparse_attention
 from tests import zoo_lm
-from tests.conftest import equations, pallas_calls
+from tests.conftest import equations, matmuls, pallas_calls, scans_with
 
 TINY = zoo_lm.preset("tiny-lm-keye.json")
 INDEX = ("index_wq", "index_wk", "index_k_scale", "index_k_bias", "index_w")
@@ -119,22 +119,66 @@ def _selections_in(jaxpr):
     return equations(jaxpr, lambda eqn: eqn.primitive.name == "cond")
 
 
-def test_thresholds_and_keep_are_kept_across_the_recomputation(case, monkeypatch):
+def _gradient_under(policy, spec, batch, params, run=True):
+    """(jaxpr, gradients) of the batch's loss with every layer recomputed
+    under `policy` in `KEEP_SELECTION`'s place."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sparse_attention, "KEEP_SELECTION", policy)
+        loss = lambda p: lm.terms(spec, p, batch)["loss"]      # a new closure each time
+        return (jax.make_jaxpr(jax.grad(loss))(params).jaxpr,
+                jax.jit(jax.grad(loss))(params) if run else None)
+
+
+@pytest.fixture(scope="module")
+def recomputations(case):
+    """The two-layer gradient under `KEEP_SELECTION` and under the flash
+    kernels' policy alone, which knows neither the selection's names nor the
+    index loss's."""
+    spec, _, batch, params = case
+    return (_gradient_under(sparse_attention.KEEP_SELECTION, spec, batch, params),
+            _gradient_under(pallas_attention.KEEP_RESIDUALS, spec, batch, params))
+
+
+def test_thresholds_and_keep_are_kept_across_the_recomputation(recomputations):
     """Under `KEEP_SELECTION` a layer's backward pass holds no second search:
     two layers, two `select`s in the whole gradient; under the flash kernels'
     policy alone, four. The values are the same where recomputing is exact."""
-    spec, _, batch, params = case
-
-    def loss(policy):
-        monkeypatch.setattr(sparse_attention, "KEEP_SELECTION", policy)
-        return lambda p: lm.terms(spec, p, batch)["loss"]     # a new closure each time
-
-    kept, flash_only = sparse_attention.KEEP_SELECTION, pallas_attention.KEEP_RESIDUALS
-    count = lambda policy: _selections_in(jax.make_jaxpr(jax.grad(loss(policy)))(params).jaxpr)
-    assert (count(kept), count(flash_only)) == (2, 4)
-    a, b = jax.jit(jax.grad(loss(kept)))(params), jax.jit(jax.grad(loss(flash_only)))(params)
+    (kept, a), (flash_only, b) = recomputations
+    assert (_selections_in(kept), _selections_in(flash_only)) == (2, 4)
     for leaf in INDEX + REST:
         np.testing.assert_array_equal(np.asarray(a[leaf]), np.asarray(b[leaf]))
+
+
+def _index_losses_in(jaxpr, batch, seq):
+    """(evaluations of the index loss's target — the scans whose body holds
+    the 4 heads' q·kᵀ of a block of rows, which nothing else makes —, the
+    matmuls of the 3 index heads' scores in those bodies)."""
+    rows = sparse_attention._rows(seq, sparse_attention.KL_ROWS)
+    target, scores = (batch, 2, 2, rows, seq), (batch, 3, rows, seq)
+    bodies = scans_with(jaxpr, lambda body: matmuls(body, target))
+    assert all(matmuls(body, target) == 1 for body in bodies)
+    return len(bodies), sum(matmuls(body, scores) for body in bodies)
+
+
+def test_the_index_loss_is_evaluated_once_a_layer(recomputations, case):
+    """Under `KEEP_SELECTION` the recomputed half of the gradient holds nothing
+    of the index loss — its three gradients come from the forward pass by name:
+    two layers, two evaluations of a block's target and of its scores; under
+    the flash kernels' policy alone, four: the loss in the forward pass (the
+    pull-back is dead code there: two kernels, not four, at a size that tiles)
+    and the whole forward rule again in the recomputation. The values are
+    `recomputations`' — equal, to the bit."""
+    spec, _, batch, params = case
+    (kept, _), (flash_only, _) = recomputations
+    size = batch["features"].shape
+    assert _index_losses_in(kept, *size) == (2, 2)
+    assert _index_losses_in(flash_only, *size) == (4, 4)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv(pallas_attention._INTERPRET_ENV, "1")
+        tiles, _ = _gradient_under(pallas_attention.KEEP_RESIDUALS, spec,
+                                   lm.batches(steps=1, seq=128)[0], params, run=False)
+    assert _index_losses_in(tiles, size[0], 128) == (4, 4)
+    assert pallas_calls(tiles, "index_score_bwd") == 2
 
 
 @pytest.fixture(scope="module")
@@ -164,6 +208,8 @@ def test_the_model_s_backward_holds_the_pull_back_kernel_once_a_layer(pull_back_
     assert pallas_calls(plain, "index_score_bwd") == 0
     assert pallas_calls(kernel, "index_score_bwd") == 2
     assert (_selections_in(plain), _selections_in(kernel)) == (2, 2)
+    # each beside the one evaluation of its layer's loss
+    assert _index_losses_in(plain, 2, 128) == _index_losses_in(kernel, 2, 128) == (2, 2)
 
 
 @pytest.mark.parametrize("leaf", INDEX)

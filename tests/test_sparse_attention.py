@@ -11,7 +11,7 @@ import pytest
 from elasticdl_tpu.ops import pallas_attention
 from elasticdl_tpu.ops import sparse_attention as sa
 from elasticdl_tpu.ops.attention import full_attention
-from tests.conftest import equations, pallas_calls
+from tests.conftest import equations, matmuls, pallas_calls, scans_with
 
 B, T, H, HKV, D, HI, DI = 2, 64, 4, 2, 16, 3, 8
 
@@ -146,26 +146,30 @@ def _pulled_back(jaxpr):
 
 
 # the pull-back of a score block by `jax.vjp(_score_block)` (64 keys have no
-# key tile) and by the kernel, two tiles a block of rows
+# key tile) and by the kernel, two tiles a block of rows; the loss as it is
+# (the rule's cotangent 1) and scaled (a cotangent that is no power of two:
+# the rule's gradients are made before it is known)
+@pytest.mark.parametrize("scale", [1.0, 0.37])
 @pytest.mark.parametrize("route, t, rows", [
     ("vjp", 64, 128), ("vjp", 64, 8), ("kernel", 256, 128), ("kernel", 256, 32)])
-def test_index_kl_and_its_gradient_are_the_plain_form_s(route, t, rows, monkeypatch, request):
+def test_index_kl_and_its_gradient_are_the_plain_form_s(route, t, rows, scale, monkeypatch,
+                                                        request):
     if route == "kernel":
         request.getfixturevalue("kernel_route")
     monkeypatch.setattr(sa, "KL_ROWS", rows)
     d, scores, keep, lse = _kl_case(t=t, k=16 * t // 64)
 
     def ours(q_index, k_index, w):
-        return sa.index_kl(q_index, k_index, w, d["q"], d["k"], lse, keep)
+        return scale * sa.index_kl(q_index, k_index, w, d["q"], d["k"], lse, keep)
 
     def plain(q_index, k_index, w):
-        return _plain_kl(_plain_scores(q_index, k_index, w), d["q"], d["k"], keep)
+        return scale * _plain_kl(_plain_scores(q_index, k_index, w), d["q"], d["k"], keep)
 
     operands = (d["q_index"], d["k_index"], d["w"])
     assert _pulled_back(jax.make_jaxpr(jax.grad(ours))(*operands).jaxpr) == (route == "kernel")
     got, got_grads = jax.value_and_grad(ours, argnums=(0, 1, 2))(*operands)
     want, want_grads = jax.value_and_grad(plain, argnums=(0, 1, 2))(*operands)
-    assert float(want) > 0.05
+    assert float(want) > 0.05 * scale
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for a, b in zip(got_grads, want_grads):
         assert float(jnp.abs(b).max()) > 1e-5
@@ -240,38 +244,93 @@ def _planes(jaxpr, shape):
 
 
 def test_the_gradient_s_program_holds_the_kernel_or_the_vjp(monkeypatch, request):
-    """On the kernel's route the backward's scan holds ONE `index_score_bwd` and,
-    of arrays the size of the heads' scores (B, Hi, R, T), only those of one
-    forward evaluation of the block — no cotangent of them, no mask; on a plain
-    CPU it holds no kernel and is the program it is with the kernels switched
-    off, the heads' scores written and read."""
+    """The gradient's program evaluates a block ONCE: one scan, with one
+    matmul of the heads' scores (B, Hi, R, T) and one of the target's (B, Hkv,
+    G, R, T) — the forward rule's; the backward rule holds none. On the
+    kernel's route that scan holds ONE `index_score_bwd` and, of arrays the
+    size of the heads' scores, only those of the loss's own forward pass — no
+    cotangent of them, no mask; on a plain CPU it holds no kernel and is the
+    program it is with the kernels switched off, the heads' scores written and
+    read."""
     monkeypatch.setattr(sa, "KL_ROWS", 128)
     d, _, keep, lse = _kl_case(t=256, k=64)
     operands = (d["q_index"], d["k_index"], d["w"])
     loss = lambda *a: sa.index_kl(*a, d["q"], d["k"], lse, keep)
-    heads_scores = (B, HI, 128, 256)
-    forward = _planes(jax.make_jaxpr(loss)(*operands).jaxpr, heads_scores)
+    heads_scores, heads_target = (B, HI, 128, 256), (B, HKV, H // HKV, 128, 256)
+    primal = jax.make_jaxpr(loss)(*operands).jaxpr
+    forward = _planes(primal, heads_scores)
     assert forward >= 1
+    assert (matmuls(primal, heads_scores), matmuls(primal, heads_target)) == (1, 1)
+
+    def one_evaluation(jaxpr):
+        scores = scans_with(jaxpr, lambda body: matmuls(body, heads_scores))
+        return (len(scores) == 1 and matmuls(jaxpr, heads_scores) == 1
+                and matmuls(jaxpr, heads_target) == 1
+                and matmuls(scores[0], heads_target) == 1)
 
     plain = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*operands)
     assert _pulled_back(plain.jaxpr) == 0
-    assert _planes(plain.jaxpr, heads_scores) > 2 * forward
+    assert one_evaluation(plain.jaxpr)
+    assert _planes(plain.jaxpr, heads_scores) > forward     # the vjp's cotangent and mask
 
     request.getfixturevalue("kernel_route")
     assert sa.pullback_keys(128, 256, DI, jnp.float32, jnp.float32) == 128
     kernel = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*operands).jaxpr
-    assert _pulled_back(kernel) == 1
-    # the scan that holds the kernel holds, of the heads' scores, ONE forward
-    # evaluation's; the loss's forward pass has the other
-    bodies = []
-    equations(kernel, lambda eqn: eqn.primitive.name == "scan" and _pulled_back(
-        eqn.params["jaxpr"].jaxpr) == 1 and bodies.append(eqn.params["jaxpr"].jaxpr))
-    assert len(bodies) == 1 and _planes(bodies[0], heads_scores) == forward
-    assert _planes(kernel, heads_scores) == 2 * forward
+    assert _pulled_back(kernel) == 1 and one_evaluation(kernel)
+    assert len(scans_with(kernel, lambda body: _pulled_back(body) == 1
+                           and matmuls(body, heads_scores) == 1)) == 1
+    assert _planes(kernel, heads_scores) == forward
 
     monkeypatch.setenv("EDL_FLASH", "0")
     assert sa.pullback_keys(128, 256, DI, jnp.float32, jnp.float32) is None
     assert str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*operands)) == str(plain)
+
+
+def _names_in(jaxpr):
+    names = []
+    equations(jaxpr, lambda eqn: eqn.primitive.name == "name" and names.append(eqn.params["name"]))
+    return tuple(names)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_forward_rule_keeps_its_three_gradients_and_no_operand(dtype):
+    """What the rule hands its backward: the gradients for q_index, k_index
+    and w in the operands' own shapes and dtypes, under
+    `INDEX_GRADIENT_NAMES` — and none of q, k, lse or keep."""
+    d, _, keep, lse = _kl_case()
+    operands = [d["q_index"].astype(dtype), d["k_index"].astype(dtype), d["w"]]
+    rest = (d["q"].astype(dtype), d["k"].astype(dtype), lse, keep)
+    loss, kept = jax.eval_shape(sa._index_kl_fwd, *operands, *rest)
+    assert (loss.shape, loss.dtype) == ((), jnp.float32)
+    assert [(x.shape, x.dtype) for x in kept] == [(x.shape, x.dtype) for x in operands]
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: sa.index_kl(*a, *rest), argnums=(0, 1, 2)))(
+        *operands).jaxpr
+    assert _names_in(jaxpr) == sa.INDEX_GRADIENT_NAMES
+    assert not set(sa.INDEX_GRADIENT_NAMES) & set(sa.SELECTION_NAMES)
+
+
+def test_a_recomputation_that_keeps_the_gradients_evaluates_nothing_again(monkeypatch):
+    """`jax.checkpoint` around the loss: under `KEEP_SELECTION` the gradient's
+    program holds the forward rule's one scan; under a policy that knows none
+    of the rule's names the recomputation holds it again — and the gradients
+    are the same, to the bit where recomputing is exact."""
+    monkeypatch.setattr(sa, "KL_ROWS", 32)
+    d, _, keep, lse = _kl_case()
+    operands = (d["q_index"], d["k_index"], d["w"])
+    heads_scores = (B, HI, 32, T)
+
+    def gradient(policy):
+        loss = lambda *a: 0.37 * sa.index_kl(*a, d["q"], d["k"], lse, keep)  # a new closure
+        return jax.grad(jax.checkpoint(loss, policy=policy), argnums=(0, 1, 2))
+
+    count = lambda policy: matmuls(
+        jax.make_jaxpr(gradient(policy))(*operands).jaxpr, heads_scores)
+    assert count(sa.KEEP_SELECTION) == 1
+    assert count(pallas_attention.KEEP_RESIDUALS) == 2
+    for a, b in zip(gradient(sa.KEEP_SELECTION)(*operands),
+                    gradient(pallas_attention.KEEP_RESIDUALS)(*operands)):
+        assert float(jnp.abs(a).max()) > 1e-6
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("rows, t, q_dtype, k_dtype, keys", [
@@ -309,11 +368,8 @@ def test_the_selection_is_not_differentiated_and_is_named():
     operands = (d["q_index"], d["k_index"], d["w"])
     grads = jax.grad(lambda w: jnp.sum(sa.select(d["q_index"], d["k_index"], w, 8)[0]))(d["w"])
     assert float(jnp.abs(grads).max()) == 0.0
-    names = []
     jaxpr = jax.make_jaxpr(lambda *a: sa.select(*a, 8)[:2])(*operands).jaxpr
-    equations(jaxpr, lambda eqn: eqn.primitive.name == "name"
-              and names.append(eqn.params["name"]))
-    assert tuple(names) == sa.SELECTION_NAMES
+    assert _names_in(jaxpr) == sa.SELECTION_NAMES
 
 
 def test_a_bit_pattern_orders_as_its_float():
