@@ -19,6 +19,7 @@ no worker process restarts, no lost task accounting.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Dict, Optional
 
@@ -27,6 +28,7 @@ from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.common.log_utils import default_logger
 from elasticdl_tpu.master.main import Master
 from elasticdl_tpu.master.process_manager import ProcessManager
+from elasticdl_tpu.observability import tracing
 
 logger = default_logger(__name__)
 
@@ -59,6 +61,11 @@ def run_local(
     timeout_s: Optional[float] = None,
 ) -> int:
     """Run a whole job on this host: in-process master, subprocess workers."""
+    # this process's own start (interpreter, imports, flags) to the master
+    # serving; its trace id is the job's, which the workers join
+    launching = contextlib.ExitStack()
+    launching.enter_context(
+        tracing.start_span("launch", since=tracing.process_start_ts()))
     if cfg.master_addr.endswith(":0"):
         # bind_with_retry closes free_port()'s TOCTOU window: Master binds
         # its port during construction and raises PortBindError when the
@@ -130,6 +137,7 @@ def run_local(
     else:
         autoscale_target = None
     master.start()
+    launching.close()
     manager.start_workers()
     deadline = time.time() + timeout_s if timeout_s else None
     restarts_left = cfg.master_restarts
@@ -185,6 +193,7 @@ def run_local(
                 rollup["workers_reporting"], rollup.get("workers_alive", 0),
                 rollup.get("skew", 1.0), rollup["straggler_count"],
             )
+        tracing.log_startup_ledger()    # if no worker ever registered
         master.shutdown()
         if ok:
             # Workers that saw job_done leave by themselves; give them the

@@ -8,16 +8,22 @@ cluster client (see elasticdl_tpu/client/k8s.py when present).
 
 from __future__ import annotations
 
-import sys
-from typing import List, Optional
+import time
 
-from elasticdl_tpu.common.config import JobConfig
-from elasticdl_tpu.version import __version__
+_ENTERED = time.time()      # before the imports: `start.launch` without /proc
+
+import sys  # noqa: E402
+from typing import List, Optional  # noqa: E402
+
+from elasticdl_tpu.common.config import JobConfig  # noqa: E402
+from elasticdl_tpu.observability import tracing  # noqa: E402
+from elasticdl_tpu.version import __version__  # noqa: E402
 
 VERBS = ("train", "evaluate", "predict", "zoo", "version")
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    tracing.mark_entry(_ENTERED)
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] in ("-h", "--help"):
         print(__doc__)

@@ -25,6 +25,7 @@ from elasticdl_tpu.master.membership import Membership
 from elasticdl_tpu.master.poll_phases import poll_phase
 from elasticdl_tpu.master.servicer import MasterServicer
 from elasticdl_tpu.master.task_dispatcher import TaskDispatcher
+from elasticdl_tpu.observability import tracing
 from elasticdl_tpu.proto.service import add_master_servicer, make_server
 
 logger = default_logger(__name__)
@@ -32,6 +33,7 @@ logger = default_logger(__name__)
 
 class Master:
     def __init__(self, cfg: JobConfig, k8s_api=None):
+        self._built_from = time.time()      # `start.master` counts from here
         cfg.validate()
         self.cfg = cfg
         # observability first: every span/log below carries the role, and
@@ -350,6 +352,8 @@ class Master:
     def start(self) -> None:
         self.server.start()
         logger.info("master serving on %s", self.cfg.master_addr)
+        # construction (port, shards, journal replay) to serving
+        tracing.record_start("master", since=self._built_from)
         # /metrics + /healthz (best-effort; never a boot failure; a set
         # EDL_METRICS_PORT overrides cfg.metrics_port either way)
         from elasticdl_tpu.observability.http import start_server
@@ -639,6 +643,9 @@ class Master:
 
     def run(self) -> int:
         self.start()
+        # a master that is its own process has started up here (the local
+        # launcher's prints when its workers have registered)
+        tracing.log_startup_ledger()
         abort_fn = (
             self.instance_manager.all_failed
             if self.instance_manager is not None else None

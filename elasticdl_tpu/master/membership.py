@@ -118,6 +118,7 @@ class Membership(CommitGate):
         # single-threaded); mark_dead iterates OUTSIDE the lock on purpose —
         # callbacks re-enter the dispatcher
         self._death_callbacks: List[Callable[[int], None]] = []
+        self._join_callbacks: List[Callable[[int], None]] = []
         snap = journal.membership_snapshot() if journal is not None else None
         if snap is not None:
             self._restore(snap)
@@ -175,6 +176,12 @@ class Membership(CommitGate):
         TaskDispatcher.recover_tasks."""
         self._death_callbacks.append(cb)
 
+    def add_join_callback(self, cb: Callable[[int], None]) -> None:
+        """cb(worker_id) fires when a worker has registered (`register`:
+        plain workers and cohort leaders), outside the lock like the death
+        callbacks — the process manager closes `start.spawn` with it."""
+        self._join_callbacks.append(cb)
+
     def register(self, name: str, preferred_id: int = -1,
                  data_addr: str = "") -> WorkerInfo:
         with self._lock:
@@ -212,6 +219,8 @@ class Membership(CommitGate):
             "membership.join", worker_id=info.worker_id, worker_name=name,
             version=version,
         )
+        for cb in self._join_callbacks:
+            cb(info.worker_id)
         return info
 
     def register_members(

@@ -161,6 +161,13 @@ class ProcessManager:
         self._probe_ckpt_mngr = None  # lazily built, reused across resizes
         self._procs: Dict[int, _WorkerProc] = {}     # guarded_by: _lock
         self._lock = threading.Lock()
+        # `start.spawn`: a worker's `Popen` (wall stamp, by worker id) to its
+        # registration, which the membership tells on the RPC's thread — a
+        # leaf lock of its own, never `_lock`
+        self._spawned: Dict[int, float] = {}         # guarded_by: _spawn_lock
+        self._spawn_lock = threading.Lock()
+        if membership is not None:
+            membership.add_join_callback(self._on_join)
         self._stop = threading.Event()
         self._watcher: Optional[threading.Thread] = None
         self._next_worker_id = 0                     # guarded_by: _lock
@@ -235,7 +242,9 @@ class ProcessManager:
             world_size=self._cohort_size,
             pending_size=self._pending_resize,
             world_version=self._world_version,
-            trace_id=self._reform_trace_id,
+            # a resize's trace while one is announced, else the job's
+            # start-up trace: a worker that boots joins what it reads here
+            trace_id=self._reform_trace_id or tracing.startup_trace_id(),
             # which master wrote this plan: a successor master at takeover
             # clears announcements stamped by its dead predecessor
             master_generation=(
@@ -253,6 +262,8 @@ class ProcessManager:
         reconnect to the same address under the new generation; only this
         manager's references move. The announcement is re-stamped so the
         signal file carries the new master generation immediately."""
+        if membership is not None:
+            membership.add_join_callback(self._on_join)
         with self._lock:
             self._membership = membership
             self._job_finished_fn = job_finished_fn or (lambda: False)
@@ -315,6 +326,7 @@ class ProcessManager:
             # that never comes up), exercising death detection and the
             # relaunch budget rather than silently skipping the spawn
             cmd = [sys.executable, "-c", "raise SystemExit(1)"]
+        spawned_at = time.time()
         # same cohort-atomicity justification as the log open above:
         # edl-lint: disable=EDL103
         proc = subprocess.Popen(
@@ -323,10 +335,27 @@ class ProcessManager:
             stdout=stdout,
             stderr=stderr,
         )
+        if process_id == 0:     # the process that registers as `worker_id`
+            with self._spawn_lock:
+                self._spawned[worker_id] = spawned_at
         wp = _WorkerProc(worker_id=worker_id, proc=proc, relaunches=relaunches)
         _SPAWNS.inc()
         logger.info("spawned worker %d (pid %d)", worker_id, proc.pid)
         return wp
+
+    def _on_join(self, worker_id: int) -> None:
+        """Membership join callback: the worker spawned as `worker_id` has
+        registered. Records `start.spawn` (its `Popen` to now); when no
+        spawned worker is still on its way, this process has started up and
+        prints its ledger (once: a relaunch's registration prints none)."""
+        with self._spawn_lock:
+            since = self._spawned.pop(worker_id, None)
+            all_in = not self._spawned
+        if since is None:
+            return
+        tracing.record_start("spawn", since=since, worker_id=worker_id)
+        if all_in:
+            tracing.log_startup_ledger()
 
     def start_workers(self) -> None:
         with self._lock:

@@ -26,6 +26,15 @@ region (`edl.data_wait`, `edl.h2d`, `edl.compute`, `edl.handoff`);
 always opened: with no profiler session running it costs a `TraceMe` (under a
 microsecond), and in a process that never imported jax it is a no-op.
 
+And it keeps the compile ledger: one pair of `jax.monitoring` listeners
+(`install_compile_ledger`, once a process) takes JAX's own figures of every
+compilation — tracing, lowering, the backend's compile or the persistent
+cache's load, with its hits and misses — and adds them to the `compile` /
+`start.state` span (observability/tracing.py) open around it on the compiling
+thread. What compiles under no such span is counted apart
+(`compile_outside`). JAX calls the listeners when it compiles and at no other
+time.
+
 Exports:
 
 - gauges `edl_step_phase_seconds{phase=...}` (rolling per-step mean over
@@ -112,6 +121,118 @@ def annotation(name: str, **attrs):
     if jax is None:
         return _NO_ANNOTATION
     return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **attrs)
+
+
+# ---------------------------------------------------------------------- #
+# the compile ledger
+
+#: the spans that take JAX's compile figures: the innermost open one wins
+COMPILE_SPANS = ("compile", "start.state")
+#: JAX's duration events -> the attribute each adds to. The first three are
+#: intervals that nest (a jitted function traced inside another's trace, a
+#: trace inside a lowering): an inner one is taken out of the one around it,
+#: so the three add up to wall time. `backend_s` is the backend call whole —
+#: the compile, or on a hit the cache's load, which `cache_load_s` repeats.
+_NESTING_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+_DURATION_EVENTS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "cache_saved_s",
+}
+#: a miss is counted where JAX writes the entry: a program that compiles
+#: faster than `jax_persistent_cache_min_compile_time_secs` is neither
+_COUNT_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_ledger_lock = threading.Lock()
+_ledger_installed = False                       # guarded_by: _ledger_lock
+_outside: Dict[str, float] = {}                 # guarded_by: _ledger_lock
+_outside_local = threading.local()              # a thread's open intervals
+
+
+def _own_seconds(intervals: list, seconds: float) -> float:
+    """`seconds` ending now, less what `intervals` (the maximal ones this
+    thread has credited so far) already hold of it; `intervals` is updated.
+    Events come in the order they end, nested or apart, never crossing."""
+    end = time.time()
+    start = end - seconds
+    inside = 0.0
+    while intervals and intervals[-1][0] >= start - 1e-4:
+        s, e = intervals.pop()
+        inside += e - s
+    intervals.append((start, end))
+    return max(0.0, seconds - inside)
+
+
+def _credit(key: str, amount: float, nests: bool = False) -> None:
+    from elasticdl_tpu.observability import tracing     # tracing imports us
+
+    span = tracing.open_span(COMPILE_SPANS)
+    if span is not None:
+        if nests:
+            if span.scratch is None:
+                span.scratch = []       # the intervals credited so far
+            amount = _own_seconds(span.scratch, amount)
+        span.attrs[key] = span.attrs.get(key, 0) + amount
+        return
+    if nests:
+        if not hasattr(_outside_local, "intervals"):
+            _outside_local.intervals = []
+        amount = _own_seconds(_outside_local.intervals, amount)
+    with _ledger_lock:
+        _outside[key] = _outside.get(key, 0) + amount
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    key = _NESTING_EVENTS.get(event)
+    if key is not None:
+        _credit(key, float(duration), nests=True)
+        if event == _BACKEND_EVENT:
+            _credit("programs", 1)
+        return
+    key = _DURATION_EVENTS.get(event)
+    if key is not None:
+        _credit(key, float(duration))
+
+
+def _on_event(event: str, **_) -> None:
+    key = _COUNT_EVENTS.get(event)
+    if key is not None:
+        _credit(key, 1)
+
+
+def install_compile_ledger() -> bool:
+    """Register the ledger's two listeners with `jax.monitoring`. Once a
+    process, whoever calls (`Trainer.__init__` does); False in a process
+    that has not imported jax."""
+    global _ledger_installed
+    jax = sys.modules.get("jax")    # never IMPORT jax from here
+    if jax is None:
+        return False
+    with _ledger_lock:
+        if not _ledger_installed:
+            import jax.monitoring
+
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            jax.monitoring.register_event_listener(_on_event)
+            _ledger_installed = True
+    return True
+
+
+def compile_outside() -> Dict[str, float]:
+    """What JAX compiled in this process under no `compile` / `start.state`
+    span, by the ledger's attribute names: helper programs (a gather, a
+    transfer's reshape), a benchmark's reference program, a second shape
+    dispatched after its kind's pin had settled. It enters no metric."""
+    with _ledger_lock:
+        return {k: round(v, 4) if isinstance(v, float) else v
+                for k, v in _outside.items()}
 
 
 class Region:

@@ -30,6 +30,7 @@ from jax.sharding import Mesh
 
 from elasticdl_tpu.common.log_utils import default_logger
 from elasticdl_tpu.observability import profile as profile_lib
+from elasticdl_tpu.observability import tracing
 from elasticdl_tpu.parallel import mesh as mesh_lib
 from elasticdl_tpu.training import compile_cache as cc
 from elasticdl_tpu.training.model_spec import ModelSpec
@@ -285,6 +286,10 @@ class Trainer:
         # AOT executables pinned per kind: (aval signature, executable or
         # None, cache AOT generation); resolved lazily per call kind
         self._pinned_exe: Dict[str, Tuple[Any, Any, int]] = {}
+        # the (kind, aval signature) pairs `_dispatch` has sent to the jitted
+        # path: the first of each compiles, under a `compile` span
+        self._dispatched: set = set()
+        profile_lib.install_compile_ledger()
         # a named policy implies remat on; "" + remat=True is full remat.
         # Resolved HERE so a bad name fails at construction, not at the
         # first train-step build after the job is already running.
@@ -350,13 +355,22 @@ class Trainer:
         """Prefer a cache-resident AOT executable for these exact avals
         (the speculative compiler's output); fall back to the jitted
         callable. The common case — no AOT entry exists for this kind —
-        pays ZERO per-step overhead: once a negative lookup is pinned, the
-        cache's AOT generation counter (bumped on every store_aot) is the
-        only thing checked until a new executable could actually match.
-        Known trade: an AOT entry stored for a shape OTHER than the first
-        one dispatched, before any store bumps the generation again, can
-        be shadowed by the negative pin — it then just runs the (correct)
-        jitted path."""
+        pays ZERO per-step overhead: once a negative lookup is pinned (at
+        a signature's SECOND dispatch: a kind's first signature is often
+        not its last — a check's stack of 4 steps before a window's of 32 —
+        and the one after it compiles too), the cache's AOT generation
+        counter (bumped on every store_aot) is the only thing checked until
+        a new executable could actually match.
+        Known trade: an AOT entry stored for a shape OTHER than the pinned
+        one, before any store bumps the generation again, can be shadowed
+        by the negative pin — it then just runs the (correct) jitted path;
+        and a new shape dispatched after a negative pin compiles under no
+        `compile` span (the compile ledger counts it as `outside`).
+
+        The first jitted dispatch of a (kind, aval signature) is where a
+        program that nobody compiled ahead of time is traced, lowered and
+        compiled, or loaded from the persistent cache: it runs under a
+        `compile` span."""
         gen = self._cache.aot_generation
         pinned = self._pinned_exe.get(kind)
         if pinned is not None and pinned[2] == gen and pinned[1] is None:
@@ -365,7 +379,8 @@ class Trainer:
         if pinned is None or pinned[0] != sig or pinned[2] != gen:
             exe = self._cache.peek(self._program_key(kind) + ("aot", sig))
             pinned = (sig, exe, gen)
-            self._pinned_exe[kind] = pinned
+            if exe is not None or (kind, sig) in self._dispatched:
+                self._pinned_exe[kind] = pinned
         exe = pinned[1]
         if exe is not None:
             try:
@@ -379,7 +394,11 @@ class Trainer:
                     "back to the jitted path", kind, exc_info=True,
                 )
                 self._pinned_exe[kind] = (sig, None, gen)
-        return jitted(*args)
+        if (kind, sig) in self._dispatched:
+            return jitted(*args)
+        self._dispatched.add((kind, sig))
+        with tracing.span("compile", program=kind, aot=False):
+            return jitted(*args)
 
     def _aot_compile(self, attr: str, kind: str, build, args,
                      speculative: bool = False):
@@ -392,9 +411,11 @@ class Trainer:
         exe = self._cache.peek(key)
         if exe is not None:
             return exe
-        # `edl.compile` in a device trace: a compile inside a traced window
-        # says so (the speculative compiler's thread shows on its own line)
-        with jax.set_mesh(self.mesh), profile_lib.annotation("compile", kind=kind):
+        # `edl.compile` in a device trace too: a compile inside a traced
+        # window says so (the speculative compiler's thread shows on its own
+        # line)
+        with jax.set_mesh(self.mesh), \
+                tracing.span("compile", program=kind, aot=True):
             exe = fn.lower(*args).compile()
         return self._cache.store_aot(key, exe, speculative=speculative)
 
@@ -454,6 +475,16 @@ class Trainer:
         PS initializing embedding rows server-side
         (reference: elasticdl/pkg/ps/embedding.go lazy init).
         """
+        with tracing.span("start.state", model=self.spec.module_name):
+            state = self._init_state(example_batch)
+            # the span holds the device's part too: what is dispatched here
+            # would else be waited for under whatever reads the state first
+            jax.block_until_ready(state)
+        n = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
+        logger.info("Initialized model %s: %.3fM params", self.spec.module_name, n / 1e6)
+        return state
+
+    def _init_state(self, example_batch: Dict[str, Any]) -> TrainState:
         model, tx = self.spec.model, self.spec.optimizer
         features, _, _ = _split_batch(example_batch)
         root_key = jax.random.PRNGKey(self.seed)
@@ -510,10 +541,7 @@ class Trainer:
                 self._program_key("init") + (cc.aval_signature(features),),
                 build_create,
             )
-            state = create(root_key, features)
-        n = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
-        logger.info("Initialized model %s: %.3fM params", self.spec.module_name, n / 1e6)
-        return state
+            return create(root_key, features)
 
     def abstract_train_state(self, example_batch: Dict[str, Any]) -> TrainState:
         """Execution-free twin of `init_state`: the same TrainState pytree

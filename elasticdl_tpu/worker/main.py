@@ -6,16 +6,28 @@ argv the master/launcher passed, build the Worker, run the task loop.
 
 from __future__ import annotations
 
-import os
-import signal
-import sys
-from typing import List, Optional
+import time
 
-from elasticdl_tpu.common.config import JobConfig
-from elasticdl_tpu.worker.worker import Worker
+_ENTERED = time.time()      # before the imports: `start.process` without /proc
+
+import os  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
+from typing import List, Optional  # noqa: E402
+
+from elasticdl_tpu.common import membership_signal  # noqa: E402
+from elasticdl_tpu.common.config import JobConfig  # noqa: E402
+from elasticdl_tpu.observability import tracing  # noqa: E402
+from elasticdl_tpu.worker.worker import Worker  # noqa: E402
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    # start-up joins the trace the master announced (a job's start, a
+    # reform), where the membership signal file reaches this process
+    tracing.mark_entry(_ENTERED)
+    tracing.join_startup_trace(membership_signal.trace_id())
+    # the process's start to here: interpreter and imports
+    tracing.record_start("process", since=tracing.process_start_ts())
     cfg = JobConfig.from_argv(sys.argv[1:] if argv is None else argv)
     # EDL_PROCESS_ID marks a cohort member even when dynamic resizing has
     # shrunk the world to 1 process (cfg.num_processes is the ORIGINAL size)

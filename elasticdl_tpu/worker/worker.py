@@ -10,6 +10,7 @@ only RPCs left are one lease + one report per task plus heartbeats.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import socket
 import threading
@@ -306,12 +307,16 @@ class Worker:
         from elasticdl_tpu.parallel.mesh import build_job_mesh
         import jax
 
-        configure_jax_runtime(self.cfg)
-        self._spec = ModelSpec.from_config(self.cfg)
-        if self._mesh is None:
-            self._mesh = build_job_mesh(self.cfg, jax.devices())
-        log_training_devices(self._mesh)
-        self._trainer = self._make_trainer(self._mesh)
+        # reaching the chips (18 s for four, PR 21) apart from building the
+        # model: two spans of the start-up ledger
+        with tracing.start_span("backend"):
+            configure_jax_runtime(self.cfg)
+            if self._mesh is None:
+                self._mesh = build_job_mesh(self.cfg, jax.devices())
+            log_training_devices(self._mesh)
+        with tracing.start_span("trainer"):
+            self._spec = ModelSpec.from_config(self.cfg)
+            self._trainer = self._make_trainer(self._mesh)
 
     def _make_trainer(self, mesh):
         """One Trainer construction path for boot AND in-place rescale: the
@@ -1210,8 +1215,16 @@ class Worker:
                 "embedding tier init failed; tier disabled for this worker"
             )
 
+    @staticmethod
+    def _startup_done(first_task: contextlib.ExitStack) -> None:
+        """Close `start.first_task` and print the start-up ledger, when the
+        first task's turn has ended. A second call does nothing."""
+        first_task.close()
+        tracing.log_startup_ledger()
+
     def run(self) -> int:
-        self._connect()
+        with tracing.start_span("connect"):
+            self._connect()
         self._init_embedding_tier()
         # /metrics + /healthz for this worker (best-effort, off the hot
         # path; a set EDL_METRICS_PORT overrides cfg.metrics_port either
@@ -1233,6 +1246,11 @@ class Worker:
         tasks_done = 0
         wait_backoff = 1.0
         prof = profile_lib.get_profiler()
+        # the first lease to the end of the first task's turn: state, restore
+        # and the compilations are its children, the rest (lease, input,
+        # transfer, the dispatches, the report) its own time
+        first_task = contextlib.ExitStack()
+        first_task.enter_context(tracing.start_span("first_task"))
         # one iteration is one task turn: lease, the task, its report. Each
         # is a span of the device profiler's trace (observability/profile.py)
         # so that a gap on the device can be put down to one of them.
@@ -1405,7 +1423,10 @@ class Worker:
                             "the requeued lease re-runs it", task.task_id,
                         )
                 tasks_done += 1
+            if tasks_done == 1:     # the first turn that ran a task to its end
+                self._startup_done(first_task)
 
+        self._startup_done(first_task)      # a job that ended before that
         # A trace window still open at exit (short job / preemption) must be
         # flushed — an unstopped trace writes nothing.
         self._stop_profiler()

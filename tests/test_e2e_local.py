@@ -117,3 +117,70 @@ def test_run_job_stops_when_the_job_is_dead(tmp_path):
     with pytest.raises(AssertionError, match="world formation failed"):
         run_job(cfg, tmp_path, extra_env={"EDL_PROCESS_ID": "2"})
     assert time.time() - t0 < 90
+
+
+# the start-up spans of the table in docs/observability.md, by the process
+# that records them
+LAUNCHER_SPANS = {"start.launch", "start.master", "start.spawn"}
+WORKER_SPANS = {"start.process", "start.connect", "start.backend",
+                "start.trainer", "start.first_task", "start.state", "compile"}
+
+
+def test_every_process_of_a_job_prints_its_start_up_ledger_once(tmp_path):
+    """The whole job as a user starts it — launcher (and master) in one
+    process, one worker in another: each prints one `start-up ledger:` line,
+    together they name every start-up span once, the worker's joins the
+    launcher's trace, and the parts lie in order on the wall clock."""
+    import json
+    import subprocess
+    import sys
+
+    from tests.jobs import HERMETIC_ENV
+
+    argv = [
+        sys.executable, "-m", "elasticdl_tpu.client.main", "train",
+        "--model_zoo", os.path.abspath("model_zoo"),
+        "--model_def", "deepfm.deepfm.custom_model",
+        "--model_params", "field_vocab=64;hidden=16,16",
+        "--minibatch_size", "64", "--steps_per_dispatch", "4",
+        "--training_data", "synthetic://criteo?n=1024&shards=2",
+        "--records_per_task", "512", "--master_addr", "localhost:0",
+        # where the membership signal file lives: how the trace id travels
+        "--checkpoint_dir", str(tmp_path / "ckpt"),
+    ]
+    proc = subprocess.run(
+        argv, env={**os.environ, **HERMETIC_ENV}, capture_output=True,
+        text=True, timeout=300)
+    log = proc.stdout + proc.stderr
+    assert proc.returncode == 0, log[-3000:]
+    marker = "start-up ledger: "
+    ledgers = [json.loads(line.split(marker, 1)[1])
+               for line in log.splitlines() if marker in line]
+    assert len(ledgers) == 2 and len({l["pid"] for l in ledgers}) == 2
+    launcher, worker = ledgers
+    assert set(launcher["spans"]) == LAUNCHER_SPANS
+    assert set(worker["spans"]) == WORKER_SPANS
+    assert (launcher["role"], worker["role"]) == ("master", "worker-0")
+    assert all(span["n"] == 1 for ledger in ledgers
+               for span in ledger["spans"].values())
+    assert worker["trace_id"] == launcher["trace_id"]
+
+    def interval(ledger, name):
+        span = ledger["spans"][name]
+        return span["ts"], span["ts"] + span["s"]
+
+    # launcher up, then the spawn, inside it the worker's interpreter and
+    # imports and its registration, then backend, trainer and the first task
+    order = [interval(launcher, "start.launch"), interval(worker, "start.process"),
+             interval(worker, "start.connect"), interval(worker, "start.backend"),
+             interval(worker, "start.trainer"), interval(worker, "start.first_task")]
+    # (the kernel stamps a process's start to the clock tick, 10 ms)
+    assert all(a[1] <= b[0] + 0.02 for a, b in zip(order, order[1:])), order
+    spawn = interval(launcher, "start.spawn")
+    assert spawn[0] <= order[1][0] + 0.05 and order[2][1] <= spawn[1] + 0.05
+    assert interval(launcher, "start.master")[1] <= order[0][1] + 1e-3
+    # state and compile are the first task's children: its own time is less
+    first_task = worker["spans"]["start.first_task"]
+    assert first_task["self_s"] < first_task["s"] - worker["spans"]["compile"]["s"]
+    assert worker["spans"]["compile"]["each"][0]["program"] == "train_many"
+    assert worker["named_s"] >= 0.95 * worker["wall_s"]

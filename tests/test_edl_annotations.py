@@ -197,8 +197,11 @@ def test_the_task_turn_and_what_it_holds(traced):
     kind, lines, _ = traced
     turns = training_turns(lines)
     assert len(turns) == len({t.stats["task_id"] for t in turns}) == 2
+    # the worker's first turn is the last part of its start-up (the cohort's
+    # loop has Trainer's start-up spans alone)
+    first = None if kind == "cohort-grouped" else "edl.start.first_task"
+    assert [t.parent and t.parent.name for t in turns] == [first, None]
     for turn in turns:
-        assert turn.parent is None
         assert len(turn.under("edl.lease")) == 1
         assert len(turn.under("edl.task")) == 1
         assert len(turn.under("edl.report")) == 1
@@ -396,7 +399,7 @@ def test_an_aot_compile_says_so_in_the_trace(tmp_path):
     with jax.profiler.trace(str(tmp_path)):
         trainer.aot_compile_train_step(state, batch)
     compiles = [s for s in _spans_of(tmp_path) if s.name == "edl.compile"]
-    assert [s.stats for s in compiles] == [{"kind": "train_step"}]
+    assert [s.stats for s in compiles] == [{"program": "train_step", "aot": 1}]
 
 
 # ---------------------------------------------------------------------- #

@@ -233,3 +233,27 @@ def test_step_phase_gauges_appear_in_live_scrape():
     assert 'edl_step_phase_seconds{phase="compute"}' in text
     assert 'edl_step_phase_seconds{phase="data_wait"}' in text
     assert "edl_mem_host_rss_mb" in text
+
+
+def test_a_compile_span_reaches_the_profiler_s_trace_through_the_bridge(tmp_path):
+    """`Trainer` opens `tracing.span("compile")` where it had its own
+    annotation: the bridge in `tracing.span` makes it `edl.compile` in a
+    device trace, with the span's attributes — for an AOT compilation and
+    for a first dispatch alike — and `start.state` beside it."""
+    import jax
+
+    from tests.test_edl_annotations import _spans_of
+    from tests.test_startup_ledger import _stack, _tiny_trainer
+
+    trainer, spec, mesh = _tiny_trainer()
+    example, stack = _stack(mesh, spec, 2)
+    with jax.profiler.trace(str(tmp_path)):
+        state = trainer.init_state(example)
+        trainer.aot_compile_train_step(state, example)
+        trainer.train_many(state, stack)
+    spans = _spans_of(tmp_path)
+    assert [s.stats for s in spans if s.name == "edl.compile"] == [
+        {"program": "train_step", "aot": 1},
+        {"program": "train_many", "aot": 0}]
+    assert [s.stats for s in spans if s.name == "edl.start.state"] == [
+        {"model": "deepfm.deepfm"}]
