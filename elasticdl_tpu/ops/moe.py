@@ -36,14 +36,19 @@ the others add nothing here (on their own chips they would). Then the work
 follows the pairs held, not N·k: the held pairs sort to the front and are
 taken in equal PASSES of `held_pass_rows` rows — twice the held experts' even
 share of the pairs — each gathered, multiplied, weighted and scatter-added to
-its tokens, as many passes as the held pairs fill: a `lax.while_loop`, forward
-and backward, so nothing is ever dropped, nothing is sized for the worst case,
-and a step on which more pairs land is slower by the passes it adds, not
-wrong. Inside a pass the grouped matmul visits only the row tiles that hold a
-held pair and returns the rows past the last one as zeros, so the experts'
-time follows the pairs held and a pass's rows size only its gather and its
-scatter-add. Without `held` every expert is held and the shapes are static at N·k
-rows, whatever the routing.
+its tokens. The first pass is straight-line code, forward and backward, and
+its cotangents go on as its kernels and scatter-adds wrote them; the pairs
+past it are the OVERFLOW, as many further passes as they fill: a
+`lax.while_loop` forward, and backward a `lax.cond` in which the first pass's
+cotangents are widened to float32, the further passes' added in a loop and
+the sums narrowed again. So nothing is ever dropped, nothing is sized for the
+worst case, a step whose held pairs fit a pass pays for no loop and no
+accumulator, and a step on which more pairs land is slower by the passes it
+adds, not wrong. Inside a pass the grouped matmul visits only the row tiles
+that hold a held pair and returns the rows past the last one as zeros, so the
+experts' time follows the pairs held and a pass's rows size only its gather
+and its scatter-add. Without `held` every expert is held and the shapes are
+static at N·k rows, whatever the routing.
 """
 
 from __future__ import annotations
@@ -286,15 +291,22 @@ def _held_passes(xd, flat_weights, experts, order, starts, ends, k, rows):
     """Σ over the passes the held pairs fill of `_held_pass`: xd (N, C),
     flat_weights (N·k,) float32, `order` the pairs with the held ones first
     and `rows` entries of padding, starts / ends (count,) of the held experts'
-    groups in it. (N, C) float32."""
+    groups in it. (N, C) float32.
+
+    The first pass is straight-line code, whatever the routing: with no held
+    pair at all it adds nothing (every row is past `ends[-1]`: the kernel
+    skips every tile and every weight is zero), at the price of one empty
+    pass. The loop is the overflow, passes 1, 2, …: held pairs that fit one
+    pass run none of it and the first pass's sum is handed through in place."""
     def one_more(carry):
         i, y = carry
         return i + 1, _held_pass(y, xd, flat_weights, experts, order, starts, ends,
                                  i * rows, k, rows)
 
-    zero = jnp.zeros(xd.shape, jnp.float32)
+    first = _held_pass(jnp.zeros(xd.shape, jnp.float32), xd, flat_weights, experts,
+                       order, starts, ends, 0, k, rows)
     return jax.lax.while_loop(lambda c: c[0] * rows < ends[-1], one_more,
-                              (jnp.int32(0), zero))[1]
+                              (jnp.int32(1), first))[1]
 
 
 def _held_passes_fwd(xd, flat_weights, experts, order, starts, ends, k, rows):
@@ -303,28 +315,39 @@ def _held_passes_fwd(xd, flat_weights, experts, order, starts, ends, k, rows):
 
 
 def _held_passes_bwd(k, rows, res, g):
-    # the same passes again: each is recomputed and transposed, its
-    # cotangents added up in float32 — nothing is kept from the forward loop
+    """The same passes again, each recomputed and transposed: nothing is kept
+    from the forward. The first pass's cotangents are the result as its
+    kernels and scatter-adds wrote them, in the operands' dtypes. Only where
+    the held pairs overflow a pass (`ends[-1] > rows`) are they widened to
+    float32, the further passes' added to them in a loop and the sums
+    narrowed again."""
     xd, flat_weights, experts, order, starts, ends = res
     zero = jnp.zeros_like(g)
-    f32 = lambda tree: jax.tree_util.tree_map(
-        lambda a: jnp.zeros(a.shape, jnp.float32), tree)
 
-    def one_more(carry):
-        i, sums = carry
+    def pull_back(lo):
         _, transpose = jax.vjp(
-            lambda a, b, c: _held_pass(zero, a, b, c, order, starts, ends,
-                                       i * rows, k, rows),
+            lambda a, b, c: _held_pass(zero, a, b, c, order, starts, ends, lo, k, rows),
             xd, flat_weights, experts)
+        # the whole pull-back of a pass is billed to the experts' scope
         with jax.named_scope("experts"):
-            return i + 1, jax.tree_util.tree_map(
-                lambda s, d: s + d.astype(jnp.float32), sums, transpose(g))
+            return transpose(g)
 
-    _, sums = jax.lax.while_loop(
-        lambda c: c[0] * rows < ends[-1], one_more,
-        (jnp.int32(0), f32((xd, flat_weights, experts))))
-    dx, dw, dexperts = jax.tree_util.tree_map(
-        lambda s, a: s.astype(a.dtype), sums, (xd, flat_weights, experts))
+    def overflow(first):
+        def one_more(carry):
+            i, sums = carry
+            more = pull_back(i * rows)
+            with jax.named_scope("experts"):
+                return i + 1, jax.tree_util.tree_map(
+                    lambda s, d: s + d.astype(jnp.float32), sums, more)
+
+        _, sums = jax.lax.while_loop(
+            lambda c: c[0] * rows < ends[-1], one_more,
+            (jnp.int32(1), jax.tree_util.tree_map(
+                lambda d: d.astype(jnp.float32), first)))
+        return jax.tree_util.tree_map(lambda s, d: s.astype(d.dtype), sums, first)
+
+    dx, dw, dexperts = jax.lax.cond(ends[-1] > rows, overflow, lambda first: first,
+                                    pull_back(0))
     return dx, dw, dexperts, None, None, None
 
 

@@ -22,9 +22,9 @@ anywhere except the convolution's (hidden C):
   weights `routed_scaling_factor · s_e / Σ_chosen s` (the bias does not
   weigh); expert e is `W_down,e relu(W_up,e h)²`; this chip holds experts
   `first_expert … first_expert + n_routed_experts − 1` and computes every
-  pair routed to them (`ops.moe.dropless_moe`, `held`, in passes of
-  `ops.moe.held_pass_rows` rows through the grouped matmul of
-  `ops.pallas_gmm`, which skips the row tiles past the last held pair;
+  pair routed to them (`ops.moe.dropless_moe`, `held`: one pass of
+  `ops.moe.held_pass_rows` rows and a loop of more for what overflows it,
+  through `ops.pallas_gmm`, which skips the row tiles past the last held pair;
   `router_state/held_passes` counts the passes and
   `router_state/held_row_tiles` the row tiles that held a pair);
   what the other experts would add is left out; plus one shared expert of

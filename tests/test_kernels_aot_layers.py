@@ -85,9 +85,40 @@ RECOMPUTED_ATTENTION = {
 }
 
 
+@pytest.fixture(scope="module")
+def compiled_texts():
+    """model -> the compiled text of its gradient program: one compile a module."""
+    return {}
+
+
+def gradient_program(model, one_chip, monkeypatch, compiled_texts):
+    """The compiled text of `RECOMPUTED_ATTENTION[model]`'s value and gradient
+    at the cell's tokens, for the described chip."""
+    import importlib
+
+    if model not in compiled_texts:
+        module, config, length, _ = RECOMPUTED_ATTENTION[model]
+        zoo = importlib.import_module(f"model_zoo.transformer.{module}")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+        net = zoo.custom_model(**config)
+        tokens = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
+        variables = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
+
+        def loss(params, state, tokens):
+            outputs = net.apply({"params": params, **state}, tokens)
+            return sum(jnp.sum(jnp.square(x)) for x in jax.tree_util.tree_leaves(outputs))
+
+        params = variables.pop("params")
+        compiled_texts[model] = jax.jit(jax.value_and_grad(loss)).lower(
+            params, variables, tokens).compile().as_text()
+    return compiled_texts[model]
+
+
 @pytest.mark.parametrize("model", sorted(RECOMPUTED_ATTENTION))
 def test_a_recomputed_layer_runs_the_flash_forward_once_under_its_scope(
-        model, one_chip, no_compile_cache, monkeypatch):
+        model, one_chip, no_compile_cache, monkeypatch, compiled_texts):
     """`forward` checkpoints its layers with `pallas_attention.
     KEEP_RESIDUALS`: the gradient program at the cell's tokens compiles with
     one `flash_attention_fwd` call a block (two under the plain checkpoint)
@@ -96,31 +127,76 @@ def test_a_recomputed_layer_runs_the_flash_forward_once_under_its_scope(
     finds each under the block's own `attn` scope: the benchmark's `mla_ms`,
     `mtp_ms`, `swa_ms` and the name-prefix readers (`mla_attn_ms`,
     `gqa_attn_ms`, `swa_attn_ms`, `global_attn_ms`) read them there."""
-    import importlib
-
     from benchmark import common
 
-    module, config, length, blocks = RECOMPUTED_ATTENTION[model]
-    zoo = importlib.import_module(f"model_zoo.transformer.{module}")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
-    net = zoo.custom_model(**config)
-    tokens = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
-    variables = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
-
-    def loss(params, state, tokens):
-        outputs = net.apply({"params": params, **state}, tokens)
-        return sum(jnp.sum(jnp.square(x)) for x in jax.tree_util.tree_leaves(outputs))
-
-    params = variables.pop("params")
-    text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
+    module, _, _, blocks = RECOMPUTED_ATTENTION[model]
+    text = gradient_program(model, one_chip, monkeypatch, compiled_texts)
     calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
     kinds = [re.sub(r"\.\d+$", "", name) for name in calls]
     scope_map = common.load_module("drivers", "resident_lm_share").scope_map
     found = scope_map(text, common.load_module("flops", module).SCOPES)
     assert sorted((kind, found.get(name)) for kind, name in zip(kinds, calls)) == sorted(
         (prefix + part, scope) for scope, prefix in blocks for part in ("fwd", "bwd"))
+
+
+def held_first_pass_scopes(text, module):
+    """Every instruction of a held dispatch's FIRST pass in a compiled text,
+    as (its `op_name` from the sparse layer's scope on, the scope the
+    benchmark's readers put it in), and the grouped matmuls among them. The
+    first pass is what stands under no `cond/branch_` (the backward's
+    overflow) and no `while/body` (the forward's)."""
+    from benchmark import common
+
+    share = common.load_module("drivers", "resident_lm_share")
+    scopes = common.load_module("flops", module).SCOPES
+    found, kernels = [], []
+    for line in text.splitlines():
+        op = share._lm._OP_NAME.search(line)
+        if not op or "/moe/" not in op.group(1):
+            continue
+        tail = op.group(1).rsplit("/moe/", 1)[1]
+        if "cond/branch_" in tail or "while/" in tail:
+            continue
+        tail = share._NOT_A_SCOPE.sub("", tail)     # a layer's `checkpoint/`
+        if re.match(r"(jvp\()?(dispatch|experts|combine)\b", tail):
+            found.append((tail, share.scope_of(op.group(1), scopes)))
+            if re.match(r"\s*(ROOT )?%?grouped_matmul", line):
+                kernels.append(found[-1])
+    return found, kernels
+
+
+def assert_the_first_pass_reads_under_the_pass_s_own_scopes(text, module):
+    found, kernels = held_first_pass_scopes(text, module)
+    assert kernels and all(re.search(r"/moe/experts$", scope) for _, scope in kernels)
+    for tail, scope in found:
+        # what the readers' normalisation leaves starts with the part's name,
+        # so the model's scope, `moe` and the part stand side by side
+        part = re.match(r"(?:jvp\()?(\w+)", tail).group(1)
+        assert scope is not None and scope.endswith(f"/moe/{part}"), (tail, scope)
+    backward = [(tail, scope) for tail, scope in found if "transpose(" in tail]
+    # a pass's whole pull-back is evaluated under `named_scope("experts")`
+    assert backward and all(tail.startswith("experts/") for tail, _ in backward)
+    assert {"experts/transpose(jvp(dispatch))", "experts/transpose(jvp(combine))"} <= {
+        "/".join(tail.split("/")[:2]) for tail, _ in backward}
+    # the cotangents' float32 sums are the overflow's alone
+    assert not any(tail.startswith("experts/add") for tail, _ in found)
+
+
+@pytest.mark.parametrize("model", sorted(RECOMPUTED_ATTENTION))
+def test_a_held_dispatch_s_first_pass_reads_under_dispatch_experts_and_combine(
+        model, one_chip, no_compile_cache, monkeypatch, compiled_texts):
+    """`ops/moe.py::_held_passes` runs its first pass as straight-line code
+    and keeps the float32 sums of several passes' cotangents inside a
+    `lax.cond`, whose `cond/branch_1_fun` the readers' normalisation does not
+    strip (`resident_lm_share._NOT_A_SCOPE`): every instruction of the first
+    pass, forward, recomputed and backward, still reads under
+    `<model>/moe/{dispatch,experts,combine}`, the grouped matmuls and the
+    whole pull-back under `experts` (`held_moe_ms`, `moe_routed_ms`,
+    `held16_moe_ms`, `xing_held_moe_ms` and the `*_gmm_roofline`s sum them
+    there), and no `experts/add` is left outside the overflow."""
+    assert_the_first_pass_reads_under_the_pass_s_own_scopes(
+        gradient_program(model, one_chip, monkeypatch, compiled_texts),
+        RECOMPUTED_ATTENTION[model][0])
 
 
 def test_trinity_s_layers_keep_the_full_layer_s_residuals_and_not_the_sliding_one_s(
@@ -163,3 +239,4 @@ def test_trinity_s_layers_keep_the_full_layer_s_residuals_and_not_the_sliding_on
     assert {"afmoe/sliding/gate", "afmoe/full/gate", "afmoe/moe/experts"} <= scopes
     assert not any(s.startswith("afmoe/full/rope") for s in scopes)
     assert not re.search(r'op_name="[^"]*full/rope', text)
+    assert_the_first_pass_reads_under_the_pass_s_own_scopes(text, "afmoe")

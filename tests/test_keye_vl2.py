@@ -115,8 +115,11 @@ def test_the_step_reports_each_sown_term_by_name(case):
 
 
 def _selections_in(jaxpr):
-    """`select`'s tie rule is the model's only `cond`: one a run of it."""
-    return equations(jaxpr, lambda eqn: eqn.primitive.name == "cond")
+    """`select`'s tie rule is the model's only `cond` that gives a boolean
+    plane (the held dispatch's backward holds one a layer around its
+    overflow's float32 sums): one a run of it."""
+    return equations(jaxpr, lambda eqn: eqn.primitive.name == "cond"
+                     and eqn.outvars[0].aval.dtype == jnp.bool_)
 
 
 def _gradient_under(policy, spec, batch, params, run=True):

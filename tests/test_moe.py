@@ -3,6 +3,8 @@ semantics, replicated-vs-expert-sharded parity, training, and the
 comm-structure bound (no expert-weight-sized collectives).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -315,7 +317,7 @@ def held_routings(n, k, num_experts, held):
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
-@pytest.mark.parametrize("pass_rows", [0, 40])
+@pytest.mark.parametrize("pass_rows", [0, 40, 47, 48])
 @pytest.mark.parametrize("routing", ["even", "every_pair_on_a_held_expert",
                                      "no_pair_on_a_held_expert"])
 @pytest.mark.parametrize("body", ["relu2", "gated_silu"])
@@ -323,8 +325,12 @@ def test_held_dispatch_matches_loop_over_held_experts(body, routing, pass_rows, 
                                                       monkeypatch):
     """16 experts, experts 4-7 held, relu² bodies (two matrices) and gated
     SiLU bodies (three). At 40 rows a pass the 192 pairs that all land on
-    held experts take five passes, the last one part full; with none on a
-    held expert no pass runs: nothing is dropped."""
+    held experts take five passes, the last one part full; the even routing
+    puts 48 pairs on them, which at 48 rows fill the first pass to its last
+    row and run no overflow, and at 47 rows leave ONE live row to one
+    overflow pass; with none on a held expert the first pass runs and adds
+    nothing — the output and every gradient are exact zeros: nothing is
+    dropped."""
     n, c, f, e, k, held = 64, 16, 8, 16, 3, (4, 4)
     r = np.random.default_rng(0)
     x = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
@@ -341,6 +347,8 @@ def test_held_dispatch_matches_loop_over_held_experts(body, routing, pass_rows, 
         monkeypatch.setattr(moe_ops, "held_pass_rows", lambda pairs, e, count: pass_rows)
     if routing == "no_pair_on_a_held_expert":
         assert on_held == 0
+    if routing == "even":
+        assert on_held == 48
     if routing == "every_pair_on_a_held_expert":
         assert on_held == n * k
     run = lambda x, weights, *w: moe_ops.dropless_moe(
@@ -349,6 +357,8 @@ def test_held_dispatch_matches_loop_over_held_experts(body, routing, pass_rows, 
     if direction == "forward":
         np.testing.assert_allclose(run(x, weights, *w), loop(x, weights, *w),
                                    rtol=1e-4, atol=1e-5)
+        if not on_held:
+            assert not np.any(np.asarray(run(x, weights, *w)))
         return
     probe = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
     every = tuple(range(2 + len(w)))
@@ -356,16 +366,18 @@ def test_held_dispatch_matches_loop_over_held_experts(body, routing, pass_rows, 
     want = jax.grad(lambda *a: jnp.sum(probe * loop(*a)), argnums=every)(x, weights, *w)
     for g, h in zip(got, want):
         np.testing.assert_allclose(g, h, rtol=1e-4, atol=1e-5)
-    if routing == "no_pair_on_a_held_expert":
-        assert not np.any(np.asarray(got[2])) and not np.any(np.asarray(got[0]))
+    if not on_held:
+        assert not any(np.any(np.asarray(g)) for g in got)
 
 
-@pytest.mark.parametrize("pass_rows", [8, 11, 64])
+@pytest.mark.parametrize("pass_rows", [8, 11, 21, 22, 64])
 def test_as_many_passes_as_the_held_pairs_fill(pass_rows, monkeypatch):
     """Random routing, passes smaller than the held pairs (8 and 11 rows:
-    several passes, group boundaries inside a pass and across passes) and
-    larger (64): values and every gradient are the loop's over held experts,
-    under `jit` as in a step."""
+    several passes, group boundaries inside a pass and across passes), one
+    row short of them (21: the overflow's loop runs once, for one live row),
+    exactly theirs (22: the first pass full, no overflow) and larger (64):
+    values and every gradient are the loop's over held experts, under `jit`
+    as in a step."""
     monkeypatch.setattr(moe_ops, "held_pass_rows", lambda pairs, e, count: pass_rows)
     n, c, f, e, k, held = 32, 8, 4, 8, 2, (2, 2)
     r = np.random.default_rng(1)
@@ -374,7 +386,7 @@ def test_as_many_passes_as_the_held_pairs_fill(pass_rows, monkeypatch):
          jnp.asarray(r.normal(size=(2, f, c)), jnp.float32))
     weights = jnp.asarray(r.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
     idx = jnp.asarray(r.integers(0, e, size=(n, k)), jnp.int32)
-    assert 11 < int(np.sum((np.asarray(idx) >= 2) & (np.asarray(idx) < 4))) < 64
+    assert int(np.sum((np.asarray(idx) >= 2) & (np.asarray(idx) < 4))) == 22
     probe = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
     run = lambda x, weights, *w: jnp.sum(probe * moe_ops.dropless_moe(
         x, idx, weights, w, held=held, num_experts=e, compute_dtype=jnp.float32))
@@ -383,6 +395,109 @@ def test_as_many_passes_as_the_held_pairs_fill(pass_rows, monkeypatch):
     want = jax.value_and_grad(loop, argnums=(0, 1, 2, 3))(x, weights, *w)
     for g, h in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, h, rtol=1e-4, atol=1e-5)
+
+
+def held_layer(body):
+    """A held layer's inputs at shapes no two of which agree: 16 experts,
+    experts 4-7 held, tokens and matrices bfloat16 as a model hands them after
+    its cast. (run, (x, weights, *matrices)); 45 of the 192 pairs are held."""
+    n, c, f, e, k, held = 64, 32, 24, 16, 3, (4, 4)
+    r = np.random.default_rng(3)
+    idx = jnp.asarray(r.integers(0, e, size=(n, k)), jnp.int32)
+    assert int(np.sum((np.asarray(idx) >= 4) & (np.asarray(idx) < 8))) == 45
+    x = jnp.asarray(r.normal(size=(n, c)), jnp.bfloat16)
+    up_like = ((held[1], c, f),) * (2 if body == "gated_silu" else 1)
+    w = tuple(jnp.asarray(r.normal(size=s) * 0.3, jnp.bfloat16)
+              for s in up_like + ((held[1], f, c),))
+    weights = jnp.asarray(r.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
+    run = lambda x, weights, *w: moe_ops.dropless_moe(
+        x, idx, weights, w, held=held, num_experts=e)
+    return run, (x, weights, *w)
+
+
+@pytest.mark.parametrize("body", ["relu2", "gated_silu"])
+def test_a_one_pass_backward_hands_on_what_the_accumulators_would(body, monkeypatch):
+    """Held pairs that fit one pass: dx, dw and every matrix's gradient are,
+    in the operands' dtypes (bfloat16, float32, bfloat16) and to the bit, what
+    the pass's cotangents give when added to float32 zeros and narrowed again
+    — the arithmetic of the accumulators this path no longer makes. (The sum
+    turns a cotangent's -0.0 into 0.0: equal as numbers, which is what is
+    compared.)"""
+    run, operands = held_layer(body)
+    seen = []
+    with monkeypatch.context() as m:
+        m.setattr(moe_ops, "_held_passes",
+                  lambda *a: seen.append(a) or jnp.zeros(a[0].shape, jnp.float32))
+        run(*operands)
+    xd, flat_weights, experts, order, starts, ends, k, rows = seen[0]
+    assert int(ends[-1]) == 45 <= rows
+    g = jnp.asarray(np.random.default_rng(4).normal(size=xd.shape), jnp.float32)
+    through = lambda passes: jax.vjp(passes, xd, flat_weights, experts)[1](g)
+    got = through(lambda a, b, c: moe_ops._held_passes(a, b, c, order, starts, ends, k, rows))
+    once = through(lambda a, b, c: moe_ops._held_pass(
+        jnp.zeros_like(g), a, b, c, order, starts, ends, 0, k, rows))
+    want = jax.tree_util.tree_map(
+        lambda d: (jnp.zeros(d.shape, jnp.float32) + d.astype(jnp.float32)).astype(d.dtype),
+        once)
+    assert [d.dtype for d in jax.tree_util.tree_leaves(got)] == (
+        [jnp.bfloat16, jnp.float32] + [jnp.bfloat16] * len(experts))
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert np.any(np.asarray(a, np.float32))
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+def hlo_computations(hlo_text):
+    """name -> body, of an HLO module's text."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{$\n(.*?)^\}$", hlo_text, re.M | re.S)}
+
+
+def hlo_reach(computations, name, through):
+    """The text of `name` and, once a mention, of every computation it
+    reaches by the attributes `through` (`to_apply`, `body`, `condition`)."""
+    return computations[name] + "".join(
+        hlo_reach(computations, callee, through) for attribute in through
+        for callee in re.findall(rf"\b{attribute}=%?([\w.\-]+)", computations[name]))
+
+
+@pytest.mark.parametrize("body", ["relu2", "gated_silu"])
+def test_the_first_pass_is_straight_line_code_and_the_accumulators_are_the_overflow_s(
+        body, monkeypatch):
+    """The lowered text of a held layer's value and gradient, with the
+    kernels as a TPU takes them: every grouped matmul of a pass that runs
+    whatever the routing — forward one a matrix, backward the recomputed one,
+    a dx and a dW a matrix — is reached from the entry by calls alone; the
+    only loop outside a `conditional` is the forward's overflow, whose carry
+    is the (N, C) sum; the backward's untaken branch hands its operands
+    through; and no float32 array of a matrix's shape exists outside the
+    taken branch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the kernel's route asks
+    run, operands = held_layer(body)
+    probe = jnp.ones(operands[0].shape, jnp.float32)
+    text = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(probe * run(*a)), argnums=tuple(range(len(operands)))
+    )).trace(*operands).lower(lowering_platforms=("tpu",)).as_text(dialect="hlo")
+    computations = hlo_computations(text)
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+    kernels = lambda t: t.count('custom_call_target="tpu_custom_call"')
+    matrices = len(operands) - 2
+    straight = hlo_reach(computations, entry, ("to_apply",))
+    assert kernels(straight) == 4 * matrices
+
+    (identity, overflow), = [
+        names.replace("%", "").split(", ")
+        for names in re.findall(r"conditional\(.*branch_computations=\{([^}]*)\}", straight)]
+    assert not re.search(r"custom-call|convert|while", computations[identity])
+    overflow = hlo_reach(computations, overflow, ("to_apply", "body", "condition"))
+    assert kernels(overflow) == 3 * matrices and " while(" in overflow
+    wide = [rf"f32\[{','.join(map(str, w.shape))}\]" for w in operands[2:]]
+    assert all(re.search(shape, overflow) for shape in wide)
+
+    loops = re.findall(r"^.* while\(.*body=%?([\w.\-]+)", straight, re.M)
+    assert len(loops) == 1                       # the forward's overflow
+    outside = straight + hlo_reach(computations, loops[0], ("to_apply", "body", "condition"))
+    assert kernels(outside) == 5 * matrices
+    assert not any(re.search(shape, outside) for shape in wide)
 
 
 def test_held_all_is_the_plain_dispatch_and_a_wrong_share_is_refused():

@@ -190,6 +190,25 @@ def test_expert_body_on_the_kernels_route_is_the_ragged_dot_routes(body, directi
     assert not np.any(np.asarray(got[1])[1])                 # the empty group's matrices
 
 
+def test_a_first_pass_with_no_held_pair_skips_every_tile_and_adds_nothing():
+    """The held dispatch runs its first pass whatever the routing: with no
+    pair on a held expert, on the kernels' route, every row is past the last
+    group — the value and every gradient are exact zeros."""
+    n, c, f, e, k, held = 64, 32, 128, 16, 3, (4, 4)
+    r = np.random.default_rng(6)
+    x = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    experts = tuple(jnp.asarray(r.normal(size=s) * 0.3, jnp.float32)
+                    for s in ((4, c, f), (4, c, f), (4, f, c)))
+    weights = jnp.asarray(r.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
+    idx = jnp.asarray(r.integers(8, e, size=(n, k)), jnp.int32)    # experts 8-15 only
+    probe = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    run = lambda x, weights, *w: jnp.sum(probe * moe_ops.dropless_moe(
+        x, idx, weights, w, held=held, num_experts=e, compute_dtype=jnp.float32))
+    with interpret_mode():
+        value, grads = jax.value_and_grad(run, argnums=(0, 1, 2, 3, 4))(x, weights, *experts)
+    assert float(value) == 0.0 and not any(np.any(np.asarray(g)) for g in grads)
+
+
 @pytest.mark.parametrize("pass_rows", [0, 40])
 def test_held_row_tiles_is_the_kernels_own_count(pass_rows, monkeypatch):
     """`ops.moe.held_row_tiles` — what `router_state/held_row_tiles` adds up —
