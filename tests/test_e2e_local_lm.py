@@ -1,5 +1,5 @@
 """End-to-end, the language models: the control plane is model-agnostic, so
-the toy transformer and the zoo's five sparse-expert models at tiny sizes run
+the toy transformer, the zoo's sparse-expert models and its looped dense one at tiny sizes run
 the SAME in-process master + real worker subprocesses over gRPC that
 `tests/test_e2e_local.py` runs MNIST through. A file of its own so that two
 xdist workers share the job tests.
@@ -222,3 +222,58 @@ def test_local_afmoe_job_end_to_end(tmp_path):
     assert 0.0 <= results["token_accuracy"] <= 1.0
     assert abs(results["gate_mean_sliding"] - 0.5) < 0.1 and abs(results["gate_mean_full"] - 0.5) < 0.1
     assert master.servicer.mean_training_loss() < 6.0       # ln 256 = 5.5, no auxiliary term
+
+
+def test_local_ouro_job_end_to_end(tmp_path):
+    """Ouro's loop (two layers run three times over shared weights, an exit
+    after every pass through one head and one gate, the expected loss over the
+    exits with its entropy term; the module's outputs a pytree the zoo's loss
+    makes the logits from) through the same master/worker path, evaluation —
+    the mean exit distribution among its metrics — included, and a checkpoint
+    saved by the worker and restored here."""
+    import jax
+    import numpy as np
+
+    from elasticdl_tpu.parallel.mesh import build_mesh
+    from elasticdl_tpu.training.checkpoint import CheckpointManager
+    from elasticdl_tpu.training.model_spec import ModelSpec
+    from elasticdl_tpu.training.trainer import Trainer
+
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.ouro.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 48, "num_hidden_layers": 2,
+            "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
+            "intermediate_size": 96, "total_ut_steps": 3, "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+        checkpoint_dir=str(tmp_path / "ckpt"),
+        checkpoint_steps=16,
+    )
+    # a worker reaped while it imports beside five other xdist workers is
+    # ROADMAP C21's, not this case's
+    master, _, counts = run_job(cfg, tmp_path, master_of=patient_master)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    results = master.evaluation.latest_results()
+    assert 0.0 <= results["token_accuracy"] <= 1.0
+    shares = [results[f"exit_share_{t}"] for t in (1, 2, 3, 4)]
+    assert abs(sum(shares) - 1.0) < 1e-4 and shares[3] == 0.0 and min(shares[:3]) > 0.05
+    # ln 256 = 5.55; the entropy term takes at most 0.1 ln 3 off it
+    assert 5.0 < master.servicer.mean_training_loss() < 6.0
+
+    trainer = Trainer(ModelSpec.from_config(cfg), build_mesh(devices=jax.devices()[:1]))
+    example = {"features": np.zeros((4, 32), np.int32), "labels": np.zeros((4, 32), np.int32),
+               "mask": np.ones((4,), np.float32)}
+    checkpoints = CheckpointManager(str(tmp_path / "ckpt"))
+    restored = checkpoints.restore(trainer.abstract_train_state(example))
+    checkpoints.close()
+    assert int(restored.step) == checkpoints.last_restored_step >= 16
+    assert restored.params["wq"].shape == (2, 48, 64)
+    assert restored.params["exit_gate_w"].shape == (48,)
+    assert int(restored.extra_vars["loop"]["layer_applications"]) == 6 * int(restored.step)

@@ -240,3 +240,38 @@ def test_trinity_s_layers_keep_the_full_layer_s_residuals_and_not_the_sliding_on
     assert not any(s.startswith("afmoe/full/rope") for s in scopes)
     assert not re.search(r'op_name="[^"]*full/rope', text)
     assert_the_first_pass_reads_under_the_pass_s_own_scopes(text, "afmoe")
+
+
+def test_ouro_s_loop_keeps_every_application_s_residuals(
+        one_chip, no_compile_cache, monkeypatch):
+    """ouro-2.6b.resident-4k at its published widths, two layers run twice
+    over shared weights at 4096 tokens, through the zoo's own loss (the exits'
+    logits one at a time): every application holds ONE forward kernel, its
+    recomputation none — 2 x 2 forward and 2 x 2 backward kernels, every one
+    under `ouro/pass/attn` — and every scope the benchmark reads the loop by
+    is in the compiled text."""
+    from benchmark import common
+    from model_zoo.transformer import ouro
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+    net = ouro.custom_model(num_hidden_layers=2, total_ut_steps=2, vocab_size=512)
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    variables = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
+
+    def loss(params, state, tokens):
+        outputs = net.apply({"params": params, **state}, tokens)
+        return jnp.sum(ouro.loss(tokens, outputs)["loss"])
+
+    params = variables.pop("params")
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
+    calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
+    kinds = sorted(re.sub(r"\.\d+$", "", name) for name in calls)
+    assert kinds == ["flash_attention_bwd"] * 4 + ["flash_attention_fwd"] * 4
+    scope_map = common.load_module("drivers", "resident_lm_share").scope_map
+    found = scope_map(text, common.load_module("flops", "ouro").SCOPES)
+    assert {found.get(name) for name in calls} == {"ouro/pass/attn"}
+    assert set(found.values()) >= {
+        "ouro/embed", "ouro/pass/attn", "ouro/pass/mlp", "ouro/pass/norm",
+        "ouro/pass/final_norm", "ouro/exit", "ouro/exit_loss"}
