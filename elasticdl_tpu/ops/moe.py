@@ -42,12 +42,26 @@ past it are the OVERFLOW, as many further passes as they fill: a
 `lax.while_loop` forward, and backward a `lax.cond` in which the first pass's
 cotangents are widened to float32, the further passes' added in a loop and
 the sums narrowed again. So nothing is ever dropped, nothing is sized for the
-worst case, a step whose held pairs fit a pass pays for no loop and no
-accumulator, and a step on which more pairs land is slower by the passes it
+worst case, a step whose held pairs fit a pass pays for no loop of passes and
+no accumulator, and a step on which more pairs land is slower by the passes it
 adds, not wrong. Inside a pass the grouped matmul visits only the row tiles
 that hold a held pair and returns the rows past the last one as zeros, so the
-experts' time follows the pairs held and a pass's rows size only its gather
-and its scatter-add. Without `held` every expert is held and the shapes are
+experts' time follows the pairs held. Of what XLA does around the kernels, the
+combine's PULL-BACK follows them too: `_add_pass_rows`, a function with a rule
+of its own (a loop of a run-time trip count has no reverse mode), gathers the
+cotangent's rows in float32 a CHUNK of `held_row_chunk` rows at a time — whole
+row tiles, a sixteenth of a pass — for as many chunks as hold a held pair, and
+each leaves its chunk as the rows the kernels take and that chunk's dw: no
+float32 (rows, C) array is made. The two scatter-adds (the combine's into the
+tokens, and the one that transposes the dispatch's gather) are XLA's, one call
+over the pass's rows — its sort-and-merge scatter costs a call 1–2 ms before
+the first row and the rows past the last held pair next to nothing, so chunks
+of it lose (`SCATTER_CHUNK_ROWS`) — but for the combine's in passes of at most
+sixteen times 256 rows, whose chunks XLA walks row by row at the whole's price a
+row. So a pass's rows
+still size one call of each kernel, the dispatch's gather and its transpose,
+the elementwise passes between the kernels and, in large passes, the combine's
+float32 addends. Without `held` every expert is held and the shapes are
 static at N·k rows, whatever the routing.
 """
 
@@ -267,12 +281,110 @@ def held_row_tiles(on_held, pairs: int, num_experts: int, count: int) -> jax.Arr
                for lo in range(0, pairs, rows))
 
 
+def held_row_chunk(rows: int) -> int:
+    """The rows of one CHUNK of a pass of `rows` rows: a whole number of the
+    kernel's row tiles, a sixteenth of the pass where its tiles divide so —
+    else the most equal parts under sixteen they do divide into, and the whole
+    pass where it is no whole number of tiles. What walks a pass's rows one
+    at a time and can choose (`_add_pass_rows` and its pull-back) walks the
+    chunks that hold a held pair and no others."""
+    tm = pallas_gmm.row_tile(rows)
+    if rows % tm:
+        return rows
+    return rows // max(d for d in range(1, 17) if (rows // tm) % d == 0)
+
+
+def held_row_chunks(on_held, pairs: int, num_experts: int, count: int) -> jax.Array:
+    """() int32: the chunks that hold a held pair, over the passes of a held
+    dispatch (as `held_row_tiles`, at `held_row_chunk` rows a chunk) — what
+    the combine's pull-back (and, in small passes, its scatter-add) walks of
+    `passes x held_pass_rows / chunk`."""
+    rows = held_pass_rows(pairs, num_experts, count)
+    chunk = held_row_chunk(rows)
+    return sum(pallas_gmm.row_tiles(jnp.clip(on_held - lo, 0, rows), chunk)
+               for lo in range(0, pairs, rows))
+
+
+def _rows_at(x, at, chunk: int):
+    """x[at:at + chunk] of a pass's (rows, ...) array."""
+    return jax.lax.dynamic_slice_in_dim(x, at, chunk)
+
+
+def _over_live_chunks(live, chunk: int, body, init):
+    """body(at, carry) for at = 0, chunk, 2·chunk, … below `live`: a loop of
+    as many trips as chunks hold a live row. It has no reverse-mode rule:
+    `_add_pass_rows` brings its own."""
+    return jax.lax.fori_loop(0, pallas_gmm.row_tiles(live, chunk),
+                             lambda c, carry: body(c * chunk, carry), init)
+
+
+# XLA's scatter-add on a TPU goes one of two ways (my chip runs, PR 53; into
+# (16 384, 2304) float32): few update rows are walked one by one, ≈ 0.2 µs a
+# row (256 rows 0.11 ms, 1024 0.34); many are sorted and merged, 1–2 ms a call
+# whatever their number and 35–100 ns a row after that (4096 rows 2.07 ms,
+# 16 384 2.99, 65 536 7.03) — and the rows past the last held pair, whose
+# tokens come in order, cost it next to nothing. So the combine's scatter-add
+# is walked in chunks only where a chunk goes the first way AT NO MORE A ROW
+# than the whole: 256 rows into 4096 tokens, 0.045 ms = 176 ns a row against
+# 167 for 4096 rows in one call, so a full pass costs what it did and a
+# half-full one half (Xing's cell: `combine` 10.1 → 7.0 ms a step). Chunks of
+# 512 rows into 8192 tokens still go the first way (0.087 ms, 170 ns a row)
+# but the call over a pass of 8192 rows is cheaper a row than that in the
+# cell (GLM's `combine` scatter-add read 3.89 ms a step whole, 4.58 in chunks),
+# and 1024 rows into 4096 tokens or 2048 anywhere go the second way.
+SCATTER_CHUNK_ROWS = 256
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _add_pass_rows(y, ys, w, tokens, live, chunk):
+    """y (N, C) float32 plus row r of ys (rows, C) times w[r], added to token
+    tokens[r]: over the chunks below `live` (chunks of `SCATTER_CHUNK_ROWS`
+    at most; the rows past `live` in the last of them weigh nothing) or in
+    one call over all rows (larger ones). Its pull-back walks the chunks
+    below `live` whatever their size."""
+    def add(at, size, y):
+        addends = _rows_at(ys, at, size).astype(jnp.float32) * _rows_at(w, at, size)[:, None]
+        return y.at[_rows_at(tokens, at, size)].add(addends, mode="promise_in_bounds")
+
+    if chunk > SCATTER_CHUNK_ROWS:
+        return add(0, tokens.shape[0], y)
+    return _over_live_chunks(live, chunk, lambda at, y: add(at, chunk, y), y)
+
+
+def _add_pass_rows_fwd(y, ys, w, tokens, live, chunk):
+    return _add_pass_rows(y, ys, w, tokens, live, chunk), (ys, w, tokens, live)
+
+
+def _add_pass_rows_bwd(chunk, res, g):
+    """g's rows are gathered a chunk at a time, in float32, and leave the
+    chunk as the rows the kernels take (ys' dtype) and the chunk's dw; the
+    chunks past `live` stay the zeros they start as."""
+    ys, w, tokens, live = res
+
+    def pull(at, carry):
+        dys, dw = carry
+        rows = _take_rows(g, _rows_at(tokens, at, chunk))
+        here = (rows * _rows_at(w, at, chunk)[:, None]).astype(ys.dtype)
+        dw_here = jnp.sum(rows * _rows_at(ys, at, chunk).astype(jnp.float32), axis=1)
+        return (jax.lax.dynamic_update_slice_in_dim(dys, here, at, 0),
+                jax.lax.dynamic_update_slice_in_dim(dw, dw_here, at, 0))
+
+    dys, dw = _over_live_chunks(live, chunk, pull, (jnp.zeros_like(ys), jnp.zeros_like(w)))
+    return g, dys, dw, None, None
+
+
+_add_pass_rows.defvjp(_add_pass_rows_fwd, _add_pass_rows_bwd)
+
+
 def _held_pass(y, xd, flat_weights, experts, order, starts, ends, lo, k, rows):
     """y (N, C) float32 plus rows lo..lo+rows of the sorted order through
     their experts, each weighted and added to its token. The rows past the
     last held pair are in no group: the grouped matmul skips their row tiles
-    and returns them as zeros, forward and backward, and their weight is
-    zero."""
+    and returns them as zeros, forward and backward, their weight is zero,
+    and the combine's pull-back stops at the chunk that holds the last held
+    pair (in a small pass its scatter-add too: `_add_pass_rows`)."""
+    chunk = held_row_chunk(rows)
+    live = jnp.clip(ends[-1] - lo, 0, rows)
     with jax.named_scope("dispatch"):
         pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
         group_sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
@@ -281,9 +393,9 @@ def _held_pass(y, xd, flat_weights, experts, order, starts, ends, lo, k, rows):
     with jax.named_scope("experts"):
         ys = _expert_body(xs, experts, group_sizes, xd.dtype)
     with jax.named_scope("combine"):
-        live = lo + jnp.arange(rows, dtype=jnp.int32) < ends[-1]
-        w = jnp.where(live, _take_rows(flat_weights, pair), 0.0)
-        return y.at[tokens].add(ys.astype(jnp.float32) * w[:, None])
+        w = jnp.where(jnp.arange(rows, dtype=jnp.int32) < live,
+                      _take_rows(flat_weights, pair), 0.0)
+        return _add_pass_rows(y, ys, w, tokens, live, chunk)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(6, 7))

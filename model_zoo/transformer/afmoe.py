@@ -101,7 +101,7 @@ from elasticdl_tpu.ops.attention import full_attention
 from elasticdl_tpu.training import metrics as metrics_lib
 from model_zoo.transformer.glm4_moe_lite import gated_mlp
 from model_zoo.transformer.nemotron_h import (
-    held_passes, held_row_tiles, matmul, pairs_on_held)
+    held_passes, held_row_chunks, held_row_tiles, matmul, pairs_on_held)
 from model_zoo.transformer.olmoe import (  # noqa: F401
     batch_partition, optimizer, rmsnorm, rope)
 from model_zoo.transformer.transformer_lm import TokenAccuracy, dataset_fn  # noqa: F401
@@ -424,6 +424,7 @@ class Afmoe(nn.Module):
         bias = counter("router_state", "expert_bias", (S, c.num_experts), jnp.float32)
         passes = counter("router_state", "held_passes", (S,))
         row_tiles = counter("router_state", "held_row_tiles", (S,))
+        row_chunks = counter("router_state", "held_row_chunks", (S,))
         held_share = counter("router_state", "pairs_held_share", (S,), jnp.float32)
         visits = counter("attn", "kv_block_visits", (len(KINDS),))
         visits_causal = counter("attn", "kv_block_visits_causal", (len(KINDS),))
@@ -434,6 +435,7 @@ class Afmoe(nn.Module):
                 bias.value = updated_bias(bias.value, idx, c)
                 passes.value = passes.value + held_passes(idx, c)
                 row_tiles.value = row_tiles.value + held_row_tiles(idx, c)
+                row_chunks.value = row_chunks.value + held_row_chunks(idx, c)
                 held_share.value = (pairs_on_held(idx, c).astype(jnp.float32)
                                     / (idx.shape[1] * idx.shape[2]))
             banded, causal = kv_block_visits(c, features.shape[1])

@@ -97,7 +97,8 @@ from elasticdl_tpu.ops import pallas_attention
 from elasticdl_tpu.ops.attention import full_attention
 # `_matmul`: the name `benchmark/rehearse/departures_glm4_moe_lite.py` patches here
 from model_zoo.transformer.nemotron_h import matmul as _matmul
-from model_zoo.transformer.nemotron_h import held_passes, held_row_tiles, updated_bias
+from model_zoo.transformer.nemotron_h import (
+    held_passes, held_row_chunks, held_row_tiles, updated_bias)
 from model_zoo.transformer.olmoe import (  # noqa: F401
     batch_partition, optimizer, rmsnorm, rope)
 from model_zoo.transformer.transformer_lm import TokenAccuracy, dataset_fn  # noqa: F401
@@ -374,11 +375,13 @@ class Glm4MoeLite(nn.Module):
                              jnp.zeros, (S, c.num_experts), jnp.float32)
         passes = self.variable("router_state", "held_passes", jnp.zeros, (S,), jnp.int32)
         row_tiles = self.variable("router_state", "held_row_tiles", jnp.zeros, (S,), jnp.int32)
+        row_chunks = self.variable("router_state", "held_row_chunks", jnp.zeros, (S,), jnp.int32)
         outputs, stats = forward(params, bias.value, features, c)
         if training and not self.is_initializing():
             bias.value = updated_bias(bias.value, stats["expert_idx"], c)
             passes.value = passes.value + held_passes(stats["expert_idx"], c)
             row_tiles.value = row_tiles.value + held_row_tiles(stats["expert_idx"], c)
+            row_chunks.value = row_chunks.value + held_row_chunks(stats["expert_idx"], c)
         return outputs
 
 

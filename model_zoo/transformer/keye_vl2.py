@@ -63,7 +63,8 @@ search and nothing of the index loss again and cannot select differently from
 the forward pass.
 
 Counters, in collections the trainer threads through every step:
-`router_state/held_passes`, `held_row_tiles`, `pairs_held_share` (as
+`router_state/held_passes`, `held_row_tiles`, `held_row_chunks`,
+`pairs_held_share` (as
 `mellum.py`'s) and, per layer, `dsa/selected_pairs` beside `dsa/causal_pairs`,
 `dsa/live_blocks` beside `dsa/causal_blocks` (of the last step) and
 `dsa/tie_rows` (summed over steps).
@@ -90,7 +91,7 @@ from elasticdl_tpu.ops import sparse_attention
 from elasticdl_tpu.ops.attention import full_attention
 from model_zoo.transformer.mellum import rotate
 from model_zoo.transformer.nemotron_h import (
-    matmul, pairs_on_held, held_passes, held_row_tiles)
+    matmul, pairs_on_held, held_passes, held_row_chunks, held_row_tiles)
 from model_zoo.transformer.olmoe import (  # noqa: F401
     batch_partition, eval_metrics_fn, optimizer, rmsnorm)
 from model_zoo.transformer.transformer_lm import dataset_fn  # noqa: F401
@@ -375,6 +376,7 @@ class Keye(nn.Module):
             group, name, jnp.zeros, (L,), dtype)
         passes = counter("router_state", "held_passes")
         row_tiles = counter("router_state", "held_row_tiles")
+        row_chunks = counter("router_state", "held_row_chunks")
         held_share = counter("router_state", "pairs_held_share", jnp.float32)
         tie_rows = counter("dsa", "tie_rows")
         last_step = {name: counter("dsa", name) for name in _LAST_STEP}
@@ -389,6 +391,7 @@ class Keye(nn.Module):
             idx, routing = stats["expert_idx"], c.routing
             passes.value = passes.value + held_passes(idx, routing)
             row_tiles.value = row_tiles.value + held_row_tiles(idx, routing)
+            row_chunks.value = row_chunks.value + held_row_chunks(idx, routing)
             held_share.value = (pairs_on_held(idx, routing).astype(jnp.float32)
                                 / (idx.shape[1] * idx.shape[2]))
             tie_rows.value = tie_rows.value + stats["tie_rows"]

@@ -62,7 +62,8 @@ PR 36; keeping the full layer's alone reads 15.93, the sliding layers' alone
 a fifth layer would have to give them up.
 
 Counters, in collections the trainer threads through every step:
-`router_state/held_passes`, `held_row_tiles` (as `glm4_moe_lite.py`'s),
+`router_state/held_passes`, `held_row_tiles`, `held_row_chunks` (as
+`glm4_moe_lite.py`'s),
 `router_state/pairs_held_share` (per layer, of the last step) and
 `attn/kv_block_visits` beside `attn/kv_block_visits_causal` (per kind
 [sliding, full], summed over steps: the (q block, kv block) pairs a head's
@@ -90,7 +91,7 @@ from elasticdl_tpu.ops import moe as moe_ops
 from elasticdl_tpu.ops import pallas_attention
 from elasticdl_tpu.ops.attention import full_attention
 from model_zoo.transformer.nemotron_h import (
-    matmul, pairs_on_held, held_passes, held_row_tiles)
+    matmul, pairs_on_held, held_passes, held_row_chunks, held_row_tiles)
 from model_zoo.transformer.olmoe import (  # noqa: F401
     batch_partition, eval_metrics_fn, optimizer, rmsnorm)
 from model_zoo.transformer.transformer_lm import dataset_fn  # noqa: F401
@@ -367,6 +368,7 @@ class Mellum(nn.Module):
             group, name, jnp.zeros, shape, dtype)
         passes = counter("router_state", "held_passes", (L,))
         row_tiles = counter("router_state", "held_row_tiles", (L,))
+        row_chunks = counter("router_state", "held_row_chunks", (L,))
         held_share = counter("router_state", "pairs_held_share", (L,), jnp.float32)
         visits = counter("attn", "kv_block_visits", (len(KINDS),))
         visits_causal = counter("attn", "kv_block_visits_causal", (len(KINDS),))
@@ -380,6 +382,7 @@ class Mellum(nn.Module):
             idx, routing = stats["expert_idx"], c.routing
             passes.value = passes.value + held_passes(idx, routing)
             row_tiles.value = row_tiles.value + held_row_tiles(idx, routing)
+            row_chunks.value = row_chunks.value + held_row_chunks(idx, routing)
             held_share.value = (pairs_on_held(idx, routing).astype(jnp.float32)
                                 / (idx.shape[1] * idx.shape[2]))
             banded, causal = kv_block_visits(c, features.shape[1])

@@ -25,8 +25,10 @@ anywhere except the convolution's (hidden C):
   pair routed to them (`ops.moe.dropless_moe`, `held`: one pass of
   `ops.moe.held_pass_rows` rows and a loop of more for what overflows it,
   through `ops.pallas_gmm`, which skips the row tiles past the last held pair;
-  `router_state/held_passes` counts the passes and
-  `router_state/held_row_tiles` the row tiles that held a pair);
+  `router_state/held_passes` counts the passes,
+  `router_state/held_row_tiles` the row tiles that held a pair and
+  `router_state/held_row_chunks` the row chunks the combine's pull-back
+  walked);
   what the other experts would add is left out; plus one shared expert of
   the same body on every token. b is no parameter: after each training step
   `b_e ← b_e + bias_update_speed · sign(mean load − load_e)` over this
@@ -328,6 +330,19 @@ def held_row_tiles(expert_idx, cfg: Config):
         cfg.num_experts, cfg.held[1])
 
 
+def held_row_chunks(expert_idx, cfg: Config):
+    """(E layers,) int32: the row chunks the held dispatch's combine walked
+    at this routing — its pull-back, and in a small pass its scatter-add
+    (`ops.moe.held_row_chunks`) — of `held_passes x held_pass_rows /
+    held_row_chunk`: the share of a pass's rows that part of the XLA around
+    the kernels still pays for. None where every expert is held."""
+    if cfg.held[1] == cfg.num_experts:
+        return jnp.zeros(expert_idx.shape[0], jnp.int32)
+    return moe_ops.held_row_chunks(
+        pairs_on_held(expert_idx, cfg), expert_idx.shape[1] * expert_idx.shape[2],
+        cfg.num_experts, cfg.held[1])
+
+
 # ------------------------------------------------------------------ #
 # The zoo contract
 
@@ -395,11 +410,13 @@ class NemotronH(nn.Module):
                              jnp.zeros, (E, c.num_experts), jnp.float32)
         passes = self.variable("router_state", "held_passes", jnp.zeros, (E,), jnp.int32)
         row_tiles = self.variable("router_state", "held_row_tiles", jnp.zeros, (E,), jnp.int32)
+        row_chunks = self.variable("router_state", "held_row_chunks", jnp.zeros, (E,), jnp.int32)
         logits, stats = forward(params, bias.value, features, c)
         if training and not self.is_initializing():
             bias.value = updated_bias(bias.value, stats["expert_idx"], c)
             passes.value = passes.value + held_passes(stats["expert_idx"], c)
             row_tiles.value = row_tiles.value + held_row_tiles(stats["expert_idx"], c)
+            row_chunks.value = row_chunks.value + held_row_chunks(stats["expert_idx"], c)
         return logits
 
 

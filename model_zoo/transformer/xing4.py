@@ -70,7 +70,7 @@ the flash kernels' five residuals.
 The Sinkhorn rounds are ONE `lax.scan` of `hc_sinkhorn_iters` steps (PERF.md
 §6, PR 48 has the set-up and step time of this form and of the unrolled one).
 
-Counters: `router_state/held_passes`, `held_row_tiles` (GLM's),
+Counters: `router_state/held_passes`, `held_row_tiles`, `held_row_chunks` (GLM's),
 `attn/kv_block_visits` (summed over steps: the (q block, kv block) pairs a
 head's forward computes in the layers); the outputs carry `mhc_stats` (B, 2):
 the largest |row or column sum - 1| of any H_res after its rounds, and the
@@ -99,7 +99,8 @@ from elasticdl_tpu.training import metrics as metrics_lib
 from model_zoo.transformer import glm4_moe_lite as glm
 from model_zoo.transformer.afmoe import LogitAccuracy
 from model_zoo.transformer.mellum import rotate, yarn_inv_freq
-from model_zoo.transformer.nemotron_h import held_passes, held_row_tiles, updated_bias
+from model_zoo.transformer.nemotron_h import (
+    held_passes, held_row_chunks, held_row_tiles, updated_bias)
 from model_zoo.transformer.olmoe import batch_partition, optimizer  # noqa: F401
 from model_zoo.transformer.transformer_lm import dataset_fn  # noqa: F401
 
@@ -445,12 +446,14 @@ class Xing4(nn.Module):
                              jnp.zeros, (S, c.num_experts), jnp.float32)
         passes = self.variable("router_state", "held_passes", jnp.zeros, (S,), jnp.int32)
         row_tiles = self.variable("router_state", "held_row_tiles", jnp.zeros, (S,), jnp.int32)
+        row_chunks = self.variable("router_state", "held_row_chunks", jnp.zeros, (S,), jnp.int32)
         visits = self.variable("attn", "kv_block_visits", jnp.zeros, (), jnp.int32)
         outputs, stats = forward(params, bias.value, features, c)
         if training and not self.is_initializing():
             bias.value = updated_bias(bias.value, stats["expert_idx"], c)
             passes.value = passes.value + held_passes(stats["expert_idx"], c)
             row_tiles.value = row_tiles.value + held_row_tiles(stats["expert_idx"], c)
+            row_chunks.value = row_chunks.value + held_row_chunks(stats["expert_idx"], c)
             visits.value = visits.value + kv_block_visits(c, features.shape[1])
         return outputs
 

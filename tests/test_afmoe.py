@@ -64,7 +64,7 @@ def router_state(bias):
     layers = bias.shape[0]
     zeros = jnp.zeros((layers,), jnp.int32)
     return {"router_state": {"expert_bias": bias, "held_passes": zeros,
-                             "held_row_tiles": zeros,
+                             "held_row_tiles": zeros, "held_row_chunks": zeros,
                              "pairs_held_share": jnp.zeros((layers,), jnp.float32)},
             "attn": {"kv_block_visits": jnp.zeros((2,), jnp.int32),
                      "kv_block_visits_causal": jnp.zeros((2,), jnp.int32)}}

@@ -57,7 +57,7 @@ def zoo():
 def router_state(bias):
     zeros = jnp.zeros((bias.shape[0],), jnp.int32)
     return {"router_state": {"e_score_correction_bias": bias, "held_passes": zeros,
-                             "held_row_tiles": zeros}}
+                             "held_row_tiles": zeros, "held_row_chunks": zeros}}
 
 
 @pytest.fixture(scope="module")

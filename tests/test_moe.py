@@ -12,6 +12,7 @@ import pytest
 
 from elasticdl_tpu.api.layers import MoE
 from elasticdl_tpu.ops import moe as moe_ops
+from elasticdl_tpu.ops import pallas_gmm
 from elasticdl_tpu.parallel.mesh import build_mesh
 
 E, C, H, N = 4, 8, 16, 32
@@ -317,7 +318,7 @@ def held_routings(n, k, num_experts, held):
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
-@pytest.mark.parametrize("pass_rows", [0, 40, 47, 48])
+@pytest.mark.parametrize("pass_rows", [0, 40, 47, 48, 96])
 @pytest.mark.parametrize("routing", ["even", "every_pair_on_a_held_expert",
                                      "no_pair_on_a_held_expert"])
 @pytest.mark.parametrize("body", ["relu2", "gated_silu"])
@@ -330,8 +331,15 @@ def test_held_dispatch_matches_loop_over_held_experts(body, routing, pass_rows, 
     row and run no overflow, and at 47 rows leave ONE live row to one
     overflow pass; with none on a held expert the first pass runs and adds
     nothing — the output and every gradient are exact zeros: nothing is
-    dropped."""
+    dropped. At a row tile of 16 a pass of 48, 96 or all 192 rows is walked
+    in chunks of 16 (40 and 47 are no whole tiles: one chunk): the even
+    routing's 48 pairs fill the pass of 48 to its last chunk and end exactly
+    on the third chunk's boundary in the passes of 96 (of six) and 192 (of
+    twelve); all 192 on held experts fill every chunk of every pass."""
     n, c, f, e, k, held = 64, 16, 8, 16, 3, (4, 4)
+    monkeypatch.setattr(pallas_gmm, "ROW_TILE", 16)
+    assert [moe_ops.held_row_chunk(rows) for rows in (40, 47, 48, 96, 192)] == [
+        40, 47, 16, 16, 16]
     r = np.random.default_rng(0)
     x = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
     up_like = ((held[1], c, f),) * (2 if body == "gated_silu" else 1)
@@ -370,15 +378,20 @@ def test_held_dispatch_matches_loop_over_held_experts(body, routing, pass_rows, 
         assert not any(np.any(np.asarray(g)) for g in got)
 
 
-@pytest.mark.parametrize("pass_rows", [8, 11, 21, 22, 64])
+@pytest.mark.parametrize("pass_rows", [8, 11, 21, 22, 33, 44, 64])
 def test_as_many_passes_as_the_held_pairs_fill(pass_rows, monkeypatch):
     """Random routing, passes smaller than the held pairs (8 and 11 rows:
     several passes, group boundaries inside a pass and across passes), one
     row short of them (21: the overflow's loop runs once, for one live row),
     exactly theirs (22: the first pass full, no overflow) and larger (64):
     values and every gradient are the loop's over held experts, under `jit`
-    as in a step."""
+    as in a step. At a row tile of 11 the passes of 22, 33 and 44 rows are
+    walked in chunks of 11: the 22 held pairs fill both chunks of the first,
+    and end exactly on the second chunk's boundary of three and of four."""
     monkeypatch.setattr(moe_ops, "held_pass_rows", lambda pairs, e, count: pass_rows)
+    monkeypatch.setattr(pallas_gmm, "ROW_TILE", 11)
+    assert [moe_ops.held_row_chunk(rows) for rows in (21, 22, 33, 44, 64)] == [
+        21, 11, 11, 11, 64]
     n, c, f, e, k, held = 32, 8, 4, 8, 2, (2, 2)
     r = np.random.default_rng(1)
     x = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
@@ -395,6 +408,84 @@ def test_as_many_passes_as_the_held_pairs_fill(pass_rows, monkeypatch):
     want = jax.value_and_grad(loop, argnums=(0, 1, 2, 3))(x, weights, *w)
     for g, h in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, h, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("rows, chunk", [
+    (65536, 4096), (32768, 2048), (8192, 512), (6144, 512), (4096, 256), (512, 256),
+    (12800, 1280), (8704, 4352), (192, 192), (40, 40)])
+def test_a_chunk_is_whole_row_tiles_and_at_most_sixteen_make_a_pass(rows, chunk):
+    """`held_row_chunk` from the shapes alone: a sixteenth of the pass at the
+    six cells' passes (65 536 rows to 4096; 6144 rows are 24 tiles: twelve
+    chunks of two), the most equal parts under sixteen that the pass's row
+    tiles divide into elsewhere (50 tiles: ten; 34: two), and the whole pass
+    where it is one tile or no whole number of tiles. `held_row_chunks` counts
+    the chunks that hold a held pair over the passes, as the pass walks them."""
+    tm = pallas_gmm.row_tile(rows)
+    assert moe_ops.held_row_chunk(rows) == chunk
+    assert rows % chunk == 0 and rows // chunk <= 16 and (chunk % tm == 0 or chunk == rows)
+    pairs, e, count = 4 * rows, 8, 1                # held_pass_rows: twice an eighth
+    if moe_ops.held_pass_rows(pairs, e, count) != rows:
+        return
+    for on_held, want in [(0, 0), (1, 1), (chunk, 1), (chunk + 1, 2), (rows, rows // chunk),
+                          (rows + 1, rows // chunk + 1), (pairs, 4 * (rows // chunk))]:
+        assert int(moe_ops.held_row_chunks(jnp.int32(on_held), pairs, e, count)) == want
+
+
+@pytest.mark.parametrize("scatter", ["in_chunks", "whole"])
+@pytest.mark.parametrize("live", [0, 1, 7, 8, 9, 32])
+@pytest.mark.parametrize("body", ["relu2", "gated_silu"])
+def test_a_pass_s_weighted_add_and_its_pull_back_walk_the_live_chunks_alone(
+        body, live, scatter, monkeypatch):
+    """`_add_pass_rows` behind a gather and an expert body, against the plain
+    `at[].add` it replaces: the value and the cotangents of the tokens, the
+    weights and every matrix, for a pass of 32 rows in chunks of 8 with no
+    live row, one, a chunk less one, a chunk, a chunk and one, and all. What a
+    chunk past the live ones holds is poison: its `ys` and weights NaN, its
+    tokens out of range (every other one in range, so that the NaN would
+    land) — a visit to a dead chunk, forward or in the pull-back, fails the
+    comparison. Chunks larger than `SCATTER_CHUNK_ROWS` take the scatter-add
+    in one call over all rows ("whole": the rows past the live ones then hold
+    what a pass gives them, zeros and tokens in range) and still pull back in
+    chunks."""
+    rows, chunk, n, c, f = 32, 8, 16, 8, 4
+    if scatter == "whole":
+        monkeypatch.setattr(moe_ops, "SCATTER_CHUNK_ROWS", 4)
+    r = np.random.default_rng(live)
+    xd = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    y0 = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    probe = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    tokens = jnp.asarray(r.integers(0, n, size=rows), jnp.int32)
+    weights = jnp.asarray(r.uniform(0.1, 1.0, size=rows), jnp.float32)
+    experts = tuple(jnp.asarray(r.normal(size=s) * 0.3, jnp.float32)
+                    for s in ((2, c, f),) * (2 if body == "gated_silu" else 1) + ((2, f, c),))
+    group_sizes = jnp.asarray([live // 3, live - live // 3], jnp.int32)
+    at = jnp.arange(rows)
+    dead = at >= -(-live // chunk) * chunk                    # the chunks nobody may visit
+    if scatter == "whole":
+        dead = dead & False
+    poisoned_tokens = jnp.where(dead & (at % 2 == 0), n + 5, tokens)
+
+    def plain(xd, weights, *experts):
+        ys = moe_ops._expert_body(xd[tokens], experts, group_sizes, jnp.float32)
+        w = jnp.where(at < live, weights, 0.0)
+        return y0.at[tokens].add(ys * w[:, None])
+
+    def chunked(xd, weights, *experts):
+        ys = moe_ops._expert_body(xd[tokens], experts, group_sizes, jnp.float32)
+        w = jnp.where(dead, jnp.nan, jnp.where(at < live, weights, 0.0))
+        return moe_ops._add_pass_rows(y0, jnp.where(dead[:, None], jnp.nan, ys), w,
+                                      poisoned_tokens, jnp.int32(live), chunk)
+
+    np.testing.assert_allclose(jax.jit(chunked)(xd, weights, *experts),
+                               plain(xd, weights, *experts), rtol=1e-5, atol=1e-6)
+    every = tuple(range(2 + len(experts)))
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(probe * chunked(*a)), argnums=every))(
+        xd, weights, *experts)
+    want = jax.grad(lambda *a: jnp.sum(probe * plain(*a)), argnums=every)(
+        xd, weights, *experts)
+    for g, h in zip(got, want):
+        np.testing.assert_allclose(g, h, rtol=1e-5, atol=1e-6)
+    assert bool(np.any(np.asarray(got[0]))) == (live > 0)
 
 
 def held_layer(body):
@@ -460,18 +551,25 @@ def hlo_reach(computations, name, through):
         for callee in re.findall(rf"\b{attribute}=%?([\w.\-]+)", computations[name]))
 
 
+@pytest.mark.parametrize("scatter_chunk_rows, walks_a_pass", [(256, 2), (8, 1)])
 @pytest.mark.parametrize("body", ["relu2", "gated_silu"])
 def test_the_first_pass_is_straight_line_code_and_the_accumulators_are_the_overflow_s(
-        body, monkeypatch):
+        body, scatter_chunk_rows, walks_a_pass, monkeypatch):
     """The lowered text of a held layer's value and gradient, with the
     kernels as a TPU takes them: every grouped matmul of a pass that runs
     whatever the routing — forward one a matrix, backward the recomputed one,
-    a dx and a dW a matrix — is reached from the entry by calls alone; the
-    only loop outside a `conditional` is the forward's overflow, whose carry
-    is the (N, C) sum; the backward's untaken branch hands its operands
+    a dx and a dW a matrix — is reached from the entry by calls alone; of the
+    loops outside a `conditional` one holds kernels, the forward's overflow,
+    whose carry is the (N, C) sum, and the others are the walks over a pass's
+    live chunks (the combine and its pull-back — the pull-back alone where a
+    chunk is larger than `SCATTER_CHUNK_ROWS` and the scatter-add is one
+    call), which hold no kernel and no float32 array of a matrix's or of a
+    pass's (rows, C) shape; the backward's untaken branch hands its operands
     through; and no float32 array of a matrix's shape exists outside the
     taken branch."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the kernel's route asks
+    monkeypatch.setattr(pallas_gmm, "ROW_TILE", 16)              # 192 rows: 12 chunks of 16
+    monkeypatch.setattr(moe_ops, "SCATTER_CHUNK_ROWS", scatter_chunk_rows)
     run, operands = held_layer(body)
     probe = jnp.ones(operands[0].shape, jnp.float32)
     text = jax.jit(jax.value_and_grad(
@@ -493,11 +591,19 @@ def test_the_first_pass_is_straight_line_code_and_the_accumulators_are_the_overf
     wide = [rf"f32\[{','.join(map(str, w.shape))}\]" for w in operands[2:]]
     assert all(re.search(shape, overflow) for shape in wide)
 
-    loops = re.findall(r"^.* while\(.*body=%?([\w.\-]+)", straight, re.M)
-    assert len(loops) == 1                       # the forward's overflow
-    outside = straight + hlo_reach(computations, loops[0], ("to_apply", "body", "condition"))
+    loops = [hlo_reach(computations, loop, ("to_apply", "body", "condition"))
+             for loop in re.findall(r"^.* while\(.*body=%?([\w.\-]+)", straight, re.M)]
+    with_kernels = [loop for loop in loops if kernels(loop)]
+    assert [kernels(loop) for loop in with_kernels] == [matrices]   # the forward's overflow
+    walks = [loop for loop in loops if not kernels(loop)]
+    assert len(walks) == walks_a_pass
+    pass_rows = rf"f32\[{3 * operands[0].shape[0]},{operands[0].shape[1]}\]"
+    assert not any(re.search(shape, walk) for walk in walks for shape in wide + [pass_rows])
+    outside = straight + "".join(loops)
     assert kernels(outside) == 5 * matrices
-    assert not any(re.search(shape, outside) for shape in wide)
+    # scatter-adds in one call make their float32 addends for all rows, forward
+    whole_addends = [pass_rows] if walks_a_pass == 2 else []
+    assert not any(re.search(shape, outside) for shape in wide + whole_addends)
 
 
 def test_held_all_is_the_plain_dispatch_and_a_wrong_share_is_refused():

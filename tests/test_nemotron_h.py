@@ -108,7 +108,8 @@ def test_several_passes_a_layer_give_the_same_step_and_are_counted(pass_rows, mo
     and parameters are those of one pass a layer, and `router_state/
     held_passes` holds ceil(pairs on held experts / pass) of every E layer,
     `router_state/held_row_tiles` the row tiles the grouped matmul's own
-    metadata counts for those passes."""
+    metadata counts for those passes, `router_state/held_row_chunks` the
+    chunks of those passes that hold a held pair."""
     from elasticdl_tpu.ops import moe as moe_ops
     from elasticdl_tpu.ops import pallas_gmm
 
@@ -120,7 +121,8 @@ def test_several_passes_a_layer_give_the_same_step_and_are_counted(pass_rows, mo
         counters = state.extra_vars[reference.PASSES[0]]
         return (float(m["loss"]), jax.device_get(state.params),
                 np.asarray(counters[reference.PASSES[1]]),
-                np.asarray(counters["held_row_tiles"]), spec.model.cfg)
+                np.asarray(counters["held_row_tiles"]),
+                np.asarray(counters["held_row_chunks"]), spec.model.cfg)
 
     def row_tiles(on_held, rows):
         """What the kernel's own metadata counts for each pass's rows."""
@@ -129,18 +131,26 @@ def test_several_passes_a_layer_give_the_same_step_and_are_counted(pass_rows, mo
             jnp.asarray([min(max(held - lo, 0), rows)], jnp.int32), rows, tm).row_tiles)
             for lo in range(0, idx[0].size, rows)) for held in on_held]
 
+    def row_chunks(on_held, rows):
+        chunk = moe_ops.held_row_chunk(rows)
+        return [sum(-(-min(max(held - lo, 0), rows) // chunk)
+                    for lo in range(0, idx[0].size, rows)) for held in on_held]
+
     idx = np.asarray(lm.assignments(warmup_steps=1)(
         lm.params(warmup_steps=1), jnp.zeros((2, 16)), data["features"])[0])
-    loss_one, params_one, passes_one, tiles_one, cfg = one_step(lm.trainer)
+    loss_one, params_one, passes_one, tiles_one, chunks_one, cfg = one_step(lm.trainer)
     on_held = np.sum((idx >= 4) & (idx < 8), axis=(1, 2))
     assert on_held.min() > pass_rows
     np.testing.assert_array_equal(passes_one, [1, 1])
     one_pass = moe_ops.held_pass_rows(idx[0].size, cfg.num_experts, cfg.held[1])
     np.testing.assert_array_equal(tiles_one, row_tiles(on_held, one_pass))
+    np.testing.assert_array_equal(chunks_one, row_chunks(on_held, one_pass))
     monkeypatch.setattr(moe_ops, "held_pass_rows", lambda pairs, e, count: pass_rows)
-    loss_many, params_many, passes_many, tiles_many, _ = one_step(lm.fresh_trainer)
+    loss_many, params_many, passes_many, tiles_many, chunks_many, _ = one_step(
+        lm.fresh_trainer)
     np.testing.assert_array_equal(passes_many, -(-on_held // pass_rows))
     np.testing.assert_array_equal(tiles_many, row_tiles(on_held, pass_rows))
+    np.testing.assert_array_equal(chunks_many, row_chunks(on_held, pass_rows))
     np.testing.assert_allclose(loss_many, loss_one, rtol=1e-6)
     for name in LEAVES:
         np.testing.assert_allclose(params_many[name], params_one[name], rtol=1e-4,
