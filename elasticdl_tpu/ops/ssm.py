@@ -43,14 +43,14 @@ from elasticdl_tpu.ops import pallas_ssd
 logger = logging.getLogger(__name__)
 
 
-def causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array) -> jax.Array:
+def causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array = None) -> jax.Array:
     """Depthwise causal convolution over time: x (B, T, Ch), weight (K, Ch),
-    bias (Ch) -> y_t = Σ_{j<K} weight_j · x_{t-K+1+j} + bias, zeros before the
-    sequence. float32."""
+    bias (Ch) or None -> y_t = Σ_{j<K} weight_j · x_{t-K+1+j} + bias, zeros
+    before the sequence. float32."""
     k, t = weight.shape[0], x.shape[1]
     x = x.astype(jnp.float32)
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    y = bias.astype(jnp.float32)
+    y = 0.0 if bias is None else bias.astype(jnp.float32)
     for j in range(k):
         y = y + padded[:, j:j + t] * weight[j].astype(jnp.float32)
     return y

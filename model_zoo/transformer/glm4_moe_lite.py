@@ -173,7 +173,9 @@ def latent_attention(p: Dict[str, jax.Array], x: jax.Array, cfg, rotate=None,
     """The attention sub-block's update of the residual stream x (B, T, C).
     `rotate`: the rotary map of q's rotary part (B, T, H, rot) and of the one
     rotary key (B, T, 1, rot), where it is not the plain table at
-    `cfg.rope_theta` (`xing4.py`: YaRN's); `q_scale`: a factor on the scores
+    `cfg.rope_theta` (`xing4.py`: YaRN's; `kimi_linear.py`: the identity, no
+    positions); p without `q_a` has the query as ONE projection `q_proj`
+    (`q_lora_rank` null: no low rank, no query norm); `q_scale`: a factor on the scores
     beside (nope + rope)^-1/2, applied to q in float32 before it is rounded
     for the kernel. v may be narrower than q and k (`v_head_dim`)."""
     dt = jnp.dtype(cfg.compute_dtype)
@@ -182,10 +184,14 @@ def latent_attention(p: Dict[str, jax.Array], x: jax.Array, cfg, rotate=None,
     if rotate is None:
         rotate = lambda part: rope(part, cfg.rope_theta)
     h = rmsnorm(x, p["attn_norm"], cfg.rms_norm_eps)
-    with jax.named_scope("q_lora"):
-        c_q = rmsnorm(_matmul(h, p["q_a"], dt, jnp.float32), p["q_a_norm"],
-                      cfg.rms_norm_eps)
-        q = _matmul(c_q, p["q_b"], dt, jnp.float32).reshape(b, t, heads, nope + rot)
+    if "q_a" in p:
+        with jax.named_scope("q_lora"):
+            c_q = rmsnorm(_matmul(h, p["q_a"], dt, jnp.float32), p["q_a_norm"],
+                          cfg.rms_norm_eps)
+            q = _matmul(c_q, p["q_b"], dt, jnp.float32).reshape(b, t, heads, nope + rot)
+    else:       # `q_lora_rank` null: one projection, no query norm
+        with jax.named_scope("q_proj"):
+            q = _matmul(h, p["q_proj"], dt, jnp.float32).reshape(b, t, heads, nope + rot)
     with jax.named_scope("kv_lora"):
         kv_a = _matmul(h, p["kv_a"], dt, jnp.float32)
         c_kv = rmsnorm(kv_a[..., :cfg.kv_lora_rank], p["kv_a_norm"], cfg.rms_norm_eps)

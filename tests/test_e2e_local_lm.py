@@ -1,5 +1,6 @@
 """End-to-end, the language models: the control plane is model-agnostic, so
-the toy transformer, the zoo's sparse-expert models and its looped dense one at tiny sizes run
+the toy transformer, the zoo's sparse-expert models, its looped dense one and its
+delta-rule hybrid at tiny sizes run
 the SAME in-process master + real worker subprocesses over gRPC that
 `tests/test_e2e_local.py` runs MNIST through. A file of its own so that two
 xdist workers share the job tests.
@@ -277,3 +278,63 @@ def test_local_ouro_job_end_to_end(tmp_path):
     assert restored.params["wq"].shape == (2, 48, 64)
     assert restored.params["exit_gate_w"].shape == (48,)
     assert int(restored.extra_vars["loop"]["layer_applications"]) == 6 * int(restored.step)
+
+
+def test_local_kimi_linear_job_end_to_end(tmp_path):
+    """Kimi Linear's layers (a delta-rule mixer with a decay a channel in
+    layer 1, latent attention without positions in layer 2; a dense
+    feed-forward then a held share of sigmoid-routed experts with a selection
+    bias and a shared expert) through the same master/worker path, evaluation
+    — the mixer's own figures among its metrics — included, and a checkpoint
+    saved by the worker and restored here."""
+    import jax
+    import numpy as np
+
+    from elasticdl_tpu.parallel.mesh import build_mesh
+    from elasticdl_tpu.training.checkpoint import CheckpointManager
+    from elasticdl_tpu.training.model_spec import ModelSpec
+    from elasticdl_tpu.training.trainer import Trainer
+
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.kimi_linear.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 48, "num_hidden_layers": 2,
+            "kda_layers": "1", "full_attn_layers": "2", "intermediate_size": 96,
+            "linear_num_heads": 4, "linear_head_dim": 16, "num_attention_heads": 4,
+            "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+            "v_head_dim": 16, "num_experts": 4, "router_experts": 16, "first_expert": 4,
+            "num_experts_per_token": 3, "moe_intermediate_size": 24, "kda_chunk": 16,
+            "kda_chunks_per_block": 2, "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+        checkpoint_dir=str(tmp_path / "ckpt"),
+        checkpoint_steps=16,
+    )
+    # a worker reaped while it imports beside five other xdist workers is
+    # ROADMAP C21's, not this case's
+    master, _, counts = run_job(cfg, tmp_path, master_of=patient_master)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    results = master.evaluation.latest_results()
+    assert 0.0 <= results["token_accuracy"] <= 1.0
+    assert results["kda_log_decay_min"] < 0 < results["kda_state_rms"]
+    assert abs(results["kda_beta_mean"] - 0.5) < 0.1
+    assert master.servicer.mean_training_loss() < 6.0       # ln 256 = 5.5, no auxiliary term
+
+    trainer = Trainer(ModelSpec.from_config(cfg), build_mesh(devices=jax.devices()[:1]))
+    example = {"features": np.zeros((4, 32), np.int32), "labels": np.zeros((4, 32), np.int32),
+               "mask": np.ones((4,), np.float32)}
+    checkpoints = CheckpointManager(str(tmp_path / "ckpt"))
+    restored = checkpoints.restore(trainer.abstract_train_state(example))
+    checkpoints.close()
+    assert int(restored.step) == checkpoints.last_restored_step >= 16
+    assert restored.params["kda_wq"].shape == (1, 48, 64)
+    assert restored.params["q_proj"].shape == (1, 48, 96)
+    assert restored.extra_vars["router_state"]["e_score_correction_bias"].shape == (1, 16)
+    # 1 KDA layer x 4 sequences x 4 heads x 2 chunks of 16, every step
+    assert int(restored.extra_vars["kda"]["chunks"]) == 4 * 4 * 2 * int(restored.step)

@@ -275,3 +275,46 @@ def test_ouro_s_loop_keeps_every_application_s_residuals(
     assert set(found.values()) >= {
         "ouro/embed", "ouro/pass/attn", "ouro/pass/mlp", "ouro/pass/norm",
         "ouro/pass/final_norm", "ouro/exit", "ouro/exit_loss"}
+
+
+def test_kimi_linear_s_two_kinds_of_layer_compile_under_their_scopes(
+        one_chip, no_compile_cache, monkeypatch):
+    """kimi-linear-48b-a3b.resident-16k at its published widths and 16 384
+    tokens, a KDA layer over the dense feed-forward and a latent layer over a
+    sparse one, through the zoo's own loss: the chunked delta rule (chunks of
+    64, blocks of 4: XLA's batched matmuls under two loops) compiles for the
+    chip beside ONE flash forward and ONE backward at heads of 192 | 128, both
+    under `kimi_linear/mla/attn`; every scope the benchmark reads the mixer by
+    is in the compiled text, forward and backward."""
+    from benchmark import common
+    from model_zoo.transformer import kimi_linear
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+    net = kimi_linear.custom_model(
+        num_hidden_layers=2, kda_layers="1", full_attn_layers="2", num_experts=8,
+        router_experts=256, vocab_size=512)
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    variables = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
+
+    def loss(params, state, tokens):
+        outputs = net.apply({"params": params, **state}, tokens)
+        return jnp.sum(kimi_linear.loss(tokens, outputs)["loss"])
+
+    params = variables.pop("params")
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
+    calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
+    kinds = sorted(re.sub(r"\.\d+$", "", name) for name in calls)
+    assert kinds == ["flash_attention_bwd", "flash_attention_fwd"]
+    scope_map = common.load_module("drivers", "resident_lm_share").scope_map
+    flops = common.load_module("flops", "kimi_linear")
+    found = scope_map(text, flops.SCOPES, flops.RAGGED_DOT_SCOPE)
+    assert {found.get(name) for name in calls} == {"kimi_linear/mla/attn"}
+    assert set(found.values()) >= {
+        f"kimi_linear/{part}" for part in (
+            "embed", "kda/proj", "kda/conv", "kda/gates", "kda/qk_norm", "kda/delta_rule",
+            "kda/out_gate", "kda/out", "mla/q_proj", "mla/kv_lora", "mla/attn", "mla/out",
+            "dense_mlp", "moe/router", "moe/experts", "moe/shared", "head_loss")}
+    # the recurrence's two loops, forward and backward, carry the scope
+    assert re.search(r"kda/delta_rule/[^\"]*while", text)
