@@ -52,13 +52,23 @@ outlives its block. Both kept arrays carry `checkpoint_name`s
 recomputed under `jax.checkpoint` with a policy that saves them runs the
 forward sweep once.
 
-There is ONE route, XLA's batched matmuls under two `lax.scan`s
-(`delta_rule_route` says so in the log, in `ops/ssm.py::scan_route`'s manner);
-no Pallas kernel reads these shapes yet.
+There are TWO routes (`delta_rule_route`, which says in the log which one a
+traced program took, in `ops/ssm.py::scan_route`'s manner). "kernel": the two
+Pallas kernels of `ops/pallas_delta_rule.py` — a visit a (sequence, head,
+block), the state in VMEM across a head's blocks, the operands read where they
+lie, nothing of a chunk's size in HBM — where they can run (a TPU, or
+interpret mode in the CPU tests) and the shapes fit (d_k = d_v whole lanes, a
+chunk of whole sub-blocks, a visit's blocks inside VMEM). "plain": this file's
+body, XLA's batched matmuls under two `lax.scan`s, everywhere else — the CPU's
+route and the kernels' yardstick. Both take Γ from `cumulative_log_decay`,
+move the state through `next_state` (looked up in this module when a program
+is traced: the benchmark's rehearsal patches them by name), keep the same two
+named arrays and give the same values within rounding.
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 import math
 from functools import partial
@@ -66,6 +76,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from elasticdl_tpu.ops import pallas_delta_rule
 
 logger = logging.getLogger(__name__)
 
@@ -140,8 +152,9 @@ def cumulative_log_decay(g):
 
 def next_state(through, state, added):
     """`Diag(exp Γ_L) S + (K ⊙ exp(Γ_L − Γ))ᵀ u`: the state a chunk leaves,
-    float32."""
-    return through[..., None] * state + added
+    float32. `through` is exp Γ_L laid against the state's key axis (the
+    kernels hold the state transposed)."""
+    return through * state + added
 
 
 def _mm(spec, a, b, dt):
@@ -208,7 +221,7 @@ def _block(state, q, k, v, g, beta, dt, chunk):
     def one_chunk(s, terms):
         w_n, u_n, to_end_n, through_n = terms
         new = u_n - _mm("bhrc,bhcv->bhrv", w_n, s, dt)              # u of the chunk
-        return next_state(through_n, s, _mm("bhrc,bhrv->bhcv", to_end_n, new, dt)), (s, new)
+        return next_state(through_n[..., None], s, _mm("bhrc,bhrv->bhcv", to_end_n, new, dt)), (s, new)
 
     chunks_first = lambda a: jnp.moveaxis(a, 2, 0)
     state, (starts, new) = jax.lax.scan(
@@ -265,15 +278,31 @@ def _blocks_bwd(dt, chunk, kept, cts):
 _blocks.defvjp(_blocks_fwd, _blocks_bwd)
 
 
-def delta_rule_route(shape, chunk: int, chunks_per_block: int) -> str:
-    """Which body the rule takes at q's shape (B, T, H, d): "xla", the only
-    one there is. Logged once a traced program."""
-    _, t, h, d = shape
+@functools.lru_cache(maxsize=None)
+def _log_route(*said):
+    """Once a process for each shape and route: a step's program traces the
+    rule a layer, a recomputation and a counter at a time."""
     logger.info(
-        "gated delta rule (%d tokens, %d heads of %d, a decay a channel) takes the "
-        "xla route: chunks of %d in sub-blocks of %d, blocks of %d chunks whose "
-        "start states are kept", t, h, d, chunk, min(SUB, chunk), chunks_per_block)
-    return "xla"
+        "gated delta rule (%d tokens, %d heads of %d | %d, a decay a channel) takes the "
+        "%s route: chunks of %d in sub-blocks of %d, blocks of %d chunks whose start "
+        "states are kept (the Pallas kernels need a TPU or interpret mode: %s; d_k = "
+        "d_v whole lanes, a chunk of whole sub-blocks, a visit's blocks inside VMEM: %s)",
+        *said)
+
+
+def delta_rule_route(shape, chunk: int, chunks_per_block: int, v_dim: int = None) -> str:
+    """Which body the rule takes at q's shape (B, T, H, d_k) and values of
+    `v_dim` channels (d_k if not given) — "kernel" or "plain": a pure function
+    of the shapes and of whether the kernels can run here (a TPU, or interpret
+    mode in the CPU tests). Logged once for each answer."""
+    _, t, h, d = shape
+    v_dim = v_dim or d
+    runnable = pallas_delta_rule.runnable()
+    fits = pallas_delta_rule.blocks(d, v_dim, chunk, min(chunks_per_block, -(-t // chunk)))
+    route = "kernel" if runnable and fits else "plain"
+    _log_route(t, h, d, v_dim, route, chunk, min(SUB, chunk), chunks_per_block, runnable,
+               f"{fits.vmem_bytes} bytes" if fits else "no")
+    return route
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, chunks_per_block: int = 8,
@@ -286,7 +315,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, chunks_per_block: int = 
     operands and the initial state."""
     if chunk > SUB and chunk % SUB:
         raise ValueError(f"a chunk of {chunk} is not whole sub-blocks of {SUB}")
-    delta_rule_route(q.shape, chunk, chunks_per_block)
+    if delta_rule_route(q.shape, chunk, chunks_per_block, v.shape[-1]) == "kernel":
+        return pallas_delta_rule.delta_rule_kernels(
+            q, k, v, g, beta, chunk, chunks_per_block, compute_dtype, initial_state)
     f32 = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
     b, t, h, dk = k.shape

@@ -84,7 +84,9 @@ recomputes one block's chunk algebra at a time.
 
 Counters (`TrainState.extra_vars`): `router_state/held_passes`,
 `held_row_tiles`, `held_row_chunks` (GLM's); `kda/chunks` (the chunks walked,
-summed over steps, layers, batch and heads), and of the last step, a KDA
+summed over steps, layers, batch and heads), `kda/kernel_chunks` (those of
+them walked by the Pallas kernels: all of them on `delta_rule_route`'s
+"kernel" route, 0 on the plain one), and of the last step, a KDA
 layer each: `kda/log_decay_min` (the most negative in-chunk Γ: how near the
 decay runs to underflow), `kda/beta_mean`, `kda/state_rms` (the state after the
 last token). The outputs carry `kda_stats` (B, 3) — those three, the worst or
@@ -374,6 +376,14 @@ def chunks_walked(cfg: Config, batch: int, seq_len: int) -> int:
     return cfg.layers_of("kda") * batch * cfg.linear_num_heads * -(-seq_len // cfg.kda_chunk)
 
 
+def kernel_chunks_walked(cfg: Config, batch: int, seq_len: int) -> int:
+    """Those of them the Pallas kernels walk: all where the recurrence takes
+    its kernel route at this shape, none where it takes the plain one."""
+    shape = (batch, seq_len, cfg.linear_num_heads, cfg.linear_head_dim)
+    route = delta_rule.delta_rule_route(shape, cfg.kda_chunk, cfg.kda_chunks_per_block)
+    return chunks_walked(cfg, batch, seq_len) if route == "kernel" else 0
+
+
 # ------------------------------------------------------------------ #
 # The zoo contract
 
@@ -442,6 +452,7 @@ class KimiLinear(nn.Module):
         row_tiles = counter("router_state", "held_row_tiles", (S,))
         row_chunks = counter("router_state", "held_row_chunks", (S,))
         chunks = counter("kda", "chunks", ())
+        kernel_chunks = counter("kda", "kernel_chunks", ())
         last_step = {name: counter("kda", name, (K,), jnp.float32)
                      for name in ("log_decay_min", "beta_mean", "state_rms")}
         outputs, stats, kda_stats = forward(params, bias.value, features, c)
@@ -452,6 +463,7 @@ class KimiLinear(nn.Module):
             row_tiles.value = row_tiles.value + held_row_tiles(idx, c)
             row_chunks.value = row_chunks.value + held_row_chunks(idx, c)
             chunks.value = chunks.value + chunks_walked(c, *features.shape)
+            kernel_chunks.value = kernel_chunks.value + kernel_chunks_walked(c, *features.shape)
             last_step["log_decay_min"].value = jnp.min(kda_stats[..., 0], axis=1)
             last_step["beta_mean"].value = jnp.mean(kda_stats[..., 1], axis=1)
             last_step["state_rms"].value = jnp.mean(kda_stats[..., 2], axis=1)

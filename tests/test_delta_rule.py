@@ -4,7 +4,8 @@ five gradients over chunk lengths, block lengths and decays from mild to so
 strong that a quotient form `(K ⊙ exp Γ)(K ⊘ exp Γ)ᵀ` would overflow; the
 rule's two limits (β = 0: pure decay; g = 0 with orthonormal keys: a pure
 delta rule); and, with one decay a head and the erase term off, `ops/ssm.py`'s
-recurrence on the same operands.
+recurrence on the same operands. The same values and gradients on the KERNEL
+route (`ops/pallas_delta_rule.py` in interpret mode) at lane-wide heads.
 """
 
 import jax
@@ -13,22 +14,23 @@ import numpy as np
 import pytest
 
 from elasticdl_tpu.ops import delta_rule as dr
-from elasticdl_tpu.ops import ssm
+from elasticdl_tpu.ops import pallas_attention, ssm
+from tests.conftest import pallas_calls
 
 B, T, H, D = 2, 70, 3, 8
 OPERANDS = ("q", "k", "v", "g", "beta")
 
 
-def operands(strength=1.0, seed=0, t=T, dv=D):
+def operands(strength=1.0, seed=0, t=T, dv=None, heads=H, d=D):
     """Unit keys and queries, write strengths in (0, 1), log-decays
     −strength · softplus(normal): at 8 a step decays by up to e^-30."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
-    return (unit(jax.random.normal(keys[0], (B, t, H, D))),
-            unit(jax.random.normal(keys[1], (B, t, H, D))),
-            jax.random.normal(keys[2], (B, t, H, dv)),
-            -strength * jax.nn.softplus(jax.random.normal(keys[3], (B, t, H, D))),
-            jax.nn.sigmoid(jax.random.normal(keys[4], (B, t, H))))
+    return (unit(jax.random.normal(keys[0], (B, t, heads, d))),
+            unit(jax.random.normal(keys[1], (B, t, heads, d))),
+            jax.random.normal(keys[2], (B, t, heads, dv or d)),
+            -strength * jax.nn.softplus(jax.random.normal(keys[3], (B, t, heads, d))),
+            jax.nn.sigmoid(jax.random.normal(keys[4], (B, t, heads))))
 
 
 def chunked(chunk, chunks_per_block):
@@ -99,6 +101,101 @@ def test_gradient_matches_the_recurrence(recurrent, chunk, per_block, operand):
     scale = float(jnp.max(jnp.abs(want)))
     assert scale > 0
     np.testing.assert_allclose(got, want, atol=5e-5 * scale)
+
+
+# ------------------------------------------------------------------ #
+# both routes at lane-wide heads: d = 128, two heads, T = 70 a multiple of
+# neither the chunk nor the block
+
+WIDE = 128
+
+
+def wide_operands(strength):
+    return operands(strength, seed=4, heads=2, d=WIDE)
+
+
+@pytest.fixture
+def take_route(monkeypatch):
+    """`take_route("kernel")` runs the Pallas kernels in interpret mode;
+    "plain" is what the CPU takes anyway."""
+    def take(route):
+        if route == "kernel":
+            monkeypatch.setenv(pallas_attention._INTERPRET_ENV, "1")
+        assert dr.delta_rule_route((B, T, 2, WIDE), 16, 2) == route
+    return take
+
+
+@pytest.fixture(scope="module", params=STRENGTHS)
+def wide_recurrent(request):
+    args = wide_operands(request.param)
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    with jax.default_matmul_precision("highest"):
+        values = jax.jit(dr.delta_rule_recurrent)(*args)
+        grads = jax.jit(jax.grad(weighted(dr.delta_rule_recurrent, weight),
+                                 argnums=range(5)))(*args)
+    return args, weight, values, grads
+
+
+@pytest.mark.parametrize("route", ["plain", "kernel"])
+def test_values_match_the_recurrence_at_lane_wide_heads(wide_recurrent, take_route, route):
+    take_route(route)
+    args, _, (want, want_last), _ = wide_recurrent
+    with jax.default_matmul_precision("highest"):
+        got, last = jax.jit(lambda *a: chunked(16, 2)(*a))(*args)
+    assert got.shape == want.shape and last.shape == want_last.shape
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_allclose(last, want_last, atol=2e-5)
+
+
+_WIDE_GRADIENTS = {}
+
+
+@pytest.mark.parametrize("route", ["plain", "kernel"])
+@pytest.mark.parametrize("operand", range(5), ids=OPERANDS)
+def test_gradient_matches_the_recurrence_at_lane_wide_heads(
+        wide_recurrent, take_route, route, operand):
+    take_route(route)
+    args, weight, _, want = wide_recurrent
+    key = (float(args[3][0, 0, 0, 0]), route)
+    if key not in _WIDE_GRADIENTS:
+        with jax.default_matmul_precision("highest"):
+            _WIDE_GRADIENTS[key] = jax.jit(jax.grad(
+                weighted(lambda *a: chunked(16, 2)(*a), weight), argnums=range(5)))(*args)
+    got = _WIDE_GRADIENTS[key][operand]
+    assert np.all(np.isfinite(got))
+    scale = float(jnp.max(jnp.abs(want[operand])))
+    assert scale > 0
+    np.testing.assert_allclose(got, want[operand], atol=5e-5 * scale)
+
+
+def test_the_kernel_route_takes_a_given_state_and_returns_the_last(take_route):
+    """From a state that is not zero, both results and the state's own
+    gradient as the plain route's."""
+    args = wide_operands(1.0)
+    state = jax.random.normal(jax.random.PRNGKey(3), (B, 2, WIDE, WIDE))
+    rule = lambda state: (lambda o, last: jnp.sum(o) + jnp.sum(last * last))(
+        *dr.gated_delta_rule(*args, chunk=16, chunks_per_block=2, compute_dtype=jnp.float32,
+                             initial_state=state))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.value_and_grad(lambda s: rule(s)))(state)
+        take_route("kernel")
+        got = jax.jit(jax.value_and_grad(lambda s: rule(s)))(state)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    np.testing.assert_allclose(got[1], want[1], atol=1e-4 * float(jnp.max(jnp.abs(want[1]))))
+
+
+def test_the_route_follows_the_head_width_and_what_can_run(monkeypatch):
+    """Narrow heads are the plain body's whatever can run; lane-wide ones the
+    kernels' where they can (interpret mode here), and the plain body's on
+    the bare CPU, at a chunk that is not whole sub-blocks and at d_k ≠ d_v."""
+    assert dr.delta_rule_route((B, T, H, 128), 64, 4) == "plain"
+    monkeypatch.setenv(pallas_attention._INTERPRET_ENV, "1")
+    assert dr.delta_rule_route((B, T, H, 8), 64, 4) == "plain"
+    assert dr.delta_rule_route((B, T, H, 128), 64, 4) == "kernel"
+    assert dr.delta_rule_route((B, T, H, 256), 16, 2) == "kernel"
+    assert dr.delta_rule_route((B, T, H, 128), 6, 4) == "plain"
+    assert dr.delta_rule_route((B, T, H, 128), 64, 4, v_dim=256) == "plain"
 
 
 def test_the_overflow_case_is_one_a_quotient_form_fails():
@@ -195,3 +292,17 @@ def test_a_recomputed_caller_keeps_the_named_residuals():
     kept = jax.make_jaxpr(jax.grad(jax.checkpoint(rule, policy=dr.KEEP_RESIDUALS)))(*args)
     scans = lambda jaxpr: str(jaxpr).count(" scan[")
     assert scans(kept) < scans(plain)
+
+
+def test_a_recomputed_caller_of_the_kernel_route_runs_the_forward_kernel_once(take_route):
+    """The kernel route names the same two arrays: recomputed without a
+    policy the gradient holds the forward kernel twice, under
+    `KEEP_RESIDUALS` once, beside the one backward kernel."""
+    take_route("kernel")
+    args = wide_operands(1.0)
+    def calls(policy):
+        jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(
+            lambda *a: chunked(16, 2)(*a)[0].sum(), policy=policy)))(*args)
+        return [pallas_calls(jaxpr, name) for name in ("delta_rule_fwd", "delta_rule_bwd")]
+    assert calls(None) == [2, 1]
+    assert calls(dr.KEEP_RESIDUALS) == [1, 1]

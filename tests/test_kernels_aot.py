@@ -6,7 +6,8 @@ backward kernel with a key-value head's keys resident in VMEM, and at a head
 that does not fit, the two that stream it (`ops/pallas_attention.py`: the
 block plan follows the head size, `bwd_route` the head's bytes), and the
 state-space scan's three kernels at the Nemotron cell's shapes
-(`ops/pallas_ssd.py`) — the same kernels inside the zoo's checkpointed layers,
+(`ops/pallas_ssd.py`) and the delta rule's two at the Kimi cell's
+(`ops/pallas_delta_rule.py`) — the same kernels inside the zoo's checkpointed layers,
 under the scopes the benchmark reads them by, are
 `tests/test_kernels_aot_layers.py`'s; and
 the pull-back of the Keye cell's index scores (`ops/sparse_attention.py::
@@ -26,7 +27,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from elasticdl_tpu.ops import pallas_gmm, pallas_ssd
+from elasticdl_tpu.ops import pallas_delta_rule, pallas_gmm, pallas_ssd
 
 # (rows of a pass or of all pairs, K, N, groups): the held experts of
 # nemotron-3-nano-30b-a3b.resident-8k, up and down; olmoe-1b-7b.resident-4k's
@@ -296,3 +297,30 @@ def test_scan_kernels_compile_for_a_v5e(one_chip, no_compile_cache):
     for kernel in ("ssd_chunk_fwd", "ssd_chunk_starts", "ssd_chunk_bwd"):
         assert text.count("%" + kernel) >= 1, kernel
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+# kimi-linear-48b-a3b.resident-16k's recurrence: one sequence of 16 384
+# tokens, 32 heads of 128 key and value channels, chunks of 64 in blocks of 4
+DELTA_RULE = dict(tokens=16384, heads=32, head_dim=128, chunk=64, chunks_per_block=4)
+
+
+def test_delta_rule_kernels_compile_for_a_v5e(one_chip, no_compile_cache):
+    """Float32 in, bfloat16 operands: the forward and the backward, a visit a
+    (head, block of 4 chunks), inside the VMEM the rule finds room for."""
+    t, h, d, l, n = DELTA_RULE.values()
+    plan = pallas_delta_rule.blocks(d, d, l, n)
+    assert plan is not None and plan.vmem_bytes <= pallas_delta_rule._vmem_bytes() // 2
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def forward_and_backward(q, k, v, g, beta, d_o, d_last):
+        (o, last), vjp = jax.vjp(
+            lambda *a: pallas_delta_rule.delta_rule_kernels(*a, l, n, jnp.bfloat16),
+            q, k, v, g, beta)
+        return o, last, vjp((d_o, d_last))
+
+    plane = shape(1, t, h, d)
+    text = jax.jit(forward_and_backward).lower(
+        plane, plane, plane, plane, shape(1, t, h), plane, shape(1, h, d, d)).compile().as_text()
+    for kernel in ("delta_rule_fwd", "delta_rule_bwd"):
+        assert text.count("%" + kernel) >= 1, kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 2

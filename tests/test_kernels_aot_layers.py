@@ -282,10 +282,13 @@ def test_kimi_linear_s_two_kinds_of_layer_compile_under_their_scopes(
     """kimi-linear-48b-a3b.resident-16k at its published widths and 16 384
     tokens, a KDA layer over the dense feed-forward and a latent layer over a
     sparse one, through the zoo's own loss: the chunked delta rule (chunks of
-    64, blocks of 4: XLA's batched matmuls under two loops) compiles for the
-    chip beside ONE flash forward and ONE backward at heads of 192 | 128, both
-    under `kimi_linear/mla/attn`; every scope the benchmark reads the mixer by
-    is in the compiled text, forward and backward."""
+    64, blocks of 4) compiles for the chip as ONE `delta_rule_fwd` — the
+    recomputed layer keeps its two named arrays — and ONE `delta_rule_bwd`,
+    both under `kimi_linear/kda/delta_rule` (or `kda_delta_rule_roofline`
+    divides a fixed floor by a scope that lost its kernels), beside ONE flash
+    forward and ONE backward at heads of 192 | 128, both under
+    `kimi_linear/mla/attn`; every scope the benchmark reads the mixer by is in
+    the compiled text, forward and backward."""
     from benchmark import common
     from model_zoo.transformer import kimi_linear
 
@@ -316,5 +319,9 @@ def test_kimi_linear_s_two_kinds_of_layer_compile_under_their_scopes(
             "embed", "kda/proj", "kda/conv", "kda/gates", "kda/qk_norm", "kda/delta_rule",
             "kda/out_gate", "kda/out", "mla/q_proj", "mla/kv_lora", "mla/attn", "mla/out",
             "dense_mlp", "moe/router", "moe/experts", "moe/shared", "head_loss")}
-    # the recurrence's two loops, forward and backward, carry the scope
-    assert re.search(r"kda/delta_rule/[^\"]*while", text)
+    # the recurrence's two kernels carry the scope, and no loop is left of it
+    rule = re.findall(r"^\s*%?(delta_rule_[\w.]+) = ", text, re.M)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name in rule) == [
+        "delta_rule_bwd", "delta_rule_fwd"]
+    assert {found.get(name) for name in rule} == {"kimi_linear/kda/delta_rule"}
+    assert not re.search(r"kda/delta_rule/[^\"]*while", text)

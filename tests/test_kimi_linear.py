@@ -126,6 +126,32 @@ def random_mixer(seed=8, c=48, heads=4, d=16, width=4):
     return p, jnp.asarray(r.normal(size=(2, 37, c)), jnp.float32)
 
 
+@pytest.mark.parametrize("route", ["plain", "kernel"])
+def test_the_counters_say_which_route_walked_the_chunks(monkeypatch, route):
+    """`kda/kernel_chunks` beside `kda/chunks` at lane-wide linear heads (2 of
+    128): none on the CPU's plain route, all of them where the Pallas kernels
+    run (interpret mode here), and the step's loss the same by both."""
+    from elasticdl_tpu.ops import pallas_attention
+    if route == "kernel":
+        # the signal alone: a kernel under `jax.checkpoint` cannot run inside
+        # the TPU interpreter's context
+        monkeypatch.setenv(pallas_attention._INTERPRET_ENV, "1")
+    wide = {"linear_num_heads": 2, "linear_head_dim": 128}
+    spec, trainer = lm.fresh_trainer(warmup_steps=1, **wide, **lm.short)
+    data = lm.batches(steps=1)[0]
+    state, logs = trainer.train_step(trainer.init_state(data), data)
+    kda = state.extra_vars["kda"]
+    # 1 KDA layer x 2 sequences x 2 heads x ceil(40 / 16) chunks
+    assert int(kda["chunks"]) == 2 * 2 * 3
+    assert int(kda["kernel_chunks"]) == (2 * 2 * 3 if route == "kernel" else 0)
+    _LOSS_BY_ROUTE[route] = float(logs["loss"])
+    if len(_LOSS_BY_ROUTE) == 2:
+        assert _LOSS_BY_ROUTE["kernel"] == pytest.approx(_LOSS_BY_ROUTE["plain"], rel=1e-5)
+
+
+_LOSS_BY_ROUTE = {}
+
+
 def test_the_mixer_alone_matches_the_reference_s_token_by_token():
     m, cfg = zoo(), cfg_of()
     p, x = random_mixer()
