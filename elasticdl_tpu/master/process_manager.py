@@ -51,7 +51,8 @@ _COHORT_SIZE = _reg.gauge(
 # listed: four processes x one chip on the four-chip v5e host (a 4-process
 # jax.distributed world summed over its devices, PR 21; no training job has
 # run on it yet). Two processes x two chips is NOT here: libtpu refused both
-# bounds tried ("Chip 0x1x0 not on Host ...") — ROADMAP A3 has the facts.
+# bounds tried ("Chip 0x1x0 not on Host ...") — several worker processes on
+# one host are ROADMAP B5's cell.
 _TPU_PROCESS_BOUNDS = {
     (4, 4): ("1,1,1", "2,2,1"),
 }
@@ -502,7 +503,13 @@ class ProcessManager:
         if self._cohort_mode:
             self._watch_cohort_loop(poll_s)
             return
-        while not self._stop.is_set():
+        while True:
+            # read BEFORE the scan: the pass that follows stop() is the
+            # last one, and books what exited since the pass before it —
+            # "worker N exited cleanly" is said whenever it was true
+            # (the launcher's own 0.2 s poll of all_exited() used to race
+            # this loop's 0.5 s for the line)
+            stopping = self._stop.is_set()
             with self._lock:
                 items = list(self._procs.items())
             for wid, wp in items:
@@ -511,14 +518,17 @@ class ProcessManager:
                     PodStatus.SUCCEEDED, PodStatus.FAILED, PodStatus.DELETED,
                 ):
                     continue
-                if code == 0:
-                    wp.status = PodStatus.SUCCEEDED
-                    logger.info("worker %d exited cleanly", wid)
-                    continue
-                if self._job_finished_fn():
+                finished = self._job_finished_fn()
+                if finished or stopping:
                     # teardown-phase exits are not failures to recover from
-                    wp.status = PodStatus.SUCCEEDED
-                    logger.info("worker %d exited (code %s) after job end", wid, code)
+                    if code == 0:
+                        wp.status = PodStatus.SUCCEEDED
+                        logger.info("worker %d exited cleanly", wid)
+                    elif finished:
+                        wp.status = PodStatus.SUCCEEDED
+                        logger.info(
+                            "worker %d exited (code %s) after job end",
+                            wid, code)
                     continue
                 if wp.evicted:
                     # policy eviction completing: the worker drained
@@ -537,7 +547,12 @@ class ProcessManager:
                         "retired", wid, code,
                     )
                     continue
-                # failure/preemption path
+                # failure/preemption path. An exit 0 BEFORE the job's end is
+                # one too: a worker leaves cleanly only when the master told
+                # it the job was done, so this one was told to go while
+                # tasks remain (the master wrote it off after a heartbeat
+                # lapse) — booked SUCCEEDED, nothing would relaunch it and
+                # the job would wait for nobody
                 if self._membership is not None:
                     self._membership.mark_dead(wid, reason=f"exit code {code}")
                 if wp.relaunches < self.cfg.relaunch_max:
@@ -559,16 +574,21 @@ class ProcessManager:
                         "worker %d died (code %s); relaunch budget exhausted",
                         wid, code,
                     )
+            if stopping:
+                return
             self._stop.wait(poll_s)
 
     def _teardown_cohort(self, items, reason: str) -> None:
         """Kill every member and reap; recover the leader's leased tasks via
         membership so the new generation re-leases at the task boundary."""
-        if self._membership is not None:
-            self._membership.mark_dead(0, reason=reason)
         for _, wp in items:
             if wp.proc.poll() is None:
                 wp.proc.kill()
+        # after the kill: a leader written off while it can still beat
+        # would re-register (the servicer asks an unknown worker to) and
+        # hold id 0 against the next generation's leader
+        if self._membership is not None:
+            self._membership.mark_dead(0, reason=reason)
         for _, wp in items:
             try:
                 wp.proc.wait(timeout=30)
@@ -743,7 +763,10 @@ class ProcessManager:
         by one. Tune `relaunch_max` down when hosts are more likely to vanish
         than to crash transiently.
         """
-        while not self._stop.is_set():
+        while True:
+            # one last pass after stop(), as in _watch_loop: it books a
+            # cohort that exited since the pass before and starts nothing
+            stopping = self._stop.is_set()
             with self._lock:
                 items = list(self._procs.items())
                 pending = self._pending_resize
@@ -752,6 +775,17 @@ class ProcessManager:
             failed = [
                 pid for pid, c in codes.items() if c is not None and c != 0
             ]
+            exited = bool(codes) and all(c is not None for c in codes.values())
+            finished = self._job_finished_fn()
+            if exited and not failed and not finished and not stopping:
+                # every member left with 0 BEFORE the job's end: nobody is
+                # left to finish it, so this is a death like any other
+                # (see _watch_loop) and the cohort is re-formed
+                failed = sorted(codes)
+            if stopping:
+                if exited and (finished or not failed):
+                    self._book_cohort_exit(codes)
+                return
             if not failed:
                 with self._lock:
                     # the retried generation has stayed up: the incident is
@@ -767,7 +801,7 @@ class ProcessManager:
                         logger.info(
                             "world formation recovered; infra retry budget reset"
                         )
-            if failed and not self._job_finished_fn():
+            if failed and not finished:
                 members = dict(items)
                 lost = [pid for pid in failed if members[pid].no_relaunch]
                 infra = all(
@@ -846,7 +880,7 @@ class ProcessManager:
             elif (
                 pending is not None
                 and pending != size_now   # snapshot: _cohort_size is locked
-                and not self._job_finished_fn()
+                and not finished
             ):
                 # planned resize of a HEALTHY cohort: quiesce first — ask for
                 # a checkpoint and wait for it, so only sub-task progress is
@@ -885,13 +919,16 @@ class ProcessManager:
                             items, reason=f"cohort resize to {pending}"
                         )
                     self._reform_cohort(pending, old, "operator resize request")
-            elif all(c is not None for c in codes.values()) and codes:
-                with self._lock:
-                    for wp in self._procs.values():
-                        wp.status = PodStatus.SUCCEEDED
-                logger.info("cohort exited, codes %s", sorted(codes.values()))
+            elif exited:
+                self._book_cohort_exit(codes)
                 return
             self._stop.wait(poll_s)
+
+    def _book_cohort_exit(self, codes: Dict[int, int]) -> None:
+        with self._lock:
+            for wp in self._procs.values():
+                wp.status = PodStatus.SUCCEEDED
+        logger.info("cohort exited, codes %s", sorted(codes.values()))
 
     def request_flight_dump(
         self, worker_id: int, process_index: Optional[int] = None

@@ -272,6 +272,25 @@ class MasterServicer:
             request.worker_id, request.model_version, stats=stats,
             members=members or None,
         )
+        if not known and not self._shutdown and context is not None:
+            # a LIVE worker the membership wrote off (its heartbeats lapsed
+            # under a long compile or a network blip and the reaper took
+            # it; its leases are requeued already). Told to shut down it
+            # would exit 0 with tasks left and nobody to run them: it gets
+            # the fence's rejection instead, which its session answers by
+            # re-registering (Membership.reregister revives it) — the
+            # handshake a master restart uses, proto/service.py
+            # is_stale_generation. A process the manager killed never
+            # heartbeats again, so nothing dead is revived by this.
+            logger.warning(
+                "Heartbeat from worker %d, which is not a live member: "
+                "asking it to re-register", request.worker_id,
+            )
+            context.abort(
+                grpc.StatusCode.FAILED_PRECONDITION,
+                f"worker {request.worker_id} is not a live member of this "
+                "master generation; re-register to continue",
+            )
         with self._ctrl_lock:
             # one atomic test-and-clear: the flag is one-shot, and two
             # concurrent heartbeats from a relaunching worker must not both

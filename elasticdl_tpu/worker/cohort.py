@@ -50,12 +50,7 @@ from elasticdl_tpu.data.reader import create_data_reader
 from elasticdl_tpu.observability import flight as flight_lib
 from elasticdl_tpu.observability import goodput as goodput_lib
 from elasticdl_tpu.observability import profile as profile_lib
-from elasticdl_tpu.observability import reqtrace as reqtrace_lib
-from elasticdl_tpu.observability.health import (
-    STATS_METADATA_KEY,
-    WorkerStepStats,
-    encode_stats,
-)
+from elasticdl_tpu.observability.health import WorkerStepStats, encode_stats
 from elasticdl_tpu.parallel.elastic import (
     CohortContext,
     context_from_env,
@@ -63,15 +58,9 @@ from elasticdl_tpu.parallel.elastic import (
     make_global_batch_stack,
 )
 from elasticdl_tpu.proto import elasticdl_tpu_pb2 as pb
-from elasticdl_tpu.proto.service import (
-    RetryingMasterStub,
-    is_stale_generation,
-    jittered,
-    make_channel,
-    register_with_retry,
-    reregister,
-)
+from elasticdl_tpu.proto.service import jittered
 from elasticdl_tpu.training.model_spec import ModelSpec
+from elasticdl_tpu.worker.session import MasterSession, job_checkpoint_manager
 from elasticdl_tpu.worker.task_data_service import TaskDataService
 
 logger = default_logger(__name__)
@@ -99,7 +88,6 @@ class CohortWorker:
     def __init__(self, cfg: JobConfig, ctx: Optional[CohortContext] = None):
         self.cfg = cfg
         self.ctx = ctx or context_from_env(cfg)
-        self._stub: Optional[RetryingMasterStub] = None
         self._trainer = None
         self._state = None
         self._spec: Optional[ModelSpec] = None
@@ -109,11 +97,16 @@ class CohortWorker:
         self._ckpt_manager = None
         self._last_ckpt_step = 0
         self._shutdown = threading.Event()
-        self._job_done = False
-        self._ckpt_requested = False  # heartbeat should_checkpoint bit
+        # leader only (followers never talk to the master): the channel,
+        # the stub, the cohort's worker id and the liveness protocol. A
+        # lost master flips the shutdown that turns the next control
+        # vector into OP_ABORT, taking the WHOLE cohort down EX_TEMPFAIL.
+        self._session = MasterSession(
+            cfg, self._shutdown, what="cohort leader",
+            when_lost="aborting cohort (EX_TEMPFAIL)",
+            on_reregistered=self._on_reregistered,
+        )
         self._preempt = False         # leader: SIGTERM drain requested
-        self._last_master_ok = time.monotonic()  # leader: last successful RPC
-        self._master_lost = False
         # Plain-int mirror of state.model_version for the heartbeat thread:
         # int(state.step) blocks on the in-flight donated computation (see
         # worker.py's identically-named field), which would stall heartbeats
@@ -126,8 +119,6 @@ class CohortWorker:
         # compiler's example input) + the background compiler itself
         self._example_host_batch = None
         self._spec_compiler = None
-        self.worker_id = -1
-        self._name = ""               # set at leader registration
         # cohort-aggregated membership: master-assigned ids for this
         # cohort's member processes 1..N-1 (leader only; empty for
         # single-process worlds). Their beats ride the leader's single
@@ -223,12 +214,8 @@ class CohortWorker:
         raise KeyError(f"unknown shard {name!r}")
 
     def _checkpoint_manager(self):
-        if self._ckpt_manager is None and self.cfg.checkpoint_dir:
-            from elasticdl_tpu.training.checkpoint import CheckpointManager
-
-            self._ckpt_manager = CheckpointManager(
-                self.cfg.checkpoint_dir, keep=self.cfg.keep_checkpoint_max
-            )
+        if self._ckpt_manager is None:
+            self._ckpt_manager = job_checkpoint_manager(self.cfg)
         return self._ckpt_manager
 
     def _ensure_state(self, example_batch) -> None:
@@ -264,40 +251,25 @@ class CohortWorker:
     # ------------------------------------------------------------------ #
     # leader-only: master RPCs
 
+    @property
+    def worker_id(self) -> int:
+        return self._session.worker_id
+
     def _connect(self) -> None:
-        import os
         import socket
 
-        self._channel = make_channel(self.cfg.master_addr)
-        # Hardened stub (deadlines, idempotent retries, circuit breaker);
-        # every successful RPC refreshes the master-unreachable clock. The
-        # channel_factory bounds master-restart recovery: repeated wire
-        # failures rebuild the channel rather than trusting a stuck one.
-        self._stub = RetryingMasterStub(
-            self._channel, on_success=self._note_master_ok,
-            channel_factory=lambda: make_channel(self.cfg.master_addr),
-        )
-        # Boot registration rides out a master that is down or restarting
-        # (proto/service.py's register_with_retry, shared with worker.py):
-        # the leader is always worker 0, so retries carry the REREGISTER
-        # marker and a successor master treats them as an idempotent
-        # reconnect of the journaled member, not a ghost second join.
-        # registered once, reused by every reconnect handshake: a renamed
-        # re-register would silently overwrite the membership entry's name
-        self._name = f"cohort-{socket.gethostname()}:{os.getpid()}"
-        resp = register_with_retry(
-            self._stub,
-            name=self._name,
-            preferred_id=0,
-            window_s=self.cfg.master_unreachable_timeout_s,
-            shutdown=self._shutdown,
-            what="cohort leader",
+        name = f"cohort-{socket.gethostname()}:{os.getpid()}"
+        # the leader is always worker 0, so boot-registration retries carry
+        # the REREGISTER marker and a successor master treats them as an
+        # idempotent reconnect of the journaled member, not a ghost second
+        # join
+        resp = self._session.connect(
+            name, 0,
             # cohort-aggregated membership: member processes join in the
             # SAME round-trip as telemetry entities — the master's fleet
             # view is per-process while reap/version stay per-cohort
-            member_names=self._member_names(),
+            member_names=self._member_names(name),
         )
-        self.worker_id = resp.worker_id
         self._member_ids = list(resp.member_ids)
         logger.info(
             "cohort leader registered as worker %d (%d processes, %d devices"
@@ -306,16 +278,14 @@ class CohortWorker:
             len(__import__("jax").devices()), len(self._member_ids),
         )
 
-    def _member_names(self) -> List[str]:
+    def _member_names(self, leader_name: str) -> List[str]:
         """Stable per-process member identities (processes 1..N-1; the
-        leader itself IS the cohort's logical worker entry). Stable across
-        reconnects so a restarted master's register_members is idempotent."""
+        leader itself IS the cohort's logical worker entry). The session
+        re-sends them with every reconnect handshake, so a restarted
+        master's register_members is idempotent."""
         return [
-            f"{self._name}#p{i}" for i in range(1, self.ctx.num_processes)
+            f"{leader_name}#p{i}" for i in range(1, self.ctx.num_processes)
         ]
-
-    def _note_master_ok(self) -> None:
-        self._last_master_ok = time.monotonic()
 
     def _init_embedding_tier(self) -> None:
         """Leader-only tier membership (cfg.embedding_shards > 0): the
@@ -330,7 +300,7 @@ class CohortWorker:
             from elasticdl_tpu.embedding.tier import WorkerTierRuntime
 
             self._tier = WorkerTierRuntime(
-                self._stub, self.worker_id,
+                self._session.stub, self.worker_id,
                 checkpoint_dir=self.cfg.checkpoint_dir,
                 cache_rows=self.cfg.embedding_cache_rows,
                 cache_staleness=self.cfg.embedding_cache_staleness,
@@ -358,91 +328,27 @@ class CohortWorker:
         except Exception:
             logger.exception("embedding tier drain failed")
 
-    def _reregister(self) -> None:
-        """Leader-only reconnect handshake after a master restart (shared
-        with worker.py — proto/service.py's reregister). The cohort itself
-        keeps running throughout — only the leader's control-plane session
-        is re-established; followers never notice."""
-        resp = reregister(
-            self._stub, name=self._name, worker_id=self.worker_id,
-            member_names=self._member_names(),
-        )
-        # the restarted master's replay requeued every lease whole — drop
-        # the local queue; fresh leases re-run the tasks exactly once
+    def _on_reregistered(self, resp) -> None:
+        """A reconnect handshake landed (worker/session.py). The cohort
+        itself keeps running throughout — only the leader's control-plane
+        session is re-established; followers never notice."""
+        # the master's replay requeued every lease whole — drop the local
+        # queue; fresh leases re-run the tasks exactly once
         self._lease_queue.clear()
-        self.worker_id = resp.worker_id
         self._member_ids = list(resp.member_ids)
         logger.warning(
             "cohort leader re-registered with restarted master as worker %d; "
             "resuming leases under the new generation", self.worker_id,
         )
 
-    def _maybe_reconnect(self, e: BaseException) -> bool:
-        """True when `e` was the stale-generation fence and the reconnect
-        handshake ran — the caller retries instead of aborting the cohort."""
-        if self.worker_id < 0 or not is_stale_generation(e):
-            return False
-        try:
-            self._reregister()
-            return True
-        except Exception as handshake_err:
-            logger.warning(
-                "cohort re-register after master restart failed: %s",
-                handshake_err,
-            )
-            self._master_unreachable()
-            return False
-
-    def _master_unreachable(self) -> bool:
-        """Leader-only, from RPC-failure paths: True (and flips the
-        shutdown that turns the next control vector into OP_ABORT, taking
-        the WHOLE cohort down EX_TEMPFAIL) when no master RPC has succeeded
-        for master_unreachable_timeout_s. Without this a cohort whose
-        master's process tree died keeps spinning on a dead address forever
-        — observed as orphan worker processes surviving for hours."""
-        limit = self.cfg.master_unreachable_timeout_s
-        if limit <= 0 or time.monotonic() - self._last_master_ok < limit:
-            return False
-        if not self._master_lost:
-            self._master_lost = True
-            logger.error(
-                "no successful master RPC for %.0fs (limit %.0fs): master "
-                "presumed gone, aborting cohort (EX_TEMPFAIL)",
-                time.monotonic() - self._last_master_ok, limit,
-            )
-            self._shutdown.set()
-        return True
-
     def _stats_payload(self):
         """Leader heartbeat telemetry (the cohort's collective cadence as
-        seen from the leader's dispatch clock)."""
-        from elasticdl_tpu.observability import tracing
-
+        seen from the leader's dispatch clock; follower profiles ride
+        their MemberBeats via the exchange)."""
         stats = self._step_stats.snapshot()
         stats.update(
-            phase=self._phase,
-            breaker_open=int(bool(self._stub and self._stub.breaker.is_open)),
-            num_processes=self.ctx.num_processes,
-            world_version=tracing.get_tracer().world_version,
-        )
-        # per-step phase breakdown + memory watermarks (the leader's own;
-        # follower profiles ride their MemberBeats via the exchange)
-        stats.update(profile_lib.get_profiler().snapshot())
-        # goodput ledger ride-along (ISSUE 12): the leader's own
-        # wall-clock attribution (followers' ledgers stay process-local;
-        # their training phases ride the member-stats exchange)
-        stats.update(goodput_lib.get_ledger().payload())
-        # request-diary ride-along (ISSUE 19): the leader's own tail
-        # attribution (rt_* keys) + degraded/shm-fallback shares
-        stats.update(reqtrace_lib.get_recorder().payload())
-        # embedding-tier skew ride-along (ISSUE 11; see worker.py's
-        # _stats_payload) — best-effort, never costs the heartbeat
-        if self._tier is not None:
-            try:
-                stats.update(self._tier.client.tier_stats())
-            except Exception:
-                # edl-lint: disable=EDL303
-                pass
+            phase=self._phase, num_processes=self.ctx.num_processes)
+        stats.update(self._session.stats_ride_alongs(self._tier))
         return stats
 
     def _member_beats(self) -> List[pb.MemberBeat]:
@@ -551,54 +457,17 @@ class CohortWorker:
             fresh[idx] = self._decode_exchange_row(rows[idx])
         self._member_stats = fresh   # atomic swap; heartbeat thread reads
 
-    def _heartbeat_loop(self) -> None:
-        from elasticdl_tpu.observability import timeseries as timeseries_lib
+    def _heartbeat_fields(self) -> Dict[str, Any]:
+        try:
+            return {"members": self._member_beats()}
+        except Exception:
+            return {}               # member telemetry never costs the beat
 
-        while not self._shutdown.is_set():
-            # interval-gated time-series sample (normally a clock read)
-            timeseries_lib.get_store().maybe_sample()
-            try:
-                # optional telemetry metadata; a payload failure degrades
-                # this beat to liveness-only (same contract as worker.py)
-                try:
-                    md = ((STATS_METADATA_KEY,
-                           encode_stats(self._stats_payload())),)
-                except Exception:
-                    md = None
-                try:
-                    members = self._member_beats()
-                except Exception:
-                    members = []    # member telemetry never costs the beat
-                resp = self._stub.Heartbeat(
-                    pb.HeartbeatRequest(
-                        worker_id=self.worker_id,
-                        model_version=self._model_version,
-                        members=members,
-                    ),
-                    timeout=10,
-                    metadata=md,
-                )
-                if resp.shutdown:
-                    if resp.job_done:
-                        self._job_done = True
-                    self._shutdown.set()
-                    break
-                if resp.should_checkpoint:
-                    # honored by the next control vector's FLAG_CHECKPOINT —
-                    # the save itself is collective and happens at the task
-                    # boundary on every process
-                    self._ckpt_requested = True
-                if resp.learning_rate > 0:
-                    # rides the next control vector (lr_bits) so every
-                    # process applies it at the same task boundary
-                    self._pushed_lr = resp.learning_rate
-            except Exception as e:
-                logger.warning("cohort heartbeat failed: %s", e)
-                if not self._maybe_reconnect(e):
-                    self._master_unreachable()
-            # jittered beat (shared helper): cohorts relaunched together
-            # must not arrive at the master in phase every interval
-            self._shutdown.wait(jittered(self.cfg.worker_heartbeat_s))
+    def _on_heartbeat_response(self, resp) -> None:
+        if resp.learning_rate > 0:
+            # rides the next control vector (lr_bits) so every
+            # process applies it at the same task boundary
+            self._pushed_lr = resp.learning_rate
 
     def request_preempt(self) -> bool:
         """Leader SIGTERM hook (signal-handler safe: sets a flag, no I/O).
@@ -624,8 +493,8 @@ class CohortWorker:
             ctrl[6] = FLAG_CHECKPOINT
             return ctrl
         if self._shutdown.is_set():
-            ctrl = [OP_DONE if self._job_done else OP_ABORT] + [0] * (CTRL_LEN - 1)
-            if self._master_lost:
+            ctrl = [OP_DONE if self._session.job_done else OP_ABORT] + [0] * (CTRL_LEN - 1)
+            if self._session.master_lost:
                 # the heartbeat thread crossed the unreachable limit while a
                 # task was running: same final-collective-save semantics as
                 # the GetTask-path abort below (the save needs no master)
@@ -637,7 +506,7 @@ class CohortWorker:
         else:
             try:
                 with profile_lib.get_profiler().span("lease"):
-                    resp = self._stub.GetTask(
+                    resp = self._session.stub.GetTask(
                         pb.GetTaskRequest(
                             worker_id=self.worker_id,
                             max_tasks=self.cfg.task_lease_batch,
@@ -646,12 +515,12 @@ class CohortWorker:
                     )
             except Exception as e:
                 logger.warning("cohort get_task failed: %s", e)
-                if self._maybe_reconnect(e):
+                if self._session.maybe_reconnect(e):
                     # master restarted; handshake landed — the cohort stays
                     # up and the next control vector re-leases under the
                     # new generation
                     return [OP_NOOP] + [0] * (CTRL_LEN - 1)
-                if self._master_unreachable():
+                if self._session.master_unreachable():
                     # carry FLAG_CHECKPOINT: we sit at a clean task boundary
                     # and the collective save needs no master, so a
                     # partitioned-but-relaunched cohort resumes here instead
@@ -662,7 +531,7 @@ class CohortWorker:
                     return ctrl
                 return [OP_NOOP] + [0] * (CTRL_LEN - 1)
             if resp.job_done:
-                self._job_done = True
+                self._session.job_done = True
                 return [OP_DONE] + [0] * (CTRL_LEN - 1)
             # old master: `tasks` empty, fall back to the singular field
             leased = list(resp.tasks) or [resp.task]
@@ -676,11 +545,11 @@ class CohortWorker:
             and self._state.model_version - self._last_ckpt_step
             >= self.cfg.checkpoint_steps
         )
-        if self._ckpt_requested:
+        if self._session.checkpoint_requested:
             # clear only when consumed: an unconditional clear could drop a
             # request the heartbeat thread set between read and clear, and
             # the servicer's should_checkpoint bit is one-shot
-            self._ckpt_requested = False
+            self._session.checkpoint_requested = False
             due = True
         return [
             OP_TASK, task.task_id, task.type,
@@ -859,7 +728,7 @@ class CohortWorker:
                 ok, err = False, "no live state and no checkpoint on disk"
             if self.ctx.is_leader:
                 try:
-                    self._stub.ReportTaskResult(
+                    self._session.stub.ReportTaskResult(
                         pb.ReportTaskResultRequest(
                             worker_id=self.worker_id, task_id=task_id,
                             success=ok, err_message=err,
@@ -874,7 +743,7 @@ class CohortWorker:
                     logger.warning(
                         "cohort report failed for save task %d: %s", task_id, e
                     )
-                    self._maybe_reconnect(e)
+                    self._session.maybe_reconnect(e)
             return
         svc = self._data_service(task_type)
         shard = self._shard_name(task_type, shard_idx)
@@ -1118,7 +987,7 @@ class CohortWorker:
         )
         try:
             with prof.span("report"):
-                self._stub.ReportTaskResult(report, timeout=30)
+                self._session.stub.ReportTaskResult(report, timeout=30)
                 if task_type == pb.EVALUATION and metric_states is not None:
                     msg = pb.ReportEvaluationMetricsRequest(
                         worker_id=self.worker_id, eval_job_id=eval_job,
@@ -1129,12 +998,12 @@ class CohortWorker:
                         msg.states.append(
                             pb.MetricState(name=name, data=arr.tobytes())
                         )
-                    self._stub.ReportEvaluationMetrics(msg, timeout=30)
+                    self._session.stub.ReportEvaluationMetrics(msg, timeout=30)
         except Exception as e:
             logger.warning("cohort report failed for task %d: %s", task_id, e)
             # fenced = the restarted master requeued this lease; re-register
             # so the next lease lands, never resend the pre-crash report
-            self._maybe_reconnect(e)
+            self._session.maybe_reconnect(e)
 
     def _export_final_model(self) -> None:
         if not self.cfg.output or self._state is None:
@@ -1235,9 +1104,12 @@ class CohortWorker:
                 with tracing.span("cohort.register", trace_id=reform_tid):
                     self._connect()
                 self._init_embedding_tier()
-                threading.Thread(
-                    target=self._heartbeat_loop, daemon=True
-                ).start()
+                self._session.start_heartbeats(
+                    model_version=lambda: self._model_version,
+                    stats_payload=self._stats_payload,
+                    on_response=self._on_heartbeat_response,
+                    request_fields=self._heartbeat_fields,
+                )
             backoff = max(0.5, self.cfg.worker_heartbeat_s / 4)
             prof = profile_lib.get_profiler()
             # one iteration is one task turn (lease, broadcast, the task and
@@ -1305,13 +1177,7 @@ class CohortWorker:
                             "prediction outputs processor close failed")
                 self._shutdown.set()
                 if self.ctx.is_leader:
-                    try:
-                        self._channel.close()
-                    except Exception:
-                        # teardown-only; still worth a trace for post-mortems
-                        logger.debug(
-                            "grpc channel close failed at exit", exc_info=True
-                        )
+                    self._session.close()
 
             # the tier's shards drain on EVERY teardown path (the next
             # leader generation restores them bit-exactly, watermarks

@@ -27,26 +27,15 @@ from elasticdl_tpu.common.log_utils import default_logger
 from elasticdl_tpu.data.reader import create_data_reader
 from elasticdl_tpu.observability import flight as flight_lib
 from elasticdl_tpu.observability import goodput as goodput_lib
-from elasticdl_tpu.observability import reqtrace as reqtrace_lib
 from elasticdl_tpu.observability import profile as profile_lib
 from elasticdl_tpu.observability import timeseries as timeseries_lib
 from elasticdl_tpu.observability import tracing
-from elasticdl_tpu.observability.health import (
-    STATS_METADATA_KEY,
-    WorkerStepStats,
-    encode_stats,
-)
+from elasticdl_tpu.observability.health import WorkerStepStats
 from elasticdl_tpu.observability.registry import default_registry
 from elasticdl_tpu.proto import elasticdl_tpu_pb2 as pb
-from elasticdl_tpu.proto.service import (
-    RetryingMasterStub,
-    is_stale_generation,
-    jittered,
-    make_channel,
-    register_with_retry,
-    reregister,
-)
+from elasticdl_tpu.proto.service import is_stale_generation, jittered
 from elasticdl_tpu.training.model_spec import ModelSpec
+from elasticdl_tpu.worker.session import MasterSession, job_checkpoint_manager
 from elasticdl_tpu.worker.task_data_service import TaskDataService
 
 logger = default_logger(__name__)
@@ -78,16 +67,18 @@ class Worker:
         self._state = None
         self._spec: Optional[ModelSpec] = None
         self._services: Dict[int, TaskDataService] = {}
-        self._stub: Optional[RetryingMasterStub] = None
-        self.worker_id = -1
         self._membership_version = -1
         self._shutdown = threading.Event()
-        self._heartbeat_thread: Optional[threading.Thread] = None
+        # the channel, the stub, our id and the liveness protocol
+        self._session = MasterSession(
+            cfg, self._shutdown, what="worker",
+            when_lost="exiting EX_TEMPFAIL",
+            on_reregistered=self._on_reregistered,
+        )
         self._parse_fns: Dict[str, Any] = {}
         self._ckpt_manager = None
         self._last_ckpt_step = 0
         self._preempted = False
-        self._job_done = False
         self._mid_training_task = False
         self._base_lr = None          # injected LR at init (elastic scaling)
         self._pending_lr = None       # set by heartbeat thread, applied by run loop
@@ -102,9 +93,6 @@ class Worker:
         # silently stall heartbeats until the master declares us dead.
         self._model_version = 0
         self._profile_state = "idle"  # idle -> active -> done (jax.profiler)
-        self._ckpt_requested = False  # heartbeat should_checkpoint bit
-        self._last_master_ok = time.monotonic()  # last successful master RPC
-        self._master_lost = False     # unreachable past the config timeout
         # In-place rescale (rescale fast path): a pending (axis_sizes,
         # devices) target applied at the next batch/task boundary — live
         # state handoff + executable-cache reuse, no teardown/restore.
@@ -131,30 +119,21 @@ class Worker:
     # ------------------------------------------------------------------ #
     # setup
 
+    @property
+    def worker_id(self) -> int:
+        return self._session.worker_id
+
     def _connect(self) -> None:
-        addr = self.cfg.master_addr
-        self._channel = make_channel(addr)
-        # Hardened stub: per-call deadlines, idempotent-only retries with
-        # backoff, circuit breaker. Every successful RPC (on any thread)
-        # refreshes the master-unreachable clock through on_success. The
-        # channel_factory makes master-restart recovery bounded: repeated
-        # transport failures rebuild the channel instead of trusting a
-        # subchannel that got stuck when the old master's listener vanished.
-        self._stub = RetryingMasterStub(
-            self._channel, on_success=self._note_master_ok,
-            channel_factory=lambda: make_channel(addr),
-        )
-        # registered once, reused by every reconnect handshake: a renamed
-        # re-register would silently overwrite the membership entry's name
-        self._name = f"{socket.gethostname()}:{os.getpid()}"
         # gRPC embedding data plane (ISSUE 15): the endpoint comes up
         # BEFORE registration so its address can ride the RegisterWorker
         # request into the owner address book; the store binds later,
         # when the tier runtime builds it (_init_embedding_tier)
         self._start_data_plane()
-        preferred = int(os.environ.get(WorkerEnv.WORKER_ID, -1))
-        resp = self._boot_register(self._name, preferred)
-        self.worker_id = resp.worker_id
+        resp = self._session.connect(
+            f"{socket.gethostname()}:{os.getpid()}",
+            int(os.environ.get(WorkerEnv.WORKER_ID, -1)),
+            data_addr=self._data_addr,
+        )
         self._membership_version = resp.membership_version
         self._last_known_workers = resp.num_workers
         # role known now: trace spans + JSON logs carry it; a reform trace
@@ -215,58 +194,14 @@ class Worker:
         srv = getattr(self, "_data_server", None)
         return srv.address or "" if srv is not None else ""
 
-    def _boot_register(self, name: str, preferred: int):
-        """Boot-time registration that rides out a master that is down or
-        restarting (see proto/service.py's register_with_retry — shared
-        with the cohort leader so the handshake cannot diverge)."""
-        return register_with_retry(
-            self._stub,
-            name=name,
-            preferred_id=preferred,
-            window_s=self.cfg.master_unreachable_timeout_s,
-            shutdown=self._shutdown,
-            data_addr=self._data_addr,
-        )
-
-    def _note_master_ok(self) -> None:
-        """RetryingMasterStub success hook (runs on whichever thread made
-        the call): the master answered, so the unreachable clock resets."""
-        self._last_master_ok = time.monotonic()
-
-    def _master_unreachable(self) -> bool:
-        """Called from RPC-failure paths: True (once; also flips
-        _master_lost and _shutdown) when no master RPC has succeeded for
-        master_unreachable_timeout_s — the master is permanently gone, and
-        retrying forever would leave an orphan process spinning on a dead
-        address (observed: cohort members surviving hours after their
-        master's process tree was killed). Exit EX_TEMPFAIL instead: a live
-        manager relaunches us; an orphan frees its chip and memory."""
-        limit = self.cfg.master_unreachable_timeout_s
-        if limit <= 0 or time.monotonic() - self._last_master_ok < limit:
-            return False
-        if not self._master_lost:
-            self._master_lost = True
-            logger.error(
-                "no successful master RPC for %.0fs (limit %.0fs): master "
-                "presumed gone, exiting EX_TEMPFAIL",
-                time.monotonic() - self._last_master_ok, limit,
-            )
-            self._shutdown.set()
-        return True
-
-    def _reregister(self) -> None:
-        """The reconnect handshake after a master restart (shared with the
-        cohort leader — see proto/service.py's reregister): idempotent
-        re-register under our EXISTING worker id, then apply the response."""
-        resp = reregister(
-            self._stub, name=self._name, worker_id=self.worker_id,
-            data_addr=self._data_addr,
-        )
-        # drop locally queued leases: the restarted master conservatively
-        # requeued every lease of the dead generation, so these tasks will
-        # re-run (exactly once) through fresh leases
+    def _on_reregistered(self, resp) -> None:
+        """A reconnect handshake landed (worker/session.py): apply its
+        response."""
+        # drop locally queued leases: the master conservatively requeued
+        # every lease of the dead generation (or of the worker it wrote
+        # off), so these tasks will re-run (exactly once) through fresh
+        # leases
         self._lease_queue.clear()
-        self.worker_id = resp.worker_id
         self._membership_version = resp.membership_version
         self._last_known_workers = resp.num_workers or self._last_known_workers
         _RECONNECTS.inc()
@@ -279,25 +214,6 @@ class Worker:
             "(membership v%d); resuming leases under the new generation",
             self.worker_id, resp.membership_version,
         )
-
-    def _maybe_reconnect(self, e: BaseException) -> bool:
-        """RPC-failure triage for the master-restart fence: True when `e`
-        was a stale-generation rejection AND the reconnect handshake ran —
-        the caller should retry its loop instead of backing off or dying.
-        Any other error (including a failed re-register: the master may
-        have crashed AGAIN mid-handshake) returns False and leaves the
-        normal unreachable accounting to the caller."""
-        if self.worker_id < 0 or not is_stale_generation(e):
-            return False
-        try:
-            self._reregister()
-            return True
-        except Exception as handshake_err:
-            logger.warning(
-                "re-register after master restart failed: %s", handshake_err
-            )
-            self._master_unreachable()
-            return False
 
     def _build_trainer(self) -> None:
         from elasticdl_tpu.common.runtime import (
@@ -378,12 +294,8 @@ class Worker:
         )
 
     def _checkpoint_manager(self):
-        if self._ckpt_manager is None and self.cfg.checkpoint_dir:
-            from elasticdl_tpu.training.checkpoint import CheckpointManager
-
-            self._ckpt_manager = CheckpointManager(
-                self.cfg.checkpoint_dir, keep=self.cfg.keep_checkpoint_max
-            )
+        if self._ckpt_manager is None:
+            self._ckpt_manager = job_checkpoint_manager(self.cfg)
         return self._ckpt_manager
 
     def _ensure_state(self, example_batch: Dict[str, Any]) -> None:
@@ -495,116 +407,43 @@ class Worker:
             )
         except ValueError:
             depth = self.cfg.prefetch_batches
-        stats.update(
-            phase=phase,
-            breaker_open=int(bool(self._stub and self._stub.breaker.is_open)),
-            prefetch_depth=depth,
-            world_version=tracing.get_tracer().world_version,
-        )
-        # step-profiler phase breakdown + memory watermarks (bounded key
-        # set): the master's ClusterHealth sees WHY a straggler is slow
-        stats.update(profile_lib.get_profiler().snapshot())
-        # goodput ledger ride-along (ISSUE 12): cumulative per-category
-        # wall-clock attribution (gp_* keys) — the master's FleetGoodput
-        # rollup totals these into the fleet goodput fraction
-        stats.update(goodput_lib.get_ledger().payload())
-        # request-diary ride-along (ISSUE 19): compact tail-attribution
-        # rollup (rt_* keys) + degraded/shm-fallback shares — the
-        # master's FleetAttribution and fleet_series read these
-        stats.update(reqtrace_lib.get_recorder().payload())
-        # embedding-tier skew ride-along (ISSUE 11): hot-id share, shard
-        # imbalance, recent pull/push p99 — the fleet rollup's sensor for
-        # the hot-row-cache decision. Best-effort like the rest of the
-        # payload: a tier hiccup must never cost the heartbeat.
-        if self._tier is not None:
-            try:
-                stats.update(self._tier.client.tier_stats())
-            except Exception:
-                # edl-lint: disable=EDL303
-                pass
+        stats.update(phase=phase, prefetch_depth=depth)
+        stats.update(self._session.stats_ride_alongs(self._tier))
         return stats
 
-    def _heartbeat_loop(self) -> None:
-        while not self._shutdown.is_set():
-            # time-series sample when due (interval-gated: normally one
-            # clock read per beat); rides the heartbeat thread so the
-            # train loop never pays for a registry snapshot
-            timeseries_lib.get_store().maybe_sample()
-            try:
-                # chaos hook: worker.heartbeat:crash kills the process here
-                # (a hard worker death between task boundaries); drop/delay
-                # fall through the same except path as a network failure
-                faults.fire("worker.heartbeat")
-                # telemetry rides as OPTIONAL metadata: a master that does
-                # not understand it ignores it, and a payload-building
-                # failure degrades this beat to liveness-only — stats must
-                # never cost a heartbeat
-                try:
-                    md = ((STATS_METADATA_KEY,
-                           encode_stats(self._stats_payload())),)
-                except Exception:
-                    md = None
-                resp = self._stub.Heartbeat(
-                    pb.HeartbeatRequest(
-                        worker_id=self.worker_id,
-                        model_version=self._model_version,
-                    ),
-                    timeout=10,
-                    metadata=md,
-                )
-                if resp.shutdown:
-                    logger.info("master requested shutdown")
-                    # job_done distinguishes normal completion (export the
-                    # final model) from aborts/evictions (don't)
-                    if resp.job_done:
-                        self._job_done = True
-                    self._shutdown.set()
-                    break
-                if getattr(resp, "evict", False):
-                    # graceful-eviction drain handshake (the closed-loop
-                    # autoscaler shrinking past this worker): identical to
-                    # a k8s SIGTERM preemption — stop at the next batch
-                    # boundary, drain-checkpoint, report the applied
-                    # prefix (the remainder requeues FRONT, retry-free),
-                    # exit EX_TEMPFAIL. The run loop does all of that off
-                    # the _preempted flag; this thread only raises it.
-                    logger.warning(
-                        "master evicted this worker (autoscale policy); "
-                        "draining"
-                    )
-                    tracing.event(
-                        "worker.evicted", worker_id=self.worker_id,
-                    )
-                    self.preempt()
-                    break
-                self._last_known_workers = resp.num_workers or self._last_known_workers
-                if resp.should_checkpoint:
-                    # honored by the run loop at the next task boundary (the
-                    # heartbeat thread must not save mid-train-step)
-                    self._ckpt_requested = True
-                if resp.membership_version != self._membership_version:
-                    self._on_membership_change(
-                        resp.membership_version, resp.num_workers
-                    )
-                if (
-                    resp.learning_rate > 0
-                    and resp.learning_rate != self._pushed_lr
-                ):
-                    # master-pushed LR override (ReduceLROnPlateau): applied
-                    # at the next task boundary, AFTER any elastic rescale
-                    # set above — the push is job-global and wins
-                    self._pushed_lr = resp.learning_rate
-                    self._pending_lr = resp.learning_rate
-            except Exception as e:
-                logger.warning("heartbeat failed: %s", e)
-                # a stale-generation fence means the master is BACK (it
-                # restarted); re-register instead of counting it toward
-                # the unreachable exit
-                if not self._maybe_reconnect(e):
-                    self._master_unreachable()
-            # jittered beat: a synchronized swarm (mass relaunch, master
-            # restart) must de-phase instead of arriving as one herd
-            self._shutdown.wait(jittered(self.cfg.worker_heartbeat_s))
+    def _on_heartbeat_response(self, resp) -> None:
+        """The session's heartbeat hook (heartbeat thread): what the
+        master's answer means to THIS worker. Everything here only raises
+        flags; the run loop acts on them at a batch or task boundary."""
+        if getattr(resp, "evict", False):
+            # graceful-eviction drain handshake (the closed-loop
+            # autoscaler shrinking past this worker): identical to
+            # a k8s SIGTERM preemption — stop at the next batch
+            # boundary, drain-checkpoint, report the applied
+            # prefix (the remainder requeues FRONT, retry-free),
+            # exit EX_TEMPFAIL. The run loop does all of that off
+            # the _preempted flag, which also ends the beats.
+            logger.warning(
+                "master evicted this worker (autoscale policy); "
+                "draining"
+            )
+            tracing.event("worker.evicted", worker_id=self.worker_id)
+            self.preempt()
+            return
+        self._last_known_workers = resp.num_workers or self._last_known_workers
+        if resp.membership_version != self._membership_version:
+            self._on_membership_change(
+                resp.membership_version, resp.num_workers
+            )
+        if (
+            resp.learning_rate > 0
+            and resp.learning_rate != self._pushed_lr
+        ):
+            # master-pushed LR override (ReduceLROnPlateau): applied
+            # at the next task boundary, AFTER any elastic rescale
+            # set above — the push is job-global and wins
+            self._pushed_lr = resp.learning_rate
+            self._pending_lr = resp.learning_rate
 
     def _on_membership_change(self, new_version: int, num_workers: int = 0) -> None:
         """Elastic hook: the worker set changed. This worker's only local
@@ -1019,7 +858,7 @@ class Worker:
         delivered = False
         try:
             faults.fire("worker.report_task")
-            resp = self._stub.ReportTaskResult(
+            resp = self._session.stub.ReportTaskResult(
                 pb.ReportTaskResultRequest(
                     worker_id=self.worker_id,
                     task_id=task.task_id,
@@ -1046,7 +885,7 @@ class Worker:
                 # double-apply. Same semantics as an explicit rejection,
                 # independent of whether the reconnect handshake succeeds.
                 delivered = True
-                self._maybe_reconnect(e)
+                self._session.maybe_reconnect(e)
         if accepted:
             # Clear the mid-task flag only when the persisted state and the
             # task queue actually agree: either the drain checkpoint covers
@@ -1104,7 +943,7 @@ class Worker:
         for name, state in states.items():
             arr = np.asarray(jax.device_get(state), np.float32)
             msg.states.append(pb.MetricState(name=name, data=arr.tobytes()))
-        self._stub.ReportEvaluationMetrics(msg, timeout=30)
+        self._session.stub.ReportEvaluationMetrics(msg, timeout=30)
         return False
 
     def _run_prediction_task(self, task: pb.Task) -> bool:
@@ -1196,7 +1035,7 @@ class Worker:
                 )
                 bind_servicer = self._data_server.servicer
             self._tier = WorkerTierRuntime(
-                self._stub, self.worker_id,
+                self._session.stub, self.worker_id,
                 checkpoint_dir=self.cfg.checkpoint_dir,
                 transport=transport,
                 bind_servicer=bind_servicer,
@@ -1237,10 +1076,12 @@ class Worker:
         # beats start BEFORE the backend comes up: reaching four chips took
         # 18 s (PR 21), and a registered worker that stays silent for three
         # beat periods is declared dead and told to leave
-        self._heartbeat_thread = threading.Thread(
-            target=self._heartbeat_loop, daemon=True
+        self._session.start_heartbeats(
+            model_version=lambda: self._model_version,
+            stats_payload=self._stats_payload,
+            on_response=self._on_heartbeat_response,
+            fault_point="worker.heartbeat",
         )
-        self._heartbeat_thread.start()
         self._build_trainer()
 
         tasks_done = 0
@@ -1263,7 +1104,7 @@ class Worker:
                 else:
                     try:
                         with prof.span("lease"):
-                            resp = self._stub.GetTask(
+                            resp = self._session.stub.GetTask(
                                 pb.GetTaskRequest(
                                     worker_id=self.worker_id,
                                     max_tasks=self.cfg.task_lease_batch,
@@ -1272,11 +1113,11 @@ class Worker:
                             )
                     except Exception as e:
                         logger.warning("get_task failed: %s; retrying", e)
-                        if self._maybe_reconnect(e):
+                        if self._session.maybe_reconnect(e):
                             # master restarted: the handshake landed, re-lease
                             # immediately under the new generation
                             continue
-                        if self._master_unreachable():
+                        if self._session.master_unreachable():
                             break
                         # jittered: a cohort of relaunched workers retrying a
                         # recovering master on the same constant beat is a
@@ -1288,7 +1129,7 @@ class Worker:
                         continue
                     if resp.job_done:
                         logger.info("job done after %d tasks", tasks_done)
-                        self._job_done = True
+                        self._session.job_done = True
                         break
                     # an old master never fills `tasks`; fall back to the
                     # classic singular field (WAIT only ever arrives alone)
@@ -1311,10 +1152,11 @@ class Worker:
                 elif pending_lr is not None:
                     # state not built yet: keep it pending for the next loop
                     self._pending_lr = pending_lr
-                if self._ckpt_requested and not self._mid_training_task:
+                if (self._session.checkpoint_requested
+                        and not self._mid_training_task):
                     # master-requested checkpoint (heartbeat should_checkpoint),
                     # taken at a task boundary only
-                    self._ckpt_requested = False
+                    self._session.checkpoint_requested = False
                     try:
                         self._maybe_checkpoint(force=True)
                     except Exception:
@@ -1405,7 +1247,7 @@ class Worker:
                 try:
                     with prof.span("report"):
                         faults.fire("worker.report_task")
-                        self._stub.ReportTaskResult(report, timeout=30)
+                        self._session.stub.ReportTaskResult(report, timeout=30)
                         if task.type == pb.TRAINING and report.success:
                             # state and task queue agree here: safe
                             # checkpoint point
@@ -1413,7 +1255,7 @@ class Worker:
                             self._maybe_checkpoint()
                 except Exception as e:
                     logger.warning("report failed for task %d: %s", task.task_id, e)
-                    if self._maybe_reconnect(e):
+                    if self._session.maybe_reconnect(e):
                         # fenced report from before the crash: the restarted
                         # master requeued this lease, so the task re-runs and
                         # retires exactly once there — never resend the report
@@ -1446,7 +1288,7 @@ class Worker:
         # Export runs here, not in the GetTask branch: a worker may learn the
         # job finished from the heartbeat shutdown flag (another worker took
         # the last task) without ever seeing a job_done GetTask response.
-        if self._job_done and not self._preempted:
+        if self._session.job_done and not self._preempted:
             self._export_final_model()
 
         processor = self._spec.prediction_outputs_processor if self._spec else None
@@ -1456,9 +1298,8 @@ class Worker:
             except Exception:
                 logger.exception("prediction outputs processor close failed")
 
-        # Orderly teardown: stop the heartbeat thread and close the channel
-        # BEFORE interpreter exit — a grpc call in flight during shutdown
-        # aborts the process from the C++ layer.
+        # Orderly teardown; the session's close() (heartbeat thread, then
+        # the channel) comes last.
         self._shutdown.set()
         if getattr(self, "_metrics_server", None) is not None:
             try:
@@ -1473,19 +1314,12 @@ class Worker:
                              exc_info=True)
         # flush trace.jsonl durably (the tracer reopens on reconfigure)
         tracing.get_tracer().close()
-        if self._heartbeat_thread is not None:
-            self._heartbeat_thread.join(timeout=2 * self.cfg.worker_heartbeat_s)
-        try:
-            self._channel.close()
-        except Exception:
-            # teardown-only: the process is exiting either way, but the
-            # failure is still worth a debug line for post-mortems
-            logger.debug("grpc channel close failed at exit", exc_info=True)
+        self._session.close()
         # A preempted worker exits non-zero (EX_TEMPFAIL) so the instance
         # manager relaunches it and recovers its lease immediately; clean
         # job-done exits return 0. A lost master is also EX_TEMPFAIL: under
         # a live manager that means relaunch; orphaned, it frees the process.
-        return 75 if (self._preempted or self._master_lost) else 0
+        return 75 if (self._preempted or self._session.master_lost) else 0
 
     def _export_final_model(self) -> None:
         """Job-end serving export (reference: model_handler → SavedModel at

@@ -474,7 +474,7 @@ def run_master_restart_scenario(seed: int, ckpt_dir: str, crash_at: int,
             ),
             metadata=((REREGISTER_KEY, "1"),),
         ).worker_id
-        # what worker.py's _reregister records via tracing.event — this
+        # what worker.py's _on_reregistered records via tracing.event — this
         # single-threaded twin records it straight into its ring
         worker_flight.record(
             "event", "worker.reconnect", worker_id=new_wid,

@@ -190,17 +190,17 @@ def test_cohort_lease_aborts_when_master_lost(tmp_path):
             raise ConnectionError("connection refused")
 
     w = CohortWorker(cfg, ctx=CohortContext("localhost:1", 2, 0))
-    w._stub = DeadStub()
+    w._session.stub = DeadStub()
     # master answered recently: failures are still transient -> NOOP
-    w._last_master_ok = time.monotonic()
+    w._session.last_master_ok = time.monotonic()
     assert w._lease_control()[0] == OP_NOOP
     assert not w._shutdown.is_set()
     # silent past the limit -> ABORT with a final collective checkpoint
     # (clean task boundary, the save needs no master), shutdown latched
-    w._last_master_ok = time.monotonic() - 6.0
+    w._session.last_master_ok = time.monotonic() - 6.0
     ctrl = w._lease_control()
     assert ctrl[0] == OP_ABORT and ctrl[6] & FLAG_CHECKPOINT
-    assert w._shutdown.is_set() and w._master_lost
+    assert w._shutdown.is_set() and w._session.master_lost
     # the heartbeat thread can be the one that crosses the limit (mid-task);
     # the ensuing shutdown-branch lease must carry the same checkpoint flag
     ctrl = w._lease_control()

@@ -7,11 +7,13 @@ a worker mid-job (SURVEY §4 fault-tolerance tests), at process granularity.
 import os
 import time
 
+import pytest
 
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.master.main import Master
 from elasticdl_tpu.master.process_manager import ProcessManager
 from elasticdl_tpu.client.local import free_port
+from tests.conftest import listening
 from tests.jobs import HERMETIC_ENV, all_logs, patient_master, run_job
 
 
@@ -226,3 +228,171 @@ def test_relaunch_reuses_compilation_cache(tmp_path):
         "relaunch produced a new train-step cache key",
         step_entries(final) - step_entries(entries_at_kill),
     )
+
+
+# ---------------------------------------------------------------------- #
+# a live worker the master wrote off (ROADMAP C21), and the watcher's last
+# pass (C19)
+
+
+def test_a_reaper_that_fires_on_a_live_worker_costs_no_task(tmp_path):
+    """The worker's heartbeats lapse while it is alive (a long compile
+    beside five other processes; here the reaper's verdict is simply
+    delivered) and the membership writes it off. Its next heartbeat is
+    told to re-register, not to shut down: the SAME process goes on, the
+    leases the reaper requeued run once, and the job ends. Before the
+    repair the worker obeyed `shutdown`, left with exit 0, was booked
+    SUCCEEDED and the job waited for nobody until the deadline."""
+    # beats every 0.3 s and two dozen tasks: the write-off after the first
+    # task leaves the job seconds — many beats — to go
+    cfg = job_config(tmp_path, worker_heartbeat_s=0.3,
+                     training_data="synthetic://mnist?n=1200&shards=4")
+
+    def write_off(master, manager):
+        if master.dispatcher.counts()["finished_training"] < 1:
+            return False
+        assert master.membership.mark_dead(0, reason="heartbeat timeout")
+        return True
+
+    # a patient reaper, so that the ONE verdict is this test's
+    *_, counts = run_job(cfg, tmp_path, mid_job=write_off,
+                         master_of=patient_master)
+    assert counts["finished_training"] == 24, counts
+    assert counts["failed_permanently"] == 0, counts
+    assert counts["todo"] == 0 and counts["doing"] == 0, counts
+    log = all_logs(tmp_path)
+    assert "re-registered with restarted master as worker 0" in log, log[-3000:]
+    # repaired in place: no second process was needed
+    assert log.count("registered as worker 0") == 1, log[-3000:]
+
+
+class _FakeProc:
+    """A `Popen` whose exit the test decides."""
+
+    pid = 4242
+
+    def __init__(self, code=None):
+        self.code = code
+        self.polls = 0
+
+    def poll(self):
+        self.polls += 1
+        return self.code
+
+    def terminate(self):
+        self.code = -15 if self.code is None else self.code
+
+    kill = terminate
+
+    def wait(self, timeout=None):
+        return self.code
+
+
+class _Deaths:
+    def __init__(self):
+        self.dead = []
+
+    def add_join_callback(self, cb):
+        pass
+
+    def mark_dead(self, wid, reason=""):
+        self.dead.append((wid, reason))
+        return True
+
+
+def _fake_manager(monkeypatch, procs, finished, num_processes=1):
+    """A ProcessManager over fake processes: `procs` are its first
+    generation, every later `_spawn` gives a running one."""
+    from elasticdl_tpu.master import process_manager as pm
+
+    monkeypatch.setattr(
+        pm.ProcessManager, "_spawn",
+        lambda self, worker_id, relaunches=0, process_id=0: pm._WorkerProc(
+            worker_id=worker_id, proc=_FakeProc(), relaunches=relaunches),
+    )
+    cfg = JobConfig(model_def="m.f", master_addr="localhost:1",
+                    num_processes=num_processes, relaunch_max=2)
+    mgr = pm.ProcessManager(
+        cfg, membership=_Deaths(), membership_signal_path="",
+        job_finished_fn=lambda: finished)
+    for slot, proc in enumerate(procs):
+        mgr._procs[slot] = pm._WorkerProc(worker_id=0, proc=proc)
+    return mgr
+
+
+def _watch(mgr, until, poll_s=0.01):
+    """Run the watcher until `until()` holds, then stop the manager."""
+    import threading
+
+    mgr._watcher = threading.Thread(
+        target=mgr._watch_loop, kwargs={"poll_s": poll_s}, daemon=True)
+    mgr._watcher.start()
+    deadline = time.time() + 10
+    while not until() and mgr._watcher.is_alive() and time.time() < deadline:
+        time.sleep(0.005)
+    held = until()
+    mgr.stop(grace_s=5)
+    assert not mgr._watcher.is_alive()
+    return held
+
+
+@pytest.mark.parametrize("finished", [False, True])
+def test_an_exit_0_is_a_success_only_after_the_job_s_end(
+        monkeypatch, caplog, finished):
+    from elasticdl_tpu.common.constants import PodStatus
+
+    left = _FakeProc(code=0)
+    mgr = _fake_manager(monkeypatch, [left], finished)
+    first = mgr._procs[0]
+    with listening(caplog, "elasticdl_tpu.master.process_manager"):
+        assert _watch(mgr, lambda: (
+            first.status == PodStatus.SUCCEEDED or mgr._procs[0] is not first))
+    said = [r.getMessage() for r in caplog.records]
+    if finished:
+        assert first.status == PodStatus.SUCCEEDED and mgr._procs[0] is first
+        assert "worker 0 exited cleanly" in said
+        assert mgr._membership.dead == []
+    else:
+        # told to go with tasks left: a death like any other
+        assert mgr._membership.dead == [(0, "exit code 0")]
+        assert mgr._procs[0].relaunches == 1
+        assert any("worker 0 died (code 0); relaunch 1/2" in m for m in said)
+        assert "worker 0 exited cleanly" not in said
+
+
+@pytest.mark.parametrize("finished", [False, True])
+def test_a_cohort_that_all_left_with_0_is_done_only_after_the_job_s_end(
+        monkeypatch, caplog, finished):
+    mgr = _fake_manager(
+        monkeypatch, [_FakeProc(code=0), _FakeProc(code=0)], finished,
+        num_processes=2)
+    with listening(caplog, "elasticdl_tpu.master.process_manager"):
+        assert _watch(mgr, lambda: (
+            bool(mgr.reformation_log) or not mgr._watcher.is_alive()))
+    said = [r.getMessage() for r in caplog.records]
+    if finished:
+        assert "cohort exited, codes [0, 0]" in said
+        assert not mgr.reformation_log and mgr._membership.dead == []
+    else:
+        assert "cohort exited, codes [0, 0]" not in said
+        assert [(old, new) for _, old, new in mgr.reformation_log] == [(2, 2)]
+        assert mgr._membership.dead == [(0, "cohort member(s) [0, 1] died")]
+
+
+@pytest.mark.parametrize("num_processes,line", [
+    (1, "worker 0 exited cleanly"), (2, "cohort exited, codes [0, 0]")])
+def test_an_exit_between_the_last_poll_and_stop_is_still_booked(
+        monkeypatch, caplog, num_processes, line):
+    """ROADMAP C19: the launcher polls `all_exited()` every 0.2 s and then
+    stops the manager, whose watcher polls every 0.5 s — an exit that lands
+    between the watcher's last poll and `stop()` used to go unsaid."""
+    from elasticdl_tpu.common.constants import PodStatus
+
+    procs = [_FakeProc() for _ in range(num_processes)]
+    mgr = _fake_manager(monkeypatch, procs, True, num_processes)
+    with listening(caplog, "elasticdl_tpu.master.process_manager"):
+        # the watcher has looked once (all running) and sleeps for an hour
+        assert _watch(mgr, lambda: all(p.polls for p in procs) and [
+            setattr(p, "code", 0) for p in procs], poll_s=3600)
+    assert line in [r.getMessage() for r in caplog.records]
+    assert all(wp.status == PodStatus.SUCCEEDED for wp in mgr._procs.values())

@@ -1,6 +1,7 @@
 """Master control plane over a real local gRPC channel in one process —
 the reference's key test trick (SURVEY §4: in-process fakes, local channels)."""
 
+import grpc
 import numpy as np
 import pytest
 
@@ -12,6 +13,7 @@ from elasticdl_tpu.proto import elasticdl_tpu_pb2 as pb
 from elasticdl_tpu.proto.service import (
     MasterStub,
     add_master_servicer,
+    is_stale_generation,
     make_channel,
     make_server,
 )
@@ -107,9 +109,16 @@ def test_heartbeat_and_membership(master_stack):
     h2 = stub.Heartbeat(pb.HeartbeatRequest(worker_id=r0.worker_id))
     assert h2.membership_version > h.membership_version
     assert h2.num_workers == 1
-    # dead worker's heartbeat tells it to shut down
-    h3 = stub.Heartbeat(pb.HeartbeatRequest(worker_id=r1.worker_id))
-    assert h3.shutdown
+    # a heartbeat from a worker written off is a LIVE worker's: it is told
+    # to re-register (the fence's rejection), not to shut down with tasks
+    # left (ROADMAP C21)
+    with pytest.raises(grpc.RpcError) as rejected:
+        stub.Heartbeat(pb.HeartbeatRequest(worker_id=r1.worker_id))
+    assert is_stale_generation(rejected.value)
+    # ... unless the master itself is shutting down
+    servicer.request_shutdown()
+    assert stub.Heartbeat(pb.HeartbeatRequest(worker_id=r1.worker_id)).shutdown
+    servicer._shutdown = False
     # recovered task is re-leasable
     resp2 = stub.GetTask(pb.GetTaskRequest(worker_id=r0.worker_id))
     assert resp2.task.task_id == resp.task.task_id
@@ -331,3 +340,71 @@ def test_register_with_member_names_and_coalesced_heartbeat(master_stack):
                                stats_json="}{not json")],
     )
     assert not stub.Heartbeat(bad).shutdown
+
+
+def test_a_reaper_that_fires_on_a_live_worker_costs_no_task():
+    """ROADMAP C21 over real gRPC, the worker's half being the one
+    `MasterSession` both worker flavours hold: the reaper fires ONCE on a
+    worker that is alive (its beats lapsed), its next beat is told to
+    re-register, the handshake revives it, and the job ends with every task
+    done exactly once — the lease the reaper requeued included."""
+    import threading
+
+    from elasticdl_tpu.common.config import JobConfig
+    from elasticdl_tpu.worker.session import MasterSession
+
+    now = [0.0]
+    dispatcher = TaskDispatcher(
+        training_shards=[("t", 0, 40)], records_per_task=10, shuffle=False)
+    membership = Membership(heartbeat_timeout_s=30, clock=lambda: now[0])
+    membership.add_death_callback(dispatcher.recover_tasks)
+    servicer = MasterServicer(dispatcher, membership, None, generation=2)
+    server = make_server()
+    add_master_servicer(server, servicer)
+    port = server.add_insecure_port("[::]:0")
+    server.start()
+    shutdown = threading.Event()
+    revived = []
+    session = MasterSession(
+        JobConfig(model_def="m.f", master_addr=f"localhost:{port}",
+                  worker_heartbeat_s=0.01),
+        shutdown, what="worker", when_lost="exiting EX_TEMPFAIL",
+        on_reregistered=revived.append)
+    try:
+        wid = session.connect("w", -1).worker_id
+        held = session.stub.GetTask(
+            pb.GetTaskRequest(worker_id=wid), timeout=5).task
+        now[0] = 31.0                       # three beats went missing
+        assert membership.reap() == [wid] and membership.alive_count() == 0
+        assert membership.reap() == []      # it fires once
+        session.heartbeat_loop(
+            model_version=lambda: 0, stats_payload=dict,
+            on_response=lambda resp: shutdown.set())
+        # one rejected beat, one handshake, one beat that was answered
+        assert len(revived) == 1 and revived[0].worker_id == wid
+        assert membership.alive_count() == 1 and not session.job_done
+        done = []
+
+        def report(task):
+            accepted = session.stub.ReportTaskResult(
+                pb.ReportTaskResultRequest(
+                    worker_id=wid, task_id=task.task_id, success=True),
+                timeout=5).accepted
+            if accepted:
+                done.append((task.start, task.end))
+
+        report(held)        # its lease went back to the queue: not counted
+        while True:
+            resp = session.stub.GetTask(
+                pb.GetTaskRequest(worker_id=wid), timeout=5)
+            if resp.job_done:
+                break
+            report(resp.task)
+        assert sorted(done) == [(0, 10), (10, 20), (20, 30), (30, 40)]
+        counts = dispatcher.counts()
+        assert counts["finished_training"] == 4, counts
+        assert counts["todo"] == 0 and counts["doing"] == 0, counts
+    finally:
+        shutdown.set()
+        session.close()
+        server.stop(0)

@@ -100,7 +100,7 @@ def test_cohort_save_model_without_checkpoint_dir_fails_task(tmp_path):
             reports.append(req)
 
     w = CohortWorker(make_cfg(tmp_path), ctx=CohortContext("localhost:1", 1, 0))
-    w._stub = Stub()
+    w._session.stub = Stub()
     assert not w.cfg.checkpoint_dir
     w._run_task([OP_TASK, 7, pb.SAVE_MODEL, 0, 0, 0, 0, 0, 0])
     assert len(reports) == 1
