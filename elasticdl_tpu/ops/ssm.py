@@ -1,9 +1,10 @@
 """State-space (Mamba-2) mixer operations: the causal depthwise convolution,
 the chunked state-space scan (SSD: Dao & Gu, arXiv:2405.21060) and the gated
-group RMSNorm. Plain `jax.numpy` / `lax`, but for the scan: on a TPU
-`ssd_chunked` runs as the Mosaic kernels of `ops/pallas_ssd.py`
-(`scan_route`); its plain body is the path everywhere else and the tests'
-reference for the kernels.
+group RMSNorm. Plain `jax.numpy` / `lax`, but for the scan and the
+convolution: on a TPU `ssd_chunked` runs as the Mosaic kernels of
+`ops/pallas_ssd.py` (`scan_route`) and `causal_conv1d` as those of
+`ops/pallas_conv1d.py` (`conv_route`); their plain bodies are the path
+everywhere else and the tests' reference for the kernels.
 
 The recurrence, per head (P channels, N state columns; B and C shared by the
 heads of a group):
@@ -33,20 +34,56 @@ sweeps the chunks once more for the state each starts from.
 from __future__ import annotations
 
 import logging
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
 
-from elasticdl_tpu.ops import pallas_ssd
+from elasticdl_tpu.ops import pallas_conv1d, pallas_ssd
 
 logger = logging.getLogger(__name__)
+
+
+@lru_cache(maxsize=None)
+def _log_conv_route(*said):
+    """Once a process for each shape and route: a step's program traces the
+    convolution a layer, a recomputation and a counter at a time."""
+    logger.info(
+        "causal convolution (%d tokens, %d channels, %d taps) takes the %s route "
+        "(the Pallas kernels need a TPU or interpret mode: %s; channels whole "
+        "lanes, whole time blocks, the taps' reach inside an 8-row tile: %s)", *said)
+
+
+def conv_route(x_shape, k: int) -> str:
+    """Which body a depthwise convolution of x (B, T, Ch) under k taps takes —
+    "kernel" or "plain": a pure function of the shapes and of whether the
+    kernels can run here (a TPU, or interpret mode in the CPU tests). Logged
+    once for each answer."""
+    _, t, ch = x_shape
+    runnable = pallas_ssd.runnable()      # the same answer for every kernel here
+    fits = pallas_conv1d.blocks(t, ch, k)
+    route = "kernel" if runnable and fits else "plain"
+    _log_conv_route(t, ch, k, route, runnable,
+                    f"blocks of {fits.time} x {fits.lanes}" if fits else "no")
+    return route
 
 
 def causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array = None) -> jax.Array:
     """Depthwise causal convolution over time: x (B, T, Ch), weight (K, Ch),
     bias (Ch) or None -> y_t = Σ_{j<K} weight_j · x_{t-K+1+j} + bias, zeros
-    before the sequence. float32."""
+    before the sequence. float32. One algorithm on two routes (`conv_route`):
+    the kernels of `ops/pallas_conv1d.py` take one pass over the plane a
+    direction and keep x and the weight for their pull-back; the plain body
+    is the path everywhere else and the tests' reference for the kernels."""
+    k = weight.shape[0]
+    if conv_route(x.shape, k) == "kernel":
+        return pallas_conv1d.causal_conv1d_kernels(
+            x, weight, bias, pallas_conv1d.blocks(x.shape[1], x.shape[2], k))
+    return _causal_conv1d_plain(x, weight, bias)
+
+
+def _causal_conv1d_plain(x, weight, bias=None):
+    """`causal_conv1d` in `jax.numpy`: K shifted multiply-adds."""
     k, t = weight.shape[0], x.shape[1]
     x = x.astype(jnp.float32)
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))

@@ -86,7 +86,10 @@ Counters (`TrainState.extra_vars`): `router_state/held_passes`,
 `held_row_tiles`, `held_row_chunks` (GLM's); `kda/chunks` (the chunks walked,
 summed over steps, layers, batch and heads), `kda/kernel_chunks` (those of
 them walked by the Pallas kernels: all of them on `delta_rule_route`'s
-"kernel" route, 0 on the plain one), and of the last step, a KDA
+"kernel" route, 0 on the plain one), `kda/kernel_convs` (the depthwise
+convolutions of the forward passes that took `ops/pallas_conv1d.py`'s kernels:
+three a KDA layer and step on `ops.ssm.conv_route`'s "kernel" route, 0 on the
+plain one), and of the last step, a KDA
 layer each: `kda/log_decay_min` (the most negative in-chunk Γ: how near the
 decay runs to underflow), `kda/beta_mean`, `kda/state_rms` (the state after the
 last token). The outputs carry `kda_stats` (B, 3) — those three, the worst or
@@ -109,7 +112,7 @@ import jax.numpy as jnp
 import optax
 
 from elasticdl_tpu.ops import delta_rule, pallas_attention
-from elasticdl_tpu.ops.ssm import causal_conv1d
+from elasticdl_tpu.ops.ssm import causal_conv1d, conv_route
 from model_zoo.transformer import glm4_moe_lite as glm
 from model_zoo.transformer.afmoe import LogitAccuracy
 from model_zoo.transformer.nemotron_h import (
@@ -384,6 +387,15 @@ def kernel_chunks_walked(cfg: Config, batch: int, seq_len: int) -> int:
     return chunks_walked(cfg, batch, seq_len) if route == "kernel" else 0
 
 
+def kernel_convs(cfg: Config, batch: int, seq_len: int) -> int:
+    """The depthwise convolutions of one step's forward pass that take the
+    Pallas kernels (`ops/pallas_conv1d.py`): q's, k's and v's of every KDA
+    layer where `conv_route` says "kernel" at this shape, none elsewhere."""
+    shape = (batch, seq_len, cfg.linear_num_heads * cfg.linear_head_dim)
+    route = conv_route(shape, cfg.short_conv_kernel_size)
+    return 3 * cfg.layers_of("kda") if route == "kernel" else 0
+
+
 # ------------------------------------------------------------------ #
 # The zoo contract
 
@@ -453,6 +465,7 @@ class KimiLinear(nn.Module):
         row_chunks = counter("router_state", "held_row_chunks", (S,))
         chunks = counter("kda", "chunks", ())
         kernel_chunks = counter("kda", "kernel_chunks", ())
+        convs = counter("kda", "kernel_convs", ())
         last_step = {name: counter("kda", name, (K,), jnp.float32)
                      for name in ("log_decay_min", "beta_mean", "state_rms")}
         outputs, stats, kda_stats = forward(params, bias.value, features, c)
@@ -464,6 +477,7 @@ class KimiLinear(nn.Module):
             row_chunks.value = row_chunks.value + held_row_chunks(idx, c)
             chunks.value = chunks.value + chunks_walked(c, *features.shape)
             kernel_chunks.value = kernel_chunks.value + kernel_chunks_walked(c, *features.shape)
+            convs.value = convs.value + kernel_convs(c, *features.shape)
             last_step["log_decay_min"].value = jnp.min(kda_stats[..., 0], axis=1)
             last_step["beta_mean"].value = jnp.mean(kda_stats[..., 1], axis=1)
             last_step["state_rms"].value = jnp.mean(kda_stats[..., 2], axis=1)

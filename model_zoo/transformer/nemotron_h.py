@@ -11,7 +11,9 @@ Every block is `x ← x + mixer(rmsnorm(x))`, eps `layer_norm_epsilon`, no bias
 anywhere except the convolution's (hidden C):
 
 - `M`: `[z | xBC | dt] = h·W_in` (d_inner | d_inner + 2·G·N | H);
-  `xBC ← silu(conv1d_causal(xBC) + b)` depthwise, kernel `conv_kernel`;
+  `xBC ← silu(conv1d_causal(xBC) + b)` depthwise, kernel `conv_kernel`
+  (`ops.ssm.causal_conv1d`: the kernels of `ops/pallas_conv1d.py` on a TPU;
+  `router_state/kernel_convs` counts the convolutions that took them);
   `xBC → x (H heads × P), B, C (G groups × N)`, head i uses group i // (H/G);
   `Δ = softplus(dt + dt_bias)`, `a_t = exp(Δ_t·A)`, `A = −exp(A_log)`;
   `S_t = a_t S_{t-1} + Δ_t x_t ⊗ B_t`, `y_t = S_t C_t + D x_t`, computed in
@@ -343,6 +345,14 @@ def held_row_chunks(expert_idx, cfg: Config):
         cfg.num_experts, cfg.held[1])
 
 
+def kernel_convs(cfg: Config, batch: int, seq_len: int) -> int:
+    """The depthwise convolutions of one step's forward pass that take the
+    Pallas kernels (`ops/pallas_conv1d.py`): one a Mamba layer where
+    `ssm.conv_route` says "kernel" at this shape, none elsewhere."""
+    route = ssm.conv_route((batch, seq_len, cfg.conv_dim), cfg.conv_kernel)
+    return cfg.layers_of("M") if route == "kernel" else 0
+
+
 # ------------------------------------------------------------------ #
 # The zoo contract
 
@@ -411,12 +421,14 @@ class NemotronH(nn.Module):
         passes = self.variable("router_state", "held_passes", jnp.zeros, (E,), jnp.int32)
         row_tiles = self.variable("router_state", "held_row_tiles", jnp.zeros, (E,), jnp.int32)
         row_chunks = self.variable("router_state", "held_row_chunks", jnp.zeros, (E,), jnp.int32)
+        convs = self.variable("router_state", "kernel_convs", jnp.zeros, (), jnp.int32)
         logits, stats = forward(params, bias.value, features, c)
         if training and not self.is_initializing():
             bias.value = updated_bias(bias.value, stats["expert_idx"], c)
             passes.value = passes.value + held_passes(stats["expert_idx"], c)
             row_tiles.value = row_tiles.value + held_row_tiles(stats["expert_idx"], c)
             row_chunks.value = row_chunks.value + held_row_chunks(stats["expert_idx"], c)
+            convs.value = convs.value + kernel_convs(c, *features.shape)
         return logits
 
 

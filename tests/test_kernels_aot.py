@@ -7,7 +7,8 @@ that does not fit, the two that stream it (`ops/pallas_attention.py`: the
 block plan follows the head size, `bwd_route` the head's bytes), and the
 state-space scan's three kernels at the Nemotron cell's shapes
 (`ops/pallas_ssd.py`) and the delta rule's two at the Kimi cell's
-(`ops/pallas_delta_rule.py`) — the same kernels inside the zoo's checkpointed layers,
+(`ops/pallas_delta_rule.py`), and the depthwise convolution's two at both
+cells' planes (`ops/pallas_conv1d.py`) — the same kernels inside the zoo's checkpointed layers,
 under the scopes the benchmark reads them by, are
 `tests/test_kernels_aot_layers.py`'s; and
 the pull-back of the Keye cell's index scores (`ops/sparse_attention.py::
@@ -27,7 +28,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from elasticdl_tpu.ops import pallas_delta_rule, pallas_gmm, pallas_ssd
+from elasticdl_tpu.ops import pallas_conv1d, pallas_delta_rule, pallas_gmm, pallas_ssd
 
 # (rows of a pass or of all pairs, K, N, groups): the held experts of
 # nemotron-3-nano-30b-a3b.resident-8k, up and down; olmoe-1b-7b.resident-4k's
@@ -324,3 +325,35 @@ def test_delta_rule_kernels_compile_for_a_v5e(one_chip, no_compile_cache):
     for kernel in ("delta_rule_fwd", "delta_rule_bwd"):
         assert text.count("%" + kernel) >= 1, kernel
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+# the depthwise convolutions of kimi-linear-48b-a3b.resident-16k's KDA layers
+# (q, k, v: no bias) and of nemotron-3-nano-30b-a3b.resident-8k's Mamba layers
+# (xBC, a bias): (tokens, channels, a bias), 4 taps
+CONV = {"kimi-linear-48b-a3b.resident-16k": (16384, 4096, False),
+        "nemotron-3-nano-30b-a3b.resident-8k": (8192, 6144, True)}
+
+
+@pytest.mark.parametrize("cell", sorted(CONV))
+def test_causal_conv1d_kernels_compile_for_a_v5e(cell, one_chip, no_compile_cache):
+    """Float32 planes in the projection's layout: the forward and the
+    pull-back at the blocks the rule gives, one call each and nothing of the
+    plane's size around them but the operands and results."""
+    t, ch, bias = CONV[cell]
+    plan = pallas_conv1d.blocks(t, ch, 4)
+    assert plan == (512, 512)
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def forward_and_backward(x, w, b, du):
+        conv = lambda x, w, b: pallas_conv1d.causal_conv1d_kernels(x, w, b if bias else None, plan)
+        u, vjp = jax.vjp(conv, x, w, b)
+        return u, vjp(du)
+
+    exe = jax.jit(forward_and_backward).lower(
+        shape(1, t, ch), shape(4, ch), shape(ch), shape(1, t, ch)).compile()
+    text = exe.as_text()
+    for kernel in ("causal_conv1d_fwd", "causal_conv1d_bwd"):
+        assert len(re.findall(rf"^\s*%{kernel}\.\d+ = .*tpu_custom_call", text, re.M)) == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.search(r" (convolution|copy)\(", text)
+    assert exe.memory_analysis().temp_size_in_bytes < 4 * t * ch // 8

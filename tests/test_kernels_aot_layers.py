@@ -2,7 +2,8 @@
 for a described v5e at the cells' shapes — every scan kernel of a Mamba mixer
 and every flash kernel of a GLM, Nemotron or Mellum2 block has to carry the
 scope the benchmark reads it by, and a recomputed layer holds ONE forward
-call. The kernels alone are in `tests/test_kernels_aot.py`, whose fixtures
+call; the depthwise convolutions of a Mamba mixer and of a KDA layer are the
+kernels of `ops/pallas_conv1d.py` under the layer's `conv` scope. The kernels alone are in `tests/test_kernels_aot.py`, whose fixtures
 these are; a file of its own so that two xdist workers share the compiles
 (the driver's command sets `ALLOW_MULTIPLE_LIBTPU_LOAD=1`).
 """
@@ -13,45 +14,96 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tests.test_kernels_aot import SCAN, no_compile_cache, one_chip  # noqa: F401  (fixtures)
+from tests.test_kernels_aot import CONV, SCAN, no_compile_cache, one_chip  # noqa: F401  (fixtures)
 
 pytestmark = pytest.mark.usefixtures("xla_optimises")
 
 
+@pytest.fixture(scope="module")
+def compiled_texts():
+    """model -> the compiled text of its gradient program: one compile a module."""
+    return {}
+
+
+def mamba_program(one_chip, monkeypatch, compiled_texts):
+    """The compiled text of a checkpointed Mamba mixer's gradient at the
+    Nemotron cell's widths and 8192 tokens, for the described chip."""
+    from model_zoo.transformer import nemotron_h
+
+    if "mamba" not in compiled_texts:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+        cfg = nemotron_h.Config(num_hidden_layers=1, hybrid_override_pattern="M")
+        assert (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.ssm_state_size,
+                cfg.chunk_size) == tuple(SCAN.values())[1:]
+        assert (SCAN["tokens"], cfg.conv_dim, True) == CONV["nemotron-3-nano-30b-a3b.resident-8k"]
+        shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+        c, h = cfg.hidden_size, cfg.mamba_num_heads
+        p = {"mamba_norm": shape(c), "mamba_in_proj": shape(c, cfg.d_inner + cfg.conv_dim + h),
+             "mamba_conv_w": shape(cfg.conv_kernel, cfg.conv_dim), "mamba_conv_b": shape(cfg.conv_dim),
+             "mamba_dt_bias": shape(h), "mamba_A_log": shape(h), "mamba_D": shape(h),
+             "mamba_gate_norm": shape(cfg.d_inner), "mamba_out_proj": shape(cfg.d_inner, c)}
+
+        def loss(p, x):
+            with jax.named_scope("nemotron_h"), jax.named_scope("mamba"):
+                y = jax.checkpoint(lambda p, x: nemotron_h.mamba(p, x, cfg))(p, x)
+            return jnp.sum(jnp.square(x + y))
+
+        compiled_texts["mamba"] = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            p, shape(1, SCAN["tokens"], c)).compile().as_text()
+    return compiled_texts["mamba"]
+
+
+def scopes_of(text, module):
+    """instruction name -> the scope the benchmark's readers put it in."""
+    from benchmark import common
+    flops = common.load_module("flops", module)
+    return common.load_module("drivers", "resident_lm_share").scope_map(
+        text, flops.SCOPES, getattr(flops, "RAGGED_DOT_SCOPE", None))
+
+
 def test_every_scan_kernel_of_a_checkpointed_mamba_mixer_carries_its_scope(
-        one_chip, no_compile_cache, monkeypatch):
+        one_chip, no_compile_cache, monkeypatch, compiled_texts):
     """`nemotron_h.forward` checkpoints the mixer: the gradient program runs
     the forward kernel twice (forward, the block's recomputation), the sweep
     and the backward kernel once — and `benchmark/drivers/resident_lm_share.py::scope_map` finds their time
     by `mamba/ssd` in each one's `op_name`, or `ssm_scan_roofline` divides a
     fixed floor by a scope that lost its kernels."""
-    from model_zoo.transformer import nemotron_h
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the route asks
-    cfg = nemotron_h.Config(num_hidden_layers=1, hybrid_override_pattern="M")
-    assert (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.ssm_state_size,
-            cfg.chunk_size) == tuple(SCAN.values())[1:]
-    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
-    c, h = cfg.hidden_size, cfg.mamba_num_heads
-    p = {"mamba_norm": shape(c), "mamba_in_proj": shape(c, cfg.d_inner + cfg.conv_dim + h),
-         "mamba_conv_w": shape(cfg.conv_kernel, cfg.conv_dim), "mamba_conv_b": shape(cfg.conv_dim),
-         "mamba_dt_bias": shape(h), "mamba_A_log": shape(h), "mamba_D": shape(h),
-         "mamba_gate_norm": shape(cfg.d_inner), "mamba_out_proj": shape(cfg.d_inner, c)}
-
-    def loss(p, x):
-        with jax.named_scope("nemotron_h"), jax.named_scope("mamba"):
-            y = jax.checkpoint(lambda p, x: nemotron_h.mamba(p, x, cfg))(p, x)
-        return jnp.sum(jnp.square(x + y))
-
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        p, shape(1, SCAN["tokens"], c)).compile().as_text()
+    text = mamba_program(one_chip, monkeypatch, compiled_texts)
     calls = re.findall(r"^\s*%?(ssd_[\w.]+) = ", text, re.M)
     assert sorted(re.sub(r"\.\d+$", "", name) for name in calls) == [
         "ssd_chunk_bwd", "ssd_chunk_fwd", "ssd_chunk_fwd", "ssd_chunk_starts"]
-    from benchmark import common
-    scope_map = common.load_module("drivers", "resident_lm_share").scope_map
-    scopes = scope_map(text, common.load_module("flops", "nemotron_h").SCOPES)
+    scopes = scopes_of(text, "nemotron_h")
     assert [scopes.get(name) for name in calls] == ["nemotron_h/mamba/ssd"] * 4
+
+
+def assert_the_convolutions_are_the_kernels(text, module, scope, convolutions):
+    """`convolutions` depthwise convolutions of a checkpointed layer: each
+    runs the forward kernel twice (the pass, the layer's recomputation) and the
+    pull-back once, every call under `scope`, and XLA is left no `convolution`
+    of its own there."""
+    from benchmark import common
+    calls = re.findall(r"^\s*%?(causal_conv1d_[\w.]+) = ", text, re.M)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name in calls) == (
+        ["causal_conv1d_bwd"] * convolutions + ["causal_conv1d_fwd"] * 2 * convolutions)
+    scopes = scopes_of(text, module)
+    assert {scopes.get(name) for name in calls} == {scope}
+    share = common.load_module("drivers", "resident_lm_share")
+    flops = common.load_module("flops", module)
+    under = [line for line in text.splitlines() if " convolution(" in line
+             and share._lm._OP_NAME.search(line)
+             and share.scope_of(share._lm._OP_NAME.search(line).group(1), flops.SCOPES) == scope]
+    assert not under, under[:2]
+
+
+def test_the_convolution_of_a_checkpointed_mamba_mixer_is_the_kernels_under_its_scope(
+        one_chip, no_compile_cache, monkeypatch, compiled_texts):
+    """The xBC plane's depthwise convolution (8192 x 6144, a bias) compiles as
+    `causal_conv1d_fwd` twice and `causal_conv1d_bwd` once, all under
+    `nemotron_h/mamba/conv`, where the builder's reading of `ssm_ms` by scope
+    finds them."""
+    assert_the_convolutions_are_the_kernels(
+        mamba_program(one_chip, monkeypatch, compiled_texts), "nemotron_h",
+        "nemotron_h/mamba/conv", 1)
 
 
 # (the zoo's module, a configuration of few layers at the cell's attention
@@ -83,12 +135,6 @@ RECOMPUTED_ATTENTION = {
         num_hidden_layers=2, first_k_dense_replace=1, n_routed_experts=8,
         router_experts=64, vocab_size=512), 4096, [("xing4/mla/attn", _CAUSAL)] * 2),
 }
-
-
-@pytest.fixture(scope="module")
-def compiled_texts():
-    """model -> the compiled text of its gradient program: one compile a module."""
-    return {}
 
 
 def gradient_program(model, one_chip, monkeypatch, compiled_texts):
@@ -277,8 +323,37 @@ def test_ouro_s_loop_keeps_every_application_s_residuals(
         "ouro/pass/final_norm", "ouro/exit", "ouro/exit_loss"}
 
 
+def kimi_program(one_chip, monkeypatch, compiled_texts):
+    """The compiled text of kimi-linear-48b-a3b.resident-16k's value and
+    gradient at its published widths and 16 384 tokens, a KDA layer over the
+    dense feed-forward and a latent layer over a sparse one, through the zoo's
+    own loss."""
+    from model_zoo.transformer import kimi_linear
+
+    if "kimi" not in compiled_texts:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+        net = kimi_linear.custom_model(
+            num_hidden_layers=2, kda_layers="1", full_attn_layers="2", num_experts=8,
+            router_experts=256, vocab_size=512)
+        assert (16384, net.cfg.linear_num_heads * net.cfg.linear_head_dim, False) == CONV[
+            "kimi-linear-48b-a3b.resident-16k"]
+        tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+        variables = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
+
+        def loss(params, state, tokens):
+            outputs = net.apply({"params": params, **state}, tokens)
+            return jnp.sum(kimi_linear.loss(tokens, outputs)["loss"])
+
+        params = variables.pop("params")
+        compiled_texts["kimi"] = jax.jit(jax.value_and_grad(loss)).lower(
+            params, variables, tokens).compile().as_text()
+    return compiled_texts["kimi"]
+
+
 def test_kimi_linear_s_two_kinds_of_layer_compile_under_their_scopes(
-        one_chip, no_compile_cache, monkeypatch):
+        one_chip, no_compile_cache, monkeypatch, compiled_texts):
     """kimi-linear-48b-a3b.resident-16k at its published widths and 16 384
     tokens, a KDA layer over the dense feed-forward and a latent layer over a
     sparse one, through the zoo's own loss: the chunked delta rule (chunks of
@@ -289,30 +364,11 @@ def test_kimi_linear_s_two_kinds_of_layer_compile_under_their_scopes(
     forward and ONE backward at heads of 192 | 128, both under
     `kimi_linear/mla/attn`; every scope the benchmark reads the mixer by is in
     the compiled text, forward and backward."""
-    from benchmark import common
-    from model_zoo.transformer import kimi_linear
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
-    net = kimi_linear.custom_model(
-        num_hidden_layers=2, kda_layers="1", full_attn_layers="2", num_experts=8,
-        router_experts=256, vocab_size=512)
-    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
-    variables = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
-
-    def loss(params, state, tokens):
-        outputs = net.apply({"params": params, **state}, tokens)
-        return jnp.sum(kimi_linear.loss(tokens, outputs)["loss"])
-
-    params = variables.pop("params")
-    text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
+    text = kimi_program(one_chip, monkeypatch, compiled_texts)
     calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
     kinds = sorted(re.sub(r"\.\d+$", "", name) for name in calls)
     assert kinds == ["flash_attention_bwd", "flash_attention_fwd"]
-    scope_map = common.load_module("drivers", "resident_lm_share").scope_map
-    flops = common.load_module("flops", "kimi_linear")
-    found = scope_map(text, flops.SCOPES, flops.RAGGED_DOT_SCOPE)
+    found = scopes_of(text, "kimi_linear")
     assert {found.get(name) for name in calls} == {"kimi_linear/mla/attn"}
     assert set(found.values()) >= {
         f"kimi_linear/{part}" for part in (
@@ -325,3 +381,14 @@ def test_kimi_linear_s_two_kinds_of_layer_compile_under_their_scopes(
         "delta_rule_bwd", "delta_rule_fwd"]
     assert {found.get(name) for name in rule} == {"kimi_linear/kda/delta_rule"}
     assert not re.search(r"kda/delta_rule/[^\"]*while", text)
+
+
+def test_the_convolutions_of_a_checkpointed_kda_layer_are_the_kernels_under_their_scope(
+        one_chip, no_compile_cache, monkeypatch, compiled_texts):
+    """q's, k's and v's depthwise convolutions (16 384 x 4096, no bias)
+    compile as `causal_conv1d_fwd` twice each and `causal_conv1d_bwd` once
+    each, all under `kimi_linear/kda/conv`, where `kda_conv_gates_ms` reads
+    them."""
+    assert_the_convolutions_are_the_kernels(
+        kimi_program(one_chip, monkeypatch, compiled_texts), "kimi_linear",
+        "kimi_linear/kda/conv", 3)

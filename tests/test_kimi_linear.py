@@ -152,6 +152,40 @@ def test_the_counters_say_which_route_walked_the_chunks(monkeypatch, route):
 _LOSS_BY_ROUTE = {}
 
 
+# (interpret mode, tokens) -> the convolutions that took the kernels, the
+# chunks the delta rule's kernels walked (2 sequences x 2 heads x chunks of 16)
+CONV_ROUTES = {"plain": (False, 64, 0, 0), "kernel": (True, 64, 3, 2 * 2 * 4),
+               "ragged_tokens": (True, 40, 0, 2 * 2 * 3)}
+
+
+@pytest.mark.parametrize("route", sorted(CONV_ROUTES))
+def test_the_counter_says_which_route_the_convolutions_took(monkeypatch, route):
+    """`kda/kernel_convs` at planes of whole lanes (2 heads of 128): q's, k's
+    and v's of the one KDA layer where the Pallas kernels run (interpret mode
+    here) and the tokens are whole time blocks; none on the CPU's plain route,
+    and none at 40 tokens, where the delta rule still takes ITS kernels; the
+    step's loss the same by both routes."""
+    from elasticdl_tpu.ops import pallas_attention
+    interpret, seq, convs, chunks = CONV_ROUTES[route]
+    if interpret:
+        monkeypatch.setenv(pallas_attention._INTERPRET_ENV, "1")
+    wide = {"linear_num_heads": 2, "linear_head_dim": 128}
+    spec, trainer = lm.fresh_trainer(warmup_steps=1, **wide, **lm.short)
+    data = lm.batches(steps=1, seq=seq)[0]
+    state, logs = trainer.train_step(trainer.init_state(data), data)
+    kda = state.extra_vars["kda"]
+    assert int(kda["kernel_convs"]) == convs
+    assert int(kda["kernel_chunks"]) == chunks
+    if seq == 64:
+        _CONV_LOSS_BY_ROUTE[route] = float(logs["loss"])
+    if len(_CONV_LOSS_BY_ROUTE) == 2:
+        assert _CONV_LOSS_BY_ROUTE["kernel"] == pytest.approx(
+            _CONV_LOSS_BY_ROUTE["plain"], rel=1e-5)
+
+
+_CONV_LOSS_BY_ROUTE = {}
+
+
 def test_the_mixer_alone_matches_the_reference_s_token_by_token():
     m, cfg = zoo(), cfg_of()
     p, x = random_mixer()

@@ -348,6 +348,36 @@ def test_the_scan_s_route_follows_backend_and_shapes_alone(route_log, monkeypatc
         assert kernel in text, kernel
 
 
+# (interpret mode, tokens) -> the convolutions that took the kernels: the tiny
+# preset's xBC plane is one lane tile wide (64 + 2·2·16 channels)
+CONV_ROUTES = {"plain": (False, 64, 0), "kernel": (True, 64, 1), "ragged_tokens": (True, 36, 0)}
+_CONV_LOSS_BY_ROUTE = {}
+
+
+@pytest.mark.parametrize("route", sorted(CONV_ROUTES))
+def test_the_counter_says_which_route_the_convolution_took(monkeypatch, route_log, route):
+    """`router_state/kernel_convs`: one a Mamba layer and step where the Pallas
+    kernels run (interpret mode here) and the tokens are whole time blocks,
+    none on the CPU's plain route and none at 36 tokens; the log says which,
+    and the step's loss is the same by both routes."""
+    interpret, seq, convs = CONV_ROUTES[route]
+    if interpret:
+        interpret_kernels(monkeypatch)
+    ssm._log_conv_route.cache_clear()
+    spec, trainer = lm.fresh_trainer(**lm.short)
+    data = lm.batches(steps=1, seq=seq)[0]
+    state, logs = trainer.train_step(trainer.init_state(data), data)
+    assert int(state.extra_vars["router_state"]["kernel_convs"]) == convs
+    taken = "kernel" if convs else "plain"
+    assert f"causal convolution ({seq} tokens, 128 channels, 4 taps) takes the {taken} route" \
+        in route_log.text
+    if seq == 64:
+        _CONV_LOSS_BY_ROUTE[route] = float(logs["loss"])
+    if len(_CONV_LOSS_BY_ROUTE) == 2:
+        assert _CONV_LOSS_BY_ROUTE["kernel"] == pytest.approx(
+            _CONV_LOSS_BY_ROUTE["plain"], rel=1e-5)
+
+
 def test_causal_conv1d_by_hand():
     r = np.random.default_rng(1)
     x, w, b = r.normal(size=(2, 7, 3)), r.normal(size=(4, 3)), r.normal(size=(3,))
