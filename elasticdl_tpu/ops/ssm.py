@@ -1,13 +1,26 @@
-"""State-space (Mamba-2) mixer operations: the causal depthwise convolution,
-the chunked state-space scan (SSD: Dao & Gu, arXiv:2405.21060) and the gated
-group RMSNorm. Plain `jax.numpy` / `lax`, but for the scan and the
-convolution: on a TPU `ssd_chunked` runs as the Mosaic kernels of
-`ops/pallas_ssd.py` (`scan_route`) and `causal_conv1d` as those of
+"""State-space mixer operations: the causal depthwise convolution, the two
+scans and the gated group RMSNorm.
+
+- `ssd_chunked` — Mamba-2's scan (SSD: Dao & Gu, arXiv:2405.21060): ONE decay
+  a head, the state shared by a head's channels, computed chunk by chunk as
+  matrix products. `model_zoo/transformer/nemotron_h.py` takes it.
+- `selective_scan` — Mamba-1's (S6: Gu & Dao, arXiv:2312.00752, Algorithm 2):
+  a step Δ for every CHANNEL, so a decay for every (channel, state index) and
+  a state (channels, N) no two channels share; no chunk is a matrix product,
+  the recurrence is walked token by token with the state on the chip.
+  `model_zoo/transformer/phi4flash.py` takes it.
+- `causal_conv1d` — both mixers' depthwise convolution (and the delta rule's:
+  `model_zoo/transformer/kimi_linear.py`).
+
+Plain `jax.numpy` / `lax`, but for the scans and the convolution: on a TPU
+`ssd_chunked` runs as the Mosaic kernels of `ops/pallas_ssd.py`
+(`scan_route`), `selective_scan` as those of `ops/pallas_selective_scan.py`
+(`selective_scan_route`) and `causal_conv1d` as those of
 `ops/pallas_conv1d.py` (`conv_route`); their plain bodies are the path
 everywhere else and the tests' reference for the kernels.
 
-The recurrence, per head (P channels, N state columns; B and C shared by the
-heads of a group):
+The SSD recurrence, per head (P channels, N state columns; B and C shared by
+the heads of a group):
 
     S_t = a_t · S_{t-1} + Δ_t · x_t ⊗ B_t,   a_t = exp(Δ_t · A),   S_0 = 0
     y_t = S_t · C_t
@@ -29,6 +42,18 @@ heads). In the plain body they pass through HBM, and the body is a
 of that shape is kept between the passes; in the kernels a chunk's matrix is
 built and consumed in VMEM, forward and backward, and the backward first
 sweeps the chunks once more for the state each starts from.
+
+The selective scan, per channel e and state index n (B and C shared by every
+channel, everything float32):
+
+    S_t[e, n] = exp(Δ_t[e] · A[e, n]) · S_{t-1}[e, n] + Δ_t[e] x_t[e] · B_t[n]
+    y_t[e]    = Σ_n S_t[e, n] · C_t[n] + D[e] · x_t[e],         S_0 = 0
+
+(T, E, N) float32 is 2.68 GB at 8192 tokens, 5120 channels of 16: neither
+route keeps anything of that size. The plain body is a `lax.scan` over time
+inside `jax.checkpoint`ed blocks of `PLAIN_BLOCK` tokens (kept: a state a
+block; recomputed: a block's states, one block at a time); the kernels keep
+the state a time block of 128 tokens starts from.
 """
 
 from __future__ import annotations
@@ -39,7 +64,7 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 
-from elasticdl_tpu.ops import pallas_conv1d, pallas_ssd
+from elasticdl_tpu.ops import pallas_conv1d, pallas_selective_scan, pallas_ssd
 
 logger = logging.getLogger(__name__)
 
@@ -191,3 +216,72 @@ def _ssd_plain(x, dt, a, b, c, chunk, compute_dtype):
                             preferred_element_type=jnp.float32)
     y = y + from_start * jnp.exp(cum).reshape(bsz, nc, l, g, r, 1)
     return y.reshape(bsz, nc * l, h, p)[:, :t]
+
+
+# ------------------------------------------------------------------ #
+# The selective scan (S6)
+
+PLAIN_BLOCK = 64     # tokens of a checkpointed block of the plain body: ≈ √T at 4k–8k
+
+
+@lru_cache(maxsize=None)
+def _log_selective_scan_route(*said):
+    """Once a process for each shape and route, as `_log_conv_route`."""
+    logger.info(
+        "selective scan (%d tokens, %d channels of %d state indices) takes the %s "
+        "route (the Pallas kernels need a TPU or interpret mode: %s; channels whole "
+        "lanes, whole time blocks, state indices whole sublane tiles: %s)", *said)
+
+
+def selective_scan_route(x_shape, n: int) -> str:
+    """Which body a selective scan of x (B, T, E) with N state indices a
+    channel takes — "kernel" or "plain": a pure function of the shapes and of
+    whether the kernels can run here (a TPU, or interpret mode in the CPU
+    tests). Logged once for each answer."""
+    _, t, e = x_shape
+    runnable = pallas_ssd.runnable()      # the same answer for every kernel here
+    fits = pallas_selective_scan.blocks(t, e, n)
+    route = "kernel" if runnable and fits else "plain"
+    _log_selective_scan_route(t, e, n, route, runnable,
+                              f"blocks of {fits.time} x {fits.lanes}" if fits else "no")
+    return route
+
+
+def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+                   c: jax.Array, d: jax.Array) -> jax.Array:
+    """The selective scan: x (B, T, E); dt (B, T, E), already positive (Δ); a
+    (E, N) negative (A); b, c (B, T, N); d (E,). Returns y (B, T, E) float32,
+    `D·x` INCLUDED. One algorithm on two routes (`selective_scan_route`),
+    float32 on both; neither keeps anything of (T, E, N) size for its
+    backward."""
+    if selective_scan_route(x.shape, a.shape[1]) == "kernel":
+        return pallas_selective_scan.selective_scan_kernels(
+            x, dt, a, b, c, d, pallas_selective_scan.blocks(x.shape[1], x.shape[2], a.shape[1]))
+    return _selective_scan_plain(x, dt, a, b, c, d)
+
+
+def _selective_scan_plain(x, dt, a, b, c, d, block: int = PLAIN_BLOCK):
+    """`selective_scan` in `jax.numpy`: a `lax.scan` over time inside
+    checkpointed blocks of `block` tokens. A ragged tail is padded with Δ = 0,
+    which leaves the state as it is."""
+    bsz, t, e = x.shape
+    x, dt, a, b, c = (v.astype(jnp.float32) for v in (x, dt, a, b, c))
+    pad = -t % block
+    # time first, in blocks: (T/block, block, B, ·)
+    blocked = lambda v: jnp.moveaxis(
+        jnp.pad(v, ((0, 0), (0, pad), (0, 0))), 1, 0).reshape(-1, block, bsz, v.shape[-1])
+
+    def token(state, operands):
+        x_t, dt_t, b_t, c_t = operands                   # (B, E), (B, E), (B, N), (B, N)
+        state = (jnp.exp(dt_t[..., None] * a) * state
+                 + (dt_t * x_t)[..., None] * b_t[:, None, :])
+        return state, jnp.sum(state * c_t[:, None, :], axis=-1)
+
+    @jax.checkpoint
+    def tokens_of_a_block(state, operands):
+        return jax.lax.scan(token, state, operands)
+
+    _, y = jax.lax.scan(tokens_of_a_block, jnp.zeros((bsz,) + a.shape, jnp.float32),
+                        tuple(blocked(v) for v in (x, dt, b, c)))
+    y = jnp.moveaxis(y.reshape(-1, bsz, e), 0, 1)[:, :t]
+    return y + d.astype(jnp.float32) * x

@@ -392,3 +392,133 @@ def test_the_convolutions_of_a_checkpointed_kda_layer_are_the_kernels_under_thei
     assert_the_convolutions_are_the_kernels(
         kimi_program(one_chip, monkeypatch, compiled_texts), "kimi_linear",
         "kimi_linear/kda/conv", 3)
+
+
+# ------------------------------------------------------------------ #
+# phi-4-mini-flash.resident-8k: the selective scan, the flash kernels at q/k
+# heads of 64 against v heads of 128, full and under a window of 512
+
+
+def test_the_selective_scan_s_kernels_compile_for_a_v5e(one_chip, no_compile_cache):
+    """The cell's scan alone: 8192 tokens, 5120 channels of 16 state indices,
+    forward and backward — `selective_scan_fwd` and `selective_scan_bwd`, two
+    Mosaic calls, at the blocks the route gives there (128 tokens x 1024
+    channels: the backward's block of states is 8.4 MB of VMEM)."""
+    from elasticdl_tpu.ops import pallas_selective_scan
+
+    t, e, n = 8192, 5120, 16
+    plan = pallas_selective_scan.blocks(t, e, n)
+    assert plan == (128, 1024)
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def forward_and_backward(x, dt, a, b, c, d, dy):
+        y, vjp = jax.vjp(lambda *o: pallas_selective_scan.selective_scan_kernels(*o, plan),
+                         x, dt, a, b, c, d)
+        return y, vjp(dy)
+
+    text = jax.jit(forward_and_backward).lower(
+        shape(1, t, e), shape(1, t, e), shape(e, n), shape(1, t, n), shape(1, t, n), shape(e),
+        shape(1, t, e)).compile().as_text()
+    calls = re.findall(r"^\s*%\w*?(selective_scan_[a-z]*?)_*\.\d+ = .*tpu_custom_call", text, re.M)
+    assert sorted(calls) == ["selective_scan_bwd", "selective_scan_fwd"]
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "window_512"])
+@pytest.mark.parametrize("route", ["resident", "split"])
+def test_flash_kernels_at_heads_of_64_and_128_compile_for_a_v5e(
+        route, window, one_chip, no_compile_cache, monkeypatch):
+    """Differential attention's one call: 40 query heads over 20 key heads of
+    64 (half a lane tile, and the NARROWER width: the two-width plan was opened
+    with q/k the wider) against 20 value heads of 128, 8192 keys, causal and
+    under a window of 512 (half the block of 1024: a q block's band is the
+    diagonal block and the one before it). One forward and one backward kernel
+    with the head resident; on a chip of 32 MiB the streaming forward and the
+    split route's two kernels."""
+    from elasticdl_tpu.ops import pallas_attention
+
+    pallas_attention._make_flash.cache_clear()
+    if route == "split":
+        monkeypatch.setattr(pallas_attention, "_vmem_bytes", lambda: 32 << 20)
+    shape = lambda h, d: jax.ShapeDtypeStruct((1, 8192, h, d), jnp.bfloat16, sharding=one_chip)
+    q, k, v, do = shape(40, 64), shape(20, 64), shape(20, 128), shape(40, 128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_attention.can_flash(q.shape, k.shape, dtype=jnp.bfloat16, window=window)
+    assert pallas_attention._plan_blocks(q.shape, k.shape, None, None,
+                                         dtype=jnp.bfloat16) == (1024, 1024)
+    assert pallas_attention.bwd_route(8192, 64, jnp.bfloat16, 1024, 1024,
+                                      v_dim=128).route == route
+
+    def forward_and_backward(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: pallas_attention.flash_attention(
+            q, k, v, causal=True, window=window, interpret=False), q, k, v)
+        return out, vjp(do)
+
+    text = jax.jit(forward_and_backward).lower(q, k, v, do).compile().as_text()
+    calls = re.findall(r"^\s*%\w*?(flash_attention_[a-z_]*?)_*\.\d+ = .*tpu_custom_call", text, re.M)
+    prefix = "flash_attention_swa_" if window else "flash_attention_"
+    assert sorted(calls) == [prefix + part for part in (
+        ["bwd", "fwd"] if route == "resident" else ["bwd_dkv", "bwd_dq", "fwd"])]
+    pallas_attention._make_flash.cache_clear()
+
+
+def phi4flash_program(one_chip, monkeypatch, compiled_texts):
+    """The compiled text of phi-4-mini-flash.resident-8k's value and gradient
+    at its published widths and 8192 tokens — the six kept layers, one of each
+    kind and the two Mamba layers — through the zoo's own loss."""
+    from model_zoo.transformer import phi4flash
+
+    if "phi4flash" not in compiled_texts:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+        net = phi4flash.custom_model(num_hidden_layers=6, kept_layers="0,1,16,17,18,19",
+                                     vocab_size=512)
+        tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+        variables = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
+
+        def loss(params, state, tokens):
+            return jnp.sum(phi4flash.loss(tokens, net.apply({"params": params, **state}, tokens)))
+
+        params = variables.pop("params")
+        compiled_texts["phi4flash"] = jax.jit(jax.value_and_grad(loss)).lower(
+            params, variables, tokens).compile().as_text()
+    return compiled_texts["phi4flash"]
+
+
+def test_phi4flash_s_kinds_of_layer_compile_under_their_scopes(
+        one_chip, no_compile_cache, monkeypatch, compiled_texts):
+    """Every Mamba layer's scan is `selective_scan_fwd` twice (the layer is
+    recomputed) and `selective_scan_bwd` once, all under `phi4flash/mamba/scan`
+    where `sambay_scan_ms` reads them, and no loop is left of the recurrence;
+    the three attention layers are ONE flash forward each — the recomputed
+    layer keeps its residuals — and ONE backward, the sliding layer's the
+    windowed kernels, all under `phi4flash/diff_attn/flash`; every scope the
+    benchmark reads the model by is in the compiled text."""
+    text = phi4flash_program(one_chip, monkeypatch, compiled_texts)
+    found = scopes_of(text, "phi4flash")
+    names = lambda prefix: re.findall(rf"^\s*%?({prefix}[\w.]+) = ", text, re.M)
+    kinds = lambda calls: sorted(re.sub(r"\.\d+$", "", name) for name in calls)
+    scan = names("selective_scan_")
+    assert kinds(scan) == ["selective_scan_bwd"] * 2 + ["selective_scan_fwd"] * 4
+    assert {found.get(name) for name in scan} == {"phi4flash/mamba/scan"}
+    assert not re.search(r"mamba/scan/[^\"]*while", text)
+    flash = names("flash_attention_")
+    assert kinds(flash) == ["flash_attention_bwd"] * 2 + ["flash_attention_fwd"] * 2 + [
+        "flash_attention_swa_bwd", "flash_attention_swa_fwd"]
+    assert {found.get(name) for name in flash} == {"phi4flash/diff_attn/flash"}
+    assert set(found.values()) >= {
+        f"phi4flash/{part}" for part in (
+            "embed", "mamba/proj", "mamba/conv", "mamba/dt", "mamba/scan", "mamba/gate_out",
+            "gmu", "diff_attn/proj", "diff_attn/flash", "diff_attn/combine", "mlp", "norm",
+            "head_loss")}
+
+
+def test_the_convolutions_of_phi4flash_s_mamba_layers_are_the_kernels_under_their_scope(
+        one_chip, no_compile_cache, monkeypatch, compiled_texts):
+    """The two Mamba layers' depthwise convolutions (8192 x 5120, K = 4, a
+    bias) compile as `causal_conv1d_fwd` twice each and `causal_conv1d_bwd`
+    once each, all under `phi4flash/mamba/conv`."""
+    assert_the_convolutions_are_the_kernels(
+        phi4flash_program(one_chip, monkeypatch, compiled_texts), "phi4flash",
+        "phi4flash/mamba/conv", 2)
