@@ -536,7 +536,12 @@ def check_lookup(size):
     ids with the first device's all owned by shard 0, more than a bucket
     holds, which take the overflow branch. Which branch a step takes is
     `fullest > cap` by the code's own rule, recomputed here; the CPU tests
-    tie the rule to the branch that runs."""
+    tie the rule to the branch that runs. The routed schedule runs twice,
+    for the two branches of the owners' lookups (`gather_rows`): uniform
+    ids, more distinct a shard than the last of `distinct_caps` holds (the plain
+    gather), and a field's ids Zipf over its own rows, as the benchmark's
+    (each distinct row fetched once) — `distinct` is the most a shard's
+    stream holds, sentinel included, again recomputed here."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -557,6 +562,9 @@ def check_lookup(size):
     spread = rng.integers(0, rows, (batch, 26), dtype=np.int32)
     piled = spread.copy()
     piled[:size["batch"]] %= rows // 4
+    vocab = size["field_vocab"]
+    ranks = np.minimum(rng.zipf(1.1, (batch, 26)) - 1, vocab - 1)
+    skewed = (ranks * 40503 % vocab + np.arange(26) * vocab).astype(np.int32)
 
     def fn(table, ids, w):
         def total(t):
@@ -570,12 +578,23 @@ def check_lookup(size):
         table = jax.device_put(table_np, by_data)
         w = jax.device_put(w_np, by_data)
         step = jax.jit(fn)
-        for name, ids_np in (("routed", spread), ("overflow", piled)):
+        for name, ids_np in (("routed", spread), ("routed_skewed", skewed),
+                             ("overflow", piled)):
             fullest = max(int(np.bincount(src // (rows // 4)).max())
                           for src in ids_np.reshape(4, -1))
             if (fullest > cap) != (name == "overflow"):
                 raise RuntimeError(
                     f"{name}: fullest bucket {fullest}, cap {cap}")
+            # an owner's stream: the ids it owns and one sentinel
+            distinct = 1 + max(
+                np.unique(ids_np[ids_np // (rows // 4) == o]).size
+                for o in range(4))
+            room = embedding.distinct_caps(
+                ids_np.size if name == "overflow" else 4 * cap)[-1]
+            if name != "overflow" and (distinct <= room) != (
+                    name == "routed_skewed"):
+                raise RuntimeError(
+                    f"{name}: {distinct} distinct ids a shard, room {room}")
             got, grad = step(table, jax.device_put(ids_np, by_data), w)
             if not np.array_equal(np.asarray(got), table_np[ids_np]):
                 raise RuntimeError(f"{name}: looked-up rows differ")
@@ -585,7 +604,8 @@ def check_lookup(size):
             if err > PLACEMENT_TOL:
                 raise RuntimeError(
                     f"{name}: table gradient err {err:.3g} > {PLACEMENT_TOL}")
-            result[name] = {"fullest": fullest, "grad_err": err}
+            result[name] = {"fullest": fullest, "distinct": distinct,
+                            "distinct_cap": room, "grad_err": err}
     return result
 
 

@@ -16,8 +16,8 @@ cliff).
 Two serving modes, selected once per store (EDL_EMB_TIER_DEVICE
 overrides; default = device on TPU backends, host elsewhere):
 
-- **device**: shard rows live as jax Arrays; pull is the jitted fused
-  gather (ops/embedding.gather_rows) and push routes the dense delta
+- **device**: shard rows live as jax Arrays; pull is one jitted
+  `jnp.take` of the client's distinct ids and push routes the dense delta
   through `scatter_add_dense` — the pallas placement kernel's lane on
   real chips, where the dense-blocked formulation IS the fast path
   (BASELINE.md round-5). Request shapes are POW2-PADDED by the client
@@ -449,12 +449,13 @@ class EmbeddingShardStore:
             import jax
             import jax.numpy as jnp
 
-            from elasticdl_tpu.ops import embedding as emb_ops
-
             def f(tab, ids):
                 in_range = (ids >= 0) & (ids < tab.shape[0])
                 safe = jnp.where(in_range, ids, 0)
-                out = emb_ops.gather_rows(tab, safe)
+                # the client pulls distinct ids (`pull_unique`) and never
+                # differentiates: `gather_rows`' sort would find nothing
+                # to share and nobody to hand it to
+                out = jnp.take(tab, safe, axis=0)
                 return jnp.where(in_range[:, None], out, 0.0)
 
             return jax.jit(f)

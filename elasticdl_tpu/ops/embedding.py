@@ -66,9 +66,24 @@ logger = default_logger(__name__)
 
 @jax.custom_vjp
 def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
-    """`table[ids]`, with a backward built around the TPU's scatter-add.
+    """`table[ids]`, fetched a distinct id at a time, with a backward built
+    around the TPU's scatter-add.
 
-    Forward: `jnp.take(table, ids, axis=0)`.
+    Forward: the rows `jnp.take(table, ids, axis=0)` gives, to the bit.
+    A row out of a large table in HBM costs the same whatever its width
+    and however often the step has fetched it already, and under skewed
+    ids most of a step's ids are repeats (PERF.md §5). So where the
+    backward sorts the stream anyway (`backward_route` gives `kernel` or
+    `tiled`; a short stream or a small table is one `jnp.take`), that
+    sort is made HERE and handed to the backward as the residual; the
+    runs of the sorted ids are the distinct ids; they are fetched from the
+    table (`emb/fwd/gather`) into a buffer small enough for fast memory —
+    the least of `distinct_caps(n)`, an eighth or a quarter of the
+    stream, that holds them — and the batch's rows are a second gather
+    out of that buffer (`emb/fwd/expand`). A step whose distinct ids fit
+    neither — uniform ids — takes the one `jnp.take` instead
+    (`emb/fwd/overflow`, the last branch of the same `lax.switch`):
+    nothing is dropped or approximated, such a step only runs as it did.
 
     Backward: the dense (rows, D) sum of the cotangent rows at their ids,
     by the route `backward_route` picks from what the code can see — the
@@ -76,8 +91,8 @@ def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
     run (a TPU, or interpret mode in the CPU tests). No environment
     variable is read on the way.
 
-    - `kernel`: one stable sort of (ids, positions) (`_sorted_stream`),
-      then the Mosaic placement kernel (ops/pallas_scatter.py) one-hot
+    - `kernel`: the forward's stable sort of (ids, positions), then the
+      Mosaic placement kernel (ops/pallas_scatter.py) one-hot
       matmuls each output block's window of the sorted stream on the MXU.
       A `lax.cond` on the fullest window guards it: a stream whose
       duplicates overflow a window is first compacted to per-distinct-id
@@ -102,13 +117,16 @@ def gather_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
     What each piece costs on the chip, per benchmark cell: PERF.md §5; why
     it is built this way: PERF.md §6.
     """
-    with jax.named_scope("emb/fwd/gather"):
-        return jnp.take(table, ids, axis=0)
+    return _lookup(table, ids)[0]
 
 
 def _gather_rows_fwd(table, ids):
-    return gather_rows(table, ids), (
-        ids, jnp.empty((0,), table.dtype), table.shape[0],
+    out, sorted_ids = _lookup(table, ids)
+    # the flat backward scatters the ids as they came; the sorted routes
+    # read the forward's sort and nothing else of the ids
+    return out, (
+        ids if sorted_ids is None else None, sorted_ids,
+        jnp.empty((0,), table.dtype), table.shape[0],
     )
 
 
@@ -155,6 +173,112 @@ def backward_route(n: int, num_rows: int, kernel_runnable: bool) -> str:
         "XLA %s path", n, num_rows, kernel_runnable, 2 * bs,
         SORTED_MIN_IDS, est_w, KERNEL_MAX_WINDOW, route)
     return route
+
+
+# The deduped lookup's buffer holds one of these shares of a stream's ids,
+# in whole 512s: the least that holds the step's distinct ids. The cells'
+# steps hold 10.4% (xDeepFM), 18.8% (deepfm) and about 12% (a shard of
+# four) distinct ids. A smaller buffer is cheaper twice over — the table
+# is asked for fewer rows (an empty slot costs what a row does) and the
+# expansion reads a buffer that sits better in fast memory: at xDeepFM's
+# shape an eighth takes 2.8 + 3.9 ms where a quarter takes 9.9 + 8.9 and
+# a half 20.9 + 8.9, against 29.4 for the plain gather (PERF.md §6, PR
+# 60). A stream with more distinct ids than the last share holds takes
+# the plain gather.
+DISTINCT_SHARES = (0.125, 0.25)
+
+
+def distinct_caps(n: int) -> Tuple[int, ...]:
+    """The buffers the deduped lookup of `n` ids may take, in rows,
+    ascending. Static."""
+    return tuple(-(-int(share * n) // 512) * 512 for share in DISTINCT_SHARES)
+
+
+def _sort_ids(flat):
+    """`(sf, order)`: the ids ascending and their positions in `flat`,
+    duplicates in batch order — ONE stable sort of (ids, positions).
+
+    `argsort` IS that sort with its sorted ids thrown away; keeping them
+    saves the gather `flat[order]`, which cost more than the row gather
+    beside it (7.1 ns an id against 1.8-6.2 ns a row: 10.25 and 8.88 ms
+    of xDeepFM's step at 1 437 696 ids, my chip runs, PR 26)."""
+    return jax.lax.sort(
+        (flat, jnp.arange(flat.shape[0], dtype=jnp.int32)),
+        dimension=0, is_stable=True, num_keys=1)
+
+
+def _sorted_runs(sf_sorted):
+    """The runs of a SORTED id vector: `(seg, uids)`, both n long — the
+    index of slot i's run (compact: seg[0] = 0, steps of 0 or 1), and in
+    slot j the j-th distinct id, the slots after the last at int32max
+    where the stream's pad already is.
+
+    The j-th distinct id of a sorted vector is its j-th run start, so the
+    run starts, everything else sent to int32max, sorted once more (keys
+    only) ARE the compact ascending ids. No scatter over the stream: a
+    scatter-max (`segment_max`) costs 8.7 ns an id, 12.6 ms of xDeepFM's
+    step, where this sort takes 0.8 (PERF.md §6, PR 28)."""
+    is_start = jnp.concatenate(
+        [jnp.ones((1,), bool), sf_sorted[1:] != sf_sorted[:-1]])
+    seg = jnp.cumsum(is_start) - 1                     # compact, sorted
+    uids = jax.lax.sort(
+        jnp.where(is_start, sf_sorted, jnp.iinfo(sf_sorted.dtype).max),
+        is_stable=False)
+    return seg, uids
+
+
+def _lookup(table, ids):
+    """`gather_rows`' forward: `(table[ids], sorted_ids)`, where
+    `sorted_ids` is `_sort_ids`' pair if the stream was sorted on the way
+    (the deduped lookup) and None if it was not (one `jnp.take`)."""
+    from elasticdl_tpu.ops import pallas_scatter
+
+    n, num_rows = ids.size, table.shape[0]
+    if backward_route(n, num_rows, pallas_scatter.runnable()) == "flat":
+        with jax.named_scope("emb/fwd/gather"):
+            return jnp.take(table, ids, axis=0), None
+    caps = distinct_caps(n)
+    # trace-time, once per compiled program: which shapes dedupe
+    logger.info(
+        "embedding lookup (%d ids out of %d rows) fetches each distinct id "
+        "once: the least of %s rows from the table that holds them, "
+        "expanded to the %d; a step with more distinct ids takes the plain "
+        "gather", n, num_rows, caps, n)
+    # int32, as the backward's stream: see `_gather_rows_bwd`
+    flat = ids.reshape(-1).astype(jnp.int32)
+    with jax.named_scope("emb/fwd/sort"):
+        sf, order = _sort_ids(flat)
+        runs = 1 + jnp.sum(sf[1:] != sf[:-1], dtype=jnp.int32)
+
+    def deduped(cap):
+        def branch(table, flat, sf, order):
+            del flat
+            with jax.named_scope("emb/fwd/sort"):
+                seg, uids = _sorted_runs(sf)
+            with jax.named_scope("emb/fwd/gather"):
+                rows_u = jnp.take(table, uids[:cap], axis=0)
+            with jax.named_scope("emb/fwd/expand"):
+                # the run of each POSITION: a second sort, not a scatter
+                # (8 ns an id, PERF.md §6, PR 28)
+                _, seg_by_position = jax.lax.sort(
+                    (order, seg), is_stable=False, num_keys=1)
+                return rows_u.at[seg_by_position].get(
+                    mode="promise_in_bounds")
+
+        return branch
+
+    def overflow(table, flat, sf, order):
+        del sf, order
+        with jax.named_scope("emb/fwd/overflow"):
+            return jnp.take(table, flat, axis=0)
+
+    # straight-line branches under one switch, no collective in any: under
+    # `shard_map` each shard takes its own
+    too_small = jnp.sum(runs > jnp.asarray(caps, jnp.int32))
+    out = jax.lax.switch(
+        too_small, [deduped(cap) for cap in caps] + [overflow],
+        table, flat, sf, order)
+    return out.reshape(*ids.shape, table.shape[1]), (sf, order)
 
 
 def _tiled_table_grad(cf, sf, num_rows):
@@ -246,19 +370,9 @@ def _compact_sorted_duplicates(cf_sorted, sf_sorted):
     the last distinct id hold zero sums at uid = int32max, where the
     stream's pad already is.
 
-    The j-th distinct id of a sorted vector is its j-th run start, so the
-    run starts, everything else sent to int32max, sorted once more (keys
-    only) ARE the compact ascending ids. No scatter over the stream: a
-    scatter-max (`segment_max`) costs 8.7 ns an id, 12.6 ms of xDeepFM's
-    step, where this sort takes 0.8 (PERF.md §6, PR 28)."""
-    is_start = jnp.concatenate(
-        [jnp.ones((1,), bool), sf_sorted[1:] != sf_sorted[:-1]])
-    seg = jnp.cumsum(is_start) - 1                     # compact, sorted
-    sums = _run_sums(cf_sorted, seg)
-    uids = jax.lax.sort(
-        jnp.where(is_start, sf_sorted, jnp.iinfo(sf_sorted.dtype).max),
-        is_stable=False)
-    return sums, uids
+    The distinct ids are `_sorted_runs`'."""
+    seg, uids = _sorted_runs(sf_sorted)
+    return _run_sums(cf_sorted, seg), uids
 
 
 # What one row scatter's output may take of the v5e's fast memory. The
@@ -313,21 +427,18 @@ def _run_sums(cf_sorted, seg):
     return acc[:n]
 
 
-def _sorted_stream(flat, cf):
+def _sorted_stream(flat, cf, sorted_ids=None):
     """The backward's sorted stream `(cf_sorted, sf)`: the ids in ascending
     order and the cotangent rows in that order, duplicates in batch order.
+    `sorted_ids` is `_sort_ids(flat)` where the forward has made it (the
+    deduped lookup's residual); a stream that comes without one
+    (`scatter_add_dense`) is sorted here.
 
-    `argsort` IS a stable sort of (ids, positions) that throws its sorted
-    ids away; keeping them saves the gather `flat[order]`, which cost more
-    than the row gather beside it (7.1 ns an id against 1.8-6.2 ns a row:
-    10.25 and 8.88 ms of xDeepFM's step at 1 437 696 ids, my chip runs,
-    PR 26). Carrying the D columns through the sort as well was measured
-    and not kept: it runs within 1 ms a step of this on every table, and
-    a sort of D + 2 operands takes the compiler 200-300 s (PERF.md §6)."""
+    Carrying the D columns through the sort as well was measured and not
+    kept: it runs within 1 ms a step of this on every table, and a sort
+    of D + 2 operands takes the compiler 200-300 s (PERF.md §6)."""
     with jax.named_scope("emb/bwd/sort"):
-        sf, order = jax.lax.sort(
-            (flat, jnp.arange(flat.shape[0], dtype=jnp.int32)),
-            dimension=0, is_stable=True, num_keys=1)
+        sf, order = _sort_ids(flat) if sorted_ids is None else sorted_ids
         return cf[order], sf
 
 
@@ -467,23 +578,24 @@ def _pallas_table_grad(cf, sf, num_rows):
 def _gather_rows_bwd(res, ct):
     from elasticdl_tpu.ops import pallas_scatter
 
-    ids, proto, num_rows = res
+    ids, sorted_ids, proto, num_rows = res
+    cf = ct.reshape(-1, ct.shape[-1]).astype(jnp.float32)
+    n = cf.shape[0]
+    if n == 0:  # static: empty batch, zero gradient
+        return jnp.zeros((num_rows, ct.shape[-1]), proto.dtype), None
     # int32: the stream's pad and the dedupe path's empty slots are
     # int32max, and the kernel subtracts block bases from the ids; vocab
     # sizes are far below 2^31
-    flat = ids.reshape(-1).astype(jnp.int32)
-    cf = ct.reshape(-1, ct.shape[-1]).astype(jnp.float32)
-    if flat.shape[0] == 0:  # static: empty batch, zero gradient
-        return jnp.zeros((num_rows, ct.shape[-1]), proto.dtype), None
-    route = backward_route(
-        flat.shape[0], num_rows, pallas_scatter.runnable())
-    if route == "kernel":
-        d_table = _pallas_table_grad(*_sorted_stream(flat, cf), num_rows)
-    elif route == "tiled":
-        d_table = _tiled_table_grad(*_sorted_stream(flat, cf), num_rows)
-    else:
+    flat = None if ids is None else ids.reshape(-1).astype(jnp.int32)
+    route = backward_route(n, num_rows, pallas_scatter.runnable())
+    if route == "flat":
         d_table = jnp.zeros((num_rows, cf.shape[1]), jnp.float32).at[
             flat].add(cf, mode="drop")
+    else:
+        sorted_grad = (
+            _pallas_table_grad if route == "kernel" else _tiled_table_grad)
+        d_table = sorted_grad(
+            *_sorted_stream(flat, cf, sorted_ids), num_rows)
     return d_table.astype(proto.dtype), None
 
 
@@ -516,8 +628,9 @@ def scatter_add_dense(
     in_range = (ids >= 0) & (ids < num_rows)
     safe_ids = jnp.where(in_range, ids, oob)
     rows = jnp.where(in_range[:, None], rows, 0)
+    # no forward ran here: the stream is sorted by the backward itself
     d_table, _ = _gather_rows_bwd(
-        (safe_ids, jnp.empty((0,), dtype), num_rows), rows
+        (safe_ids, None, jnp.empty((0,), dtype), num_rows), rows
     )
     return d_table
 

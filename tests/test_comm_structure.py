@@ -87,26 +87,34 @@ def test_manual_embedding_backward_moves_no_table_sized_buffers(mesh8):
 
 
 def test_routed_embedding_exchanges_only_owned_ids_and_rows(mesh8):
-    """fwd+bwd of the manual lookup on a data-only mesh (data=4): ids and
-    rows cross the mesh all-to-all in buckets of `cap` — every collective
-    is <= n_shards * cap * D elements, UNDER the (B, L, D) block the
-    gathered schedule reduces — and a shard's table gather takes
-    n_shards * cap indices, a fraction of the global batch's B * L, which
-    only the overflow branch (`emb/route/overflow`) still gathers."""
+    """fwd+bwd of the manual lookup on a data-only mesh (data=4), at a
+    table and a batch past the sorted routes' gates (abstract values: the
+    program is compiled, nothing runs): ids and rows cross the mesh
+    all-to-all in buckets of `cap` — every collective is <= n_shards * cap
+    * D elements, UNDER the (B, L, D) block the gathered schedule reduces.
+    A shard's table gathers fetch a buffer of DISTINCT ids of its n_shards
+    * cap slots (`emb/fwd/gather`, one a buffer size); all of the slots'
+    rows come from the table only inside `emb/fwd/overflow`, and the global batch's B * L
+    only inside the routed schedule's own overflow branch
+    (`emb/route/overflow`). The stream of a shard's slots is sorted ONCE,
+    stably with its positions: the forward's sort is the backward's."""
     n_shards = 4
     mesh = build_mesh({"data": n_shards}, list(mesh8.devices.flat)[:n_shards])
-    V, D, B, L = emb.padded_vocab(4096), 16, 256, 64
+    V, D, B, L = n_shards * 33 * 8192, 16, 256, 64
     cap = emb.route_cap(B // n_shards * L, n_shards)
-    assert n_shards * cap < B * L
-    table = jnp.asarray(np.random.RandomState(0).randn(V, D).astype(np.float32))
-    ids = jnp.asarray(
-        np.random.RandomState(1).randint(0, V, (B, L)).astype(np.int32))
+    slots = n_shards * cap
+    assert slots < B * L
+    assert emb.backward_route(slots, V // n_shards, False) == "tiled"
+    distinct, distinct_all = emb.distinct_caps(slots), emb.distinct_caps(B * L)
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     with jax.set_mesh(mesh):
-        table_s = jax.device_put(table, NamedSharding(mesh, P(("data",), None)))
-        ids_s = jax.device_put(ids, NamedSharding(mesh, P("data", None)))
+        table_s = jax.ShapeDtypeStruct(
+            (V, D), jnp.float32,
+            sharding=NamedSharding(mesh, P(("data",), None)))
+        ids_s = jax.ShapeDtypeStruct(
+            (B, L), jnp.int32, sharding=NamedSharding(mesh, P("data", None)))
         f = jax.jit(jax.grad(
             lambda t, i: jnp.sum(emb.embedding_lookup(t, i, mode="manual") ** 2)
         ))
@@ -117,20 +125,45 @@ def test_routed_embedding_exchanges_only_owned_ids_and_rows(mesh8):
     # outside the overflow branch nothing is larger than the rows' exchange
     routed = collective_sizes("\n".join(
         line for line in txt.splitlines() if "emb/route/overflow" not in line))
-    assert max(n for _, n in routed) <= n_shards * cap * D, routed
-    assert n_shards * cap * D < B * L * D
+    assert max(n for _, n in routed) <= slots * D, routed
+    assert slots * D < B * L * D
     assert max(n for _, n in sizes) <= B * L * D, sizes
 
-    # the table gathers (`gather_rows`' scope), by how many rows they fetch
-    fetched = {}
+    # the forward's gathers, by how many rows they fetch: out of a shard's
+    # table (`gather_rows`' two table scopes) and out of the buffer
+    fetched, expanded = {}, {}
     for line in txt.splitlines():
         m = re.search(r"= f32\[([\d,]+)\]\S* gather\(", line)
-        if m and "emb/fwd/gather" in line:
-            rows = int(np.prod([int(x) for x in m.group(1).split(",")])) // D
+        if not m:
+            continue
+        rows = int(np.prod([int(x) for x in m.group(1).split(",")])) // D
+        if "emb/fwd/gather" in line or "emb/fwd/overflow" in line:
             fetched.setdefault(rows, []).append(line)
-    assert sorted(fetched) == [n_shards * cap, B * L], sorted(fetched)
-    assert all("emb/route/gather" in l for l in fetched[n_shards * cap])
-    assert all("emb/route/overflow" in l for l in fetched[B * L])
+        elif "emb/fwd/expand" in line:
+            expanded.setdefault(rows, []).append(line)
+    assert sorted(expanded) == [slots, B * L], sorted(expanded)
+    assert sorted(fetched) == sorted(
+        [*distinct, *distinct_all, slots, B * L]), sorted(fetched)
+    for rows in (*distinct, *distinct_all):     # each distinct id once
+        assert all("emb/fwd/gather" in l and "emb/fwd/overflow" not in l
+                   for l in fetched[rows])
+    for rows in (slots, B * L):                 # every slot: overflow only
+        assert all("emb/fwd/overflow" in l for l in fetched[rows])
+    for rows in (*distinct, slots):
+        assert all("emb/route/gather" in l for l in fetched[rows])
+    for rows in (*distinct_all, B * L):
+        assert all("emb/route/overflow" in l for l in fetched[rows])
+
+    # ONE stable sort of (ids, positions) a stream: the shared one
+    def stable_sorts(n):
+        return [l for l in txt.splitlines()
+                if " sort(" in l and "is_stable=true" in l
+                and "= (s32[%d]{0}, s32[%d]{0}) " % (n, n) in l]
+
+    assert len(stable_sorts(slots)) == 1, stable_sorts(slots)
+    assert "emb/route/gather" in stable_sorts(slots)[0]
+    assert len(stable_sorts(B * L)) == 1, stable_sorts(B * L)
+    assert "emb/route/overflow" in stable_sorts(B * L)[0]
 
 
 def test_ring_attention_backward_moves_only_kv_blocks(mesh8):

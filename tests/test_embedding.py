@@ -267,6 +267,176 @@ def test_sorted_stream_is_the_argsort_permutation(d, ids_kind):
     np.testing.assert_array_equal(np.asarray(cf_sorted), np.asarray(cf[order]))
 
 
+def _tier_cap(case, n):
+    return emb_ops.distinct_caps(n)[int(case[-1])]
+
+
+@contextlib.contextmanager
+def _spy_on_deduped_branches(monkeypatch):
+    """The buffers (in rows) of the deduped branches that RUN inside: a
+    branch is the one caller of `_sorted_runs` in the forward, and its
+    buffer is the next table gather traced."""
+    ran = []
+    sorted_runs, take = emb_ops._sorted_runs, jnp.take
+
+    def spy(sf):
+        seg, uids = sorted_runs(sf)
+
+        def spy_take(table, ids, axis):
+            rows = ids.shape[0]
+            jax.debug.callback(lambda: ran.append(rows))
+            monkeypatch.setattr(jnp, "take", take)
+            return take(table, ids, axis=axis)
+
+        monkeypatch.setattr(jnp, "take", spy_take)
+        return seg, uids
+
+    monkeypatch.setattr(emb_ops, "_sorted_runs", spy)
+    try:
+        yield ran
+    finally:
+        monkeypatch.setattr(emb_ops, "_sorted_runs", sorted_runs)
+        monkeypatch.setattr(jnp, "take", take)
+
+
+def _lookup_case(case, route):
+    """(rows, ids) of one stream of `gather_rows`' forward; `rows` is the
+    table's."""
+    r = np.random.RandomState(zlib.crc32(case.encode()))
+    rows, n = 4096, 8192
+    if case == "zipf":                   # few distinct ids: deduped
+        ids = np.minimum(r.zipf(1.1, n) - 1, rows - 1) * 977 % rows
+    elif case == "uniform":              # more distinct ids than the buffer
+        ids = r.randint(0, rows, n)
+    elif case == "all_equal":
+        ids = np.full(n, 7)
+    elif case == "both_sentinels":       # embedding_lookup's and a shard's
+        ids = np.minimum(r.zipf(1.1, n) - 1, rows - 1) * 977 % rows
+        ids = np.where(r.rand(n) < 0.2, np.iinfo(np.int32).max // 2, ids)
+        ids = np.where(r.rand(n) < 0.5, 2 * rows, ids)
+    elif case.startswith("at_cap_"):     # exactly a buffer's rows: fits
+        ids = np.resize(r.permutation(rows)[:_tier_cap(case, n)], n)
+    elif case.startswith("over_cap_"):   # one more: the next buffer
+        ids = np.resize(r.permutation(rows)[:_tier_cap(case, n) + 1], n)
+    else:
+        # the least stream that is sorted, and one id fewer; the gates as
+        # they are, so only where the route needs no small block
+        assert route == "tiled"
+        n = emb_ops.SORTED_MIN_IDS - (case == "below_sorted_min")
+        ids = np.minimum(r.zipf(1.1, n) - 1, rows - 1) * 977 % rows
+    return rows, r.permutation(ids).astype(np.int32)
+
+
+LOOKUP_CASES = [
+    (route, case) for route in ("kernel", "tiled")
+    for case in ("zipf", "uniform", "all_equal", "both_sentinels",
+                 "at_cap_0", "over_cap_0", "at_cap_1", "over_cap_1")
+] + [("tiled", "below_sorted_min"), ("tiled", "at_sorted_min")]
+
+
+@pytest.mark.parametrize("route,case", LOOKUP_CASES)
+def test_deduped_lookup_equals_take(monkeypatch, route, case):
+    """`gather_rows`' forward is `jnp.take`'s rows to the bit on every
+    branch of its `switch` — the deduped lookup with the least buffer of
+    `distinct_caps(n)` that holds the step's distinct ids, the plain gather
+    from one id over the last — and under it on a stream too short to
+    sort; the branch that ran is the one the distinct count calls for; the gradient, which reads the forward's
+    sort as its residual, is `jnp.take`'s VJP; and `scatter_add_dense`,
+    which sorts for itself, places the same stream the same way."""
+    rows, ids_np = _lookup_case(case, route)
+    n, d = ids_np.size, 11
+    r = np.random.RandomState(n)
+    t = jnp.asarray(r.randn(rows, d), jnp.float32)
+    w_np = r.randn(n, d).astype(np.float32)
+    ids = jnp.asarray(ids_np)
+    if case.endswith("sorted_min"):
+        monkeypatch.setattr(emb_ops, "TILE_ROWS", 16)
+        ctx = contextlib.nullcontext()
+        want_route = "flat" if case == "below_sorted_min" else "tiled"
+        assert emb_ops.backward_route(n, rows, False) == want_route
+    else:
+        ctx, want_route = _route(monkeypatch, route, n, rows), route
+
+    with ctx:
+        with _spy_on_deduped_branches(monkeypatch) as ran:
+            # a new function each case: `jit` keeps a trace, and its spy
+            out = jax.jit(lambda t, i: emb_ops.gather_rows(t, i))(t, ids)
+            jax.block_until_ready(out)
+            jax.effects_barrier()
+        sorted_ids = jax.eval_shape(emb_ops._lookup, t, ids)[1]
+        g = jax.jit(jax.grad(
+            lambda t: jnp.sum(emb_ops.gather_rows(t, ids) * w_np)))(t)
+        pushed = jax.jit(
+            lambda i, c: emb_ops.scatter_add_dense(i, c, rows))(ids, w_np)
+    assert (sorted_ids is not None) == (want_route != "flat")
+    holds = [cap for cap in emb_ops.distinct_caps(n)
+             if np.unique(ids_np).size <= cap][:1]
+    assert ran == (holds if want_route != "flat" else []), (case, ran)
+
+    # out-of-range ids read `jnp.take`'s fill, NaN, on every branch
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(jnp.take(t, ids, axis=0)))
+    g_take = jax.grad(lambda t: jnp.sum(jnp.take(t, ids, axis=0) * w_np))(t)
+    scale = np.abs(np.asarray(g_take)).max()
+    atol = 2e-5 if want_route == "kernel" else 1e-6
+    np.testing.assert_allclose(
+        np.asarray(g) / scale, np.asarray(g_take) / scale, atol=atol)
+    np.testing.assert_allclose(
+        np.asarray(pushed) / scale, np.asarray(g_take) / scale, atol=atol)
+
+
+@pytest.mark.parametrize("n,distinct,want", [
+    (55296 * 26, 149_228, (179_712, 359_424)),   # xdeepfm-criteo: an eighth
+    (8192 * 26, 40_111, (26_624, 53_248)),       # deepfm-criteo: a quarter
+    (4 * 79_872, 37_591, (39_936, 79_872)),      # criteo1tb, a shard's slots
+    (4096, None, (512, 1024)),
+    (4097, None, (512, 1024)),
+    (6000, None, (1024, 1536)),
+])
+def test_distinct_caps(n, distinct, want):
+    """An eighth and a quarter of the stream in whole 512s, from the
+    length alone; every cell's step (its distinct ids as the benchmark's
+    generator draws them, a count) fits one of them."""
+    assert emb_ops.distinct_caps(n) == want
+    assert distinct is None or distinct <= want[-1]
+
+
+def test_deduped_lookup_shares_one_sort_with_its_backward():
+    """At xDeepFM's shape (abstract values, nothing runs) a training step's
+    lookup and its gradient hold ONE stable sort of (ids, positions) — the
+    forward's, which the backward reads as its residual — beside the two
+    that are not of the ids: the runs of each position, keyed by position,
+    and the keys-only sort of the run starts (in each deduped branch of the
+    forward, once in the backward's dedupe branch). The table is gathered
+    from once a branch: a buffer's rows, or all n in the overflow's."""
+    from elasticdl_tpu.ops.pallas_attention import interpret_mode
+
+    n, rows, d = 55296 * 26, 2_605_056, 11
+    caps = emb_ops.distinct_caps(n)
+    with interpret_mode():
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda t, i, w: jnp.sum(emb_ops.gather_rows(t, i) * w)))(
+            jax.ShapeDtypeStruct((rows, d), jnp.float32),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n, d), jnp.float32))
+    eqns = list(_all_eqns(jaxpr.jaxpr))
+    sorts = sorted(
+        (len(e.invars), e.params["num_keys"], e.params["is_stable"])
+        for e in eqns if e.primitive.name == "sort")
+    assert sorts == (
+        [(1, 1, False)] * (len(caps) + 1) + [(2, 1, False)] * len(caps)
+        + [(2, 1, True)])
+    table_gathers = sorted(
+        e.outvars[0].aval.shape[0] for e in eqns
+        if e.primitive.name == "gather"
+        and e.invars[0].aval.shape == (rows, d))
+    assert table_gathers == [*caps, n]
+    for cap in caps:
+        expands = [e for e in eqns if e.primitive.name == "gather"
+                   and e.invars[0].aval.shape == (cap, d)]
+        assert [e.outvars[0].aval.shape for e in expands] == [(n, d)]
+
+
 def _all_eqns(jaxpr):
     for eqn in jaxpr.eqns:
         yield eqn
@@ -1067,6 +1237,57 @@ def test_routed_lookup_matches_dense(monkeypatch, mesh8, kind):
     np.testing.assert_array_equal(np.asarray(out), want)
     expected = np.zeros_like(table_np)
     np.add.at(expected, ids_np[valid], w_np[valid])
+    np.testing.assert_allclose(np.asarray(g), expected, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kind,deduped_shards", [
+    ("zipf", 4), ("uniform", 0), ("two_shards_uniform", 2)])
+def test_routed_lookup_dedupes_on_each_shard(
+        monkeypatch, mesh8, kind, deduped_shards):
+    """The routed lookup on four devices with the owners' gathers on the
+    deduped lookup (a table and a stream past the sorted routes' gates):
+    rows and table gradient equal the unsharded numpy result, and each
+    shard takes the branch ITS distinct ids call for — the branches hold
+    no collective, so two shards may fetch distinct rows while two take
+    the plain gather."""
+    from elasticdl_tpu.parallel.mesh import build_mesh
+
+    mesh = build_mesh({"data": 4}, list(mesh8.devices.flat)[:4])
+    B, L, V, D = 256, 16, 8192, 8
+    per = V // 4
+    r = np.random.RandomState(zlib.crc32(kind.encode()))
+    if kind == "zipf":
+        ids_np = np.minimum(r.zipf(1.1, (B, L)) - 1, V - 1) * 977 % V
+    else:
+        ids_np = r.randint(0, V, (B, L))
+        if kind == "two_shards_uniform":     # shards 2 and 3: four ids each
+            ids_np = np.where(ids_np < 2 * per, ids_np, ids_np // per * per
+                              + ids_np % 4)
+    ids_np = ids_np.astype(np.int32)
+    cap = emb_ops.route_cap(B // 4 * L, 4)
+    stream = 4 * cap
+    for shard in range(4):
+        mine = ids_np[ids_np // per == shard]
+        # the shard's stream: its ids and, in the empty slots, one sentinel
+        assert (np.unique(mine).size + 1
+                <= emb_ops.distinct_caps(stream)[-1]) == (
+            kind == "zipf" or (kind == "two_shards_uniform" and shard >= 2))
+    table_np, table = make_table(mesh, V=V, D=D, seed=61)
+    w_np = r.randn(B, L, D).astype(np.float32)
+    ids = jax.device_put(ids_np, NamedSharding(mesh, P("data", None)))
+
+    with _route(monkeypatch, "tiled", stream, per), jax.set_mesh(mesh):
+        with _spy_on_deduped_branches(monkeypatch) as ran:
+            out = jax.jit(lambda t: emb_ops.embedding_lookup(
+                t, ids, mode="manual"))(table)
+            jax.block_until_ready(out)
+            jax.effects_barrier()
+        g = jax.jit(jax.grad(lambda t: jnp.sum(
+            emb_ops.embedding_lookup(t, ids, mode="manual") * w_np)))(table)
+    assert len(ran) == deduped_shards, (kind, ran)
+    np.testing.assert_array_equal(np.asarray(out), table_np[ids_np])
+    expected = np.zeros_like(table_np)
+    np.add.at(expected, ids_np, w_np)
     np.testing.assert_allclose(np.asarray(g), expected, rtol=1e-5, atol=1e-5)
 
 
