@@ -29,7 +29,10 @@ child, not a CPU run).
               numpy on BOTH schedules: ids every shard owns its share of
               (ids and rows exchanged all-to-all) and ids one shard owns too
               many of (the overflow branch, the gathered schedule), the
-              placement kernel under `shard_map` in each. Several worker
+              placement kernel under `shard_map` in each; and of the job's
+              compiled step on data=4, how many table-sized instructions that
+              are not the lookup's own stand in the lookup's conditional
+              branches (`table_sized_ops_in_cond`: 0). Several worker
               processes on one host are reported as not brought up (ROADMAP
               A3), not attempted.
 
@@ -529,6 +532,76 @@ def check_shards(size):
     return {"shard_shape": list(shapes[0]), "bytes_in_use": in_use}
 
 
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\) -> .* \{$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\((.*)$")
+_HLO_CALLEES = re.compile(
+    r"(?:calls|to_apply|body|condition|true_computation|false_computation)"
+    r"=%([\w.\-]+)|branch_computations=\{([^}]*)\}")
+# they name or view an array that another instruction made
+_HLO_FREE = ("parameter", "get-tuple-element", "tuple", "bitcast")
+
+
+def hlo_computations(text):
+    """A compiled program's text (`exe.as_text()`) as {computation:
+    [instruction]}, an instruction a dict of its `name`, result `shape`
+    (layout and all, a tuple's in parentheses), `opcode`, `op_name` (the
+    scopes it was traced under, "" where it has none), the computations it
+    `calls` (a fusion's body, a conditional's branches, a loop's two)."""
+    computations, current = {}, None
+    for line in text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            current = computations.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            current = None
+        elif current is not None:
+            inst = _HLO_INSTRUCTION.match(line)
+            if not inst:
+                continue
+            name, shape, opcode, rest = inst.groups()
+            op_name = re.search(r'op_name="([^"]*)"', rest)
+            current.append({
+                "name": name, "shape": shape, "opcode": opcode,
+                "op_name": op_name.group(1) if op_name else "",
+                "calls": [c for one, group in _HLO_CALLEES.findall(rest)
+                          for c in ([one] if one else
+                                    re.findall(r"%([\w.\-]+)", group))]})
+    return computations
+
+
+def foreign_ops_in_lookup_branches(computations, shard_shape):
+    """Of a program's `hlo_computations`: the instructions of `shard_shape`
+    ("f32[425984,11]") that stand un-fused in a branch of the manual
+    lookup's conditionals — those traced under `shard_map/`, the
+    routed-or-overflow `cond` and `gather_rows`' guards inside it, and what
+    their branches call — and are NOT the lookup's own: their `op_name` does
+    not lie under `shard_map/`. What is the lookup's own there (the
+    conditionals' results, the placement kernel's call, the flat branch's
+    zeros and scatter) stays uncounted; an instruction XLA's conditional
+    code motion carried in from outside — the optimizer's g * g before
+    PR 61 — is a table-sized pass that fuses with nothing."""
+    branches, seen = [], set()
+    for insts in computations.values():
+        for inst in insts:
+            if inst["opcode"] == "conditional" and "shard_map/" in inst["op_name"]:
+                branches += inst["calls"]
+    found = []
+    while branches:
+        name = branches.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for inst in computations.get(name, ()):
+            if inst["opcode"] != "fusion":     # a fusion's body is fused
+                branches += inst["calls"]
+            if (inst["shape"].startswith(shard_shape)
+                    and inst["opcode"] not in _HLO_FREE
+                    and "shard_map/" not in inst["op_name"]):
+                found.append(inst)
+    return found
+
+
 def check_lookup(size):
     """Phase C: rows and table gradient of the manual lookup on data=4
     against numpy, once on each of its schedules (ops/embedding.py): ids
@@ -541,7 +614,12 @@ def check_lookup(size):
     ids, more distinct a shard than the last of `distinct_caps` holds (the plain
     gather), and a field's ids Zipf over its own rows, as the benchmark's
     (each distinct row fetched once) — `distinct` is the most a shard's
-    stream holds, sentinel included, again recomputed here."""
+    stream holds, sentinel included, again recomputed here.
+
+    Then a fact of the program a job runs on this mesh, compiled and not
+    run: in the zoo's DeepFM step, Adam and all, nothing table-sized that
+    is not the lookup's own stands in the lookup's conditional branches
+    (`table_sized_ops_in_cond`: 0; `foreign_ops_in_lookup_branches`)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -606,6 +684,18 @@ def check_lookup(size):
                     f"{name}: table gradient err {err:.3g} > {PLACEMENT_TOL}")
             result[name] = {"fullest": fullest, "distinct": distinct,
                             "distinct_cap": room, "grad_err": err}
+    trainer = _deepfm_trainer(size, mesh)
+    step_batch = _deepfm_batch(size, batch)
+    exe = trainer.aot_compile_train_step(
+        trainer.abstract_train_state(step_batch), step_batch, abstract=True)
+    foreign = foreign_ops_in_lookup_branches(
+        hlo_computations(exe.as_text()), f"f32[{rows // 4},{dim}]")
+    result["table_sized_ops_in_cond"] = len(foreign)
+    if foreign:
+        raise RuntimeError(
+            "table-sized instructions moved into the lookup's conditional "
+            f"branches: {[inst['name'] for inst in foreign]} "
+            "(ops/embedding.py::_fence_cotangent)")
     return result
 
 

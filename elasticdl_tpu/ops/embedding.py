@@ -803,6 +803,36 @@ def _unbucket_bwd(res, g):
 _unbucket.defvjp(_unbucket_fwd, _unbucket_bwd)
 
 
+@jax.custom_vjp
+def _fence_cotangent(table_shard):
+    """Identity on a table shard; its cotangent passes an
+    `optimization_barrier` on the way out of the manual lookup's backward.
+
+    The shard's gradient is the result of the backward's `lax.cond`s (the
+    schedule's routed-or-overflow, `gather_rows`' kernel-or-flat). XLA moves
+    an elementwise user of a conditional's result INTO its branches: without
+    the fence the optimizer's `g * g` (Adam's second moment) ran un-fused at
+    the end of each branch, a table-sized plane written there and read back
+    by the optimizer's pass as one operand more — 6.7 of the four-chip
+    cell's 43.7 ms a step (PERF.md §6, PR 61). Behind the barrier the
+    conditional's only user is the barrier, which no pass moves, and the
+    square fuses into the optimizer's pass as it does on one chip. The
+    barrier compiles to no instruction and no copy
+    (`tests/test_kernels_aot_mesh.py` reads the compiled text)."""
+    return table_shard
+
+
+def _fence_cotangent_fwd(table_shard):
+    return table_shard, None
+
+
+def _fence_cotangent_bwd(_, g):
+    return (jax.lax.optimization_barrier(g),)
+
+
+_fence_cotangent.defvjp(_fence_cotangent_fwd, _fence_cotangent_bwd)
+
+
 def embedding_lookup(
     table: jax.Array,
     ids: jax.Array,
@@ -924,8 +954,15 @@ def embedding_lookup(
 
     if route == "routed":
         cap = route_cap(n_local, n_shards)
+    schedule = routed_or_overflow if route == "routed" else gathered
+
+    def fenced(table_shard, ids_local):
+        # whichever schedule runs (and however the two are folded one day):
+        # the shard's gradient leaves it behind a barrier
+        return schedule(_fence_cotangent(table_shard), ids_local)
+
     out = jax.shard_map(
-        routed_or_overflow if route == "routed" else gathered,
+        fenced,
         in_specs=(P(axes, None), P(data_ax, None)),
         out_specs=P(data_ax, None, None),
     )(table, ids2d)

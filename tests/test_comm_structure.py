@@ -86,7 +86,7 @@ def test_manual_embedding_backward_moves_no_table_sized_buffers(mesh8):
     assert any(op.startswith("all-gather") for op, _ in sizes), sizes
 
 
-def test_routed_embedding_exchanges_only_owned_ids_and_rows(mesh8):
+def test_routed_embedding_exchanges_only_owned_ids_and_rows(mesh8, monkeypatch):
     """fwd+bwd of the manual lookup on a data-only mesh (data=4), at a
     table and a batch past the sorted routes' gates (abstract values: the
     program is compiled, nothing runs): ids and rows cross the mesh
@@ -97,7 +97,9 @@ def test_routed_embedding_exchanges_only_owned_ids_and_rows(mesh8):
     rows come from the table only inside `emb/fwd/overflow`, and the global batch's B * L
     only inside the routed schedule's own overflow branch
     (`emb/route/overflow`). The stream of a shard's slots is sorted ONCE,
-    stably with its positions: the forward's sort is the backward's."""
+    stably with its positions: the forward's sort is the backward's. The
+    fence on the shard's gradient (`_fence_cotangent`, PR 61) is free: the
+    same collectives and no table-sized `copy` more than without it."""
     n_shards = 4
     mesh = build_mesh({"data": n_shards}, list(mesh8.devices.flat)[:n_shards])
     V, D, B, L = n_shards * 33 * 8192, 16, 256, 64
@@ -115,13 +117,21 @@ def test_routed_embedding_exchanges_only_owned_ids_and_rows(mesh8):
             sharding=NamedSharding(mesh, P(("data",), None)))
         ids_s = jax.ShapeDtypeStruct(
             (B, L), jnp.int32, sharding=NamedSharding(mesh, P("data", None)))
-        f = jax.jit(jax.grad(
-            lambda t, i: jnp.sum(emb.embedding_lookup(t, i, mode="manual") ** 2)
-        ))
-        txt = f.lower(table_s, ids_s).compile().as_text()
+        def compiled_text():
+            # a new function each call: `jit` keeps a trace, and its fence
+            return jax.jit(jax.grad(lambda t, i: jnp.sum(
+                emb.embedding_lookup(t, i, mode="manual") ** 2
+            ))).lower(table_s, ids_s).compile().as_text()
+
+        txt = compiled_text()
+        monkeypatch.setattr(emb, "_fence_cotangent", lambda t: t)
+        unfenced = compiled_text()
 
     sizes = collective_sizes(txt)
     assert any(op.startswith("all-to-all") for op, _ in sizes), sizes
+    assert sizes == collective_sizes(unfenced)
+    shard_copy = "= f32[%d,%d]{1,0} copy(" % (V // n_shards, D)
+    assert txt.count(shard_copy) == unfenced.count(shard_copy)
     # outside the overflow branch nothing is larger than the rows' exchange
     routed = collective_sizes("\n".join(
         line for line in txt.splitlines() if "emb/route/overflow" not in line))

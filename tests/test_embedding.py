@@ -1291,6 +1291,99 @@ def test_routed_lookup_dedupes_on_each_shard(
     np.testing.assert_allclose(np.asarray(g), expected, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("kind", ["uniform", "zipf", "piled", "gathered"])
+def test_fenced_lookup_is_the_unfenced_reference_to_the_bit(
+        monkeypatch, mesh8, mesh_4x2, kind):
+    """`_fence_cotangent` (PR 61) is an identity whose backward is a
+    barrier: rows and table gradient of the manual lookup equal `jnp.take`'s
+    and its own VJP's BIT FOR BIT on the routed schedule's three id mixes —
+    uniform (the owners' plain gather), Zipf (each distinct row once), the
+    first source's ids piled on shard 0 (the overflow branch) — and on the
+    gathered schedule of a data x model mesh; and the lookup with the fence
+    taken out gives the same bits under cotangents of any value. The
+    reference's cotangents are whole numbers: every sum of them is exact
+    in float32 whatever its order, which the two scatters do not share."""
+    from elasticdl_tpu.parallel.mesh import build_mesh
+
+    mesh = (mesh_4x2 if kind == "gathered"
+            else build_mesh({"data": 4}, list(mesh8.devices.flat)[:4]))
+    shards = mesh.devices.size
+    B, L, V, D = 256, 16, 8192, 8
+    per = V // shards
+    r = np.random.RandomState(zlib.crc32(f"fenced {kind}".encode()))
+    if kind == "zipf":      # a field's ids Zipf over its own rows
+        ranks = np.minimum(r.zipf(1.5, (B, L)) - 1, V // L - 1)
+        ids_np = ranks * 40503 % (V // L) + np.arange(L) * (V // L)
+    else:
+        ids_np = r.randint(0, V, (B, L))
+        if kind == "piled":
+            ids_np[:B // 4] %= per
+    ids_np = ids_np.astype(np.int32)
+    if kind != "gathered":
+        cap = emb_ops.route_cap(B // 4 * L, 4)
+        fullest = max(np.bincount(src // per, minlength=4).max()
+                      for src in ids_np.reshape(4, -1))
+        assert (fullest > cap) == (kind == "piled")
+        fits = [np.unique(ids_np[ids_np // per == o]).size + 1
+                <= emb_ops.distinct_caps(4 * cap)[-1] for o in range(4)]
+        assert all(fits) if kind == "zipf" else not any(fits)
+    stream = B * L if kind in ("piled", "gathered") else 4 * cap
+    table_np, table = make_table(mesh, V=V, D=D, seed=71)
+    whole = r.randint(-8, 9, (B, L, D)).astype(np.float32)
+    any_value = r.randn(B, L, D).astype(np.float32)
+    ids = jax.device_put(ids_np, NamedSharding(mesh, P("data", None)))
+
+    def rows_and_grads(lookup):
+        # a new function each call: `jit` keeps a trace, and its fence
+        return jax.jit(lambda t: (lookup(t), *(
+            jax.grad(lambda t: jnp.sum(lookup(t) * w))(t)
+            for w in (whole, any_value))))(table)
+
+    manual = lambda t: emb_ops.embedding_lookup(t, ids, mode="manual")
+    with _route(monkeypatch, "tiled", stream, per), jax.set_mesh(mesh):
+        out, g_whole, g_any = rows_and_grads(manual)
+        ref_out, ref_whole, _ = rows_and_grads(
+            lambda t: jnp.take(t, ids, axis=0))
+        monkeypatch.setattr(emb_ops, "_fence_cotangent", lambda t: t)
+        plain_out, plain_whole, plain_any = rows_and_grads(manual)
+    np.testing.assert_array_equal(np.asarray(out), table_np[ids_np])
+    for got, want in ((out, ref_out), (g_whole, ref_whole),
+                      (out, plain_out), (g_whole, plain_whole),
+                      (g_any, plain_any)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("mesh_name,barriers", [
+    ("mesh8", 1), ("mesh_4x2", 1), ("one", 0)])
+def test_the_table_s_cotangent_leaves_the_manual_lookup_behind_one_barrier(
+        mesh_name, barriers, request):
+    """One `optimization_barrier` in the backward of either schedule, on a
+    value of the table shard's shape and outside every `cond` — so nothing
+    that reads the gradient can be moved into the conditionals' branches —
+    and none on one device, whose route calls `gather_rows` directly."""
+    from elasticdl_tpu.parallel.mesh import build_mesh
+
+    mesh = (build_mesh({"data": 1}, jax.devices()[:1]) if mesh_name == "one"
+            else request.getfixturevalue(mesh_name))
+    V, D = 512, 8
+    with jax.set_mesh(mesh):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda t, i: jnp.sum(
+            emb_ops.embedding_lookup(t, i, mode="manual"))))(
+            jax.ShapeDtypeStruct((V, D), jnp.float32),
+            jax.ShapeDtypeStruct((16, 5), jnp.int32))
+
+    def fences(jaxpr, in_cond=False):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "optimization_barrier":
+                yield in_cond, [v.aval.shape for v in eqn.outvars]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from fences(
+                    sub, in_cond or eqn.primitive.name == "cond")
+
+    assert list(fences(jaxpr.jaxpr)) == [
+        (False, [(V // mesh.devices.size, D)])] * barriers
+
+
 def test_owner_plan_and_unbucket_hold_no_scatter():
     """At the four-chip cell's shape (212 992 local ids, 4 shards, buckets
     of 79 872, 11 columns; abstract values, nothing runs) the plan is two
