@@ -160,16 +160,19 @@ def topk_route(logits: jax.Array, k: int):
     return probs, weights, expert_idx
 
 
-def sigmoid_topk_route(logits: jax.Array, bias: jax.Array, k: int, scale: float):
+def sigmoid_topk_route(logits: jax.Array, bias: jax.Array, k: int, scale: float,
+                       eps: float = 1e-20):
     """Sigmoid router over float32 logits (N, E): (scores (N, E), weights
     (N, k), expert_idx (N, k)). The k experts with the largest `score + bias`
     are chosen — the bias selects and does not weigh — and their weights are
     the scores renormalised to sum to one, times `scale`
-    (`norm_topk_prob: true`, `routed_scaling_factor`)."""
+    (`norm_topk_prob: true`, `routed_scaling_factor`). `eps` is what the
+    renormaliser adds to the chosen scores' sum: 1e-20 in DeepSeek-V3's
+    family, 1e-6 in `lfm2_moe`'s."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, expert_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(scores, expert_idx, axis=-1)
-    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps)
     return scores, weights, expert_idx
 
 

@@ -90,8 +90,9 @@ and dk D wide; the softmax scale is D^-1/2. Every BlockSpec, scratch shape and
 both plans take the two apart, and nothing is padded: a v widened to D would
 spend D/Dv of the p·v matmuls, of `out` and of the kept residuals. With
 Dv == D the calls, grids, blocks and plans are what they were. A width over
-the lane tile that is no multiple of it (192) is a full-dimension block, which
-Mosaic lays out in whole tiles: the plans count it so (`_in_vmem`).
+the lane tile that is no multiple of it (192), or under it (64), is a
+full-dimension block, which Mosaic lays out in whole tiles: the plans count it
+so (`_in_vmem`).
 
 Grouped-query attention: k and v may carry FEWER heads than q (B, T, Hkv, D
 with H a multiple of Hkv); query head h reads key-value head h // (H/Hkv)
@@ -633,10 +634,12 @@ def _plan(part, other, what, need, t_k, head_dim, v_dim, dtype_name, bq, bk, vme
 
 
 def _in_vmem(dim: int) -> int:
-    """The lanes a minor dimension of `dim` takes in VMEM where it is wider
-    than the lane tile and no multiple of it (q and k heads of 192: two whole
-    tiles); a narrower one is counted as it is."""
-    return dim if dim <= _LANE else -(-dim // _LANE) * _LANE
+    """The lanes a minor dimension of `dim` takes in VMEM: whole lane tiles,
+    whether it is wider than one and no multiple of it (q and k heads of 192:
+    two) or narrower (a head of 64: one, half of it padding — counted "as it
+    is" until PR 62, when the compiler refused a resident backward at 32 768
+    keys of 64 that the plan had put at 76.0 MB: it takes 99.5)."""
+    return -(-dim // _LANE) * _LANE
 
 
 @functools.lru_cache(maxsize=None)

@@ -118,6 +118,23 @@ def _causal_conv1d_plain(x, weight, bias=None):
     return y
 
 
+def gated_short_conv(bgu: jax.Array, weight: jax.Array) -> jax.Array:
+    """The double-gated short convolution of `lfm2`'s mixers on the three
+    column blocks of ONE projection: bgu (B, T, 3C) = [B | G | u] float32,
+    weight (K, C) -> G ⊙ conv_K(B ⊙ u) (B, T, C) float32, no bias, no
+    activation. The convolution is `causal_conv1d` (and so on `conv_route`'s
+    route); the two products are XLA's, each under a scope of its own so that
+    a trace prices the three parts apart."""
+    c = weight.shape[1]
+    bgu = bgu.astype(jnp.float32)
+    with jax.named_scope("gate_in"):
+        v = bgu[..., :c] * bgu[..., 2 * c:]
+    with jax.named_scope("conv"):
+        y = causal_conv1d(v, weight)
+    with jax.named_scope("gate_out"):
+        return bgu[..., c:2 * c] * y
+
+
 def gated_group_rmsnorm(y: jax.Array, z: jax.Array, weight: jax.Array,
                         groups: int, eps: float) -> jax.Array:
     """rmsnorm_grouped(y · silu(z)) · weight: the RMS is taken over each of

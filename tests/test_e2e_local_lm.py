@@ -395,3 +395,61 @@ def test_local_phi4flash_job_end_to_end(tmp_path):
     assert float(restored.extra_vars["s6"]["scan_elements"]) == 2 * 4 * 32 * 128 * 16 * int(
         restored.step)
     assert int(restored.extra_vars["memory"]["reads"]) == int(restored.step)
+
+
+def test_local_lfm2_moe_job_end_to_end(tmp_path):
+    """LFM2-MoE's three kinds of layer (a dense convolution layer, a sparse
+    attention layer, a sparse convolution layer by PUBLISHED index; 2 of 8
+    sigmoid-routed experts held; one matrix embedding and head) through the
+    same master/worker path, evaluation included, and a checkpoint saved by
+    the worker and restored here."""
+    import jax
+    import numpy as np
+
+    from elasticdl_tpu.parallel.mesh import build_mesh
+    from elasticdl_tpu.training.checkpoint import CheckpointManager
+    from elasticdl_tpu.training.model_spec import ModelSpec
+    from elasticdl_tpu.training.trainer import Trainer
+
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.lfm2_moe.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 3,
+            "kept_layers": "0,2,3", "layer_types": "conv,conv,full_attention,conv",
+            "intermediate_size": 96, "num_attention_heads": 4, "num_key_value_heads": 2,
+            "num_experts": 2, "router_experts": 8, "first_expert": 2,
+            "num_experts_per_tok": 2, "moe_intermediate_size": 24,
+            "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+        checkpoint_dir=str(tmp_path / "ckpt"),
+        checkpoint_steps=16,
+    )
+    master, _, counts = run_job(cfg, tmp_path, master_of=patient_master)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    results = master.evaluation.latest_results()
+    assert 0.0 <= results["token_accuracy"] <= 1.0
+    assert master.servicer.mean_training_loss() < 6.0       # ln 256 = 5.5, no auxiliary term
+
+    trainer = Trainer(ModelSpec.from_config(cfg), build_mesh(devices=jax.devices()[:1]))
+    example = {"features": np.zeros((4, 32), np.int32), "labels": np.zeros((4, 32), np.int32),
+               "mask": np.ones((4,), np.float32)}
+    checkpoints = CheckpointManager(str(tmp_path / "ckpt"))
+    restored = checkpoints.restore(trainer.abstract_train_state(example))
+    checkpoints.close()
+    assert int(restored.step) == checkpoints.last_restored_step >= 16
+    assert restored.params["conv_in"].shape == (2, 64, 192)
+    assert restored.params["conv_w"].shape == (2, 3, 64)
+    assert restored.params["wq"].shape == (1, 64, 64)
+    assert restored.params["w_gate"].shape == (2, 2, 64, 24)
+    assert "head" not in restored.params                    # the embedding is the head
+    bias = restored.extra_vars["router_state"]["expert_bias"]
+    assert bias.shape == (2, 8) and float(np.max(np.abs(bias))) > 0
+    # no kernel on the CPU: every convolution took the plain route
+    assert int(restored.extra_vars["conv"]["kernel_convs"]) == 0

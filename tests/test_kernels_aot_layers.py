@@ -522,3 +522,55 @@ def test_the_convolutions_of_phi4flash_s_mamba_layers_are_the_kernels_under_thei
     assert_the_convolutions_are_the_kernels(
         phi4flash_program(one_chip, monkeypatch, compiled_texts), "phi4flash",
         "phi4flash/mamba/conv", 2)
+
+
+def test_lfm2_s_attention_layer_holds_one_flash_forward_and_its_convolution_layer_the_kernels(
+        one_chip, no_compile_cache, monkeypatch):
+    """lfm2-8b-a1b.resident-32k at its published widths — published layers 2
+    and 3, a sparse attention layer and a sparse convolution layer, 32 768
+    tokens, 32 query heads on 8 key-value heads, q, k, v and the output all at
+    a head of 64, 8 of 32 experts held — through the zoo's own loss (the head
+    in row blocks). The attention layer keeps its flash residuals: ONE
+    `flash_attention_fwd`; its backward takes the SPLIT route (`bwd_dq` +
+    `bwd_dkv`: at 64 lanes padded to 128 a head's k, v, dk and dv do not fit
+    the resident kernel's VMEM at 32 768 keys, and the plan says so), all
+    under `lfm2/attn/attn`. The convolution layer's K = 3 convolution is
+    `causal_conv1d_fwd` twice and `causal_conv1d_bwd` once under
+    `lfm2/conv/conv`, between two XLA products under `gate_in` and
+    `gate_out`."""
+    from benchmark import common
+    from elasticdl_tpu.ops import pallas_attention
+    from model_zoo.transformer import lfm2_moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+    blocks = (pallas_attention.DEFAULT_BLOCK_Q, pallas_attention.DEFAULT_BLOCK_K)
+    assert pallas_attention.fwd_route(32768, 64, jnp.bfloat16, *blocks).route == "resident"
+    assert pallas_attention.bwd_route(32768, 64, jnp.bfloat16, *blocks).route == "split"
+    assert pallas_attention.bwd_route(16384, 64, jnp.bfloat16, *blocks).route == "resident"
+    net = lfm2_moe.custom_model(num_hidden_layers=2, kept_layers="2,3", num_experts=8,
+                                router_experts=32, vocab_size=512)
+    assert [net.cfg.kind(l) for l in net.cfg.layers] == ["full_attention", "conv"]
+    assert (net.cfg.num_attention_heads, net.cfg.num_key_value_heads, net.cfg.head_dim) == (
+        32, 8, 64)
+    tokens = jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=one_chip)
+    variables = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
+
+    def loss(params, state, tokens):
+        outputs = net.apply({"params": params, **state}, tokens)
+        return jnp.sum(lfm2_moe.loss(tokens, outputs)["loss"])
+
+    params = variables.pop("params")
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
+    calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
+    kinds = sorted(re.sub(r"\.\d+$", "", name) for name in calls)
+    assert kinds == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq", "flash_attention_fwd"]
+    found = scopes_of(text, "lfm2_moe")
+    assert {found.get(name) for name in calls} == {"lfm2/attn/attn"}
+    assert_the_convolutions_are_the_kernels(text, "lfm2_moe", "lfm2/conv/conv", 1)
+    assert set(found.values()) >= {
+        "lfm2/embed", "lfm2/head_loss", "lfm2/moe/router", "lfm2/moe/experts"} | {
+        f"lfm2/conv/{part}" for part in ("in_proj", "gate_in", "conv", "gate_out", "out_proj")} | {
+        f"lfm2/attn/{part}" for part in ("qkv", "qk_norm", "rope", "attn", "out")}
+    assert_the_first_pass_reads_under_the_pass_s_own_scopes(text, "lfm2_moe")

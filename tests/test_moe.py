@@ -286,6 +286,27 @@ def test_sigmoid_topk_route_by_hand():
     assert sorted(np.asarray(idx[1]).tolist()) == [1, 3]
 
 
+@pytest.mark.parametrize("eps", [None, 1e-20, 1e-6])
+def test_sigmoid_topk_route_s_renormaliser_is_an_argument(eps):
+    """`eps` is what the chosen scores' sum is raised by: left out it is the
+    1e-20 its callers had — the same weights to the last bit — and 1e-6
+    (`lfm2_moe.py`) lowers every weight by that over the sum, no choice."""
+    logits = jnp.asarray(np.random.default_rng(0).normal(size=(64, 8)), jnp.float32)
+    bias = jnp.asarray(np.random.default_rng(1).normal(size=(8,)) * 0.1, jnp.float32)
+    given = {} if eps is None else {"eps": eps}
+    scores, weights, idx = moe_ops.sigmoid_topk_route(logits, bias, 3, 1.0, **given)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    total = jnp.sum(chosen, axis=-1, keepdims=True)
+    np.testing.assert_array_equal(weights, 1.0 * chosen / (total + (eps or 1e-20)))
+    _, plain, plain_idx = moe_ops.sigmoid_topk_route(logits, bias, 3, 1.0)
+    np.testing.assert_array_equal(idx, plain_idx)
+    if eps == 1e-6:
+        rel = np.asarray((plain - weights) / plain)
+        assert np.all(rel >= 0) and 1e-7 < rel.max() < 2e-6
+    else:
+        np.testing.assert_array_equal(weights, plain)
+
+
 def relu2_loop(x, expert_idx, weights, w_up, w_down, held):
     """Every held expert on every token, a mask on its output."""
     first, count = held

@@ -119,8 +119,8 @@ def test_the_plans_count_both_widths_and_say_so(bwd_log):  # noqa: F811
     bwd = pa._bwd_plan(4096, 192, "bfloat16", 1024, 512, vmem, False, 128)
     lines = list({id(r): r.getMessage() for r in bwd_log.records}.values())
     assert [line.count("head 192 | v 128") for line in lines] == [1, 1]
-    # a width over the lane tile takes whole tiles: 192 is counted as 256
-    assert pa._in_vmem(192) == 256 and pa._in_vmem(128) == 128 and pa._in_vmem(64) == 64
+    # a width takes whole lane tiles: 192 is counted as 256, 64 as 128
+    assert pa._in_vmem(192) == 256 and pa._in_vmem(128) == 128 and pa._in_vmem(64) == 128
     assert fwd.route == bwd.route == "resident"
     # narrower than a head of 256 everywhere, wider than one of 128
     wide, narrow = (pa._fwd_plan(4096, d, "bfloat16", 1024, 1024, vmem) for d in (256, 128))
