@@ -42,13 +42,29 @@ default 16 MB and each recomputes s and dp) run seven, a second exp and a
 second fetch of q, k, v, do, o. The operands' dtypes and the order of every
 sum are the split route's — dq over kv blocks ascending, dk and dv over the
 group's heads, then q blocks — so the two routes agree to the bit, on the CPU
-and on a v5e (PERF.md section 6, PR 37). A bfloat16 head of 128 fits the
-resident backward up to 16 384 keys, a head of 256 up to 8192.
+and on a v5e (PERF.md section 6, PR 37).
+
+What the resident backward holds for a head is its whole k and v, the dk and
+dv blocks it writes and their float32 accumulators, and the pipeline gives
+every blocked operand TWO buffers — also the four whole-head blocks, whose
+index changes once a head, so that the second buffer buys the overlap of one
+fetch and one write-back a head (8 MB each way at 32 768 keys: ≈ 10 µs each)
+and costs as much VMEM as the first. With two, a bfloat16 head of 128 — or of
+64, which takes a whole lane tile (`_in_vmem`) — fits a v5e up to 16 384 keys,
+a head of 256 up to 8192. Where two do not fit, the plan prices the same
+kernel with ONE buffer for each of the four (`pl.Buffered(1)` on their
+BlockSpecs; `Plan.buffers`), which takes it to 32 768 keys of 128 or 64 and
+16 384 of 256 (PERF.md section 6, PR 63: 7.5 µs a (q block, kv block) pair at
+32 768 keys of 64 where the split route takes 13, dq, dk and dv the same in
+every element); 65 536 keys of 128 fit neither way and take the split route. A
+shape that fits with two is planned, traced and compiled as it was before one
+was an answer.
 
 `fwd_route` and `bwd_route` decide from the keys, the head, the dtype, whether
-the call has a data mask and the chip's VMEM alone and log their answer once a
-shape. The forward's kernels and the resident backward pass `vmem_limit_bytes`
-(three quarters of the chip's); the split kernels pass none. The forward's and
+the call has a data mask and the chip's VMEM alone and log their answer — the
+route, the buffers, the bytes — once a shape. The forward's kernels and the
+resident backward pass `vmem_limit_bytes` (three quarters of the chip's); the
+split kernels pass none. The forward's and
 the backward's blocks are planned apart (`_plan_blocks`): the residuals are q,
 k, v, out and the logsumexp whatever blocks made them.
 
@@ -151,8 +167,8 @@ values it visited, not zero. The kernels carry names of their own
 (`flash_attention_sel_fwd`, `_sel_bwd`; split: `_sel_bwd_dq`, `_sel_bwd_dkv`).
 The strip costs the resident backward 2·bq·Tk bytes of VMEM, so the backward
 of a `keep` call plans q blocks of `SEL_BLOCK_Q` (at 1024 a head of 128 with
-16 384 keys no longer fits and takes the split route); the forward, with no
-dk and dv to hold, keeps the default. The resident forward walks the q
+16 384 keys no longer fits in two buffers each, only in one); the forward,
+with no dk and dv to hold, keeps the default. The resident forward walks the q
 blocks in its outer order and the group's heads within (the backward sums dk
 and dv over heads first and cannot), so that a strip is fetched once a group
 and not once a head: at 16 384 keys and 32 heads on 4, 1.07 GB a call, where
@@ -212,7 +228,8 @@ DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 # With a `keep` mask the resident backward also holds the q block's (bq, Tk)
 # int8 strip of it, twice: at 16 384 keys, a bfloat16 head of 128 and bq = 1024
-# that is 32 MiB more than the 96 MiB a kernel may use leave, at 512 it fits.
+# that is 32 MiB more than the 96 MiB a kernel may use leave beside two buffers
+# of each whole-head block, at 512 it fits.
 SEL_BLOCK_Q = 512
 
 # The names of the custom rule's array residuals, in its order: q, k, v as
@@ -617,19 +634,26 @@ class Plan(NamedTuple):
     route: str       # a head's keys "resident" in VMEM, or the other route
     vmem_bytes: int  # what the resident kernel's blocks, scratch and values take
     vmem_limit: int  # what Mosaic may use
+    buffers: int = 2  # of each block the resident kernel fetches once a head
 
 
-def _plan(part, other, what, need, t_k, head_dim, v_dim, dtype_name, bq, bk, vmem,
+def _plan(part, other, what, needs, t_k, head_dim, v_dim, dtype_name, bq, bk, vmem,
           keep):
+    """`needs`: the resident kernel's bytes by the buffers it gives a whole-head
+    block, most first; the plan is the first that fits, else `other`'s."""
     limit = vmem * 3 // 4
-    plan = Plan("resident" if need <= limit else other, need, limit)
+    for buffers, need in needs.items():
+        if need <= limit:
+            break
+    plan = Plan("resident" if need <= limit else other, need, limit, buffers)
     # once a shape and process: which kernel this shape takes
     head = f"head {head_dim}" if v_dim == head_dim else f"head {head_dim} | v {v_dim}"
     logger.info(
         "flash attention's %s (%d keys, %s, %s, blocks %d x %d%s) "
-        "takes the %s route: a head's %s resident in VMEM need "
+        "takes the %s route: a head's %s resident in VMEM, %s each, need "
         "%d bytes of the %d a kernel may use here", part, t_k, head, dtype_name,
-        bq, bk, ", a data mask" if keep else "", plan.route, what, need, limit)
+        bq, bk, ", a data mask" if keep else "", plan.route, what,
+        {1: "one buffer", 2: "two buffers"}[buffers], need, limit)
     return plan
 
 
@@ -638,7 +662,9 @@ def _in_vmem(dim: int) -> int:
     whether it is wider than one and no multiple of it (q and k heads of 192:
     two) or narrower (a head of 64: one, half of it padding — counted "as it
     is" until PR 62, when the compiler refused a resident backward at 32 768
-    keys of 64 that the plan had put at 76.0 MB: it takes 99.5)."""
+    keys of 64 that the plan had put at 76.0 MB: with two buffers for each
+    whole-head block it takes 99.5 of the 96 a kernel may use; with one, which
+    the plan gives that shape since PR 63, it compiles and runs)."""
     return -(-dim // _LANE) * _LANE
 
 
@@ -657,7 +683,7 @@ def _fwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
             + 2 * bq * (qk + vo) * size + 16 * bq * _LANE + 4 * bq * vo
             + 16 * bq * bk + 4 * bk * vo
             + (2 * bq * t_k + 4 * bq * bk if keep else 0))
-    return _plan("forward", "streaming", "k and v", need, t_k, head_dim, v_dim,
+    return _plan("forward", "streaming", "k and v", {2: need}, t_k, head_dim, v_dim,
                  dtype_name, bq, bk, vmem, keep)
 
 
@@ -953,8 +979,11 @@ def _bwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
     size = jnp.dtype(dtype_name).itemsize
     v_dim = v_dim or head_dim
     qk, vo = _in_vmem(head_dim), _in_vmem(v_dim)
-    # two buffers each of k, v in and dk, dv out; dk, dv in float32
-    resident = t_k * (qk + vo) * (4 * size + 4)
+    # `buffers` each of k, v in and dk, dv out — the pipeline's two, or where
+    # those do not fit ONE (their block index changes once a head: the second
+    # buffer buys the overlap of one fetch and one write-back a head); dk, dv
+    # in float32
+    resident = lambda buffers: t_k * (qk + vo) * (2 * buffers * size + 4)
     # a step: two buffers each of q, o, do in and dq out, of lse and its
     # cotangent; dq in float32; the float32 (bq, bk) values (s, p, dp, ds and
     # a transposed operand) and float32 forms of q, do, o, k, v; with a data
@@ -962,7 +991,8 @@ def _bwd_plan(t_k: int, head_dim: int, dtype_name: str, bq: int, bk: int,
     step = (4 * bq * (qk + vo) * size + 16 * bq * _LANE + 4 * bq * qk
             + 20 * bq * bk + 4 * (bq * (qk + 2 * vo) + bk * (qk + vo))
             + (2 * bq * t_k + 4 * bq * bk if keep else 0))
-    return _plan("backward", "split", "k, v, dk and dv", resident + step, t_k,
+    return _plan("backward", "split", "k, v, dk and dv",
+                 {buffers: resident(buffers) + step for buffers in (2, 1)}, t_k,
                  head_dim, v_dim, dtype_name, bq, bk, vmem, keep)
 
 
@@ -970,9 +1000,11 @@ def bwd_route(t_k: int, head_dim: int, dtype, bq: int, bk: int,
               keep: bool = False, v_dim: Optional[int] = None) -> Plan:
     """Which backward a call of `t_k` keys a head takes — `resident`: ONE
     kernel, the key-value head's whole k and v and its float32 dk and dv in
-    VMEM, a pair's score block computed once for dq, dk and dv; or `split`: a
-    dq kernel and a dkv kernel that each stream kv blocks and each recompute
-    the score block, where the head does not fit. A pure function of the
+    VMEM, a pair's score block computed once for dq, dk and dv, the head's k,
+    v, dk and dv blocks in the pipeline's two buffers each or, where only that
+    fits, in one (`Plan.buffers`); or `split`: a dq kernel and a dkv kernel
+    that each stream kv blocks and each recompute the score block, where the
+    head fits neither way. A pure function of the
     shapes, the dtype, whether the call has a data mask (`keep`: its strip
     sits beside k and v) and the chip's VMEM; nothing a caller sets. `head_dim`
     and `v_dim` as `fwd_route`'s."""
@@ -996,18 +1028,20 @@ def _flash_bwd(res, g, g_lse, *, causal, bq, bk, interpret, window=None):
     static = dict(causal=causal, bq=bq, bk=bk, interpret=interpret, window=window,
                   optional=(g_lse is not None, bool(keep)))
     if plan.route == "resident":
-        dq, dk, dv = _bwd_resident(operands, plan.vmem_limit, **static)
+        dq, dk, dv = _bwd_resident(operands, plan.vmem_limit, plan.buffers, **static)
     else:
         dq, dk, dv = _bwd_split(operands, **static)
     back = lambda x: x.transpose(0, 2, 1, 3)
     return (None, back(dq), back(dk), back(dv)) + (None,) * len(keep)
 
 
-def _bwd_resident(operands, vmem_limit, *, causal, bq, bk, interpret, window,
-                  optional):
+def _bwd_resident(operands, vmem_limit, buffers, *, causal, bq, bk, interpret,
+                  window, optional):
     """(dq, dk, dv) as (B, H, T, D) by `_bwd_kernel`: grid axis 1 counts
     KEY-VALUE heads, the sequential axis 2 walks the group's query heads and
-    their q blocks (y = head in group · num_q + q block). `optional`: whether
+    their q blocks (y = head in group · num_q + q block). `buffers`: the
+    plan's, of the whole-head blocks (k, v in; dk, dv out) — the pipeline's two
+    (nothing is said, and the call is what it was), or one. `optional`: whether
     the operands end with (the logsumexp's cotangent, the data mask)."""
     _, qt, kt, vt = operands[:4]
     B, H, Tq, D = qt.shape
@@ -1020,7 +1054,9 @@ def _bwd_resident(operands, vmem_limit, *, causal, bq, bk, interpret, window,
         q_at = lambda b, h, y, offs: (b, h * group + y // num_q, y % num_q, 0)
     q_spec, o_spec = (pl.BlockSpec((1, 1, bq, d), q_at) for d in (D, Dv))
     lse_spec = pl.BlockSpec((1, 1, bq, _LANE), q_at)
-    k_spec, v_spec = (pl.BlockSpec((1, 1, Tk, d), lambda b, h, y, offs: (b, h, 0, 0))
+    once_a_head = None if buffers == 2 else pl.Buffered(buffers)
+    k_spec, v_spec = (pl.BlockSpec((1, 1, Tk, d), lambda b, h, y, offs: (b, h, 0, 0),
+                                   pipeline_mode=once_a_head)
                       for d in (D, Dv))
     # the q block's strip of the mask: every key, as k and v are whole
     keep_spec = pl.BlockSpec((1, bq, Tk), lambda b, h, y, offs: (b, y % num_q, 0))

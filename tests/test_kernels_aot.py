@@ -2,9 +2,11 @@
 described v5e at the widths the benchmark's three language-model cells run
 (`ops/pallas_gmm.py`; the on-chip-measurement guide, section 2), and the
 flash-attention kernels at the four language-model cells' shapes, the ONE
-backward kernel with a key-value head's keys resident in VMEM, and at a head
-that does not fit, the two that stream it (`ops/pallas_attention.py`: the
-block plan follows the head size, `bwd_route` the head's bytes), and the
+backward kernel with a key-value head's keys resident in VMEM — at 32 768
+keys in ONE buffer for each of k, v, dk and dv — and at a head that does not
+fit so either (65 536 keys), the two that stream it
+(`ops/pallas_attention.py`: the block plan follows the head size, `bwd_route`
+the head's bytes), and the
 state-space scan's three kernels at the Nemotron cell's shapes
 (`ops/pallas_ssd.py`) and the delta rule's two at the Kimi cell's
 (`ops/pallas_delta_rule.py`), and the depthwise convolution's two at both
@@ -97,23 +99,27 @@ def test_forward_and_backward_compile_for_a_v5e(name, one_chip, no_compile_cache
 
 
 # (batch, tokens, query heads, key-value heads, head, window) -> the plan's
-# blocks for the backward and the backward's route: the attention of
-# BENCHMARK.json's language-model cells, then a head that does not fit VMEM
-# whole (its forward, with no dk and dv to hold, still does)
+# blocks for the backward, the backward's route and the buffers it gives each
+# of the head's k, v, dk and dv: the attention of BENCHMARK.json's
+# language-model cells, then a head that fits VMEM whole in one buffer each
+# and not in the pipeline's two (PR 63), then one that does not fit at all
+# (its forward, with no dk and dv to hold, still does)
 FLASH = {
-    "olmoe-1b-7b.resident-4k": ((2, 4096, 16, 16, 128, None), (1024, 1024), "resident"),
-    "nemotron-3-nano-30b-a3b.resident-8k": ((1, 8192, 32, 2, 128, None), (1024, 1024), "resident"),
+    "olmoe-1b-7b.resident-4k": ((2, 4096, 16, 16, 128, None), (1024, 1024), "resident", 2),
+    "nemotron-3-nano-30b-a3b.resident-8k": ((1, 8192, 32, 2, 128, None), (1024, 1024), "resident", 2),
     # the plan halves the backward's key block at head 256: at (1024, 1024) the
     # dq kernel asked for 16.9 MB of Mosaic's default 16 (PR 32); the forward's
     # blocks are its own (`FORWARD_BLOCKS`)
-    "glm-4.7-flash.resident-8k": ((1, 8192, 20, 20, 256, None), (1024, 512), "resident"),
-    "mellum2-12b-a2.5b.resident-16k/full": ((1, 16384, 32, 4, 128, None), (1024, 1024), "resident"),
-    "mellum2-12b-a2.5b.resident-16k/sliding": ((1, 16384, 32, 4, 128, 1024), (1024, 1024), "resident"),
+    "glm-4.7-flash.resident-8k": ((1, 8192, 20, 20, 256, None), (1024, 512), "resident", 2),
+    "mellum2-12b-a2.5b.resident-16k/full": ((1, 16384, 32, 4, 128, None), (1024, 1024), "resident", 2),
+    "mellum2-12b-a2.5b.resident-16k/sliding": ((1, 16384, 32, 4, 128, 1024), (1024, 1024), "resident", 2),
     # three key blocks a query block where Mellum2's window of 1024 has two;
     # its full layer is Mellum2's (unrotated q and k are no other shape)
-    "trinity-mini.resident-16k/sliding": ((1, 16384, 32, 4, 128, 2048), (1024, 1024), "resident"),
-    "32k_keys": ((1, 32768, 8, 2, 128, None), (1024, 1024), "split"),
-    "32k_keys/sliding": ((1, 32768, 8, 2, 128, 1024), (1024, 1024), "split"),
+    "trinity-mini.resident-16k/sliding": ((1, 16384, 32, 4, 128, 2048), (1024, 1024), "resident", 2),
+    "32k_keys": ((1, 32768, 8, 2, 128, None), (1024, 1024), "resident", 1),
+    "32k_keys/sliding": ((1, 32768, 8, 2, 128, 1024), (1024, 1024), "resident", 1),
+    "64k_keys": ((1, 65536, 8, 2, 128, None), (1024, 1024), "split", 1),
+    "64k_keys/sliding": ((1, 65536, 8, 2, 128, 1024), (1024, 1024), "split", 1),
 }
 # the forward's blocks where they are not the backward's
 FORWARD_BLOCKS = {"glm-4.7-flash.resident-8k": (1024, 1024)}
@@ -125,14 +131,17 @@ def test_flash_kernels_compile_for_a_v5e(name, one_chip, no_compile_cache):
     forward with the key-value head's k and v resident in VMEM at every one of
     these shapes, ONE backward kernel where its k, v, dk and dv fit the VMEM
     `bwd_route` allows it (`vmem_limit_bytes`: Mosaic's default 16 MB hold
-    none of these heads), the dq and the dkv kernel where they do not."""
+    none of these heads) — in two buffers each, or at 32 768 keys in one: the
+    compiler takes what the plan says fits — the dq and the dkv kernel where
+    they do not."""
     from elasticdl_tpu.ops import pallas_attention
 
-    (b, t, h, hkv, d, window), blocks, route = FLASH[name]
+    (b, t, h, hkv, d, window), blocks, route, buffers = FLASH[name]
     q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16, sharding=one_chip)
     assert pallas_attention._plan_blocks(q.shape, k.shape, None, None, dtype=jnp.bfloat16) == blocks
-    assert pallas_attention.bwd_route(t, d, jnp.bfloat16, *blocks).route == route
+    plan = pallas_attention.bwd_route(t, d, jnp.bfloat16, *blocks)
+    assert (plan.route, plan.buffers) == (route, buffers)
     forward = FORWARD_BLOCKS.get(name, blocks)
     assert pallas_attention._plan_blocks(q.shape, k.shape, None, None, dtype=jnp.bfloat16,
                                          forward=True) == forward

@@ -531,10 +531,11 @@ def test_lfm2_s_attention_layer_holds_one_flash_forward_and_its_convolution_laye
     tokens, 32 query heads on 8 key-value heads, q, k, v and the output all at
     a head of 64, 8 of 32 experts held — through the zoo's own loss (the head
     in row blocks). The attention layer keeps its flash residuals: ONE
-    `flash_attention_fwd`; its backward takes the SPLIT route (`bwd_dq` +
-    `bwd_dkv`: at 64 lanes padded to 128 a head's k, v, dk and dv do not fit
-    the resident kernel's VMEM at 32 768 keys, and the plan says so), all
-    under `lfm2/attn/attn`. The convolution layer's K = 3 convolution is
+    `flash_attention_fwd`; its backward is ONE `flash_attention_bwd` and no
+    `bwd_dq` / `bwd_dkv` (at 64 lanes padded to 128 a head's k, v, dk and dv
+    fit the resident kernel's VMEM at 32 768 keys in ONE buffer each, not in
+    the pipeline's two, and the plan says so; at 16 384 keys they fit in
+    two), all under `lfm2/attn/attn`. The convolution layer's K = 3 convolution is
     `causal_conv1d_fwd` twice and `causal_conv1d_bwd` once under
     `lfm2/conv/conv`, between two XLA products under `gate_in` and
     `gate_out`."""
@@ -545,8 +546,9 @@ def test_lfm2_s_attention_layer_holds_one_flash_forward_and_its_convolution_laye
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
     blocks = (pallas_attention.DEFAULT_BLOCK_Q, pallas_attention.DEFAULT_BLOCK_K)
     assert pallas_attention.fwd_route(32768, 64, jnp.bfloat16, *blocks).route == "resident"
-    assert pallas_attention.bwd_route(32768, 64, jnp.bfloat16, *blocks).route == "split"
-    assert pallas_attention.bwd_route(16384, 64, jnp.bfloat16, *blocks).route == "resident"
+    backward = {t: pallas_attention.bwd_route(t, 64, jnp.bfloat16, *blocks) for t in (32768, 16384)}
+    assert {t: (p.route, p.buffers) for t, p in backward.items()} == {
+        32768: ("resident", 1), 16384: ("resident", 2)}
     net = lfm2_moe.custom_model(num_hidden_layers=2, kept_layers="2,3", num_experts=8,
                                 router_experts=32, vocab_size=512)
     assert [net.cfg.kind(l) for l in net.cfg.layers] == ["full_attention", "conv"]
@@ -565,7 +567,7 @@ def test_lfm2_s_attention_layer_holds_one_flash_forward_and_its_convolution_laye
     text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
     calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
     kinds = sorted(re.sub(r"\.\d+$", "", name) for name in calls)
-    assert kinds == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq", "flash_attention_fwd"]
+    assert kinds == ["flash_attention_bwd", "flash_attention_fwd"]
     found = scopes_of(text, "lfm2_moe")
     assert {found.get(name) for name in calls} == {"lfm2/attn/attn"}
     assert_the_convolutions_are_the_kernels(text, "lfm2_moe", "lfm2/conv/conv", 1)

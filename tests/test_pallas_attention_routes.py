@@ -104,17 +104,23 @@ def test_the_resident_backward_is_the_split_one_to_the_bit(name, monkeypatch):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-# (keys, head, dtype) -> the blocks the plan gives and the route on a v5e's
-# 128 MiB of VMEM: the four language-model cells of BENCHMARK.json, then what
+# (keys, head, dtype) -> the route on a v5e's 128 MiB of VMEM at the blocks the
+# plan gives, and the buffers it gives each of the head's k, v, dk and dv: the
+# four language-model cells of BENCHMARK.json, then what fits in one buffer
+# each and not in the pipeline's two (PR 63; until then `split`), then what
 # does not fit
 ROUTES = {
-    "olmoe-1b-7b.resident-4k": (4096, 128, jnp.bfloat16, "resident"),
-    "nemotron-3-nano-30b-a3b.resident-8k": (8192, 128, jnp.bfloat16, "resident"),
-    "glm-4.7-flash.resident-8k": (8192, 256, jnp.bfloat16, "resident"),
-    "mellum2-12b-a2.5b.resident-16k": (16384, 128, jnp.bfloat16, "resident"),
-    "32k_keys": (32768, 128, jnp.bfloat16, "split"),
-    "16k_keys_of_256": (16384, 256, jnp.bfloat16, "split"),
-    "16k_keys_float32": (16384, 128, jnp.float32, "split"),
+    "olmoe-1b-7b.resident-4k": (4096, 128, jnp.bfloat16, "resident", 2),
+    "nemotron-3-nano-30b-a3b.resident-8k": (8192, 128, jnp.bfloat16, "resident", 2),
+    "glm-4.7-flash.resident-8k": (8192, 256, jnp.bfloat16, "resident", 2),
+    "mellum2-12b-a2.5b.resident-16k": (16384, 128, jnp.bfloat16, "resident", 2),
+    "32k_keys": (32768, 128, jnp.bfloat16, "resident", 1),
+    "lfm2-8b-a1b.resident-32k": (32768, 64, jnp.bfloat16, "resident", 1),
+    "16k_keys_of_256": (16384, 256, jnp.bfloat16, "resident", 1),
+    "16k_keys_float32": (16384, 128, jnp.float32, "resident", 1),
+    "64k_keys": (65536, 128, jnp.bfloat16, "split", 1),
+    "32k_keys_of_256": (32768, 256, jnp.bfloat16, "split", 1),
+    "32k_keys_float32": (32768, 128, jnp.float32, "split", 1),
 }
 
 
@@ -124,24 +130,123 @@ def test_the_backward_route_follows_the_head_s_bytes_and_logs_once_a_shape(
     from elasticdl_tpu.ops import pallas_attention as pa
 
     take_route(monkeypatch, "resident")            # a described v5e
-    t_k, head, dtype, want = ROUTES[name]
+    t_k, head, dtype, want, buffers = ROUTES[name]
     shape = (1, t_k, 4, head)
     bq, bk = pa._plan_blocks(shape, shape, None, None, dtype=dtype)
     plan = pa.bwd_route(t_k, head, dtype, bq, bk)
-    assert plan.route == want
+    assert (plan.route, plan.buffers) == (want, buffers)
     assert (plan.vmem_bytes <= plan.vmem_limit) == (want == "resident")
     assert plan.vmem_limit == (128 << 20) * 3 // 4
-    # k, v, dk, dv twice buffered and the two float32 accumulators at least
-    assert plan.vmem_bytes > t_k * head * (8 * jnp.dtype(dtype).itemsize + 8)
+    # k, v, dk, dv in the plan's buffers and the two float32 accumulators at
+    # least, a head under a lane tile counted as a whole one
+    assert plan.vmem_bytes > t_k * max(head, 128) * (4 * buffers * jnp.dtype(dtype).itemsize + 8)
     assert pa.bwd_route(t_k, head, dtype, bq, bk) == plan
     # (a record that also propagates to the root logger is listed twice)
     lines = list({id(r): r.getMessage() for r in bwd_log.records
                   if "backward" in r.getMessage()}.values())
     assert len(lines) == 1 and f"takes the {want} route" in lines[0]
     assert f"{t_k} keys, head {head}" in lines[0]
+    assert f"{('one buffer', 'two buffers')[buffers - 1]} each" in lines[0]
     # a smaller chip: the same function, the other answer
     monkeypatch.setattr(pa, "_vmem_bytes", lambda: 16 << 20)
     assert pa.bwd_route(t_k, head, dtype, bq, bk).route == "split"
+
+
+# (keys, q's and k's head, v's, a data mask, the backward's blocks) -> the
+# bytes the parent of PR 63 planned on a v5e, where it planned `resident`:
+# every language-model cell's attention but LFM2's at 32 768 keys (OLMoE's and
+# Ouro's; Nemotron's; GLM's; Mellum2's and Trinity's, full and sliding; Phi's
+# 64 | 128; Xing's 192 | 128; Keye's with its mask; Kimi's 192 | 128; LFM2's
+# at half its tokens), written down from that commit's `_bwd_plan`
+TWO_BUFFER_PLANS = {
+    (4096, 128, 128, False, 1024, 1024): 40894464,
+    (8192, 128, 128, False, 1024, 1024): 53477376,
+    (8192, 256, 256, False, 1024, 512): 72351744,
+    (16384, 128, 128, False, 1024, 1024): 78643200,
+    (8192, 64, 128, False, 1024, 1024): 53477376,
+    (4096, 192, 128, False, 1024, 512): 38535168,
+    (16384, 128, 128, True, 512, 1024): 83886080,
+    (16384, 192, 128, False, 1024, 512): 95158272,
+    (16384, 64, 64, False, 1024, 1024): 78643200,
+}
+
+
+@pytest.mark.parametrize("shape", sorted(TWO_BUFFER_PLANS), ids=lambda s: "-".join(map(str, s)))
+def test_a_head_that_fits_in_two_buffers_plans_what_it_planned_before_one_was_an_answer(shape):
+    """No other cell's plan moved: where the pipeline's two buffers fit, the
+    route, the bytes and the buffers are the parent's, so the call is too."""
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    t_k, head, v_dim, keep, bq, bk = shape
+    assert pa._plan_blocks((1, t_k, 4, head), (1, t_k, 4, head), None, None,
+                           dtype=jnp.bfloat16, keep=keep) == (bq, bk)
+    plan = pa._bwd_plan(t_k, head, "bfloat16", bq, bk, 128 << 20, keep, v_dim)
+    assert plan == pa.Plan("resident", TWO_BUFFER_PLANS[shape], 96 << 20, 2)
+
+
+def test_every_resident_flash_shape_of_the_aot_file_is_held_to_its_two_buffer_plan():
+    from tests.test_kernels_aot import FLASH
+
+    two_buffers = {(t, d, d, False, *blocks)
+                   for (_, t, _, _, d, _), blocks, route, buffers in FLASH.values()
+                   if (route, buffers) == ("resident", 2)}
+    assert two_buffers and two_buffers <= set(TWO_BUFFER_PLANS)
+
+
+def _whole_head_buffering(jaxpr):
+    """{kernel name: the pipeline modes its BlockSpecs name} of a jaxpr."""
+    out = {}
+
+    def note(eqn):
+        if eqn.primitive.name == "pallas_call":
+            out[eqn.params["name"]] = [
+                m.pipeline_mode.buffer_count
+                for m in eqn.params["grid_mapping"].block_mappings if m.pipeline_mode is not None]
+
+    equations(jaxpr, note)
+    return out
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["causal", "window_40"])
+def test_the_one_buffer_backward_is_the_split_one_to_the_bit(window, monkeypatch):
+    """LFM2's attention small: 4 query heads a key-value head, a head of 64,
+    causal (and once under a window). On a chip whose VMEM holds the head's k,
+    v, dk and dv in ONE buffer each and not in two, the plan is `resident`
+    with one buffer, the ONE backward kernel's four whole-head BlockSpecs say
+    `Buffered(1)` — with room for two nothing says anything, as before — and
+    dq, dk and dv are the split route's in every element."""
+    from elasticdl_tpu.ops import pallas_attention as pa
+
+    t, heads, kv_heads, head, bq, bk = 128, 8, 2, 64, 32, 32
+    r = np.random.RandomState(63)
+    draw = lambda h: jnp.asarray(r.randn(1, t, h, head) * 0.5, jnp.float32)
+    q, k, v, probe = draw(heads), draw(kv_heads), draw(kv_heads), draw(heads)
+    two = pa._bwd_plan(t, head, "float32", bq, bk, 1 << 40)
+    assert two.buffers == 2
+    # three quarters of it are a byte short of what two buffers need
+    vmem = {"two": 1 << 40, "one": (two.vmem_bytes - 1) * 4 // 3, "split": 1 << 10}
+    want = {"two": ("resident", 2), "one": ("resident", 1), "split": ("split", 1)}
+    got = {}
+    for name in vmem:
+        pa._make_flash.cache_clear()
+        monkeypatch.setattr(pa, "_vmem_bytes", lambda name=name: vmem[name])
+        plan = pa.bwd_route(t, head, jnp.float32, bq, bk)
+        assert (plan.route, plan.buffers) == want[name]
+        f = lambda q, k, v: jnp.sum(probe * flash_attention(
+            q, k, v, window=window, block_q=bq, block_k=bk, interpret=True))
+        jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, k, v).jaxpr
+        kind = "swa_" if window else ""
+        assert {n: b for n, b in _whole_head_buffering(jaxpr).items() if "bwd" in n} == {
+            "two": {f"flash_attention_{kind}bwd": []},
+            "one": {f"flash_attention_{kind}bwd": [1, 1, 1, 1]},
+            "split": {f"flash_attention_{kind}bwd_dq": [], f"flash_attention_{kind}bwd_dkv": []},
+        }[name]
+        got[name] = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    pa._make_flash.cache_clear()
+    for a, b, c in zip(got["one"], got["split"], got["two"]):
+        assert float(jnp.max(jnp.abs(a))) > 1e-3
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
 
 
 
@@ -415,7 +520,11 @@ def test_a_keep_call_plans_smaller_q_blocks_and_counts_its_strip():
     vmem = 128 << 20
     plan = lambda bq, keep: pa._bwd_plan(16384, 128, "bfloat16", bq, 1024, vmem, keep)
     assert plan(1024, False).route == "resident" and plan(512, True).route == "resident"
-    assert plan(1024, True).route == "split"
+    assert plan(1024, False).buffers == plan(512, True).buffers == 2
+    # the strip at 1024 rows leaves the head's blocks ONE buffer each (PR 63;
+    # `split` until then): 512 is what the call plans, and there two fit
+    assert (plan(1024, True).route, plan(1024, True).buffers) == ("resident", 1)
+    assert plan(2048, True).route == "split"
     assert plan(512, True).vmem_bytes - plan(512, False).vmem_bytes \
         == 2 * 512 * 16384 + 4 * 512 * 1024
     # an int8 tile has 32 rows: a sequence with no such block is declined

@@ -181,9 +181,12 @@ def signature(cell, v_dim=None):
     walk(jaxpr)
     fwd = pa._plan_blocks(q, k, None, None, dtype=dt, keep=keep, forward=True)
     bwd = pa._plan_blocks(q, k, None, None, dtype=dt, keep=keep)
+    # (route, bytes, limit) as the parent recorded them; every one of these
+    # shapes fits with the pipeline's two buffers a whole-head block (PR 63)
+    plans = pa.fwd_route(t, head, dt, *fwd, keep=keep), pa.bwd_route(t, head, dt, *bwd, keep=keep)
+    assert [plan.buffers for plan in plans] == [2, 2]
     return {"calls": calls, "fwd_blocks": list(fwd), "bwd_blocks": list(bwd),
-            "fwd_plan": list(pa.fwd_route(t, head, dt, *fwd, keep=keep)),
-            "bwd_plan": list(pa.bwd_route(t, head, dt, *bwd, keep=keep))}
+            "fwd_plan": list(plans[0][:3]), "bwd_plan": list(plans[1][:3])}
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
