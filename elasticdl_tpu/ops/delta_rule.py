@@ -1,16 +1,27 @@
-"""The gated delta rule with a decay for every CHANNEL: linear attention whose
-state is decayed channel by channel, has the key's direction erased and is
-then written, every token (Kimi Delta Attention, arXiv:2510.26692; the delta
-rule of arXiv:2406.06484 under the gate of arXiv:2412.06464 made
-channel-wise). Per head, with a state S (d_k, d_v), S_0 = 0:
+"""The gated delta rule: linear attention whose state is decayed, has the
+key's direction erased and is then written, every token (the delta rule of
+arXiv:2406.06484 under the gate of arXiv:2412.06464). Per head, with a state
+S (d_k, d_v), S_0 = 0:
 
     S_t = (I − β_t k_t k_tᵀ) · Diag(exp(g_t)) · S_{t−1} + β_t k_t v_tᵀ
     o_t = S_tᵀ q_t
 
-g_t ≤ 0 one log-decay a key channel, β_t the write strength of the head.
-`delta_rule_recurrent` is that, token by token (tests; the benchmark's
-reference has its own). `gated_delta_rule` computes it in chunks of L tokens.
-With u_t = β_t (v_t − S_{t−1}ᵀ (exp(g_t) ⊙ k_t)) the step is
+β_t the write strength of the head, g_t ≤ 0 its log-decay, in one of TWO FORMS
+that the shape of g selects:
+
+- "channel": g (B, T, H, d_k), one decay a key CHANNEL (Kimi Delta Attention,
+  arXiv:2510.26692: `model_zoo/transformer/kimi_linear.py`); q, k, v all of H
+  heads;
+- "scalar": g (B, T, H_v), ONE decay a head (Gated DeltaNet, arXiv:2412.06464:
+  `model_zoo/transformer/qwen3_next.py`), `Diag(exp g_t)` then `exp(g_t) · I`;
+  q, k of H_k heads against v, g, β of H_v = r · H_k: value head h reads key
+  head ⌊h / r⌋, and no H_v-head copy of q or k is ever made.
+
+`delta_rule_recurrent` is the recurrence, token by token, in either form
+(tests; the benchmark's references have their own). `gated_delta_rule` computes
+it in chunks of L tokens.
+
+The channel-wise form first. With u_t = β_t (v_t − S_{t−1}ᵀ (exp(g_t) ⊙ k_t)) the step is
 `S_t = Diag(exp(g_t)) S_{t−1} + k_t u_tᵀ`, so inside a chunk that starts from
 S, with Γ_r = Σ_{i≤r} g_i (per channel, ≤ 0 and falling):
 
@@ -19,16 +30,31 @@ S, with Γ_r = Σ_{i≤r} g_i (per channel, ≤ 0 and falling):
     O  = (Q ⊙ exp Γ) S + P u
     S' = Diag(exp Γ_L) S + (K ⊙ exp(Γ_L − Γ))ᵀ u
 
-One scalar decay a head would make `exp(Γ_r − Γ_i)` an (L, L) mask
-(`ops/ssm.py::ssd_chunked`); a decay a channel sits INSIDE the contraction
-over c and is carried on the operands. Written as a quotient `(K ⊙ exp Γ)(K ⊘
-exp Γ)ᵀ` it overflows (128 channels, each decaying over L steps): every
-exponent taken here is a DIFFERENCE that is ≤ 0. The chunk is cut into
+A decay a channel sits INSIDE the contraction over c and is carried on the
+operands. Written as a quotient `(K ⊙ exp Γ)(K ⊘ exp Γ)ᵀ` it overflows (128
+channels, each decaying over L steps): every exponent taken here is a
+DIFFERENCE that is ≤ 0. The chunk is cut into
 sub-blocks of `SUB` tokens; a row of sub-block a against a column of an
 EARLIER sub-block goes through a's start, `exp(Γ_r − Γ_a) · exp(Γ_a − Γ_i)`,
 both factors ≤ 1, the column factor made once for each (a, i); inside a
 sub-block the (SUB, SUB, d) differences are taken one by one, masked BEFORE
 the exponential.
+
+The scalar form is the same chunk algebra with the decay OUTSIDE the
+contraction: Γ_r = Σ_{i≤r} g_i is one number a head and token, so
+
+    M = tril(K Kᵀ, −1) ⊙ exp(Γ_r − Γ_i)        P = tril(Q Kᵀ) ⊙ exp(Γ_r − Γ_i)
+    (I + Diag(β) M) u = Diag(β) (V − exp(Γ) ⊙ (K S))
+    O  = exp(Γ) ⊙ (Q S) + P u                  S' = exp(Γ_L) S + Kᵀ (exp(Γ_L − Γ) ⊙ u)
+
+— ONE (L, L) product of the key head's q and k (shared by the r value heads
+that read it) and ONE (L, L) exponential a value head, masked BEFORE the
+exponential, where the channel-wise form takes sub-blocks of (SUB, SUB, d)
+differences; the decays that are not in M and P scale rows and columns of
+what is a value head's own already (A's columns, u's rows, the output's rows),
+so nothing of (T, H_v, d_k) size — no plane of g, Γ or their exponentials, no
+decayed copy of k — exists on either route. The pull-back gives dΓ as row and
+column sums of (L, L) products, and dq, dk summed over the r value heads.
 
 The triangular system is solved on the MXU without a sequential sweep: with
 N = −Diag(β) M strictly lower, `(I − N)⁻¹ = (I + N)(I + N²)(I + N⁴)…`, log₂ L
@@ -39,7 +65,8 @@ Precision: g, Γ, every exponential, β, M's and P's diagonal sub-blocks, the
 inverse (matmuls at the highest precision), u, S and what is added to it are
 float32; the chunk's other matmuls take `compute_dtype` operands (bfloat16 on
 the chip), rounded AFTER the decay has been applied in float32, and
-accumulate in float32.
+accumulate in float32 (the scalar form's K Kᵀ and Q Kᵀ take the operands as
+they are and the decay multiplies the float32 product).
 
 Memory: the sequence is walked in BLOCKS of `chunks_per_block` chunks. The
 forward keeps the state each block starts from (B·H·d_k·d_v float32 a block:
@@ -63,7 +90,11 @@ body, XLA's batched matmuls under two `lax.scan`s, everywhere else — the CPU's
 route and the kernels' yardstick. Both take Γ from `cumulative_log_decay`,
 move the state through `next_state` (looked up in this module when a program
 is traced: the benchmark's rehearsal patches them by name), keep the same two
-named arrays and give the same values within rounding.
+named arrays and give the same values within rounding — in both forms: the
+scalar form has a plain body (`_block_scalar`) and a kernel pair of its own
+(`delta_rule_scalar_fwd` / `_bwd`), and shares `unit_lower_inverse`, the block
+walk, the residuals, `cumulative_log_decay` (over a (…, L, 1) plane) and
+`next_state`.
 """
 
 from __future__ import annotations
@@ -90,9 +121,14 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 def delta_rule_recurrent(q, k, v, g, beta, initial_state=None):
     """The recurrence as written, one token at a time, float32: q, k, g
     (B, T, H, d_k), v (B, T, H, d_v), beta (B, T, H) -> (o (B, T, H, d_v), the
-    last state (B, H, d_k, d_v))."""
+    last state (B, H, d_k, d_v)). The scalar form too: g (B, T, H) is laid
+    against every channel, q and k of fewer heads are repeated to v's."""
     f32 = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
+    if g.ndim == 3:
+        g = g[..., None]
+    if k.shape[2] != v.shape[2]:
+        q, k = (jnp.repeat(a, v.shape[2] // k.shape[2], axis=2) for a in (q, k))
     b, _, h, dk = k.shape
     state = (jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32)
              if initial_state is None else f32(initial_state))
@@ -233,41 +269,95 @@ def _block(state, q, k, v, g, beta, dt, chunk):
     return jnp.moveaxis(o, 1, 3).reshape(b, tokens, h, -1), state
 
 
+def _block_scalar(state, q, k, v, g, beta, dt, chunk):
+    """`_block` in the scalar form: q, k (B, n·L, H_k, d_k), v (B, n·L, H_v,
+    d_v), g, beta (B, n·L, H_v), state (B, H_v, d_k, d_v) -> (o like v, the
+    state after them). The r = H_v / H_k value heads of a key head are an
+    axis of their own, (B, H_k, r, n, L, ·), against q and k's (B, H_k, n, L,
+    d): K Kᵀ and Q Kᵀ are made once a key head, and every decay scales what is
+    a value head's already."""
+    b, tokens, hv = beta.shape
+    hk = k.shape[2]
+    r = hv // hk
+
+    def by_chunk(a):
+        """(B, n·L, H, ...) -> (B, H, n, L, ...)."""
+        a = a.reshape((b, tokens // chunk, chunk) + a.shape[2:])
+        return jnp.moveaxis(a, 3, 1)
+
+    grouped = lambda a: a.reshape((b, hk, r) + a.shape[2:])           # H_v -> (H_k, r)
+    q, k = by_chunk(q), by_chunk(k)                                   # (B, H_k, n, L, d)
+    v, g, beta = (grouped(by_chunk(a)) for a in (v, g, beta))         # (B, H_k, r, n, L[, d])
+    cum = cumulative_log_decay(g[..., None])[..., 0]                  # Γ, inclusive
+    row, col = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    decay = jnp.exp(jnp.where(row >= col, cum[..., :, None] - cum[..., None, :], -jnp.inf))
+    m = _mm("bhnrc,bhnic->bhnri", k, k, dt)[:, :, None] * decay * (row > col)
+    p = _mm("bhnrc,bhnic->bhnri", q, k, dt)[:, :, None] * decay
+    solved = unit_lower_inverse(-beta[..., :, None] * m) * beta[..., None, :]
+    grown = jnp.exp(cum)                                              # exp Γ, ≤ 1
+    w = _mm("bhgnri,bhnic->bhgnrc", solved * grown[..., None, :], k, dt)
+    u = _mm("bhgnri,bhgniv->bhgnrv", solved, v, dt)                   # A V
+    to_end = jnp.exp(cum[..., -1:] - cum)                             # exp(Γ_L − Γ)
+    through = jnp.exp(cum[..., -1])                                   # exp Γ_L
+
+    def one_chunk(s, terms):
+        w_n, u_n, k_n, to_end_n, through_n = terms
+        new = u_n - _mm("bhgrc,bhgcv->bhgrv", w_n, s, dt)             # u of the chunk
+        added = _mm("bhrc,bhgrv->bhgcv", k_n, to_end_n[..., None] * new, dt)
+        return next_state(through_n[..., None, None], s, added), (s, new)
+
+    chunks_first = lambda a, axis: jnp.moveaxis(a, axis, 0)
+    state, (starts, new) = jax.lax.scan(
+        one_chunk, grouped(state),
+        (chunks_first(w, 3), chunks_first(u, 3), chunks_first(k, 2),
+         chunks_first(to_end, 3), chunks_first(through, 3)))
+    starts, new = jnp.moveaxis(starts, 0, 3), jnp.moveaxis(new, 0, 3)
+    o = (grown[..., None] * _mm("bhnrc,bhgncv->bhgnrv", q, starts, dt)
+         + _mm("bhgnri,bhgniv->bhgnrv", p, new, dt))
+    # (B, H_k, r, n, L, d_v) -> (B, n·L, H_v, d_v)
+    o = jnp.moveaxis(o.reshape((b, hv) + o.shape[3:]), 1, 3).reshape(b, tokens, hv, -1)
+    return o, state.reshape((b, hv) + state.shape[3:])
+
+
+_BLOCK_OF = {"channel": _block, "scalar": _block_scalar}
+
+
 # ------------------------------------------------------------------ #
 # the blocks of a sequence, forward and backward
 
 
-def _sweep(state, blocks, dt, chunk):
+def _sweep(state, blocks, dt, chunk, form):
     """Every block in turn: (o of every block, the state each STARTED from,
     the last state)."""
     def one(s, block):
-        o, after = _block(s, *block, dt, chunk)
+        o, after = _BLOCK_OF[form](s, *block, dt, chunk)
         return after, (o, s)
 
     last, (o, starts) = jax.lax.scan(one, state, blocks)
     return o, starts, last
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _blocks(state, blocks, dt, chunk):
-    o, _, last = _sweep(state, blocks, dt, chunk)
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _blocks(state, blocks, dt, chunk, form="channel"):
+    o, _, last = _sweep(state, blocks, dt, chunk, form)
     return o, last
 
 
-def _blocks_fwd(state, blocks, dt, chunk):
-    o, starts, last = _sweep(state, blocks, dt, chunk)
+def _blocks_fwd(state, blocks, dt, chunk, form):
+    o, starts, last = _sweep(state, blocks, dt, chunk, form)
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
     starts = checkpoint_name(starts, RESIDUAL_NAMES[1])
     return (o, last), (blocks, starts)
 
 
-def _blocks_bwd(dt, chunk, kept, cts):
+def _blocks_bwd(dt, chunk, form, kept, cts):
     blocks, starts = kept
     d_o, d_last = cts
 
     def one(d_state, block):
         start, operands, d_o_block = block
-        _, pull = jax.vjp(lambda s, *ops: _block(s, *ops, dt, chunk), start, *operands)
+        _, pull = jax.vjp(lambda s, *ops: _BLOCK_OF[form](s, *ops, dt, chunk),
+                          start, *operands)
         d_start, *d_operands = pull((d_o_block, d_state))
         return d_start, tuple(d_operands)
 
@@ -283,25 +373,32 @@ def _log_route(*said):
     """Once a process for each shape and route: a step's program traces the
     rule a layer, a recomputation and a counter at a time."""
     logger.info(
-        "gated delta rule (%d tokens, %d heads of %d | %d, a decay a channel) takes the "
+        "gated delta rule (%d tokens, %d heads of %d | %d, %s) takes the "
         "%s route: chunks of %d in sub-blocks of %d, blocks of %d chunks whose start "
         "states are kept (the Pallas kernels need a TPU or interpret mode: %s; d_k = "
         "d_v whole lanes, a chunk of whole sub-blocks, a visit's blocks inside VMEM: %s)",
         *said)
 
 
-def delta_rule_route(shape, chunk: int, chunks_per_block: int, v_dim: int = None) -> str:
+def delta_rule_route(shape, chunk: int, chunks_per_block: int, v_dim: int = None,
+                     form: str = "channel", group: int = 1) -> str:
     """Which body the rule takes at q's shape (B, T, H, d_k) and values of
     `v_dim` channels (d_k if not given) — "kernel" or "plain": a pure function
-    of the shapes and of whether the kernels can run here (a TPU, or interpret
-    mode in the CPU tests). Logged once for each answer."""
+    of the shapes, of the `form` ("channel", or "scalar" with `group` value
+    heads reading each of the H key heads) and of whether the kernels can run
+    here (a TPU, or interpret mode in the CPU tests). Logged once for each
+    answer, form and route."""
     _, t, h, d = shape
     v_dim = v_dim or d
     runnable = pallas_delta_rule.runnable()
-    fits = pallas_delta_rule.blocks(d, v_dim, chunk, min(chunks_per_block, -(-t // chunk)))
+    n = min(chunks_per_block, -(-t // chunk))
+    fits = (pallas_delta_rule.blocks(d, v_dim, chunk, n) if form == "channel"
+            else pallas_delta_rule.scalar_blocks(d, v_dim, chunk, n, group))
     route = "kernel" if runnable and fits else "plain"
-    _log_route(t, h, d, v_dim, route, chunk, min(SUB, chunk), chunks_per_block, runnable,
-               f"{fits.vmem_bytes} bytes" if fits else "no")
+    said = ("a decay a channel" if form == "channel" else
+            f"the SCALAR form, one decay a head and {group} value head(s) a key head")
+    _log_route(t, h, d, v_dim, said, route, chunk, min(SUB, chunk), chunks_per_block,
+               runnable, f"{fits.vmem_bytes} bytes" if fits else "no")
     return route
 
 
@@ -309,18 +406,28 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, chunks_per_block: int = 
                      compute_dtype=jnp.bfloat16, initial_state=None):
     """The rule in chunks: q, k, g (B, T, H, d_k), v (B, T, H, d_v), beta
     (B, T, H) -> (o (B, T, H, d_v) float32, the last state (B, H, d_k, d_v)
-    float32). g ≤ 0. T need not be a multiple of the chunk or of the block:
-    the tail is padded with g = 0, β = 0, which leaves the state as it is.
-    `chunk` is a multiple of `SUB` or at most `SUB`. Differentiable in all five
-    operands and the initial state."""
+    float32) — or, the SCALAR form, g (B, T, H_v) beside v (B, T, H_v, d_v)
+    and beta (B, T, H_v), with q, k of H_k heads, H_v a multiple of H_k. g ≤ 0.
+    T need not be a multiple of the chunk or of the block: the tail is padded
+    with g = 0, β = 0, which leaves the state as it is. `chunk` is a multiple
+    of `SUB` or at most `SUB`. Differentiable in all five operands and the
+    initial state."""
     if chunk > SUB and chunk % SUB:
         raise ValueError(f"a chunk of {chunk} is not whole sub-blocks of {SUB}")
-    if delta_rule_route(q.shape, chunk, chunks_per_block, v.shape[-1]) == "kernel":
-        return pallas_delta_rule.delta_rule_kernels(
-            q, k, v, g, beta, chunk, chunks_per_block, compute_dtype, initial_state)
+    form = "scalar" if g.ndim == 3 else "channel"
+    h, group = v.shape[2], v.shape[2] // k.shape[2]
+    if k.shape[2] * group != h or (form == "channel" and group != 1):
+        raise ValueError(f"{k.shape[2]} key heads against {h} value heads in the "
+                         f"{form} form")
+    if delta_rule_route(q.shape, chunk, chunks_per_block, v.shape[-1], form,
+                        group) == "kernel":
+        kernels = (pallas_delta_rule.delta_rule_kernels if form == "channel"
+                   else pallas_delta_rule.delta_rule_scalar_kernels)
+        return kernels(q, k, v, g, beta, chunk, chunks_per_block, compute_dtype,
+                       initial_state)
     f32 = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
-    b, t, h, dk = k.shape
+    b, t, _, dk = k.shape
     n = min(chunks_per_block, -(-t // chunk))
     pad = -t % (chunk * n)
     nb = (t + pad) // (chunk * n)
@@ -333,6 +440,6 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, chunks_per_block: int = 
     state = (jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32)
              if initial_state is None else f32(initial_state))
     o, last = _blocks(state, tuple(map(blocked, (q, k, v, g, beta))),
-                      jnp.dtype(compute_dtype), chunk)
+                      jnp.dtype(compute_dtype), chunk, form)
     # (blocks, B, n·L, H, d_v) -> (B, T, H, d_v)
     return jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, -1)[:, :t], last

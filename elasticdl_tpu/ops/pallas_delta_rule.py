@@ -37,6 +37,17 @@ float32; the other matmuls take `compute_dtype` operands rounded AFTER the
 decay has been applied in float32 — in the backward the cotangents that take
 their places — and accumulate in float32. The `pallas_call`s are named
 `delta_rule_fwd` and `delta_rule_bwd`, so a trace names them.
+
+THE SCALAR FORM (one decay a head, `delta_rule.py`'s second form; the second
+half of this file) has a kernel pair of its own, `delta_rule_scalar_fwd` and
+`delta_rule_scalar_bwd`: the same visit, with Γ a ROW of L numbers a chunk
+(laid out as β is), M and P one (L, L) product each under ONE (L, L)
+exponential, and every other decay a scale of rows or columns. The grid is
+(sequence, KEY head, block, the r value heads that read the key head), the
+value heads innermost: q's and k's blocks do not change between a key head's
+visits, so the pipeline reads them once, and in the backward their dq and dk
+blocks stay in VMEM and are ADDED to over the r visits — no H_v-head copy of q,
+k, dq or dk exists. The r states live side by side in the VMEM scratch.
 """
 
 from __future__ import annotations
@@ -416,9 +427,10 @@ def _call(q, beta, n, l, reverse) -> _Call:
         pl.BlockSpec((None, None, d, d), lambda b, i, c: (b, i, 0, 0)))
 
 
-def _params(plan):
+def _params(plan, carried: int = 1):
+    """`carried` trailing grid axes walk in order (the state lives across them)."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel") + ("arbitrary",) * carried,
         vmem_limit_bytes=plan.vmem_limit)
 
 
@@ -523,3 +535,346 @@ def delta_rule_kernels(q, k, v, g, beta, chunk, chunks_per_block, compute_dtype,
     o, last = chunk_rule(plane(q), plane(k), plane(v), cum, beta, state, n, l,
                          jnp.dtype(compute_dtype))
     return o.reshape(b, tp, h, d)[:, :t], jnp.swapaxes(last, -1, -2)
+
+
+# ------------------------------------------------------------------ #
+# the scalar form: one decay a head, r value heads a key head
+
+
+def scalar_blocks(d_k: int, d_v: int, chunk: int, chunks_per_block: int,
+                  group: int = 1) -> Optional[Blocks]:
+    """`blocks` for the scalar form at `group` value heads a key head: d_k =
+    d_v whole lanes, a chunk of whole sublane tiles, the backward's visit
+    inside half the chip's VMEM."""
+    if d_k != d_v or d_k % LANES or chunk % 8:
+        return None
+    n = chunks_per_block
+    plane, state, square = 4 * n * chunk * d_k, 4 * d_k * d_v, 4 * chunk * chunk
+    rows = 4 * 8 * max(chunk, LANES)
+    # q, k, v, do read, dq, dk, dv written; Γ, β, dΓ, dβ; the group's states thrice
+    moved = 7 * plane + 4 * rows + 3 * group * state
+    # dSᵀ of the group, the chunk starts, M, P and X of every chunk, W and new
+    held = (group + n) * state + 3 * n * square + 2 * plane
+    values = 12 * 4 * chunk * d_k + 16 * square + 2 * state
+    need = 2 * moved + held + values
+    vmem = _vmem_bytes()
+    return Blocks(need, vmem * 3 // 4) if need <= vmem // 2 else None
+
+
+class _Decay(NamedTuple):
+    """A chunk's decays, from its Γ as a row."""
+    row: jax.Array      # exp Γ (1, L)
+    col: jax.Array      # exp Γ (L, 1)
+    both: jax.Array     # exp(Γ_r − Γ_i) for i ≤ r, 0 above the diagonal (L, L)
+    to_end: jax.Array   # exp(Γ_L − Γ) (L, 1)
+    through: jax.Array  # exp Γ_L down a column of the state's height (d, 1)
+    last: jax.Array     # exp Γ_L (1, 1)
+
+
+def _decay(g_row, mk: _Masks, d: int) -> _Decay:
+    """Every exponent a difference ≤ 0 (or Γ itself), masked BEFORE the
+    exponential. Γ_L is laid down a column by a masked lane sum: Mosaic does
+    not broadcast a (1, 1) value over sublanes and lanes at once."""
+    l = mk.l
+    g_col = mk.column(g_row)
+
+    def last_down(rows):
+        at_last = jax.lax.broadcasted_iota(jnp.int32, (rows, l), 1) == l - 1
+        return jnp.sum(jnp.where(at_last, g_row, 0.0), axis=1, keepdims=True)
+
+    both = jnp.exp(jnp.where(mk.strict | mk.eye, g_col - g_row, -jnp.inf))
+    return _Decay(jnp.exp(g_row), jnp.exp(g_col), both, jnp.exp(last_down(l) - g_col),
+                  jnp.exp(last_down(d)), jnp.exp(g_row[:, l - 1:l]))
+
+
+def _scalar_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, c, l):
+    """Chunk c's (q, k, v (L, d) each, Γ (1, L), β (1, L))."""
+    return (_read(q_ref, c, l), _read(k_ref, c, l), _read(v_ref, c, l),
+            g_ref[c:c + 1, :], beta_ref[c:c + 1, :])
+
+
+def _scalar_chunks(operands, mk: _Masks, dt):
+    """(the `_Chunk` of each of a visit's chunks in the scalar form, its
+    `_Decay`): M and P are ONE product each, decayed after it in float32."""
+    decays = [_decay(g_row, mk, k.shape[1]) for _, k, _, g_row, _ in operands]
+    made = []
+    for (q, k, _, _, _), decay in zip(operands, decays):
+        kc = k.astype(dt)
+        made.append((jnp.where(mk.strict, _nt(kc, kc) * decay.both, 0.0),
+                     _nt(q.astype(dt), kc) * decay.both))
+    xs = _inverses([-mk.column(beta_row) * m
+                    for (m, _), (*_, beta_row) in zip(made, operands)], mk)
+    chunks = []
+    for (m, p), x, decay, (_, k, v, _, beta_row) in zip(made, xs, decays, operands):
+        solved = x * beta_row
+        chunks.append(_Chunk(m, p, x, _nn((solved * decay.row).astype(dt), k.astype(dt)),
+                             _nn(solved.astype(dt), v.astype(dt))))
+    return chunks, decays
+
+
+def _scalar_after(state, new, k, decay: _Decay, dt):
+    """The state Sᵀ a chunk leaves: exp(Γ_L) Sᵀ + (exp(Γ_L − Γ) ⊙ new)ᵀ K."""
+    return delta_rule.next_state(
+        decay.through, state, _tn((new * decay.to_end).astype(dt), k.astype(dt)))
+
+
+def _scalar_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, init_ref, o_ref, starts_ref,
+                       last_ref, state_ref, *, n, l, dt):
+    j = pl.program_id(3)                # which of the key head's value heads
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_visit():
+        state_ref[j] = init_ref[j]
+
+    mk = _Masks(l, delta_rule.SUB)
+    state = state_ref[j]
+    starts_ref[j] = state
+    operands = [_scalar_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, c, l) for c in range(n)]
+    for c, (terms, decay, (q, k, *_)) in enumerate(zip(*_scalar_chunks(operands, mk, dt),
+                                                       operands)):
+        new = _written(state, terms.w, terms.u, dt)
+        o_ref[_rows(c, l), :] = (decay.col * _nt(q.astype(dt), state.astype(dt))
+                                 + _nn(terms.p.astype(dt), new.astype(dt)))
+        state = _scalar_after(state, new, k, decay, dt)
+    state_ref[j] = state
+    last_ref[j] = state
+
+
+def _scalar_pull(q, k, v, beta_row, decay: _Decay, m, p, x, w, new, state, d_o, d_state,
+                 mk: _Masks, dt):
+    """The pull-back through one chunk of the scalar form: from d_o (L, d_v)
+    and d_state (d_v, d_k), (dq, dk, dv (L, d) each, dΓ (1, L), dβ (1, L), the
+    cotangent of the state it started from). dΓ is row and column sums of
+    (L, L) products."""
+    l = mk.l
+    qc, kc, vc = q.astype(dt), k.astype(dt), v.astype(dt)
+    sc, newc, doc, dsc = state.astype(dt), new.astype(dt), d_o.astype(dt), d_state.astype(dt)
+    solved = x * beta_row                                               # A
+    solved_c = solved.astype(dt)
+    decayed = solved * decay.row                                        # A ⊙ exp Γ_i
+    written = new * decay.to_end                                        # exp(Γ_L − Γ) ⊙ new
+    # the state's sweep: new = U − W·S, O = exp Γ ⊙ (Q·S) + P·new, S' = ...
+    k_ds = _nt(kc, dsc)                                                 # K·dS' (L, d_v)
+    d_new = _tn(p.astype(dt), doc) + decay.to_end * k_ds
+    d_newc = d_new.astype(dt)
+    d_p = _nt(doc, newc)                                                # (L, L)
+    do_s = _nn(doc, sc)                                                 # dO·Sᵀ (L, d_k)
+    gated = (decay.col * d_o).astype(dt)                                # exp Γ ⊙ dO
+    d_wc = (-_nn(d_newc, sc)).astype(dt)
+    d_start = decay.through * d_state + _tn(gated, qc) - _tn(d_newc, w.astype(dt))
+    d_col = jnp.sum(q * do_s, axis=1, keepdims=True)                    # ∂/∂ exp Γ_r, of O
+    d_to_end = jnp.sum(new * k_ds, axis=1, keepdims=True)               # ∂/∂ exp(Γ_L − Γ_r)
+    d_through = jnp.sum(jnp.sum(d_state * state, axis=1, keepdims=True), axis=0, keepdims=True)
+    # W = (A ⊙ exp Γ) K, U = A V, A = X Diag(β), X = (I − N)⁻¹, N = −Diag(β) M
+    d_decayed = _nt(d_wc, kc)                                           # (L, L)
+    d_a = d_decayed * decay.row + _nt(d_newc, vc)
+    d_row = jnp.sum(d_decayed * solved, axis=0, keepdims=True)          # ∂/∂ exp Γ_i, of W
+    d_v = _tn(solved_c, d_newc)
+    d_n = _exact(_exact(x, d_a * beta_row, ((0,), (0,))), x, ((1,), (1,)))      # Xᵀ X̄ Xᵀ
+    d_beta = (jnp.sum(d_a * x, axis=0, keepdims=True)
+              - mk.row(jnp.sum(d_n * m, axis=1, keepdims=True)))
+    d_m = jnp.where(mk.strict, -mk.column(beta_row) * d_n, 0.0)
+    # M = tril(K Kᵀ, −1) ⊙ D, P = tril(Q Kᵀ) ⊙ D: the products' cotangents
+    # stacked, one matmul a side
+    d_products = jnp.concatenate([d_m * decay.both, d_p * decay.both], axis=0).astype(dt)
+    by_k = _nn(d_products, kc)                                          # (2L, d_k)
+    d_q = decay.col * do_s + by_k[l:]
+    d_k = (_nn(written.astype(dt), dsc) + _tn(decayed.astype(dt), d_wc) + by_k[:l]
+           + _tn(d_products, jnp.concatenate([kc, qc], axis=0)))
+    # Γ: D_ri = exp(Γ_r − Γ_i) gives +row sums and −column sums of D̄ ⊙ D
+    through_d = d_m * m + d_p * p
+    d_last = (jnp.sum(d_to_end * decay.to_end, axis=0, keepdims=True)
+              + d_through * decay.last)                                 # (1, 1)
+    at_last = jax.lax.broadcasted_iota(jnp.int32, (1, l), 1) == l - 1
+    d_g = (mk.row(jnp.sum(through_d, axis=1, keepdims=True) + d_col * decay.col
+                  - d_to_end * decay.to_end)
+           - jnp.sum(through_d, axis=0, keepdims=True) + d_row * decay.row
+           + jnp.where(at_last, d_last, 0.0))
+    return d_q, d_k, d_v, d_g, d_beta, d_start
+
+
+def _scalar_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, dlast_ref,
+                       dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dinit_ref,
+                       dstate_ref, states_ref, m_ref, p_ref, x_ref, w_ref, new_ref,
+                       *, n, l, dt):
+    j = pl.program_id(3)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_visit():
+        dstate_ref[j] = dlast_ref[j]
+
+    mk = _Masks(l, delta_rule.SUB)
+    operands = [_scalar_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, c, l) for c in range(n)]
+    # the block's forward again, its terms left in VMEM
+    chunks, decays = _scalar_chunks(operands, mk, dt)
+    for c, terms in enumerate(chunks):
+        m_ref[c], p_ref[c], x_ref[c], w_ref[c] = terms.m, terms.p, terms.x, terms.w
+        new_ref[c] = terms.u                    # U until the sweep makes new of it
+    state = starts_ref[j]
+    for c in range(n):
+        states_ref[c] = state
+        new = _written(state, w_ref[c], new_ref[c], dt)
+        new_ref[c] = new
+        if c + 1 < n:
+            state = _scalar_after(state, new, operands[c][1], decays[c], dt)
+    # and the pull-back, the chunks from the last. dq and dk are the KEY
+    # head's: its block stays in VMEM over the r visits, the first writes it
+    # and the others add to it
+    d_state = dstate_ref[j]
+    for c in reversed(range(n)):
+        q, k, v, _, beta_row = operands[c]
+        d_q, d_k, d_v, d_g, d_beta, d_state = _scalar_pull(
+            q, k, v, beta_row, decays[c], m_ref[c], p_ref[c], x_ref[c], w_ref[c], new_ref[c],
+            states_ref[c], _read(do_ref, c, l), d_state, mk, dt)
+        rows = _rows(c, l)
+        dq_ref[rows, :] = jnp.where(j > 0, dq_ref[rows, :], 0.0) + d_q
+        dk_ref[rows, :] = jnp.where(j > 0, dk_ref[rows, :], 0.0) + d_k
+        dv_ref[rows, :] = d_v
+        dg_ref[c:c + 1, :], dbeta_ref[c:c + 1, :] = d_g, d_beta
+    dstate_ref[j] = d_state
+    dinit_ref[j] = d_state
+
+
+class _ScalarCall(NamedTuple):
+    """What the scalar form's two `pallas_call`s take from their operands'
+    shapes: the grid (sequence, key head, block, value head of the key head)."""
+    grid: tuple
+    chunks: int
+    plan: Blocks
+    of_key: pl.BlockSpec        # q, k, dq, dk: the KEY head's (n·L, d)
+    of_value: pl.BlockSpec      # v, o, do, dv: the value head's
+    rows: pl.BlockSpec          # Γ, β and their cotangents: (n, L)
+    of_block: pl.BlockSpec      # the r block-start states of the key head
+    of_head: pl.BlockSpec       # the r first or last states
+
+
+def _scalar_call(q, v, beta, n, l, reverse) -> _ScalarCall:
+    bsz, tp, _ = q.shape
+    hv, nb = beta.shape[1:3]
+    d = v.shape[2] // hv
+    hk = q.shape[2] // d
+    r = hv // hk
+    plan = scalar_blocks(d, d, l, n, r)
+    if plan is None:
+        raise ValueError(f"the scalar delta-rule kernels do not take heads of {d} at "
+                         f"blocks of {n} chunks of {l}, {r} value heads a key head")
+    at = (lambda c: nb - 1 - c) if reverse else (lambda c: c)
+    return _ScalarCall(
+        (bsz, hk, nb, r), bsz * hv * nb * n, plan,
+        pl.BlockSpec((None, n * l, d), lambda b, i, c, j: (b, at(c), i)),
+        pl.BlockSpec((None, n * l, d), lambda b, i, c, j: (b, at(c), i * r + j)),
+        pl.BlockSpec((None, None, None, n, l), lambda b, i, c, j: (b, i * r + j, at(c), 0, 0)),
+        pl.BlockSpec((None, None, None, r, d, d), lambda b, i, c, j: (b, i, at(c), 0, 0, 0)),
+        pl.BlockSpec((None, None, r, d, d), lambda b, i, c, j: (b, i, 0, 0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("n", "l", "dt", "interpret"))
+def _scalar_forward(q, k, v, g, beta, state, *, n, l, dt, interpret):
+    bsz, tp, _ = q.shape
+    call = _scalar_call(q, v, beta, n, l, reverse=False)
+    (_, hk, nb, r), d = call.grid, state.shape[-1]
+    hv = hk * r
+    return pl.pallas_call(
+        functools.partial(_scalar_fwd_kernel, n=n, l=l, dt=dt),
+        grid=call.grid,
+        in_specs=[call.of_key, call.of_key, call.of_value, call.rows, call.rows, call.of_head],
+        out_specs=[call.of_value, call.of_block, call.of_head],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, _F32),
+                   jax.ShapeDtypeStruct((bsz, hk, nb, r, d, d), _F32),
+                   jax.ShapeDtypeStruct((bsz, hk, r, d, d), _F32)],
+        scratch_shapes=[pltpu.VMEM((r, d, d), _F32)],
+        compiler_params=_params(call.plan, carried=2),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * call.chunks * (5 * l * l * d + 3 * l * d * d + 10 * l * l * l),
+            transcendentals=call.chunks * (l * l + 4 * l),
+            bytes_accessed=4 * (2 * bsz * tp * (hk + hv) * d + bsz * hv * (nb + 2) * d * d)),
+        interpret=interpret,
+        name="delta_rule_scalar_fwd",
+    )(q, k, v, g, beta, state)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "l", "dt", "interpret"))
+def _scalar_backward(q, k, v, g, beta, starts, d_o, d_last, *, n, l, dt, interpret):
+    bsz, tp, _ = q.shape
+    call = _scalar_call(q, v, beta, n, l, reverse=True)
+    (_, hk, nb, r), d = call.grid, starts.shape[-1]
+    hv = hk * r
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, _F32)
+    return pl.pallas_call(
+        functools.partial(_scalar_bwd_kernel, n=n, l=l, dt=dt),
+        grid=call.grid,
+        in_specs=[call.of_key, call.of_key, call.of_value, call.rows, call.rows,
+                  call.of_block, call.of_value, call.of_head],
+        out_specs=[call.of_key, call.of_key, call.of_value, call.rows, call.rows,
+                   call.of_head],
+        out_shape=[like(q), like(k), like(v), like(g), like(beta), like(d_last)],
+        scratch_shapes=[pltpu.VMEM((r, d, d), _F32), pltpu.VMEM((n, d, d), _F32),
+                        pltpu.VMEM((n, l, l), _F32), pltpu.VMEM((n, l, l), _F32),
+                        pltpu.VMEM((n, l, l), _F32), pltpu.VMEM((n, l, d), _F32),
+                        pltpu.VMEM((n, l, d), _F32)],
+        compiler_params=_params(call.plan, carried=2),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * call.chunks * (17 * l * l * d + 9 * l * d * d + 12 * l * l * l),
+            transcendentals=call.chunks * 2 * (l * l + 4 * l),
+            bytes_accessed=4 * (4 * bsz * tp * (hk + hv) * d
+                                + bsz * hv * (nb + 2) * d * d)),
+        interpret=interpret,
+        name="delta_rule_scalar_bwd",
+    )(q, k, v, g, beta, starts, d_o, d_last)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def scalar_chunk_rule(q, k, v, g, beta, state, n, l, dt):
+    """The scalar form over whole blocks of `n` chunks of `l` tokens. q, k
+    (B, T, H_k·d), v (B, T, H_v·d) float32; Γ = g and beta (B, H_v, T/(n·l),
+    n, l); state (B, H_k, r, d_v, d_k), transposed. Returns (o (B, T, H_v·d)
+    float32, the last state as `state`). Differentiable in all six; the
+    residuals are the first five and the block-start states."""
+    o, _, last = _scalar_forward(q, k, v, g, beta, state, n=n, l=l, dt=dt,
+                                 interpret=kernel_interpret())
+    return o, last
+
+
+def _scalar_chunk_rule_fwd(q, k, v, g, beta, state, n, l, dt):
+    o, starts, last = _scalar_forward(q, k, v, g, beta, state, n=n, l=l, dt=dt,
+                                      interpret=kernel_interpret())
+    o = checkpoint_name(o, delta_rule.RESIDUAL_NAMES[0])
+    starts = checkpoint_name(starts, delta_rule.RESIDUAL_NAMES[1])
+    return (o, last), (q, k, v, g, beta, starts)
+
+
+def _scalar_chunk_rule_bwd(n, l, dt, kept, cts):
+    with jax.named_scope("delta_rule"):
+        return tuple(_scalar_backward(*kept, *cts, n=n, l=l, dt=dt,
+                                      interpret=kernel_interpret()))
+
+
+scalar_chunk_rule.defvjp(_scalar_chunk_rule_fwd, _scalar_chunk_rule_bwd)
+
+
+def delta_rule_scalar_kernels(q, k, v, g, beta, chunk, chunks_per_block, compute_dtype,
+                              initial_state=None):
+    """`delta_rule.gated_delta_rule` in the scalar form on the kernel route:
+    q, k (B, T, H_k, d), v (B, T, H_v, d), g, beta (B, T, H_v). Γ — a (B, T,
+    H_v) plane — and the rows' layout are made here, in float32."""
+    b, t, hk, d = k.shape
+    hv = v.shape[2]
+    r = hv // hk
+    l = chunk
+    n = min(chunks_per_block, -(-t // l))
+    pad = -t % (l * n)
+    tp = t + pad
+    f32 = lambda a: a.astype(_F32)
+    widen = lambda a: jnp.pad(f32(a), ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+    plane = lambda a: widen(a).reshape(b, tp, -1)
+    # (B, T, H_v) -> (B, H_v, blocks, n, L): a chunk's numbers a row
+    rows = lambda a: a.reshape(b, tp // (l * n), n, l, hv).transpose(0, 4, 1, 2, 3)
+    cum = delta_rule.cumulative_log_decay(
+        widen(g).reshape(b, tp // l, l, hv)).reshape(b, tp, hv)
+    state = (jnp.zeros((b, hv, d, d), _F32) if initial_state is None
+             else jnp.swapaxes(f32(initial_state), -1, -2))
+    o, last = scalar_chunk_rule(plane(q), plane(k), plane(v), rows(cum), rows(widen(beta)),
+                                state.reshape(b, hk, r, d, d), n, l,
+                                jnp.dtype(compute_dtype))
+    return (o.reshape(b, tp, hv, d)[:, :t],
+            jnp.swapaxes(last.reshape(b, hv, d, d), -1, -2))

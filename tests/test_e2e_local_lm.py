@@ -453,3 +453,38 @@ def test_local_lfm2_moe_job_end_to_end(tmp_path):
     assert bias.shape == (2, 8) and float(np.max(np.abs(bias))) > 0
     # no kernel on the CPU: every convolution took the plain route
     assert int(restored.extra_vars["conv"]["kernel_convs"]) == 0
+
+
+def test_local_qwen3_next_job_end_to_end(tmp_path):
+    """Qwen3-Next's two kinds of layer by PUBLISHED index (a Gated DeltaNet
+    layer — the scalar delta rule, 2 key heads read by 4 value heads — and the
+    gated attention layer; 4 of 16 softmax-routed experts held beside the gated
+    shared expert; the head's loss in row blocks, the auxiliary loss sown)
+    through the same master/worker path, evaluation included."""
+    cfg = job_config(
+        tmp_path,
+        model_def="transformer.qwen3_next.custom_model",
+        model_params={
+            "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 2,
+            "kept_layers": "2,3", "linear_key_head_dim": 16, "linear_value_head_dim": 16,
+            "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+            "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+            "num_experts": 4, "router_experts": 16, "first_expert": 4,
+            "num_experts_per_tok": 3, "moe_intermediate_size": 24,
+            "shared_expert_intermediate_size": 24, "gdn_chunk": 16,
+            "compute_dtype": "float32",
+        },
+        training_data="synthetic://lm?n=128&shards=4&vocab=256&seq=32",
+        validation_data="synthetic://lm?n=16&shards=1&vocab=256&seq=32",
+        records_per_task=32,
+        minibatch_size=4,
+        steps_per_dispatch=4,
+    )
+    master, _, counts = run_job(cfg, tmp_path, master_of=patient_master)
+    assert counts["finished_training"] == 4      # 128 / 32
+    assert counts["failed_permanently"] == 0
+    results = master.evaluation.latest_results()
+    assert 0.0 <= results["token_accuracy"] <= 1.0
+    assert set(results) >= {"gdn_log_decay_min", "gdn_beta_mean", "gdn_state_rms"}
+    assert results["gdn_log_decay_min"] < 0 < results["gdn_beta_mean"] < 1
+    assert master.servicer.mean_training_loss() < 6.0       # ln 256 = 5.5, + 0.002 auxiliary

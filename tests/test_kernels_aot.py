@@ -336,6 +336,39 @@ def test_delta_rule_kernels_compile_for_a_v5e(one_chip, no_compile_cache):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
+# qwen3-next-80b-a3b.resident-16k's recurrence: the SCALAR form, one sequence
+# of 16 384 tokens, 16 key heads read by 32 value heads of 128, chunks of 64 in
+# blocks of 4
+SCALAR_DELTA_RULE = dict(tokens=16384, key_heads=16, value_heads=32, head_dim=128, chunk=64,
+                         chunks_per_block=4)
+
+
+def test_scalar_delta_rule_kernels_compile_for_a_v5e(one_chip, no_compile_cache):
+    """Float32 in, bfloat16 operands: the forward and the backward on the grid
+    (sequence, key head, block, value head of the key head) — q, k, dq and dk
+    at the KEY heads' width, g and β as (T, 32) planes — inside the VMEM the
+    rule finds room for. Mosaic takes the r states side by side in the scratch
+    and the key head's dq, dk added to over its value heads' visits."""
+    t, hk, hv, d, l, n = SCALAR_DELTA_RULE.values()
+    plan = pallas_delta_rule.scalar_blocks(d, d, l, n, hv // hk)
+    assert plan is not None and plan.vmem_bytes <= pallas_delta_rule._vmem_bytes() // 2
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def forward_and_backward(q, k, v, g, beta, d_o, d_last):
+        (o, last), vjp = jax.vjp(
+            lambda *a: pallas_delta_rule.delta_rule_scalar_kernels(*a, l, n, jnp.bfloat16),
+            q, k, v, g, beta)
+        return o, last, vjp((d_o, d_last))
+
+    keys, values, rows = shape(1, t, hk, d), shape(1, t, hv, d), shape(1, t, hv)
+    text = jax.jit(forward_and_backward).lower(
+        keys, keys, values, rows, rows, values, shape(1, hv, d, d)).compile().as_text()
+    for kernel in ("delta_rule_scalar_fwd", "delta_rule_scalar_bwd"):
+        assert text.count("%" + kernel) >= 1, kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "%delta_rule_fwd" not in text and "%delta_rule_bwd" not in text
+
+
 # the depthwise convolutions of kimi-linear-48b-a3b.resident-16k's KDA layers
 # (q, k, v: no bias) and of nemotron-3-nano-30b-a3b.resident-8k's Mamba layers
 # (xBC, a bias): (tokens, channels, a bias), 4 taps

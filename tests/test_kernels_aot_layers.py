@@ -576,3 +576,71 @@ def test_lfm2_s_attention_layer_holds_one_flash_forward_and_its_convolution_laye
         f"lfm2/conv/{part}" for part in ("in_proj", "gate_in", "conv", "gate_out", "out_proj")} | {
         f"lfm2/attn/{part}" for part in ("qkv", "qk_norm", "rope", "attn", "out")}
     assert_the_first_pass_reads_under_the_pass_s_own_scopes(text, "lfm2_moe")
+
+
+def test_qwen3_next_s_two_kinds_of_layer_compile_under_their_scopes_and_keep_no_wide_decay(
+        one_chip, no_compile_cache, monkeypatch):
+    """qwen3-next-80b-a3b.resident-16k at its published widths — published
+    layers 2 and 3, a Gated DeltaNet layer and the gated attention layer,
+    16 384 tokens, 16 key heads read by 32 value heads of 128, 16 query heads
+    on 2 key-value heads of 256, 32 of 512 experts held — through the zoo's own
+    loss (the head in row blocks). The SCALAR delta rule compiles as ONE
+    `delta_rule_scalar_fwd` (the recomputed layer keeps its two named arrays)
+    and ONE `delta_rule_scalar_bwd`, both under `qwen3_next/gdn/delta_rule`,
+    and no channel-wise kernel; the ONE convolution over q | k | v's 8192
+    channels is the kernels under `qwen3_next/gdn/conv`; ONE flash forward and
+    ONE backward at heads of 256 under `qwen3_next/attn/flash`. In the compiled
+    text there is NO (16 384, 32, 128) float32 plane that g, Γ or their
+    exponentials could be — every array of that shape is the gated norm's (o, z
+    and silu(z) a value head) — and no 32-head copy of q or k: the kernels' q,
+    k, dq and dk operands are (1, 16 384, 2048)."""
+    from model_zoo.transformer import qwen3_next
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the routes ask
+    net = qwen3_next.custom_model(num_hidden_layers=2, kept_layers="2,3", num_experts=32,
+                                  router_experts=512, vocab_size=512)
+    assert [net.cfg.kind(l) for l in net.cfg.layers] == ["linear_attention", "full_attention"]
+    assert (net.cfg.key_width, net.cfg.value_width, net.cfg.value_group) == (2048, 4096, 2)
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    variables = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(net.init, jax.random.PRNGKey(0), tokens))
+
+    def loss(params, state, tokens):
+        outputs = net.apply({"params": params, **state}, tokens)
+        return jnp.sum(qwen3_next.loss(tokens, outputs)["loss"])
+
+    params = variables.pop("params")
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, variables, tokens).compile().as_text()
+    found = scopes_of(text, "qwen3_next")
+    rule = re.findall(r"^\s*%?(delta_rule_[\w.]+) = ", text, re.M)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name in rule) == [
+        "delta_rule_scalar_bwd", "delta_rule_scalar_fwd"]
+    assert {found.get(name) for name in rule} == {"qwen3_next/gdn/delta_rule"}
+    calls = re.findall(r"^\s*%?(flash_attention_[\w.]+) = ", text, re.M)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name in calls) == [
+        "flash_attention_bwd", "flash_attention_fwd"]
+    assert {found.get(name) for name in calls} == {"qwen3_next/attn/flash"}
+    assert_the_convolutions_are_the_kernels(text, "qwen3_next", "qwen3_next/gdn/conv", 1)
+    assert set(found.values()) >= {
+        "qwen3_next/embed", "qwen3_next/head_loss"} | {
+        f"qwen3_next/gdn/{part}" for part in (
+            "proj", "conv", "qk_norm", "gates", "delta_rule", "gate_norm", "out")} | {
+        f"qwen3_next/attn/{part}" for part in (
+            "proj", "qk_norm", "rope", "flash", "gate", "out")} | {
+        f"qwen3_next/moe/{part}" for part in ("router", "experts", "shared")}
+    assert_the_first_pass_reads_under_the_pass_s_own_scopes(text, "qwen3_next")
+    # the scalar kernels take q and k at their own 16 heads, Γ and β as rows
+    for line in (l for l in text.splitlines() if re.match(r"\s*%?delta_rule_scalar_bwd", l)):
+        operands = line[line.index("custom-call("):]
+        assert operands.count("f32[1,16384,2048]") == 2             # q, k
+        assert "f32[1,32,64,4,64]" in operands                      # Γ, β: (B, H_v, blocks, n, L)
+        assert "f32[1,16384,32,128]" not in operands
+    # what IS of (T, 32, 128) float32 is the gated norm's: o, z and silu(z) a
+    # value head — nothing under the decay's, the recurrence's, the L2 norms' or
+    # the convolution's scope, where a widened g, Γ, exp Γ or a repeated q or k
+    # would be
+    wide = [re.search(r'op_name="([^"]*)"', line) for line in text.splitlines()
+            if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = f32\[(?:1,)?16384,32,128\]", line)]
+    named = [m.group(1) for m in wide if m]
+    assert named and all("gdn/gate_norm" in name for name in named), sorted(set(named))[:5]

@@ -643,3 +643,56 @@ def test_held_all_is_the_plain_dispatch_and_a_wrong_share_is_refused():
                                rtol=1e-4, atol=1e-5)
     with pytest.raises(ValueError, match="held="):
         moe_ops.dropless_moe(x, idx, weights, w, held=(0, 2), num_experts=8)
+
+
+# ------------------------------------------------------------------ #
+# 512 router outputs, top-10, 32 held (qwen3-next-80b-a3b.resident-16k)
+
+
+def test_the_passes_of_a_share_of_512_experts_are_sized_from_the_shapes():
+    """163 840 pairs a layer sorted over 512 experts of which 32 are held: a
+    pass of 20 480 rows (twice the even share), 80 row tiles of 256, chunks of
+    1280 rows — a sixteenth of the pass."""
+    pairs = 16384 * 10
+    rows = moe_ops.held_pass_rows(pairs, 512, 32)
+    assert rows == 20480 == 2 * pairs * 32 // 512
+    assert moe_ops.held_row_chunk(rows) == 1280
+    # at even routing 10 240 pairs are held: half the pass's tiles, chunks
+    assert int(moe_ops.held_row_tiles(jnp.int32(10240), pairs, 512, 32)) == 40
+    assert int(moe_ops.held_row_chunks(jnp.int32(10240), pairs, 512, 32)) == 8
+    # more than twice the even share overflows into a second pass
+    assert int(moe_ops.held_row_chunks(jnp.int32(20481), pairs, 512, 32)) == 17
+
+
+@pytest.mark.parametrize("first", [0, 480])
+def test_a_softmax_top10_of_512_held_32_matches_the_loop_over_the_held(first):
+    """The softmax router at 512 outputs and k = 10, renormalised, through the
+    held dispatch at 32 experts of width 8: values and the gradients of the
+    tokens, the weights and every matrix, against every held expert on every
+    token and a mask; a token's ten experts are distinct and its weights sum
+    to one."""
+    n, c, f, e, k, held = 96, 16, 8, 512, 10, (first, 32)
+    r = np.random.default_rng(7)
+    x = jnp.asarray(r.normal(size=(n, c)), jnp.float32)
+    w = tuple(jnp.asarray(r.normal(size=s) * 0.3, jnp.float32)
+              for s in ((32, c, f), (32, c, f), (32, f, c)))
+    logits = jnp.asarray(r.normal(size=(n, e)) * 2.0, jnp.float32)
+    probs, weights, idx = moe_ops.topk_route(logits, k)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    assert all(len(set(row)) == k for row in np.asarray(idx).tolist())
+    np.testing.assert_allclose(np.sum(weights, axis=-1), 1.0, atol=1e-6)
+    balance, _ = moe_ops.router_aux_losses(logits, probs, idx)
+    assert 0.9 < float(balance) < 3.0                 # 1.0 at perfect balance
+    on_held = int(np.sum((np.asarray(idx) >= first) & (np.asarray(idx) < first + 32)))
+    assert 20 < on_held < 120                         # about 960 / 16
+    held_moe = lambda x, weights, *w: moe_ops.dropless_moe(
+        x, idx, weights, w, held=held, num_experts=e, compute_dtype=jnp.float32)
+    loop = lambda x, weights, *w: gated_silu_loop(x, idx, weights, *w, held)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(held_moe(x, weights, *w), loop(x, weights, *w),
+                                   rtol=1e-4, atol=1e-5)
+        scalar = lambda f: (lambda *a: jnp.sum(jnp.sin(f(*a))))
+        got = jax.grad(scalar(held_moe), argnums=range(5))(x, weights, *w)
+        want = jax.grad(scalar(loop), argnums=range(5))(x, weights, *w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
