@@ -87,14 +87,15 @@ lie, nothing of a chunk's size in HBM — where they can run (a TPU, or
 interpret mode in the CPU tests) and the shapes fit (d_k = d_v whole lanes, a
 chunk of whole sub-blocks, a visit's blocks inside VMEM). "plain": this file's
 body, XLA's batched matmuls under two `lax.scan`s, everywhere else — the CPU's
-route and the kernels' yardstick. Both take Γ from `cumulative_log_decay`,
+route and the kernels' yardstick. Both take Γ from `cumulative_log_decay` — a
+triangular product in XLA on both routes, never a sum inside the kernels —,
 move the state through `next_state` (looked up in this module when a program
 is traced: the benchmark's rehearsal patches them by name), keep the same two
 named arrays and give the same values within rounding — in both forms: the
 scalar form has a plain body (`_block_scalar`) and a kernel pair of its own
 (`delta_rule_scalar_fwd` / `_bwd`), and shares `unit_lower_inverse`, the block
-walk, the residuals, `cumulative_log_decay` (over a (…, L, 1) plane) and
-`next_state`.
+walk, the residuals, `cumulative_log_decay` (over a (…, L, H_v) plane on the
+kernel route, a (…, L, 1) view on the plain one) and `next_state`.
 """
 
 from __future__ import annotations
@@ -182,8 +183,16 @@ unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 def cumulative_log_decay(g):
     """Γ of chunks g (..., L, d): the inclusive sum over a chunk's tokens,
-    float32."""
-    return jnp.cumsum(g, axis=-2)
+    float32 — as ONE product of the (L, L) lower triangle of ones with the
+    chunk, at the highest precision: the float32 operand is split into
+    bfloat16 parts that sum to it, every product with a 0 or a 1 is exact and
+    the sums accumulate in float32, so this is the float32 cumulative sum in
+    another order of additions (on the chip a product at the plane's rate of
+    bytes, where a scan is a `reduce_window` between two relayouts; its
+    pull-back is the transposed triangle's product)."""
+    l = g.shape[-2]
+    lower = jnp.tril(jnp.ones((l, l), g.dtype))
+    return jnp.einsum("ij,...jd->...id", lower, g, precision=_HIGHEST)
 
 
 def next_state(through, state, added):

@@ -25,10 +25,14 @@ then walks the block's chunks backwards and pulls back through them
 (`N̄ = Xᵀ X̄ Xᵀ`), and writes dq, dk, dv, dΓ and dβ.
 
 What stays in XLA (`delta_rule_kernels`): padding T to whole blocks, Γ =
-`delta_rule.cumulative_log_decay(g)` inside a chunk (a cumulative sum in the
-kernel would be a triangular matmul that rounds its float32 operand) and its
-pull-back, β's layout (a chunk's strengths a row: (B, H, blocks, n, L)); JAX
-differentiates those, the `custom_vjp` covers the kernels alone.
+`delta_rule.cumulative_log_decay(g)` inside a chunk and its pull-back — since
+PR 65 a triangular product at the highest precision, exact for a float32
+operand against ones (at the DEFAULT precision it would round the operand to
+bfloat16); it is XLA's and not the kernels' first lines because the
+benchmark's rehearsal patches that function by name to round Γ, and a sum
+made in here would pass the patch by — and β's layout (a chunk's strengths a
+row: (B, H, blocks, n, L)); JAX differentiates those, the `custom_vjp` covers
+the kernels alone.
 
 Precision, as the plain body's: Γ, every exponential (every exponent a
 difference ≤ 0), β, the diagonal sub-blocks, the inverse (float32 in and out,

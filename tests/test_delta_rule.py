@@ -5,7 +5,10 @@ strong that a quotient form `(K ⊙ exp Γ)(K ⊘ exp Γ)ᵀ` would overflow; th
 rule's two limits (β = 0: pure decay; g = 0 with orthonormal keys: a pure
 delta rule); and, with one decay a head and the erase term off, `ops/ssm.py`'s
 recurrence on the same operands. The same values and gradients on the KERNEL
-route (`ops/pallas_delta_rule.py` in interpret mode) at lane-wide heads.
+route (`ops/pallas_delta_rule.py` in interpret mode) at lane-wide heads, and
+the two routes against each other in both forms. Γ's own function
+(`cumulative_log_decay`, a triangular product since PR 65) against numpy's
+float64 running sum, its pull-back, and what it lowers to.
 """
 
 import jax
@@ -118,10 +121,10 @@ def wide_operands(strength):
 def take_route(monkeypatch):
     """`take_route("kernel")` runs the Pallas kernels in interpret mode;
     "plain" is what the CPU takes anyway."""
-    def take(route):
+    def take(route, form="channel"):
         if route == "kernel":
             monkeypatch.setenv(pallas_attention._INTERPRET_ENV, "1")
-        assert dr.delta_rule_route((B, T, 2, WIDE), 16, 2) == route
+        assert dr.delta_rule_route((B, T, 2, WIDE), 16, 2, WIDE, form) == route
     return take
 
 
@@ -169,20 +172,28 @@ def test_gradient_matches_the_recurrence_at_lane_wide_heads(
     np.testing.assert_allclose(got, want[operand], atol=5e-5 * scale)
 
 
-def test_the_kernel_route_takes_a_given_state_and_returns_the_last(take_route):
-    """From a state that is not zero, both results and the state's own
-    gradient as the plain route's."""
-    args = wide_operands(1.0)
+@pytest.mark.parametrize("strength", [1.0, 8.0])
+@pytest.mark.parametrize("form", ["channel", "scalar"])
+def test_the_kernel_route_takes_a_given_state_and_returns_the_last(take_route, form, strength):
+    """From a state that is not zero, both results, the state's own gradient
+    and g's — what reads Γ, which both routes take from
+    `cumulative_log_decay` — as the plain route's, in both forms (the scalar
+    one: the first channel's decay for the whole head)."""
+    q, k, v, g, beta = wide_operands(strength)
+    g = g if form == "channel" else g[..., 0]
     state = jax.random.normal(jax.random.PRNGKey(3), (B, 2, WIDE, WIDE))
-    rule = lambda state: (lambda o, last: jnp.sum(o) + jnp.sum(last * last))(
-        *dr.gated_delta_rule(*args, chunk=16, chunks_per_block=2, compute_dtype=jnp.float32,
-                             initial_state=state))
+    rule = lambda state, g: (lambda o, last: jnp.sum(o) + jnp.sum(last * last))(
+        *dr.gated_delta_rule(q, k, v, g, beta, chunk=16, chunks_per_block=2,
+                             compute_dtype=jnp.float32, initial_state=state))
     with jax.default_matmul_precision("highest"):
-        want = jax.jit(jax.value_and_grad(lambda s: rule(s)))(state)
-        take_route("kernel")
-        got = jax.jit(jax.value_and_grad(lambda s: rule(s)))(state)
+        take_route("plain", form)
+        want = jax.jit(jax.value_and_grad(lambda s, g: rule(s, g), argnums=(0, 1)))(state, g)
+        take_route("kernel", form)
+        got = jax.jit(jax.value_and_grad(lambda s, g: rule(s, g), argnums=(0, 1)))(state, g)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
-    np.testing.assert_allclose(got[1], want[1], atol=1e-4 * float(jnp.max(jnp.abs(want[1]))))
+    for a, b in zip(got[1], want[1]):
+        assert float(jnp.max(jnp.abs(b))) > 0
+        np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.max(jnp.abs(b))))
 
 
 def test_the_route_follows_the_head_width_and_what_can_run(monkeypatch):
@@ -208,6 +219,67 @@ def test_the_overflow_case_is_one_a_quotient_form_fails():
     assert float(jnp.min(cum)) < -88.0
     assert np.isinf(np.asarray(jnp.exp(-cum))).any()
     assert np.isnan(np.asarray(jnp.exp(cum) * jnp.exp(-cum))).any()
+
+
+# Γ's own cases: (B, H, L, d) chunks at Kimi's kind of plane, at a narrow one
+# and at the plain route's scalar view (…, L, 1)
+GAMMA_SHAPES = [(2, 3, 64, 256), (2, 3, 64, 4), (2, 3, 16, 1)]
+
+
+def gamma_operands(shape):
+    """g in the overflow case's range (`operands(8.0)`: −8 · softplus(normal),
+    so that Γ runs below −88 in a chunk of 64) and a cotangent."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 2)
+    return (-8.0 * jax.nn.softplus(jax.random.normal(keys[0], shape)),
+            jax.random.normal(keys[1], shape))
+
+
+def float32_unit(terms):
+    """The float32 unit a sum of a chunk's terms is held to: that of Σ |term|
+    over the chunk, a (chunk, channel) — for g ≤ 0 the magnitude of Γ at the
+    chunk's end."""
+    return np.spacing(np.sum(np.abs(np.asarray(terms, np.float64)), axis=-2,
+                             keepdims=True).astype(np.float32))
+
+
+@pytest.mark.parametrize("shape", GAMMA_SHAPES, ids=str)
+def test_gamma_is_the_running_sum_of_a_chunk(shape):
+    """The triangular product against numpy's float64 cumulative sum, within
+    eight float32 units of Γ's magnitude at the chunk's end (|Γ| ≈ 530): the
+    same float32 additions in another order — 64 of them in a row read up to
+    6.4 units over the 98 304 sums of the widest case and five seeds here, 4.1
+    at four channels, where `jnp.cumsum`'s tree of them read 2.7 and 1.9; a
+    bfloat16 Γ is 32 768 units off."""
+    g, _ = gamma_operands(shape)
+    want = np.cumsum(np.asarray(g, np.float64), axis=-2)
+    got = jax.jit(dr.cumulative_log_decay)(g)
+    assert got.dtype == jnp.float32 and got.shape == shape
+    assert shape[-2] < 64 or want.min() < -88.0
+    assert np.all(np.abs(np.asarray(got, np.float64) - want) <= 8 * float32_unit(g))
+
+
+@pytest.mark.parametrize("shape", GAMMA_SHAPES, ids=str)
+def test_gamma_pulls_back_to_the_reversed_running_sum(shape):
+    """dg_i = Σ_{r ≥ i} dΓ_r, the transposed triangle's product: within four
+    units of Σ |dΓ| over the chunk (terms of both signs: 2.2 read here)."""
+    g, ct = gamma_operands(shape)
+    want = np.flip(np.cumsum(np.flip(np.asarray(ct, np.float64), -2), axis=-2), -2)
+    got, = jax.jit(lambda g, ct: jax.vjp(dr.cumulative_log_decay, g)[1](ct))(g, ct)
+    assert got.dtype == jnp.float32 and got.shape == shape
+    assert np.all(np.abs(np.asarray(got, np.float64) - want) <= 4 * float32_unit(ct))
+
+
+@pytest.mark.parametrize("direction", ["forward", "pull-back"])
+def test_gamma_is_a_product_and_no_windowed_reduction(direction):
+    """What the chip is handed: a `dot_general` at the highest precision in
+    either direction, and no `reduce_window` (XLA:TPU runs that one between two
+    relayouts of the plane: PERF.md §6, PR 65)."""
+    g = jnp.zeros((1, 4, 64, 256), jnp.float32)
+    f = (dr.cumulative_log_decay if direction == "forward"
+         else lambda ct: jax.vjp(dr.cumulative_log_decay, g)[1](ct)[0])
+    text = jax.jit(f).lower(g).as_text()
+    assert text.count("dot_general") == 1 and "HIGHEST" in text
+    assert "reduce_window" not in text and "cumsum" not in text
 
 
 def test_beta_zero_is_pure_decay():

@@ -381,6 +381,15 @@ def test_kimi_linear_s_two_kinds_of_layer_compile_under_their_scopes(
         "delta_rule_bwd", "delta_rule_fwd"]
     assert {found.get(name) for name in rule} == {"kimi_linear/kda/delta_rule"}
     assert not re.search(r"kda/delta_rule/[^\"]*while", text)
+    # Γ's in-chunk sum is a product at the highest precision under the same
+    # scope — forward, recomputed, pulled back — and no windowed reduction
+    # between relayouts of the (16 384, 4096) plane (PR 65)
+    under_rule = [line for line in text.splitlines() if "kda/delta_rule/" in line]
+    products = [line for line in under_rule if " convolution(" in line]
+    assert len(products) == 3 and all(
+        "operand_precision={highest,highest}" in line for line in products)
+    assert not [line for line in under_rule
+                if " reduce-window(" in line or re.search(r"4096\]\S* copy\(", line)]
 
 
 def test_the_convolutions_of_a_checkpointed_kda_layer_are_the_kernels_under_their_scope(
