@@ -71,7 +71,14 @@ def main(argv=None) -> int:
     os.makedirs(work_dir)
     keep_dir = os.path.join(common.OUT_DIR, args.workload)
 
+    check_failed = []
+
     def say(text):
+        # every driver says a number that passed its limit as "CHECK FAILED:
+        # <name> <value> > <limit>": kept for the result line, so that the
+        # record of a run that read `correct: false` says which number it was
+        if text.startswith("CHECK FAILED: "):
+            check_failed.append(text[len("CHECK FAILED: "):])
         print(f"[{time.monotonic() - T0:7.1f}s] {text}", flush=True)
 
     def keep(path, name):
@@ -121,7 +128,10 @@ def main(argv=None) -> int:
                              "idle_gaps": traced["idle_gaps"]}
     if args.rehearse:
         line["rehearsal"] = True
-    print(json.dumps(line), flush=True)
+    line["check_failed"] = check_failed     # last: what the check held against
+    print(json.dumps(line), flush=True)     # a limit and found over it
+    for failure in check_failed:
+        print(f"CHECK FAILED: {failure}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -18,6 +18,10 @@ driver = common.load_module("drivers", "resident_lm_model")
 CELL = "glm-4.7-flash.resident-8k"
 NEW_METRICS = ("mla_ms", "mla_attn_ms", "mla_attn_roofline", "held_moe_ms",
                "held_gmm_roofline", "mtp_ms", "head_loss_ms")
+# since PR 66 the head's, the optimizer's and the whole step's readings are named
+# for the layer, one reader for every model: `workloads` lists this cell among
+# others, and another model's scopes are read as this one's are
+FOLDED = ("head_loss_ms", "optimizer_ms", "lm_mfu_pct")
 # the catalog row's `config` (architectures.jsonl, GLM-4.7-Flash)
 PUBLISHED = {
     "attention_bias": False, "hidden_act": "silu", "hidden_size": 2048,
@@ -212,6 +216,8 @@ def test_reader_returns_nothing_where_the_program_has_no_such_scopes(name):
     for run in ({"trace": None}, {"trace": {"steps": 2, "busy_s": 1.0, "window_s": 1.0}},
                 {"trace": {"steps": 2, "scope_s": {"unattributed": 1.0},
                            "flash_attention_s": 0.0}, "shape": {}, "peaks": None}):
+        if name in FOLDED and set((run["trace"] or {}).get("scope_s", ())) - {"unattributed"}:
+            continue
         assert read(run) is None
     # the flash kernel's calls are found by name in any program that runs
     # them: `workloads` in BENCHMARK.json is what binds the entry to its cell
@@ -223,7 +229,7 @@ def test_new_per_layer_entry_is_bound_to_the_cell(name):
     with open(common.ROOT + "/BENCHMARK.json") as f:
         bench = json.load(f)
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"] if name in FOLDED else entry["workloads"] == [CELL]
     assert entry["moves"] == "samples_per_s_per_chip" and entry["source"] == "device_trace"
     resolved = common.resolve_cell(CELL)
     assert {m["name"] for m in resolved["per_layer"]} == set(NEW_METRICS) | {

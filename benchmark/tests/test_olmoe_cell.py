@@ -119,7 +119,7 @@ def test_layer_metric_readers():
     read = lambda name: common.load_module("layer_metrics", name).read(run)
     assert run["trace"]["scope_s"]["unattributed"] == 0.002
     assert abs(read("optimizer_ms") - 20.0) < 1e-9
-    assert abs(read("lm_head_ms") - 40.0) < 1e-9
+    assert abs(read("head_loss_ms") - 40.0) < 1e-9
     assert abs(read("moe_ms") - 28.0) < 1e-9              # dispatch 3 + experts 25
     assert abs(read("moe_dispatch_ms") - 3.0) < 1e-9
     assert abs(read("attn_ms") - 15.0) < 1e-9             # the kernels, by name
@@ -132,5 +132,5 @@ def test_readers_return_nothing_where_the_program_has_no_scopes():
                 {"trace": {"steps": 2, "scope_s": {"unattributed": 1.0},
                            "flash_attention_s": 0.0}, "shape": {}, "peaks": None}):
         for name in ("moe_ms", "moe_dispatch_ms", "moe_gmm_roofline", "attn_ms",
-                     "attn_roofline", "lm_head_ms", "optimizer_ms"):
+                     "attn_roofline", "head_loss_ms", "optimizer_ms"):
             assert common.load_module("layer_metrics", name).read(run) is None

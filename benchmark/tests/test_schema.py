@@ -5,6 +5,8 @@ import json
 import os
 import re
 
+import pytest
+
 from benchmark import common
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -36,6 +38,33 @@ def test_names_and_units():
         assert 0.01 <= m["bound"] <= 0.1
     four = sum(1 for w in bench["workloads"] if w["chips"] == 4)
     assert four <= max(1, len(bench["workloads"]) // 4)
+    assert len(bench["per_layer"]) <= 128                      # the contract's limit
+
+
+def test_every_entry_has_a_reader_and_every_reader_an_entry():
+    """A per-layer metric is named for its LAYER, not for a model (PR 66 folded
+    the seven head-and-loss, six optimizer and three traced-MFU entries into
+    one reader each): a reader without an entry is a leftover, an entry
+    without a reader a run that cannot start."""
+    bench = _bench()
+    for kind, key in (("layer_metrics", "per_layer"), ("end_to_end", "end_to_end")):
+        files = {f[:-3] for f in os.listdir(os.path.join(common.BENCH_DIR, kind))
+                 if f.endswith(".py") and f != "__init__.py"}
+        assert files == {m["name"] for m in bench[key]}, kind
+    for gone in ("lm_head_ms", "afmoe_head_loss_ms", "xing_head_loss_ms", "kda_head_loss_ms",
+                 "sambay_head_loss_ms", "lfm2_head_loss_ms", "xing_optimizer_ms", "ut_optimizer_ms",
+                 "kda_optimizer_ms", "sambay_optimizer_ms", "lfm2_optimizer_ms", "sambay_mfu_pct",
+                 "lfm2_mfu_pct", "gdn_mfu_pct", "host_wait_pct", "input_host_samples_per_s"):
+        assert all(m["name"] != gone for m in bench["per_layer"]), gone
+
+
+@pytest.mark.parametrize("name", ["head_loss_ms", "optimizer_ms", "lm_mfu_pct", "mfu_pct"])
+def test_a_metric_read_in_some_cells_alone_says_which(name):
+    """A reader that finds its subject in any model's run is bound by its
+    `workloads` list and by nothing else: without one it would print in every
+    cell that reports `samples_per_s_per_chip`, the Criteo cells too."""
+    entry = next(m for m in _bench()["per_layer"] if m["name"] == name)
+    assert entry.get("workloads")
 
 
 def test_every_name_resolves_to_files():

@@ -1,7 +1,8 @@
 """From a profiler trace (`.xplane.pb`) to the numbers the per-layer metrics
-read: device busy and idle time, time per operation, Mosaic custom calls,
-collectives and their exposed part, idle gaps and what the host was doing in
-them. Reads the file through `jax.profiler.ProfileData` and nothing else.
+read: device busy and idle time, time per operation, Mosaic custom calls (in
+all and by kernel), collectives and their exposed part, idle gaps and what the
+host was doing in them. Reads the file through `jax.profiler.ProfileData` and
+nothing else.
 
 What a v5e trace looks like (looked at by hand, PR 22): one plane per chip,
 `/device:TPU:<n>`, with the lines `Steps`, `XLA Modules` (one event per run of
@@ -32,6 +33,9 @@ COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter",
                "collective-permute")
 # events that only group others: never an operation's own work
 CONTAINERS = ("while", "conditional", "call")
+# `%all_to_all.10 = f32[...]{...} all-to-all(...)`: the instruction's name, then
+# its opcode
+_INSTRUCTION = re.compile(r"%?([\w.\-]+) = .*?\s([\w\-]+)\(")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -50,8 +54,18 @@ def op_kind(name: str) -> str:
     return re.sub(r"[.\d]+$", "", stem) or stem
 
 
+def opcode(name: str) -> str:
+    """`%all_to_all.10 = f32[8] all-to-all(...)` -> `all-to-all`: the HLO
+    opcode of an event named by its instruction's whole text. JAX names an
+    instruction for the primitive that made it (`all_to_all`, `pmax`), so the
+    name's stem is not the opcode; an event that carries the name alone has
+    nothing else to give (`op_kind`)."""
+    m = _INSTRUCTION.match(name)
+    return m.group(2) if m else op_kind(name)
+
+
 def is_collective(name: str) -> bool:
-    kind = op_kind(name)
+    kind = opcode(name)
     return any(kind == c or kind.startswith(c + "-") for c in COLLECTIVES)
 
 
@@ -64,8 +78,21 @@ def is_mosaic(name: str) -> bool:
 def short_name(name: str) -> str:
     """`%place_sorted_grads.4 = f32[...] custom-call(...)` ->
     `place_sorted_grads.4 (custom-call)`: what a breakdown can carry."""
-    m = re.match(r"%?([\w.\-]+) = .*?\s([\w\-]+)\(", name)
+    m = _INSTRUCTION.match(name)
     return f"{m.group(1)} ({m.group(2)})" if m else name[:80]
+
+
+def seconds_by_kernel(per_op_s: dict) -> dict:
+    """Seconds of the Mosaic kernels of one device's `per_op_s`, by kernel:
+    `%place_sorted_grads.4 = ... custom-call(...)` counts under
+    `place_sorted_grads`, the `name=` its `pallas_call` was given, whatever
+    number the compiler put behind it."""
+    out = defaultdict(float)
+    for text, seconds in per_op_s.items():
+        if is_mosaic(text):
+            name = text.lstrip("%").split(" ", 1)[0]
+            out[re.sub(r"\.\d+$", "", name)] += seconds
+    return dict(out)
 
 
 def _events(line):
@@ -214,11 +241,40 @@ def attribute_gaps(gaps_ns, annotations, top=10) -> list:
     return sorted(([k, v] for k, v in by_name.items()), key=lambda kv: -kv[1])[:top]
 
 
+def program_spans(profile) -> list:
+    """What the host was doing where the benchmark annotates nothing (a job
+    runs the program's own loop): the program's `edl.*` spans on the task
+    loop's thread, cut into disjoint pieces in time order, each with the path
+    of spans over it, outermost first. `benchmark/edl_spans.py` finds the
+    thread and cuts the pieces, as it does for the `gap_*` metrics."""
+    from benchmark import edl_spans     # it imports this module
+
+    return edl_spans.innermost_segments(edl_spans.task_loop_spans(profile))
+
+
+def split_gaps(gaps_ns, pieces, top=10) -> list:
+    """Idle seconds under each INNERMOST span of `program_spans`, by name;
+    what lies under none is `unattributed`. A gap is SPLIT among its pieces
+    (`edl_spans.split_gaps`, the `gap_*` metrics' own split), not given whole
+    to the widest cover as `attribute_gaps` does: between two dispatches of a
+    job the device idles ONCE, under the read-back's tail, the turn, the wait
+    for a batch, its transfer and the dispatch, and the widest of them — or
+    `edl.task_turn`, which covers a whole turn — would take it all."""
+    from benchmark import edl_spans
+
+    by_span = edl_spans.split_gaps(gaps_ns, pieces)["by_span"]
+    rows = [["unattributed" if name is None else name, ns / 1e9]
+            for name, ns in by_span.items() if ns > 0]
+    return sorted(rows, key=lambda kv: -kv[1])[:top]
+
+
 def reduce_file(path: str) -> dict:
     """The whole reduction of one trace file. The window is the span of the
     benchmark's annotations where there are any (resident cells), else the
     span of device 0's module runs less the first and the last (job cell:
-    whole dispatches of a steady stretch), else every operation event."""
+    whole dispatches of a steady stretch), else every operation event. Where
+    the benchmark annotated nothing, `program_spans` name the idle gaps of the
+    breakdown, and nothing else: the window and every second stay as they are."""
     from jax.profiler import ProfileData
 
     profile = ProfileData.from_file(path)
@@ -227,7 +283,7 @@ def reduce_file(path: str) -> dict:
          for p in profile.planes if DEVICE_PLANE.match(p.name)),
         key=lambda ip: ip[0])
     if not planes:
-        return {"devices": {}, "annotations": []}
+        return {"devices": {}, "annotations": [], "program_spans": []}
     annotations = host_annotations(profile)
     window = None
     if annotations:
@@ -243,7 +299,8 @@ def reduce_file(path: str) -> dict:
             window = (runs[1][0], runs[-1][0])
             devices = {i: reduce_plane(p, window) for i, p in planes}
             devices = {i: r for i, r in devices.items() if r}
-    return {"devices": devices, "annotations": annotations}
+    return {"devices": devices, "annotations": annotations,
+            "program_spans": [] if annotations else program_spans(profile)}
 
 
 def summary(reduced: dict) -> dict:
@@ -257,16 +314,21 @@ def summary(reduced: dict) -> dict:
     for name, seconds in first["per_op_s"].items():
         by_short[short_name(name)] += seconds
     top_ops = sorted(by_short.items(), key=lambda kv: -kv[1])[:10]
+    if reduced.get("program_spans"):
+        idle_gaps = split_gaps(first["gaps_ns"], reduced["program_spans"])
+    else:
+        idle_gaps = attribute_gaps(first["gaps_ns"], reduced["annotations"])
     return {
         "chips_traced": n,
         "window_s": sum(d["window_s"] for d in devices.values()) / n,
         "busy_s": sum(d["busy_s"] for d in devices.values()) / n,
         "mosaic_s": first["mosaic_s"],
         "mosaic_calls": first["mosaic_calls"],
+        "mosaic_kernel_s": seconds_by_kernel(first["per_op_s"]),
         "collective_s": first["collective_s"],
         "collective_exposed_s": first["collective_exposed_s"],
         "device_ops": [[k, v] for k, v in top_ops],
-        "idle_gaps": attribute_gaps(first["gaps_ns"], reduced["annotations"]),
+        "idle_gaps": idle_gaps,
     }
 
 
